@@ -11,9 +11,14 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import infinistore_tpu as its
+from infinistore_tpu import compile_cache
 
 
 def parse_args():
+    # Every example starts here; the ones that jit (engine_serving,
+    # prefix_reuse, disagg_prefill_decode) find their compiled programs
+    # again on the next run.
+    compile_cache.enable()
     p = argparse.ArgumentParser()
     p.add_argument("--host", default="127.0.0.1")
     p.add_argument(

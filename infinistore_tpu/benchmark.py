@@ -14,6 +14,7 @@ import uuid
 
 import numpy as np
 
+from . import compile_cache
 from .config import TYPE_RDMA, TYPE_TCP, ClientConfig
 from .lib import InfinityConnection, StripedConnection
 
@@ -215,6 +216,7 @@ def _measure_decode_wave(wave: int) -> dict:
         )
     except ImportError:
         return {}
+    compile_cache.enable()
 
     n, bt, kvh, d, h, ntbl = 256, 16, 2, 64, 8, 16
     wave = max(2, wave)
@@ -291,6 +293,7 @@ def _run_trace(args) -> dict:
         import jax.numpy as jnp
     except ImportError as e:
         raise SystemExit(f"--trace needs jax for the engine harness: {e}")
+    compile_cache.enable()
 
     from . import loadgen
     from .connector import KVConnector

@@ -887,6 +887,10 @@ class ClusterKVConnector:
                     on_layer=on_layer,
                 )
             except PartialReadError as e:
+                if not isinstance(e.cause, InfiniStoreException):
+                    # Not the store's failure (see _load_serving).
+                    self._cold_done(j, None)
+                    raise
                 # Same contract as the serving path: the caches list in
                 # the error is the only live one — no retry possible.
                 self._cold_done(j, e)
@@ -2026,6 +2030,14 @@ class ClusterKVConnector:
                     on_layer=on_layer,
                 )
             except PartialReadError as e:
+                if not isinstance(e.cause, InfiniStoreException):
+                    # The reader wraps WHATEVER broke its pipeline so the
+                    # live caches travel with the error. Only a store
+                    # cause (transport, miss, pressure) may degrade to
+                    # "loaded 0, recompute": a device error inside an
+                    # install is the engine's to see, not a cache miss.
+                    self._done(i, None)  # the member answered
+                    raise
                 # The member died mid-read AFTER some layers' scatters
                 # donated their input buffers: e.caches is the ONLY live
                 # cache list, so no replica retry is possible — handing the

@@ -69,8 +69,9 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from . import telemetry, tracing, wire
+from . import compile_cache, telemetry, tracing, wire
 from .connector import KVConnector
+from .hostmesh import cpu_child_env
 from .models.llama import (
     LlamaConfig,
     decode_wave_layer,
@@ -360,9 +361,10 @@ async def _run_decode_steps(
 ):
     """Greedy decode over ``state["out"]`` caches with the layerwise wave
     chain. ``ensure_layer(l)`` (first step only) is the watermark gate —
-    it may swap ``state["out"]`` under us (install donates, fallback
-    recomputes), which is why the cache list lives in the shared ``state``
-    dict rather than a local. Returns ``(tokens, first_logits, t_first)``."""
+    it replaces entries of ``state["out"]`` under us (install donates
+    layer ``l``'s arrays, fallback recomputes every layer), which is why
+    the cache list lives in the shared ``state`` dict rather than a local.
+    Returns ``(tokens, first_logits, t_first)``."""
     tables = jnp.asarray(np.asarray(block_table), jnp.int32)[None]
     tok = int(first_token)
     pos = start_pos
@@ -473,7 +475,13 @@ async def overlapped_decode(
             return
         if not state["fallback"]:
             out, ok = await handle.install_layer(state["out"], ids, layer)
-            state["out"] = out
+            # Only this layer's entry: install_layer works on a COPY of the
+            # list taken before its awaits, and the compute loop has been
+            # writing shallower layers' updated caches into state["out"]
+            # meanwhile — replacing the whole list would drop them (the
+            # first step's K/V insert at every layer whose compute
+            # overlapped a deeper install).
+            state["out"][layer] = out[layer]
             if ok:
                 installed[layer] = True
                 via_handle[layer] = True
@@ -871,7 +879,7 @@ class PrefillProcess:
 
       stdin:  ``go <prompt_seed>``  — prefill+stream that prompt's KV
               ``quit``              — exit
-      stdout: ``ready``             — jax up, store connected
+      stdout: ``ready platform=P``  — jax up on platform P, store connected
               ``shipped <seed> <layer>`` — layer's puts durable (the
               announce channel the decode side's fetch gate consumes)
               ``done <seed> <written>``  — all layers durable
@@ -886,6 +894,7 @@ class PrefillProcess:
     def __init__(self, proc, n_layers: int):
         self.proc = proc
         self.n_layers = n_layers
+        self.platform = ""  # the child's JAX platform, from its ready line
         self._rounds: dict = {}
         self._reader: Optional[asyncio.Task] = None
 
@@ -900,8 +909,12 @@ class PrefillProcess:
             block_tokens=block_tokens, dim=dim, ffn_dim=ffn_dim,
             pace_ms=pace_ms, seed=seed,
         )
+        # This process imported jax (module top) and runs the decode side,
+        # so it holds the machine's device: the child is pinned to the CPU
+        # platform here, at the launch site, never by the role itself.
         proc = await asyncio.create_subprocess_exec(
-            *argv, stdin=asyncio.subprocess.PIPE, stdout=asyncio.subprocess.PIPE
+            *argv, stdin=asyncio.subprocess.PIPE, stdout=asyncio.subprocess.PIPE,
+            env=cpu_child_env(),
         )
         self = cls(proc, n_layers)
 
@@ -910,7 +923,9 @@ class PrefillProcess:
                 line = await proc.stdout.readline()
                 if not line:
                     raise RuntimeError("prefill process exited before ready")
-                if line.decode().strip() == "ready":
+                parts = line.decode().split()
+                if parts[:1] == ["ready"]:
+                    self.platform = parts[1].partition("=")[2]
                     return
 
         await asyncio.wait_for(until_ready(), ready_timeout_s)
@@ -982,9 +997,11 @@ def _main(argv=None) -> int:
     ap.add_argument("--trace-id", type=int, default=None)
     args = ap.parse_args(argv)
 
-    from .hostmesh import force_cpu_devices
-
-    force_cpu_devices(1)
+    # The role runs on whatever platform its environment gives it: a
+    # launcher that shares a machine with a chip-holding parent pins
+    # JAX_PLATFORMS in the child's environment at the launch site
+    # (PrefillProcess.spawn, tools/fleet.py spawn_disagg_prefill).
+    compile_cache.enable()
     import infinistore_tpu as its
 
     cfg = demo_config(
@@ -1031,7 +1048,7 @@ def _main(argv=None) -> int:
 
         async def serve() -> None:
             loop = asyncio.get_running_loop()
-            print("ready", flush=True)
+            print(f"ready platform={jax.devices()[0].platform}", flush=True)
             while True:
                 line = await loop.run_in_executor(None, sys.stdin.readline)
                 parts = line.split()

@@ -19,19 +19,16 @@ from typing import List, Tuple
 
 import jax
 import jax.numpy as jnp
-try:
-    from jax import shard_map
-except ImportError:  # older jax: the experimental home (see paged_attention)
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .llama import LlamaConfig, Params, _rms_norm, _rope
-from .ring_attention import _axis_size, _ring_attention_local
+from .ring_attention import _ring_attention_local
 
 
 def _local_forward(params, tokens, config: LlamaConfig, axis: str):
     """Runs INSIDE shard_map: tokens [B, S_loc] is this shard's chunk."""
-    ring = _axis_size(axis)
+    ring = jax.lax.axis_size(axis)
     rank = jax.lax.axis_index(axis)
     b, s_loc = tokens.shape
     positions = (rank * s_loc + jnp.arange(s_loc, dtype=jnp.int32))[None].repeat(

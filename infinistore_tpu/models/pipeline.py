@@ -19,13 +19,8 @@ from typing import Dict
 
 import jax
 import jax.numpy as jnp
-try:
-    from jax import shard_map
-except ImportError:  # older jax: the experimental home (see paged_attention)
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from .ring_attention import _pcast_varying
 
 from .llama import LlamaConfig, Params, _block, _kv_proj, _rms_norm
 
@@ -110,7 +105,7 @@ def pp_loss_fn(
     init = jnp.zeros((mb, s, config.dim), dtype=config.dtype)
     # The carry flows through ppermute (varying over pp in shard_map's
     # manual-axes typing); the zero init must carry the same type.
-    init = _pcast_varying(init, axis)
+    init = jax.lax.pcast(init, (axis,), to="varying")
     _, sums = jax.lax.scan(tick, init, jnp.arange(ticks))
     total = jax.lax.psum(sums.sum(), axis)  # only the last stage contributes
     return total / (b * (s - 1))

@@ -24,36 +24,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-try:
-    from jax import shard_map
-except ImportError:  # older jax: the experimental home (see paged_attention)
-    # check_rep=False: the scan carry's replication typing needs the explicit
-    # ``pcast`` only newer jax understands (see ``_pcast_varying``); the old
-    # checker can't see it and rejects the gradient path's carry.
-    from jax.experimental.shard_map import shard_map as _esm
-
-    shard_map = functools.partial(_esm, check_rep=False)
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-
-def _axis_size(axis: str) -> int:
-    """Static mesh-axis size inside shard_map. ``jax.lax.axis_size`` where
-    the jax is new enough; older jax has no such helper but statically
-    folds a ``psum`` of a Python constant, so ``psum(1, axis)`` is the
-    size as a plain int there too (``range``/``perm`` below need it
-    static)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis)
-    return jax.lax.psum(1, axis)
-
-
-def _pcast_varying(x, axis: str):
-    """``jax.lax.pcast(..., to="varying")`` where the jax has explicit
-    varying-axes typing; older shard_map treats every value as varying
-    already, so the cast is an identity there."""
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, (axis,), to="varying")
-    return x
 
 
 def _ring_attention_local(
@@ -63,7 +35,7 @@ def _ring_attention_local(
     axis: str,
     causal: bool,
 ) -> jax.Array:
-    ring = _axis_size(axis)
+    ring = jax.lax.axis_size(axis)
     rank = jax.lax.axis_index(axis)
     b, s_loc, h, d = q.shape
     scale = 1.0 / jnp.sqrt(jnp.asarray(d, jnp.float32))
@@ -112,7 +84,9 @@ def _ring_attention_local(
     o0 = jnp.zeros((b, h, s_loc, d), dtype=jnp.float32)
     # The accumulators mix with per-shard data (varying over sp in
     # shard_map's manual-axes typing); their zero inits must match.
-    m0, l0, o0 = (_pcast_varying(x, axis) for x in (m0, l0, o0))
+    m0, l0, o0 = (
+        jax.lax.pcast(x, (axis,), to="varying") for x in (m0, l0, o0)
+    )
     (m, l, o, _, _), _ = jax.lax.scan(
         step, (m0, l0, o0, k, v), jnp.arange(ring)
     )

@@ -18,18 +18,13 @@ import functools
 
 import jax
 import jax.numpy as jnp
-try:
-    from jax import shard_map
-except ImportError:  # older jax: the experimental home (see paged_attention)
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-from .ring_attention import _axis_size
 
 
 def _ulysses_local(q, k, v, axis: str, causal: bool):
     """Runs INSIDE shard_map: q/k/v [B, S_loc, H, D] (sequence-sharded)."""
-    ring = _axis_size(axis)
+    ring = jax.lax.axis_size(axis)
     b, s_loc, h, d = q.shape
     assert h % ring == 0, f"n_heads={h} must divide the {axis} axis ({ring})"
 

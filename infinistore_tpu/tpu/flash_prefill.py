@@ -33,24 +33,26 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-try:  # TPU-specific pallas bits
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
 
 
-def _dividing_block(n: int, limit: int) -> int:
-    """Largest divisor of n that is <= limit (>= 1 always)."""
-    for cand in range(min(limit, n), 0, -1):
-        if n % cand == 0:
-            return cand
-    return 1
+def _block_and_padded(n: int, limit: int, align: int):
+    """(block, padded length) for a sequence of ``n`` rows: the fewest
+    blocks of at most ~``limit`` rows, each a multiple of ``align`` (the
+    dtype's sublane tile — the TPU lowering rejects a second-to-last block
+    dim that is not a multiple of 8, and 16-bit dtypes pack 16 rows per
+    tile), with the sequence padded up to a whole number of blocks."""
+    blocks = -(-n // limit)
+    rows = -(-n // blocks)  # per block, before alignment
+    block = -(-rows // align) * align
+    return block, -(-n // block) * block
 
 
-def _flash_kernel(q_ref, k_ref, v_ref, out_ref, m_scr, l_scr, acc_scr, *, causal):
+def _flash_kernel(
+    q_ref, k_ref, v_ref, out_ref, m_scr, l_scr, acc_scr, *, causal, kv_len
+):
     qb = pl.program_id(1)
     kb = pl.program_id(2)
     _, bq, d = q_ref.shape
@@ -72,9 +74,13 @@ def _flash_kernel(q_ref, k_ref, v_ref, out_ref, m_scr, l_scr, acc_scr, *, causal
 
         @pl.when(kb <= kb_max)
         def _update():
-            _flash_update(qb, kb, q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, causal)
+            _flash_update(
+                qb, kb, q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, causal, kv_len
+            )
     else:
-        _flash_update(qb, kb, q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, causal)
+        _flash_update(
+            qb, kb, q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, causal, kv_len
+        )
 
     @pl.when(kb == pl.num_programs(2) - 1)
     def _finish():
@@ -85,7 +91,9 @@ def _flash_kernel(q_ref, k_ref, v_ref, out_ref, m_scr, l_scr, acc_scr, *, causal
         ).astype(out_ref.dtype)
 
 
-def _flash_update(qb, kb, q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, causal):
+def _flash_update(
+    qb, kb, q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, causal, kv_len
+):
     _, bq, d = q_ref.shape
     bk = k_ref.shape[1]
     scale = 1.0 / np.sqrt(d)
@@ -115,10 +123,17 @@ def _flash_update(qb, kb, q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, causal):
         )
         * scale
     )  # [BQ, BK] f32
-    if causal:
-        qpos = qb * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+    # ``kv_len`` is set only when the wrapper padded K/V to a whole number
+    # of blocks: the padded tail rows are zeros, not context.
+    valid = None
+    if causal or kv_len is not None:
         kpos = kb * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        valid = qpos >= kpos
+        if causal:
+            qpos = qb * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+            valid = qpos >= kpos
+        if kv_len is not None:
+            in_ctx = kpos < kv_len
+            valid = in_ctx if valid is None else jnp.logical_and(valid, in_ctx)
         logits = jnp.where(valid, logits, _NEG_INF)
 
     m_prev = m_scr[...]  # [BQ, 128]
@@ -126,9 +141,9 @@ def _flash_update(qb, kb, q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, causal):
     m_next = jnp.maximum(m_prev, m_curr)
     alpha = jnp.exp(m_prev[:, :1] - m_next[:, :1])
     p = jnp.exp(logits - m_next[:, :1])
-    if causal:
+    if valid is not None:
         # A fully-masked block leaves m_next at _NEG_INF and exp(0)=1 would
-        # leak weight onto future positions; zero those probabilities.
+        # leak weight onto masked positions; zero those probabilities.
         p = jnp.where(valid, p, 0.0)
     l_next = alpha * l_scr[:, :1] + jnp.sum(p, axis=1, keepdims=True)
     # Probabilities ride in V's dtype for the PV pass (exact for f32
@@ -153,16 +168,24 @@ def _flash_prefill_pallas(q, k, v, *, causal, block_q, block_k, interpret):
     b, s, h, d = q.shape
     t, kvh = k.shape[1], k.shape[2]
     groups = h // kvh
-    # Largest divisor of the sequence length within the requested block
-    # size, so ANY length works (a 264-token prompt gets bq=132, not a
-    # trace-time error). A near-prime length degrades toward tiny blocks —
-    # the correct-but-slow end; callers with hot odd lengths should pad.
-    bq = _dividing_block(s, block_q)
-    bk = _dividing_block(t, block_k)
+    # Any length works: the sequence is padded with zero rows up to a whole
+    # number of tile-aligned blocks (a 264-token bf16 prompt runs as two
+    # 144-row blocks over 288 rows), padded keys are masked in the kernel,
+    # and padded query rows are sliced off below. Lengths that already are
+    # a whole number of aligned blocks (every multiple of 256, and every
+    # multiple of 16 up to 256) pad nothing.
+    align = 32 // jnp.dtype(q.dtype).itemsize
+    bq, s_pad = _block_and_padded(s, block_q, align)
+    bk, t_pad = _block_and_padded(t, block_k, align)
     # Head-major rows: [B*H, S, D] queries against [B*KVH, T, D] keys.
     qr = jnp.swapaxes(q, 1, 2).reshape(b * h, s, d)
     kr = jnp.swapaxes(k, 1, 2).reshape(b * kvh, t, d)
     vr = jnp.swapaxes(v, 1, 2).reshape(b * kvh, t, d)
+    if s_pad != s:
+        qr = jnp.pad(qr, ((0, 0), (0, s_pad - s), (0, 0)))
+    if t_pad != t:
+        kr = jnp.pad(kr, ((0, 0), (0, t_pad - t), (0, 0)))
+        vr = jnp.pad(vr, ((0, 0), (0, t_pad - t), (0, 0)))
 
     def kv_row(bh):
         return (bh // h) * kvh + (bh % h) // groups
@@ -177,9 +200,11 @@ def _flash_prefill_pallas(q, k, v, *, causal, block_q, block_k, interpret):
         def kv_block(qb, kb):
             return kb
 
-    grid = (b * h, s // bq, t // bk)
+    grid = (b * h, s_pad // bq, t_pad // bk)
     out = pl.pallas_call(
-        functools.partial(_flash_kernel, causal=causal),
+        functools.partial(
+            _flash_kernel, causal=causal, kv_len=t if t_pad != t else None
+        ),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda bh, qb, kb: (bh, qb, 0)),
@@ -187,7 +212,7 @@ def _flash_prefill_pallas(q, k, v, *, causal, block_q, block_k, interpret):
             pl.BlockSpec((1, bk, d), lambda bh, qb, kb: (kv_row(bh), kv_block(qb, kb), 0)),
         ],
         out_specs=pl.BlockSpec((1, bq, d), lambda bh, qb, kb: (bh, qb, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b * h, s_pad, d), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, 128), jnp.float32),
@@ -195,7 +220,7 @@ def _flash_prefill_pallas(q, k, v, *, causal, block_q, block_k, interpret):
         ],
         interpret=interpret,
     )(qr, kr, vr)
-    return jnp.swapaxes(out.reshape(b, h, s, d), 1, 2)  # [B, S, H, D]
+    return jnp.swapaxes(out[:, :s].reshape(b, h, s, d), 1, 2)  # [B, S, H, D]
 
 
 @functools.partial(jax.jit, static_argnames=("causal",))
@@ -229,14 +254,15 @@ def flash_prefill_xla(q, k, v, *, causal=True):
 
 
 def _use_pallas() -> bool:
-    return pltpu is not None and jax.default_backend() == "tpu"
+    return jax.default_backend() == "tpu"
 
 
 def flash_prefill_attention(q, k, v, *, causal=True, block_q=256, block_k=256):
     """Prefill attention without materializing S x T logits.
 
     q: [B, S, H, D]; k/v: [B, T, KVH, D] with KVH dividing H (GQA); any S/T
-    work (block sizes clamp to the largest dividing value <= block_q/k).
+    work (``block_q``/``block_k`` are block-size targets; odd lengths are
+    padded to whole tile-aligned blocks and masked inside the kernel).
     Pallas flash kernel on TPU, dense XLA elsewhere. Softmax statistics are
     f32 on both paths; for f32 inputs the outputs agree to f32 rounding.
     For bf16 inputs the TPU kernel runs native-dtype MXU dots and rounds

@@ -27,13 +27,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .. import wire
-
-try:  # TPU-specific pallas bits
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
 
 
 @jax.jit
@@ -334,7 +330,7 @@ class QuantizedKVConnector:
 
 
 def _use_pallas() -> bool:
-    return pltpu is not None and jax.default_backend() == "tpu"
+    return jax.default_backend() == "tpu"
 
 
 def paged_decode_attention_quantized(

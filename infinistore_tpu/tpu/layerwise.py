@@ -68,10 +68,9 @@ def kv_block_key(model: str, chain_hash: str, layer: int, kind: str, block: int)
 class _LayerRegions:
     """Read-staging layout: region r holds one layer's K blocks immediately
     followed by its V blocks — a single contiguous span, so the whole layer
-    uploads to the device as ONE transfer (per-transfer fixed cost is the
-    dominant H2D cost on tunneled/remote TPU hosts). The region count adapts
-    to the pool size (>= 2 — double buffering — up to 8), deepening the
-    fetch/H2D pipeline when the pool affords it."""
+    uploads to the device as ONE transfer. The region count adapts to the
+    pool size (>= 2 — double buffering — up to 8), deepening the fetch/H2D
+    pipeline when the pool affords it."""
 
     def __init__(self, pool: HostStagingPool, spec: PagedKVCacheSpec, max_blocks: int):
         if spec.block_nbytes > pool.block_size:
@@ -104,8 +103,7 @@ class LayerwiseKVWriter:
     """Stream a request's KV blocks to the store, one layer at a time.
 
     Pipeline per layer: Pallas-gather blocks from the paged cache (device),
-    pack K and V into one array, start ONE async D2H (per-transfer fixed
-    cost dominates on tunneled/remote TPU hosts — same reason the reader
+    pack K and V into one array, start ONE async D2H (the reader likewise
     uploads one packed span per layer), and ship previous layers' host
     buffers on the network concurrently — up to ``depth`` layer-groups of
     puts in flight. Puts go straight from jax's D2H buffer (registered for
@@ -123,9 +121,8 @@ class LayerwiseKVWriter:
         self.pool = pool
         self.max_blocks = max_blocks
         self.depth = depth
-        # Layers of D2H kept in flight: device->host transfers pipeline (on
-        # tunneled/remote TPU hosts batching them is worth several x), at a
-        # device-memory cost of 2 x n x block_nbytes per window entry.
+        # Layers of D2H kept in flight: device->host transfers pipeline, at
+        # a device-memory cost of 2 x n x block_nbytes per window entry.
         self.d2h_window = d2h_window
 
     async def write(
@@ -299,8 +296,7 @@ class LayerwiseKVReader:
         # previous occupant's UPLOAD (the single K+V device_put) has landed —
         # never its scatters, which queue on the device and must not gate the
         # host loop. The barrier targets a transfer dispatched W layers ago,
-        # so several H2D uploads stay in flight instead of serializing — the
-        # decisive factor when device transfers ride a tunnel or PCIe queue.
+        # so several H2D uploads stay in flight instead of serializing.
         R = self.regions.count
         W = max(1, R - 2)
         out: List[Tuple[jax.Array, jax.Array]] = list(caches)
@@ -720,7 +716,7 @@ class LayerwisePrefetch:
         gate; per-layer host bytes usually sit staged already, so the hold
         is device-transfer time, not store time. When every layer is
         staged in back-to-back regions the whole prefix rides ONE device
-        upload (per-transfer fixed cost dominates tunneled hosts)."""
+        upload."""
         if self._discarded:
             raise PrefetchDiscarded("install() after discard()")
         out = list(caches)
@@ -749,9 +745,8 @@ class LayerwisePrefetch:
         )
         if fused:
             # Back-to-back regions, fully staged: one packed
-            # [L x (K | V)] span -> ONE H2D transfer for the whole prefix
-            # (per-transfer fixed cost dominates tunneled hosts). The
-            # device work runs in an executor so the EVENT LOOP — and
+            # [L x (K | V)] span -> ONE H2D transfer for the whole prefix.
+            # The device work runs in an executor so the EVENT LOOP — and
             # every other request's in-flight fetch completion — never
             # stalls behind it; the caller's gate still serializes the
             # cache mutation across the await.

@@ -20,11 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-try:  # TPU-specific pallas bits
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 
 @dataclass(frozen=True)
@@ -157,7 +153,7 @@ def _scatter_blocks_pallas(cache, block_ids, blocks, *, interpret):
 
 
 def _use_pallas() -> bool:
-    return pltpu is not None and jax.default_backend() == "tpu"
+    return jax.default_backend() == "tpu"
 
 
 def gather_blocks(cache: jax.Array, block_ids: jax.Array) -> jax.Array:

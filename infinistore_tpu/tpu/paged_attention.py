@@ -34,13 +34,19 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
-
-try:  # TPU-specific pallas bits
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
+
+
+def _varying_like(shape, dtype, *operands):
+    """``out_shape`` entry for a pallas_call that also runs inside
+    ``shard_map`` (the stats kernels, under the sharded decode entries): the
+    output varies over every mesh axis an operand varies over, and jax's
+    varying-axes typing requires the kernel to say so. Outside shard_map the
+    set is empty."""
+    vma = frozenset().union(*(jax.typeof(x).vma for x in operands))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
 def _attn_block_update(b, i, seqlen_ref, q, k, v, m_scr, l_scr, acc_scr):
@@ -275,16 +281,17 @@ def _paged_decode_attention_pallas_stats(
         ],
     )
     seq_lens = jnp.asarray(seq_lens, dtype=jnp.int32).reshape(bsz)
+    operands = (block_tables, seq_lens, q, k_cache, v_cache)
     acc, m, l = pl.pallas_call(
         _decode_attn_stats_kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((bsz, h, d), jnp.float32),
-            jax.ShapeDtypeStruct((bsz, h, 128), jnp.float32),
-            jax.ShapeDtypeStruct((bsz, h, 128), jnp.float32),
+            _varying_like((bsz, h, d), jnp.float32, *operands),
+            _varying_like((bsz, h, 128), jnp.float32, *operands),
+            _varying_like((bsz, h, 128), jnp.float32, *operands),
         ],
         interpret=interpret,
-    )(block_tables, seq_lens, q, k_cache, v_cache)
+    )(*operands)
     return acc, m[:, :, :1], l[:, :, :1]
 
 
@@ -588,16 +595,17 @@ def _paged_decode_attention_pallas_ragged_stats(
             pl.BlockSpec((1, h, 128), out),
         ],
     )
+    operands = (page_rows, pages, page_starts, seq_lens, q, k_cache, v_cache)
     acc, m, l = pl.pallas_call(
         _ragged_decode_attn_stats_kernel,
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((r, h, d), jnp.float32),
-            jax.ShapeDtypeStruct((r, h, 128), jnp.float32),
-            jax.ShapeDtypeStruct((r, h, 128), jnp.float32),
+            _varying_like((r, h, d), jnp.float32, *operands),
+            _varying_like((r, h, 128), jnp.float32, *operands),
+            _varying_like((r, h, 128), jnp.float32, *operands),
         ],
         interpret=interpret,
-    )(page_rows, pages, page_starts, seq_lens, q, k_cache, v_cache)
+    )(*operands)
     return acc, m[:, :, :1], l[:, :, :1]
 
 
@@ -695,7 +703,7 @@ def _decode_attention_stats_ragged(
 
 
 def _use_pallas() -> bool:
-    return pltpu is not None and jax.default_backend() == "tpu"
+    return jax.default_backend() == "tpu"
 
 
 def paged_decode_attention_sharded(
@@ -741,26 +749,10 @@ def paged_decode_attention_sharded(
     )
 
 
-def _shard_map():
-    """``jax.shard_map`` where the jax is new enough, else the experimental
-    namespace it graduated from (this box's 0.4.x) — same signature either
-    way. Function-local on purpose: the module-level ``from jax import
-    shard_map`` in ici.py/models/* is a KNOWN env failure this repo leaves
-    alone (ROADMAP note), and a global compat shim would make those
-    modules' tests collect and fail on deeper new-jax APIs."""
-    try:
-        from jax import shard_map
-    except ImportError:  # pragma: no cover - depends on host jax version
-        from jax.experimental.shard_map import shard_map
-    return shard_map
-
-
 @functools.lru_cache(maxsize=None)
 def _sharded_decode_fn(mesh, axis: str):
     """Build (once per mesh/axis) the shard_map'd local-stats + combine."""
     from jax.sharding import PartitionSpec as P
-
-    shard_map = _shard_map()
 
     def local_fn(q_rep, kc, vc, tbl, sl):
         acc, m, l = _decode_attention_stats(q_rep[None], kc, vc, tbl, sl)
@@ -777,7 +769,7 @@ def _sharded_decode_fn(mesh, axis: str):
     # jit around the shard_map: without it every call re-traces and
     # re-lowers (measured ~1900x slower per call on the 8-device CPU mesh).
     fn = jax.jit(
-        shard_map(
+        jax.shard_map(
             local_fn,
             mesh=mesh,
             in_specs=(P(None, None), cache_spec, cache_spec, P(axis, None), P(axis)),
@@ -875,8 +867,6 @@ def _sharded_ragged_decode_fn(mesh, axis: str, table_width: int):
     builder: this is a per-decode-token entry point."""
     from jax.sharding import PartitionSpec as P
 
-    shard_map = _shard_map()
-
     def local_fn(q_rep, kc, vc, pages, rows, starts, lens):
         acc, m, l = _decode_attention_stats_ragged(
             q_rep, kc, vc, pages[0], rows[0], starts[0], lens[0], table_width
@@ -892,7 +882,7 @@ def _sharded_ragged_decode_fn(mesh, axis: str, table_width: int):
     cache_spec = P(axis, None, None, None)
     meta_spec = P(axis, None)
     fn = jax.jit(
-        shard_map(
+        jax.shard_map(
             local_fn,
             mesh=mesh,
             in_specs=(

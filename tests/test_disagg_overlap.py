@@ -155,6 +155,17 @@ class TestByteIdentity:
         )["result"]
         assert over.tokens == oracle.tokens
         assert harness.check_bytes(over, oracle)
+        # The generated tokens' own K/V, every layer: a first-step insert
+        # dropped while a deeper layer was still installing leaves zeros
+        # here and the tokens above can still happen to agree.
+        gen_block = int(harness.tables()[2][-1])
+        for layer in range(CFG.n_layers):
+            for kind in (0, 1):
+                np.testing.assert_array_equal(
+                    np.asarray(over.caches[layer][kind][gen_block]),
+                    np.asarray(oracle.caches[layer][kind][gen_block]),
+                    err_msg=f"layer {layer}: generation block diverged",
+                )
 
 
 class TestFallback:

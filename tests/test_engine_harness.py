@@ -36,6 +36,22 @@ def params():
     return init_params(CFG, jax.random.PRNGKey(0))
 
 
+def _assert_same_to_f32_rounding(got, want, err_msg):
+    """One row computed in two launch shapes (inside a wave, and solo)
+    agrees to float32 rounding, not bitwise: XLA picks a GEMM's
+    accumulation order per shape, so the same sum of float32 products can
+    land on a neighbouring float (jax 0.9.0 on the CPU shows 1 ulp). The
+    bound is 32 ulps of the largest value — far above that reordering,
+    and four orders of magnitude below what a drop to bfloat16 (2^-8)
+    anywhere in the step would show. Cache bytes that MOVE (store round
+    trips, install scatters) stay bitwise elsewhere in this file."""
+    want = np.asarray(want)
+    atol = 32 * np.finfo(np.float32).eps * float(np.max(np.abs(want)))
+    np.testing.assert_allclose(
+        np.asarray(got), want, rtol=0, atol=atol, err_msg=err_msg
+    )
+
+
 def _prompts(n, shared_blocks, total_blocks, seed=0):
     """n prompts sharing the first shared_blocks blocks, diverging after."""
     bt = CFG.block_tokens
@@ -369,10 +385,12 @@ def test_mixed_spec_and_decode_requests_share_waves(conn, params):
 def test_ragged_wave_byte_identical_to_sequential_decode(params):
     """THE ragged-assembly determinism pin: a MIXED wave (two 1-token
     decode rows beside a 3-token verification chunk, concatenated ragged —
-    no row duplication) must produce logits AND cache bytes IDENTICAL to
-    advancing each request alone, one wave of one request at a time. This
-    is the guarantee that lets the scheduler coalesce whatever happens to
-    be ready without ever changing a request's output."""
+    no row duplication) must produce the logits AND cache of advancing
+    each request alone, one wave of one request at a time, to float32
+    rounding (_assert_same_to_f32_rounding: XLA does not grant bitwise
+    equality across launch shapes). This is the guarantee that lets the
+    scheduler coalesce whatever happens to be ready without changing a
+    request's output."""
     from infinistore_tpu.engine import ContinuousBatchingHarness, WaveDecoder
     from infinistore_tpu.models import prefill
 
@@ -426,16 +444,15 @@ def test_ragged_wave_byte_identical_to_sequential_decode(params):
     seq_outs, seq_caches = asyncio.run(seq_run())
     assert wave.max_wave == 3, "requests did not coalesce into one wave"
     for b in range(3):
-        np.testing.assert_array_equal(
+        _assert_same_to_f32_rounding(
             wave_outs[b], seq_outs[b],
             err_msg=f"request {b} logits diverged in the mixed wave",
         )
     for layer in range(CFG.n_layers):
         for kind in (0, 1):
-            np.testing.assert_array_equal(
-                np.asarray(wave_caches[layer][kind]),
-                np.asarray(seq_caches[layer][kind]),
-                err_msg=f"cache bytes diverged (layer {layer})",
+            _assert_same_to_f32_rounding(
+                wave_caches[layer][kind], seq_caches[layer][kind],
+                err_msg=f"cache diverged (layer {layer})",
             )
     # Ragged pad accounting: 5 real flat rows bucket to 8 (3 pad rows) —
     # the rectangle would have launched 4 requests x 4-token chunks = 16.
@@ -689,8 +706,8 @@ def test_skew_policy_off_is_behavior_identical(params):
 def test_skew_policy_defers_outlier_and_stays_byte_identical(params):
     """Policy on: the bucket-bumping 3-token chunk rides a later wave
     (deferral counted, process ledger bumped) while logits AND cache
-    bytes stay identical to per-request sequential decode — the
-    scheduling-only guarantee."""
+    stay those of per-request sequential decode, to float32 rounding —
+    the scheduling-only guarantee."""
     from infinistore_tpu.engine import (
         WaveDecoder, reset_wave_counters, wave_counters,
     )
@@ -727,16 +744,15 @@ def test_skew_policy_defers_outlier_and_stays_byte_identical(params):
     assert (wave.launched_rows, wave.pad_rows) == (6, 1)
     assert len(wave.defer_ages_us) >= 1
     for b in range(3):
-        np.testing.assert_array_equal(
+        _assert_same_to_f32_rounding(
             wave_outs[b], seq_outs[b],
             err_msg=f"request {b} logits diverged under deferral",
         )
     for layer in range(CFG.n_layers):
         for kind in (0, 1):
-            np.testing.assert_array_equal(
-                np.asarray(wave_caches[layer][kind]),
-                np.asarray(seq_caches[layer][kind]),
-                err_msg=f"cache bytes diverged under deferral (layer {layer})",
+            _assert_same_to_f32_rounding(
+                wave_caches[layer][kind], seq_caches[layer][kind],
+                err_msg=f"cache diverged under deferral (layer {layer})",
             )
     st = wave_counters().status()
     assert st["engine_wave_deferrals"] >= 1

@@ -63,26 +63,33 @@ def test_kernel_matches_oracle(case, causal):
     )
 
 
-def test_awkward_lengths_pick_dividing_blocks():
-    """Lengths that don't divide the requested block size must still work
-    (the kernel clamps to the largest dividing block) — a 264-token prompt
-    is valid under the model's S % block_tokens contract and must not
-    trace-error on TPU."""
-    from infinistore_tpu.tpu.flash_prefill import _dividing_block
+def test_awkward_lengths_pad_to_aligned_blocks():
+    """Lengths that are not a whole number of tile-aligned blocks are padded
+    and masked, never handed to the TPU lowering as an unaligned block (a
+    264-token prompt used to pick bq=132, which Mosaic rejects — interpret
+    mode never noticed; tests/test_tpu_aot_compile.py compiles it now)."""
+    from infinistore_tpu.tpu.flash_prefill import _block_and_padded
 
-    assert _dividing_block(264, 256) == 132
-    assert _dividing_block(20, 8) == 5
-    assert _dividing_block(17, 8) == 1  # prime tail: slow but correct
+    assert _block_and_padded(264, 256, 16) == (144, 288)
+    assert _block_and_padded(272, 256, 16) == (144, 288)
+    assert _block_and_padded(1024, 256, 16) == (256, 1024)
+    assert _block_and_padded(48, 256, 16) == (48, 48)
+    assert _block_and_padded(20, 8, 8) == (8, 24)
+    assert _block_and_padded(17, 8, 8) == (8, 24)
     rng = np.random.default_rng(9)
-    q = jnp.asarray(rng.standard_normal((1, 20, 2, 16)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((1, 20, 2, 16)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((1, 20, 2, 16)), jnp.float32)
-    got = _flash_prefill_pallas(
-        q, k, v, causal=True, block_q=8, block_k=8, interpret=True
-    )
-    np.testing.assert_allclose(
-        np.asarray(got, np.float64), _oracle(q, k, v, True), rtol=1e-5, atol=1e-5
-    )
+    for causal in (True, False):
+        # S != T off the causal path: both paddings and the kv mask at once.
+        s_len, t_len = (20, 20) if causal else (20, 27)
+        q = jnp.asarray(rng.standard_normal((1, s_len, 2, 16)), jnp.float32)
+        k = jnp.asarray(rng.standard_normal((1, t_len, 2, 16)), jnp.float32)
+        v = jnp.asarray(rng.standard_normal((1, t_len, 2, 16)), jnp.float32)
+        got = _flash_prefill_pallas(
+            q, k, v, causal=causal, block_q=8, block_k=8, interpret=True
+        )
+        np.testing.assert_allclose(
+            np.asarray(got, np.float64), _oracle(q, k, v, causal),
+            rtol=1e-5, atol=1e-5,
+        )
 
 
 def test_dispatcher_is_dense_off_tpu():

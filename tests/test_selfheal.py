@@ -383,6 +383,49 @@ def test_stage_layer_save_stage_time_error_obeys_degrade():
         strict.stage_layer_save(tokens, 0, kv, np.array([0, 1], np.int32))
 
 
+def test_partial_read_degrades_only_on_store_causes():
+    """A mid-pipeline read failure degrades to "loaded 0, recompute" only
+    when the STORE caused it. The reader wraps whatever broke its pipeline
+    (so the live caches travel with the error); a device error inside an
+    install must reach the engine, not read as a cache miss."""
+    from infinistore_tpu.tpu.layerwise import PartialReadError
+
+    class Member:
+        spec = SPEC
+        cause: Exception = None
+
+        async def load(self, token_ids, caches, block_ids, **kw):
+            raise PartialReadError(list(caches), self.cause)
+
+    class FakeConn:
+        class config:
+            host_addr = "x"
+            service_port = 1
+
+    member = Member()
+    soft = ClusterKVConnector(
+        [FakeConn()], SPEC, "m", max_blocks=8, degrade=True,
+        member_factory=lambda c: member,
+        breaker_factory=_fast_breakers,
+    )
+    tokens = list(range(2 * SPEC.block_tokens))
+    caches = SPEC.make_caches()
+    ids = np.array([0, 1], np.int32)
+
+    member.cause = its.InfiniStoreException("connection reset mid-read")
+    out, n = asyncio.run(soft.load(tokens, caches, ids))
+    assert n == 0 and soft.degraded_ops == 1
+    assert soft.health()["members"][0]["errors"] == 1
+
+    member.cause = RuntimeError("INVALID_ARGUMENT: donated buffer")
+    with pytest.raises(PartialReadError) as err:
+        asyncio.run(soft.load(tokens, caches, ids))
+    assert isinstance(err.value.cause, RuntimeError)
+    assert soft.degraded_ops == 1  # not counted as a degraded miss
+    # The member answered: a device error must not trip its breaker.
+    assert soft.health()["members"][0]["errors"] == 1
+
+
 def test_per_member_stats_carry_health_and_aggregate_persists(trio):
     _, conns = trio
     cluster = _cluster(conns, replicas=1, degrade=True)

@@ -1,0 +1,217 @@
+"""Every Pallas kernel must COMPILE for a TPU v5e — checked with no chip.
+
+The rest of the suite runs the kernels in Pallas interpret mode on the CPU,
+which accepts block shapes Mosaic rejects (flash prefill's 132-row block for
+a 264-token prompt went unnoticed that way). ``libtpu`` can compile for a
+device it does not have: ``get_topology_desc("v5e:2x2")`` describes the
+chips, and ``jit(...).trace(...).lower(lowering_platforms=("tpu",))
+.compile()`` runs the real Mosaic / XLA:TPU compiler against them. So an
+interpret-only kernel fails here, on the CPU box, before any chip time is
+spent. Numerical agreement and donation stay questions for the chip
+(``chip_smoke.py``).
+
+Geometries: the smoke's (Llama-3-8B attention: 32 q / 8 kv heads, head_dim
+128, 16-token blocks, bf16) and the two demo geometries ``bench.py`` and the
+examples run (engine demo: 4 q / 2 kv heads, head_dim 32, 16-token blocks,
+f32; disagg demo: head_dim 16, 8-token blocks, f32).
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+pytest.importorskip("libtpu")
+
+from jax.experimental import topologies  # noqa: E402
+from jax.sharding import (  # noqa: E402
+    Mesh,
+    NamedSharding,
+    PartitionSpec as P,
+    SingleDeviceSharding,
+)
+
+from infinistore_tpu.tpu import flash_prefill as fp  # noqa: E402
+from infinistore_tpu.tpu import kv_quant as kq  # noqa: E402
+from infinistore_tpu.tpu import paged  # noqa: E402
+from infinistore_tpu.tpu import paged_attention as pa  # noqa: E402
+
+# (name, q heads, kv heads, head_dim, block_tokens, dtype)
+GEOMETRIES = [
+    ("smoke", 32, 8, 128, 16, jnp.bfloat16),
+    ("engine_demo", 4, 2, 32, 16, jnp.float32),
+    ("disagg_demo", 4, 2, 16, 8, jnp.float32),
+]
+NUM_BLOCKS = 128  # cache blocks
+N_IDS = 16  # blocks per gather/scatter
+ROWS = 8  # decode rows (a wave of 8)
+TABLE = 16  # table entries per row
+PAGES = 64  # flat ragged page list
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    topo = topologies.get_topology_desc(topology_name="v5e:2x2", platform="tpu")
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(jitted, *args, **static):
+    """Compile for TPU; return the executable after checking the Mosaic
+    kernel is really in it (not an XLA fallback that happens to compile)."""
+    exe = (
+        jitted.trace(*args, **static)
+        .lower(lowering_platforms=("tpu",))
+        .compile()
+    )
+    assert "tpu_custom_call" in exe.as_text(), "no Mosaic kernel in the program"
+    return exe
+
+
+def _shapes(v5e, geom):
+    _, h, kvh, d, bt, dtype = geom
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=v5e)
+    return {
+        "cache": s((NUM_BLOCKS, bt, kvh, d), dtype),
+        "ids": s((N_IDS,), jnp.int32),
+        "blocks": s((N_IDS, bt, kvh, d), dtype),
+        "q": s((ROWS, h, d), dtype),
+        "tables": s((ROWS, TABLE), jnp.int32),
+        "lens": s((ROWS,), jnp.int32),
+        "pages": s((PAGES,), jnp.int32),
+        "page_rows": s((PAGES + 1,), jnp.int32),
+        "i8": s((NUM_BLOCKS, bt, kvh, d), jnp.int8),
+        "scales": s((NUM_BLOCKS, bt, kvh), jnp.float32),
+    }
+
+
+@pytest.mark.parametrize("geom", GEOMETRIES, ids=[g[0] for g in GEOMETRIES])
+def test_paged_kernels_compile(v5e, geom):
+    a = _shapes(v5e, geom)
+    _compile(paged._gather_blocks_pallas, a["cache"], a["ids"], interpret=False)
+    exe = _compile(
+        paged._scatter_blocks_pallas, a["cache"], a["ids"], a["blocks"],
+        interpret=False,
+    )
+    # The scatter's whole point: the cache is updated in place.
+    assert exe.memory_analysis().alias_size_in_bytes > 0
+    dense = (a["q"], a["cache"], a["cache"], a["tables"], a["lens"])
+    _compile(pa._paged_decode_attention_pallas_batched, *dense, interpret=False)
+    _compile(pa._paged_decode_attention_pallas_stats, *dense, interpret=False)
+    ragged = (
+        a["q"], a["cache"], a["cache"], a["pages"], a["page_rows"], a["lens"],
+        a["lens"],
+    )
+    _compile(pa._paged_decode_attention_pallas_ragged, *ragged, interpret=False)
+    _compile(
+        pa._paged_decode_attention_pallas_ragged_stats, *ragged, interpret=False
+    )
+    _compile(
+        kq._quant_decode_pallas, a["q"], a["i8"], a["scales"], a["i8"],
+        a["scales"], a["tables"], a["lens"], interpret=False,
+    )
+
+
+@pytest.mark.parametrize("geom", GEOMETRIES, ids=[g[0] for g in GEOMETRIES])
+@pytest.mark.parametrize("seq", [48, 264, 272, 1024])
+def test_flash_prefill_compiles_at_any_prompt_length(v5e, geom, seq):
+    """48: one short block; 264: the length the old divisor rule broke
+    (bq=132); 272: the old rule's 136-row block, 8-aligned but not a bf16
+    tile; 1024: the smoke's prompt."""
+    _, h, kvh, d, _, dtype = geom
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=v5e)
+    q = s((1, seq, h, d), dtype)
+    kv = s((1, seq, kvh, d), dtype)
+    _compile(
+        fp._flash_prefill_pallas, q, kv, kv,
+        causal=True, block_q=256, block_k=256, interpret=False,
+    )
+
+
+def test_sharded_decode_compiles_for_four_chips(monkeypatch):
+    """The sharded decode entries run the stats kernels INSIDE shard_map;
+    on the CPU they always took the XLA fallback, so nothing had ever asked
+    jax's varying-axes typing about a pallas_call there (it refused: the
+    kernel's out_shape must say which mesh axes it varies over)."""
+    for mod in (paged, pa, fp, kq):
+        monkeypatch.setattr(mod, "_use_pallas", lambda: True)
+    topo = topologies.get_topology_desc(topology_name="v5e:2x2", platform="tpu")
+    mesh = Mesh(np.array(topo.devices), ("sp",))
+    _, h, kvh, d, bt, dtype = GEOMETRIES[0]
+    per, n_local, rows, max_p = 128, 32, 4, 64
+
+    def s(shape, dt, *spec):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=NamedSharding(mesh, P(*spec)))
+
+    cache = s((4 * per, bt, kvh, d), dtype, "sp", None, None, None)
+    fn, _ = pa._sharded_decode_fn(mesh, "sp")
+    _compile(
+        fn, s((h, d), dtype, None, None), cache, cache,
+        s((4, n_local), jnp.int32, "sp", None), s((4,), jnp.int32, "sp"),
+    )
+    fn, _ = pa._sharded_ragged_decode_fn(mesh, "sp", n_local)
+    meta = lambda n: s((4, n), jnp.int32, "sp", None)
+    _compile(
+        fn, s((rows, h, d), dtype, None, None, None), cache, cache,
+        meta(max_p), meta(max_p + 1), meta(rows), meta(rows),
+    )
+
+
+@pytest.mark.slow
+def test_full_width_steps_compile_and_fit_one_v5e(v5e, monkeypatch):
+    """The smoke's two big programs — ``prefill`` (S=1024) and
+    ``verify_step_ragged`` (a wave of 8 rows over 64-block requests) at
+    Llama-3-8B widths, 8 layers, 2 GiB paged cache — compile for one v5e
+    through the models' own dispatchers and fit its 16 GiB."""
+    from infinistore_tpu.models import llama
+
+    # The dispatchers look at the process's default backend (cpu here);
+    # this test asks what they emit when that backend is the chip.
+    for mod in (paged, pa, fp, kq):
+        monkeypatch.setattr(mod, "_use_pallas", lambda: True)
+    cfg = llama.LlamaConfig(
+        vocab=128256, dim=4096, n_layers=8, n_heads=32, n_kv_heads=8,
+        ffn_dim=14336, rope_theta=500000.0, block_tokens=16,
+        dtype=jnp.bfloat16,
+    )
+    num_blocks, req_blocks, rows, seq = 4096, 64, 8, 1024
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=v5e)
+    params = jax.tree.map(
+        lambda x: s(x.shape, x.dtype),
+        jax.eval_shape(lambda: llama.init_params(cfg, jax.random.PRNGKey(0))),
+    )
+    cache = s(cfg.kv_spec(num_blocks).cache_shape, cfg.dtype)
+    caches = [(cache, cache)] * cfg.n_layers
+    i32 = lambda *shape: s(shape, jnp.int32)
+
+    def fits_one_chip(exe):
+        m = exe.memory_analysis()
+        live = (
+            m.argument_size_in_bytes + m.output_size_in_bytes
+            - m.alias_size_in_bytes + m.temp_size_in_bytes
+        )
+        return live < 16 << 30
+
+    # Fresh jit wrappers: the module-level ones cache traces by argument
+    # shapes, and a trace taken under the CPU dispatch must not be reused.
+    exe = _compile(
+        jax.jit(llama.prefill.__wrapped__, static_argnames=("config",)),
+        params, i32(seq), caches, i32(seq // cfg.block_tokens), config=cfg,
+    )
+    assert exe.as_text().count("tpu_custom_call") >= 3 * cfg.n_layers
+    assert fits_one_chip(exe)
+
+    pages = rows * req_blocks
+    exe = _compile(
+        jax.jit(
+            llama.verify_step_ragged.__wrapped__,
+            static_argnames=("config", "max_blocks"),
+        ),
+        params, i32(rows), i32(rows), i32(rows), i32(pages), i32(pages + 1),
+        i32(rows), caches, i32(rows, req_blocks),
+        config=cfg, max_blocks=req_blocks,
+    )
+    assert exe.as_text().count("tpu_custom_call") >= cfg.n_layers
+    assert fits_one_chip(exe)

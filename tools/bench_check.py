@@ -472,10 +472,10 @@ CHECKS = [
     ),
     # Ragged decode attention (tpu/paged_attention.py), two gates on the
     # TPU-backend receipt keys (skipped on hosts without the TPU leg).
-    # Wave 1: the fused kernel must not lose to gather+dense — BENCH_r05
-    # recorded the tie (0.99) this work closed; 0.95 clears the paired
-    # estimator's residual scatter while a structural loss (the kernel
-    # re-materializing what dense gather gets for free) reads well below.
+    # Wave 1: the fused kernel must not lose to gather+dense; 0.95 clears
+    # the paired estimator's residual scatter while a structural loss (the
+    # kernel re-materializing what dense gather gets for free) reads well
+    # below.
     Check(
         "decode_attn_wave1",
         ["tpu_decode_attn_speedup"],

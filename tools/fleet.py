@@ -15,6 +15,11 @@ Two populations, one spawn/readiness/kill/restart protocol:
   whole point: a member dict remembers its ``argv``, so a restart is a
   faithful crash-recovery, not a reconfiguration.
 
+Members that import JAX (fleet clients, the disagg prefill engine) are
+spawned with ``hostmesh.cpu_child_env()``: the spawning process may hold the
+machine's accelerator, and a chip belongs to one process at a time. Server
+members never import JAX.
+
 Every member dict carries ``{"argv", "proc", ...ports}``; ``kill_member``
 is SIGKILL (no shutdown handlers — the crash the durable journal exists
 to survive), ``restart_member`` re-Popens the recorded argv and waits for
@@ -28,6 +33,8 @@ import sys
 import time
 import urllib.error
 import urllib.request
+
+from infinistore_tpu.hostmesh import cpu_child_env
 
 
 def free_port() -> int:
@@ -166,7 +173,9 @@ def spawn_disagg_prefill(port: int, **kw):
     from infinistore_tpu import disagg
 
     argv = disagg.prefill_argv(port, **kw)
-    proc = subprocess.Popen(argv, stdout=subprocess.PIPE, text=True)
+    proc = subprocess.Popen(
+        argv, stdout=subprocess.PIPE, text=True, env=cpu_child_env()
+    )
     return {"service_port": port, "proc": proc, "argv": argv}
 
 
@@ -262,6 +271,7 @@ def spawn_fleet_client(manage_port: int = 0, wait_ready: bool = True,
     argv = client_argv(manage_port, **kw)
     proc = subprocess.Popen(
         argv, stdout=subprocess.PIPE if capture else None,
+        env=cpu_child_env(),
     )
     member = {"manage_port": manage_port, "proc": proc, "argv": argv}
     if wait_ready:
@@ -307,7 +317,7 @@ def restart_member(member: dict, timeout_s: float = 60.0,
     servers), ``None`` skips waiting."""
     if member["proc"].poll() is None:
         raise RuntimeError("member still running — kill_member first")
-    member["proc"] = subprocess.Popen(member["argv"])
+    member["proc"] = subprocess.Popen(member["argv"], env=cpu_child_env())
     if ready == "auto":
         if "service_port" in member:
             _wait_server_ready(member, timeout_s)
