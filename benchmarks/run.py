@@ -1,0 +1,908 @@
+#!/usr/bin/env python3
+"""One run of one cell of the benchmark, in a new process.
+
+    python3 benchmarks/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
+
+Builds the native core if its ``.so`` is missing, starts the store server as
+its own JAX-free process, makes the weights on the device from the seed,
+warms the shapes this cell's traffic uses (set-up), runs the window, checks
+the outputs, prints one JSON line, stops the server. It exits non-zero, and
+prints no result, unless JAX reports a TPU with the chips the cell asks for.
+
+The engine is driven through ``ContinuousBatchingHarness.run_request`` over
+``EngineKVAdapter`` -> ``KVConnector`` -> the real server. Everything that
+belongs to one configuration, traffic mix or per-layer metric is a data
+file found by the name in ``BENCHMARK.json`` (``configs/``, ``traffic/``,
+``layer_metrics/``); nothing here names a cell.
+
+How a token is timed without editing the program: the benchmark wraps
+``harness.wave.step_chunk`` on its own harness instance and records, per
+request, the entry time of every call. A request enters round i + 1 the
+moment round i's token is on the host, so the entry of its second call is
+the first token's emit time and successive entries are the gaps between
+tokens (the last token's emit is not seen: 63 stamps for 64 tokens).
+"""
+
+import time
+
+T_PROCESS = time.perf_counter()  # set-up is counted from here
+
+import argparse
+import asyncio
+import dataclasses
+import gc
+import importlib
+import json
+import os
+import shutil
+import socket
+import subprocess
+import sys
+from typing import Dict, List, Optional
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+REPO = os.path.dirname(HERE)
+sys.path.insert(0, HERE)
+sys.path.insert(0, REPO)
+
+import costs  # noqa: E402
+import readers  # noqa: E402
+import trace_reduce  # noqa: E402
+import traffic  # noqa: E402
+
+# Logits against the float32 reference, as multiples of the reference
+# logits' rms over the compared rows. bfloat16 keeps 8 significant bits
+# (unit roundoff u = 2^-9). Each layer rounds its activations about ten
+# times, so L layers walk ~sqrt(10 L) u: 2.5% of the residual stream at 16
+# layers, which the final norm and the head carry to the logits. The v5e
+# showed 0.68% rms and 3.7% worst at 8 layers (PERF.md, PR 21), so ~1% and
+# ~5% are expected here; the bounds are about 2.5x that. An 8-bit cache or
+# weight (u 16x larger) or one layer left out of 16 (a quarter of the
+# stream's variance) is far outside.
+LOGITS_RMS_TOL = 0.025
+LOGITS_MAX_TOL = 0.15
+DECODE_STEPS_CHECKED = 8
+TRACE_SECONDS = 8.0  # the traced run profiles this long, mid-window
+DOC_BASE_WARM, DOC_BASE_CHECK = 10_000_000, 20_000_000
+
+
+def fail(msg: str, code: int = 1):
+    print(f"benchmarks/run.py: {msg}", file=sys.stderr, flush=True)
+    sys.exit(code)
+
+
+def load_json(path: str) -> Dict:
+    with open(path) as f:
+        return json.load(f)
+
+
+def resolve(dotted: str):
+    module, _, attr = dotted.partition(":")
+    return getattr(importlib.import_module(module), attr)
+
+
+def cell_of(bench: Dict, workload: str):
+    cells = {w["name"]: w for w in bench["workloads"]}
+    if workload not in cells:
+        fail(f"no workload {workload!r} in BENCHMARK.json (have {sorted(cells)})", 2)
+    cell = cells[workload]
+    (config,) = [c for c in bench["configs"] if c["name"] == cell["config"]]
+    return cell, load_json(os.path.join(REPO, config["file"]))
+
+
+def metrics_for(bench: Dict, group: str, workload: str) -> List[Dict]:
+    return [
+        m for m in bench[group]
+        if "workloads" not in m or workload in m["workloads"]
+    ]
+
+
+# ---------------------------------------------------------------------------
+# The store server: its own process, never JAX.
+# ---------------------------------------------------------------------------
+
+
+def free_port() -> int:
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        return s.getsockname()[1]
+
+
+def build_native_if_missing():
+    so = os.path.join(REPO, "infinistore_tpu", "_native", "libinfinistore_tpu.so")
+    if not os.path.exists(so):
+        subprocess.run(
+            ["make", "-s", "-C", os.path.join(REPO, "native"), "-j", str(os.cpu_count() or 2)],
+            check=True, stdout=subprocess.DEVNULL,
+        )
+
+
+def start_server(pool_gib: int, block_kib: int) -> Dict:
+    from infinistore_tpu.hostmesh import cpu_child_env
+
+    service, manage = free_port(), free_port()
+    argv = [
+        sys.executable, "-m", "infinistore_tpu.server", "--host", "127.0.0.1",
+        "--service-port", str(service), "--manage-port", str(manage),
+        "--prealloc-size", str(pool_gib), "--minimal-allocate-size", str(block_kib),
+        "--no-pin-memory", "--log-level", "error",
+    ]
+    proc = subprocess.Popen(argv, cwd=REPO, env=cpu_child_env())
+    deadline = time.time() + 120
+    while time.time() < deadline:
+        if proc.poll() is not None:
+            raise RuntimeError(f"the store server exited with {proc.returncode}")
+        try:
+            with socket.create_connection(("127.0.0.1", service), timeout=0.3):
+                return {"proc": proc, "service_port": service}
+        except OSError:
+            time.sleep(0.05)
+    proc.kill()
+    proc.wait()
+    raise RuntimeError("the store server did not come up in 120 s")
+
+
+def stop_server(server: Dict):
+    proc = server["proc"]
+    if proc.poll() is None:
+        proc.send_signal(2)
+        try:
+            proc.wait(timeout=10)
+        except subprocess.TimeoutExpired:
+            proc.kill()
+    proc.wait()
+
+
+# ---------------------------------------------------------------------------
+# Records.
+# ---------------------------------------------------------------------------
+
+
+@dataclasses.dataclass
+class Record:
+    """What the benchmark notes about one request, from outside."""
+
+    req: traffic.Request
+    t_start: float  # due (open loop) or sent (closed loop): TTFT counts from here
+    t_dispatch: float  # when the generator handed it over (open loop: due + lateness)
+    t_sent: float  # when it entered the harness (open loop: after a live slot freed)
+    stamps: List[float] = dataclasses.field(default_factory=list)  # step_chunk entries
+    pages: List[int] = dataclasses.field(default_factory=list)  # context pages per entry
+    alloc_waited: bool = False
+    stats: Optional[object] = None  # RequestStats
+    error: Optional[str] = None
+    logits: Optional[list] = None  # kept only in the correctness phase
+
+    def emits(self) -> List[float]:
+        """Emit times of the generated tokens seen: entry k + 1 is the
+        moment token k reached the host. The extra round that lands a
+        block-completing last token is not a token."""
+        return self.stamps[1 : self.req.answer_tokens]
+
+
+class Instruments:
+    """The benchmark's taps on its own harness instance: ``step_chunk``
+    entry stamps, ``BlockPool.alloc`` waits, prefill calls. Nothing in the
+    program is edited; the attributes are set on the instances."""
+
+    def __init__(self, harness, block_tokens: int):
+        import jax
+
+        self.h = harness
+        self.bt = block_tokens
+        self.by_task: Dict[object, Record] = {}
+        self.keep_logits = False
+        self.prefills: List[tuple] = []  # (t_start, tokens)
+        self._step_chunk = harness.wave.step_chunk
+        self._alloc = harness.pool.alloc
+        self._prefill_full = harness._prefill_full
+        self._chunked_resume = harness._chunked_resume
+        self._annotate = jax.profiler.TraceAnnotation
+        harness.wave.step_chunk = self.step_chunk
+        harness.pool.alloc = self.alloc
+        harness._prefill_full = self.prefill_full
+        harness._chunked_resume = self.chunked_resume
+
+    async def step_chunk(self, tokens, positions, padded_table, priority=0):
+        rec = self.by_task.get(asyncio.current_task())
+        if rec is not None:
+            rec.stamps.append(time.perf_counter())
+            rec.pages.append(sum(-(-(p + 1) // self.bt) for p in positions))
+        rows = await self._step_chunk(tokens, positions, padded_table, priority=priority)
+        if rec is not None and self.keep_logits:
+            rec.logits.append(rows)
+        return rows
+
+    async def alloc(self, n):
+        rec = self.by_task.get(asyncio.current_task())
+        if rec is not None and self.h.pool.available < n:
+            rec.alloc_waited = True
+        return await self._alloc(n)
+
+    def prefill_full(self, token_ids, table):
+        self.prefills.append((time.perf_counter(), len(token_ids)))
+        with self._annotate("bench.prefill_full"):
+            return self._prefill_full(token_ids, table)
+
+    def chunked_resume(self, token_ids, table, start_block):
+        with self._annotate("bench.chunked_resume"):
+            return self._chunked_resume(token_ids, table, start_block)
+
+
+class Compiles:
+    """Compilations and compile-cache loads, as JAX reports them."""
+
+    def __init__(self):
+        import jax
+
+        self.count = 0
+        jax.monitoring.register_event_duration_secs_listener(self._duration)
+        jax.monitoring.register_event_listener(self._event)
+
+    def _duration(self, event, _seconds, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            self.count += 1
+
+    def _event(self, event, **_):
+        if event == "/jax/compilation_cache/cache_hits":
+            self.count += 1
+
+
+# ---------------------------------------------------------------------------
+# The run.
+# ---------------------------------------------------------------------------
+
+
+class CellRun:
+    def __init__(self, args, cell, config, plan):
+        self.args, self.cell, self.config, self.plan = args, cell, config, plan
+        self.records: List[Record] = []
+        self.failed_checks: List[str] = []
+
+    # -- set-up ---------------------------------------------------------------
+
+    def build(self, conn, compiles):
+        import jax
+        import jax.numpy as jnp
+
+        from infinistore_tpu.connector import KVConnector, token_chain_hashes
+        from infinistore_tpu.engine import ContinuousBatchingHarness, EngineKVAdapter
+
+        prog, serving = self.config["program"], self.config["serving"]
+        fields = {k: self.config[v] for k, v in prog["fields"].items()}
+        self.cfg = resolve(prog["config_class"])(
+            block_tokens=serving["block_tokens"], dtype=jnp.bfloat16, **fields
+        )
+        if self.cfg.head_dim != self.config["head_dim"]:
+            raise ValueError("head_dim of the program's config differs from the file's")
+        self.reference = importlib.import_module(prog["reference"])
+        init = resolve(prog["init_params"])
+        # One jitted call from the seed, on the device, in the served type.
+        # The rbg generator: XLA:TPU takes about a minute to compile
+        # threefry at these shapes (PERF.md, PR 21).
+        key = jax.random.key(self.args.seed % (2**63), impl="rbg")
+        self.params = jax.block_until_ready(jax.jit(lambda k: init(self.cfg, k))(key))
+        bt = self.cfg.block_tokens
+        # A traffic mix may size the cache to what it can hold at most (its
+        # file says why); otherwise the configuration's own size.
+        self.num_blocks = int(self.plan.params.get("cache_blocks", serving["cache_blocks"]))
+        self.max_req_blocks = max(
+            -(-(r.prompt_tokens + r.answer_tokens) // bt) for r in self.plan.requests
+        )
+        self.spec = self.cfg.kv_spec(self.num_blocks)
+        connector = KVConnector(
+            conn, self.spec, self.config["name"], max_blocks=self.max_req_blocks
+        )
+
+        bt_, keep_host_copy = bt, self.keep_host_copy
+
+        class CheckingAdapter(EngineKVAdapter):
+            """The engine adapter, noting every block handed to a save (for
+            the eviction count); in the correctness phase it also keeps a
+            host copy of those blocks."""
+
+            def __init__(self, connector):
+                super().__init__(connector)
+                self.chains_saved = set()
+                self.saved: Dict[str, list] = {}  # chain hash -> per-layer (K, V) bytes
+                self.keep = False
+
+            async def save_kv(self, token_ids, caches, block_table, first_block=0):
+                chains = token_chain_hashes(token_ids, bt_)[first_block:][: len(block_table)]
+                self.chains_saved.update(chains)
+                if self.keep:
+                    keep_host_copy(self.saved, chains, caches, block_table)
+                return await super().save_kv(
+                    token_ids, caches, block_table, first_block=first_block
+                )
+
+        self.adapter = CheckingAdapter(connector)
+        self.h = ContinuousBatchingHarness(
+            self.adapter, self.params, self.cfg, self.num_blocks, self.max_req_blocks
+        )
+        self.taps = Instruments(self.h, bt)
+        self.compiles = compiles
+        self.conn = conn
+
+    @staticmethod
+    def keep_host_copy(saved, chains, caches, block_table):
+        import numpy as np
+
+        host = [(np.asarray(k), np.asarray(v)) for k, v in caches]
+        for chain, blk in zip(chains, np.asarray(block_table)):
+            saved[chain] = [(k[blk].tobytes(), v[blk].tobytes()) for k, v in host]
+
+    def wave_buckets(self) -> List[tuple]:
+        """Every (rows, pages) bucket a wave of this traffic can land on.
+        The decoder pads rows to a power of two by repeating the last row
+        (its pages count again) and pages to a power of two of their sum."""
+        bt = self.cfg.block_tokens
+        lo = min(-(-r.prompt_tokens // bt) for r in self.plan.requests)
+        hi = self.max_req_blocks
+        out, rows = [], 1
+        while rows < 2 * self.plan.clients:
+            p = 1 << (rows * lo - 1).bit_length()
+            while True:
+                out.append((rows, p))
+                if p >= rows * hi:
+                    break
+                p <<= 1
+            rows <<= 1
+        return out
+
+    async def warm_waves(self):
+        """One throwaway wave per bucket: the real ``verify_step_ragged``
+        program on zero tokens at position 0 (the scatter rides block 0
+        slot 0, which no request owns yet), and the row slices and argmax
+        ``_generate`` takes of its logits."""
+        import jax.numpy as jnp
+        import numpy as np
+
+        from infinistore_tpu.models.llama import verify_step_ragged
+        from infinistore_tpu.tpu.paged_attention import build_ragged_wave
+
+        mrb = self.max_req_blocks
+        for rows, pages in self.wave_buckets():
+            meta = build_ragged_wave(
+                [np.zeros(mrb, np.int32)] * rows, [1] * rows, self.cfg.block_tokens,
+                pad_to=pages,
+            )
+            # From Python lists, as the decoder converts them: that small
+            # conversion is a program per length too.
+            zeros = [jnp.asarray([0] * rows, jnp.int32) for _ in range(3)]
+            async with self.h.gate.exclusive():
+                logits, self.h.caches = verify_step_ragged(
+                    self.params, *zeros, jnp.asarray(meta.pages),
+                    jnp.asarray(meta.page_rows), jnp.asarray(meta.page_starts),
+                    self.h.caches, jnp.asarray(np.zeros((rows, mrb), np.int32)),
+                    self.cfg, mrb,
+                )
+            for off in range(rows):
+                np.asarray(jnp.argmax(logits[off : off + 1], axis=-1))
+
+    def prompt_classes(self) -> List[traffic.Request]:
+        """The first request of each (prefix, own tokens) shape in the plan."""
+        first = {}
+        for r in self.plan.requests:
+            first.setdefault((r.prefix_tokens, r.own_tokens), r)
+        return list(first.values())
+
+    def warm_requests(self) -> List[traffic.Request]:
+        """One request per shape the traffic compiles: each prompt length
+        (a miss), and for shared prefixes the same document again (a hit:
+        install and chunked resume). Answers are cut to a few tokens; the
+        save shapes of whole answers are warmed apart."""
+        reqs = []
+        for r in self.prompt_classes():
+            doc = DOC_BASE_WARM + len(reqs)
+            for ask in (0, 1) if r.prefix_tokens else (0,):
+                reqs.append(dataclasses.replace(
+                    r, index=DOC_BASE_WARM + len(reqs), doc=doc, ask=ask, due_s=None,
+                    answer_tokens=self.cfg.block_tokens,
+                ))
+        return reqs
+
+    async def warm_saves(self):
+        """The response-save shapes: one ``_save_blocks`` per distinct count
+        of whole answer blocks, over blocks no request holds."""
+        import numpy as np
+
+        bt = self.cfg.block_tokens
+        counts = sorted({
+            (r.prompt_tokens + r.answer_tokens) // bt - r.prompt_tokens // bt
+            for r in self.plan.requests
+        })
+        rng = np.random.default_rng([self.args.seed, 13])
+        for n in counts:
+            if n <= 0:
+                continue
+            chain = rng.integers(0, self.cfg.vocab, size=n * bt).tolist()
+            table = await self.h.pool.alloc(n)
+            try:
+                await self.h._save_blocks(chain, table, 0)
+            finally:
+                await self.h.pool.free(table)
+
+    # -- one request ----------------------------------------------------------
+
+    async def send(self, req: traffic.Request, t_start=None, t_dispatch=None) -> Record:
+        now = time.perf_counter()
+        rec = Record(
+            req=req, t_start=now if t_start is None else t_start,
+            t_dispatch=now if t_dispatch is None else t_dispatch, t_sent=now,
+        )
+        if self.taps.keep_logits:
+            rec.logits = []
+        self.records.append(rec)
+        task = asyncio.current_task()
+        self.taps.by_task[task] = rec
+        try:
+            tokens = traffic.token_ids(req, self.args.seed, self.cfg.vocab)
+            rec.stats = await self.h.run_request(tokens, gen_tokens=req.answer_tokens)
+        except Exception as e:  # noqa: BLE001 - a failed request is a result
+            rec.error = f"{type(e).__name__}: {e}"
+            print(f"request {req.index} failed: {rec.error[:2000]}", file=sys.stderr, flush=True)
+        finally:
+            del self.taps.by_task[task]
+        return rec
+
+    # -- the window -----------------------------------------------------------
+
+    async def closed_loop(self):
+        opened = [asyncio.Event() for _ in range(self.plan.clients)]
+
+        async def client(c: int):
+            for i, req in enumerate(self.plan.client_list(c)):
+                if self.t_close is not None and time.perf_counter() >= self.t_close:
+                    return
+                await self.send(req)
+                if i == 0:
+                    opened[c].set()
+            self.ran_dry = True
+
+        async def open_window():
+            # The window opens when every client has finished its first
+            # request: the system is then under its steady load.
+            for ev in opened:
+                await ev.wait()
+            self.open_now()
+
+        await asyncio.gather(open_window(), *(client(c) for c in range(self.plan.clients)))
+
+    async def open_loop(self):
+        lead_in = float(self.plan.params.get("lead_in_s", 0.0))
+        live = asyncio.Semaphore(self.plan.clients)
+        tasks = []
+
+        async def one(req, due):
+            dispatched = time.perf_counter()
+            async with live:
+                await self.send(req, t_start=due, t_dispatch=dispatched)
+
+        t_open = time.perf_counter() + lead_in
+        opener = asyncio.get_running_loop().call_later(lead_in, self.open_now, t_open)
+        for req in self.plan.requests:
+            if req.due_s >= self.args.seconds:
+                break
+            due = t_open + req.due_s
+            delay = due - time.perf_counter()
+            if delay > 0:
+                await asyncio.sleep(delay)
+            tasks.append(asyncio.ensure_future(one(req, due)))
+        else:
+            self.ran_dry = True
+        await asyncio.gather(*tasks)
+        opener.cancel()
+
+    def open_now(self, at: Optional[float] = None):
+        self.t_open = time.perf_counter() if at is None else at
+        self.t_close = self.t_open + self.args.seconds
+        self.at_open = self.snapshot()
+        loop = asyncio.get_running_loop()
+        loop.call_later(self.args.seconds, self.close_now)
+        if self.args.trace:
+            start = max(0.0, min(0.4 * self.args.seconds, self.args.seconds - TRACE_SECONDS - 1))
+            loop.call_later(start, self.trace_start)
+
+    def close_now(self):
+        if self.at_close is None:
+            self.at_close = self.snapshot()
+
+    def snapshot(self) -> Dict[str, float]:
+        w = self.h.wave
+        return {
+            "waves": w.waves, "real_rows": w.launched_rows - w.pad_rows,
+            "compiles": self.compiles.count, "t": time.perf_counter(),
+        }
+
+    def trace_start(self):
+        import jax
+
+        shutil.rmtree(self.trace_dir, ignore_errors=True)
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 2
+        jax.profiler.start_trace(self.trace_dir, profiler_options=options)
+        self.trace_t0 = time.perf_counter()
+        asyncio.get_running_loop().call_later(TRACE_SECONDS, self.trace_stop)
+
+    def trace_stop(self):
+        """Ends the profile in a thread: collecting and writing it takes
+        seconds, and on the event loop that would stall every request."""
+        import jax
+
+        self.trace_t1 = time.perf_counter()
+        self.trace_written = asyncio.get_running_loop().run_in_executor(
+            None, jax.profiler.stop_trace
+        )
+
+    async def run(self):
+        """Set-up's last part (the shapes), the window, the drain."""
+        self.t_open = self.t_close = self.at_close = None
+        self.ran_dry = False
+        self.trace_t0 = self.trace_t1 = None
+        self.trace_dir = os.path.join(REPO, ".bench_out", f"trace-{self.cell['name']}")
+        await self.warm_waves()
+        await self.warm_saves()
+        for req in self.warm_requests():
+            rec = await self.send(req)
+            if rec.error:
+                raise RuntimeError(f"warm-up request failed: {rec.error}")
+        self.records.clear()
+        self.taps.prefills.clear()
+        gc.collect()
+        gc.freeze()
+        if self.plan.loop == "closed":
+            await self.closed_loop()
+        else:
+            await self.open_loop()
+        if self.trace_t0 is not None:
+            if self.trace_t1 is None:
+                self.trace_stop()
+            await self.trace_written
+        if self.at_close is None:  # the lists ran dry: still close the window
+            await asyncio.sleep(max(0.0, self.t_close - time.perf_counter()))
+            self.close_now()
+
+    # -- correctness, outside the window ----------------------------------------
+
+    async def check(self):
+        """Per prompt class: a miss against the float32 reference (first
+        token and 8 decode steps), the same prompt again as a full hit
+        (installed blocks byte-identical to what the miss saved, first-token
+        logits equal), and for shared prefixes a partial hit (a new question
+        after the stored prefix: the traffic's own hit path) against the
+        reference."""
+        import jax.numpy as jnp
+        import numpy as np
+
+        from infinistore_tpu.connector import token_chain_hashes
+        from infinistore_tpu.tpu.paged import gather_blocks
+
+        bt = self.cfg.block_tokens
+        self.taps.keep_logits = True
+        self.adapter.keep = True
+        for n, r in enumerate(self.prompt_classes(), start=1):
+            label = f"prompt {r.prompt_tokens}"
+            base = dataclasses.replace(
+                r, index=DOC_BASE_CHECK + 3 * n, doc=DOC_BASE_CHECK + n, ask=0, due_s=None,
+                answer_tokens=2 * bt,
+            )
+            self.adapter.saved.clear()
+            miss = await self.send(base)
+            if not self.expect(miss.error is None, f"{label}: miss failed: {miss.error}"):
+                continue
+            tokens = traffic.token_ids(base, self.args.seed, self.cfg.vocab)
+            self.expect(
+                miss.stats.loaded_blocks == 0 and miss.stats.computed_blocks == len(tokens) // bt,
+                f"{label}: the first ask was not a miss",
+            )
+            self.against_reference(label + " miss", miss, tokens)
+            # Full hit: same prompt, own table; read the installed blocks back.
+            held = {}
+            real_install = self.adapter.install_kv
+
+            async def install_and_keep(prefetch, caches, block_table):
+                out, loaded = await real_install(prefetch, caches, block_table)
+                n = loaded // bt
+                ids = jnp.asarray(np.asarray(block_table[:n]), jnp.int32)
+                held["blocks"] = [
+                    (np.asarray(gather_blocks(k, ids)), np.asarray(gather_blocks(v, ids)))
+                    for k, v in out
+                ]
+                return out, loaded
+
+            self.adapter.install_kv = install_and_keep
+            try:
+                hit = await self.send(base)  # the same tokens: a full hit
+            finally:
+                del self.adapter.install_kv
+            if not self.expect(hit.error is None, f"{label}: hit failed: {hit.error}"):
+                continue
+            n = len(tokens) // bt
+            self.expect(
+                hit.stats.loaded_blocks == n and hit.stats.computed_blocks == 0,
+                f"{label}: the second ask loaded {hit.stats.loaded_blocks} of {n} blocks",
+            )
+            chains = token_chain_hashes(tokens, bt)[:n]
+            same = "blocks" in held and len(held["blocks"][0][0]) == n and all(
+                held["blocks"][layer][kind][i].tobytes() == self.adapter.saved[c][layer][kind]
+                for layer in range(self.cfg.n_layers)
+                for kind in (0, 1)
+                for i, c in enumerate(chains)
+            )
+            self.expect(same, f"{label}: installed blocks are not the bytes that were saved")
+            first = lambda rec: np.asarray(rec.logits[0][0], np.float32)
+            self.expect(
+                bool(np.array_equal(first(hit), first(miss))),
+                f"{label}: hit-path and miss-path first-token logits differ",
+            )
+            self.expect(hit.stats.generated == miss.stats.generated, f"{label}: hit tokens differ")
+            if r.prefix_tokens:
+                part_req = dataclasses.replace(base, index=base.index + 2, ask=1)
+                part = await self.send(part_req)
+                if self.expect(part.error is None, f"{label}: partial hit failed: {part.error}"):
+                    self.expect(
+                        part.stats.loaded_blocks == r.prefix_tokens // bt,
+                        f"{label}: partial hit loaded {part.stats.loaded_blocks} blocks",
+                    )
+                    self.against_reference(
+                        label + " partial hit", part,
+                        traffic.token_ids(part_req, self.args.seed, self.cfg.vocab),
+                    )
+        self.taps.keep_logits = False
+        self.adapter.keep = False
+        self.adapter.saved.clear()
+
+    def expect(self, ok: bool, what: str) -> bool:
+        if not ok:
+            self.failed_checks.append(what)
+            print(f"check failed: {what}", file=sys.stderr, flush=True)
+        return ok
+
+    def against_reference(self, label: str, rec: Record, tokens: List[int]):
+        """Round j of the request decodes position len - 1 + j: the logits
+        that chose generated token j, teacher-forced on the tokens it chose."""
+        import jax.numpy as jnp
+
+        rounds = DECODE_STEPS_CHECKED + 1
+        got = jnp.concatenate([rows[:1] for rows in rec.logits[:rounds]]).astype(jnp.float32)
+        ref = self.reference.logits(
+            self.params, self.config, tokens + rec.stats.generated[: rounds - 1], rounds
+        )
+        scale = float(jnp.sqrt(jnp.mean(ref * ref)))
+        rms = float(jnp.sqrt(jnp.mean((got - ref) ** 2))) / scale
+        worst = float(jnp.max(jnp.abs(got - ref))) / scale
+        print(f"logits {label}: rms {rms:.5f} worst {worst:.5f} (x ref rms {scale:.4f}; "
+              f"tol {LOGITS_RMS_TOL} / {LOGITS_MAX_TOL})", file=sys.stderr, flush=True)
+        self.expect(bool(jnp.all(jnp.isfinite(got))), f"{label}: non-finite logits")
+        self.expect(
+            rms <= LOGITS_RMS_TOL and worst <= LOGITS_MAX_TOL,
+            f"{label}: logits off the float32 reference: rms {rms:.4f} worst {worst:.4f}",
+        )
+
+    # -- the numbers ------------------------------------------------------------
+
+    def request_row(self, rec: Record) -> Dict:
+        """One row of the table the per-layer readers see (see readers.py)."""
+        s, bt = rec.stats, self.cfg.block_tokens
+        emits = rec.emits()
+        row = {
+            "ttft_ms": (emits[0] - rec.t_start) * 1e3 if emits else None,
+            "late_ms": (rec.t_dispatch - rec.t_start) * 1e3,
+            "live_wait_ms": (rec.t_sent - rec.t_dispatch) * 1e3,
+            "alloc_waited": 1.0 if rec.alloc_waited else 0.0,
+            "prompt_blocks": rec.req.prompt_tokens // bt,
+            "hit": False,
+        }
+        if s is not None:
+            per_block = 2 * self.cfg.n_layers * self.spec.block_nbytes
+            row.update(
+                hit=s.loaded_blocks > 0,
+                loaded_blocks=s.loaded_blocks,
+                gate_stall_ms=s.gate_stall_us / 1e3,
+                prefix_ready_ms=s.prefix_ready_us / 1e3,
+                ttft_engine_ms=s.ttft_us / 1e3,
+                after_ready_ms=(
+                    None if row["ttft_ms"] is None else row["ttft_ms"] - s.prefix_ready_us / 1e3
+                ),
+                gate_hold_s=s.gate_hold_us / 1e6,
+                fetch_s=s.fetch_us / 1e6,
+                installed_bytes=s.loaded_blocks * per_block,
+                fetched_bytes=s.prefetched_blocks * self.spec.block_nbytes,
+            )
+        return row
+
+    def results(self, setup_s: float, peak_bytes: int) -> Dict:
+        t0, t1 = self.t_open, self.t_close
+        started = [r for r in self.records if t0 <= r.t_start < t1]
+        rows = [self.request_row(r) for r in started if r.error is None]
+        emits = [r.emits() for r in self.records]
+        in_window = sum(1 for es in emits for e in es if t0 <= e < t1)
+        gaps = [
+            (b - a) * 1e3 for es in emits for a, b in zip(es, es[1:]) if t0 <= b < t1
+        ]
+        e2e = readers.end_to_end(rows, in_window, gaps, t1 - t0, setup_s)
+        # What the run wrote against what the server holds: every block
+        # handed to a save is one key for K and one for V in every layer.
+        written = len(self.adapter.chains_saved)
+        held = self.conn.get_stats()["kvmap_len"]
+        counters = {
+            "waves": self.at_close["waves"] - self.at_open["waves"],
+            "real_rows": self.at_close["real_rows"] - self.at_open["real_rows"],
+            "window_compiles": self.at_close["compiles"] - self.at_open["compiles"],
+            "store_evictions": max(0, written * 2 * self.cfg.n_layers - held),
+            "peak_hbm_bytes": peak_bytes,
+        }
+        if "tpot_mean_ms" in e2e:
+            counters["tpot_mean_ms"] = e2e["tpot_mean_ms"]
+        return {
+            "attempted": len(started),
+            "failed": sum(1 for r in started if r.error is not None),
+            "end_to_end": e2e, "rows": rows, "counters": counters,
+        }
+
+    def trace_results(self) -> Optional[Dict]:
+        if self.trace_t0 is None:
+            return None
+        trace = trace_reduce.reduce(trace_reduce.load(trace_reduce.find_xplane(self.trace_dir)))
+        a, b = self.trace_t0, self.trace_t1
+        c, layers = self.cfg, self.cfg.n_layers
+        item = 2  # bfloat16
+        decode_bytes = flash_flops = 0
+        for rec in self.records:
+            for t, pages in zip(rec.stamps, rec.pages):
+                if a <= t < b:
+                    decode_bytes += layers * costs.ragged_decode_bytes(
+                        pages, 1, c.block_tokens, c.n_heads, c.n_kv_heads, c.head_dim, item
+                    )
+        ktok = 0.0
+        for t, n in self.taps.prefills:
+            if a <= t < b:
+                ktok += n / 1000.0
+                flash_flops += layers * costs.flash_prefill_flops(n, c.n_heads, c.head_dim)
+        trace["work"] = {
+            "ragged_decode_bytes": decode_bytes, "flash_prefill_flops": flash_flops,
+            "prefill_ktok": ktok,
+        }
+        return trace
+
+
+def execute(args, cell, config, plan, device):
+    """Server, set-up, window, checks: everything after the device is known.
+    Returns the result line (without metrics), the window's results and the
+    reduced trace."""
+    import jax
+
+    import infinistore_tpu as its
+
+    compiles = Compiles()
+    run = CellRun(args, cell, config, plan)
+    block_bytes = (
+        config["serving"]["block_tokens"] * config["num_key_value_heads"]
+        * config["head_dim"] * 2
+    )
+    # The pool holds the whole plan's working set below the server's
+    # on-demand eviction threshold (0.8 of the pool), so nothing is evicted.
+    need = traffic.store_bytes(plan, config["serving"]["kv_bytes_per_token"])
+    pool_gib = max(2, int(need / 0.7 / 2**30) + 2)
+    server = start_server(pool_gib, max(16, block_bytes // 1024))
+    conn = None
+    try:
+        conn = its.InfinityConnection(its.ClientConfig(
+            host_addr="127.0.0.1", service_port=server["service_port"], log_level="error",
+        ))
+        conn.connect()
+        run.build(conn, compiles)
+
+        async def whole():
+            await run.run()
+            stats = jax.devices()[0].memory_stats() or {}
+            peak = stats.get("peak_bytes_in_use", 0)
+            await run.check()
+            return peak
+
+        peak_bytes = asyncio.run(whole())
+        res = run.results(run.t_open - T_PROCESS, peak_bytes)
+        trace = run.trace_results()
+    finally:
+        if conn is not None:
+            conn.close()
+        stop_server(server)
+
+    if res["counters"]["window_compiles"]:
+        run.failed_checks.append(
+            f"{res['counters']['window_compiles']} compilations inside the window"
+        )
+    if run.ran_dry:
+        run.failed_checks.append("the traffic's lists ran dry before the window closed")
+    for check in run.failed_checks:
+        print(f"not correct: {check}", file=sys.stderr, flush=True)
+    line = {
+        "correct": not run.failed_checks and res["failed"] == 0,
+        "attempted": res["attempted"], "failed": res["failed"],
+        "metrics": {}, "device": dict(device, memory_peak_bytes=peak_bytes),
+        "workload": cell["name"], "seed": args.seed, "seconds": args.seconds,
+    }
+    return line, res, trace
+
+
+def detail(args, cell, line, res, layer):
+    """Everything this run could read, for whoever studies a run: every
+    end-to-end metric and every per-layer metric whatever ``--trace`` says,
+    in ``.bench_out/`` of the checkout. The driver reads only the line."""
+    out = os.path.join(REPO, ".bench_out")
+    os.makedirs(out, exist_ok=True)
+    name = f"{cell['name']}.seed{args.seed}.trace{args.trace}.{int(time.time())}.json"
+    with open(os.path.join(out, name), "w") as f:
+        json.dump({
+            "line": line, "end_to_end": res["end_to_end"], "per_layer": layer,
+            "counters": res["counters"], "rows": res["rows"],
+        }, f)
+
+
+def device_line(jax) -> Dict:
+    dev = jax.devices()[0]
+    return {"platform": dev.platform, "kind": dev.device_kind, "count": len(jax.devices())}
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--workload", required=True)
+    ap.add_argument("--seed", type=int, required=True)
+    ap.add_argument("--seconds", type=float, required=True)
+    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    args = ap.parse_args()
+
+    bench = load_json(os.path.join(REPO, "BENCHMARK.json"))
+    cell, config = cell_of(bench, args.workload)
+    try:
+        import infinistore_tpu  # noqa: F401 - the system under test must be here
+    except ImportError as e:
+        fail(f"the system under test is not in this checkout: {e}", 2)
+    import jax
+
+    device = device_line(jax)
+    if device["platform"] != "tpu" or device["count"] < cell["chips"]:
+        fail(
+            f"JAX found platform {device['platform']!r} with {device['count']} device(s); "
+            f"cell {cell['name']} needs {cell['chips']} TPU chip(s). Nothing was measured."
+        )
+    peaks = load_json(os.path.join(HERE, "peaks.json"))
+    if device["kind"] not in peaks:
+        fail(f"no peaks for device kind {device['kind']!r} in benchmarks/peaks.json")
+
+    from infinistore_tpu import compile_cache
+
+    compile_cache.enable()
+    # Small programs too: the store path runs dozens of sub-second ones, and
+    # a warm run should find every one of them in the cache.
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    build_native_if_missing()
+    plan = traffic.build_plan(cell["traffic"])
+    line, res, trace = execute(args, cell, config, plan, device)
+    view = readers.Run(res["rows"], res["counters"], trace, peaks[device["kind"]])
+    per_layer = metrics_for(bench, "per_layer", cell["name"])
+    layer = {m["name"]: readers.read_layer_metric(m["name"], view) for m in per_layer}
+    if args.trace:
+        for m in per_layer:
+            if layer[m["name"]] is not None:
+                line["metrics"][m["name"]] = {"value": layer[m["name"]], "unit": m["unit"]}
+        line["device"].update(busy_s=trace["busy_s"], window_s=trace["window_s"])
+        line["breakdown"] = {
+            "device_ops": trace_reduce.top(trace["ops"]),
+            "idle_gaps": trace_reduce.top(trace["idle_gaps"]),
+        }
+    else:
+        for m in metrics_for(bench, "end_to_end", cell["name"]):
+            if m["name"] in res["end_to_end"]:
+                line["metrics"][m["name"]] = {
+                    "value": res["end_to_end"][m["name"]], "unit": m["unit"],
+                }
+    detail(args, cell, line, res, layer)
+    print(json.dumps(line), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

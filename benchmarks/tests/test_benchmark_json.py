@@ -1,0 +1,99 @@
+"""BENCHMARK.json keeps to the contract's letters, and every name in it
+finds its file."""
+
+import json
+import os
+import re
+
+import pytest
+
+import readers
+import traffic
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+    BENCH = json.load(f)
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+
+
+def test_top_level_keys_and_sizes():
+    assert set(BENCH) == {
+        "command", "paths", "run_seconds", "configs", "workloads", "end_to_end", "per_layer",
+    }
+    assert len(json.dumps(BENCH)) < 64 * 1024
+    assert 1 <= BENCH["run_seconds"] <= 51 and isinstance(BENCH["run_seconds"], int)
+    assert BENCH["paths"] == ["benchmarks"] and BENCH["command"][1].startswith("benchmarks/")
+
+
+def test_names_and_units_are_made_of_the_allowed_characters():
+    names = []
+    for group in ("configs", "workloads", "end_to_end", "per_layer"):
+        for entry in BENCH[group]:
+            assert NAME.match(entry["name"]), entry["name"]
+            names.append((group in ("end_to_end", "per_layer"), entry["name"]))
+    assert len(set(names)) == len(names)
+    for w in BENCH["workloads"]:
+        assert NAME.match(w["config"]) and NAME.match(w["traffic"]) and w["chips"] in (1, 4)
+        assert 1 <= len(w["why"]) <= 200 and "\n" not in w["why"] and "\t" not in w["why"]
+    for m in BENCH["end_to_end"] + BENCH["per_layer"]:
+        assert UNIT.match(m["unit"]), m
+        assert m["better"] in ("lower", "higher") and m["source"] in SOURCES
+    for c in BENCH["configs"]:
+        assert set(c) == {"name", "source", "file", "reduced", "why"}
+        assert 1 <= len(c["why"]) <= 200 and all(NAME.match(k) for k in c["reduced"])
+
+
+def test_end_to_end_metrics_have_bounds_and_cells():
+    e2e = {m["name"]: m for m in BENCH["end_to_end"]}
+    assert "setup_s" in e2e and e2e["setup_s"]["bound"] <= 0.1
+    cells = {w["name"] for w in BENCH["workloads"]}
+    for m in e2e.values():
+        assert set(m) <= {"name", "unit", "better", "bound", "source", "workloads"}
+        assert 0.01 <= m["bound"] <= 0.1 and m["source"] in ("host_clock", "device_trace")
+        assert set(m.get("workloads", cells)) <= cells
+    for cell in cells:
+        listed = [m for m in e2e.values() if cell in m.get("workloads", cells)]
+        assert len(listed) >= 2
+
+
+def test_loop_kinds_decide_where_rate_and_tail_are_reported():
+    e2e = {m["name"]: m for m in BENCH["end_to_end"]}
+    loops = {w["name"]: traffic.load_params(w["traffic"])["loop"] for w in BENCH["workloads"]}
+    if "tokens_per_s" in e2e:
+        assert all(loops[c] == "closed" for c in e2e["tokens_per_s"]["workloads"])
+    if "ttft_p90_ms" in e2e:
+        assert all(loops[c] == "open" for c in e2e["ttft_p90_ms"]["workloads"])
+
+
+@pytest.mark.parametrize("metric", [m["name"] for m in BENCH["per_layer"]])
+def test_layer_metric_file_agrees_with_benchmark_json(metric):
+    (entry,) = [m for m in BENCH["per_layer"] if m["name"] == metric]
+    assert set(entry) <= {"name", "unit", "better", "source", "layer", "moves", "workloads"}
+    spec = readers.load_layer_metric(metric)
+    for key in entry:
+        assert spec[key] == entry[key], (metric, key)
+    assert spec["reader"]["kind"] in readers.KINDS
+    e2e = {m["name"]: m for m in BENCH["end_to_end"]}
+    cells = [w["name"] for w in BENCH["workloads"]]
+    moved = e2e[entry["moves"]]
+    # The metric it moves is reported wherever this one is.
+    assert set(entry.get("workloads", cells)) <= set(moved.get("workloads", cells))
+
+
+@pytest.mark.parametrize("cell", [w["name"] for w in BENCH["workloads"]])
+def test_every_cell_finds_its_files(cell):
+    (w,) = [w for w in BENCH["workloads"] if w["name"] == cell]
+    (cfg,) = [c for c in BENCH["configs"] if c["name"] == w["config"]]
+    with open(os.path.join(REPO, cfg["file"])) as f:
+        config = json.load(f)
+    assert config["source"] == cfg["source"] and config["reduced"] == cfg["reduced"]
+    for key in config["program"]["fields"].values():
+        assert key in config
+    assert config["hidden_size"] == config["num_attention_heads"] * config["head_dim"]
+    s = config["serving"]
+    assert s["kv_bytes_per_token"] == (
+        config["num_hidden_layers"] * 2 * config["num_key_value_heads"] * config["head_dim"] * 2
+    )
+    assert traffic.build_plan(w["traffic"]).requests
