@@ -48,7 +48,7 @@ lost to eviction (the cache-semantics path: the engine just recomputes).
 import asyncio
 import time
 from contextlib import asynccontextmanager
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import List, Optional, Sequence
 
 import jax
@@ -178,9 +178,16 @@ class BlockPool:
         return len(self._free)
 
     async def alloc(self, n: int) -> np.ndarray:
+        # `pool_alloc`: entry to blocks in hand (the wait for another
+        # request to free its table is the whole of it when there is one).
+        span = tracing.start_span("pool_alloc")
+        if span is not None:
+            span.annotate(blocks=n, free_at_entry=len(self._free))
         async with self._cond:
             await self._cond.wait_for(lambda: len(self._free) >= n)
             ids = [self._free.pop() for _ in range(n)]
+        if span is not None:
+            span.finish()
         return np.asarray(ids, dtype=np.int32)
 
     async def free(self, ids: np.ndarray):
@@ -220,6 +227,10 @@ class DeviceGate:
 
     @asynccontextmanager
     async def exclusive(self, expedite: bool = False):
+        # `gate_wait`: entry to acquired, whoever asked is its parent.
+        span = tracing.start_span("gate_wait")
+        if span is not None:
+            span.annotate(mode="expedite" if expedite else "exclusive")
         async with self._cond:
             self._exclusive_waiting += 1
             if expedite:
@@ -239,6 +250,8 @@ class DeviceGate:
                 # notify they would sleep forever on a free gate.
                 self._cond.notify_all()
             self._exclusive = True
+        if span is not None:
+            span.finish()
         try:
             yield
         finally:
@@ -248,11 +261,16 @@ class DeviceGate:
 
     @asynccontextmanager
     async def shared(self):
+        span = tracing.start_span("gate_wait")
+        if span is not None:
+            span.annotate(mode="shared")
         async with self._cond:
             await self._cond.wait_for(
                 lambda: not self._exclusive and self._exclusive_waiting == 0
             )
             self._shared += 1
+        if span is not None:
+            span.finish()
         try:
             yield
         finally:
@@ -554,6 +572,7 @@ class WaveDecoder:
 
     async def _flush(self):
         batch: List[tuple] = []
+        wspan = None
         try:
             # Yield twice: once so sibling coroutines already unblocked this
             # tick can enqueue, once more for requests their completions wake.
@@ -564,6 +583,12 @@ class WaveDecoder:
             self._flush_scheduled = False
             if not batch:
                 return
+            # `wave`: a trace of its own (it serves many requests), one
+            # span per flush that launches — a flush the policy holds back
+            # whole never finishes its span, so it is never recorded.
+            if tracing.enabled():
+                wspan = tracing.Span("wave")
+                wspan.stage("taken")
             if self.skew_policy:
                 now = time.perf_counter()
                 batch, deferred = self._partition(batch, now)
@@ -631,21 +656,36 @@ class WaveDecoder:
             if self.skew_policy:
                 _WAVE_COUNTERS.bump("engine_wave_policy_waves")
                 _WAVE_COUNTERS.note_wave(t_real, t_bucket)
-
-            async with self.h.gate.exclusive():
-                logits, self.h.caches = verify_step_ragged(
-                    self.h.params,
-                    jnp.asarray(flat_toks, jnp.int32),
-                    jnp.asarray(flat_pos, jnp.int32),
-                    jnp.asarray(row_of, jnp.int32),
-                    jnp.asarray(meta.pages),
-                    jnp.asarray(meta.page_rows),
-                    jnp.asarray(meta.page_starts),
-                    self.h.caches,
-                    jnp.asarray(np.stack(tables)),
-                    self.h.config,
-                    self.h.max_req_blocks,
+            if wspan is not None:
+                wspan.stage("assembled")
+                wspan.annotate(
+                    entries=len(batch), real_rows=t_real, rows=t_bucket,
+                    pages=meta.num_pages,
                 )
+
+            # The flush task inherited the context of the request that
+            # scheduled it: bind the wave's own span, so the gate wait
+            # below is the wave's child and not that request's.
+            with tracing.override_span(wspan):
+                async with self.h.gate.exclusive():
+                    if wspan is not None:
+                        wspan.stage("gate")
+                    with tracing.device_call("its.wave_dispatch", wspan):
+                        logits, self.h.caches = verify_step_ragged(
+                            self.h.params,
+                            jnp.asarray(flat_toks, jnp.int32),
+                            jnp.asarray(flat_pos, jnp.int32),
+                            jnp.asarray(row_of, jnp.int32),
+                            jnp.asarray(meta.pages),
+                            jnp.asarray(meta.page_rows),
+                            jnp.asarray(meta.page_starts),
+                            self.h.caches,
+                            jnp.asarray(np.stack(tables)),
+                            self.h.config,
+                            self.h.max_req_blocks,
+                        )
+                    if wspan is not None:
+                        wspan.stage("dispatched")
             self.waves += 1
             self.max_wave = max(self.max_wave, len(batch))
             off = 0
@@ -653,7 +693,12 @@ class WaveDecoder:
                 if not fut.done():
                     fut.set_result(logits[off : off + len(toks)])
                 off += len(toks)
+            if wspan is not None:
+                wspan.stage("resolved")
+                wspan.finish()
         except BaseException as e:  # noqa: BLE001 - must fail the waiters
+            if wspan is not None:
+                wspan.finish(status=f"error:{type(e).__name__}")
             # A dead flush (model error, or cancellation/GC at shutdown)
             # must strand NO waiter: fail the taken batch and anything still
             # pending, and clear the flag so a later step() starts fresh.
@@ -862,12 +907,22 @@ class RequestStats:
     # computed): the end-to-end figure that decides whether a cache hit
     # actually beats recomputing.
     prefix_ready_us: float = 0.0
-    # t0 -> the FIRST generated token emitted (0.0 when gen_tokens == 0):
-    # the serving-side latency figure the skew-aware flush policy is
-    # graded on (docs/serving_load.md), and the request's QoS class
-    # (wire.PRIORITY_*) so TTFT percentiles split by class.
+    # t0 -> the first wave handed this request its logits rows (0.0 when
+    # gen_tokens == 0): the serving-side latency figure the skew-aware
+    # flush policy is graded on (docs/serving_load.md). It PRECEDES the
+    # read-back that puts the sampled token on the host; the emit time
+    # itself is token_emit_s[0]. And the request's QoS class
+    # (wire.PRIORITY_*), so TTFT percentiles split by class.
     ttft_us: float = 0.0
     priority: int = 0
+    # time.perf_counter() of every generated token, taken once the token
+    # is on the host (after the round's read-back); always filled, tracing
+    # on or off. A speculative round that emits several tokens stamps them
+    # all with the one reading.
+    token_emit_s: List[float] = field(default_factory=list)
+    # The request's trace (tracing.py): every span it recorded carries
+    # this id. 0 with tracing off.
+    trace_id: int = 0
 
 
 class ContinuousBatchingHarness:
@@ -1061,39 +1116,64 @@ class ContinuousBatchingHarness:
             self.max_req_blocks,
         )
 
-    async def _save_blocks(self, chain_ids, phys_blocks, first_block: int):
+    async def _save_blocks(
+        self, chain_ids, phys_blocks, first_block: int,
+        before_first_token: bool = False,
+    ):
         """Snapshot the given physical blocks into private arrays under the
         shared gate (device-side gathers, microseconds), then stream them to
         the store with NO gate held: the save — the long store-I/O phase —
         overlaps other requests' loads, computes, and saves. Holding the
         gate across the save would serialize the whole pipeline (the next
         request's exclusive load waits on it). ``chain_ids`` key the blocks
-        (the prompt, or prompt + generated for response blocks)."""
+        (the prompt, or prompt + generated for response blocks).
+
+        Two spans, both with ``blocks`` and ``before_first_token`` (true
+        for the prompt's save, which the first token waits for):
+        ``save_snapshot`` — the shared gate's wait (its ``gate_wait``
+        child), the executor hop, the gathers and their readiness wait —
+        and ``save_io``, the whole ``adapter.save_kv`` await."""
         dev = jnp.asarray(np.asarray(phys_blocks))
-        async with self.gate.shared():
-            caches = self.caches  # stable under the shared gate
+        with tracing.trace_op("save_snapshot") as sspan:
+            if sspan is not None:
+                sspan.annotate(
+                    blocks=len(phys_blocks), before_first_token=before_first_token
+                )
+            async with self.gate.shared():
+                caches = self.caches  # stable under the shared gate
 
-            def snap():
-                s = [
-                    (gather_blocks(k, dev), gather_blocks(v, dev))
-                    for k, v in caches
-                ]
-                jax.block_until_ready(s)
-                return s
+                def snap():
+                    with tracing.device_call("its.save_snapshot", sspan):
+                        s = [
+                            (gather_blocks(k, dev), gather_blocks(v, dev))
+                            for k, v in caches
+                        ]
+                        jax.block_until_ready(s)
+                    return s
 
-            # Executor: the gathers + readiness wait must not pin the event
-            # loop (it is the artery every gate-free fetch completion and
-            # wave flush flows through).
-            snapshot = await asyncio.get_running_loop().run_in_executor(None, snap)
+                # Executor: the gathers + readiness wait must not pin the
+                # event loop (it is the artery every gate-free fetch
+                # completion and wave flush flows through).
+                snapshot = await asyncio.get_running_loop().run_in_executor(
+                    None, snap
+                )
         self._saving += 1
         self.max_concurrent_saves = max(self.max_concurrent_saves, self._saving)
         try:
-            await self.adapter.save_kv(
-                chain_ids,
-                snapshot,
-                np.arange(len(phys_blocks), dtype=np.int32),
-                first_block=first_block,
-            )
+            with tracing.trace_op("save_io") as iospan:
+                await self.adapter.save_kv(
+                    chain_ids,
+                    snapshot,
+                    np.arange(len(phys_blocks), dtype=np.int32),
+                    first_block=first_block,
+                )
+                # Last: the store's write ops annotate the span they run
+                # under too (`op`, and one op's `blocks`).
+                if iospan is not None:
+                    iospan.annotate(
+                        blocks=len(phys_blocks),
+                        before_first_token=before_first_token,
+                    )
         finally:
             self._saving -= 1
 
@@ -1116,46 +1196,65 @@ class ContinuousBatchingHarness:
         overwrite it). The chunk is capped to the tokens still wanted, so
         a round never overshoots ``gen_tokens``.
 
-        Returns ``(tokens, first_token_t)`` — the perf_counter stamp of
-        the first emitted token feeds ``RequestStats.ttft_us``."""
+        Returns ``(tokens, first_token_t, emit_s)``: the perf_counter stamp
+        of the first wave's result feeds ``RequestStats.ttft_us``, and
+        ``emit_s`` (one perf_counter reading per token, taken after the
+        round's read-back) is ``RequestStats.token_emit_s``.
+
+        One ``generate`` span per request, with three stage stamps a round
+        (a span per token would be thousands a minute): ``wave_enqueue``
+        as the chunk goes to the decoder, ``wave_result`` when the wave's
+        future hands the rows back, ``token`` once the sampled token is on
+        the host."""
         padded = self._padded_table(table)
         pos = len(token_ids) - 1
         tok = int(token_ids[-1])
         history = list(token_ids)
         out: List[int] = []
+        emit_s: List[float] = []
         first_token_t: Optional[float] = None
-        while len(out) < gen_tokens:
-            chunk = [tok]
-            if self.drafter is not None:
-                remaining = gen_tokens - len(out)
-                chunk += self.drafter.draft(history)[: remaining - 1]
-            rows = await self.wave.step_chunk(
-                chunk, list(range(pos, pos + len(chunk))), padded,
-                priority=priority,
-            )
-            if first_token_t is None:
-                first_token_t = time.perf_counter()
-            # ONE device->host transfer per round (the [K] argmaxes).
-            preds = np.asarray(jnp.argmax(rows, axis=-1))
-            n_acc = 1
-            while n_acc < len(chunk) and chunk[n_acc] == int(preds[n_acc - 1]):
-                n_acc += 1
-            emitted = chunk[1:n_acc] + [int(preds[n_acc - 1])]
-            out.extend(emitted)
-            history.extend(emitted)
-            self.spec_rounds += 1
-            self.spec_drafted += len(chunk) - 1
-            self.spec_accepted += n_acc - 1
-            pos += n_acc
-            tok = emitted[-1]
-        # Each round inserts its CHUNK's K/V; the final emitted token's
-        # insert only happens as the next round's committed token. When it
-        # completes a block (which the extended-chain save below persists),
-        # one more step lands it; otherwise its block is an incomplete tail
-        # with no chain key — skip the wasted wave.
-        if (len(token_ids) + gen_tokens) % self.config.block_tokens == 0:
-            await self.wave.step(tok, pos, padded, priority=priority)
-        return out, first_token_t
+        with tracing.trace_op("generate") as gspan:
+            while len(out) < gen_tokens:
+                chunk = [tok]
+                if self.drafter is not None:
+                    remaining = gen_tokens - len(out)
+                    chunk += self.drafter.draft(history)[: remaining - 1]
+                if gspan is not None:
+                    gspan.stage("wave_enqueue")
+                rows = await self.wave.step_chunk(
+                    chunk, list(range(pos, pos + len(chunk))), padded,
+                    priority=priority,
+                )
+                if gspan is not None:
+                    gspan.stage("wave_result")
+                if first_token_t is None:
+                    first_token_t = time.perf_counter()
+                # ONE device->host transfer per round (the [K] argmaxes).
+                with tracing.device_call("its.readback", gspan):
+                    preds = np.asarray(jnp.argmax(rows, axis=-1))
+                now = time.perf_counter()
+                if gspan is not None:
+                    gspan.stage("token")
+                n_acc = 1
+                while n_acc < len(chunk) and chunk[n_acc] == int(preds[n_acc - 1]):
+                    n_acc += 1
+                emitted = chunk[1:n_acc] + [int(preds[n_acc - 1])]
+                out.extend(emitted)
+                emit_s.extend([now] * len(emitted))
+                history.extend(emitted)
+                self.spec_rounds += 1
+                self.spec_drafted += len(chunk) - 1
+                self.spec_accepted += n_acc - 1
+                pos += n_acc
+                tok = emitted[-1]
+            # Each round inserts its CHUNK's K/V; the final emitted token's
+            # insert only happens as the next round's committed token. When
+            # it completes a block (which the extended-chain save below
+            # persists), one more step lands it; otherwise its block is an
+            # incomplete tail with no chain key — skip the wasted wave.
+            if (len(token_ids) + gen_tokens) % self.config.block_tokens == 0:
+                await self.wave.step(tok, pos, padded, priority=priority)
+        return out, first_token_t, emit_s
 
     def _verify_request(self, token_ids, table: np.ndarray) -> bool:
         """Compare the harness cache's blocks for this request against a
@@ -1210,12 +1309,16 @@ class ContinuousBatchingHarness:
         # stamped at admission t0, `install` when fetched bytes land in the
         # paged cache; every store op issued below (prefetch -> coalescer ->
         # striped scheduler -> wire) becomes a child of this span via the
-        # bound context. With tracing off this is three no-op calls.
+        # bound context, and so do the request's own phases: `pool_alloc`,
+        # `gate_wait`, `install`, `compute`, `save_snapshot`, `save_io`,
+        # `generate`. With tracing off each hook is a no-op call.
         rspan = tracing.start_span("engine_request")
         rtoken = tracing.bind_span(rspan)
         if rspan is not None:
             rspan.stage("enqueue")
-            rspan.annotate(tokens=len(token_ids), blocks=n_blocks)
+            rspan.annotate(
+                tokens=len(token_ids), blocks=n_blocks, gen_tokens=gen_tokens
+            )
         # Speculative prefetch AT ENQUEUE: probe + start streaming the hit
         # prefix into host staging before BlockPool.alloc even completes —
         # the store fetch overlaps this request's own admission wait and
@@ -1302,11 +1405,14 @@ class ContinuousBatchingHarness:
                     async with self.gate.exclusive(expedite=True):
                         gate_stall_us = (time.perf_counter() - t_gate) * 1e6
                         t_hold = time.perf_counter()
-                        self.caches, loaded_tokens = await self.adapter.install_kv(
-                            prefetch,
-                            self.caches,
-                            prompt_table[: prefetch.n_blocks],
-                        )
+                        with tracing.trace_op("install") as ispan:
+                            if ispan is not None:
+                                ispan.annotate(blocks=prefetch.n_blocks)
+                            self.caches, loaded_tokens = await self.adapter.install_kv(
+                                prefetch,
+                                self.caches,
+                                prompt_table[: prefetch.n_blocks],
+                            )
                         if rspan is not None:
                             rspan.stage("install")
                         gate_hold_us = (time.perf_counter() - t_hold) * 1e6
@@ -1336,9 +1442,15 @@ class ContinuousBatchingHarness:
                 async with self.gate.exclusive():
                     gate_stall_us = (time.perf_counter() - t_gate) * 1e6
                     t_io = time.perf_counter()
-                    self.caches, loaded_tokens = await self.adapter.load_kv(
-                        token_ids, self.caches, prompt_table
-                    )
+                    # One phase: the span holds the store fetch too.
+                    with tracing.trace_op("install") as ispan:
+                        self.caches, loaded_tokens = await self.adapter.load_kv(
+                            token_ids, self.caches, prompt_table
+                        )
+                        # (after the store's read ops, which annotate the
+                        # span they run under with one op's `blocks`)
+                        if ispan is not None:
+                            ispan.annotate(blocks=loaded_tokens // bt, one_phase=True)
                     if rspan is not None and loaded_tokens:
                         rspan.stage("install")
                     gate_hold_us = (time.perf_counter() - t_io) * 1e6
@@ -1361,19 +1473,31 @@ class ContinuousBatchingHarness:
                     # exactly the overlap this pipeline exists to create.
                     # The gate (held across the await) still serializes
                     # cache mutation.
+                    # `compute` covers the executor hop and the call.
+                    # waits_for_device says what its end means: the prefill
+                    # returns when the device is done, the resume when it
+                    # is DISPATCHED (its device time is first waited for by
+                    # whoever touches the cache next: the save's snapshot).
                     loop = asyncio.get_running_loop()
-                    if loaded_blocks == 0:
-                        await loop.run_in_executor(
-                            None, self._prefill_full, token_ids, prompt_table
-                        )
-                    else:
-                        await loop.run_in_executor(
-                            None,
-                            self._chunked_resume,
-                            token_ids,
-                            table,
-                            loaded_blocks,
-                        )
+                    full = loaded_blocks == 0
+                    with tracing.trace_op("compute") as cspan:
+                        if cspan is not None:
+                            cspan.annotate(
+                                kind="prefill_full" if full else "chunked_resume",
+                                tokens=(n_blocks - loaded_blocks) * bt,
+                                waits_for_device=full,
+                            )
+
+                        def compute():
+                            with tracing.device_call("its.compute", cspan):
+                                if full:
+                                    self._prefill_full(token_ids, prompt_table)
+                                else:
+                                    self._chunked_resume(
+                                        token_ids, table, loaded_blocks
+                                    )
+
+                        await loop.run_in_executor(None, compute)
             prefix_ready_us = (time.perf_counter() - t0) * 1e6
             verified = None
             if self.verify:
@@ -1384,12 +1508,14 @@ class ContinuousBatchingHarness:
             # prefix hit.
             if loaded_blocks < n_blocks:
                 await self._save_blocks(
-                    token_ids, prompt_table[loaded_blocks:], loaded_blocks
+                    token_ids, prompt_table[loaded_blocks:], loaded_blocks,
+                    before_first_token=bool(gen_tokens),
                 )
             generated = None
             ttft_us = 0.0
+            token_emit_s: List[float] = []
             if gen_tokens:
-                generated, first_token_t = await self._generate(
+                generated, first_token_t, token_emit_s = await self._generate(
                     token_ids, table, gen_tokens, priority=priority
                 )
                 if first_token_t is not None:
@@ -1428,6 +1554,8 @@ class ContinuousBatchingHarness:
                 prefix_ready_us=prefix_ready_us,
                 ttft_us=ttft_us,
                 priority=priority,
+                token_emit_s=token_emit_s,
+                trace_id=rspan.trace_id if rspan is not None else 0,
             )
             self.stats.append(stats)
             return stats

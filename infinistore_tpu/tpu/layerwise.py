@@ -22,7 +22,7 @@ from typing import Callable, List, Optional, Sequence, Tuple
 import jax
 import numpy as np
 
-from .. import wire
+from .. import tracing, wire
 from ..lib import (
     InfiniStoreException,
     InfiniStoreKeyNotFound,
@@ -737,6 +737,9 @@ class LayerwisePrefetch:
         bn = self.spec.block_nbytes
         dt = np.dtype(jax.numpy.dtype(self.spec.dtype))
         loop = asyncio.get_running_loop()
+        # The caller's span (the engine's `install`): the uploads and
+        # scatters below run in executor threads, which do not inherit it.
+        tspan = tracing.active_span()
         fused = (
             self.regions >= self.num_layers
             and self._region_stride == self._region_bytes
@@ -759,17 +762,20 @@ class LayerwisePrefetch:
             )
 
             def dev_all(caches_in):
-                kv_all = jax.device_put(host_all)
-                scattered = []
-                for layer in range(self.num_layers):
-                    base = layer * 2 * n
-                    k_cache, v_cache = caches_in[layer]
-                    scattered.append((
-                        scatter_blocks(k_cache, ids_dev, kv_all[base : base + n]),
-                        scatter_blocks(
-                            v_cache, ids_dev, kv_all[base + n : base + 2 * n]
-                        ),
-                    ))
+                with tracing.device_call("its.install", tspan):
+                    kv_all = jax.device_put(host_all)
+                    scattered = []
+                    for layer in range(self.num_layers):
+                        base = layer * 2 * n
+                        k_cache, v_cache = caches_in[layer]
+                        scattered.append((
+                            scatter_blocks(
+                                k_cache, ids_dev, kv_all[base : base + n]
+                            ),
+                            scatter_blocks(
+                                v_cache, ids_dev, kv_all[base + n : base + 2 * n]
+                            ),
+                        ))
                 return kv_all, scattered
 
             kv_all, scattered = await loop.run_in_executor(
@@ -830,12 +836,13 @@ class LayerwisePrefetch:
             )
 
             def dev_one(pair, kv_host=kv_host):
-                kv_dev = jax.device_put(kv_host)
-                k_cache, v_cache = pair
-                return kv_dev, (
-                    scatter_blocks(k_cache, ids_dev, kv_dev[:n]),
-                    scatter_blocks(v_cache, ids_dev, kv_dev[n:]),
-                )
+                with tracing.device_call("its.install", tspan):
+                    kv_dev = jax.device_put(kv_host)
+                    k_cache, v_cache = pair
+                    return kv_dev, (
+                        scatter_blocks(k_cache, ids_dev, kv_dev[:n]),
+                        scatter_blocks(v_cache, ids_dev, kv_dev[n:]),
+                    )
 
             # Off-loop for the same reason as the fused path: upload +
             # scatter must not freeze other requests' fetch completions.

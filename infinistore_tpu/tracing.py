@@ -41,10 +41,26 @@ schema and docs/observability.md to this tuple, in lockstep):
 - ``last_slice``      last payload/slice unit of server work      [native]
 - ``completion_ring`` completion drained from the native ring
 - ``install``         bytes installed into the engine's paged cache
+- ``wave_enqueue``    a generation round handed its chunk to the wave decoder
+- ``wave_result``     the wave's future handed the round its logits rows
+- ``token``           the round's sampled token(s) reached the host
+- ``taken``           a wave flush took its batch off the pending queue
+- ``assembled``       the wave's ragged metadata is built (host side)
+- ``gate``            the wave holds the exclusive device gate
+- ``dispatched``      the wave's jitted step returned (dispatch, not completion)
+- ``resolved``        every rider's future holds its rows
+
+The first ten are one op's path through the store; the last eight belong
+to the engine's own spans (``generate`` stamps three per round, ``wave``
+five per launch — see docs/observability.md for the span tree).
 
 Clocks: every stamp (Python and native) is CLOCK_MONOTONIC microseconds,
 so same-host client and server ticks share a timebase and merge into one
-timeline; across hosts only within-process deltas are meaningful.
+timeline; across hosts only within-process deltas are meaningful. A
+``jax.profiler`` trace stamps a clock of its own: :func:`profile_clock_mark`
+drops this clock's reading into the profile, and :func:`profile_clock_offset`
+turns two such marks into the offset that lays recorded spans over the
+device timeline.
 """
 
 import contextlib
@@ -72,6 +88,14 @@ STAGES = (
     "last_slice",
     "completion_ring",
     "install",
+    "wave_enqueue",
+    "wave_result",
+    "token",
+    "taken",
+    "assembled",
+    "gate",
+    "dispatched",
+    "resolved",
 )
 
 # Stages stamped by the NATIVE server reactor: stats_json()["trace"] tick
@@ -465,6 +489,87 @@ def trace_op(name: str, stage: Optional[str] = None):
         _current.reset(token)
         _notify_bind()
         span.finish()
+
+
+# ---------------------------------------------------------------------------
+# One clock with a jax.profiler trace.
+# ---------------------------------------------------------------------------
+
+CLOCK_MARK_PREFIX = "its.clock:"
+
+_NO_CALL = contextlib.nullcontext()
+
+
+def profile_clock_mark() -> int:
+    """Emit one (near) zero-length ``jax.profiler.TraceAnnotation`` named
+    ``its.clock:<monotonic_ns>`` and return the reading. Call it right
+    after ``start_trace`` and right before ``stop_trace``: the profile then
+    holds this module's clock at two of its own timestamps, which is all
+    :func:`profile_clock_offset` needs. JAX is imported HERE, not at module
+    level: the server process imports this module and stays JAX-free."""
+    import jax
+
+    now = time.monotonic_ns()
+    with jax.profiler.TraceAnnotation(f"{CLOCK_MARK_PREFIX}{now}"):
+        pass
+    return now
+
+
+def clock_mark_ns(event_name: str) -> Optional[int]:
+    """The CLOCK_MONOTONIC reading a profile event named by
+    :func:`profile_clock_mark` carries (None for any other event)."""
+    if not event_name.startswith(CLOCK_MARK_PREFIX):
+        return None
+    try:
+        return int(event_name[len(CLOCK_MARK_PREFIX):])
+    except ValueError:
+        return None
+
+
+def profile_clock_offset(marks) -> Optional[tuple]:
+    """``(offset_ns, drift_ns)`` from ``[(profile_start_ns, event_name),
+    ...]``, the clock-mark events found in a profile. ``profile_ns =
+    monotonic_ns + offset_ns`` (the mean over the marks); ``drift_ns`` is
+    how far the two clocks moved apart between the first and the last
+    mark (0 with one mark). None when the profile holds no mark."""
+    pairs = sorted(
+        (float(ts), mono) for ts, name in marks
+        if (mono := clock_mark_ns(name)) is not None
+    )
+    if not pairs:
+        return None
+    offsets = [ts - mono for ts, mono in pairs]
+    return sum(offsets) / len(offsets), offsets[-1] - offsets[0]
+
+
+def to_profile_ns(t_us: float, offset_ns: float) -> float:
+    """A span stamp (CLOCK_MONOTONIC microseconds) on the profile's
+    timeline, given :func:`profile_clock_offset`'s offset."""
+    return t_us * 1000.0 + offset_ns
+
+
+@contextlib.contextmanager
+def _device_call(name: str, span: Span):
+    import jax
+
+    t0 = _now_us()
+    with jax.profiler.TraceAnnotation(name):
+        yield
+    span.attrs.setdefault("device_calls", []).append([name, t0, _now_us()])
+
+
+def device_call(name: str, span: Optional[Span]):
+    """Bracket one SYNCHRONOUS call into the device, recorded both ways
+    when ``span`` is live: as a real ``jax.profiler.TraceAnnotation`` (what
+    an operator sees in a profile taken with any tool) and as a ``[name,
+    t0_us, t1_us]`` entry of ``span.attrs["device_calls"]`` (the same
+    region on this module's clock — the pair that checks the clock
+    offset). Never wrap an ``await``: the annotation belongs to the thread
+    that entered it. With ``span`` None (tracing off) this returns a
+    shared no-op context and builds nothing."""
+    if span is None:
+        return _NO_CALL
+    return _device_call(name, span)
 
 
 def wire_ids(span: Optional[Span]):

@@ -359,10 +359,13 @@ def test_engine_hit_admission_not_slower_than_miss(conn):
             0, big.vocab, size=MAX_REQ_BLOCKS * big.block_tokens
         ).tolist()
 
+    pairs = 12
+
     async def drive():
-        seeds = [prompt(100 + i) for i in range(6)]
+        seeds = [prompt(100 + i) for i in range(pairs)]
         for p in seeds:
-            await h.run_request(p)  # seed + warm the jit caches
+            await h.run_request(p)  # seed + warm the prefill's jit cache
+        await h.run_request(seeds[0])  # warm the hit path too (the install's programs)
         h.stats.clear()
         for i, p in enumerate(seeds):
             await h.run_request(p)  # hit
@@ -371,9 +374,23 @@ def test_engine_hit_admission_not_slower_than_miss(conn):
 
     m = asyncio.run(drive())
     assert m["hit_rate"] > 0
-    hit, miss = m["p50_prefix_ready_hit_us"], m["p50_prefix_ready_miss_us"]
-    assert hit <= miss, (
-        f"prefix hit ({hit:.0f}us) slower than recompute ({miss:.0f}us)"
+    # Judged pair by pair: hit i and miss i run back to back, so whatever
+    # else loads this host (the suite runs six workers wide) loads both,
+    # where median against median of six samples a side compared moments
+    # that were far apart. The median of the twelve ratios is the typical
+    # hit against its own neighbour: ~0.6 on a quiet host, and a
+    # regression that slows the typical hit (store I/O back under the
+    # gate, gate contention on most admissions) moves it, which a
+    # fastest-few statistic would not see.
+    hits = [s.prefix_ready_us for s in h.stats[0::2]]
+    misses = [s.prefix_ready_us for s in h.stats[1::2]]
+    assert all(s.loaded_blocks for s in h.stats[0::2])
+    assert not any(s.loaded_blocks for s in h.stats[1::2])
+    ratios = sorted(hit / miss for hit, miss in zip(hits, misses))
+    median = (ratios[pairs // 2 - 1] + ratios[pairs // 2]) / 2
+    assert median <= 1.0, (
+        f"the typical prefix hit is {median:.2f}x its neighbouring recompute: "
+        f"hits {[round(x) for x in hits]}us, misses {[round(x) for x in misses]}us"
     )
 
 
