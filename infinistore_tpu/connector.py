@@ -463,7 +463,9 @@ class KVConnector:
 
         Saves are BACKGROUND class by default (docs/qos.md): a prefill save
         is never decode-blocking, so its store puts yield to concurrent
-        foreground reads in every queue they cross. Pass
+        foreground reads in every queue they cross (true of the engine's
+        own request too since PR 25: ``run_request`` runs this write
+        beside the request's generation, not ahead of its first token). Pass
         ``priority=wire.PRIORITY_FOREGROUND`` to opt a save out (e.g. a
         handoff the consumer is already waiting on).
 

@@ -923,6 +923,13 @@ class RequestStats:
     # The request's trace (tracing.py): every span it recorded carries
     # this id. 0 with tracing off.
     trace_id: int = 0
+    # The prompt save's store write runs beside the request's generation
+    # (run_request). save_overlap_us: how long it ran while the request
+    # generated. save_tail_us: how long the request, its last token out,
+    # still waited for the write's acknowledgement (0.0 when it had
+    # arrived). Both 0.0 where nothing was computed or nothing generated.
+    save_overlap_us: float = 0.0
+    save_tail_us: float = 0.0
 
 
 class ContinuousBatchingHarness:
@@ -998,6 +1005,10 @@ class ContinuousBatchingHarness:
         self.max_live = 0
         self._saving = 0
         self.max_concurrent_saves = 0
+        # Prompt saves whose store write ran as a task beside the request's
+        # own `_generate` (run_request): every request that computed
+        # blocks and generates.
+        self.saves_overlapped = 0
         # Admissions that wanted a prefetch but found the staging arena
         # full and fell back to the one-phase gated load (backpressure).
         self.prefetch_fallbacks = 0
@@ -1116,23 +1127,16 @@ class ContinuousBatchingHarness:
             self.max_req_blocks,
         )
 
-    async def _save_blocks(
-        self, chain_ids, phys_blocks, first_block: int,
-        before_first_token: bool = False,
-    ):
-        """Snapshot the given physical blocks into private arrays under the
-        shared gate (device-side gathers, microseconds), then stream them to
-        the store with NO gate held: the save — the long store-I/O phase —
-        overlaps other requests' loads, computes, and saves. Holding the
-        gate across the save would serialize the whole pipeline (the next
-        request's exclusive load waits on it). ``chain_ids`` key the blocks
-        (the prompt, or prompt + generated for response blocks).
+    async def _snapshot_blocks(self, phys_blocks, before_first_token: bool = False):
+        """A save's first phase: gather the given physical blocks into
+        PRIVATE arrays under the shared gate (device-side gathers) and wait
+        until they are ready. What comes back no later wave, install or
+        prefill can change, so the write may run beside any of them.
 
-        Two spans, both with ``blocks`` and ``before_first_token`` (true
-        for the prompt's save, which the first token waits for):
-        ``save_snapshot`` — the shared gate's wait (its ``gate_wait``
-        child), the executor hop, the gathers and their readiness wait —
-        and ``save_io``, the whole ``adapter.save_kv`` await."""
+        One ``save_snapshot`` span with ``blocks`` and
+        ``before_first_token`` (true for the prompt's snapshot, which the
+        first token waits for): the shared gate's wait (its ``gate_wait``
+        child), the executor hop, the gathers and their readiness wait."""
         dev = jnp.asarray(np.asarray(phys_blocks))
         with tracing.trace_op("save_snapshot") as sspan:
             if sspan is not None:
@@ -1154,9 +1158,22 @@ class ContinuousBatchingHarness:
                 # Executor: the gathers + readiness wait must not pin the
                 # event loop (it is the artery every gate-free fetch
                 # completion and wave flush flows through).
-                snapshot = await asyncio.get_running_loop().run_in_executor(
-                    None, snap
-                )
+                return await asyncio.get_running_loop().run_in_executor(None, snap)
+
+    async def _write_snapshot(
+        self, chain_ids, snapshot, first_block: int, overlaps_generate: bool = False
+    ):
+        """A save's second phase: stream a snapshot's blocks to the store
+        with NO gate held, keyed by ``chain_ids`` from logical block
+        ``first_block`` on. Returns the perf_counter readings of its start
+        and its end (the store's acknowledgement).
+
+        One ``save_io`` span, the whole ``adapter.save_kv`` await, with
+        ``blocks``, ``before_first_token`` (false since PR 25: no first
+        token waits for a store write) and ``overlaps_generate`` (true
+        where ``run_request`` ran it as a task beside ``_generate``)."""
+        n = len(snapshot[0][0])
+        t_start = time.perf_counter()
         self._saving += 1
         self.max_concurrent_saves = max(self.max_concurrent_saves, self._saving)
         try:
@@ -1164,18 +1181,37 @@ class ContinuousBatchingHarness:
                 await self.adapter.save_kv(
                     chain_ids,
                     snapshot,
-                    np.arange(len(phys_blocks), dtype=np.int32),
+                    np.arange(n, dtype=np.int32),
                     first_block=first_block,
                 )
                 # Last: the store's write ops annotate the span they run
                 # under too (`op`, and one op's `blocks`).
                 if iospan is not None:
                     iospan.annotate(
-                        blocks=len(phys_blocks),
-                        before_first_token=before_first_token,
+                        blocks=n,
+                        before_first_token=False,
+                        overlaps_generate=overlaps_generate,
                     )
         finally:
             self._saving -= 1
+        return t_start, time.perf_counter()
+
+    async def _save_blocks(
+        self, chain_ids, phys_blocks, first_block: int,
+        before_first_token: bool = False,
+    ):
+        """Snapshot the given physical blocks (``_snapshot_blocks``: under
+        the shared gate), then stream them to the store with NO gate held
+        (``_write_snapshot``): the save — the long store-I/O phase —
+        overlaps other requests' loads, computes, and saves. Holding the
+        gate across the save would serialize the whole pipeline (the next
+        request's exclusive load waits on it). ``chain_ids`` key the blocks
+        (the prompt, or prompt + generated for response blocks). Both
+        phases in turn, awaited: the caller has its acknowledgement when
+        this returns. ``run_request`` runs the two apart for a prompt that
+        generates (its docstring)."""
+        snapshot = await self._snapshot_blocks(phys_blocks, before_first_token)
+        await self._write_snapshot(chain_ids, snapshot, first_block)
 
     async def _generate(
         self, token_ids, table: np.ndarray, gen_tokens: int, priority: int = 0
@@ -1289,7 +1325,25 @@ class ContinuousBatchingHarness:
         gen_tokens: int = 0,
         priority: int = 0,
     ) -> RequestStats:
-        """``priority``: the request's QoS class (wire.PRIORITY_*).
+        """One request, from admission to its acknowledgement: prefix
+        fetch and install, compute of what the store did not hold, the
+        save of what was computed, ``gen_tokens`` of generation, the save
+        of the complete blocks the answer filled.
+
+        The prompt's save is two phases (``_save_blocks``). Its snapshot is
+        awaited before the first wave, which rewrites the last prompt
+        block. Its store write waits for nothing the generation needs, so
+        with ``gen_tokens > 0`` it runs as a task beside ``_generate``
+        (since PR 25; no first token waits for a store write) and is
+        joined after the last token, before the answer's save starts.
+        Returning is the acknowledgement: every computed prompt block and
+        every complete answer block has been written by then, the blocks
+        are back in the pool and the stats are appended. A failed write
+        raises from here; if the generation raises or the task is
+        cancelled, the write is cancelled and awaited first. With
+        ``gen_tokens == 0`` the save is awaited in line.
+
+        ``priority``: the request's QoS class (wire.PRIORITY_*).
         BACKGROUND requests tag their speculative store prefetch
         background and tolerate a longer wave-deferral age under the
         skew-aware flush policy (docs/serving_load.md); the class is
@@ -1328,6 +1382,7 @@ class ContinuousBatchingHarness:
         prefetch_settled = True  # nothing to discard until a fetch starts
         fallback_hit: Optional[int] = None  # probe answer from a failed start_fetch
         table = None
+        prompt_write: Optional[asyncio.Future] = None  # the prompt save's store write
         # One try for the whole admission (the speculative starter INCLUDED):
         # a probe that dies on a dead store must still release the live
         # count, unbind the trace context, and finish the request span —
@@ -1505,14 +1560,30 @@ class ContinuousBatchingHarness:
                     verified = self._verify_request(token_ids, prompt_table)
             # Save ONLY the computed suffix — the loaded prefix came from the
             # store and re-writing it would double write traffic for every
-            # prefix hit.
+            # prefix hit. The snapshot comes BEFORE the first wave: the
+            # first round re-decodes the last prompt token into the last
+            # prompt block, and what is saved must stay what the prefill
+            # or the resume wrote. The write has no such reason to come
+            # first: with tokens to generate it runs as a task of its own
+            # beside `_generate` (which stays in THIS task) and is joined
+            # below.
             if loaded_blocks < n_blocks:
-                await self._save_blocks(
-                    token_ids, prompt_table[loaded_blocks:], loaded_blocks,
-                    before_first_token=bool(gen_tokens),
-                )
+                computed = prompt_table[loaded_blocks:]
+                if gen_tokens:
+                    snapshot = await self._snapshot_blocks(
+                        computed, before_first_token=True
+                    )
+                    prompt_write = asyncio.ensure_future(
+                        self._write_snapshot(
+                            token_ids, snapshot, loaded_blocks, overlaps_generate=True
+                        )
+                    )
+                    del snapshot  # the write's alone: HBM it frees when acknowledged
+                    self.saves_overlapped += 1
+                else:
+                    await self._save_blocks(token_ids, computed, loaded_blocks)
             generated = None
-            ttft_us = 0.0
+            ttft_us = save_overlap_us = save_tail_us = 0.0
             token_emit_s: List[float] = []
             if gen_tokens:
                 generated, first_token_t, token_emit_s = await self._generate(
@@ -1520,6 +1591,14 @@ class ContinuousBatchingHarness:
                 )
                 if first_token_t is not None:
                     ttft_us = (first_token_t - t0) * 1e6
+                if prompt_write is not None:
+                    # Join: the prompt's blocks commit before the blocks
+                    # whose chain extends them, and a failed write fails
+                    # the request here.
+                    t_generated = time.perf_counter()
+                    write_start, write_end = await prompt_write
+                    save_overlap_us = (min(write_end, t_generated) - write_start) * 1e6
+                    save_tail_us = max(write_end - t_generated, 0.0) * 1e6
                 # Save the COMPLETE blocks the response filled, keyed by the
                 # extended chain (prompt + generated): a follow-up turn whose
                 # prompt is this conversation so far gets a full prefix hit
@@ -1556,6 +1635,8 @@ class ContinuousBatchingHarness:
                 priority=priority,
                 token_emit_s=token_emit_s,
                 trace_id=rspan.trace_id if rspan is not None else 0,
+                save_overlap_us=save_overlap_us,
+                save_tail_us=save_tail_us,
             )
             self.stats.append(stats)
             return stats
@@ -1564,6 +1645,17 @@ class ContinuousBatchingHarness:
             # reports a CALLER's already-being-handled exception during a
             # normal return (a retry inside an except block would record a
             # successful request as failed).
+            if prompt_write is not None:
+                # `_generate` raised, or this task was cancelled, with the
+                # prompt's write still running: cancel it AND wait for it
+                # (the writer's `finally` drains the puts in flight from
+                # registered host buffers) before the span closes and the
+                # blocks go back to the pool. The error on its way out is
+                # the request's; a write that failed too has been seen.
+                prompt_write.cancel()
+                await asyncio.wait([prompt_write])
+                if not prompt_write.cancelled():
+                    prompt_write.exception()
             if rspan is not None:
                 rspan.finish(status=f"error:{type(e).__name__}")
             raise
@@ -1624,7 +1716,9 @@ class ContinuousBatchingHarness:
         end-to-end prefix residency (``p50_prefix_ready_hit_us``,
         ``p50_prefix_ready_miss_us``); the recompute ledger
         (``recompute_saved_s``, ``prefill_per_block_s``); concurrency
-        receipts (``max_live_requests``, ``max_concurrent_saves``); the
+        receipts (``max_live_requests``, ``max_concurrent_saves``,
+        ``saves_overlapped`` — prompt saves whose store write ran beside
+        the request's own generation); the
         ragged wave-decode story (``decode_waves``, ``max_wave_size``,
         ``wave_buckets`` — distinct padded (B, T, P) jit buckets —
         ``wave_prewarmed_buckets`` — the canonical ladder
@@ -1723,6 +1817,7 @@ class ContinuousBatchingHarness:
             "prefill_per_block_s": per_block,
             "max_live_requests": self.max_live,
             "max_concurrent_saves": self.max_concurrent_saves,
+            "saves_overlapped": self.saves_overlapped,
             "decode_waves": self.wave.waves,
             "max_wave_size": self.wave.max_wave,
             # Distinct PADDED (B, T, P) buckets == jit cache entries for

@@ -109,9 +109,14 @@ def test_request_span_tree(conn, params, traced, which):
         (io,) = [s for s in spans if s["name"] == "save_io"]
         # The store's write ops stamp and annotate the span they run under
         # (one op's `blocks` among them); the save's own attrs are set last.
-        assert io["attrs"]["blocks"] == 3 and io["attrs"]["before_first_token"] is True
+        assert io["attrs"]["blocks"] == 3 and io["attrs"]["before_first_token"] is False
+        assert io["attrs"]["overlaps_generate"] is True
         assert io["attrs"]["op"] == "write_cache" and "submit" in [n for n, _ in io["stages"]]
-        assert io["start_us"] >= snaps[0]["end_us"] and gen["start_us"] >= io["end_us"]
+        # The snapshot precedes the first wave; the write runs beside the
+        # generation, a child of the request like it.
+        assert io["start_us"] >= snaps[0]["end_us"] and gen["start_us"] >= snaps[0]["end_us"]
+        assert gen["start_us"] < io["end_us"] and io["parent_id"] == root["span_id"]
+        assert miss.save_overlap_us > 0 and hit.save_overlap_us == hit.save_tail_us == 0.0
     else:
         (inst,) = [s for s in spans if s["name"] == "install"]
         assert inst["attrs"]["blocks"] == 3
@@ -136,6 +141,24 @@ def test_request_span_tree(conn, params, traced, which):
         s for s in traced.snapshot() if s["name"] == "gate_wait" and s["parent_id"] in wave_ids
     ]
     assert len(wave_waits) == len(waves)  # the wave's wait is its own child, not a request's
+
+
+def test_prompt_write_ends_before_the_answer_save_starts(conn, params, traced):
+    h = _harness(conn, params, f"spans-answer-{conn.shm_active}")
+    gen = CFG.block_tokens  # the answer fills one block: a second save
+    stats = asyncio.run(asyncio.wait_for(h.run_request(_prompt(3), gen_tokens=gen), 60))
+    spans = [s for s in traced.snapshot() if s["trace_id"] == stats.trace_id]
+    prompt_snap, answer_snap = [s for s in spans if s["name"] == "save_snapshot"]
+    prompt_io, answer_io = [s for s in spans if s["name"] == "save_io"]
+    (generate,) = [s for s in spans if s["name"] == "generate"]
+    assert [s["attrs"]["before_first_token"] for s in (prompt_snap, answer_snap)] == [True, False]
+    assert [s["attrs"]["blocks"] for s in (prompt_io, answer_io)] == [3, 1]
+    assert [s["attrs"]["overlaps_generate"] for s in (prompt_io, answer_io)] == [True, False]
+    assert not prompt_io["attrs"]["before_first_token"] and not answer_io["attrs"]["before_first_token"]
+    assert prompt_snap["end_us"] <= generate["start_us"] < prompt_io["end_us"]
+    assert prompt_io["end_us"] <= answer_snap["start_us"] and generate["end_us"] <= answer_snap["start_us"]
+    assert answer_snap["end_us"] <= answer_io["start_us"]
+    assert h.metrics()["saves_overlapped"] == 1
 
 
 def test_tracing_off_records_nothing_and_still_stamps_emits(conn, params, monkeypatch):
