@@ -1,12 +1,46 @@
 """Operations and bytes a kernel's algorithm needs, from its shapes alone.
 
 Kept with the benchmark so that no later PR can move a roofline share by
-recounting. Every function returns what ONE call on one layer needs; the
-caller multiplies by layers and calls. Only useful work counts: pages that
-are padding, rows that repeat the last row and masked blocks above the
-causal diagonal are not work the algorithm needs, so a share computed from
-these can only read low, never above 100%.
+recounting. Every kernel function returns what ONE call on one layer needs.
+Only useful work counts: pages that are padding, rows that repeat the last
+row and masked blocks above the causal diagonal are not work the algorithm
+needs, so a share computed from these can only read low, never above 100%.
+
+A configuration names its cost module (``program.costs``; this one serves
+the Llama-shaped ones). What the harness asks of such a module:
+
+``WORK_KEYS``                       the keys of ``trace["work"]`` it fills
+``wave_work(config, pages, rows)``  one request's entry into a decode wave:
+                                    ``rows`` query rows over ``pages`` real
+                                    context pages
+``prefill_work(config, tokens)``    one whole-prompt prefill
+
+Each returns ``{key: amount}`` for ONE such call as a total over all layers,
+given the configuration's file, so a model whose layers differ states its
+own sum. The harness adds them up over the traced calls and knows no key.
 """
+
+from typing import Dict
+
+ITEMSIZE = {"bfloat16": 2, "float16": 2, "float32": 4}
+WORK_KEYS = ("ragged_decode_bytes", "flash_prefill_flops")
+
+
+def wave_work(config: Dict, pages: int, rows: int) -> Dict[str, int]:
+    return {
+        "ragged_decode_bytes": config["num_hidden_layers"] * ragged_decode_bytes(
+            pages, rows, config["serving"]["block_tokens"], config["num_attention_heads"],
+            config["num_key_value_heads"], config["head_dim"], ITEMSIZE[config["torch_dtype"]],
+        )
+    }
+
+
+def prefill_work(config: Dict, tokens: int) -> Dict[str, int]:
+    return {
+        "flash_prefill_flops": config["num_hidden_layers"] * flash_prefill_flops(
+            tokens, config["num_attention_heads"], config["head_dim"]
+        )
+    }
 
 
 def ragged_decode_bytes(

@@ -10,19 +10,26 @@ What a reader is given (``Run``):
 
 ``rows``      one dict per request that STARTED inside the window (sent, or
               due), with the fields listed in ``run.py`` ``request_row``
-``counters``  window deltas of the program's own counters and the
-              benchmark's (waves, real_rows, compiles, ...)
+``counters``  window deltas: the benchmark's own (``run.py``
+              ``OWN_COUNTERS``) and whatever key of the program's
+              ``harness.metrics()`` or of the connector's ``get_stats()``
+              (dotted where nested: ``spill.dropped``) a ``counter`` reader
+              of the cell names: the harness snapshots exactly those at
+              window open and close (``counter_keys``)
 ``trace``     ``trace_reduce.reduce`` of the traced seconds, with
               ``trace["work"]``: the useful operations and bytes the
               traced calls needed, by cost-function name (``costs.py``)
 ``peaks``     the row of ``peaks.json`` for this device
+``spans``     with the program's recorder on (``--trace 1``): what it held
+              when the run ended, and where the profile puts it
+              (``span_readers.py``, whose two kinds are registered here)
 """
 
 import dataclasses
 import json
 import math
 import os
-from typing import Dict, List, Optional
+from typing import Dict, Iterable, List, Optional, Set
 
 import trace_reduce
 
@@ -35,6 +42,7 @@ class Run:
     counters: Dict[str, float]
     trace: Optional[Dict]
     peaks: Dict
+    spans: Optional[Dict] = None
 
 
 def percentile(values: List[float], q: float) -> float:
@@ -176,3 +184,12 @@ def read_layer_metric(name: str, run: Run) -> Optional[float]:
     spec = load_layer_metric(name)
     reader = spec["reader"]
     return KINDS[reader["kind"]](run, reader)
+
+
+def counter_keys(names: Iterable[str]) -> Set[str]:
+    """Every counter those metrics' files name (``key`` and ``per``)."""
+    readers = [load_layer_metric(name)["reader"] for name in names]
+    return {r[k] for r in readers if r["kind"] == "counter" for k in ("key", "per") if k in r}
+
+
+import span_readers  # noqa: E402,F401 - adds its kinds to KINDS; it imports this module

@@ -13,7 +13,13 @@ The engine is driven through ``ContinuousBatchingHarness.run_request`` over
 ``EngineKVAdapter`` -> ``KVConnector`` -> the real server. Everything that
 belongs to one configuration, traffic mix or per-layer metric is a data
 file found by the name in ``BENCHMARK.json`` (``configs/``, ``traffic/``,
-``layer_metrics/``); nothing here names a cell.
+``layer_metrics/``); nothing here names a cell, a model file or the shape of
+a cache. What a block of the cache weighs is read off the caches the program
+built (``cache_geometry.py``), the useful work of a traced call comes from
+the module the configuration names (``program.costs``), and a counter of the
+program's is read by the name a metric file gives it. With ``--trace 1`` the
+program's own recorder is on and its spans are laid over the profile
+(``span_readers.py``); with ``--trace 0`` it stays off.
 
 How a token is timed without editing the program: the benchmark wraps
 ``harness.wave.step_chunk`` on its own harness instance and records, per
@@ -45,10 +51,12 @@ REPO = os.path.dirname(HERE)
 sys.path.insert(0, HERE)
 sys.path.insert(0, REPO)
 
-import costs  # noqa: E402
 import readers  # noqa: E402
+import span_readers  # noqa: E402
 import trace_reduce  # noqa: E402
 import traffic  # noqa: E402
+from cache_geometry import CacheGeometry  # noqa: E402
+from infinistore_tpu import tracing  # noqa: E402 - the system under test and its recorder
 
 # Logits against the float32 reference, as multiples of the reference
 # logits' rms over the compared rows. bfloat16 keeps 8 significant bits
@@ -64,6 +72,15 @@ LOGITS_MAX_TOL = 0.15
 DECODE_STEPS_CHECKED = 8
 TRACE_SECONDS = 8.0  # the traced run profiles this long, mid-window
 DOC_BASE_WARM, DOC_BASE_CHECK = 10_000_000, 20_000_000
+# The recorder's ring in a traced run: ~15 spans a request and its store
+# ops, two a wave; a dropped span makes the run not correct.
+SPAN_CAPACITY = 1 << 18
+# Counters the benchmark keeps itself (``CellRun.results``). Any other key a
+# ``counter`` reader names is the program's: ``harness.metrics()`` or the
+# connector's ``get_stats()``.
+OWN_COUNTERS = frozenset({
+    "waves", "real_rows", "window_compiles", "store_evictions", "peak_hbm_bytes", "tpot_mean_ms",
+})
 
 
 def fail(msg: str, code: int = 1):
@@ -167,7 +184,7 @@ class Record:
     t_dispatch: float  # when the generator handed it over (open loop: due + lateness)
     t_sent: float  # when it entered the harness (open loop: after a live slot freed)
     stamps: List[float] = dataclasses.field(default_factory=list)  # step_chunk entries
-    pages: List[int] = dataclasses.field(default_factory=list)  # context pages per entry
+    calls: List[tuple] = dataclasses.field(default_factory=list)  # (context pages, rows) per entry
     alloc_waited: bool = False
     stats: Optional[object] = None  # RequestStats
     error: Optional[str] = None
@@ -207,7 +224,7 @@ class Instruments:
         rec = self.by_task.get(asyncio.current_task())
         if rec is not None:
             rec.stamps.append(time.perf_counter())
-            rec.pages.append(sum(-(-(p + 1) // self.bt) for p in positions))
+            rec.calls.append((sum(-(-(p + 1) // self.bt) for p in positions), len(tokens)))
         rows = await self._step_chunk(tokens, positions, padded_table, priority=priority)
         if rec is not None and self.keep_logits:
             rec.logits.append(rows)
@@ -254,8 +271,10 @@ class Compiles:
 
 
 class CellRun:
-    def __init__(self, args, cell, config, plan):
+    def __init__(self, args, cell, config, plan, program_counters=()):
         self.args, self.cell, self.config, self.plan = args, cell, config, plan
+        # Keys of the program's own counters that this cell's metrics read.
+        self.program_counters = sorted(program_counters)
         self.records: List[Record] = []
         self.failed_checks: List[str] = []
 
@@ -273,9 +292,14 @@ class CellRun:
         self.cfg = resolve(prog["config_class"])(
             block_tokens=serving["block_tokens"], dtype=jnp.bfloat16, **fields
         )
-        if self.cfg.head_dim != self.config["head_dim"]:
-            raise ValueError("head_dim of the program's config differs from the file's")
+        for attr, key in prog.get("equals", {}).items():
+            if getattr(self.cfg, attr) != self.config[key]:
+                raise ValueError(
+                    f"{attr} of the program's config is {getattr(self.cfg, attr)!r}, "
+                    f"{key} of the file {self.config[key]!r}"
+                )
         self.reference = importlib.import_module(prog["reference"])
+        self.costs = importlib.import_module(prog["costs"])
         init = resolve(prog["init_params"])
         # One jitted call from the seed, on the device, in the served type.
         # The rbg generator: XLA:TPU takes about a minute to compile
@@ -289,9 +313,9 @@ class CellRun:
         self.max_req_blocks = max(
             -(-(r.prompt_tokens + r.answer_tokens) // bt) for r in self.plan.requests
         )
-        self.spec = self.cfg.kv_spec(self.num_blocks)
         connector = KVConnector(
-            conn, self.spec, self.config["name"], max_blocks=self.max_req_blocks
+            conn, self.cfg.kv_spec(self.num_blocks), self.config["name"],
+            max_blocks=self.max_req_blocks,
         )
 
         bt_, keep_host_copy = bt, self.keep_host_copy
@@ -304,7 +328,7 @@ class CellRun:
             def __init__(self, connector):
                 super().__init__(connector)
                 self.chains_saved = set()
-                self.saved: Dict[str, list] = {}  # chain hash -> per-layer (K, V) bytes
+                self.saved: Dict[str, list] = {}  # chain hash -> per layer, its tensors' bytes
                 self.keep = False
 
             async def save_kv(self, token_ids, caches, block_table, first_block=0):
@@ -320,17 +344,23 @@ class CellRun:
         self.h = ContinuousBatchingHarness(
             self.adapter, self.params, self.cfg, self.num_blocks, self.max_req_blocks
         )
+        # The cache as the program built it: what a block weighs and how
+        # many values it puts in the store. The file's serving numbers, which
+        # sized the server before anything was built, must agree.
+        self.geometry = CacheGeometry.of(self.h.caches)
+        self.geometry.check(serving)
         self.taps = Instruments(self.h, bt)
         self.compiles = compiles
         self.conn = conn
+        self.read_program_counters()  # a key that is nowhere stops the run here
 
     @staticmethod
     def keep_host_copy(saved, chains, caches, block_table):
         import numpy as np
 
-        host = [(np.asarray(k), np.asarray(v)) for k, v in caches]
+        host = [[np.asarray(t) for t in layer] for layer in caches]
         for chain, blk in zip(chains, np.asarray(block_table)):
-            saved[chain] = [(k[blk].tobytes(), v[blk].tobytes()) for k, v in host]
+            saved[chain] = [[t[blk].tobytes() for t in layer] for layer in host]
 
     def wave_buckets(self) -> List[tuple]:
         """Every (rows, pages) bucket a wave of this traffic can land on.
@@ -351,34 +381,33 @@ class CellRun:
         return out
 
     async def warm_waves(self):
-        """One throwaway wave per bucket: the real ``verify_step_ragged``
-        program on zero tokens at position 0 (the scatter rides block 0
-        slot 0, which no request owns yet), and the row slices and argmax
-        ``_generate`` takes of its logits."""
+        """One throwaway wave per bucket, launched by the decoder itself:
+        ``rows`` concurrent ``step_chunk`` calls of one token each, their
+        positions chosen so that the pages add up into the bucket, over a
+        table of block 0 alone (the scatter rides block 0, which no request
+        owns yet), and the argmax ``_generate`` takes of each row. No model
+        file is named: whatever the engine launches is what gets warmed.
+        These calls come from tasks of their own, so no record is stamped."""
         import jax.numpy as jnp
         import numpy as np
 
-        from infinistore_tpu.models.llama import verify_step_ragged
-        from infinistore_tpu.tpu.paged_attention import build_ragged_wave
-
-        mrb = self.max_req_blocks
+        bt, mrb = self.cfg.block_tokens, self.max_req_blocks
+        table = np.zeros(mrb, np.int32)
         for rows, pages in self.wave_buckets():
-            meta = build_ragged_wave(
-                [np.zeros(mrb, np.int32)] * rows, [1] * rows, self.cfg.block_tokens,
-                pad_to=pages,
+            total = min(pages, rows * mrb)
+            share = [total // rows + (i < total % rows) for i in range(rows)]
+            waves = self.h.wave.waves
+            got = await asyncio.gather(
+                *(self.h.wave.step_chunk([0], [n * bt - 1], table) for n in share)
             )
-            # From Python lists, as the decoder converts them: that small
-            # conversion is a program per length too.
-            zeros = [jnp.asarray([0] * rows, jnp.int32) for _ in range(3)]
-            async with self.h.gate.exclusive():
-                logits, self.h.caches = verify_step_ragged(
-                    self.params, *zeros, jnp.asarray(meta.pages),
-                    jnp.asarray(meta.page_rows), jnp.asarray(meta.page_starts),
-                    self.h.caches, jnp.asarray(np.zeros((rows, mrb), np.int32)),
-                    self.cfg, mrb,
+            for logits in got:
+                np.asarray(jnp.argmax(logits, axis=-1))
+            if self.h.wave.waves != waves + 1 or (rows, rows, pages) not in self.h.wave.bucket_sizes:
+                raise RuntimeError(
+                    f"warm-up could not pin the wave bucket ({rows} rows, {pages} pages): the "
+                    f"decoder launched {self.h.wave.waves - waves} wave(s), its buckets are "
+                    f"{sorted(self.h.wave.bucket_sizes)}"
                 )
-            for off in range(rows):
-                np.asarray(jnp.argmax(logits[off : off + 1], axis=-1))
 
     def prompt_classes(self) -> List[traffic.Request]:
         """The first request of each (prefix, own tokens) shape in the plan."""
@@ -513,7 +542,30 @@ class CellRun:
         return {
             "waves": w.waves, "real_rows": w.launched_rows - w.pad_rows,
             "compiles": self.compiles.count, "t": time.perf_counter(),
+            **self.read_program_counters(),
         }
+
+    def read_program_counters(self) -> Dict[str, float]:
+        """The program's counters this cell's metrics name, as they stand:
+        a key of ``harness.metrics()``, else of the connector's
+        ``get_stats()`` (dotted where nested). No call where none is named."""
+        if not self.program_counters:
+            return {}
+        metrics, stats = self.h.metrics(), self.adapter.connector.get_stats()
+        out = {}
+        for key in self.program_counters:
+            value = metrics.get(key)
+            if value is None:
+                value = stats
+                for part in key.split("."):
+                    value = value.get(part) if isinstance(value, dict) else None
+            if isinstance(value, bool) or not isinstance(value, (int, float)):
+                raise ValueError(
+                    f"counter {key!r} is no number in harness.metrics() or the connector's "
+                    f"get_stats(): a metric file of {self.cell['name']} names it"
+                )
+            out[key] = value
+        return out
 
     def trace_start(self):
         import jax
@@ -523,6 +575,7 @@ class CellRun:
         options.python_tracer_level = 0
         options.host_tracer_level = 2
         jax.profiler.start_trace(self.trace_dir, profiler_options=options)
+        tracing.profile_clock_mark()
         self.trace_t0 = time.perf_counter()
         asyncio.get_running_loop().call_later(TRACE_SECONDS, self.trace_stop)
 
@@ -531,6 +584,7 @@ class CellRun:
         seconds, and on the event loop that would stall every request."""
         import jax
 
+        tracing.profile_clock_mark()
         self.trace_t1 = time.perf_counter()
         self.trace_written = asyncio.get_running_loop().run_in_executor(
             None, jax.profiler.stop_trace
@@ -607,8 +661,7 @@ class CellRun:
                 n = loaded // bt
                 ids = jnp.asarray(np.asarray(block_table[:n]), jnp.int32)
                 held["blocks"] = [
-                    (np.asarray(gather_blocks(k, ids)), np.asarray(gather_blocks(v, ids)))
-                    for k, v in out
+                    [np.asarray(gather_blocks(t, ids)) for t in layer] for layer in out
                 ]
                 return out, loaded
 
@@ -626,9 +679,9 @@ class CellRun:
             )
             chains = token_chain_hashes(tokens, bt)[:n]
             same = "blocks" in held and len(held["blocks"][0][0]) == n and all(
-                held["blocks"][layer][kind][i].tobytes() == self.adapter.saved[c][layer][kind]
-                for layer in range(self.cfg.n_layers)
-                for kind in (0, 1)
+                tensor[i].tobytes() == self.adapter.saved[c][layer][kind]
+                for layer, tensors in enumerate(held["blocks"])
+                for kind, tensor in enumerate(tensors)
                 for i, c in enumerate(chains)
             )
             self.expect(same, f"{label}: installed blocks are not the bytes that were saved")
@@ -696,7 +749,6 @@ class CellRun:
             "hit": False,
         }
         if s is not None:
-            per_block = 2 * self.cfg.n_layers * self.spec.block_nbytes
             row.update(
                 hit=s.loaded_blocks > 0,
                 loaded_blocks=s.loaded_blocks,
@@ -708,8 +760,11 @@ class CellRun:
                 ),
                 gate_hold_s=s.gate_hold_us / 1e6,
                 fetch_s=s.fetch_us / 1e6,
-                installed_bytes=s.loaded_blocks * per_block,
-                fetched_bytes=s.prefetched_blocks * self.spec.block_nbytes,
+                installed_bytes=s.loaded_blocks * self.geometry.block_nbytes,
+                fetched_bytes=s.prefetched_blocks * self.geometry.mean_value_nbytes,
+                # For the span readers: the request's trace, and the program's
+                # emit stamps beside the benchmark's.
+                trace_id=s.trace_id, emit_s=list(s.token_emit_s), bench_emit_s=emits,
             )
         return row
 
@@ -724,69 +779,89 @@ class CellRun:
         ]
         e2e = readers.end_to_end(rows, in_window, gaps, t1 - t0, setup_s)
         # What the run wrote against what the server holds: every block
-        # handed to a save is one key for K and one for V in every layer.
+        # handed to a save is one key per tensor of every layer.
         written = len(self.adapter.chains_saved)
         held = self.conn.get_stats()["kvmap_len"]
+
+        def delta(key):
+            return self.at_close[key] - self.at_open[key]
+
         counters = {
-            "waves": self.at_close["waves"] - self.at_open["waves"],
-            "real_rows": self.at_close["real_rows"] - self.at_open["real_rows"],
-            "window_compiles": self.at_close["compiles"] - self.at_open["compiles"],
-            "store_evictions": max(0, written * 2 * self.cfg.n_layers - held),
+            "waves": delta("waves"), "real_rows": delta("real_rows"),
+            "window_compiles": delta("compiles"),
+            "store_evictions": max(0, written * self.geometry.values_per_block - held),
             "peak_hbm_bytes": peak_bytes,
+            **{key: delta(key) for key in self.program_counters},
         }
         if "tpot_mean_ms" in e2e:
             counters["tpot_mean_ms"] = e2e["tpot_mean_ms"]
+        # With the recorder on: what it holds, for the span readers.
+        spans, rec = None, tracing.recorder()
+        if tracing.enabled() and rec is not None:
+            spans = {
+                "spans": rec.snapshot(), "recorded": rec.recorded, "dropped": rec.dropped,
+                "window_us": [t0 * 1e6, t1 * 1e6], "profile": None,
+            }
         return {
             "attempted": len(started),
             "failed": sum(1 for r in started if r.error is not None),
-            "end_to_end": e2e, "rows": rows, "counters": counters,
+            "end_to_end": e2e, "rows": rows, "counters": counters, "spans": spans,
         }
 
-    def trace_results(self) -> Optional[Dict]:
+    def trace_results(self, spans: Optional[Dict]) -> Optional[Dict]:
+        """The profile, read once and reduced for both kinds of reader: the
+        device's tables, and (into ``spans["profile"]``) the idle gaps by
+        program phase. ``trace["work"]`` is the useful work of the traced
+        calls, summed by the configuration's cost module over what the taps
+        saw: a request's entries into waves, and the prefills."""
         if self.trace_t0 is None:
             return None
-        trace = trace_reduce.reduce(trace_reduce.load(trace_reduce.find_xplane(self.trace_dir)))
+        raw = trace_reduce.load(trace_reduce.find_xplane(self.trace_dir))
+        trace = trace_reduce.reduce(raw)
+        if spans is not None:
+            spans["profile"] = span_readers.reduce_profile(raw, spans["spans"])
         a, b = self.trace_t0, self.trace_t1
-        c, layers = self.cfg, self.cfg.n_layers
-        item = 2  # bfloat16
-        decode_bytes = flash_flops = 0
+        work = dict.fromkeys(self.costs.WORK_KEYS, 0)
+        work["prefill_ktok"] = 0.0
+
+        def add(amounts: Dict):
+            for key, amount in amounts.items():
+                work[key] += amount
+
         for rec in self.records:
-            for t, pages in zip(rec.stamps, rec.pages):
+            for t, (pages, rows) in zip(rec.stamps, rec.calls):
                 if a <= t < b:
-                    decode_bytes += layers * costs.ragged_decode_bytes(
-                        pages, 1, c.block_tokens, c.n_heads, c.n_kv_heads, c.head_dim, item
-                    )
-        ktok = 0.0
+                    add(self.costs.wave_work(self.config, pages, rows))
         for t, n in self.taps.prefills:
             if a <= t < b:
-                ktok += n / 1000.0
-                flash_flops += layers * costs.flash_prefill_flops(n, c.n_heads, c.head_dim)
-        trace["work"] = {
-            "ragged_decode_bytes": decode_bytes, "flash_prefill_flops": flash_flops,
-            "prefill_ktok": ktok,
-        }
+                work["prefill_ktok"] += n / 1000.0
+                add(self.costs.prefill_work(self.config, n))
+        trace["work"] = work
         return trace
 
 
-def execute(args, cell, config, plan, device):
+def execute(args, cell, config, plan, device, program_counters=()):
     """Server, set-up, window, checks: everything after the device is known.
     Returns the result line (without metrics), the window's results and the
-    reduced trace."""
+    reduced trace. The server is sized from the file's ``serving`` alone
+    (nothing is built yet); ``build`` holds those numbers to the caches."""
     import jax
 
     import infinistore_tpu as its
 
     compiles = Compiles()
-    run = CellRun(args, cell, config, plan)
-    block_bytes = (
-        config["serving"]["block_tokens"] * config["num_key_value_heads"]
-        * config["head_dim"] * 2
-    )
+    run = CellRun(args, cell, config, plan, program_counters)
     # The pool holds the whole plan's working set below the server's
     # on-demand eviction threshold (0.8 of the pool), so nothing is evicted.
     need = traffic.store_bytes(plan, config["serving"]["kv_bytes_per_token"])
     pool_gib = max(2, int(need / 0.7 / 2**30) + 2)
-    server = start_server(pool_gib, max(16, block_bytes // 1024))
+    # The server allocates in units no smaller than 16 KiB.
+    block_kib = max(16, int(config["serving"]["store_block_kib"]))
+    if args.trace:
+        # The program's own spans, in traced runs only: end-to-end runs
+        # never carry the recorder.
+        tracing.configure(enabled=True, capacity=SPAN_CAPACITY)
+    server = start_server(pool_gib, block_kib)
     conn = None
     try:
         conn = its.InfinityConnection(its.ClientConfig(
@@ -804,11 +879,13 @@ def execute(args, cell, config, plan, device):
 
         peak_bytes = asyncio.run(whole())
         res = run.results(run.t_open - T_PROCESS, peak_bytes)
-        trace = run.trace_results()
+        trace = run.trace_results(res["spans"])
     finally:
         if conn is not None:
             conn.close()
         stop_server(server)
+        if args.trace:
+            tracing.configure(enabled=False)
 
     if res["counters"]["window_compiles"]:
         run.failed_checks.append(
@@ -816,6 +893,8 @@ def execute(args, cell, config, plan, device):
         )
     if run.ran_dry:
         run.failed_checks.append("the traffic's lists ran dry before the window closed")
+    if res["spans"] and res["spans"]["dropped"]:
+        run.failed_checks.append(f"the recorder dropped {res['spans']['dropped']} spans")
     for check in run.failed_checks:
         print(f"not correct: {check}", file=sys.stderr, flush=True)
     line = {
@@ -823,6 +902,7 @@ def execute(args, cell, config, plan, device):
         "attempted": res["attempted"], "failed": res["failed"],
         "metrics": {}, "device": dict(device, memory_peak_bytes=peak_bytes),
         "workload": cell["name"], "seed": args.seed, "seconds": args.seconds,
+        "server": {"block_kib": block_kib, "pool_gib": pool_gib},
     }
     return line, res, trace
 
@@ -830,15 +910,22 @@ def execute(args, cell, config, plan, device):
 def detail(args, cell, line, res, layer):
     """Everything this run could read, for whoever studies a run: every
     end-to-end metric and every per-layer metric whatever ``--trace`` says,
-    in ``.bench_out/`` of the checkout. The driver reads only the line."""
+    in ``.bench_out/`` of the checkout; with the recorder on, beside it every
+    span it held and the idle table by phase (install, save_snapshot,
+    compute and the rest, which are no metrics). The driver reads only the
+    line."""
     out = os.path.join(REPO, ".bench_out")
     os.makedirs(out, exist_ok=True)
-    name = f"{cell['name']}.seed{args.seed}.trace{args.trace}.{int(time.time())}.json"
-    with open(os.path.join(out, name), "w") as f:
+    name = f"{cell['name']}.seed{args.seed}.trace{args.trace}"
+    with open(os.path.join(out, f"{name}.{int(time.time())}.json"), "w") as f:
         json.dump({
             "line": line, "end_to_end": res["end_to_end"], "per_layer": layer,
             "counters": res["counters"], "rows": res["rows"],
         }, f)
+    if res["spans"]:
+        held = {k: v for k, v in res["spans"].items() if k != "index"}
+        with open(os.path.join(out, f"recorder.{name}.json"), "w") as f:
+            json.dump(held, f)
 
 
 def device_line(jax) -> Dict:
@@ -856,10 +943,6 @@ def main() -> int:
 
     bench = load_json(os.path.join(REPO, "BENCHMARK.json"))
     cell, config = cell_of(bench, args.workload)
-    try:
-        import infinistore_tpu  # noqa: F401 - the system under test must be here
-    except ImportError as e:
-        fail(f"the system under test is not in this checkout: {e}", 2)
     import jax
 
     device = device_line(jax)
@@ -880,15 +963,22 @@ def main() -> int:
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     build_native_if_missing()
     plan = traffic.build_plan(cell["traffic"])
-    line, res, trace = execute(args, cell, config, plan, device)
-    view = readers.Run(res["rows"], res["counters"], trace, peaks[device["kind"]])
     per_layer = metrics_for(bench, "per_layer", cell["name"])
+    named = readers.counter_keys(m["name"] for m in per_layer) - OWN_COUNTERS
+    line, res, trace = execute(args, cell, config, plan, device, named)
+    view = readers.Run(
+        res["rows"], res["counters"], trace, peaks[device["kind"]], spans=res["spans"]
+    )
     layer = {m["name"]: readers.read_layer_metric(m["name"], view) for m in per_layer}
     if args.trace:
         for m in per_layer:
             if layer[m["name"]] is not None:
                 line["metrics"][m["name"]] = {"value": layer[m["name"]], "unit": m["unit"]}
         line["device"].update(busy_s=trace["busy_s"], window_s=trace["window_s"])
+        spans = res["spans"]
+        line["spans"] = {
+            "recorded": spans["recorded"], "dropped": spans["dropped"], **(spans["profile"] or {}),
+        }
         line["breakdown"] = {
             "device_ops": trace_reduce.top(trace["ops"]),
             "idle_gaps": trace_reduce.top(trace["idle_gaps"]),

@@ -32,6 +32,11 @@ kinds read them, in the form ``readers.py`` gives its own:
                    covered the gap matches ``pattern`` (a span's name, or a
                    part's from ``STAGE_PHASES``).
 
+What they read is ``readers.Run.spans``: ``{"spans": [span dicts],
+"recorded", "dropped", "window_us": [open, close] on the spans' clock,
+"profile": reduce_profile(...) or None}``, what the recorder held when the
+run ended.
+
 A reader returns ``None`` only where there is nothing to read: no recorder,
 no span of that name, no profile. Spans that are there but waited for
 nothing give 0.0.
@@ -48,7 +53,6 @@ recordings should agree.
 """
 
 import bisect
-import dataclasses
 import re
 from typing import Dict, List, Optional, Tuple
 
@@ -70,15 +74,6 @@ STAGE_PHASES = {
         ("gate", "dispatched", "wave_dispatch"), ("dispatched", "resolved", "wave_resolve"),
     ),
 }
-
-
-@dataclasses.dataclass
-class SpanRun(readers.Run):
-    """``readers.Run`` and what the recorder held when the run ended:
-    ``{"spans": [span dicts], "recorded", "dropped", "window_us": [open,
-    close] on the spans' clock, "profile": reduce_profile(...) or None}``."""
-
-    spans: Optional[Dict] = None
 
 
 # ---------------------------------------------------------------------------
@@ -169,7 +164,7 @@ def _parts(run, view: Dict, p: Dict) -> Optional[float]:
 def _spans(run, p: Dict) -> Optional[float]:
     if "skew" in p:
         return _skew(run, p)
-    view = getattr(run, "spans", None)
+    view = run.spans
     if not view or not view["spans"]:
         return None
     if "index" not in view:
@@ -184,7 +179,7 @@ def _spans(run, p: Dict) -> Optional[float]:
 
 
 def _trace_idle_in(run, p: Dict) -> Optional[float]:
-    view = getattr(run, "spans", None)
+    view = run.spans
     profile = view.get("profile") if view else None
     if not profile or profile["window_s"] <= 0:
         return None
@@ -194,6 +189,7 @@ def _trace_idle_in(run, p: Dict) -> Optional[float]:
 
 
 KINDS = {"spans": _spans, "trace_idle_in": _trace_idle_in}
+readers.KINDS.update(KINDS)
 
 
 # ---------------------------------------------------------------------------

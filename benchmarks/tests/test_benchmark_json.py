@@ -1,6 +1,7 @@
 """BENCHMARK.json keeps to the contract's letters, and every name in it
 finds its file."""
 
+import importlib
 import json
 import os
 import re
@@ -82,18 +83,46 @@ def test_layer_metric_file_agrees_with_benchmark_json(metric):
     assert set(entry.get("workloads", cells)) <= set(moved.get("workloads", cells))
 
 
+def resolves(dotted: str) -> bool:
+    module, _, attr = dotted.partition(":")
+    return hasattr(importlib.import_module(module), attr)
+
+
 @pytest.mark.parametrize("cell", [w["name"] for w in BENCH["workloads"]])
 def test_every_cell_finds_its_files(cell):
+    """Its configuration, traffic and every listed per-layer metric's file
+    exist and parse; what the configuration's ``program`` names is there; a
+    ``cost`` a metric names is one the configuration's cost module fills.
+    Nothing here knows a model's shape: ``run.py`` holds ``serving`` to the
+    caches the program builds (``cache_geometry.py``)."""
     (w,) = [w for w in BENCH["workloads"] if w["name"] == cell]
     (cfg,) = [c for c in BENCH["configs"] if c["name"] == w["config"]]
     with open(os.path.join(REPO, cfg["file"])) as f:
         config = json.load(f)
     assert config["source"] == cfg["source"] and config["reduced"] == cfg["reduced"]
-    for key in config["program"]["fields"].values():
+    prog, serving = config["program"], config["serving"]
+    for key in list(prog["fields"].values()) + list(prog.get("equals", {}).values()):
         assert key in config
-    assert config["hidden_size"] == config["num_attention_heads"] * config["head_dim"]
-    s = config["serving"]
-    assert s["kv_bytes_per_token"] == (
-        config["num_hidden_layers"] * 2 * config["num_key_value_heads"] * config["head_dim"] * 2
-    )
+    assert resolves(prog["config_class"]) and resolves(prog["init_params"])
+    assert callable(importlib.import_module(prog["reference"]).logits)
+    costs = importlib.import_module(prog["costs"])
+    assert callable(costs.wave_work) and callable(costs.prefill_work) and costs.WORK_KEYS
+    for key in ("block_tokens", "cache_blocks", "kv_bytes_per_token", "store_block_kib"):
+        assert serving[key] > 0, key
+    assert config["guarantees"]
     assert traffic.build_plan(w["traffic"]).requests
+    for m in BENCH["per_layer"]:
+        if cell not in m.get("workloads", [cell]):
+            continue
+        reader = readers.load_layer_metric(m["name"])["reader"]
+        assert reader["kind"] in readers.KINDS
+        if "cost" in reader:
+            assert reader["cost"] in costs.WORK_KEYS, (m["name"], reader["cost"])
+        if reader["kind"] == "trace_time" and reader.get("per") not in (None, "event"):
+            assert reader["per"] in (*costs.WORK_KEYS, "prefill_ktok"), m["name"]
+
+
+def test_no_file_under_layer_metrics_is_left_unlisted():
+    """A metric file that BENCHMARK.json does not list is read by nothing."""
+    listed = {m["name"] + ".json" for m in BENCH["per_layer"]}
+    assert set(os.listdir(os.path.join(REPO, "benchmarks", "layer_metrics"))) == listed
