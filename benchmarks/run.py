@@ -15,7 +15,8 @@ belongs to one configuration, traffic mix or per-layer metric is a data
 file found by the name in ``BENCHMARK.json`` (``configs/``, ``traffic/``,
 ``layer_metrics/``); nothing here names a cell, a model file or the shape of
 a cache. What a block of the cache weighs is read off the caches the program
-built (``cache_geometry.py``), the useful work of a traced call comes from
+built and what a hit installs of it off the configuration's file
+(``cache_geometry.py``), the useful work of a traced call comes from
 the module the configuration names (``program.costs``), and a counter of the
 program's is read by the name a metric file gives it. With ``--trace 1`` the
 program's own recorder is on and its spans are laid over the profile
@@ -55,7 +56,7 @@ import readers  # noqa: E402
 import span_readers  # noqa: E402
 import trace_reduce  # noqa: E402
 import traffic  # noqa: E402
-from cache_geometry import CacheGeometry  # noqa: E402
+from cache_geometry import CacheGeometry, hit_mismatch  # noqa: E402
 from infinistore_tpu import tracing  # noqa: E402 - the system under test and its recorder
 
 # Logits against the float32 reference, as multiples of the reference
@@ -105,6 +106,21 @@ def cell_of(bench: Dict, workload: str):
     cell = cells[workload]
     (config,) = [c for c in bench["configs"] if c["name"] == cell["config"]]
     return cell, load_json(os.path.join(REPO, config["file"]))
+
+
+def warm_answer_tokens(plan: traffic.Plan, block_tokens: int) -> int:
+    """A warm-up request's answer: one block of tokens, so that the save of
+    a whole answer block is warmed too, where the traffic's answers reach
+    one; else the traffic's longest answer."""
+    return min(block_tokens, max(r.answer_tokens for r in plan.requests))
+
+
+def check_answer_tokens(plan: traffic.Plan, block_tokens: int) -> int:
+    """A checked request's answer: two blocks of tokens or the traffic's
+    longest answer, whichever is shorter, and never under the first token
+    and the decode steps ``against_reference`` reads."""
+    longest = max(r.answer_tokens for r in plan.requests)
+    return max(min(2 * block_tokens, longest), DECODE_STEPS_CHECKED + 1)
 
 
 def metrics_for(bench: Dict, group: str, workload: str) -> List[Dict]:
@@ -345,9 +361,10 @@ class CellRun:
             self.adapter, self.params, self.cfg, self.num_blocks, self.max_req_blocks
         )
         # The cache as the program built it: what a block weighs and how
-        # many values it puts in the store. The file's serving numbers, which
-        # sized the server before anything was built, must agree.
-        self.geometry = CacheGeometry.of(self.h.caches)
+        # many values it puts in the store; from the file, which of its
+        # tensors a hit installs in its last blocks only. The file's serving
+        # numbers, which sized the server before anything was built, must agree.
+        self.geometry = CacheGeometry.of(self.h.caches, serving.get("hit_installs", ()))
         self.geometry.check(serving)
         self.taps = Instruments(self.h, bt)
         self.compiles = compiles
@@ -421,13 +438,14 @@ class CellRun:
         (a miss), and for shared prefixes the same document again (a hit:
         install and chunked resume). Answers are cut to a few tokens; the
         save shapes of whole answers are warmed apart."""
+        answer_tokens = warm_answer_tokens(self.plan, self.cfg.block_tokens)
         reqs = []
         for r in self.prompt_classes():
             doc = DOC_BASE_WARM + len(reqs)
             for ask in (0, 1) if r.prefix_tokens else (0,):
                 reqs.append(dataclasses.replace(
                     r, index=DOC_BASE_WARM + len(reqs), doc=doc, ask=ask, due_s=None,
-                    answer_tokens=self.cfg.block_tokens,
+                    answer_tokens=answer_tokens,
                 ))
         return reqs
 
@@ -623,10 +641,10 @@ class CellRun:
     async def check(self):
         """Per prompt class: a miss against the float32 reference (first
         token and 8 decode steps), the same prompt again as a full hit
-        (installed blocks byte-identical to what the miss saved, first-token
-        logits equal), and for shared prefixes a partial hit (a new question
-        after the stored prefix: the traffic's own hit path) against the
-        reference."""
+        (the blocks the configuration says a hit installs byte-identical to
+        what the miss saved, first-token logits equal), and for shared
+        prefixes a partial hit (a new question after the stored prefix: the
+        traffic's own hit path) against the reference."""
         import jax.numpy as jnp
         import numpy as np
 
@@ -634,13 +652,14 @@ class CellRun:
         from infinistore_tpu.tpu.paged import gather_blocks
 
         bt = self.cfg.block_tokens
+        answer_tokens = check_answer_tokens(self.plan, bt)
         self.taps.keep_logits = True
         self.adapter.keep = True
         for n, r in enumerate(self.prompt_classes(), start=1):
             label = f"prompt {r.prompt_tokens}"
             base = dataclasses.replace(
                 r, index=DOC_BASE_CHECK + 3 * n, doc=DOC_BASE_CHECK + n, ask=0, due_s=None,
-                answer_tokens=2 * bt,
+                answer_tokens=answer_tokens,
             )
             self.adapter.saved.clear()
             miss = await self.send(base)
@@ -678,13 +697,8 @@ class CellRun:
                 f"{label}: the second ask loaded {hit.stats.loaded_blocks} of {n} blocks",
             )
             chains = token_chain_hashes(tokens, bt)[:n]
-            same = "blocks" in held and len(held["blocks"][0][0]) == n and all(
-                tensor[i].tobytes() == self.adapter.saved[c][layer][kind]
-                for layer, tensors in enumerate(held["blocks"])
-                for kind, tensor in enumerate(tensors)
-                for i, c in enumerate(chains)
-            )
-            self.expect(same, f"{label}: installed blocks are not the bytes that were saved")
+            wrong = hit_mismatch(held.get("blocks", ()), self.adapter.saved, chains, self.geometry)
+            self.expect(wrong is None, f"{label}: installed blocks: {wrong}")
             first = lambda rec: np.asarray(rec.logits[0][0], np.float32)
             self.expect(
                 bool(np.array_equal(first(hit), first(miss))),
@@ -760,8 +774,9 @@ class CellRun:
                 ),
                 gate_hold_s=s.gate_hold_us / 1e6,
                 fetch_s=s.fetch_us / 1e6,
-                installed_bytes=s.loaded_blocks * self.geometry.block_nbytes,
-                fetched_bytes=s.prefetched_blocks * self.geometry.mean_value_nbytes,
+                installed_bytes=self.geometry.installed_nbytes(s.loaded_blocks),
+                # ``prefetched_blocks`` counts store values, for ``hit_blocks`` blocks.
+                fetched_bytes=self.geometry.fetched_nbytes(s.prefetched_blocks, s.hit_blocks),
                 # For the span readers: the request's trace, and the program's
                 # emit stamps beside the benchmark's.
                 trace_id=s.trace_id, emit_s=list(s.token_emit_s), bench_emit_s=emits,

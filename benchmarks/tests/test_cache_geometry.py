@@ -1,9 +1,11 @@
 """The harness on a cache that is not a K and a V of one shape: what a block
-weighs, the bytes and evictions it accounts, the useful work of a cost module
-it has never seen and a counter of the program's named by a metric file, all
-from made-up objects and all against numbers worked out by hand."""
+weighs, what a hit installs of it where some tensors are checkpoints, the
+bytes and evictions it accounts, the useful work of a cost module it has never
+seen and a counter of the program's named by a metric file, all from made-up
+objects and all against numbers worked out by hand."""
 
 import argparse
+import json
 import os
 import types
 
@@ -14,14 +16,14 @@ import readers
 import run
 import trace_reduce
 import traffic
-from cache_geometry import CacheGeometry
+from cache_geometry import CacheGeometry, hit_mismatch
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 BLOCKS = 4
 
 
-def tensor(*block_shape):
-    return np.zeros((BLOCKS, *block_shape), np.float16)  # two bytes a value, as bf16
+def tensor(*block_shape, dtype=np.float16):  # float16: two bytes a value, as bf16
+    return np.zeros((BLOCKS, *block_shape), dtype)
 
 
 # A latent of 512 and a rope key of 64 per token: 16 x 512 x 2 = 16,384 B and
@@ -46,8 +48,10 @@ def test_geometry_from_the_caches_alone(case):
     g = CacheGeometry.of(want["caches"])
     assert g.block_nbytes == want["block"] and g.values_per_block == want["values"]
     assert g.largest_value_nbytes == want["largest"] == want["kib"] * 1024
-    assert g.mean_value_nbytes * g.values_per_block == want["block"]
     g.check(serving(want))  # the file agrees: nothing raised
+    # No tensor is a checkpoint: a hit installs and fetches whole blocks.
+    assert g.installed_nbytes(5) == g.fetched_nbytes(5 * want["values"], 5) == 5 * want["block"]
+    assert g.fetched_values(5) == 5 * want["values"] == len(g.compared(5))
 
 
 @pytest.mark.parametrize("case", sorted(BY_HAND))
@@ -62,30 +66,182 @@ def test_a_file_that_misstates_its_cache_is_refused_with_both_numbers(case):
         g.check(wrong)
 
 
-def stub_cell_run(caches, program_counters=()):
+def stub_cell_run(caches, program_counters=(), hit_installs=()):
     """A ``CellRun`` with nothing behind it but what the accounting reads."""
     args = argparse.Namespace(seed=1, seconds=4.0, trace=0)
     cell_run = run.CellRun(args, {"name": "made-up"}, {"name": "made-up"}, None, program_counters)
     cell_run.cfg = types.SimpleNamespace(block_tokens=16)
-    cell_run.geometry = CacheGeometry.of(caches)
+    cell_run.geometry = CacheGeometry.of(caches, hit_installs)
     return cell_run
+
+
+def hit_row(cell_run, blocks, values):
+    """The row of a request that hit ``blocks`` blocks and fetched ``values`` store values."""
+    stats = types.SimpleNamespace(
+        loaded_blocks=blocks, hit_blocks=blocks, prefetched_blocks=values, gate_stall_us=0.0,
+        prefix_ready_us=1e3, ttft_us=2e3, gate_hold_us=0.0, fetch_us=5e3, trace_id=7, token_emit_s=[1.5],
+    )
+    req = traffic.Request(0, 0, None, 0, 1, 48, 16, 4)
+    rec = run.Record(req=req, t_start=1.0, t_dispatch=1.0, t_sent=1.0, stamps=[1.2, 1.5, 1.6], stats=stats)
+    return cell_run.request_row(rec)
 
 
 @pytest.mark.parametrize("case", sorted(BY_HAND))
 def test_installed_and_fetched_bytes_of_a_request(case):
     want = BY_HAND[case]
-    cell_run = stub_cell_run(want["caches"])
-    stats = types.SimpleNamespace(
-        loaded_blocks=3, prefetched_blocks=3 * want["values"], gate_stall_us=0.0, prefix_ready_us=1e3,
-        ttft_us=2e3, gate_hold_us=0.0, fetch_us=5e3, trace_id=7, token_emit_s=[1.5],
-    )
-    req = traffic.Request(0, 0, None, 0, 1, 48, 16, 4)
-    rec = run.Record(req=req, t_start=1.0, t_dispatch=1.0, t_sent=1.0, stamps=[1.2, 1.5, 1.6], stats=stats)
-    row = cell_run.request_row(rec)
+    row = hit_row(stub_cell_run(want["caches"]), 3, 3 * want["values"])
     assert row["installed_bytes"] == 3 * want["block"]
     # A fetch takes every value of a block, so count x mean is exact.
     assert row["fetched_bytes"] == 3 * want["block"]
     assert row["trace_id"] == 7 and row["emit_s"] == [1.5] and row["bench_emit_s"] == [1.5, 1.6]
+
+
+# A hybrid of paged attention and recurrent state at 1,024-token blocks: three
+# layers of a K and a V of 2 heads x 128 and the keys pooled to 64 a block
+# (2 x 524,288 + 32,768 B), beside nine layers of ONE tensor, a state of 32
+# heads x 128 x 128 float32 = 2 MiB saved with every block, of which a hit
+# installs the last block's alone.
+ATTN = (1024 * 2 * 128 * 2, 1024 * 2 * 128 * 2, 64 * 2 * 128 * 2)
+STATE = 32 * 128 * 128 * 4
+HYBRID_LAYERS = "ASSSSSSAASSS"  # which of the twelve layers is which
+STATE_LAYERS = [i for i, kind in enumerate(HYBRID_LAYERS) if kind == "S"]
+HYBRID_POLICY = [{"layers": STATE_LAYERS, "tensor": 0, "last_blocks": 1}]
+
+
+def hybrid_caches(scale=1):
+    """The hybrid's caches; ``scale`` divides every tensor's last axis, for
+    the cases that fill them with bytes."""
+    attn = (tensor(1024, 2, 128 // scale), tensor(1024, 2, 128 // scale), tensor(64, 2, 128 // scale))
+    state = (tensor(32, 128, 128 // scale, dtype=np.float32),)
+    return [attn if kind == "A" else state for kind in HYBRID_LAYERS]
+
+
+def test_a_hit_installs_every_kv_block_and_the_last_blocks_state():
+    g = CacheGeometry.of(hybrid_caches(), HYBRID_POLICY)
+    # Every block still WRITES every tensor: the pool and the evictions see all of it.
+    assert g.block_nbytes == 3 * sum(ATTN) + 9 * STATE == 22_118_400 == 21_600 * 1024
+    assert g.values_per_block == 3 * 3 + 9 and g.largest_value_nbytes == STATE == 2048 * 1024
+    g.check({"block_tokens": 1024, "kv_bytes_per_token": 21_600, "store_block_kib": 2048})
+    # A hit of 32 blocks: 32 x the attention layers' values, one state a layer.
+    assert g.fetched_values(32) == 32 * 9 + 9
+    assert g.installed_nbytes(32) == 32 * 3_244_032 + 18_874_368 == 122_683_392
+    assert g.installed_nbytes(32) != 32 * g.block_nbytes == 707_788_800
+    assert g.installed_nbytes(1) == g.block_nbytes and g.installed_nbytes(0) == 0
+    triples = g.compared(32)
+    assert len(triples) == 32 * 9 + 9
+    assert {(layer, block) for layer, _, block in triples if layer in STATE_LAYERS} == {
+        (layer, 31) for layer in STATE_LAYERS
+    }
+    assert [(t, b) for layer, t, b in triples if layer == 0] == [(t, b) for t in range(3) for b in range(32)]
+    # The request's row: what the program counted as fetched, in bytes.
+    row = hit_row(stub_cell_run(hybrid_caches(), hit_installs=HYBRID_POLICY), 32, 32 * 9 + 9)
+    assert row["installed_bytes"] == row["fetched_bytes"] == 122_683_392
+
+
+def test_a_window_of_two_blocks():
+    """A sliding layer whose window is two blocks: a hit installs its last two."""
+    caches = [(tensor(16, 4, 32), tensor(16, 4, 32)), (tensor(16, 4, 32), tensor(16, 4, 32))]
+    policy = [{"layers": [1], "tensor": t, "last_blocks": 2} for t in (0, 1)]
+    g = CacheGeometry.of(caches, policy)
+    assert g.block_nbytes == 4 * 4096 and g.values_per_block == 4
+    assert g.installed_nbytes(5) == 2 * 5 * 4096 + 2 * 2 * 4096 and g.fetched_values(5) == 10 + 4
+    assert g.installed_nbytes(1) == g.block_nbytes  # a hit shorter than the window: all of it
+    assert [(t, b) for layer, t, b in g.compared(5) if layer == 1] == [(0, 3), (0, 4), (1, 3), (1, 4)]
+    assert g.fetched_nbytes(14, 5) == g.installed_nbytes(5)
+
+
+@pytest.mark.parametrize("policy,says", [
+    ([{"layers": [3, 12], "tensor": 0, "last_blocks": 1}], r"tensor 0 of layer 12 .* 12 layers of \[3, 1, 1, "),
+    ([{"layers": [1], "tensor": 1, "last_blocks": 1}], r"tensor 1 of layer 1 .* 12 layers of \[3, 1, 1, "),
+    ([{"layers": [1], "tensor": 0, "last_blocks": 0}], "last_blocks of 1 or more"),
+    ([{"layers": [1, 1], "tensor": 0, "last_blocks": 1}], "twice"),
+], ids=["layer", "tensor", "count", "twice"])
+def test_a_policy_the_caches_do_not_have_stops_with_both_shapes(policy, says):
+    with pytest.raises(ValueError, match=says):
+        CacheGeometry.of(hybrid_caches(), policy)
+
+
+def filled_hit(n, seed=5):
+    """A hit of ``n`` blocks of the hybrid, at an eighth of its widths: the
+    bytes every block's save was handed, and what a program that follows the
+    policy leaves on the device: every attention block, and the state of
+    block n - 1 alone (the states before it were never installed)."""
+    rng = np.random.default_rng(seed)
+    caches = hybrid_caches(scale=8)
+    g = CacheGeometry.of(caches, HYBRID_POLICY)
+    chains = [f"chain-{i}" for i in range(n)]
+    saved = {c: [[rng.bytes(t[0].nbytes) for t in layer] for layer in caches] for c in chains}
+    installed = [
+        [
+            np.frombuffer(b"".join(saved[c][layer][i] for c in chains), t.dtype).reshape(n, *t.shape[1:]).copy()
+            for i, t in enumerate(tensors)
+        ]
+        for layer, tensors in enumerate(caches)
+    ]
+    for layer in STATE_LAYERS:
+        installed[layer][0][: n - 1] = 0
+    return g, installed, saved, chains
+
+
+def flip_a_byte(array, block):
+    array[block].view(np.uint8).reshape(-1)[-1] ^= 1
+
+
+def test_the_full_hits_comparison_follows_the_policy():
+    g, installed, saved, chains = filled_hit(32)
+    assert hit_mismatch(installed, saved, chains, g) is None  # only block 31's states are there
+    # The same read-back under a file WITHOUT the key: every state block is held to its save.
+    every = CacheGeometry.of(hybrid_caches(scale=8))
+    assert "layer 1 tensor 0 block 0 of 32" in hit_mismatch(installed, saved, chains, every)
+    # Block 31's state differs by one byte.
+    flip_a_byte(installed[STATE_LAYERS[-1]][0], 31)
+    assert "layer 11 tensor 0 block 31 of 32 is not the bytes that were saved" in hit_mismatch(installed, saved, chains, g)
+    flip_a_byte(installed[STATE_LAYERS[-1]][0], 31)
+    assert hit_mismatch(installed, saved, chains, g) is None
+    # Any K block differs; so for a V and for the pooled keys.
+    for layer, t, block in ((0, 0, 0), (7, 1, 17), (8, 2, 31)):
+        flip_a_byte(installed[layer][t], block)
+        assert f"layer {layer} tensor {t} block {block} of 32" in hit_mismatch(installed, saved, chains, g)
+        flip_a_byte(installed[layer][t], block)
+    # A read-back that is short of blocks, of layers, or a chain no save was seen for.
+    assert "30 blocks of a hit of 32" in hit_mismatch(
+        [[t[:30] for t in layer] for layer in installed], saved, chains, g)
+    assert "0 layers" in hit_mismatch((), saved, chains, g)
+    assert "block 4 of 32: no save" in hit_mismatch(
+        installed, {c: v for c, v in saved.items() if c != chains[4]}, chains, g)
+
+
+def accepted_files():
+    bench = run.load_json(os.path.join(run.REPO, "BENCHMARK.json"))
+    return [pytest.param(c["file"], id=c["name"]) for c in bench["configs"]]
+
+
+@pytest.mark.parametrize("path", accepted_files())
+def test_an_accepted_file_without_the_key_counts_what_it_counted(path):
+    """Neither accepted file has the key, and for each the bytes of a hit and
+    the compared triples are what ``run.py`` computed before the key existed
+    (``d292d7e``: ``loaded_blocks * block_nbytes``, ``prefetched_blocks *
+    mean_value_nbytes``, every tensor of every layer in all n blocks)."""
+    with open(os.path.join(run.REPO, path)) as f:
+        config = json.load(f)
+    assert "hit_installs" not in config["serving"]
+    kv = tensor(config["serving"]["block_tokens"], config["num_key_value_heads"], config["head_dim"])
+    caches = [(kv, kv)] * config["num_hidden_layers"]
+    g = CacheGeometry.of(caches)
+    g.check(config["serving"])
+    block_nbytes = sum(t.nbytes // BLOCKS for layer in caches for t in layer)
+    values_per_block = 2 * len(caches)
+    cell_run = stub_cell_run(caches)
+    for n in (0, 1, 64, 136, 520):
+        row = hit_row(cell_run, n, n * values_per_block)
+        assert row["installed_bytes"] == g.installed_nbytes(n) == n * block_nbytes
+        for values in (n * values_per_block, n * values_per_block // 2, 7):  # whole, cut short
+            assert g.fetched_nbytes(values, n) == values * (block_nbytes / values_per_block)
+        assert row["fetched_bytes"] == n * block_nbytes
+        assert g.compared(n) == [
+            (layer, kind, i) for layer, tensors in enumerate(caches)
+            for kind in range(len(tensors)) for i in range(n)
+        ]
 
 
 @pytest.mark.parametrize("case", sorted(BY_HAND))

@@ -36,7 +36,7 @@ with open(os.path.join(REPO, "BENCHMARK.json")) as f:
     BENCH = json.load(f)
 
 
-def toy_run(params, seed, program_counters=()):
+def toy_run(params, seed, program_counters=(), hit_installs=None):
     import jax
 
     if jax.devices()[0].platform != "cpu":
@@ -45,6 +45,8 @@ def toy_run(params, seed, program_counters=()):
 
     with open(os.path.join(REPO, "benchmarks", "configs", "mistral-7b-v0.3.json")) as f:
         config = dict(TOY, program=json.load(f)["program"])
+    if hit_installs is not None:
+        config["serving"] = dict(TOY["serving"], hit_installs=hit_installs)
     plan = (traffic._closed_plan if params["loop"] == "closed" else traffic._open_plan)("toy", params)
     args = argparse.Namespace(workload="toy", seed=seed, seconds=4.0, trace=0)
     return run.execute(
@@ -108,3 +110,59 @@ def test_toy_cell_with_spans_on(params, suffix):
     assert values["emit_stamp_skew_p95_ms" + suffix] < 20.0
     assert 50.0 < values["after_ready_accounted_pct" + suffix] <= 100.5, values
     assert values["first_wave_wait_p50_ms" + suffix] > 0
+
+
+@pytest.mark.parametrize("name,block_tokens,warm,check", [
+    ("reuse-sessions-2k-8k", 16, 16, 32),
+    ("reuse-sessions-1k-2k", 16, 16, 32),
+    ("chat-replay", 16, 16, 32),
+    # Blocks of 1,024 tokens under 64-token answers, which complete no block:
+    # warm-up and check decode what the traffic decodes, not 1,024 and 2,048.
+    ("made-up-64", 1024, 64, 64),
+    ("made-up-4", 16, 4, 9),  # never under the rounds the reference comparison reads
+])
+def test_warm_up_and_check_answers_come_from_the_traffic(name, block_tokens, warm, check):
+    import run
+
+    if name.startswith("made-up"):
+        plan = traffic._closed_plan(name, dict(CLOSED, answer_tokens=int(name.rsplit("-", 1)[1])))
+    else:
+        plan = traffic.build_plan(name)
+    assert run.warm_answer_tokens(plan, block_tokens) == warm
+    assert run.check_answer_tokens(plan, block_tokens) == check >= run.DECODE_STEPS_CHECKED + 1
+
+
+def test_a_policy_the_built_caches_do_not_have_stops_the_run_at_build():
+    with pytest.raises(ValueError, match=r"tensor 2 of layer 1 .* 2 layers of \[2, 2\] tensors"):
+        toy_run(CLOSED, 2**31 + 13, hit_installs=[{"layers": [1], "tensor": 2, "last_blocks": 1}])
+
+
+@pytest.mark.parametrize("policy,seed", [
+    (None, 2**31 + 17), ([{"layers": [1], "tensor": 1, "last_blocks": 1}], 2**31 + 19),
+], ids=["every_block", "last_block"])
+def test_a_run_whose_install_alters_a_block_is_not_correct(monkeypatch, capfd, policy, seed):
+    """The rest of a run over a timed path that is broken underneath: the
+    adapter's install leaves one byte of one block of the last layer's V
+    other than it was saved. Window, drain and checks run as ever, and
+    ``correct`` comes out false for that reason: with the policy a file
+    without the key has, and where the file calls that tensor a checkpoint
+    of one block and the altered block is a hit's last."""
+    import jax.numpy as jnp
+
+    import run
+    from infinistore_tpu.engine import EngineKVAdapter
+
+    real = EngineKVAdapter.install_kv
+
+    async def install_kv(self, prefetch, caches, block_table):
+        out, loaded = await real(self, prefetch, caches, block_table)
+        if loaded:
+            last = int(block_table[loaded // TOY["serving"]["block_tokens"] - 1])
+            k, v = out[-1]
+            out = list(out[:-1]) + [(k, v.at[last, 0, 0, 0].add(jnp.asarray(1.0, v.dtype)))]
+        return out, loaded
+
+    monkeypatch.setattr(EngineKVAdapter, "install_kv", install_kv)
+    line, res, _ = toy_run(CLOSED, seed, hit_installs=policy)
+    assert not line["correct"] and line["failed"] == 0 and line["attempted"] >= 8, line
+    assert "installed blocks: layer 1 tensor 1 block" in capfd.readouterr().err
