@@ -71,9 +71,10 @@ LOGITS_RMS_TOL = 0.025  # x rms(reference logits)
 LOGITS_MAX_TOL = 0.15  # x rms(reference logits)
 # The harness's own verify_tol default (2e-4) is a CPU/float32 number. On
 # the MXU the harness cache and its one-shot prefill oracle can differ
-# wherever the two took different bf16 paths: a suffix computed by
-# prefill_continue (float32 decode attention over the cached prefix) against
-# the oracle's flash prefill (bf16 probabilities). K/V entries are
+# wherever the two took different paths: a suffix computed by
+# prefill_continue (the chunk kernel over the cached prefix's pages, folded
+# 128 keys a step) against the oracle's flash prefill (256-key blocks over
+# the whole prompt); both round probabilities to bf16. K/V entries are
 # unit-variance projections of a stream carrying the ~1.7% estimate above,
 # so an entry may be off by ~6 sigma * 1.7% ~ 0.1 whatever its own size.
 # Stale or misplaced bytes differ by O(1) in most entries and still fail.
@@ -86,10 +87,10 @@ class Sizes:
     compiles an 8-layer program."""
 
     prompt_tokens: int = 1024
-    suffix_tokens: int = 64
+    suffix_tokens: int = 192  # 1.5 row tiles of the resume's chunk kernel
     gen_tokens: int = 32
     num_blocks: int = 4096  # x 16 tokens x 8 kv heads x 128 x bf16 x K,V x 8 layers = 2 GiB
-    max_req_blocks: int = 72  # (1024 + 64 + 32) / 16 = 70, rounded up
+    max_req_blocks: int = 80  # (1024 + 192 + 32) / 16 = 78, rounded up
 
 
 def smoke_config():
@@ -238,12 +239,13 @@ def start_server():
 
 
 def check_kernels(cfg) -> dict:
+    from infinistore_tpu.tpu import chunk_attention as ca
     from infinistore_tpu.tpu import flash_prefill as fp
     from infinistore_tpu.tpu import kv_quant as kq
     from infinistore_tpu.tpu import paged
     from infinistore_tpu.tpu import paged_attention as pa
 
-    for mod in (paged, pa, fp, kq):
+    for mod in (paged, pa, fp, kq, ca):
         assert mod._use_pallas(), f"{mod.__name__} would dispatch to XLA here"
 
     out = {}
@@ -339,6 +341,26 @@ def check_kernels(cfg) -> dict:
         out[f"flash_prefill_{s}"] = _check_rows(
             f"flash S={s}", got, fp.flash_prefill_xla(qs, ks_, vs_, causal=True),
             1, FLASH_OUT_ULPS, floor=p_rounding,
+        )
+    # The resume's chunk kernel: a 64-token chunk over prefixes that end on
+    # a page-group boundary and inside a page, and a 300-token remainder
+    # after a short prefix (two of the kernel's row tiles and a part), the
+    # table padded with block 0. Like flash prefill it rounds probabilities
+    # to bf16.
+    for start, rows in ((1024, 64), (1000, 64), (100, 300)):
+        qc = normal(rows, h, d)
+        table = np.zeros(72, np.int32)
+        n_pages = -(-(start + rows) // bt)
+        table[:n_pages] = 1 + rng.permutation(nblk - 1)[:n_pages]
+        args = (qc, k_cache, v_cache, jnp.asarray(table), jnp.int32(start))
+        assert _mosaic_kernels(
+            ca._chunk_prefix_attention_pallas, *args, interpret=False
+        ) == {"_chunk_attn_kernel"}
+        got = ca._chunk_prefix_attention_pallas(*args, interpret=False)
+        p_rounding = 2.0**-9 * float(jnp.max(jnp.abs(v_cache.astype(jnp.float32))))
+        out[f"chunk_resume_{start}"] = _check_rows(
+            f"chunk start={start}", got, ca.chunk_prefix_attention_xla(*args),
+            0, FLASH_OUT_ULPS, floor=p_rounding,
         )
     for name, rec in out.items():
         print(f"  kernel {name}: {rec}", flush=True)
@@ -573,9 +595,10 @@ async def run_traffic(conn, cfg, params, sizes: Sizes, compiles: Compiles) -> di
 
 
 def check_steps_hold_kernels(cfg, params, sizes: Sizes) -> dict:
-    """The two jitted steps the harness runs hold the Mosaic kernels at the
-    traffic's shapes: flash prefill and the block scatter in ``prefill``,
-    the ragged decode kernel in the wave step."""
+    """The three jitted steps the harness runs hold the Mosaic kernels at
+    the traffic's shapes: flash prefill and the block scatter in
+    ``prefill``, the ragged decode kernel in the wave step, the chunk
+    kernel in the resume of a partial hit."""
     from infinistore_tpu.models import llama
     from infinistore_tpu.tpu.paged_attention import build_ragged_wave
 
@@ -596,7 +619,15 @@ def check_steps_hold_kernels(cfg, params, sizes: Sizes) -> dict:
         config=cfg, max_blocks=mrb,
     )
     assert in_wave == {"_ragged_decode_attn_kernel"}, in_wave
-    return {"prefill": sorted(in_prefill), "verify_step_ragged": sorted(in_wave)}
+    in_resume = _mosaic_kernels(
+        llama.resume_chunk, params, i32(sizes.suffix_tokens), i32(), caches,
+        i32(mrb), config=cfg,
+    )
+    assert in_resume == {"_chunk_attn_kernel"}, in_resume
+    return {
+        "prefill": sorted(in_prefill), "verify_step_ragged": sorted(in_wave),
+        "resume_chunk": sorted(in_resume),
+    }
 
 
 async def check_staging_reuse(kvc, cfg, caches, written_blocks, rng, report):

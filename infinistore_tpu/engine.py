@@ -1009,6 +1009,13 @@ class ContinuousBatchingHarness:
         # own `_generate` (run_request): every request that computed
         # blocks and generates.
         self.saves_overlapped = 0
+        # Prefix hits resumed as one chunk (_chunked_resume): how many, the
+        # suffix tokens they computed and the context pages they attended
+        # (ceil((prefix + suffix) / block_tokens) each: what the chunk
+        # kernel walks once, whatever the table's padding).
+        self.resumes = 0
+        self.resume_tokens = 0
+        self.resume_pages = 0
         # Admissions that wanted a prefetch but found the staging arena
         # full and fell back to the one-phase gated load (backpressure).
         self.prefetch_fallbacks = 0
@@ -1110,13 +1117,19 @@ class ContinuousBatchingHarness:
 
     def _chunked_resume(self, token_ids, table: np.ndarray, start_block: int):
         """Compute the suffix after a prefix hit as ONE chunked continuation
-        (models/llama.py prefill_continue — the engine's chunked-prefill
-        resume path): every suffix row attends its own prefix in a single
-        batched kernel launch per layer, with chunk-wide GEMMs, instead of
-        S_c sequential decode launches. Cache-mutating: caller holds the
-        exclusive gate."""
+        (models/llama.py prefill_continue -> resume_chunk, the engine's
+        chunked-prefill resume path): a program of its own whose attention
+        reads each of the request's context pages once for the whole chunk
+        (tpu/chunk_attention.py), with chunk-wide GEMMs, instead of S_c
+        decode rows that each walk the padded table. Returns at DISPATCH;
+        the device time is first waited for by whoever reads the cache next
+        (the save's snapshot). Cache-mutating: caller holds the exclusive
+        gate."""
         bt = self.config.block_tokens
         suffix = jnp.asarray(token_ids[start_block * bt :], jnp.int32)
+        self.resumes += 1
+        self.resume_tokens += int(suffix.shape[0])
+        self.resume_pages += -(-len(token_ids) // bt)
         _, self.caches = prefill_continue(
             self.params,
             suffix,
@@ -1542,6 +1555,9 @@ class ContinuousBatchingHarness:
                                 tokens=(n_blocks - loaded_blocks) * bt,
                                 waits_for_device=full,
                             )
+                            if not full:
+                                # the context pages the chunk attends
+                                cspan.annotate(pages=n_blocks)
 
                         def compute():
                             with tracing.device_call("its.compute", cspan):
@@ -1718,7 +1734,9 @@ class ContinuousBatchingHarness:
         (``recompute_saved_s``, ``prefill_per_block_s``); concurrency
         receipts (``max_live_requests``, ``max_concurrent_saves``,
         ``saves_overlapped`` — prompt saves whose store write ran beside
-        the request's own generation); the
+        the request's own generation); the chunked resumes of prefix hits
+        (``resumes``, ``resume_tokens`` — suffix tokens they computed —
+        and ``resume_pages`` — context pages their attention walked); the
         ragged wave-decode story (``decode_waves``, ``max_wave_size``,
         ``wave_buckets`` — distinct padded (B, T, P) jit buckets —
         ``wave_prewarmed_buckets`` — the canonical ladder
@@ -1818,6 +1836,9 @@ class ContinuousBatchingHarness:
             "max_live_requests": self.max_live,
             "max_concurrent_saves": self.max_concurrent_saves,
             "saves_overlapped": self.saves_overlapped,
+            "resumes": self.resumes,
+            "resume_tokens": self.resume_tokens,
+            "resume_pages": self.resume_pages,
             "decode_waves": self.wave.waves,
             "max_wave_size": self.wave.max_wave,
             # Distinct PADDED (B, T, P) buckets == jit cache entries for

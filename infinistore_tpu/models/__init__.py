@@ -24,6 +24,7 @@ from .llama import (
     loss_fn,
     prefill,
     prefill_continue,
+    resume_chunk,
     speculative_verify,
     train_step,
 )
@@ -33,6 +34,7 @@ __all__ = [
     "init_params",
     "prefill",
     "prefill_continue",
+    "resume_chunk",
     "prefill_layer",
     "embed_prompt",
     "embed_wave",

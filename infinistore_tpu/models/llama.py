@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..tpu.chunk_attention import chunk_prefix_attention
 from ..tpu.flash_prefill import flash_prefill_attention
 from ..tpu.paged import PagedKVCacheSpec, scatter_blocks
 from ..tpu.paged_attention import (
@@ -311,14 +312,14 @@ def verify_step_batched(
     """THE paged-inference body: a wave of B requests each advancing a
     K-token chunk against the shared cache in one launch per layer.
 
-    Every per-request inference entry point is a view of this: K=1 is
-    batched decode (``decode_step_batched``), B=1 with K>1 is chunked
-    continuation prefill / speculative verification (``prefill_continue``,
-    ``speculative_verify``), and B>1 with K>1 is a MIXED wave — some
-    requests decoding one token, others verifying drafts — which is what
-    lets a continuous-batching engine fold speculative decoding into its
-    lockstep waves (engine.py WaveDecoder) instead of running spec
-    requests out-of-band.
+    The rectangular entry points are views of this: K=1 is batched decode
+    (``decode_step_batched``, ``decode_step``), and B>1 with K>1 is a MIXED
+    wave — some requests decoding one token, others verifying drafts. The
+    serving engine runs neither: its waves are ragged
+    (``verify_step_ragged``) and a prefix hit's chunk is a program of its
+    own (``resume_chunk``, behind ``prefill_continue`` and
+    ``speculative_verify``), which reads the request's pages once where
+    B=1, K>1 here walks the padded table once a row.
 
     Each row inserts its K/V at (table[pos // bt], pos % bt), then one
     batched fused attention launch covers all B*K rows, each masked to its
@@ -451,6 +452,50 @@ def verify_step_ragged(
     return logits[0], new_caches
 
 
+@functools.partial(jax.jit, static_argnames=("config",))
+def resume_chunk(
+    params: Params,
+    tokens: jax.Array,  # [S_c] int32, the suffix chunk
+    start_pos: jax.Array,  # [] int32, absolute position of tokens[0]
+    caches: Caches,
+    block_table: jax.Array,  # [max_blocks] int32 (padded)
+    config: LlamaConfig,
+) -> Tuple[jax.Array, Caches]:
+    """The program behind ``prefill_continue``: ONE request's chunk at
+    contiguous positions. Each layer inserts the chunk's K/V at
+    (table[pos // bt], pos % bt) and then attends the request's pages in
+    place, once for the whole chunk, row r masked to ``start_pos + r + 1``
+    (tpu/chunk_attention.py). A program of its own: a decode wave's rows
+    belong to different requests and want a page walk each
+    (``verify_step_ragged``), a miss has no pages yet (``prefill``), and a
+    chunk of one request wants one walk for all its rows. The compile key
+    is the chunk's length and the table's (``max_blocks``)."""
+    s_c = tokens.shape[0]
+    bt = config.block_tokens
+    positions = start_pos + jnp.arange(s_c, dtype=jnp.int32)
+    pos2d = positions[None]  # [1, S_c]
+    x = jnp.take(params["embed"], tokens, axis=0)[None]  # [1, S_c, dim]
+    block_idx = jnp.take(block_table, positions // bt)
+    slots = positions % bt
+
+    new_caches: Caches = []
+    for layer, (k_cache, v_cache) in enumerate(caches):
+        k, v = _kv_proj(params, layer, x, pos2d, config)  # [1, S_c, KVH, D]
+        k_cache = k_cache.at[block_idx, slots].set(k[0].astype(k_cache.dtype))
+        v_cache = v_cache.at[block_idx, slots].set(v[0].astype(v_cache.dtype))
+        pre = f"l{layer}."
+        q = _q_proj(params, layer, x, pos2d, config)  # [1, S_c, H, D]
+        attn = chunk_prefix_attention(
+            q[0], k_cache, v_cache, block_table, start_pos
+        )[None]
+        x = x + jnp.einsum("bshk,hkd->bsd", attn, params[pre + "wo"])
+        x = _ffn(params, layer, x, config)
+        new_caches.append((k_cache, v_cache))
+    x = _rms_norm(x, params["final_norm"])
+    logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"])
+    return logits[0], new_caches
+
+
 def prefill_continue(
     params: Params,
     tokens: jax.Array,  # [S_c] int32, the suffix chunk
@@ -465,29 +510,20 @@ def prefill_continue(
     chunked-prefill resume path — vLLM's treatment of a prefix-cache hit).
     Token-by-token ``decode_step`` costs S_c launches per layer and GEMV
     matmuls; this inserts the whole chunk's K/V and attends all chunk rows
-    in one batched kernel launch (each row masked to its own prefix length),
-    with chunk-wide GEMMs for the projections and FFN. Semantically equal to
-    the decode loop (tested). Returns ([S_c, vocab] logits, caches).
+    in one kernel launch that reads each context page once for the whole
+    chunk (each row masked to its own prefix length), with chunk-wide GEMMs
+    for the projections and FFN. Semantically equal to the decode loop
+    (tested). Returns ([S_c, vocab] logits, caches).
 
-    This is the B=1 view of ``verify_step_batched`` — one inference body
-    to maintain."""
+    A plain function over the jitted ``resume_chunk``: what selects that
+    program is this signature (one request, contiguous positions, a chunk),
+    and its compile key is the chunk's length and ``max_blocks``."""
     if block_table.shape[0] != max_blocks:
         raise ValueError(
             f"block_table has {block_table.shape[0]} entries, expected "
             f"max_blocks={max_blocks} (pad the table to the static bound)"
         )
-    s_c = tokens.shape[0]
-    positions = start_pos + jnp.arange(s_c, dtype=jnp.int32)
-    logits, new_caches = verify_step_batched(
-        params,
-        tokens[None],
-        positions[None],
-        caches,
-        block_table[None],
-        config,
-        max_blocks,
-    )
-    return logits[0], new_caches
+    return resume_chunk(params, tokens, start_pos, caches, block_table, config)
 
 
 def speculative_verify(

@@ -18,6 +18,7 @@ from .paged import (
     scatter_blocks,
     scatter_blocks_xla,
 )
+from .chunk_attention import chunk_prefix_attention, chunk_prefix_attention_xla
 from .flash_prefill import flash_prefill_attention, flash_prefill_xla
 from .kv_quant import (
     QuantizedKVConnector,
@@ -46,6 +47,8 @@ from .layerwise import (
 )
 
 __all__ = [
+    "chunk_prefix_attention",
+    "chunk_prefix_attention_xla",
     "flash_prefill_attention",
     "flash_prefill_xla",
     "QuantizedKVConnector",

@@ -274,8 +274,8 @@ def flash_prefill_attention(q, k, v, *, causal=True, block_q=256, block_k=256):
     ``causal=True`` masks by GLOBAL position assuming q and k both start at
     position 0, so it requires S == T; a suffix chunk attending a longer
     context (S < T with q offset T-S) would be silently over-masked —
-    rejected loudly instead (use prefill_continue's explicit-offset path
-    for chunked continuation)."""
+    rejected loudly instead (a chunk at an offset over a paged context is
+    tpu/chunk_attention.py's, behind models/llama.py prefill_continue)."""
     if causal and q.shape[1] != k.shape[1]:
         raise ValueError(
             f"causal=True assumes q and k start at position 0, so S must "

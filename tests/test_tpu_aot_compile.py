@@ -13,7 +13,8 @@ spent. Numerical agreement and donation stay questions for the chip
 Geometries: the smoke's (Llama-3-8B attention: 32 q / 8 kv heads, head_dim
 128, 16-token blocks, bf16) and the two demo geometries ``bench.py`` and the
 examples run (engine demo: 4 q / 2 kv heads, head_dim 32, 16-token blocks,
-f32; disagg demo: head_dim 16, 8-token blocks, f32).
+f32; disagg demo: head_dim 16, 8-token blocks, f32); for the resume of a
+prefix hit also the benchmark's two reuse cells (``RESUMES``).
 """
 
 import functools
@@ -33,6 +34,7 @@ from jax.sharding import (  # noqa: E402
     SingleDeviceSharding,
 )
 
+from infinistore_tpu.tpu import chunk_attention as ca  # noqa: E402
 from infinistore_tpu.tpu import flash_prefill as fp  # noqa: E402
 from infinistore_tpu.tpu import kv_quant as kq  # noqa: E402
 from infinistore_tpu.tpu import paged  # noqa: E402
@@ -128,6 +130,86 @@ def test_flash_prefill_compiles_at_any_prompt_length(v5e, geom, seq):
         fp._flash_prefill_pallas, q, kv, kv,
         causal=True, block_q=256, block_k=256, interpret=False,
     )
+
+
+# The resume of a prefix hit (tpu/chunk_attention.py, models/llama.py
+# resume_chunk) at the benchmark's two reuse cells (bf16, 16-token blocks,
+# head_dim 128, a 128-token question: Mistral's 32/8 heads over a 524-entry
+# table and a 1,024-block cache, DeepSeek's 32/32 over 140 and 320) and at
+# the two demo geometries with a one-block chunk. ``LONG_RESUMES``: a short
+# shared prefix and a long fresh remainder, as far as each cell's table
+# allows (the kernel cuts the chunk into row tiles, so its VMEM is one
+# tile's whatever the length: 2,048 rows in one block did not fit).
+# (name, q heads, kv heads, head_dim, block_tokens, dtype, chunk, table, blocks)
+RESUMES = [
+    ("mistral_reuse", 32, 8, 128, 16, jnp.bfloat16, 128, 524, 1024),
+    ("deepseek_reuse", 32, 32, 128, 16, jnp.bfloat16, 128, 140, 320),
+    ("engine_demo", 4, 2, 32, 16, jnp.float32, 16, 16, NUM_BLOCKS),
+    ("disagg_demo", 4, 2, 16, 8, jnp.float32, 8, 16, NUM_BLOCKS),
+]
+LONG_RESUMES = [
+    ("mistral_reuse_2048", 32, 8, 128, 16, jnp.bfloat16, 2048, 524, 1024),
+    ("mistral_reuse_8064", 32, 8, 128, 16, jnp.bfloat16, 8192 - 128, 524, 1024),
+    ("deepseek_reuse_2048", 32, 32, 128, 16, jnp.bfloat16, 2048, 140, 320),
+    ("deepseek_reuse_2100", 32, 32, 128, 16, jnp.bfloat16, 2100, 140, 320),
+]
+
+
+@pytest.mark.parametrize(
+    "case", RESUMES + LONG_RESUMES, ids=[c[0] for c in RESUMES + LONG_RESUMES]
+)
+def test_chunk_prefix_attention_compiles(v5e, case):
+    _, h, kvh, d, bt, dtype, chunk, table, blocks = case
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=v5e)
+    cache = s((blocks, bt, kvh, d), dtype)
+    _compile(
+        ca._chunk_prefix_attention_pallas, s((chunk, h, d), dtype), cache, cache,
+        s((table,), jnp.int32), s((), jnp.int32), interpret=False,
+    )
+
+
+def test_chunk_prefix_attention_compiles_for_a_draft_of_five(v5e):
+    """``speculative_verify`` hands ``prefill_continue`` a draft of any
+    length: the rows are padded to the dtype's sublane tile."""
+    a = _shapes(v5e, GEOMETRIES[0])
+    _, h, _, d, _, dtype = GEOMETRIES[0]
+    q = jax.ShapeDtypeStruct((5, h, d), dtype, sharding=v5e)
+    start = jax.ShapeDtypeStruct((), jnp.int32, sharding=v5e)
+    _compile(
+        ca._chunk_prefix_attention_pallas, q, a["cache"], a["cache"],
+        a["pages"], start, interpret=False,
+    )
+
+
+@pytest.mark.parametrize(
+    "case", RESUMES + LONG_RESUMES[:1], ids=[c[0] for c in RESUMES + LONG_RESUMES[:1]]
+)
+def test_resume_program_compiles_with_its_kernel(v5e, monkeypatch, case):
+    """``resume_chunk`` through the model's own dispatcher: one Mosaic call
+    a layer in the program (one layer at the cell's attention widths: the
+    layers are identical, 16 of them only compile for longer, and the FFN
+    and the vocabulary, which the kernel never sees, are kept small)."""
+    from infinistore_tpu.models import llama
+
+    monkeypatch.setattr(ca, "_use_pallas", lambda: True)
+    _, h, kvh, d, bt, dtype, chunk, table, blocks = case
+    cfg = llama.LlamaConfig(
+        vocab=1024, dim=h * d, n_layers=1, n_heads=h, n_kv_heads=kvh,
+        ffn_dim=1024, block_tokens=bt, dtype=dtype,
+    )
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=v5e)
+    params = jax.tree.map(
+        lambda x: s(x.shape, x.dtype),
+        jax.eval_shape(lambda: llama.init_params(cfg, jax.random.PRNGKey(0))),
+    )
+    cache = s(cfg.kv_spec(blocks).cache_shape, cfg.dtype)
+    exe = _compile(
+        jax.jit(llama.resume_chunk.__wrapped__, static_argnames=("config",)),
+        params, s((chunk,), jnp.int32), s((), jnp.int32),
+        [(cache, cache)] * cfg.n_layers, s((table,), jnp.int32),
+        config=cfg,
+    )
+    assert exe.as_text().count("tpu_custom_call") >= cfg.n_layers
 
 
 def test_sharded_decode_compiles_for_four_chips(monkeypatch):

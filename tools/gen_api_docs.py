@@ -90,6 +90,7 @@ SURFACE = [
     ("infinistore_tpu.tpu.paged", None),
     ("infinistore_tpu.tpu.paged_attention", None),
     ("infinistore_tpu.tpu.flash_prefill", None),
+    ("infinistore_tpu.tpu.chunk_attention", None),
     ("infinistore_tpu.tpu.kv_quant", [
         "quantize_kv", "dequantize_kv", "paged_decode_attention_quantized",
         "QuantizedKVConnector", "QuantizingKVAdapter",
