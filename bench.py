@@ -1878,182 +1878,6 @@ def _tpu_connector_gbps(its, np, conn):
     return out
 
 
-def _tpu_decode_attention_us(np) -> dict:
-    """Consumer-side hot op: fused paged decode attention (Pallas) vs the
-    gather+dense XLA path on the TPU backend, Llama-8B-ish decode shape
-    (32 q heads / 8 kv heads / head_dim 128, 4k-token context in 16-token
-    blocks), plus the RAGGED wave leg — variable-length per-request KV in
-    one launch vs the padded-dense rectangle it replaces — on an 8:1
-    length-skew wave.
-
-    Timing discipline, both rules at once: K dispatches CHAINED by data
-    dependency per sample (each call's output is the next call's query —
-    fake-async completion acks cannot shortcut a chain, and dispatch cost
-    amortizes over K), and the A/B pairs sampled as ORDER-ALTERNATING
-    PAIRED interleaved rounds with the min(median-of-per-pair-ratios,
-    ratio-of-interleaved-sums) estimator — this host's ceilings swing ~2x
-    between seconds (the ring/QoS legs' weather rule), so the old
-    separate-block sampling could book a weather period against either
-    kernel; a pair times both inside one window, the order flip keeps
-    cache/loop warmth honest, and min() debiases spikes without hiding a
-    real loss. A losing estimate pools more pairs before it is believed
-    (bounded noise guard); the gates in tools/bench_check.py read the
-    paired keys. These are host-clock times around chained dispatches:
-    comparative figures, not kernel times from a device trace."""
-    import time as _time
-
-    import jax
-    import jax.numpy as jnp
-
-    from infinistore_tpu.tpu.paged_attention import (
-        _paged_decode_attention_pallas,
-        _paged_decode_attention_pallas_batched,
-        _paged_decode_attention_pallas_ragged,
-        _use_pallas,
-        build_ragged_wave,
-        paged_decode_attention_xla,
-        paged_decode_attention_xla_batched,
-    )
-
-    if not _use_pallas():
-        # Off-TPU the dispatcher IS the XLA path; timing it against itself
-        # would report timer noise as a kernel comparison.
-        raise RuntimeError(
-            "decode-attention leg needs the tpu platform, have "
-            f"{jax.default_backend()}"
-        )
-
-    N, bt, kvh, d, h, ntbl = 4096, 16, 8, 128, 32, 256
-    K = 32
-    rng = np.random.default_rng(0)
-    k_cache = jnp.asarray(rng.standard_normal((N, bt, kvh, d)), jnp.bfloat16)
-    v_cache = jnp.asarray(rng.standard_normal((N, bt, kvh, d)), jnp.bfloat16)
-
-    def chained_s(op, q0) -> float:
-        """One sample: K data-chained dispatches, end to end."""
-        qc = q0
-        t0 = _time.perf_counter()
-        for _ in range(K):
-            qc = op(qc)
-        qc.block_until_ready()
-        return _time.perf_counter() - t0
-
-    def paired(op_num, op_den, q_num, q_den, pairs=6, max_pairs=18):
-        """Order-alternating paired rounds; returns (speedup of num over
-        den, num_us, den_us) under the min(median-of-ratios,
-        ratio-of-sums) estimator. Pools more pairs while the estimate
-        reads a num loss — a genuine one will not converge and reports
-        honestly against the gate."""
-        op_num(q_num).block_until_ready()  # compile + warm
-        op_den(q_den).block_until_ready()
-        sums = {"num": 0.0, "den": 0.0}
-        ratios = []
-        flip = [0]
-
-        def one_pair():
-            flip[0] ^= 1
-            order = ("den", "num") if flip[0] else ("num", "den")
-            sample = {}
-            for side in order:
-                sample[side] = chained_s(
-                    op_num if side == "num" else op_den,
-                    q_num if side == "num" else q_den,
-                )
-            for side in ("num", "den"):
-                sums[side] += sample[side]
-            ratios.append(sample["den"] / sample["num"])
-
-        def estimate() -> float:
-            med = sorted(ratios)[len(ratios) // 2]
-            return min(med, sums["den"] / sums["num"])
-
-        for _ in range(pairs):
-            one_pair()
-        while estimate() < 1.0 and len(ratios) < max_pairs:
-            one_pair()
-        n = len(ratios)
-        return (
-            estimate(),
-            sums["num"] / (n * K) * 1e6,
-            sums["den"] / (n * K) * 1e6,
-        )
-
-    # -- wave-1 A/B: the fused kernel must not lose to gather+dense --------
-    q = jnp.asarray(rng.standard_normal((h, d)), jnp.bfloat16)
-    table = jnp.asarray(rng.permutation(N)[:ntbl], jnp.int32)
-    sl = jnp.int32(ntbl * bt)
-    speedup, fused, dense = paired(
-        lambda qc: _paged_decode_attention_pallas(
-            qc, k_cache, v_cache, table, sl, interpret=False
-        ),
-        lambda qc: paged_decode_attention_xla(qc, k_cache, v_cache, table, sl),
-        q,
-        q,
-    )
-
-    # -- wave-8 amortization (one launch vs the vmapped dense wave) --------
-    B = 8
-    qb = jnp.asarray(rng.standard_normal((B, h, d)), jnp.bfloat16)
-    tbls = jnp.asarray(
-        np.stack([rng.permutation(N)[:ntbl] for _ in range(B)]), jnp.int32
-    )
-    sls = jnp.asarray(rng.integers(1, ntbl * bt, size=B), jnp.int32)
-    _, wave, wave_dense = paired(
-        lambda qc: _paged_decode_attention_pallas_batched(
-            qc, k_cache, v_cache, tbls, sls, interpret=False
-        ),
-        lambda qc: paged_decode_attention_xla_batched(
-            qc, k_cache, v_cache, tbls, sls
-        ),
-        qb,
-        qb,
-    )
-
-    # -- ragged A/B: 8:1 length-skew wave vs the padded-dense rectangle ----
-    # One near-max request beside seven short ones: the rectangle pays
-    # B * max(K_i) (every short row padded to the longest), the ragged
-    # kernel walks the flat page list (sum of real pages, tail-bucketed).
-    skew_lens = [ntbl * bt] + [ntbl * bt // 8] * (B - 1)
-    skew_tables = [np.asarray(rng.permutation(N)[:ntbl]) for _ in range(B)]
-    meta = build_ragged_wave(skew_tables, skew_lens, bt, pad_to_pow2=True)
-    rg_pages = jnp.asarray(meta.pages)
-    rg_rows = jnp.asarray(meta.page_rows)
-    rg_starts = jnp.asarray(meta.page_starts)
-    rg_sls = jnp.asarray(meta.seq_lens)
-    skew_tbls = jnp.asarray(np.stack(skew_tables), jnp.int32)
-    ragged_vs_padded, ragged_us, padded_us = paired(
-        lambda qc: _paged_decode_attention_pallas_ragged(
-            qc, k_cache, v_cache, rg_pages, rg_rows, rg_starts, rg_sls,
-            interpret=False,
-        ),
-        lambda qc: paged_decode_attention_xla_batched(
-            qc, k_cache, v_cache, skew_tbls, rg_sls
-        ),
-        qb,
-        qb,
-    )
-    skew_factor = B * max(skew_lens) / sum(skew_lens)
-
-    return {
-        "decode_attn_fused_us": fused,
-        "decode_attn_gather_dense_us": dense,
-        "decode_attn_speedup": speedup,
-        "decode_attn_wave8_us": wave,
-        # The vmapped gather+dense wave materializes B gathers; the fused
-        # kernel's edge over it GROWS with wave size (measured 1.07x at
-        # B=8, 1.36x at B=16 on this host).
-        "decode_attn_wave8_dense_us": wave_dense,
-        "decode_attn_wave8_amortization": B * fused / wave,
-        # The ragged receipt: paired-estimator speedup over padded-dense on
-        # the skewed wave, plus the skew factor (B * max / sum = the
-        # padding multiple the rectangle pays) so the win is attributable.
-        "decode_attn_ragged_us": ragged_us,
-        "decode_attn_padded_dense_us": padded_us,
-        "decode_attn_ragged_vs_padded": ragged_vs_padded,
-        "decode_attn_skew_factor": skew_factor,
-    }
-
-
 def _engine_harness_metrics(its, np) -> dict:
     """BASELINE config 4, engine-shaped: the continuous-batching harness
     drives the connector like a vLLM-TPU-style engine — concurrent requests
@@ -3633,7 +3457,6 @@ def main(argv=None) -> int:
     tpu = None
     if backend == "tpu":
         tpu = _tpu_connector_gbps(its, np, conn)
-        tpu.update(_tpu_decode_attention_us(np))
 
     conn.close()
     srv.stop()
@@ -3772,7 +3595,7 @@ def main(argv=None) -> int:
         "engine_recompute_saved_s": round(engine["recompute_saved_s"], 4),
         "engine_max_live_requests": engine["max_live_requests"],
         # Generation rides lockstep batched waves (engine.py WaveDecoder;
-        # one verify_step_batched per wave) with speculative decoding in
+        # one verify_step_ragged per wave) with speculative decoding in
         # the loop: n-gram drafts verified in mixed waves. tokens/step > 1
         # = speculation is paying; output is greedy-identical (tested).
         "engine_decode_waves": engine["decode_waves"],
@@ -3874,44 +3697,6 @@ def main(argv=None) -> int:
                 "tpu_h2d_per_layer_ms": round(tpu["h2d_per_layer_ms"], 2),
                 "tpu_save_vs_ceiling": round(tpu["save_vs_ceiling"], 3),
                 "tpu_load_vs_ceiling": round(tpu["load_vs_ceiling"], 3),
-            }
-        )
-        # Fused Pallas decode attention vs gather+dense at a 4k context
-        # (tpu/paged_attention.py); the ratio is the comparison.
-        extra.update(
-            {
-                "tpu_decode_attn_fused_us": round(tpu["decode_attn_fused_us"], 1),
-                "tpu_decode_attn_gather_dense_us": round(
-                    tpu["decode_attn_gather_dense_us"], 1
-                ),
-                "tpu_decode_attn_speedup": round(tpu["decode_attn_speedup"], 2),
-                # One launch for 8 requests vs 8 launches: dispatch
-                # amortization of the continuous-batching wave.
-                "tpu_decode_attn_wave8_us": round(tpu["decode_attn_wave8_us"], 1),
-                "tpu_decode_attn_wave8_dense_us": round(
-                    tpu["decode_attn_wave8_dense_us"], 1
-                ),
-                "tpu_decode_attn_wave8_amortization": round(
-                    tpu["decode_attn_wave8_amortization"], 2
-                ),
-                # Ragged wave A/B (tpu/paged_attention.py ragged
-                # kernel): 8:1 length-skew wave vs the padded-dense
-                # rectangle, paired-interleaved estimator; the skew
-                # factor is the padding multiple the rectangle pays.
-                # Gated in tools/bench_check.py (ragged_vs_padded
-                # > 1.0, speedup >= 0.95 at wave 1).
-                "tpu_decode_attn_ragged_us": round(
-                    tpu["decode_attn_ragged_us"], 1
-                ),
-                "tpu_decode_attn_padded_dense_us": round(
-                    tpu["decode_attn_padded_dense_us"], 1
-                ),
-                "tpu_decode_attn_ragged_vs_padded": round(
-                    tpu["decode_attn_ragged_vs_padded"], 2
-                ),
-                "tpu_decode_attn_skew_factor": round(
-                    tpu["decode_attn_skew_factor"], 2
-                ),
             }
         )
         # Present only when the noise guard couldn't converge and the ratio

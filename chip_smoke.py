@@ -47,7 +47,7 @@ REPO = os.path.dirname(os.path.abspath(__file__))
 # differ a hundredfold in size: a one-token context returns a value row
 # (|x| up to ~4), a thousand-token context their average (|x| ~ 0.05).
 #
-# Decode-family kernels (batched, stats, ragged, int8) and their XLA
+# Decode-family kernels (ragged, its stats twin, int8) and their XLA
 # references both do float32 math at HIGHEST precision and round ONCE to the
 # query dtype. They may land on neighbouring bfloat16 values where the
 # float32 sums (accumulated in different orders) straddle a rounding
@@ -245,8 +245,7 @@ def check_kernels(cfg) -> dict:
     from infinistore_tpu.tpu import paged
     from infinistore_tpu.tpu import paged_attention as pa
 
-    for mod in (paged, pa, fp, kq, ca):
-        assert mod._use_pallas(), f"{mod.__name__} would dispatch to XLA here"
+    assert paged._use_pallas(), "the dispatchers would take their XLA branch here"
 
     out = {}
     rng = np.random.default_rng(3)
@@ -298,13 +297,6 @@ def check_kernels(cfg) -> dict:
 
     # Raw (acc, m, l) statistics normalize to the same output.
     unstat = lambda s: (s[0] / jnp.maximum(s[2], 1e-30)).astype(dt)
-    decode_case(
-        "decode_batched", pa._paged_decode_attention_pallas_batched, dense, dense_ref
-    )
-    decode_case(
-        "decode_stats", pa._paged_decode_attention_pallas_stats, dense, dense_ref,
-        unstat,
-    )
     meta = pa.build_ragged_wave(list(tables), lens, bt, pad_to_pow2=True)
     ragged = (
         q, k_cache, v_cache, jnp.asarray(meta.pages), jnp.asarray(meta.page_rows),
@@ -316,6 +308,15 @@ def check_kernels(cfg) -> dict:
     decode_case(
         "decode_ragged_stats", pa._paged_decode_attention_pallas_ragged_stats,
         ragged, dense_ref, unstat,
+    )
+    # The same wave as a rectangle, full-width tables through the in-jit
+    # metadata: what decode_step and the disagg decode layer ride.
+    rect = (
+        q, k_cache, v_cache, *pa.rectangle_as_ragged(jnp.asarray(tables)),
+        jnp.asarray(lens),
+    )
+    decode_case(
+        "decode_rectangle", pa._paged_decode_attention_pallas_ragged, rect, dense_ref
     )
 
     # int8: both sides dequantize the same int8 cache, so the scheme's own
@@ -598,7 +599,8 @@ def check_steps_hold_kernels(cfg, params, sizes: Sizes) -> dict:
     """The three jitted steps the harness runs hold the Mosaic kernels at
     the traffic's shapes: flash prefill and the block scatter in
     ``prefill``, the ragged decode kernel in the wave step, the chunk
-    kernel in the resume of a partial hit."""
+    kernel in the resume of a partial hit. So does the disagg decode
+    layer, which rides the wave step's kernel as a rectangle."""
     from infinistore_tpu.models import llama
     from infinistore_tpu.tpu.paged_attention import build_ragged_wave
 
@@ -618,15 +620,21 @@ def check_steps_hold_kernels(cfg, params, sizes: Sizes) -> dict:
         jnp.asarray(meta.page_starts), caches, i32(1, mrb),
         config=cfg, max_blocks=mrb,
     )
-    assert in_wave == {"_ragged_decode_attn_kernel"}, in_wave
+    assert in_wave == {"_ragged_attn_kernel"}, in_wave
     in_resume = _mosaic_kernels(
         llama.resume_chunk, params, i32(sizes.suffix_tokens), i32(), caches,
         i32(mrb), config=cfg,
     )
     assert in_resume == {"_chunk_attn_kernel"}, in_resume
+    x = jax.ShapeDtypeStruct((2, 1, cfg.dim), cfg.dtype)
+    in_layer = _mosaic_kernels(
+        llama.decode_wave_layer, params, x, i32(2, 1), cache, cache, i32(2, mrb),
+        config=cfg, layer=0, max_blocks=mrb,
+    )
+    assert in_layer == {"_ragged_attn_kernel"}, in_layer
     return {
         "prefill": sorted(in_prefill), "verify_step_ragged": sorted(in_wave),
-        "resume_chunk": sorted(in_resume),
+        "resume_chunk": sorted(in_resume), "decode_wave_layer": sorted(in_layer),
     }
 
 
@@ -738,38 +746,58 @@ def check_four_chips(cfg) -> dict:
     out["ici_handoff"] = {"dst": [1, 2, 3], "blocks": n, "launches_each": 1,
                           "result": "byte-identical, own slices, donated"}
 
-    # -- paged_decode_attention_sharded against the single-chip kernel ------
+    # -- paged_decode_attention_ragged_sharded against the single-chip kernel
     mesh = Mesh(np.array(devs), ("sp",))
-    per, n_local = 128, 32
+    per, n_local, rows = 128, 32, 3
     h, kvh, d = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     k_host, v_host = (
         rng.standard_normal((4 * per, bt, kvh, d), np.float32).astype(np_dt)
         for _ in "kv"
     )
-    q = jnp.asarray(rng.standard_normal((h, d), np.float32), cfg.dtype)
+    q = jnp.asarray(rng.standard_normal((rows, h, d), np.float32), cfg.dtype)
     block_sharded = NamedSharding(mesh, P("sp", None, None, None))
     k_sh, v_sh = (jax.device_put(a, block_sharded) for a in (k_host, v_host))
     _own_slices(k_sh, per)
     _own_slices(v_sh, per)
-    local_tables = np.stack([rng.permutation(per)[:n_local] for _ in range(4)]).astype(np.int32)
-    # Uneven shards, one of them empty; the only partial block is the last.
-    local_lens = np.asarray([n_local * bt, 20 * bt, 0, n_local * bt - 5], np.int32)
-    got = pa.paged_decode_attention_sharded(
-        q, k_sh, v_sh, local_tables, local_lens, mesh=mesh
+    local_tables = np.stack([
+        [rng.permutation(per)[:n_local] for _ in range(rows)] for _ in range(4)
+    ]).astype(np.int32)  # [shard, row, n_local]
+    # Per (shard, row): a long request over uneven shards, one of them empty
+    # (the only partial block is the last shard's last); a request that
+    # lives on one shard; a short one over two.
+    local_lens = np.asarray([
+        [n_local * bt, 0, 3 * bt],
+        [20 * bt, 0, 7],
+        [0, 0, 0],
+        [n_local * bt - 5, 17 * bt + 3, 0],
+    ], np.int32)
+    pages, page_rows, page_starts, lens, width = pa.build_ragged_wave_sharded(
+        local_tables, local_lens, bt
+    )
+    got = pa.paged_decode_attention_ragged_sharded(
+        q, k_sh, v_sh, pages, page_rows, page_starts, lens,
+        mesh=mesh, table_width=width,
     )
     assert got.sharding.is_fully_replicated
     assert len({s.device for s in got.addressable_shards}) == 4
-    table = np.concatenate([
-        local_tables[p, : -(-int(local_lens[p]) // bt)] + p * per for p in range(4)
-    ])
-    table = np.pad(table, (0, 4 * n_local - len(table)))
+    # One chip, the whole cache: each row's global table is its shards'
+    # valid pages in shard order (every shard but a row's last is whole
+    # blocks, so the concatenation is the row's context).
+    tables = [
+        np.concatenate([
+            local_tables[p, r, : -(-int(local_lens[p, r]) // bt)] + p * per
+            for p in range(4)
+        ])
+        for r in range(rows)
+    ]
+    meta = pa.build_ragged_wave(tables, local_lens.sum(axis=0), bt)
     one = lambda a: jax.device_put(a, devs[0])
-    ref = pa._paged_decode_attention_pallas(
-        one(q), one(k_host), one(v_host), one(table.astype(np.int32)),
-        jnp.int32(int(local_lens.sum())), interpret=False,
+    ref = pa._paged_decode_attention_pallas_ragged(
+        one(q), one(k_host), one(v_host), one(meta.pages), one(meta.page_rows),
+        one(meta.page_starts), one(meta.seq_lens), interpret=False,
     )
     out["sharded_decode"] = _check_rows(
-        "sharded decode, 4 chips vs 1", one(got)[None], ref[None], 0, DECODE_ULPS
+        "ragged sharded decode, 4 chips vs 1", one(got), ref, 0, DECODE_ULPS
     )
     for name, rec in out.items():
         print(f"  four chips {name}: {rec}", flush=True)

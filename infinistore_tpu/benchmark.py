@@ -54,11 +54,7 @@ def parse_args(argv=None):
         "--wave", type=int, default=0,
         help="also measure admission-wave read coalescing (N concurrent "
              "requests' reads issued as N separate calls vs merged into one "
-             "— the FetchCoalescer mechanism; connector.py) AND the "
-             "decode-wave cost: one ragged attention launch for an N-request "
-             "length-skewed wave vs the padded-dense rectangle "
-             "(tpu/paged_attention.py; same estimator as bench.py's decode "
-             "leg)",
+             "— the FetchCoalescer mechanism; connector.py)",
     )
     p.add_argument(
         "--trace", default=None, metavar="FILE|PRESET",
@@ -191,91 +187,6 @@ def _measure_wave_coalescing(conn, keys, offsets, block_size, dst, wave: int) ->
         "wave_split_mb_s": round(moved_mb / best_split, 2),
         "wave_merged_mb_s": round(moved_mb / best_merged, 2),
         "wave_coalescing_gain": round(best_split / best_merged, 3),
-    }
-
-
-def _measure_decode_wave(wave: int) -> dict:
-    """Decode-wave cost on the CONSUME side of the store: one ragged
-    attention launch for a ``wave``-request, 8:1 length-skewed wave vs the
-    padded-dense rectangle the engine's WaveDecoder used to assemble
-    (every row padded to the wave max). Uses the same paged shapes and the
-    same order-alternating paired interleaved sampling with the
-    min(median-of-ratios, ratio-of-sums) estimator as ``bench.py``'s
-    decode-attention leg, so this CLI harness and the bench agree on what
-    a wave costs. Off-TPU both paths lower to the same XLA gather (the
-    ragged fallback reconstructs rectangular tables), so the gain reads
-    ~1.0 there by construction; the ragged win is a TPU-kernel property.
-    Returns {} when jax is unavailable."""
-    try:
-        import jax.numpy as jnp
-
-        from .tpu.paged_attention import (
-            build_ragged_wave,
-            paged_decode_attention_batched,
-            paged_decode_attention_ragged,
-        )
-    except ImportError:
-        return {}
-    compile_cache.enable()
-
-    n, bt, kvh, d, h, ntbl = 256, 16, 2, 64, 8, 16
-    wave = max(2, wave)
-    rng = np.random.default_rng(0)
-    k_cache = jnp.asarray(rng.standard_normal((n, bt, kvh, d)), jnp.float32)
-    v_cache = jnp.asarray(rng.standard_normal((n, bt, kvh, d)), jnp.float32)
-    q = jnp.asarray(rng.standard_normal((wave, h, d)), jnp.float32)
-    lens = [ntbl * bt] + [ntbl * bt // 8] * (wave - 1)
-    tables = [np.asarray(rng.permutation(n)[:ntbl]) for _ in range(wave)]
-    meta = build_ragged_wave(tables, lens, bt, pad_to_pow2=True)
-    tbls = jnp.asarray(np.stack(tables), jnp.int32)
-    sls = jnp.asarray(meta.seq_lens)
-    pages = jnp.asarray(meta.pages)
-    rows = jnp.asarray(meta.page_rows)
-    starts = jnp.asarray(meta.page_starts)
-
-    def ragged(qc):
-        return paged_decode_attention_ragged(
-            qc, k_cache, v_cache, pages, rows, starts, sls, table_width=ntbl
-        )
-
-    def padded(qc):
-        return paged_decode_attention_batched(qc, k_cache, v_cache, tbls, sls)
-
-    reps = 8
-    ragged(q).block_until_ready()  # compile + warm
-    padded(q).block_until_ready()
-
-    def sample(op) -> float:
-        qc = q
-        t0 = time.perf_counter()
-        for _ in range(reps):
-            qc = op(qc)
-        qc.block_until_ready()
-        return time.perf_counter() - t0
-
-    sums = {"ragged": 0.0, "padded": 0.0}
-    ratios = []
-    for i in range(6):
-        order = (
-            ("ragged", "padded") if i % 2 else ("padded", "ragged")
-        )
-        s = {}
-        for side in order:
-            s[side] = sample(ragged if side == "ragged" else padded)
-        for side in s:
-            sums[side] += s[side]
-        ratios.append(s["padded"] / s["ragged"])
-    med = sorted(ratios)[len(ratios) // 2]
-    gain = min(med, sums["padded"] / sums["ragged"])
-    pairs = len(ratios)
-    return {
-        "wave_decode_requests": wave,
-        "wave_decode_skew_factor": round(
-            wave * max(lens) / sum(lens), 2
-        ),
-        "wave_decode_ragged_us": round(sums["ragged"] / (pairs * reps) * 1e6, 1),
-        "wave_decode_padded_us": round(sums["padded"] / (pairs * reps) * 1e6, 1),
-        "wave_decode_ragged_gain": round(gain, 3),
     }
 
 
@@ -462,13 +373,6 @@ def run(args) -> dict:
             result["coalescing"] = _measure_wave_coalescing(
                 conn, keys, offsets, block_size, dst, args.wave
             )
-        if args.wave > 1:
-            # The consume-side half of the wave story: what the DECODE
-            # launch for this wave costs through the ragged path vs the
-            # padded rectangle (same estimator as bench.py's decode leg).
-            decode = _measure_decode_wave(args.wave)
-            if decode:
-                result["decode_wave"] = decode
         if args.type == "rdma":
             # Wakeup coalescing over the whole run (native ring pushes vs
             # eventfd signals; >1 means pipelined ops shared loop wakes).

@@ -12,13 +12,11 @@ step to compile.
 from .llama import (
     LlamaConfig,
     decode_step,
-    decode_step_batched,
     decode_wave_layer,
     embed_prompt,
     embed_wave,
     lm_logits,
     prefill_layer,
-    verify_step_batched,
     verify_step_ragged,
     init_params,
     loss_fn,
@@ -42,8 +40,6 @@ __all__ = [
     "decode_wave_layer",
     "speculative_verify",
     "decode_step",
-    "decode_step_batched",
-    "verify_step_batched",
     "verify_step_ragged",
     "loss_fn",
     "train_step",

@@ -27,8 +27,8 @@ from ..tpu.chunk_attention import chunk_prefix_attention
 from ..tpu.flash_prefill import flash_prefill_attention
 from ..tpu.paged import PagedKVCacheSpec, scatter_blocks
 from ..tpu.paged_attention import (
-    paged_decode_attention_batched,
     paged_decode_attention_rows,
+    rectangle_as_ragged,
 )
 
 Params = Dict[str, jax.Array]
@@ -222,9 +222,11 @@ def _kv_proj(params: Params, layer: int, x, positions, config):
 
 
 # ---------------------------------------------------------------------------
-# Paged-cache inference. Batch = 1 sequence per call (engine loops/vmaps);
-# the cache is shared across sequences via the block table, exactly the
-# paged-attention model the store serves.
+# Paged-cache inference. The serving entries are ``prefill`` (a miss),
+# ``resume_chunk`` (a prefix hit's chunk) and ``verify_step_ragged`` (a decode
+# wave); ``decode_step``, ``prefill_continue`` and ``speculative_verify`` are
+# views over them. The cache is shared across sequences via the block
+# tables, exactly the paged-attention model the store serves.
 # ---------------------------------------------------------------------------
 
 
@@ -277,100 +279,30 @@ def decode_step(
     (tpu/paged_attention.py: on TPU each context block crosses HBM exactly
     once — no materialized gather; gather+dense XLA elsewhere, same f32
     softmax contract). ``max_blocks`` must equal the padded block_table
-    length (validated at trace time — a mismatch fails loudly, as the old
-    gather-and-reshape path did). Returns (logits, caches).
+    length (validated at trace time — a mismatch fails loudly). Returns
+    (logits, caches).
 
-    This is the B=1 wrapper over ``decode_step_batched`` — one decode body
-    to maintain, mirroring the same pattern in tpu/paged_attention.py."""
+    The one-row, one-token view of ``verify_step_ragged`` — one decode body
+    to maintain. The table rides the wave as a rectangle of one row
+    (``rectangle_as_ragged``): entries past the sequence fold fully masked."""
     if block_table.shape[0] != max_blocks:
         raise ValueError(
             f"block_table has {block_table.shape[0]} entries, expected "
             f"max_blocks={max_blocks} (pad the table to the static bound)"
         )
-    logits, new_caches = decode_step_batched(
+    block_tables = block_table[None]
+    logits, new_caches = verify_step_ragged(
         params,
         token[None],
         position[None],
+        jnp.zeros((1,), jnp.int32),
+        *rectangle_as_ragged(block_tables),
         caches,
-        block_table[None],
+        block_tables,
         config,
         max_blocks,
     )
     return logits[0], new_caches
-
-
-@functools.partial(jax.jit, static_argnames=("config", "max_blocks"))
-def verify_step_batched(
-    params: Params,
-    tokens: jax.Array,  # [B, K] int32, one token chunk per live request
-    positions: jax.Array,  # [B, K] int32 absolute position of each token
-    caches: Caches,  # SHARED paged cache across the wave
-    block_tables: jax.Array,  # [B, max_blocks] int32 (rows padded)
-    config: LlamaConfig,
-    max_blocks: int,
-) -> Tuple[jax.Array, Caches]:
-    """THE paged-inference body: a wave of B requests each advancing a
-    K-token chunk against the shared cache in one launch per layer.
-
-    The rectangular entry points are views of this: K=1 is batched decode
-    (``decode_step_batched``, ``decode_step``), and B>1 with K>1 is a MIXED
-    wave — some requests decoding one token, others verifying drafts. The
-    serving engine runs neither: its waves are ragged
-    (``verify_step_ragged``) and a prefix hit's chunk is a program of its
-    own (``resume_chunk``, behind ``prefill_continue`` and
-    ``speculative_verify``), which reads the request's pages once where
-    B=1, K>1 here walks the padded table once a row.
-
-    Each row inserts its K/V at (table[pos // bt], pos % bt), then one
-    batched fused attention launch covers all B*K rows, each masked to its
-    own position + 1 (tpu/paged_attention.py). Requests own disjoint
-    blocks (the engine's block-table manager guarantees it); duplicate
-    rows WITHIN a request (wave/chunk padding that repeats a row) write
-    identical bytes and are therefore value-safe. Rows may attend sibling
-    rows' K/V within the chunk: inserts complete before attention, and
-    per-row masking keeps causality. Returns ([B, K, vocab] logits,
-    updated caches)."""
-    bsz, kk = tokens.shape
-    if block_tables.shape != (bsz, max_blocks):
-        raise ValueError(
-            f"block_tables must be [{bsz}, {max_blocks}] (one padded row per "
-            f"request), got {block_tables.shape}"
-        )
-    if positions.shape != (bsz, kk):
-        raise ValueError(
-            f"positions must match tokens' [{bsz}, {kk}], got {positions.shape}"
-        )
-    bt = config.block_tokens
-    x = jnp.take(params["embed"], tokens, axis=0)  # [B, K, dim]
-
-    flat_pos = positions.reshape(-1)  # [B*K]
-    block_idx = jnp.take_along_axis(
-        block_tables, positions // bt, axis=1
-    ).reshape(-1)  # [B*K]
-    slots = flat_pos % bt
-    row_tables = jnp.repeat(block_tables, kk, axis=0)  # [B*K, max_blocks]
-
-    new_caches: Caches = []
-    for layer, (k_cache, v_cache) in enumerate(caches):
-        k, v = _kv_proj(params, layer, x, positions, config)  # [B, K, KVH, D]
-        k_cache = k_cache.at[block_idx, slots].set(
-            k.reshape(bsz * kk, *k.shape[2:]).astype(k_cache.dtype)
-        )
-        v_cache = v_cache.at[block_idx, slots].set(
-            v.reshape(bsz * kk, *v.shape[2:]).astype(v_cache.dtype)
-        )
-        pre = f"l{layer}."
-        q = _q_proj(params, layer, x, positions, config)  # [B, K, H, D]
-        attn = paged_decode_attention_batched(
-            q.reshape(bsz * kk, *q.shape[2:]), k_cache, v_cache,
-            row_tables, flat_pos + 1,
-        ).reshape(bsz, kk, *q.shape[2:])  # [B, K, H, D]
-        x = x + jnp.einsum("bshk,hkd->bsd", attn, params[pre + "wo"])
-        x = _ffn(params, layer, x, config)
-        new_caches.append((k_cache, v_cache))
-    x = _rms_norm(x, params["final_norm"])
-    logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"])
-    return logits, new_caches
 
 
 @functools.partial(jax.jit, static_argnames=("config", "max_blocks"))
@@ -387,29 +319,34 @@ def verify_step_ragged(
     config: LlamaConfig,
     max_blocks: int,
 ) -> Tuple[jax.Array, Caches]:
-    """The RAGGED form of ``verify_step_batched``: a mixed wave where
-    request chunks keep their OWN lengths — the wave is one flat [T] token
-    list (T = sum of chunk lengths) with per-token request/page metadata,
-    instead of a [B, K] rectangle padded to the widest chunk.
+    """THE paged-inference wave body: a wave of requests, each advancing a
+    chunk of its OWN length against the shared cache, in one launch per
+    layer. The wave is one flat [T] token list (T = sum of chunk lengths)
+    with per-token request/page metadata — no [B, K] rectangle padded to
+    the widest chunk. The serving engine's decode waves run this; one
+    decode token (``decode_step``) is its one-row view, and the disagg
+    decode layer (``decode_wave_layer``) is one layer of the same math.
+    A prefix hit's chunk is a program of its own (``resume_chunk``), which
+    reads the request's pages once for all its rows.
 
-    Why it exists: the rectangular wave pays B x max(K_i) rows per launch
-    (a lone 8-token verification chunk makes every decoding request pad
-    7 duplicate rows), and its attention grid scans max_blocks table
-    entries per row. Here the launch covers exactly the real rows (plus
-    tail-bucket padding that repeats the LAST flat row — same-bytes
-    scatter, value-safe like the rectangular padding, but one row instead
-    of a rectangle), and on TPU the attention walks the flat page list
-    (tpu/paged_attention.py ragged kernel): sum(ceil((pos_t + 1) / bt))
-    block folds, no padding to the wave max.
+    Each flat token inserts its K/V at (table[pos // bt], pos % bt), then
+    one attention launch covers all T rows, each masked to its own
+    position + 1 (tpu/paged_attention.py paged_decode_attention_rows; on
+    TPU the ragged kernel walks the flat page list: sum(ceil((pos_t + 1) /
+    bt)) block folds, no padding to the wave max). Requests own disjoint
+    blocks (the engine's block-table manager guarantees it). Tail-bucket
+    padding repeats the LAST flat row: a same-bytes scatter, value-safe.
+    Rows may attend sibling rows' K/V within a chunk: inserts complete
+    before attention, and per-row masking keeps causality.
 
-    Per-token semantics are IDENTICAL to ``verify_step_batched`` — each
-    flat token inserts its K/V at (table[pos // bt], pos % bt) and attends
-    its own prefix masked to pos + 1 — so a mixed ragged wave equals
-    per-request sequential decode byte-for-byte on the cache and the
-    logits (pinned by the engine tests). ``block_tables`` rows beyond the
-    real requests (bucket padding) are never referenced by any flat token:
-    a padded WAVE ROW no longer scatters or attends at all, it is simply
-    absent. Returns ([T, vocab] logits, updated caches)."""
+    A mixed ragged wave equals per-request sequential decode on the cache
+    and the logits to float32 rounding on a float32 model (the tolerance
+    the engine and kernel tests write down; XLA is free to fuse a wave and
+    a single row differently, so no bitwise claim across batch shapes).
+    ``block_tables`` rows beyond the real requests (bucket padding) are
+    never referenced by any flat token: a padded WAVE ROW neither scatters
+    nor attends, it is simply absent. Returns ([T, vocab] logits, updated
+    caches)."""
     t = tokens.shape[0]
     if positions.shape != (t,) or row_of.shape != (t,):
         raise ValueError(
@@ -593,47 +530,17 @@ def speculative_verify(
     return n_accepted, next_token, caches
 
 
-def decode_step_batched(
-    params: Params,
-    tokens: jax.Array,  # [B] int32, one next-token per live request
-    positions: jax.Array,  # [B] int32 absolute position of each token
-    caches: Caches,  # SHARED paged cache across the wave
-    block_tables: jax.Array,  # [B, max_blocks] int32 (rows padded)
-    config: LlamaConfig,
-    max_blocks: int,
-) -> Tuple[jax.Array, Caches]:
-    """One decode step for a WAVE of requests sharing the paged cache — the
-    continuous-batching engine's inner loop (every live request advances one
-    token per step). Per-token semantics are identical to ``decode_step``
-    (tested); the win is paying the model's dispatch and kernel-launch cost
-    once per wave instead of once per request. Returns ([B, vocab] logits,
-    updated caches).
-
-    This is the K=1 view of ``verify_step_batched`` — one inference body
-    to maintain."""
-    logits, new_caches = verify_step_batched(
-        params,
-        tokens[:, None],
-        positions[:, None],
-        caches,
-        block_tables,
-        config,
-        max_blocks,
-    )
-    return logits[:, 0], new_caches
-
-
 # ---------------------------------------------------------------------------
 # Layerwise inference entry points (disaggregated prefill -> decode handoff,
-# docs/disaggregation.md). The monolithic ``prefill``/``verify_step_batched``
+# docs/disaggregation.md). The monolithic ``prefill``/``verify_step_ragged``
 # bodies are re-expressed one layer per jitted call so a prefill engine can
 # SHIP layer l's KV while layer l+1 computes, and a decode engine can gate
 # each layer's attention on that layer's install alone (the watermark rule).
 # Both handoff directions — streamed prefill and the fallback recompute —
 # use THESE functions, and the watermarked and blocking decode paths share
-# ``decode_wave_layer``, so "overlapped equals blocking byte-for-byte" holds
-# by construction regardless of how XLA fuses across the per-layer
-# boundaries.
+# ``decode_wave_layer``: the same compiled program a layer on both paths,
+# so "overlapped equals blocking" holds by construction regardless of how
+# XLA fuses across the per-layer boundaries.
 # ---------------------------------------------------------------------------
 
 
@@ -701,14 +608,19 @@ def decode_wave_layer(
     layer: int,
     max_blocks: int,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """One layer of ``verify_step_batched``'s wave body: insert the wave's
-    K/V at (table[pos // bt], pos % bt), fused paged attention over this
-    layer's cache, residual + FFN. Returns ``(x_next, k_cache, v_cache)``.
+    """One layer of ``verify_step_ragged``'s wave body, for a RECTANGULAR
+    wave (B requests, K tokens each): insert the wave's K/V at
+    (table[pos // bt], pos % bt), paged attention over this layer's cache
+    through the same dispatcher (the [B, K] rows ride it as a rectangle,
+    ``rectangle_as_ragged``), residual + FFN. Returns ``(x_next, k_cache,
+    v_cache)``. Chained over the layers between ``embed_wave`` and
+    ``lm_logits`` it equals ``verify_step_ragged`` on the same wave to
+    float32 rounding (tested).
 
     The watermark-gated decode admission (disagg.py) calls this only after
     THIS layer's prefix KV installed — layer l's attention never reads
     bytes still in flight — and the blocking fetch-all path chains the same
-    function, so the two paths agree byte-for-byte on logits and caches."""
+    function, so the two paths run the same programs on the same inputs."""
     bsz, kk = positions.shape
     if block_tables.shape != (bsz, max_blocks):
         raise ValueError(
@@ -731,9 +643,9 @@ def decode_wave_layer(
     )
     pre = f"l{layer}."
     q = _q_proj(params, layer, x, positions, config)
-    attn = paged_decode_attention_batched(
+    attn = paged_decode_attention_rows(
         q.reshape(bsz * kk, *q.shape[2:]), k_cache, v_cache,
-        row_tables, flat_pos + 1,
+        row_tables, flat_pos + 1, *rectangle_as_ragged(row_tables),
     ).reshape(bsz, kk, *q.shape[2:])
     x = x + jnp.einsum("bshk,hkd->bsd", attn, params[pre + "wo"])
     x = _ffn(params, layer, x, config)

@@ -31,12 +31,8 @@ from .paged_attention import (
     RaggedWaveMeta,
     build_ragged_wave,
     build_ragged_wave_sharded,
-    paged_decode_attention,
-    paged_decode_attention_batched,
     paged_decode_attention_ragged,
     paged_decode_attention_ragged_sharded,
-    paged_decode_attention_sharded,
-    paged_decode_attention_xla,
 )
 from .staging import HostStagingPool, StagedTransfer
 from .layerwise import (
@@ -59,12 +55,8 @@ __all__ = [
     "RaggedWaveMeta",
     "build_ragged_wave",
     "build_ragged_wave_sharded",
-    "paged_decode_attention",
-    "paged_decode_attention_batched",
     "paged_decode_attention_ragged",
     "paged_decode_attention_ragged_sharded",
-    "paged_decode_attention_sharded",
-    "paged_decode_attention_xla",
     "HostStagingPool",
     "StagedTransfer",
     "PagedKVCacheSpec",

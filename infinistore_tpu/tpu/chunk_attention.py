@@ -4,9 +4,9 @@ A prefix hit leaves the request's context in the paged cache and a suffix
 chunk (the question) to compute: S_c queries at positions ``start_pos ..
 start_pos + S_c - 1`` of ONE request, each attending that request's pages up
 to its own position (the chunk's K/V are inserted before attention). Run as
-S_c decode rows (``paged_decode_attention_batched`` over a table repeated
-once a row) every row walks the padded table again: S_c x max_blocks grid
-steps a layer, each a DMA of one page and two dots a few rows tall — bound by
+S_c decode rows (the decode kernel of ``paged_attention.py``, one row a
+token) every row walks the request's pages again: S_c walks a layer, each
+grid step a DMA of one page and two dots a few rows tall — bound by
 step overhead, not by bytes or FLOPs. Here the chunk walks the request's
 pages ONCE: the scalar-prefetched block table drives the K/V index maps as in
 the decode kernels (pages are read in place, nothing is gathered), several
@@ -35,6 +35,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from . import paged
 
 _NEG_INF = -1e30
 # Tokens folded per grid step: 8 pages of 16 tokens, a page per operand, so
@@ -245,10 +247,6 @@ def chunk_prefix_attention_xla(q, k_cache, v_cache, block_table, start_pos):
     return out.reshape(s, h, d).astype(q.dtype)
 
 
-def _use_pallas() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def chunk_prefix_attention(q, k_cache, v_cache, block_table, start_pos):
     """Attention of ONE request's suffix chunk over its paged context.
 
@@ -260,7 +258,7 @@ def chunk_prefix_attention(q, k_cache, v_cache, block_table, start_pos):
     Row r attends positions ``0 .. start_pos + r``. Returns [S_c, n_heads,
     head_dim] in q's dtype. One walk over the request's pages on TPU, gather
     + dense XLA elsewhere."""
-    if _use_pallas():
+    if paged._use_pallas():
         return _chunk_prefix_attention_pallas(
             q, k_cache, v_cache, block_table, start_pos, interpret=False
         )

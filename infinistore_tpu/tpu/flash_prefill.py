@@ -35,6 +35,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import paged
+
 _NEG_INF = -1e30
 
 
@@ -253,10 +255,6 @@ def flash_prefill_xla(q, k, v, *, causal=True):
     return out.astype(q.dtype)
 
 
-def _use_pallas() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def flash_prefill_attention(q, k, v, *, causal=True, block_q=256, block_k=256):
     """Prefill attention without materializing S x T logits.
 
@@ -282,7 +280,7 @@ def flash_prefill_attention(q, k, v, *, causal=True, block_q=256, block_k=256):
             f"equal T (got S={q.shape[1]}, T={k.shape[1]}); offset suffix "
             "chunks would be over-masked"
         )
-    if _use_pallas():
+    if paged._use_pallas():
         return _flash_prefill_pallas(
             q, k, v, causal=causal, block_q=block_q, block_k=block_k,
             interpret=False,

@@ -30,6 +30,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .. import wire
+from . import paged
 
 
 @jax.jit
@@ -72,18 +73,19 @@ def _quant_decode_kernel(
     l_scr,  # VMEM [H, 128] f32
     acc_scr,  # VMEM [H, D] f32
 ):
-    from .paged_attention import _attn_block_update
+    from .paged_attention import _attn_block_fold
 
     del table_ref
     b = pl.program_id(0)
     i = pl.program_id(1)
     # Dequantize in VMEM — the HBM read was int8 width — then delegate to
     # the SAME online-softmax update the float kernels use (one copy of the
-    # numeric contract, paged_attention.py).
-    _attn_block_update(
-        b,
+    # numeric contract, paged_attention.py). This grid is (row, block in
+    # row), so the grid step is the block index.
+    _attn_block_fold(
+        i == 0,
         i,
-        seqlen_ref,
+        seqlen_ref[b],
         q_ref[0].astype(jnp.float32),
         k_ref[0].astype(jnp.float32) * ks_ref[0][..., None],
         v_ref[0].astype(jnp.float32) * vs_ref[0][..., None],
@@ -329,10 +331,6 @@ class QuantizedKVConnector:
         return self.data.get_stats()
 
 
-def _use_pallas() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def paged_decode_attention_quantized(
     q, k_data, k_scales, v_data, v_scales, block_tables, seq_lens
 ):
@@ -345,7 +343,7 @@ def paged_decode_attention_quantized(
     VMEM; outputs equal attention over the dequantized cache to f32
     rounding (the quantization error itself is the int8 scheme's, measured
     in tests)."""
-    if _use_pallas():
+    if paged._use_pallas():
         return _quant_decode_pallas(
             q, k_data, k_scales, v_data, v_scales, block_tables, seq_lens,
             interpret=False,

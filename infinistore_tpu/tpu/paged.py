@@ -153,6 +153,11 @@ def _scatter_blocks_pallas(cache, block_ids, blocks, *, interpret):
 
 
 def _use_pallas() -> bool:
+    """Whether the dispatchers of this package take their Pallas branch: on
+    the chip, never elsewhere (the kernels run off it only in interpret
+    mode, under test). The one copy: flash_prefill, chunk_attention,
+    paged_attention and kv_quant ask this module, and a test that steers
+    the dispatch patches this name."""
     return jax.default_backend() == "tpu"
 
 

@@ -1,6 +1,6 @@
-"""Fused paged decode attention: one query token attending over the paged KV
-cache, computed block-by-block with an online softmax — no materialized
-context.
+"""Fused paged decode attention: a wave of query rows, each attending its own
+pages of the paged KV cache, computed block-by-block with an online softmax —
+no materialized context.
 
 This is the hot op on the consumer side of the store. The engine resumes a
 request from fetched cache blocks and then decodes token-by-token; every
@@ -9,17 +9,27 @@ then dense attention) moves each context block HBM->HBM into a contiguous
 buffer and then reads it again for attention — every cached byte crosses HBM
 three times per token. Decode attention does O(1) FLOPs per byte, so it is
 purely HBM-bandwidth-bound and that 3x is the whole cost. The fused kernel
-reads each block exactly once: the scalar-prefetched block table drives the
-BlockSpec index maps (the pipeline DMAs cache[table[i]] directly into VMEM,
-double-buffering consecutive blocks), and a flash-style running
+reads each block exactly once: the scalar-prefetched flat page list drives
+the BlockSpec index maps (the pipeline DMAs cache[pages[i]] directly into
+VMEM, double-buffering consecutive blocks), and a flash-style running
 (max, sum, acc) in VMEM scratch folds each block into the softmax as it
 arrives. The reference never needed this op — CUDA engines bring their own
 paged attention (vLLM) and the store hands them raw pointers; on TPU the
 engine-side kernel is part of the framework's job.
 
-GQA layout: q is [n_heads, head_dim] against caches of n_kv_heads; the
-kernel unrolls over kv heads and issues one MXU dot per (kv head, block) —
-no batched dot_general, which Mosaic handles unevenly at small shapes.
+ONE kernel family: the ragged one (a flat grid over the wave's concatenated
+page lists, with a raw-statistics twin for context sharded over a mesh).
+How a decode row attends its pages is decided in this module alone: the
+model's wave body, its one-token view and the disagg decode layer all call
+:func:`paged_decode_attention_rows`; a caller that holds a rectangle
+(``[B, M]`` tables) describes it as a ragged wave with
+:func:`rectangle_as_ragged`. The two XLA bodies are the fallback off the
+chip and the tests' reference.
+
+GQA layout: each query row is [n_heads, head_dim] against caches of
+n_kv_heads; the kernel unrolls over kv heads and issues one MXU dot per
+(kv head, block) — no batched dot_general, which Mosaic handles unevenly at
+small shapes.
 
 Numerical contract (shared with the XLA fallback and the dense oracle in
 models/llama.py): logits and softmax statistics in float32, output cast to
@@ -36,12 +46,14 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import paged
+
 _NEG_INF = -1e30
 
 
 def _varying_like(shape, dtype, *operands):
     """``out_shape`` entry for a pallas_call that also runs inside
-    ``shard_map`` (the stats kernels, under the sharded decode entries): the
+    ``shard_map`` (the stats kernel, under the sharded decode entry): the
     output varies over every mesh axis an operand varies over, and jax's
     varying-axes typing requires the kernel to say so. Outside shard_map the
     set is empty."""
@@ -49,20 +61,11 @@ def _varying_like(shape, dtype, *operands):
     return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
-def _attn_block_update(b, i, seqlen_ref, q, k, v, m_scr, l_scr, acc_scr):
-    """One grid step of the online softmax on the RECTANGULAR (B, n) grid:
-    fold cache block ``i`` of request ``b`` into the running scratch. Thin
-    wrapper over :func:`_attn_block_fold` kept for the callers whose grid
-    coordinates ARE the (request, block-in-request) pair — the dense-wave
-    kernels here and the int8 kernel (kv_quant.py, which dequantizes in
-    VMEM first)."""
-    _attn_block_fold(i == 0, i, seqlen_ref[b], q, k, v, m_scr, l_scr, acc_scr)
-
-
 def _attn_block_fold(first, j, seq_len, q, k, v, m_scr, l_scr, acc_scr):
     """Fold ONE cache block into the running (max, denominator, accumulator)
     scratch — the single copy of the online-softmax numeric contract every
-    decode kernel shares (dense-wave, ragged, stats, int8).
+    decode kernel shares (ragged, its stats twin, and kv_quant.py's int8
+    kernel, which dequantizes in VMEM first).
 
     ``first``: traced bool — this is the request's first block, reset the
     accumulators. ``j``: block index WITHIN the request (the ragged grid is
@@ -75,8 +78,9 @@ def _attn_block_fold(first, j, seq_len, q, k, v, m_scr, l_scr, acc_scr):
 
     A fully-masked block is a BITWISE no-op on the scratch (alpha = exp(0)
     = 1, every p zeroed, l and acc multiplied by 1.0 and incremented by
-    0.0), which is what lets the ragged layout pad its flat page list and
-    the dense layout pad its tables without changing a single output bit."""
+    0.0), which is what lets the ragged layout pad its flat page list, and
+    a rectangle ride it with every row's table at full width
+    (rectangle_as_ragged), without changing a single output bit."""
     h, d = q.shape
     bt, kvh = k.shape[0], k.shape[1]
     groups = h // kvh
@@ -137,167 +141,12 @@ def _attn_block_fold(first, j, seq_len, q, k, v, m_scr, l_scr, acc_scr):
     acc_scr[...] = acc_scr[...] * alpha + pv
 
 
-def _decode_attn_kernel(
-    table_ref,  # scalar-prefetch: [B, max_blocks] int32 (drives DMA)
-    seqlen_ref,  # scalar-prefetch: [B] int32 valid context lengths
-    q_ref,  # [1, H, D] query dtype (this request's query)
-    k_ref,  # [1, bt, KVH, D] one cache block
-    v_ref,  # [1, bt, KVH, D]
-    out_ref,  # [1, H, D]
-    m_scr,  # VMEM [H, 128] f32 running max (broadcast across lanes)
-    l_scr,  # VMEM [H, 128] f32 running denominator
-    acc_scr,  # VMEM [H, D] f32 running numerator
-):
-    del table_ref
-    b = pl.program_id(0)
-    i = pl.program_id(1)
-    _attn_block_update(
-        b,
-        i,
-        seqlen_ref,
-        q_ref[0].astype(jnp.float32),
-        k_ref[0].astype(jnp.float32),
-        v_ref[0].astype(jnp.float32),
-        m_scr,
-        l_scr,
-        acc_scr,
-    )
-
-    @pl.when(i == pl.num_programs(1) - 1)
-    def _finish():
-        # max(l, tiny): for any non-empty row l >= 1 (the max logit's exp),
-        # so this only changes the seq_len == 0 case — which must yield
-        # zeros, not 0/0 NaN (contract shared with the XLA fallback).
-        out_ref[0] = (
-            acc_scr[...] / jnp.maximum(l_scr[:, :1], 1e-30)
-        ).astype(out_ref.dtype)
-
-
-def _decode_attn_stats_kernel(
-    table_ref,
-    seqlen_ref,
-    q_ref,
-    k_ref,
-    v_ref,
-    acc_ref,  # [1, H, D] f32 UNNORMALIZED numerator
-    m_ref,  # [1, H, 128] f32 running max (lane-broadcast)
-    l_ref,  # [1, H, 128] f32 denominator (lane-broadcast)
-    m_scr,
-    l_scr,
-    acc_scr,
-):
-    """Same online softmax, but emits the raw (acc, m, l) statistics instead
-    of normalizing — the shard-local half of sharded decode attention, whose
-    cross-shard combine rescales by the global max and sums."""
-    del table_ref
-    b = pl.program_id(0)
-    i = pl.program_id(1)
-    _attn_block_update(
-        b,
-        i,
-        seqlen_ref,
-        q_ref[0].astype(jnp.float32),
-        k_ref[0].astype(jnp.float32),
-        v_ref[0].astype(jnp.float32),
-        m_scr,
-        l_scr,
-        acc_scr,
-    )
-
-    @pl.when(i == pl.num_programs(1) - 1)
-    def _finish():
-        acc_ref[0] = acc_scr[...]
-        m_ref[0] = m_scr[...]
-        l_ref[0] = l_scr[...]
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _paged_decode_attention_pallas_batched(
-    q, k_cache, v_cache, block_tables, seq_lens, *, interpret
-):
-    """q: [B, H, D]; block_tables: [B, max_blocks]; seq_lens: [B]."""
-    bsz, h, d = q.shape
-    _, bt, kvh, _ = k_cache.shape
-    n = block_tables.shape[1]
-    block = (1, bt, kvh, d)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(bsz, n),
-        in_specs=[
-            pl.BlockSpec((1, h, d), lambda b, i, tbl, sl: (b, 0, 0)),
-            pl.BlockSpec(block, lambda b, i, tbl, sl: (tbl[b, i], 0, 0, 0)),
-            pl.BlockSpec(block, lambda b, i, tbl, sl: (tbl[b, i], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, h, d), lambda b, i, tbl, sl: (b, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((h, 128), jnp.float32),
-            pltpu.VMEM((h, 128), jnp.float32),
-            pltpu.VMEM((h, d), jnp.float32),
-        ],
-    )
-    seq_lens = jnp.asarray(seq_lens, dtype=jnp.int32).reshape(bsz)
-    return pl.pallas_call(
-        _decode_attn_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bsz, h, d), q.dtype),
-        interpret=interpret,
-    )(block_tables, seq_lens, q, k_cache, v_cache)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _paged_decode_attention_pallas(q, k_cache, v_cache, block_table, seq_len, *, interpret):
-    seq_len = jnp.asarray(seq_len, dtype=jnp.int32).reshape(1)
-    return _paged_decode_attention_pallas_batched(
-        q[None], k_cache, v_cache, block_table[None], seq_len, interpret=interpret
-    )[0]
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _paged_decode_attention_pallas_stats(
-    q, k_cache, v_cache, block_tables, seq_lens, *, interpret
-):
-    """Raw (acc, m, l) per request: acc [B,H,D] f32, m/l [B,H,1] f32."""
-    bsz, h, d = q.shape
-    _, bt, kvh, _ = k_cache.shape
-    n = block_tables.shape[1]
-    block = (1, bt, kvh, d)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(bsz, n),
-        in_specs=[
-            pl.BlockSpec((1, h, d), lambda b, i, tbl, sl: (b, 0, 0)),
-            pl.BlockSpec(block, lambda b, i, tbl, sl: (tbl[b, i], 0, 0, 0)),
-            pl.BlockSpec(block, lambda b, i, tbl, sl: (tbl[b, i], 0, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, h, d), lambda b, i, tbl, sl: (b, 0, 0)),
-            pl.BlockSpec((1, h, 128), lambda b, i, tbl, sl: (b, 0, 0)),
-            pl.BlockSpec((1, h, 128), lambda b, i, tbl, sl: (b, 0, 0)),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((h, 128), jnp.float32),
-            pltpu.VMEM((h, 128), jnp.float32),
-            pltpu.VMEM((h, d), jnp.float32),
-        ],
-    )
-    seq_lens = jnp.asarray(seq_lens, dtype=jnp.int32).reshape(bsz)
-    operands = (block_tables, seq_lens, q, k_cache, v_cache)
-    acc, m, l = pl.pallas_call(
-        _decode_attn_stats_kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            _varying_like((bsz, h, d), jnp.float32, *operands),
-            _varying_like((bsz, h, 128), jnp.float32, *operands),
-            _varying_like((bsz, h, 128), jnp.float32, *operands),
-        ],
-        interpret=interpret,
-    )(*operands)
-    return acc, m[:, :, :1], l[:, :, :1]
-
-
 @jax.jit
 def _decode_attention_stats_xla(q, k_cache, v_cache, block_tables, seq_lens):
-    """XLA fallback for the raw statistics (same shapes as the Pallas one)."""
+    """XLA form of the raw per-row statistics over RECTANGULAR tables
+    ([B, M] ``block_tables``, [B] ``seq_lens``): acc [B, H, D] f32
+    unnormalized, m / l [B, H, 1] f32. The fallback off the chip and the
+    reference the kernels are tested against."""
     _, bt, kvh, d = k_cache.shape
     h = q.shape[1]
     groups = h // kvh
@@ -336,19 +185,6 @@ def _decode_attention_stats_xla(q, k_cache, v_cache, block_tables, seq_lens):
 
 
 @jax.jit
-def paged_decode_attention_xla(q, k_cache, v_cache, block_table, seq_len):
-    """Reference semantics on any backend: gather the table's blocks, mask
-    positions >= seq_len, softmax via the SAME statistics computation the
-    sharded combine uses (one body to keep the numeric contract in). A
-    seq_len of 0 yields zeros — matching the kernel, not NaN."""
-    seq_len = jnp.asarray(seq_len, dtype=jnp.int32).reshape(1)
-    acc, _, l = _decode_attention_stats_xla(
-        q[None], k_cache, v_cache, block_table[None], seq_len
-    )
-    return (acc[0] / jnp.maximum(l[0], 1e-30)).astype(q.dtype)
-
-
-@jax.jit
 def paged_decode_attention_xla_batched(q, k_cache, v_cache, block_tables, seq_lens):
     """Batched reference semantics, derived from the stats body (one copy of
     the numeric contract). Zero-length rows yield zeros."""
@@ -361,12 +197,11 @@ def paged_decode_attention_xla_batched(q, k_cache, v_cache, block_tables, seq_le
 # ---------------------------------------------------------------------------
 # Ragged decode attention: one flat grid over the wave's CONCATENATED page
 # lists — a length-skewed wave costs sum(ceil(len_i / bt)) block folds
-# instead of the rectangular layout's B * max_blocks (Ragged Paged
+# instead of a rectangular layout's B * max_blocks (Ragged Paged
 # Attention, PAPERS.md). The kernel never materializes gathered KV: the
-# scalar-prefetched flat page list drives the K/V BlockSpec index maps
-# exactly like the rectangular kernel, and the per-page row map decides
-# when the online-softmax scratch resets and when a row's output is
-# finalized.
+# scalar-prefetched flat page list drives the K/V BlockSpec index maps, and
+# the per-page row map decides when the online-softmax scratch resets and
+# when a row's output is finalized.
 # ---------------------------------------------------------------------------
 
 
@@ -489,7 +324,7 @@ def _ragged_fold(rows_ref, starts_ref, seqlen_ref, q_ref, k_ref, v_ref,
     return b, rows_ref[i + 1] != b
 
 
-def _ragged_decode_attn_kernel(
+def _ragged_attn_kernel(
     rows_ref,  # scalar-prefetch: [P + 1] int32 owning row per page
     pages_ref,  # scalar-prefetch: [P] int32 flat page list (drives DMA)
     starts_ref,  # scalar-prefetch: [R] int32 first flat index per row
@@ -515,7 +350,7 @@ def _ragged_decode_attn_kernel(
         ).astype(out_ref.dtype)
 
 
-def _ragged_decode_attn_stats_kernel(
+def _ragged_attn_stats_kernel(
     rows_ref, pages_ref, starts_ref, seqlen_ref,
     q_ref, k_ref, v_ref,
     acc_ref,  # [1, H, D] f32 unnormalized numerator
@@ -524,8 +359,7 @@ def _ragged_decode_attn_stats_kernel(
     m_scr, l_scr, acc_scr,
 ):
     """Ragged online softmax emitting raw (acc, m, l) — the shard-local
-    half of ragged sharded decode (combined with pmax/psum exactly like the
-    rectangular stats kernel's output)."""
+    half of ragged sharded decode (combined with one pmax and two psum)."""
     del pages_ref
     _, last = _ragged_fold(
         rows_ref, starts_ref, seqlen_ref, q_ref, k_ref, v_ref,
@@ -571,7 +405,7 @@ def _paged_decode_attention_pallas_ragged(
         pl.BlockSpec((1, h, d), lambda i, rows, pages, st, sl: (rows[i], 0, 0)),
     )
     return pl.pallas_call(
-        _ragged_decode_attn_kernel,
+        _ragged_attn_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((r, h, d), q.dtype),
         interpret=interpret,
@@ -597,7 +431,7 @@ def _paged_decode_attention_pallas_ragged_stats(
     )
     operands = (page_rows, pages, page_starts, seq_lens, q, k_cache, v_cache)
     acc, m, l = pl.pallas_call(
-        _ragged_decode_attn_stats_kernel,
+        _ragged_attn_stats_kernel,
         grid_spec=grid_spec,
         out_shape=[
             _varying_like((r, h, d), jnp.float32, *operands),
@@ -613,9 +447,8 @@ def _ragged_row_tables(pages, page_starts, table_width: int):
     """Reconstruct [R, table_width] per-row tables from the flat page list
     for the XLA fallback (which gathers per row). Entries past a row's real
     pages alias LATER pages in the flat list (clamped in range) — valid ids
-    whose contents are masked by seq_len, the padded-table contract the
-    rectangular fallback already honors (tested:
-    test_padded_table_entries_are_ignored)."""
+    whose contents are masked by seq_len, the padded-table contract of the
+    XLA body (tested: test_padded_table_entries_are_ignored)."""
     idx = page_starts[:, None] + jnp.arange(table_width, dtype=jnp.int32)[None, :]
     return jnp.take(pages, jnp.minimum(idx, pages.shape[0] - 1), axis=0)
 
@@ -647,7 +480,7 @@ def paged_decode_attention_ragged(
     walks the flat page list: sum(ceil(len_i / bt)) block folds total, so
     an 8:1 length-skewed wave costs ~the mean length, not B x max. Rows
     with seq_len 0 return zeros on every backend."""
-    if _use_pallas():
+    if paged._use_pallas():
         return _paged_decode_attention_pallas_ragged(
             q, k_cache, v_cache,
             jnp.asarray(pages, jnp.int32),
@@ -665,19 +498,42 @@ def paged_decode_attention_ragged(
     )
 
 
+def rectangle_as_ragged(block_tables):
+    """Ragged metadata ``(pages, page_rows, page_starts)`` of a RECTANGULAR
+    wave: ``block_tables`` [B, M], one full-width table a row. Row b owns
+    flat pages ``[b * M, (b + 1) * M)``; those past its sequence fold fully
+    masked, a bitwise no-op (_attn_block_fold), and a zero-length row still
+    writes zeros. Static given the shape, so it traces inside a jit: what
+    the callers that hold a rectangle (one decode token, the disagg decode
+    layer) hand :func:`paged_decode_attention_rows`, at the B x M grid steps
+    a rectangular kernel would take."""
+    b, m = block_tables.shape
+    rows = jnp.arange(b + 1, dtype=jnp.int32)
+    return (
+        block_tables.reshape(-1).astype(jnp.int32),
+        jnp.repeat(rows, m)[: b * m + 1],  # [B*M + 1], sentinel B last
+        rows[:b] * m,
+    )
+
+
 def paged_decode_attention_rows(
     q, k_cache, v_cache, row_tables, seq_lens, pages, page_rows, page_starts
 ):
-    """Per-row decode attention with BOTH layouts in hand — the model's
-    ragged wave body (models/llama.py verify_step_ragged) calls this with
-    one row per flat wave token. Same semantics as
-    :func:`paged_decode_attention_batched` over ``row_tables``; on TPU the
-    flat ragged metadata routes to the ragged kernel (sum of per-row page
-    counts, no B x max_blocks grid), while the XLA fallback keeps the
-    rectangular gather — whose per-row computation is shape-identical to a
-    B=1 launch, the property the engine's wave-vs-sequential byte-identity
-    test pins."""
-    if _use_pallas():
+    """Per-row decode attention with BOTH layouts in hand: THE way a decode
+    row attends its pages. q: [R, n_heads, head_dim]; row r attends the
+    first ``seq_lens[r]`` tokens of its table ``row_tables[r]`` ([R,
+    max_blocks], padded with any valid id), which the flat ragged metadata
+    (:class:`RaggedWaveMeta`'s layout, from :func:`build_ragged_wave` on
+    the host or :func:`rectangle_as_ragged` in a jit) describes a second
+    time. A row with ``seq_lens[r] == 0`` returns zeros. On TPU the flat
+    metadata routes to the ragged kernel (one grid step a page, no
+    B x max_blocks grid); elsewhere the XLA body gathers ``row_tables``.
+    Every caller in models/llama.py comes through here (the wave body
+    verify_step_ragged, decode_step as its one-row view, the disagg
+    decode_wave_layer), so a wave and the same tokens decoded one at a time
+    agree to float32 rounding on a float32 model (the tests' written
+    tolerance), whatever the batch shape."""
+    if paged._use_pallas():
         return _paged_decode_attention_pallas_ragged(
             q, k_cache, v_cache, pages, page_rows, page_starts, seq_lens,
             interpret=False,
@@ -693,90 +549,13 @@ def _decode_attention_stats_ragged(
 ):
     """Raw ragged (acc, m, l) dispatcher (Pallas on TPU, XLA off) — the
     shard-local half of ragged sharded decode."""
-    if _use_pallas():
+    if paged._use_pallas():
         return _paged_decode_attention_pallas_ragged_stats(
             q, k_cache, v_cache, pages, page_rows, page_starts, seq_lens,
             interpret=False,
         )
     tables = _ragged_row_tables(pages, page_starts, table_width)
     return _decode_attention_stats_xla(q, k_cache, v_cache, tables, seq_lens)
-
-
-def _use_pallas() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def paged_decode_attention_sharded(
-    q, k_cache, v_cache, local_tables, local_lens, *, mesh, axis: str = "sp"
-):
-    """Decode attention over a paged KV cache SHARDED across a mesh axis —
-    the long-context serving shape where one request's context exceeds a
-    single device's HBM (the decode-side complement of ring/Ulysses prefill,
-    models/ring_attention.py).
-
-    Layout contract: ``k_cache``/``v_cache`` are [P * blocks_per_shard, bt,
-    KVH, D] sharded over ``axis`` on the block dimension — shard p owns
-    global rows [p*blocks_per_shard, (p+1)*blocks_per_shard). ``local_tables``
-    is [P, n_local] of SHARD-LOCAL block ids (each row indexes within its
-    shard's rows); ``local_lens`` is [P] valid token counts per shard (0 is
-    fine — an empty shard contributes nothing). ``q`` is [H, D], replicated.
-
-    Each shard folds its local blocks with the same online-softmax kernel the
-    single-chip path uses, but emits raw (acc, m, l); one ``pmax`` + two
-    ``psum`` over ``axis`` combine them exactly (softmax is permutation-
-    invariant, so shard order does not matter):
-
-        out = sum_p(acc_p * e^(m_p - m)) / sum_p(l_p * e^(m_p - m)),
-        m = max_p(m_p)
-
-    Every byte of cached context stays on its owning shard — only [H, D]-
-    sized statistics cross the interconnect. Returns [H, D] replicated.
-
-    The shard_map is built once per (mesh, axis) (_sharded_decode_fn is
-    lru_cached) — this is a per-decode-token entry point, so a fresh
-    closure per call would retrace every token. device_put on an input
-    already laid out per the contract is a no-op view."""
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
-    fn, cache_spec = _sharded_decode_fn(mesh, axis)
-    put = lambda x, spec: jax.device_put(x, NamedSharding(mesh, spec))
-    return fn(
-        put(q, P(None, None)),
-        put(k_cache, cache_spec),
-        put(v_cache, cache_spec),
-        put(jnp.asarray(local_tables, jnp.int32), P(axis, None)),
-        put(jnp.asarray(local_lens, jnp.int32), P(axis)),
-    )
-
-
-@functools.lru_cache(maxsize=None)
-def _sharded_decode_fn(mesh, axis: str):
-    """Build (once per mesh/axis) the shard_map'd local-stats + combine."""
-    from jax.sharding import PartitionSpec as P
-
-    def local_fn(q_rep, kc, vc, tbl, sl):
-        acc, m, l = _decode_attention_stats(q_rep[None], kc, vc, tbl, sl)
-        acc, m, l = acc[0], m[0], l[0]  # [H, D], [H, 1], [H, 1]
-        m_g = jax.lax.pmax(m, axis)
-        w = jnp.exp(m - m_g)
-        l_g = jax.lax.psum(l * w, axis)
-        acc_g = jax.lax.psum(acc * w, axis)
-        # max(l, tiny): only the "whole context empty" case, which decode
-        # never presents (>= 1 token globally); avoids 0/0 surprises anyway.
-        return (acc_g / jnp.maximum(l_g, 1e-30)).astype(q_rep.dtype)
-
-    cache_spec = P(axis, None, None, None)
-    # jit around the shard_map: without it every call re-traces and
-    # re-lowers (measured ~1900x slower per call on the 8-device CPU mesh).
-    fn = jax.jit(
-        jax.shard_map(
-            local_fn,
-            mesh=mesh,
-            in_specs=(P(None, None), cache_spec, cache_spec, P(axis, None), P(axis)),
-            out_specs=P(None, None),
-        )
-    )
-    return fn, cache_spec
 
 
 def build_ragged_wave_sharded(local_tables, local_lens, block_tokens: int):
@@ -835,11 +614,17 @@ def paged_decode_attention_ragged_sharded(
 
     Each shard folds its local pages with the RAGGED stats kernel (flat
     grid, no padding to the wave max) and the per-row (acc, m, l) combine
-    with the same one-pmax-two-psum rule as the single-request sharded
-    path — softmax statistics merge identically whether the rows were
-    rectangular or ragged, so the ragged layout composes with context
-    sharding for free. Cached bytes never cross the interconnect; only
-    [R, H, D]-sized statistics do. Returns [R, H, D], replicated."""
+    with one ``pmax`` and two ``psum`` over ``axis``, exactly (softmax is
+    permutation-invariant, so shard order does not matter):
+
+        out = sum_p(acc_p * e^(m_p - m)) / sum_p(l_p * e^(m_p - m)),
+        m = max_p(m_p)
+
+    Cached bytes never cross the interconnect; only [R, H, D]-sized
+    statistics do. Returns [R, H, D], replicated. A single long request is
+    the R = 1 wave. The shard_map is built once per (mesh, axis, width)
+    (_sharded_ragged_decode_fn is lru_cached): this is a per-decode-token
+    entry point, and a fresh closure a call would retrace every token."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     fn, cache_spec = _sharded_ragged_decode_fn(mesh, axis, int(table_width))
@@ -863,8 +648,8 @@ def paged_decode_attention_ragged_sharded(
 @functools.lru_cache(maxsize=None)
 def _sharded_ragged_decode_fn(mesh, axis: str, table_width: int):
     """Build (once per mesh/axis/width) the shard_map'd ragged local-stats
-    + per-row combine. lru_cached for the same reason as the single-request
-    builder: this is a per-decode-token entry point."""
+    + per-row combine. The jit around the shard_map matters: without it
+    every call re-traces and re-lowers."""
     from jax.sharding import PartitionSpec as P
 
     def local_fn(q_rep, kc, vc, pages, rows, starts, lens):
@@ -893,45 +678,3 @@ def _sharded_ragged_decode_fn(mesh, axis: str, table_width: int):
         )
     )
     return fn, cache_spec
-
-
-def _decode_attention_stats(q, k_cache, v_cache, block_tables, seq_lens):
-    """Dispatcher for the raw-stats computation (Pallas on TPU, XLA off)."""
-    if _use_pallas():
-        return _paged_decode_attention_pallas_stats(
-            q, k_cache, v_cache, block_tables, seq_lens, interpret=False
-        )
-    return _decode_attention_stats_xla(q, k_cache, v_cache, block_tables, seq_lens)
-
-
-def paged_decode_attention_batched(q, k_cache, v_cache, block_tables, seq_lens):
-    """Decode attention for a WAVE of requests against one shared paged
-    cache — the continuous-batching serving shape (every live request
-    decodes one token per engine step).
-
-    q: [B, n_heads, head_dim]; block_tables: [B, max_blocks] (each row padded
-    with any valid block id); seq_lens: [B] — a row with seq_lens[b] == 0
-    returns zeros on every backend (not NaN). Returns [B, n_heads,
-    head_dim]. One fused kernel launch covers the whole wave on TPU
-    (requests are grid rows, so per-request dispatch cost is paid once per
-    wave, not per request); gather+dense elsewhere."""
-    if _use_pallas():
-        return _paged_decode_attention_pallas_batched(
-            q, k_cache, v_cache, block_tables, seq_lens, interpret=False
-        )
-    return paged_decode_attention_xla_batched(q, k_cache, v_cache, block_tables, seq_lens)
-
-
-def paged_decode_attention(q, k_cache, v_cache, block_table, seq_len):
-    """Single-token decode attention over the paged cache.
-
-    q: [n_heads, head_dim]; k_cache/v_cache: [num_blocks, block_tokens,
-    n_kv_heads, head_dim]; block_table: [max_blocks] int32 (entries past the
-    sequence may be any valid block id); seq_len: scalar int32 count of valid
-    context tokens. Returns [n_heads, head_dim] in q's dtype. Fused Pallas
-    kernel on TPU, gather+dense XLA elsewhere."""
-    if _use_pallas():
-        return _paged_decode_attention_pallas(
-            q, k_cache, v_cache, block_table, seq_len, interpret=False
-        )
-    return paged_decode_attention_xla(q, k_cache, v_cache, block_table, seq_len)

@@ -18,6 +18,7 @@ import numpy as np
 import pytest
 
 from infinistore_tpu.tpu import chunk_attention as ca
+from infinistore_tpu.tpu import paged
 
 # (name, q heads, kv heads, head_dim, block_tokens, dtype)
 GEOMETRIES = [
@@ -152,7 +153,7 @@ def test_table_exactly_full_single_row_and_a_draft_of_five(form):
 def test_dispatcher_takes_the_xla_form_off_the_chip():
     geom = GEOMETRIES[3]
     q, k_cache, v_cache, table = _case(geom, 8, 16, 8, seed=4)
-    assert jax.default_backend() != "tpu" and not ca._use_pallas()
+    assert jax.default_backend() != "tpu" and not paged._use_pallas()
     got = ca.chunk_prefix_attention(q, k_cache, v_cache, jnp.asarray(table), jnp.int32(16))
     want = ca.chunk_prefix_attention_xla(q, k_cache, v_cache, jnp.asarray(table), jnp.int32(16))
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

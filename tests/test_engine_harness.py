@@ -478,7 +478,7 @@ def test_wave_decoder_failure_fails_all_waiters(params):
 
     async def run():
         bad = np.zeros(MAX_REQ_BLOCKS, np.int32)
-        # Poison one step: a wrong-shaped table makes decode_step_batched
+        # Poison one step: a wrong-shaped table makes the wave step
         # raise for the whole wave.
         t1 = asyncio.ensure_future(wave.step(1, 8, jnp.asarray(bad)))
         t2 = asyncio.ensure_future(wave.step(2, 8, jnp.asarray(bad[:2])))

@@ -99,9 +99,6 @@ def test_paged_kernels_compile(v5e, geom):
     )
     # The scatter's whole point: the cache is updated in place.
     assert exe.memory_analysis().alias_size_in_bytes > 0
-    dense = (a["q"], a["cache"], a["cache"], a["tables"], a["lens"])
-    _compile(pa._paged_decode_attention_pallas_batched, *dense, interpret=False)
-    _compile(pa._paged_decode_attention_pallas_stats, *dense, interpret=False)
     ragged = (
         a["q"], a["cache"], a["cache"], a["pages"], a["page_rows"], a["lens"],
         a["lens"],
@@ -109,6 +106,16 @@ def test_paged_kernels_compile(v5e, geom):
     _compile(pa._paged_decode_attention_pallas_ragged, *ragged, interpret=False)
     _compile(
         pa._paged_decode_attention_pallas_ragged_stats, *ragged, interpret=False
+    )
+    # A rectangle rides the same kernel: full-width tables through the
+    # in-jit metadata, as decode_step and the disagg decode layer pass them.
+    _compile(
+        jax.jit(
+            lambda q, k, v, tables, lens: pa._paged_decode_attention_pallas_ragged(
+                q, k, v, *pa.rectangle_as_ragged(tables), lens, interpret=False
+            )
+        ),
+        a["q"], a["cache"], a["cache"], a["tables"], a["lens"],
     )
     _compile(
         kq._quant_decode_pallas, a["q"], a["i8"], a["scales"], a["i8"],
@@ -191,7 +198,7 @@ def test_resume_program_compiles_with_its_kernel(v5e, monkeypatch, case):
     and the vocabulary, which the kernel never sees, are kept small)."""
     from infinistore_tpu.models import llama
 
-    monkeypatch.setattr(ca, "_use_pallas", lambda: True)
+    monkeypatch.setattr(paged, "_use_pallas", lambda: True)
     _, h, kvh, d, bt, dtype, chunk, table, blocks = case
     cfg = llama.LlamaConfig(
         vocab=1024, dim=h * d, n_layers=1, n_heads=h, n_kv_heads=kvh,
@@ -212,13 +219,57 @@ def test_resume_program_compiles_with_its_kernel(v5e, monkeypatch, case):
     assert exe.as_text().count("tpu_custom_call") >= cfg.n_layers
 
 
+@pytest.mark.parametrize("view", ["decode_step", "decode_wave_layer"])
+def test_rectangle_views_compile_with_the_ragged_kernel(v5e, monkeypatch, view):
+    """``decode_step`` and the disagg ``decode_wave_layer`` through the
+    model's own dispatcher at the smoke's attention widths (one layer, small
+    FFN and vocabulary): one Mosaic call a layer, and it is the ragged
+    decode kernel, fed the in-jit rectangle metadata."""
+    from infinistore_tpu.models import llama
+
+    monkeypatch.setattr(paged, "_use_pallas", lambda: True)
+    _, h, kvh, d, bt, dtype = GEOMETRIES[0]
+    cfg = llama.LlamaConfig(
+        vocab=1024, dim=h * d, n_layers=1, n_heads=h, n_kv_heads=kvh,
+        ffn_dim=1024, block_tokens=bt, dtype=dtype,
+    )
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=v5e)
+    params = jax.tree.map(
+        lambda x: s(x.shape, x.dtype),
+        jax.eval_shape(lambda: llama.init_params(cfg, jax.random.PRNGKey(0))),
+    )
+    cache = s(cfg.kv_spec(NUM_BLOCKS).cache_shape, cfg.dtype)
+    # Fresh jit wrappers, the wave body under decode_step included: a trace
+    # taken under the CPU dispatch must not be reused, and this one must
+    # not be left in the module's caches for a later test.
+    fresh = lambda fn, *static: jax.jit(fn.__wrapped__, static_argnames=static)
+    monkeypatch.setattr(
+        llama, "verify_step_ragged",
+        fresh(llama.verify_step_ragged, "config", "max_blocks"),
+    )
+    i32 = lambda *shape: s(shape, jnp.int32)
+    if view == "decode_step":
+        traced = fresh(llama.decode_step, "config", "max_blocks").trace(
+            params, i32(), i32(), [(cache, cache)], i32(TABLE),
+            config=cfg, max_blocks=TABLE,
+        )
+    else:
+        traced = fresh(llama.decode_wave_layer, "config", "layer", "max_blocks").trace(
+            params, s((ROWS, 2, cfg.dim), dtype), i32(ROWS, 2), cache, cache,
+            i32(ROWS, TABLE), config=cfg, layer=0, max_blocks=TABLE,
+        )
+    lowered = traced.lower(lowering_platforms=("tpu",))
+    assert 'kernel_name = "_ragged_attn_kernel"' in lowered.as_text()
+    assert "tpu_custom_call" in lowered.compile().as_text()
+
+
 def test_sharded_decode_compiles_for_four_chips(monkeypatch):
-    """The sharded decode entries run the stats kernels INSIDE shard_map;
-    on the CPU they always took the XLA fallback, so nothing had ever asked
+    """The sharded decode entry runs the stats kernel INSIDE shard_map; on
+    the CPU it always took the XLA fallback, so nothing had ever asked
     jax's varying-axes typing about a pallas_call there (it refused: the
-    kernel's out_shape must say which mesh axes it varies over)."""
-    for mod in (paged, pa, fp, kq):
-        monkeypatch.setattr(mod, "_use_pallas", lambda: True)
+    kernel's out_shape must say which mesh axes it varies over). A wave of
+    four rows, and the one-row wave a single long request is."""
+    monkeypatch.setattr(paged, "_use_pallas", lambda: True)
     topo = topologies.get_topology_desc(topology_name="v5e:2x2", platform="tpu")
     mesh = Mesh(np.array(topo.devices), ("sp",))
     _, h, kvh, d, bt, dtype = GEOMETRIES[0]
@@ -228,17 +279,13 @@ def test_sharded_decode_compiles_for_four_chips(monkeypatch):
         return jax.ShapeDtypeStruct(shape, dt, sharding=NamedSharding(mesh, P(*spec)))
 
     cache = s((4 * per, bt, kvh, d), dtype, "sp", None, None, None)
-    fn, _ = pa._sharded_decode_fn(mesh, "sp")
-    _compile(
-        fn, s((h, d), dtype, None, None), cache, cache,
-        s((4, n_local), jnp.int32, "sp", None), s((4,), jnp.int32, "sp"),
-    )
     fn, _ = pa._sharded_ragged_decode_fn(mesh, "sp", n_local)
     meta = lambda n: s((4, n), jnp.int32, "sp", None)
-    _compile(
-        fn, s((rows, h, d), dtype, None, None, None), cache, cache,
-        meta(max_p), meta(max_p + 1), meta(rows), meta(rows),
-    )
+    for r, pages in ((rows, max_p), (1, n_local)):
+        _compile(
+            fn, s((r, h, d), dtype, None, None, None), cache, cache,
+            meta(pages), meta(pages + 1), meta(r), meta(r),
+        )
 
 
 @pytest.mark.slow
@@ -251,8 +298,7 @@ def test_full_width_steps_compile_and_fit_one_v5e(v5e, monkeypatch):
 
     # The dispatchers look at the process's default backend (cpu here);
     # this test asks what they emit when that backend is the chip.
-    for mod in (paged, pa, fp, kq):
-        monkeypatch.setattr(mod, "_use_pallas", lambda: True)
+    monkeypatch.setattr(paged, "_use_pallas", lambda: True)
     cfg = llama.LlamaConfig(
         vocab=128256, dim=4096, n_layers=8, n_heads=32, n_kv_heads=8,
         ffn_dim=14336, rope_theta=500000.0, block_tokens=16,

@@ -470,35 +470,6 @@ CHECKS = [
             f"{m['timeseries_anomaly_clean']:.0f} (must be 0)"
         ),
     ),
-    # Ragged decode attention (tpu/paged_attention.py), two gates on the
-    # TPU-backend receipt keys (skipped on hosts without the TPU leg).
-    # Wave 1: the fused kernel must not lose to gather+dense; 0.95 clears
-    # the paired estimator's residual scatter while a structural loss (the
-    # kernel re-materializing what dense gather gets for free) reads well
-    # below.
-    Check(
-        "decode_attn_wave1",
-        ["tpu_decode_attn_speedup"],
-        lambda m: m["tpu_decode_attn_speedup"] >= 0.95,
-        lambda m: (
-            f"fused decode attention runs {m['tpu_decode_attn_speedup']:.2f}x "
-            "gather+dense at wave size 1 (must be >= 0.95, paired-interleaved)"
-        ),
-    ),
-    # The ragged win itself: on the 8:1 length-skew wave the flat-page-list
-    # kernel must beat the padded-dense rectangle (which pays
-    # skew_factor x the real pages in padding) — ANY ratio <= 1.0 means the
-    # ragged path stopped earning its complexity.
-    Check(
-        "decode_attn_ragged",
-        ["tpu_decode_attn_ragged_vs_padded", "tpu_decode_attn_skew_factor"],
-        lambda m: m["tpu_decode_attn_ragged_vs_padded"] > 1.0,
-        lambda m: (
-            f"ragged wave runs {m['tpu_decode_attn_ragged_vs_padded']:.2f}x "
-            f"padded-dense on the skew-{m['tpu_decode_attn_skew_factor']:.2f} "
-            "wave (must be > 1.0, paired-interleaved)"
-        ),
-    ),
     # Tiered capacity plane (ROADMAP-4, docs/tiering.md), three gates.
     # Hot-set isolation: with a Zipf working set 4x the serving-RAM budget
     # and the tail demoted to the pooled cold tier, the HOT set's load p99
