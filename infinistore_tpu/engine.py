@@ -309,7 +309,7 @@ class WaveDecoder:
     whose padded rows no flat token references (they neither scatter nor
     attend). Attention page metadata (tpu/paged_attention.py
     ``build_ragged_wave``) pads to a power-of-two page bucket the same
-    way; padded pages fold fully masked (a bitwise no-op).
+    way; the ragged kernel neither fetches nor computes the padded pages.
 
     ``bucket_sizes`` records the distinct (B, T, P) buckets — table rows,
     flat token rows, flat attention pages — which ARE the jit cache
@@ -317,7 +317,9 @@ class WaveDecoder:
     ``launched_rows`` feed the ``engine_wave_pad_fraction`` metric: the
     share of launched wave rows that were padding (the rectangle's was
     1 - sum(len_i) / (B_bucket * K_bucket); the ragged tail's is
-    1 - sum(len_i) / T_bucket).
+    1 - sum(len_i) / T_bucket). ``wave_pages``/``wave_pad_pages`` count the
+    flat attention pages launched and how many were the page bucket's
+    padding (``RaggedWaveMeta.pad_pages``): the share the kernel skips.
 
     **Skew-aware flush policy** (``skew_policy=True``, off by default;
     docs/serving_load.md): blind first-arrival flush lets one 8:1-skew
@@ -382,6 +384,10 @@ class WaveDecoder:
         # Wave-row padding ledger (engine_wave_pad_fraction).
         self.pad_rows = 0
         self.launched_rows = 0
+        # Flat attention pages launched (bucket padding included) and how
+        # many of them were padding: what the ragged kernel skips.
+        self.wave_pages = 0
+        self.wave_pad_pages = 0
         # Skew-policy ledger (per-decoder; the process-wide WaveCounters
         # singleton aggregates the same events for /metrics).
         self.deferrals = 0
@@ -653,6 +659,8 @@ class WaveDecoder:
             self.bucket_sizes.add((b_bucket, t_bucket, meta.num_pages))
             self.pad_rows += t_bucket - t_real
             self.launched_rows += t_bucket
+            self.wave_pages += meta.num_pages
+            self.wave_pad_pages += meta.pad_pages
             if self.skew_policy:
                 _WAVE_COUNTERS.bump("engine_wave_policy_waves")
                 _WAVE_COUNTERS.note_wave(t_real, t_bucket)
@@ -660,7 +668,7 @@ class WaveDecoder:
                 wspan.stage("assembled")
                 wspan.annotate(
                     entries=len(batch), real_rows=t_real, rows=t_bucket,
-                    pages=meta.num_pages,
+                    pages=meta.num_pages, pad_pages=meta.pad_pages,
                 )
 
             # The flush task inherited the context of the request that
@@ -1742,6 +1750,8 @@ class ContinuousBatchingHarness:
         ``wave_prewarmed_buckets`` — the canonical ladder
         ``prewarm_wave_buckets`` compiled at startup — and
         ``wave_pad_fraction``, the share of launched wave rows that were
+        padding, ``wave_pages`` / ``wave_pad_pages``, the flat attention
+        pages launched and those of them that were the page bucket's
         padding); the skew-aware flush policy's ledger
         (docs/serving_load.md: ``wave_deferrals``,
         ``wave_aging_escapes`` — deferred entries force-launched at the
@@ -1857,6 +1867,11 @@ class ContinuousBatchingHarness:
                 if self.wave.launched_rows
                 else 0.0
             ),
+            # Flat attention pages the waves launched, and how many were
+            # the power-of-two bucket's padding: steps the ragged kernel
+            # neither computes nor fetches (tpu/paged_attention.py).
+            "wave_pages": self.wave.wave_pages,
+            "wave_pad_pages": self.wave.wave_pad_pages,
             # Skew-aware flush policy (docs/serving_load.md): the per-
             # harness deferral ledger (the process-wide WaveCounters
             # singleton aggregates the same events for /metrics), and

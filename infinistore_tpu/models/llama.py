@@ -284,7 +284,7 @@ def decode_step(
 
     The one-row, one-token view of ``verify_step_ragged`` — one decode body
     to maintain. The table rides the wave as a rectangle of one row
-    (``rectangle_as_ragged``): entries past the sequence fold fully masked."""
+    (``rectangle_as_ragged``): entries past the sequence are not walked."""
     if block_table.shape[0] != max_blocks:
         raise ValueError(
             f"block_table has {block_table.shape[0]} entries, expected "
@@ -303,6 +303,47 @@ def decode_step(
         max_blocks,
     )
     return logits[0], new_caches
+
+
+def _layer_weights(params: Params, layer: int) -> Params:
+    """Layer ``layer``'s weights under layer 0's names: the one pytree every
+    layer hands :func:`_wave_layer`, so that one trace serves them all."""
+    pre = f"l{layer}."
+    return {"l0." + k[len(pre):]: w for k, w in params.items() if k.startswith(pre)}
+
+
+def _wave_layer(
+    weights: Params,  # ONE layer's weights, named as layer 0's (_layer_weights)
+    x: jax.Array,  # [1, T, dim] activations entering the layer
+    positions: jax.Array,  # [1, T] int32
+    k_cache: jax.Array,  # this LAYER's paged K array
+    v_cache: jax.Array,
+    block_idx: jax.Array,  # [T] cache block of each flat token's own K/V
+    slots: jax.Array,  # [T] slot within that block
+    row_tables: jax.Array,  # [T, max_blocks]
+    seq_lens: jax.Array,  # [T]
+    pages: jax.Array,
+    page_rows: jax.Array,
+    page_starts: jax.Array,
+    config: LlamaConfig,
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """ONE layer of the paged wave body on T flat rows: insert the rows' K/V,
+    attend each row's pages through the one dispatcher, residual + FFN.
+    Returns ``(x_next, k_cache, v_cache)``. ``verify_step_ragged`` runs it a
+    layer under one ``jax.jit`` of its own, so the layers share one traced
+    and one lowered function (every wave bucket a run warms traces and
+    lowers its program again: PERF.md, PR 31); the disagg
+    ``decode_wave_layer`` is this function on a rectangle."""
+    k, v = _kv_proj(weights, 0, x, positions, config)  # [1, T, KVH, D]
+    k_cache = k_cache.at[block_idx, slots].set(k[0].astype(k_cache.dtype))
+    v_cache = v_cache.at[block_idx, slots].set(v[0].astype(v_cache.dtype))
+    q = _q_proj(weights, 0, x, positions, config)  # [1, T, H, D]
+    attn = paged_decode_attention_rows(
+        q[0], k_cache, v_cache, row_tables, seq_lens,
+        pages, page_rows, page_starts,
+    )[None]  # [1, T, H, D]
+    x = x + jnp.einsum("bshk,hkd->bsd", attn, weights["l0.wo"])
+    return _ffn(weights, 0, x, config), k_cache, v_cache
 
 
 @functools.partial(jax.jit, static_argnames=("config", "max_blocks"))
@@ -333,7 +374,7 @@ def verify_step_ragged(
     one attention launch covers all T rows, each masked to its own
     position + 1 (tpu/paged_attention.py paged_decode_attention_rows; on
     TPU the ragged kernel walks the flat page list: sum(ceil((pos_t + 1) /
-    bt)) block folds, no padding to the wave max). Requests own disjoint
+    bt)) page reads, no padding to the wave max). Requests own disjoint
     blocks (the engine's block-table manager guarantees it). Tail-bucket
     padding repeats the LAST flat row: a same-bytes scatter, value-safe.
     Rows may attend sibling rows' K/V within a chunk: inserts complete
@@ -370,19 +411,16 @@ def verify_step_ragged(
     slots = positions % bt
     seq_lens = positions + 1
 
+    # One jit for this trace alone: the layers share its traced and lowered
+    # function, and nothing outlives the trace.
+    layer_fn = jax.jit(_wave_layer, static_argnames=("config",))
     new_caches: Caches = []
     for layer, (k_cache, v_cache) in enumerate(caches):
-        k, v = _kv_proj(params, layer, x, pos2d, config)  # [1, T, KVH, D]
-        k_cache = k_cache.at[block_idx, slots].set(k[0].astype(k_cache.dtype))
-        v_cache = v_cache.at[block_idx, slots].set(v[0].astype(v_cache.dtype))
-        pre = f"l{layer}."
-        q = _q_proj(params, layer, x, pos2d, config)  # [1, T, H, D]
-        attn = paged_decode_attention_rows(
-            q[0], k_cache, v_cache, row_tables, seq_lens,
-            pages, page_rows, page_starts,
-        )[None]  # [1, T, H, D]
-        x = x + jnp.einsum("bshk,hkd->bsd", attn, params[pre + "wo"])
-        x = _ffn(params, layer, x, config)
+        x, k_cache, v_cache = layer_fn(
+            _layer_weights(params, layer), x, pos2d, k_cache, v_cache,
+            block_idx, slots, row_tables, seq_lens, pages, page_rows, page_starts,
+            config=config,
+        )
         new_caches.append((k_cache, v_cache))
     x = _rms_norm(x, params["final_norm"])
     logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"])
@@ -634,22 +672,12 @@ def decode_wave_layer(
     ).reshape(-1)
     slots = flat_pos % bt
     row_tables = jnp.repeat(block_tables, kk, axis=0)
-    k, v = _kv_proj(params, layer, x, positions, config)
-    k_cache = k_cache.at[block_idx, slots].set(
-        k.reshape(bsz * kk, *k.shape[2:]).astype(k_cache.dtype)
+    x, k_cache, v_cache = _wave_layer(
+        _layer_weights(params, layer), x.reshape(1, bsz * kk, -1),
+        flat_pos[None], k_cache, v_cache, block_idx, slots, row_tables,
+        flat_pos + 1, *rectangle_as_ragged(row_tables), config,
     )
-    v_cache = v_cache.at[block_idx, slots].set(
-        v.reshape(bsz * kk, *v.shape[2:]).astype(v_cache.dtype)
-    )
-    pre = f"l{layer}."
-    q = _q_proj(params, layer, x, positions, config)
-    attn = paged_decode_attention_rows(
-        q.reshape(bsz * kk, *q.shape[2:]), k_cache, v_cache,
-        row_tables, flat_pos + 1, *rectangle_as_ragged(row_tables),
-    ).reshape(bsz, kk, *q.shape[2:])
-    x = x + jnp.einsum("bshk,hkd->bsd", attn, params[pre + "wo"])
-    x = _ffn(params, layer, x, config)
-    return x, k_cache, v_cache
+    return x.reshape(bsz, kk, -1), k_cache, v_cache
 
 
 # ---------------------------------------------------------------------------

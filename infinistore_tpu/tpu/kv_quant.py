@@ -64,31 +64,37 @@ def _quant_decode_kernel(
     table_ref,  # scalar-prefetch: [B, max_blocks] int32
     seqlen_ref,  # scalar-prefetch: [B] int32
     q_ref,  # [1, H, D] float query
-    k_ref,  # [1, bt, KVH, D] int8
-    ks_ref,  # [1, bt, KVH] f32 scales
-    v_ref,  # [1, bt, KVH, D] int8
-    vs_ref,  # [1, bt, KVH] f32
+    kpos_ref,  # [H, bt * KVH] int32 (paged_attention._key_positions)
+    k_ref,  # [1, bt * KVH, D] int8, a page as it lies in the cache
+    ks_ref,  # [1, bt * KVH, 1] f32 scales
+    v_ref,  # [1, bt * KVH, D] int8
+    vs_ref,  # [1, bt * KVH, 1] f32
     out_ref,  # [1, H, D]
     m_scr,  # VMEM [H, 128] f32
     l_scr,  # VMEM [H, 128] f32
     acc_scr,  # VMEM [H, D] f32
+    *,
+    bt,
 ):
-    from .paged_attention import _attn_block_fold
+    from .paged_attention import _attn_fold
 
     del table_ref
     b = pl.program_id(0)
     i = pl.program_id(1)
     # Dequantize in VMEM — the HBM read was int8 width — then delegate to
     # the SAME online-softmax update the float kernels use (one copy of the
-    # numeric contract, paged_attention.py). This grid is (row, block in
-    # row), so the grid step is the block index.
-    _attn_block_fold(
+    # numeric contract, paged_attention.py; f32 operands, so its dots ask
+    # Precision.HIGHEST). This grid is (row, block in row): a step is one
+    # page, the grid step its index.
+    _attn_fold(
         i == 0,
-        i,
+        i * bt,
         seqlen_ref[b],
-        q_ref[0].astype(jnp.float32),
-        k_ref[0].astype(jnp.float32) * ks_ref[0][..., None],
-        v_ref[0].astype(jnp.float32) * vs_ref[0][..., None],
+        1.0 / np.sqrt(q_ref.shape[-1]),
+        q_ref[0],
+        k_ref[0].astype(jnp.float32) * ks_ref[0],
+        v_ref[0].astype(jnp.float32) * vs_ref[0],
+        kpos_ref[...],
         m_scr,
         l_scr,
         acc_scr,
@@ -105,20 +111,27 @@ def _quant_decode_kernel(
 def _quant_decode_pallas(
     q, k_data, k_scales, v_data, v_scales, block_tables, seq_lens, *, interpret
 ):
+    from .paged_attention import _key_positions
+
     bsz, h, d = q.shape
-    _, bt, kvh, _ = k_data.shape
+    nb, bt, kvh, _ = k_data.shape
     n = block_tables.shape[1]
-    data_block = (1, bt, kvh, d)
-    scale_block = (1, bt, kvh)
+    # A page as it lies in the cache: [bt * KVH, D], a row a (key, KV head).
+    rows = bt * kvh
+    data_block = (1, rows, d)
+    scale_block = (1, rows, 1)
+    page = lambda b, i, tbl, sl: (tbl[b, i], 0, 0)
+    key_pos = _key_positions(h, kvh, bt)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(bsz, n),
         in_specs=[
             pl.BlockSpec((1, h, d), lambda b, i, tbl, sl: (b, 0, 0)),
-            pl.BlockSpec(data_block, lambda b, i, tbl, sl: (tbl[b, i], 0, 0, 0)),
-            pl.BlockSpec(scale_block, lambda b, i, tbl, sl: (tbl[b, i], 0, 0)),
-            pl.BlockSpec(data_block, lambda b, i, tbl, sl: (tbl[b, i], 0, 0, 0)),
-            pl.BlockSpec(scale_block, lambda b, i, tbl, sl: (tbl[b, i], 0, 0)),
+            pl.BlockSpec(key_pos.shape, lambda b, i, tbl, sl: (0, 0)),
+            pl.BlockSpec(data_block, page),
+            pl.BlockSpec(scale_block, page),
+            pl.BlockSpec(data_block, page),
+            pl.BlockSpec(scale_block, page),
         ],
         out_specs=pl.BlockSpec((1, h, d), lambda b, i, tbl, sl: (b, 0, 0)),
         scratch_shapes=[
@@ -129,11 +142,15 @@ def _quant_decode_pallas(
     )
     seq_lens = jnp.asarray(seq_lens, dtype=jnp.int32).reshape(bsz)
     return pl.pallas_call(
-        _quant_decode_kernel,
+        functools.partial(_quant_decode_kernel, bt=bt),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bsz, h, d), q.dtype),
         interpret=interpret,
-    )(block_tables, seq_lens, q, k_data, k_scales, v_data, v_scales)
+    )(
+        block_tables, seq_lens, q, key_pos,
+        k_data.reshape(nb, rows, d), k_scales.reshape(nb, rows, 1),
+        v_data.reshape(nb, rows, d), v_scales.reshape(nb, rows, 1),
+    )
 
 
 @jax.jit

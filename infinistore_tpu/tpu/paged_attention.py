@@ -1,5 +1,5 @@
 """Fused paged decode attention: a wave of query rows, each attending its own
-pages of the paged KV cache, computed block-by-block with an online softmax —
+pages of the paged KV cache, computed step by step with an online softmax —
 no materialized context.
 
 This is the hot op on the consumer side of the store. The engine resumes a
@@ -9,11 +9,11 @@ then dense attention) moves each context block HBM->HBM into a contiguous
 buffer and then reads it again for attention — every cached byte crosses HBM
 three times per token. Decode attention does O(1) FLOPs per byte, so it is
 purely HBM-bandwidth-bound and that 3x is the whole cost. The fused kernel
-reads each block exactly once: the scalar-prefetched flat page list drives
-the BlockSpec index maps (the pipeline DMAs cache[pages[i]] directly into
-VMEM, double-buffering consecutive blocks), and a flash-style running
-(max, sum, acc) in VMEM scratch folds each block into the softmax as it
-arrives. The reference never needed this op — CUDA engines bring their own
+reads each block exactly once: the scalar-prefetched step table says which
+cache pages a grid step needs, the kernel copies cache[page] from HBM
+directly into VMEM (its own copies, double-buffered across consecutive
+steps), and a flash-style running (max, sum, acc) in VMEM scratch folds
+each step into the softmax as it arrives. The reference never needed this op — CUDA engines bring their own
 paged attention (vLLM) and the store hands them raw pointers; on TPU the
 engine-side kernel is part of the framework's job.
 
@@ -26,16 +26,39 @@ model's wave body, its one-token view and the disagg decode layer all call
 :func:`rectangle_as_ragged`. The two XLA bodies are the fallback off the
 chip and the tests' reference.
 
-GQA layout: each query row is [n_heads, head_dim] against caches of
-n_kv_heads; the kernel unrolls over kv heads and issues one MXU dot per
-(kv head, block) — no batched dot_general, which Mosaic handles unevenly at
-small shapes.
+What a grid step is (``_ragged_steps``, ``_ragged_walk``, ``_attn_fold``):
+
+- *What it folds.* Up to ``_STEP_TOKENS`` keys — eight 16-token pages, one
+  v5e MXU tile along the key axis — of ONE row, copied one under the other
+  as they lie in the cache ([bt * KVH, D] a page: a row a (key, KV head)
+  pair). A row's last step is partial and masked by its
+  ``seq_len``; steps never span rows. Two dots a step, whatever the heads:
+  all query heads against all of the step's (key, KV head) rows, the
+  columns of the KV heads that do not serve a query head masked like keys
+  past the sequence. K and V pass the MXU once as its stationary operand,
+  which is what bounds decode attention there; the query rows that meet
+  them ride free, no head is cut out of a page tile, and the program does
+  not grow with the head count.
+- *What is skipped.* The step count is static (the wave's bucket decides
+  it), the number of REAL steps rides in as a prefetched scalar: steps past
+  it start no copy and skip their compute (the query and output blocks do
+  not move either: their index maps repeat the last real step's). A wave's
+  power-of-two page padding, and whatever a row's span of the flat list
+  holds past its sequence (a rectangle's full-width table), costs neither
+  bytes nor dots.
+- *Which precision each dtype gets.* Chosen from the operands' dtype, with
+  no switch. bf16 queries on a bf16 cache: Q.K on the native operands with
+  f32 accumulation (bf16 x bf16 products are exact in f32: the float32
+  result at one MXU pass instead of six); P.V with the probabilities'
+  float32 value, cut into three bf16 pieces stacked as rows against V
+  (exact in bf16), so V still passes once. A float32 cache (and the int8
+  kernel's dequantized pages): both dots at ``Precision.HIGHEST``.
 
 Numerical contract (shared with the XLA fallback and the dense oracle in
 models/llama.py): logits and softmax statistics in float32, output cast to
 the query dtype. Positions >= seq_len are masked out; padded block-table
 entries past the sequence contribute nothing (their probabilities are
-explicitly zeroed, so a whole-block mask cannot poison the running max).
+explicitly zeroed, so a whole-step mask cannot poison the running max).
 """
 
 import functools
@@ -61,29 +84,82 @@ def _varying_like(shape, dtype, *operands):
     return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
 
-def _attn_block_fold(first, j, seq_len, q, k, v, m_scr, l_scr, acc_scr):
-    """Fold ONE cache block into the running (max, denominator, accumulator)
-    scratch — the single copy of the online-softmax numeric contract every
-    decode kernel shares (ragged, its stats twin, and kv_quant.py's int8
-    kernel, which dequantizes in VMEM first).
+# Keys a grid step of the ragged kernels folds: eight 16-token pages, one
+# v5e MXU tile along the key axis, a copy a page into the step's half of a
+# double buffer. tpu/chunk_attention.py measured the same choice for the
+# resume; here 64 keys a step ran 12-18% slower on 2k-8k contexts (PERF.md,
+# PR 31). A module constant: the pages a step follow the
+# cache's block size alone, never the wave's bucket (that would mint
+# programs).
+_STEP_TOKENS = 128
 
-    ``first``: traced bool — this is the request's first block, reset the
-    accumulators. ``j``: block index WITHIN the request (the ragged grid is
-    flat, so the grid step is not the block index). ``seq_len``: traced
-    scalar count of the request's valid context tokens.
 
-    q: [H, D] f32; k/v: [bt, KVH, D] f32 (already loaded from refs — all
-    dots request f32 accumulation at HIGHEST precision: XLA's DEFAULT runs
-    f32 matmuls in bf16 passes, which would quantize the statistics).
+def _key_positions(h: int, kvh: int, keys: int):
+    """[H, keys * KVH] int32, in the program: for query head ``h`` and column
+    ``c`` of a step's K (or V) tile — the step's pages as they lie in the
+    cache, one row a (key, KV head) pair, key-major — the key's index within
+    the step where column ``c`` holds the KV head that serves ``h``, and
+    2**30 (past any sequence) where it holds another's."""
+    lax, shape = jax.lax, (h, keys * kvh)
+    col = lax.broadcasted_iota(jnp.int32, shape, 1)
+    row = lax.broadcasted_iota(jnp.int32, shape, 0)
+    serves = lax.eq(lax.rem(col, np.int32(kvh)), lax.div(row, np.int32(h // kvh)))
+    return lax.select(
+        serves, lax.div(col, np.int32(kvh)), lax.full(shape, np.int32(1 << 30))
+    )
 
-    A fully-masked block is a BITWISE no-op on the scratch (alpha = exp(0)
+
+def _attn_fold(first, kpos0, seq_len, scale, q, k, v, key_pos, m_scr, l_scr,
+               acc_scr):
+    """Fold ONE grid step's keys into the running (max, denominator,
+    accumulator) scratch — the single copy of the online-softmax numeric
+    contract every decode kernel shares (ragged, its stats twin, and
+    kv_quant.py's int8 kernel, which dequantizes in VMEM first).
+
+    ``first``: traced bool — this is the row's first step, reset the
+    accumulators. ``kpos0``: position WITHIN the row's context of the
+    step's first key (the ragged grid is flat, so the grid step says
+    nothing). ``seq_len``: traced scalar count of the row's valid tokens.
+    ``scale``: 1 / sqrt(head_dim), the caller's (D may be padded).
+
+    q: [H, D]; k/v: [T * KVH, D], the step's pages as they lie in the cache
+    (a row a (key, KV head) pair, key-major: a page is [bt * KVH, D] without
+    moving a byte), T = 16 keys for the int8 kernel's one page,
+    ``_STEP_TOKENS`` for the ragged kernels; key_pos: [H, T * KVH] from
+    :func:`_key_positions`. TWO dots a step, whatever the heads: every query
+    head meets every KV head's keys in one ``[H, D] x [D, T * KVH]`` product
+    and the columns of the other KV heads are masked like keys past the
+    sequence (probability exactly 0), so ``[H, T * KVH] x [T * KVH, D]`` sums
+    a head's own keys only. Decode attention streams K and V through the MXU
+    once as its stationary operand; the rows that meet them there (32 for 4
+    of use under GQA) ride free, and no head is cut out of a page tile (a
+    sublane gather a key) or unrolled in the program.
+
+    The MXU passes follow the operands' dtype, which is all that is looked
+    at. bf16 queries on a bf16 cache: Q.K takes them as they are with f32
+    accumulation (a bf16 x bf16 product is exact in f32, so this is the
+    float32 result at one pass instead of six). P.V keeps the
+    probabilities' float32 value: p is cut into three bf16 pieces — p
+    rounded, the remainder rounded, what is then left; each remainder is
+    exact in f32 and three 8-bit significands hold f32's 24, so the pieces
+    sum to p — stacked as ROWS against V (exact in bf16 already), so V
+    still passes the MXU once, and the three partial products add up in
+    f32. Anything else (a float32 cache, the dequantized int8 pages) is cast
+    to f32 and both dots ask ``Precision.HIGHEST``: XLA's DEFAULT runs f32
+    matmuls in bf16 passes, which would quantize the statistics. Logits,
+    softmax statistics and the accumulator are f32 either way.
+
+    A fully-masked step is a BITWISE no-op on the scratch (alpha = exp(0)
     = 1, every p zeroed, l and acc multiplied by 1.0 and incremented by
-    0.0), which is what lets the ragged layout pad its flat page list, and
-    a rectangle ride it with every row's table at full width
-    (rectangle_as_ragged), without changing a single output bit."""
-    h, d = q.shape
-    bt, kvh = k.shape[0], k.shape[1]
-    groups = h // kvh
+    0.0): a rectangle's empty row and the int8 kernel's padded table
+    entries rely on it. A ragged wave's padding does not run at all."""
+    h = q.shape[0]
+    native = q.dtype == k.dtype == v.dtype == jnp.bfloat16
+    if native:
+        precision = jax.lax.Precision.DEFAULT
+    else:
+        q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+        precision = jax.lax.Precision.HIGHEST
 
     @pl.when(first)
     def _init():
@@ -91,54 +167,38 @@ def _attn_block_fold(first, j, seq_len, q, k, v, m_scr, l_scr, acc_scr):
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    scale = 1.0 / np.sqrt(d)
-
-    # Per-kv-head MXU dots, stacked head-major: logits[H, bt].
-    logits = (
-        jnp.concatenate(
-            [
-                jax.lax.dot_general(
-                    q[g * groups : (g + 1) * groups],  # [G, D]
-                    k[:, g, :],  # [bt, D]
-                    (((1,), (1,)), ((), ())),
-                    precision=jax.lax.Precision.HIGHEST,
-                )
-                for g in range(kvh)
-            ],
-            axis=0,
-        )
-        * scale
-    )
-
-    pos = j * bt + jax.lax.broadcasted_iota(jnp.int32, (h, bt), 1)
-    valid = pos < seq_len
-    logits = jnp.where(valid, logits, _NEG_INF)
+    logits = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32, precision=precision,
+    ) * scale  # [H, T * KVH]
+    valid = kpos0 + key_pos < seq_len
+    logits = jax.lax.select(valid, logits, jnp.full_like(logits, _NEG_INF))
 
     m_prev = m_scr[...]  # [H, 128] (all lanes equal)
     m_curr = jnp.max(logits, axis=1, keepdims=True)  # [H, 1]
     m_next = jnp.maximum(m_prev, m_curr)  # [H, 128]
     alpha = jnp.exp(m_prev[:, :1] - m_next[:, :1])  # [H, 1]
-    p = jnp.exp(logits - m_next[:, :1])  # [H, bt]
-    # A fully-masked block leaves m_next at _NEG_INF and exp(0)=1 would leak
+    p = jnp.exp(logits - m_next[:, :1])  # [H, T * KVH]
+    # A fully-masked step leaves m_next at _NEG_INF and exp(0)=1 would leak
     # weight onto padded slots; zero them unconditionally instead.
-    p = jnp.where(valid, p, 0.0)
+    p = jax.lax.select(valid, p, jnp.zeros_like(p))
 
     l_next = alpha * l_scr[:, :1] + jnp.sum(p, axis=1, keepdims=True)  # [H, 1]
-    pv = jnp.concatenate(
-        [
-            jax.lax.dot_general(
-                p[g * groups : (g + 1) * groups],  # [G, bt]
-                v[:, g, :],  # [bt, D]
-                (((1,), (0,)), ((), ())),
-                precision=jax.lax.Precision.HIGHEST,
-            )
-            for g in range(kvh)
-        ],
-        axis=0,
-    )  # [H, D]
+    if native:
+        hi = p.astype(v.dtype)
+        rest = p - hi.astype(jnp.float32)
+        mid = rest.astype(v.dtype)
+        lo = (rest - mid.astype(jnp.float32)).astype(v.dtype)
+        p = jnp.concatenate([hi, mid, lo], axis=0)  # [3H, T * KVH]
+    pv = jax.lax.dot_general(
+        p, v, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32, precision=precision,
+    )
+    if native:
+        pv = pv[:h] + pv[h : 2 * h] + pv[2 * h :]
     m_scr[...] = m_next
     l_scr[...] = jax.lax.broadcast_in_dim(l_next, l_scr.shape, (0, 1))
-    acc_scr[...] = acc_scr[...] * alpha + pv
+    acc_scr[...] = acc_scr[...] * alpha + pv  # [H, D]
 
 
 @jax.jit
@@ -196,12 +256,12 @@ def paged_decode_attention_xla_batched(q, k_cache, v_cache, block_tables, seq_le
 
 # ---------------------------------------------------------------------------
 # Ragged decode attention: one flat grid over the wave's CONCATENATED page
-# lists — a length-skewed wave costs sum(ceil(len_i / bt)) block folds
+# lists — a length-skewed wave costs sum(ceil(len_i / bt)) page reads
 # instead of a rectangular layout's B * max_blocks (Ragged Paged
 # Attention, PAPERS.md). The kernel never materializes gathered KV: the
-# scalar-prefetched flat page list drives the K/V BlockSpec index maps, and
-# the per-page row map decides when the online-softmax scratch resets and
-# when a row's output is finalized.
+# step table cut from the flat page list (_ragged_steps) names the pages the
+# kernel copies for a step and says which row the step belongs to, so when
+# the online-softmax scratch resets and when a row's output is finalized.
 # ---------------------------------------------------------------------------
 
 
@@ -217,8 +277,8 @@ class RaggedWaveMeta:
       row carries ONE fully-masked page so its output block is still
       written — as zeros, the framework-wide empty-row contract). The tail
       may be padded with copies of the last page to a static bucket; padded
-      entries belong to the last row and fold as fully-masked blocks, a
-      bitwise no-op (see _attn_block_fold).
+      entries belong to the last row; the kernel walks each row's real
+      pages only, so they are neither fetched nor folded.
     - ``page_rows`` [P + 1]: owning row of each flat page, non-decreasing,
       with sentinel ``page_rows[P] == R`` so ``page_rows[i + 1] != row``
       detects a row's last page without branching.
@@ -299,49 +359,139 @@ def build_ragged_wave(
     )
 
 
-def _ragged_fold(rows_ref, starts_ref, seqlen_ref, q_ref, k_ref, v_ref,
-                 m_scr, l_scr, acc_scr):
-    """Shared body of the ragged kernels: fold flat page ``i`` into its
-    row's scratch; returns (row, is_last_page_of_row)."""
+def _ragged_steps(pages, page_starts, seq_lens, bt: int, step_pages: int):
+    """The wave's flat page list cut into grid steps, in the program: a step
+    is up to ``step_pages`` consecutive pages of ONE row, a row's last step
+    may be partial, steps never span rows. Row r has ``nb_r = max(1,
+    ceil(seq_lens[r] / bt))`` real pages (:class:`RaggedWaveMeta`'s rule;
+    whatever else its span of the flat list holds — a rectangle's full-width
+    table, the wave's bucket padding — is never walked) and so
+    ``ceil(nb_r / step_pages)`` steps.
+
+    The step COUNT is static: ``R + (P - R) // step_pages`` bounds the sum
+    for any rows that fit P flat pages, so the program's shape is the
+    bucket's. Returns ``(n_real [1], step_row [S], step_page0 [S],
+    step_ids [S * step_pages])``: the real step count; per step its row and
+    the index within the row of its first page; and the cache page each of
+    its ``step_pages`` slots copies, slots past the row's last page repeating
+    that page (masked by position). Steps past ``n_real`` repeat the last
+    real step entry for entry: the query and output blocks stay where they
+    are, and the kernel starts no copy and skips the compute.
+
+    Every wave bucket's program traces and lowers this once (the layers
+    share the jitted function around the kernel), so it is written in
+    ``lax`` primitives on non-negative int32 — about thirty equations, no
+    nested function: through ``jnp``'s wrappers it cost as much set-up as
+    the kernel's body."""
+    p, r = pages.shape[0], seq_lens.shape[0]
+    n_steps = r + max(p - r, 0) // step_pages
+    lax, i32 = jax.lax, np.int32
+    over_steps = lambda x: lax.broadcast_in_dim(x, (n_steps, r), (1,))
+    per_row = lambda x: lax.broadcast_in_dim(x, (n_steps, r), (0,))
+    nb = lax.max(lax.div(seq_lens + i32(bt - 1), i32(bt)), i32(1))  # [R]
+    steps = lax.div(nb + i32(step_pages - 1), i32(step_pages))
+    ends = lax.cumsum(steps, axis=0)
+    n_real = lax.min(lax.slice(ends, (r - 1,), (r,)), i32(n_steps))  # [1]
+    s = lax.min(
+        lax.iota(jnp.int32, n_steps),
+        lax.broadcast_in_dim(n_real - i32(1), (n_steps,), (0,)),
+    )
+    # Rows that end at or before step s: their count is the step's row, the
+    # sum of their steps its row's first step. The row's own numbers come
+    # through the same [S, R] compare (R is a wave's rows: small).
+    done = lax.le(over_steps(ends), per_row(s))
+    zeros = lax.full((n_steps, r), i32(0))
+    total = lambda x: lax.reduce_sum(x, (1,))
+    row = total(lax.convert_element_type(done, jnp.int32))
+    page0 = (s - total(lax.select(done, over_steps(steps), zeros))) * i32(
+        step_pages
+    )
+    own = lax.eq(per_row(row), lax.broadcasted_iota(jnp.int32, (n_steps, r), 1))
+    first = total(lax.select(own, over_steps(page_starts), zeros))
+    last = total(lax.select(own, over_steps(nb), zeros)) - i32(1)
+    cols = lambda x: lax.broadcast_in_dim(x, (n_steps, step_pages), (0,))
+    slot = lax.min(
+        cols(page0) + lax.broadcasted_iota(jnp.int32, (n_steps, step_pages), 1),
+        cols(last),
+    )
+    flat = lax.reshape(cols(first) + slot, (n_steps * step_pages, 1))
+    ids = lax.gather(
+        pages, flat,
+        lax.GatherDimensionNumbers(
+            offset_dims=(), collapsed_slice_dims=(0,), start_index_map=(0,)
+        ),
+        slice_sizes=(1,), mode=lax.GatherScatterMode.CLIP,
+    )
+    return n_real, row, page0, ids
+
+
+def _ragged_walk(refs, n_out: int, step_pages: int, bt: int, scale: float):
+    """Shared body of the ragged kernels: fold this grid step's pages into
+    its row's scratch, or do nothing past the wave's real steps. ``refs``
+    are the kernel's: five scalar-prefetch refs — [1] real steps of the
+    wave, [S] row of each step, [S] first page of each step counted within
+    its row, [S * PG] cache page of each of a step's PG slots, [R] valid
+    context lengths — then the row's query [1, H, D], the key positions
+    [H, PG * bt * KVH] (_key_positions), the K and the V cache whole and
+    where they are ([blocks, bt * KVH, D], no block: HBM), ``n_out`` outputs,
+    and the scratch: the three softmax refs, two [2, PG * bt * KVH, D]
+    buffers a cache and a [2, 2] DMA semaphore.
+
+    The pages come in by the kernel's own copies, one a page, double
+    buffered across grid steps: step i waits for its own (started by step
+    i - 1; step 0 starts its own first) after starting step i + 1's into the
+    other half. Against one BlockSpec a page this keeps the program small —
+    two copies in a loop, not sixteen operands each with an index map of its
+    own to trace, lower and load for every wave bucket a run warms (PERF.md,
+    PR 31) — and steps past the real ones start nothing.
+
+    Returns (is the step its row's last, outputs, softmax scratch)."""
+    nreal_ref, row_ref, page0_ref, ids_ref, seqlen_ref = refs[:5]
+    q_ref, kpos_ref, k_hbm, v_hbm = refs[5:9]
+    outs = refs[9 : 9 + n_out]
+    m_scr, l_scr, acc_scr, k_buf, v_buf, sem = refs[9 + n_out :]
     i = pl.program_id(0)
-    b = rows_ref[i]
-    # First page of a row: flat index 0, or the row changed. The i == 0 arm
-    # keeps the clamped rows_ref[-1] read from aliasing row 0's own id.
-    first = jnp.logical_or(i == 0, rows_ref[jnp.maximum(i - 1, 0)] != b)
-    _attn_block_fold(
-        first,
-        i - starts_ref[b],
-        seqlen_ref[b],
-        q_ref[0].astype(jnp.float32),
-        k_ref[0].astype(jnp.float32),
-        v_ref[0].astype(jnp.float32),
-        m_scr,
-        l_scr,
-        acc_scr,
-    )
-    # rows_ref is [P + 1] with sentinel R, so i + 1 never reads past the end
-    # and the wave's very last page (padding included) finalizes its row.
-    return b, rows_ref[i + 1] != b
+    n_real = nreal_ref[0]
+    seq_len = seqlen_ref[row_ref[i]]
+    j0 = page0_ref[i]
+    rows = k_hbm.shape[1]  # bt * KVH: a page
+
+    def pages_of(step, do):
+        slot = jax.lax.rem(step, 2)
+
+        def page(j, carry):
+            pid = ids_ref[step * step_pages + j]
+            at = pl.ds(j * rows, rows)
+            do(pltpu.make_async_copy(k_hbm.at[pid], k_buf.at[slot, at], sem.at[slot, 0]))
+            do(pltpu.make_async_copy(v_hbm.at[pid], v_buf.at[slot, at], sem.at[slot, 1]))
+            return carry
+
+        jax.lax.fori_loop(0, step_pages, page, 0)
+        return slot
+
+    @pl.when(i == 0)
+    def _first():
+        pages_of(i, lambda copy: copy.start())
+
+    @pl.when(i + 1 < n_real)
+    def _ahead():
+        pages_of(i + 1, lambda copy: copy.start())
+
+    @pl.when(i < n_real)
+    def _fold():
+        slot = pages_of(i, lambda copy: copy.wait())
+        _attn_fold(
+            j0 == 0, j0 * bt, seq_len, scale, q_ref[0], k_buf[slot],
+            v_buf[slot], kpos_ref[...], m_scr, l_scr, acc_scr,
+        )
+
+    n_pages = jnp.maximum(1, jax.lax.div(seq_len + (bt - 1), bt))
+    last = jnp.logical_and(i < n_real, j0 + step_pages >= n_pages)
+    return last, outs, (m_scr, l_scr, acc_scr)
 
 
-def _ragged_attn_kernel(
-    rows_ref,  # scalar-prefetch: [P + 1] int32 owning row per page
-    pages_ref,  # scalar-prefetch: [P] int32 flat page list (drives DMA)
-    starts_ref,  # scalar-prefetch: [R] int32 first flat index per row
-    seqlen_ref,  # scalar-prefetch: [R] int32 valid context lengths
-    q_ref,  # [1, H, D] this row's query
-    k_ref,  # [1, bt, KVH, D] one cache page
-    v_ref,  # [1, bt, KVH, D]
-    out_ref,  # [1, H, D]
-    m_scr,  # VMEM [H, 128] f32
-    l_scr,  # VMEM [H, 128] f32
-    acc_scr,  # VMEM [H, D] f32
-):
-    del pages_ref
-    _, last = _ragged_fold(
-        rows_ref, starts_ref, seqlen_ref, q_ref, k_ref, v_ref,
-        m_scr, l_scr, acc_scr,
-    )
+def _ragged_attn_kernel(*refs, **walk):
+    last, (out_ref,), (_, l_scr, acc_scr) = _ragged_walk(refs, 1, **walk)
 
     @pl.when(last)
     def _finish():
@@ -350,20 +500,13 @@ def _ragged_attn_kernel(
         ).astype(out_ref.dtype)
 
 
-def _ragged_attn_stats_kernel(
-    rows_ref, pages_ref, starts_ref, seqlen_ref,
-    q_ref, k_ref, v_ref,
-    acc_ref,  # [1, H, D] f32 unnormalized numerator
-    m_ref,  # [1, H, 128] f32
-    l_ref,  # [1, H, 128] f32
-    m_scr, l_scr, acc_scr,
-):
-    """Ragged online softmax emitting raw (acc, m, l) — the shard-local
-    half of ragged sharded decode (combined with one pmax and two psum)."""
-    del pages_ref
-    _, last = _ragged_fold(
-        rows_ref, starts_ref, seqlen_ref, q_ref, k_ref, v_ref,
-        m_scr, l_scr, acc_scr,
+def _ragged_attn_stats_kernel(*refs, **walk):
+    """Ragged online softmax emitting raw (acc [1, H, D] f32 unnormalized,
+    m, l [1, H, 128] f32) — the shard-local half of ragged sharded decode
+    (combined with one pmax and two psum). The same walk, another last
+    step."""
+    last, (acc_ref, m_ref, l_ref), (m_scr, l_scr, acc_scr) = _ragged_walk(
+        refs, 3, **walk
     )
 
     @pl.when(last)
@@ -373,43 +516,85 @@ def _ragged_attn_stats_kernel(
         l_ref[0] = l_scr[...]
 
 
-def _ragged_grid_spec(h, d, bt, kvh, p, out_specs):
-    block = (1, bt, kvh, d)
-    return pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(p,),
-        in_specs=[
-            pl.BlockSpec((1, h, d), lambda i, rows, pages, st, sl: (rows[i], 0, 0)),
-            pl.BlockSpec(block, lambda i, rows, pages, st, sl: (pages[i], 0, 0, 0)),
-            pl.BlockSpec(block, lambda i, rows, pages, st, sl: (pages[i], 0, 0, 0)),
-        ],
-        out_specs=out_specs,
-        scratch_shapes=[
-            pltpu.VMEM((h, 128), jnp.float32),
-            pltpu.VMEM((h, 128), jnp.float32),
-            pltpu.VMEM((h, d), jnp.float32),
-        ],
+# Above the compiler's default scoped limit, far under a core's VMEM: at 32
+# KV heads a step's logits are [32, 4096] f32 beside 4 MiB of page buffers.
+_VMEM_LIMIT = 64 << 20
+
+
+def _ragged_call(kernel, outs, q, k_cache, v_cache, pages, page_starts,
+                 seq_lens, interpret):
+    """One ``pallas_call`` of a ragged kernel over the wave's steps; every
+    output is [R, H, width] (``outs``: a (width, dtype) each, width None for
+    the head dim) and its block its row's, indexed like the query's. The cache goes in as [blocks, bt * KVH, D] — the same
+    bytes, a page a [bt * KVH, D] tile."""
+    r, h, d = q.shape
+    n, bt, kvh, _ = k_cache.shape
+    step_pages = max(1, _STEP_TOKENS // bt)
+    n_real, step_row, step_page0, step_ids = _ragged_steps(
+        pages, page_starts, seq_lens, bt, step_pages
     )
+    # The kernel's copies take whole lane tiles: a head_dim under one (the
+    # demo geometries' 16 and 32; no serving configuration) rides zero-padded
+    # — a copy of the cache a call, where nothing is timed.
+    lanes = -(-d // 128) * 128
+    if lanes != d:
+        pad = lambda x: jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, lanes - d)])
+        q, k_cache, v_cache = pad(q), pad(k_cache), pad(v_cache)
+    by_row = lambda i, n, rows, p0, ids, sl: (rows[i], 0, 0)
+    key_pos = _key_positions(h, kvh, step_pages * bt)
+    page_buffer = pltpu.VMEM((2, step_pages * bt * kvh, lanes), k_cache.dtype)
+    widths = [lanes if w is None else w for w, _ in outs]
+    got = pl.pallas_call(
+        functools.partial(
+            kernel, step_pages=step_pages, bt=bt, scale=1.0 / np.sqrt(d)
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=5,
+            grid=(step_row.shape[0],),
+            in_specs=[
+                pl.BlockSpec((1, h, lanes), by_row),
+                pl.BlockSpec(key_pos.shape, lambda i, *_: (0, 0)),
+                pl.BlockSpec(memory_space=pltpu.HBM),
+                pl.BlockSpec(memory_space=pltpu.HBM),
+            ],
+            out_specs=[pl.BlockSpec((1, h, w), by_row) for w in widths],
+            scratch_shapes=[
+                pltpu.VMEM((h, 128), jnp.float32),
+                pltpu.VMEM((h, 128), jnp.float32),
+                pltpu.VMEM((h, lanes), jnp.float32),
+                page_buffer,
+                page_buffer,
+                pltpu.SemaphoreType.DMA((2, 2)),
+            ],
+        ),
+        out_shape=[
+            _varying_like((r, h, w), dt, q, k_cache, v_cache, pages, seq_lens)
+            for w, (_, dt) in zip(widths, outs)
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",), vmem_limit_bytes=_VMEM_LIMIT
+        ),
+        interpret=interpret,
+    )(
+        n_real, step_row, step_page0, step_ids, seq_lens, q, key_pos,
+        k_cache.reshape(n, bt * kvh, lanes), v_cache.reshape(n, bt * kvh, lanes),
+    )
+    return [o if w is not None else o[..., :d] for o, (w, _) in zip(got, outs)]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _paged_decode_attention_pallas_ragged(
     q, k_cache, v_cache, pages, page_rows, page_starts, seq_lens, *, interpret
 ):
-    """q: [R, H, D]; flat metadata per RaggedWaveMeta's layout contract."""
-    r, h, d = q.shape
-    _, bt, kvh, _ = k_cache.shape
-    p = pages.shape[0]
-    grid_spec = _ragged_grid_spec(
-        h, d, bt, kvh, p,
-        pl.BlockSpec((1, h, d), lambda i, rows, pages, st, sl: (rows[i], 0, 0)),
+    """q: [R, H, D]; flat metadata per RaggedWaveMeta's layout contract
+    (``page_rows`` is not read: the steps follow ``page_starts`` and
+    ``seq_lens``, _ragged_steps)."""
+    del page_rows
+    (out,) = _ragged_call(
+        _ragged_attn_kernel, [(None, q.dtype)],
+        q, k_cache, v_cache, pages, page_starts, seq_lens, interpret,
     )
-    return pl.pallas_call(
-        _ragged_attn_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((r, h, d), q.dtype),
-        interpret=interpret,
-    )(page_rows, pages, page_starts, seq_lens, q, k_cache, v_cache)
+    return out
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -417,29 +602,12 @@ def _paged_decode_attention_pallas_ragged_stats(
     q, k_cache, v_cache, pages, page_rows, page_starts, seq_lens, *, interpret
 ):
     """Raw ragged (acc, m, l): acc [R,H,D] f32, m/l [R,H,1] f32."""
-    r, h, d = q.shape
-    _, bt, kvh, _ = k_cache.shape
-    p = pages.shape[0]
-    out = lambda i, rows, pages, st, sl: (rows[i], 0, 0)
-    grid_spec = _ragged_grid_spec(
-        h, d, bt, kvh, p,
-        [
-            pl.BlockSpec((1, h, d), out),
-            pl.BlockSpec((1, h, 128), out),
-            pl.BlockSpec((1, h, 128), out),
-        ],
-    )
-    operands = (page_rows, pages, page_starts, seq_lens, q, k_cache, v_cache)
-    acc, m, l = pl.pallas_call(
+    del page_rows
+    acc, m, l = _ragged_call(
         _ragged_attn_stats_kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            _varying_like((r, h, d), jnp.float32, *operands),
-            _varying_like((r, h, 128), jnp.float32, *operands),
-            _varying_like((r, h, 128), jnp.float32, *operands),
-        ],
-        interpret=interpret,
-    )(*operands)
+        [(None, jnp.float32), (128, jnp.float32), (128, jnp.float32)],
+        q, k_cache, v_cache, pages, page_starts, seq_lens, interpret,
+    )
     return acc, m[:, :, :1], l[:, :, :1]
 
 
@@ -477,7 +645,7 @@ def paged_decode_attention_ragged(
     :class:`RaggedWaveMeta` (use :func:`build_ragged_wave`). ``table_width``
     (static): max pages any row spans — only the XLA fallback uses it, to
     reconstruct rectangular tables for its gather. On TPU one fused kernel
-    walks the flat page list: sum(ceil(len_i / bt)) block folds total, so
+    walks the flat page list: sum(ceil(len_i / bt)) page reads total, so
     an 8:1 length-skewed wave costs ~the mean length, not B x max. Rows
     with seq_len 0 return zeros on every backend."""
     if paged._use_pallas():
@@ -501,12 +669,11 @@ def paged_decode_attention_ragged(
 def rectangle_as_ragged(block_tables):
     """Ragged metadata ``(pages, page_rows, page_starts)`` of a RECTANGULAR
     wave: ``block_tables`` [B, M], one full-width table a row. Row b owns
-    flat pages ``[b * M, (b + 1) * M)``; those past its sequence fold fully
-    masked, a bitwise no-op (_attn_block_fold), and a zero-length row still
-    writes zeros. Static given the shape, so it traces inside a jit: what
-    the callers that hold a rectangle (one decode token, the disagg decode
-    layer) hand :func:`paged_decode_attention_rows`, at the B x M grid steps
-    a rectangular kernel would take."""
+    flat pages ``[b * M, (b + 1) * M)``; those past its sequence are not
+    walked (the steps follow ``seq_lens``, _ragged_steps), and a zero-length
+    row still writes zeros. Static given the shape, so it traces inside a
+    jit: what the callers that hold a rectangle (one decode token, the
+    disagg decode layer) hand :func:`paged_decode_attention_rows`."""
     b, m = block_tables.shape
     rows = jnp.arange(b + 1, dtype=jnp.int32)
     return (
@@ -526,8 +693,9 @@ def paged_decode_attention_rows(
     (:class:`RaggedWaveMeta`'s layout, from :func:`build_ragged_wave` on
     the host or :func:`rectangle_as_ragged` in a jit) describes a second
     time. A row with ``seq_lens[r] == 0`` returns zeros. On TPU the flat
-    metadata routes to the ragged kernel (one grid step a page, no
-    B x max_blocks grid); elsewhere the XLA body gathers ``row_tables``.
+    metadata routes to the ragged kernel (a grid step folds up to
+    ``_STEP_TOKENS`` keys of one row, no B x max_blocks grid); elsewhere the
+    XLA body gathers ``row_tables``.
     Every caller in models/llama.py comes through here (the wave body
     verify_step_ragged, decode_step as its one-row view, the disagg
     decode_wave_layer), so a wave and the same tokens decoded one at a time

@@ -296,6 +296,9 @@ def test_wave_sizes_bucket_to_powers_of_two(conn, params, monkeypatch):
     # at most T_bucket - B rows per wave — strictly no more than the old
     # rectangle's (B_bucket - B) duplicated rows at K = 1.
     assert 0.0 <= m["wave_pad_fraction"] < 0.5, m["wave_pad_fraction"]
+    # The page ledger beside it: padding is what a power of two adds to a
+    # sum, so under half of what was launched.
+    assert 0 <= m["wave_pad_pages"] < m["wave_pages"] / 2, m
 
 
 def test_ngram_drafter_proposes_recurring_continuations():
@@ -457,6 +460,10 @@ def test_ragged_wave_byte_identical_to_sequential_decode(params):
     # Ragged pad accounting: 5 real flat rows bucket to 8 (3 pad rows) —
     # the rectangle would have launched 4 requests x 4-token chunks = 16.
     assert (wave.launched_rows, wave.pad_rows) == (8, 3)
+    # Page accounting: every flat row (the three that repeat the last one
+    # too) attends ceil((pos + 1) / 8) = 3 pages, 24 of a bucket of 32.
+    assert (wave.wave_pages, wave.wave_pad_pages) == (32, 8)
+    assert wave.bucket_sizes == {(4, 8, 32)}
 
 
 def test_wave_decoder_failure_fails_all_waiters(params):

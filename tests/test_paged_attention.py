@@ -198,7 +198,9 @@ def test_decode_wave_layer_chain_equals_ragged_wave_body(geom, monkeypatch):
         params, tokens.reshape(-1), positions.reshape(-1), row_of,
         *rectangle_as_ragged(row_tables), caches, block_tables, cfg, max_blocks,
     )
-    assert len(calls) == 2 * cfg.n_layers, "verify_step_ragged left the dispatcher"
+    # The wave body traces ONE layer for all of them (models/llama.py
+    # _wave_layer), so it reaches the dispatcher once.
+    assert len(calls) == cfg.n_layers + 1, "verify_step_ragged left the dispatcher"
     np.testing.assert_allclose(
         np.asarray(layer_logits).reshape(bsz * kk, -1), np.asarray(wave_logits),
         rtol=2e-5, atol=2e-5,
@@ -209,7 +211,7 @@ def test_decode_wave_layer_chain_equals_ragged_wave_body(geom, monkeypatch):
         params, tokens[0, 0], positions[0, 0], caches, block_tables[0], cfg,
         max_blocks,
     )
-    assert len(calls) == 3 * cfg.n_layers, "decode_step left the dispatcher"
+    assert len(calls) == cfg.n_layers + 2, "decode_step left the dispatcher"
     np.testing.assert_allclose(
         np.asarray(one_logits), np.asarray(wave_logits[0]), rtol=2e-5, atol=2e-5
     )
@@ -225,9 +227,35 @@ def _ragged_meta(tables, seq_lens, bt, pad_to=0):
     )
 
 
-@pytest.mark.parametrize("wave", ["one_row", "rectangle", "mixed"])
-@pytest.mark.parametrize("case", CASES)
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+# The two serving geometries' head counts (Mistral's GQA 32/8, DeepSeek's
+# MHA 32/32) at a small head_dim, 16-token blocks (8 pages a grid step).
+SERVING_CASES = [
+    (48, 16, 8, 16, 32, 20),
+    (48, 16, 32, 16, 32, 20),
+]
+LAYOUT_WAVES = ["one_row", "rectangle", "mixed"]
+# What the walk by grid steps has to get right: rows whose page count is not
+# a multiple of the pages a step; a bucket whose padding is longer than its
+# real pages; an empty row first, in the middle, last.
+STEP_WAVES = [
+    "step_edges", "long_padding", "zero_first", "zero_middle", "zero_last",
+]
+RAGGED_PARAMS = [
+    pytest.param(case, dtype, wave, id=f"{wave}-{name}-{dtype.__name__}")
+    for cases, waves in (
+        (list(zip(CASES, ["gqa2", "gqa4", "mha", "mqa"])), LAYOUT_WAVES),
+        (
+            list(zip(SERVING_CASES + CASES[3:], ["gqa32_8", "mha32_32", "mqa"])),
+            STEP_WAVES,
+        ),
+    )
+    for wave in waves
+    for case, name in cases
+    for dtype in (jnp.float32, jnp.bfloat16)
+]
+
+
+@pytest.mark.parametrize("case, dtype, wave", RAGGED_PARAMS)
 def test_ragged_kernel_matches_oracle(case, dtype, wave):
     """The ragged kernel (flat page list, interpret mode) against the numpy
     oracle across GQA shapes, dtypes and wave layouts. ``one_row``: what
@@ -237,8 +265,12 @@ def test_ragged_kernel_matches_oracle(case, dtype, wave):
     full-width tables with uneven lengths and one row empty. Both through
     the in-jit metadata (rectangle_as_ragged). ``mixed``: host-built
     metadata (build_ragged_wave) for waves of 3 and 8 with skewed seq_lens,
-    a seq_len=1 row next to a near-max one."""
+    a seq_len=1 row next to a near-max one. ``STEP_WAVES`` (host-built, at
+    the serving head counts and MQA): the walk by grid steps of several
+    pages. float32 agrees to float32 rounding — the proof that the walk
+    leaves nothing out of the mathematics — and bf16 to bf16's."""
     from infinistore_tpu.tpu.paged_attention import (
+        _STEP_TOKENS,
         _paged_decode_attention_pallas_ragged,
         paged_decode_attention_ragged,
         paged_decode_attention_xla_batched,
@@ -251,6 +283,15 @@ def test_ragged_kernel_matches_oracle(case, dtype, wave):
     v_cache = jnp.asarray(rng.standard_normal((n, bt, kvh, d)), dtype)
     tol = 1e-5 if dtype == jnp.float32 else 2e-2
     full = ntbl * bt
+    step = max(1, _STEP_TOKENS // bt)  # pages a grid step
+    # Rows of 1, step - 1, step, step + 1 and 2 x step + 1 pages (as far as
+    # the table goes), the last page partial in some.
+    edges = [
+        min(pages, ntbl) * bt - cut
+        for pages, cut in (
+            (1, 3), (step - 1, 0), (step, 1), (step + 1, bt - 1), (2 * step + 1, 2),
+        )
+    ]
     waves = {
         "one_row": [[1], [bt - 1], [bt], [full // 2 + 3], [full]],
         "rectangle": [[1, full, 0, full // 2 + 1, bt]],
@@ -258,16 +299,23 @@ def test_ragged_kernel_matches_oracle(case, dtype, wave):
             [1, full, full // 2 + 1],  # seq_len=1 beside a near-max row
             [1, full, 3, full - 1, bt, bt - 1, full // 2, 2],
         ],
+        "step_edges": [edges, edges[::-1]],
+        "long_padding": [[bt + 1, 3]],  # 3 real pages in a bucket of 64
+        "zero_first": [[0, full - 1, bt]],
+        "zero_middle": [[full // 2, 0, 0, bt + 1]],
+        "zero_last": [[bt, full, 0]],
     }[wave]
     for lens in waves:
         bsz = len(lens)
         q = jnp.asarray(rng.standard_normal((bsz, h, d)), dtype)
         tables = [rng.permutation(n)[:ntbl] for _ in range(bsz)]
         rect = jnp.asarray(np.stack(tables), jnp.int32)
-        if wave != "mixed":
+        if wave in ("one_row", "rectangle"):
             meta = (*jax.jit(rectangle_as_ragged)(rect), jnp.asarray(lens, jnp.int32))
         else:
-            meta = _ragged_meta(tables, lens, bt)
+            meta = _ragged_meta(
+                tables, lens, bt, pad_to=64 if wave == "long_padding" else 0
+            )
         got = _paged_decode_attention_pallas_ragged(
             q, k_cache, v_cache, *meta, interpret=True
         )
@@ -296,8 +344,10 @@ def test_ragged_kernel_matches_oracle(case, dtype, wave):
 
 def test_ragged_padding_pages_are_bitwise_noops():
     """Bucket-padding the flat page list (what the engine does to bound jit
-    compiles) must not change one output bit: padded pages fold fully
-    masked — alpha = 1, p = 0 (see _attn_block_fold)."""
+    compiles) must not change one output bit: the padding is past the
+    wave's real steps, which the kernel neither fetches nor computes (and a
+    step that did run fully masked would still be a bitwise no-op: alpha =
+    1, p = 0, see _attn_fold)."""
     from infinistore_tpu.tpu.paged_attention import (
         _paged_decode_attention_pallas_ragged,
     )

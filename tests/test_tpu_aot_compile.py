@@ -18,6 +18,7 @@ prefix hit also the benchmark's two reuse cells (``RESUMES``).
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -261,6 +262,146 @@ def test_rectangle_views_compile_with_the_ragged_kernel(v5e, monkeypatch, view):
     lowered = traced.lower(lowering_platforms=("tpu",))
     assert 'kernel_name = "_ragged_attn_kernel"' in lowered.as_text()
     assert "tpu_custom_call" in lowered.compile().as_text()
+
+
+# The benchmark's two configurations at the decode kernel (bf16, 16-token
+# blocks, head_dim 128): Mistral's GQA 32/8, DeepSeek's MHA 32/32.
+# (name, q heads, kv heads)
+SERVING_HEADS = [("mistral", 32, 8), ("deepseek", 32, 32)]
+
+
+def _count_primitive(jaxpr, name=None):
+    """Equations named ``name`` (all of them if None) in ``jaxpr`` and every
+    jaxpr under it."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += name in (None, eqn.primitive.name)
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    n += _count_primitive(sub, name)
+    return n
+
+
+@pytest.mark.parametrize("heads", SERVING_HEADS, ids=[c[0] for c in SERVING_HEADS])
+@pytest.mark.parametrize("twin", ["attend", "stats"])
+def test_ragged_kernel_body_stays_small(heads, twin):
+    """A guard on the program's size, with no clock in it. Every wave
+    bucket a run warms (24 in the chat cell) traces, lowers and loads the
+    decode kernel's body again, so what the body holds is paid a bucket in
+    set-up: a kernel that unrolled its heads and pages cost one cell 6 s of
+    ``setup_s`` and the PR its acceptance (PERF.md, PR 30). The body holds
+    TWO ``dot_general`` whatever the heads and the pages a step — never
+    more than the two a KV head of the kernel before — and its size does
+    not follow the head count."""
+    _, h, kvh = heads
+    fn = (
+        pa._paged_decode_attention_pallas_ragged if twin == "attend"
+        else pa._paged_decode_attention_pallas_ragged_stats
+    )
+    s = jax.ShapeDtypeStruct
+    cache = s((NUM_BLOCKS, 16, kvh, 128), jnp.bfloat16)
+    i32 = lambda n: s((n,), jnp.int32)
+    traced = fn.trace(
+        s((ROWS, h, 128), jnp.bfloat16), cache, cache, i32(PAGES),
+        i32(PAGES + 1), i32(ROWS), i32(ROWS), interpret=False,
+    )
+    (call,) = [e for e in traced.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    body = call.params["jaxpr"]
+    assert _count_primitive(body, "dot_general") == 2 <= 2 * kvh
+    # About a hundred equations (PR 31), the same for 8 KV heads and 32;
+    # the per-head body it replaced held 138 and 420.
+    assert _count_primitive(body) < 130, _count_primitive(body)
+    # The step table is built by the program around the kernel: a few dozen
+    # primitive equations, no nested function to lower.
+    around = [e.primitive.name for e in traced.jaxpr.eqns]
+    assert len(around) < 80 and "jit" not in around and "pjit" not in around, around
+
+
+def _dots(jaxpr):
+    """Every ``dot_general`` equation in ``jaxpr`` and the jaxprs under it."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            yield eqn
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _dots(sub)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32], ids=["bf16", "f32"])
+def test_ragged_kernel_precision_follows_the_operands_dtype(dtype):
+    """No switch chooses the MXU passes: a bf16 cache under bf16 queries
+    sends both dots native operands with float32 accumulation (default
+    precision: one pass a product that is exact in float32), a float32
+    cache asks ``Precision.HIGHEST`` of float32 operands, as before PR 31."""
+    s = jax.ShapeDtypeStruct
+    cache = s((NUM_BLOCKS, 16, 8, 128), dtype)
+    i32 = lambda n: s((n,), jnp.int32)
+    traced = pa._paged_decode_attention_pallas_ragged.trace(
+        s((ROWS, 32, 128), dtype), cache, cache, i32(PAGES), i32(PAGES + 1),
+        i32(ROWS), i32(ROWS), interpret=False,
+    )
+    (call,) = [e for e in traced.jaxpr.eqns if e.primitive.name == "pallas_call"]
+    dots = list(_dots(call.params["jaxpr"]))
+    assert len(dots) == 2
+    highest = (jax.lax.Precision.HIGHEST, jax.lax.Precision.HIGHEST)
+    for dot in dots:
+        assert dot.params["preferred_element_type"] == jnp.float32
+        assert {v.aval.dtype for v in dot.invars} == {jnp.dtype(dtype)}
+        if dtype == jnp.float32:
+            assert dot.params["precision"] == highest
+        else:
+            assert dot.params["precision"] in (
+                None, jax.lax.Precision.DEFAULT,
+                (jax.lax.Precision.DEFAULT, jax.lax.Precision.DEFAULT),
+            )
+
+
+def test_wave_program_shares_one_layer_and_one_kernel_function(v5e, monkeypatch):
+    """One chat bucket of ``verify_step_ragged`` (8 rows, 1,024 flat pages,
+    Mistral's attention widths, 16 layers; the FFN and the vocabulary, which
+    the kernel never sees, kept small) lowered for a v5e: the 16 layers call
+    ONE lowered layer function, which holds ONE function around the Mosaic
+    kernel — the jits are what makes them share — under the name the
+    benchmark's ``decode_attn_roofline`` finds the device op by. What the
+    program holds once, a wave bucket's set-up pays once."""
+    from infinistore_tpu.models import llama
+
+    monkeypatch.setattr(paged, "_use_pallas", lambda: True)
+    cfg = llama.LlamaConfig(
+        vocab=1024, dim=4096, n_layers=16, n_heads=32, n_kv_heads=8,
+        ffn_dim=1024, block_tokens=16, dtype=jnp.bfloat16,
+    )
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=v5e)
+    params = jax.tree.map(
+        lambda x: s(x.shape, x.dtype),
+        jax.eval_shape(lambda: llama.init_params(cfg, jax.random.PRNGKey(0))),
+    )
+    cache = s(cfg.kv_spec(640).cache_shape, cfg.dtype)
+    i32 = lambda *shape: s(shape, jnp.int32)
+    rows, pages, table = 8, 1024, 80
+    text = (
+        jax.jit(
+            llama.verify_step_ragged.__wrapped__,
+            static_argnames=("config", "max_blocks"),
+        )
+        .trace(
+            params, i32(rows), i32(rows), i32(rows), i32(pages), i32(pages + 1),
+            i32(rows), [(cache, cache)] * cfg.n_layers, i32(rows, table),
+            config=cfg, max_blocks=table,
+        )
+        .lower(lowering_platforms=("tpu",))
+        .as_text()
+    )
+    layers = re.findall(r"call @(_wave_layer\w*)\(", text)
+    assert len(layers) == cfg.n_layers and len(set(layers)) == 1, layers
+    calls = re.findall(r"call @(\w*paged_decode_attention_pallas_ragged\w*)\(", text)
+    assert len(calls) == 1, calls
+    assert text.count("tpu_custom_call") == 1
+    assert text.count('kernel_name = "_ragged_attn_kernel"') == 1
 
 
 def test_sharded_decode_compiles_for_four_chips(monkeypatch):
