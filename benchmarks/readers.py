@@ -148,15 +148,21 @@ def _trace_time(run: Run, p: Dict):
 
 
 def _trace_roofline(run: Run, p: Dict):
-    """Useful work of the traced calls over what the chip could have done in
-    the kernel's device time, in percent."""
+    """The least time the chip could have taken for the useful work of the
+    traced calls over the kernel's device time, in percent: ``cost`` over
+    ``peak``, or where the reader names a second bound (``or_cost`` over
+    ``or_peak``: operations beside bytes) the larger of the two, which is
+    the one that binds."""
     if run.trace is None:
         return None
     seconds, events = trace_reduce.matching(run.trace["ops"], p["pattern"])
-    work = run.trace["work"].get(p["cost"], 0)
-    if events == 0 or seconds <= 0 or work <= 0:
+    work = run.trace["work"]
+    least = work.get(p["cost"], 0) / run.peaks[p["peak"]]
+    if "or_cost" in p:
+        least = max(least, work.get(p["or_cost"], 0) / run.peaks[p["or_peak"]])
+    if events == 0 or seconds <= 0 or least <= 0:
         return None
-    return 100.0 * work / (run.peaks[p["peak"]] * seconds)
+    return 100.0 * least / seconds
 
 
 def _trace_idle(run: Run, p: Dict):

@@ -56,7 +56,9 @@ import readers  # noqa: E402
 import span_readers  # noqa: E402
 import trace_reduce  # noqa: E402
 import traffic  # noqa: E402
-from cache_geometry import CacheGeometry, hit_mismatch  # noqa: E402
+from cache_geometry import (  # noqa: E402
+    POOL_EVICTS_FROM, CacheGeometry, hit_mismatch, pool_gib, store_layout,
+)
 from infinistore_tpu import tracing  # noqa: E402 - the system under test and its recorder
 
 # Logits against the float32 reference, as multiples of the reference
@@ -150,14 +152,14 @@ def build_native_if_missing():
         )
 
 
-def start_server(pool_gib: int, block_kib: int) -> Dict:
+def start_server(pool_gib: int, unit_kib: int) -> Dict:
     from infinistore_tpu.hostmesh import cpu_child_env
 
     service, manage = free_port(), free_port()
     argv = [
         sys.executable, "-m", "infinistore_tpu.server", "--host", "127.0.0.1",
         "--service-port", str(service), "--manage-port", str(manage),
-        "--prealloc-size", str(pool_gib), "--minimal-allocate-size", str(block_kib),
+        "--prealloc-size", str(pool_gib), "--minimal-allocate-size", str(unit_kib),
         "--no-pin-memory", "--log-level", "error",
     ]
     proc = subprocess.Popen(argv, cwd=REPO, env=cpu_child_env())
@@ -226,6 +228,7 @@ class Instruments:
         self.by_task: Dict[object, Record] = {}
         self.keep_logits = False
         self.prefills: List[tuple] = []  # (t_start, tokens)
+        self.resumes: List[tuple] = []  # (t_start, context pages, chunk rows)
         self._step_chunk = harness.wave.step_chunk
         self._alloc = harness.pool.alloc
         self._prefill_full = harness._prefill_full
@@ -258,6 +261,9 @@ class Instruments:
             return self._prefill_full(token_ids, table)
 
     def chunked_resume(self, token_ids, table, start_block):
+        # What the program counts itself (``resume_pages``, ``resume_tokens``).
+        rows = len(token_ids) - start_block * self.bt
+        self.resumes.append((time.perf_counter(), -(-len(token_ids) // self.bt), rows))
         with self._annotate("bench.chunked_resume"):
             return self._chunked_resume(token_ids, table, start_block)
 
@@ -369,15 +375,26 @@ class CellRun:
         self.taps = Instruments(self.h, bt)
         self.compiles = compiles
         self.conn = conn
-        self.read_program_counters()  # a key that is nowhere stops the run here
+        for key in set(self.program_counters) - set(self.read_program_counters()):
+            # A metric file may come with the counter it reads: on a tree
+            # that has no such counter yet the metric is left out of the line.
+            print(f"benchmarks/run.py: counter {key!r} is neither in harness.metrics() nor in "
+                  f"the connector's get_stats(): no metric of {self.cell['name']} reads it in "
+                  f"this run", file=sys.stderr, flush=True)
 
     @staticmethod
     def keep_host_copy(saved, chains, caches, block_table):
+        """The blocks handed to a save, copied to the host: one gather a
+        tensor of those blocks alone, never the whole cache."""
+        import jax.numpy as jnp
         import numpy as np
 
-        host = [[np.asarray(t) for t in layer] for layer in caches]
-        for chain, blk in zip(chains, np.asarray(block_table)):
-            saved[chain] = [[t[blk].tobytes() for t in layer] for layer in host]
+        from infinistore_tpu.tpu.paged import gather_blocks
+
+        ids = jnp.asarray(np.asarray(block_table[: len(chains)]), jnp.int32)
+        host = [[np.asarray(gather_blocks(t, ids)) for t in layer] for layer in caches]
+        for i, chain in enumerate(chains):
+            saved[chain] = [[t[i].tobytes() for t in layer] for layer in host]
 
     def wave_buckets(self) -> List[tuple]:
         """Every (rows, pages) bucket a wave of this traffic can land on.
@@ -566,7 +583,9 @@ class CellRun:
     def read_program_counters(self) -> Dict[str, float]:
         """The program's counters this cell's metrics name, as they stand:
         a key of ``harness.metrics()``, else of the connector's
-        ``get_stats()`` (dotted where nested). No call where none is named."""
+        ``get_stats()`` (dotted where nested). A key that is in neither is
+        left out (``readers._counter`` then gives None for its metric); one
+        that is there and is no number raises. No call where none is named."""
         if not self.program_counters:
             return {}
         metrics, stats = self.h.metrics(), self.adapter.connector.get_stats()
@@ -577,6 +596,8 @@ class CellRun:
                 value = stats
                 for part in key.split("."):
                     value = value.get(part) if isinstance(value, dict) else None
+            if value is None:
+                continue
             if isinstance(value, bool) or not isinstance(value, (int, float)):
                 raise ValueError(
                     f"counter {key!r} is no number in harness.metrics() or the connector's "
@@ -622,6 +643,7 @@ class CellRun:
                 raise RuntimeError(f"warm-up request failed: {rec.error}")
         self.records.clear()
         self.taps.prefills.clear()
+        self.taps.resumes.clear()
         gc.collect()
         gc.freeze()
         if self.plan.loop == "closed":
@@ -677,10 +699,14 @@ class CellRun:
 
             async def install_and_keep(prefetch, caches, block_table):
                 out, loaded = await real_install(prefetch, caches, block_table)
-                n = loaded // bt
-                ids = jnp.asarray(np.asarray(block_table[:n]), jnp.int32)
+                table = np.asarray(block_table)
+                # Only the blocks the policy says this hit installed.
                 held["blocks"] = [
-                    [np.asarray(gather_blocks(t, ids)) for t in layer] for layer in out
+                    [
+                        np.asarray(gather_blocks(t, jnp.asarray(table[blocks.start : blocks.stop], jnp.int32)))
+                        for t, blocks in zip(layer, names)
+                    ]
+                    for layer, names in zip(out, self.geometry.installed_blocks(loaded // bt))
                 ]
                 return out, loaded
 
@@ -696,6 +722,7 @@ class CellRun:
                 hit.stats.loaded_blocks == n and hit.stats.computed_blocks == 0,
                 f"{label}: the second ask loaded {hit.stats.loaded_blocks} of {n} blocks",
             )
+            self.expect_fetch(label + " hit", hit.stats)
             chains = token_chain_hashes(tokens, bt)[:n]
             wrong = hit_mismatch(held.get("blocks", ()), self.adapter.saved, chains, self.geometry)
             self.expect(wrong is None, f"{label}: installed blocks: {wrong}")
@@ -713,6 +740,7 @@ class CellRun:
                         part.stats.loaded_blocks == r.prefix_tokens // bt,
                         f"{label}: partial hit loaded {part.stats.loaded_blocks} blocks",
                     )
+                    self.expect_fetch(label + " partial hit", part.stats)
                     self.against_reference(
                         label + " partial hit", part,
                         traffic.token_ids(part_req, self.args.seed, self.cfg.vocab),
@@ -726,6 +754,16 @@ class CellRun:
             self.failed_checks.append(what)
             print(f"check failed: {what}", file=sys.stderr, flush=True)
         return ok
+
+    def expect_fetch(self, label: str, stats) -> bool:
+        """The fetch follows the policy too: a hit of n blocks fetched the
+        store values the configuration says it installs, no more, no fewer."""
+        want = self.geometry.fetched_values(stats.hit_blocks)
+        return self.expect(
+            stats.prefetched_blocks == want,
+            f"{label}: fetched {stats.prefetched_blocks} store values for a hit of "
+            f"{stats.hit_blocks} blocks, the configuration's policy names {want}",
+        )
 
     def against_reference(self, label: str, rec: Record, tokens: List[int]):
         """Round j of the request decodes position len - 1 + j: the logits
@@ -766,6 +804,8 @@ class CellRun:
             row.update(
                 hit=s.loaded_blocks > 0,
                 loaded_blocks=s.loaded_blocks,
+                hit_blocks=s.hit_blocks,
+                fetched_values=s.prefetched_blocks,
                 gate_stall_ms=s.gate_stall_us / 1e3,
                 prefix_ready_ms=s.prefix_ready_us / 1e3,
                 ttft_engine_ms=s.ttft_us / 1e3,
@@ -796,7 +836,12 @@ class CellRun:
         # What the run wrote against what the server holds: every block
         # handed to a save is one key per tensor of every layer.
         written = len(self.adapter.chains_saved)
-        held = self.conn.get_stats()["kvmap_len"]
+        server = self.conn.get_stats()
+        held = server["kvmap_len"]
+        self.pool_usage = server.get("usage")
+        for rec in started:
+            if rec.error is None and rec.stats is not None and rec.stats.loaded_blocks > 0:
+                self.expect_fetch(f"request {rec.req.index}", rec.stats)
 
         def delta(key):
             return self.at_close[key] - self.at_open[key]
@@ -806,7 +851,8 @@ class CellRun:
             "window_compiles": delta("compiles"),
             "store_evictions": max(0, written * self.geometry.values_per_block - held),
             "peak_hbm_bytes": peak_bytes,
-            **{key: delta(key) for key in self.program_counters},
+            # A named key the program does not have (yet) is in neither snapshot.
+            **{key: delta(key) for key in self.program_counters if key in self.at_close},
         }
         if "tpot_mean_ms" in e2e:
             counters["tpot_mean_ms"] = e2e["tpot_mean_ms"]
@@ -828,7 +874,8 @@ class CellRun:
         device's tables, and (into ``spans["profile"]``) the idle gaps by
         program phase. ``trace["work"]`` is the useful work of the traced
         calls, summed by the configuration's cost module over what the taps
-        saw: a request's entries into waves, and the prefills."""
+        saw: a request's entries into waves, the prefills, and the resumes
+        of prefix hits."""
         if self.trace_t0 is None:
             return None
         raw = trace_reduce.load(trace_reduce.find_xplane(self.trace_dir))
@@ -851,6 +898,11 @@ class CellRun:
             if a <= t < b:
                 work["prefill_ktok"] += n / 1000.0
                 add(self.costs.prefill_work(self.config, n))
+        # A cost module without ``resume_work`` prices no resume.
+        resume_work = getattr(self.costs, "resume_work", None)
+        for t, pages, rows in self.taps.resumes if resume_work else ():
+            if a <= t < b:
+                add(resume_work(self.config, pages, rows))
         trace["work"] = work
         return trace
 
@@ -864,19 +916,20 @@ def execute(args, cell, config, plan, device, program_counters=()):
 
     import infinistore_tpu as its
 
+    # The server's allocation unit and what a block takes of its pool in
+    # whole units, from the file alone: a file that cannot start a server
+    # stops here. The pool holds the whole plan's working set, at that
+    # weight, below the server's on-demand eviction threshold (0.8 of the
+    # pool), so nothing is evicted.
+    layout = store_layout(config["serving"])
+    pool = pool_gib(traffic.store_bytes(plan, layout.pool_bytes_per_token))
     compiles = Compiles()
     run = CellRun(args, cell, config, plan, program_counters)
-    # The pool holds the whole plan's working set below the server's
-    # on-demand eviction threshold (0.8 of the pool), so nothing is evicted.
-    need = traffic.store_bytes(plan, config["serving"]["kv_bytes_per_token"])
-    pool_gib = max(2, int(need / 0.7 / 2**30) + 2)
-    # The server allocates in units no smaller than 16 KiB.
-    block_kib = max(16, int(config["serving"]["store_block_kib"]))
     if args.trace:
         # The program's own spans, in traced runs only: end-to-end runs
         # never carry the recorder.
         tracing.configure(enabled=True, capacity=SPAN_CAPACITY)
-    server = start_server(pool_gib, block_kib)
+    server = start_server(pool, layout.unit_kib)
     conn = None
     try:
         conn = its.InfinityConnection(its.ClientConfig(
@@ -910,6 +963,14 @@ def execute(args, cell, config, plan, device, program_counters=()):
         run.failed_checks.append("the traffic's lists ran dry before the window closed")
     if res["spans"] and res["spans"]["dropped"]:
         run.failed_checks.append(f"the recorder dropped {res['spans']['dropped']} spans")
+    if "store_unit_kib" in config["serving"] and not (
+        run.pool_usage is not None and run.pool_usage < POOL_EVICTS_FROM
+    ):
+        run.failed_checks.append(
+            f"the server's pool is {run.pool_usage} used at the close of the run, and it evicts "
+            f"on demand from {POOL_EVICTS_FROM}: the pool of {pool} GiB does not hold what "
+            f"the run saved at {layout.pool_units_per_block} units of {layout.unit_kib} KiB a block"
+        )
     for check in run.failed_checks:
         print(f"not correct: {check}", file=sys.stderr, flush=True)
     line = {
@@ -917,15 +978,19 @@ def execute(args, cell, config, plan, device, program_counters=()):
         "attempted": res["attempted"], "failed": res["failed"],
         "metrics": {}, "device": dict(device, memory_peak_bytes=peak_bytes),
         "workload": cell["name"], "seed": args.seed, "seconds": args.seconds,
-        "server": {"block_kib": block_kib, "pool_gib": pool_gib},
+        "server": {
+            "block_kib": layout.block_kib, "pool_gib": pool, "unit_kib": layout.unit_kib,
+            "pool_units_per_block": layout.pool_units_per_block, "pool_usage": run.pool_usage,
+        },
     }
     return line, res, trace
 
 
-def detail(args, cell, line, res, layer):
+def detail(args, cell, line, res, layer, trace=None):
     """Everything this run could read, for whoever studies a run: every
     end-to-end metric and every per-layer metric whatever ``--trace`` says,
-    in ``.bench_out/`` of the checkout; with the recorder on, beside it every
+    and of a traced run the work and the device's tables by operation and
+    program, in ``.bench_out/`` of the checkout; with the recorder on, beside it every
     span it held and the idle table by phase (install, save_snapshot,
     compute and the rest, which are no metrics). The driver reads only the
     line."""
@@ -936,6 +1001,7 @@ def detail(args, cell, line, res, layer):
         json.dump({
             "line": line, "end_to_end": res["end_to_end"], "per_layer": layer,
             "counters": res["counters"], "rows": res["rows"],
+            "trace": trace and {k: trace[k] for k in ("work", "ops", "modules")},
         }, f)
     if res["spans"]:
         held = {k: v for k, v in res["spans"].items() if k != "index"}
@@ -1004,7 +1070,7 @@ def main() -> int:
                 line["metrics"][m["name"]] = {
                     "value": res["end_to_end"][m["name"]], "unit": m["unit"],
                 }
-    detail(args, cell, line, res, layer)
+    detail(args, cell, line, res, layer, trace)
     print(json.dumps(line), flush=True)
     return 0
 

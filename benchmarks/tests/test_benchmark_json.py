@@ -116,8 +116,8 @@ def test_every_cell_finds_its_files(cell):
             continue
         reader = readers.load_layer_metric(m["name"])["reader"]
         assert reader["kind"] in readers.KINDS
-        if "cost" in reader:
-            assert reader["cost"] in costs.WORK_KEYS, (m["name"], reader["cost"])
+        for key in ("cost", "or_cost"):
+            assert reader.get(key, costs.WORK_KEYS[0]) in costs.WORK_KEYS, (m["name"], reader[key])
         if reader["kind"] == "trace_time" and reader.get("per") not in (None, "event"):
             assert reader["per"] in (*costs.WORK_KEYS, "prefill_ktok"), m["name"]
 

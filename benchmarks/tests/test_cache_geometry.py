@@ -7,6 +7,7 @@ objects and all against numbers worked out by hand."""
 import argparse
 import json
 import os
+import re
 import types
 
 import numpy as np
@@ -33,13 +34,17 @@ LATENT = [(tensor(16, 1, 512), tensor(16, 1, 64)) for _ in range(3)]
 # x 32: 16 x 4 x 32 x 2 = 4,096 B each.
 MIXED = LATENT[:2] + [(tensor(16, 4, 32), tensor(16, 4, 32))]
 BY_HAND = {
-    "latent": dict(caches=LATENT, block=3 * 18432, values=6, largest=16384, per_token=3456, kib=16),
-    "mixed": dict(caches=MIXED, block=2 * 18432 + 8192, values=6, largest=16384, per_token=2816, kib=16),
+    "latent": dict(caches=LATENT, block=3 * 18432, values=6, largest=16384, per_token=3456, kib=16,
+                   listed=[[3, 16], [3, 2]]),
+    "mixed": dict(caches=MIXED, block=2 * 18432 + 8192, values=6, largest=16384, per_token=2816, kib=16,
+                  listed=[[2, 16], [2, 2], [2, 4]]),
 }
 
 
 def serving(want):
-    return {"block_tokens": 16, "kv_bytes_per_token": want["per_token"], "store_block_kib": want["kib"]}
+    """Values of unlike sizes: the file lists them, and names its unit."""
+    return {"block_tokens": 16, "kv_bytes_per_token": want["per_token"], "store_block_kib": want["kib"],
+            "store_unit_kib": 16, "store_values_kib": want["listed"]}
 
 
 @pytest.mark.parametrize("case", sorted(BY_HAND))
@@ -61,9 +66,19 @@ def test_a_file_that_misstates_its_cache_is_refused_with_both_numbers(case):
     wrong = dict(serving(want), kv_bytes_per_token=65536)
     with pytest.raises(ValueError, match=rf"65536.*\b{want['per_token']}\b"):
         g.check(wrong)
-    wrong = dict(serving(want), store_block_kib=32)
+    wrong = dict(serving(want), store_block_kib=32, store_values_kib=[[6, 32]])
     with pytest.raises(ValueError, match=r"store_block_kib is 32 .*\b16384\b"):
         g.check(wrong)
+    # The list of values misstates the caches: both lists are said, by size.
+    built = sorted(want["listed"], key=lambda v: v[1])
+    listed = [[n + (kib == 2), kib] for n, kib in built]
+    says = re.escape(f"store_values_kib is {listed} ") + ".*" + re.escape(f" puts {built} in the store")
+    with pytest.raises(ValueError, match=says):
+        g.check(dict(serving(want), store_values_kib=listed))
+    # Without the list every value is held to be of store_block_kib: these caches' are not.
+    unlisted = {k: v for k, v in serving(want).items() if k != "store_values_kib"}
+    with pytest.raises(ValueError, match="no whole number of values of serving.store_block_kib"):
+        g.check(unlisted)
 
 
 def stub_cell_run(caches, program_counters=(), hit_installs=()):
@@ -108,6 +123,14 @@ STATE_LAYERS = [i for i, kind in enumerate(HYBRID_LAYERS) if kind == "S"]
 HYBRID_POLICY = [{"layers": STATE_LAYERS, "tensor": 0, "last_blocks": 1}]
 
 
+# What its file's ``serving`` says of the store: a unit of 64 KiB, the six K and
+# V of 512 KiB, the three pooled keys of 32 KiB, the nine states of 2 MiB.
+HYBRID_SERVING = {
+    "block_tokens": 1024, "kv_bytes_per_token": 21_600, "store_block_kib": 2048, "store_unit_kib": 64,
+    "store_values_kib": [[6, 512], [3, 32], [9, 2048]], "hit_installs": HYBRID_POLICY,
+}
+
+
 def hybrid_caches(scale=1):
     """The hybrid's caches; ``scale`` divides every tensor's last axis, for
     the cases that fill them with bytes."""
@@ -121,7 +144,7 @@ def test_a_hit_installs_every_kv_block_and_the_last_blocks_state():
     # Every block still WRITES every tensor: the pool and the evictions see all of it.
     assert g.block_nbytes == 3 * sum(ATTN) + 9 * STATE == 22_118_400 == 21_600 * 1024
     assert g.values_per_block == 3 * 3 + 9 and g.largest_value_nbytes == STATE == 2048 * 1024
-    g.check({"block_tokens": 1024, "kv_bytes_per_token": 21_600, "store_block_kib": 2048})
+    g.check(HYBRID_SERVING)
     # A hit of 32 blocks: 32 x the attention layers' values, one state a layer.
     assert g.fetched_values(32) == 32 * 9 + 9
     assert g.installed_nbytes(32) == 32 * 3_244_032 + 18_874_368 == 122_683_392
@@ -164,8 +187,9 @@ def test_a_policy_the_caches_do_not_have_stops_with_both_shapes(policy, says):
 def filled_hit(n, seed=5):
     """A hit of ``n`` blocks of the hybrid, at an eighth of its widths: the
     bytes every block's save was handed, and what a program that follows the
-    policy leaves on the device: every attention block, and the state of
-    block n - 1 alone (the states before it were never installed)."""
+    policy leaves on the device and the check reads back: every attention
+    block, and the state of block n - 1 alone (the states before it were
+    never installed, and are not read back)."""
     rng = np.random.default_rng(seed)
     caches = hybrid_caches(scale=8)
     g = CacheGeometry.of(caches, HYBRID_POLICY)
@@ -179,7 +203,7 @@ def filled_hit(n, seed=5):
         for layer, tensors in enumerate(caches)
     ]
     for layer in STATE_LAYERS:
-        installed[layer][0][: n - 1] = 0
+        installed[layer][0] = installed[layer][0][n - 1 :]
     return g, installed, saved, chains
 
 
@@ -190,13 +214,18 @@ def flip_a_byte(array, block):
 def test_the_full_hits_comparison_follows_the_policy():
     g, installed, saved, chains = filled_hit(32)
     assert hit_mismatch(installed, saved, chains, g) is None  # only block 31's states are there
+    assert [[len(t) for t in layer] for layer in installed] == [
+        [len(blocks) for blocks in layer] for layer in g.installed_blocks(32)
+    ] == [[32, 32, 32] if kind == "A" else [1] for kind in HYBRID_LAYERS]
     # The same read-back under a file WITHOUT the key: every state block is held to its save.
     every = CacheGeometry.of(hybrid_caches(scale=8))
-    assert "layer 1 tensor 0 block 0 of 32" in hit_mismatch(installed, saved, chains, every)
-    # Block 31's state differs by one byte.
-    flip_a_byte(installed[STATE_LAYERS[-1]][0], 31)
+    assert "layer 1 tensor 0: read back 1 blocks, a hit of 32 installs 32" in hit_mismatch(installed, saved, chains, every)
+    whole = [[np.concatenate([np.zeros((32 - len(t), *t.shape[1:]), t.dtype), t]) for t in layer] for layer in installed]
+    assert "layer 1 tensor 0 block 0 of 32" in hit_mismatch(whole, saved, chains, every)
+    # Block 31's state, the one read back, differs by one byte.
+    flip_a_byte(installed[STATE_LAYERS[-1]][0], 0)
     assert "layer 11 tensor 0 block 31 of 32 is not the bytes that were saved" in hit_mismatch(installed, saved, chains, g)
-    flip_a_byte(installed[STATE_LAYERS[-1]][0], 31)
+    flip_a_byte(installed[STATE_LAYERS[-1]][0], 0)
     assert hit_mismatch(installed, saved, chains, g) is None
     # Any K block differs; so for a V and for the pooled keys.
     for layer, t, block in ((0, 0, 0), (7, 1, 17), (8, 2, 31)):
@@ -204,7 +233,7 @@ def test_the_full_hits_comparison_follows_the_policy():
         assert f"layer {layer} tensor {t} block {block} of 32" in hit_mismatch(installed, saved, chains, g)
         flip_a_byte(installed[layer][t], block)
     # A read-back that is short of blocks, of layers, or a chain no save was seen for.
-    assert "30 blocks of a hit of 32" in hit_mismatch(
+    assert "layer 0 tensor 0: read back 30 blocks, a hit of 32 installs 32" in hit_mismatch(
         [[t[:30] for t in layer] for layer in installed], saved, chains, g)
     assert "0 layers" in hit_mismatch((), saved, chains, g)
     assert "block 4 of 32: no save" in hit_mismatch(
@@ -273,11 +302,24 @@ def test_store_evictions_and_the_programs_counters_by_name(case):
 
 
 def test_a_counter_that_is_nowhere_stops_the_run():
-    cell_run = stub_cell_run(LATENT, ("no_such_counter",))
-    cell_run.h = types.SimpleNamespace(metrics=lambda: {"wave_buckets": []})
-    cell_run.adapter = types.SimpleNamespace(connector=types.SimpleNamespace(get_stats=lambda: {}))
-    with pytest.raises(ValueError, match="no_such_counter"):
-        cell_run.read_program_counters()
+    """Since PR 32 it stops nothing: a metric file may come in the same PR
+    as the counter it reads, and the driver lays the new files over the
+    parent, which has no such counter. The key is left out, so its metric is
+    left out of that side's line; a key that IS there and is no number still
+    stops the run."""
+    cell_run = stub_cell_run(LATENT, ("no_such_counter", "waves_launched", "spill.dropped", "spill.no_such"))
+    cell_run.h = types.SimpleNamespace(metrics=lambda: {"wave_buckets": [], "waves_launched": 7})
+    stats = {"spill": {"dropped": 2}}
+    cell_run.adapter = types.SimpleNamespace(connector=types.SimpleNamespace(get_stats=lambda: stats))
+    assert cell_run.read_program_counters() == {"waves_launched": 7, "spill.dropped": 2}
+    view = readers.Run([], {"waves_launched": 3}, None, {})
+    assert readers.KINDS["counter"](view, {"kind": "counter", "key": "no_such_counter"}) is None
+    assert readers.KINDS["counter"](view, {"kind": "counter", "key": "no_such_counter", "per": "waves_launched"}) is None
+    assert readers.KINDS["counter"](view, {"kind": "counter", "key": "waves_launched", "per": "no_such_counter"}) is None
+    for key in ("wave_buckets", "spill"):  # a list, a table: there, and no number
+        cell_run.program_counters = [key]
+        with pytest.raises(ValueError, match=key):
+            cell_run.read_program_counters()
 
 
 def test_work_of_a_cost_module_the_harness_has_never_seen(monkeypatch):
@@ -313,3 +355,44 @@ def test_work_of_a_cost_module_the_harness_has_never_seen(monkeypatch):
     assert readers.KINDS["trace_roofline"](view, reader) == pytest.approx(
         100.0 * trace["work"]["latent_decode_bytes"] / (819e9 * seconds)
     )
+
+
+def test_the_traced_resumes_are_priced_by_a_cost_module_that_has_resume_work(monkeypatch):
+    """The resumes the taps saw inside the traced seconds (dispatch time,
+    context pages, chunk rows), summed by ``resume_work`` under its own key;
+    ``chunk_attn_roofline.reuse`` reads it over the kernel's device time."""
+    import costs
+
+    config = run.load_json(os.path.join(run.HERE, "configs", "mistral-7b-v0.3.json"))
+    cell_run = stub_cell_run(LATENT)
+    cell_run.config, cell_run.costs = config, costs
+    cell_run.trace_t0, cell_run.trace_t1, cell_run.trace_dir = 100.0, 108.0, "unused"
+    monkeypatch.setattr(trace_reduce, "find_xplane", lambda _dir: os.path.join(DATA, "small_trace.json"))
+    cell_run.records = []
+    cell_run.taps = types.SimpleNamespace(
+        prefills=[], resumes=[(99.9, 520, 128), (100.0, 136, 128), (104.0, 264, 128), (108.0, 520, 128)],
+    )
+    trace = cell_run.trace_results(None)
+    assert trace["work"]["chunk_attn_flops"] == 70_883_737_600 + 139_603_214_336  # the two inside
+    assert trace["work"]["chunk_attn_bytes"] == 16 * 2 * ((2176 + 4224) * 8 + 2 * 128 * 32) * 128 * 2
+    assert trace["work"]["ragged_decode_bytes"] == trace["work"]["flash_prefill_flops"] == 0
+    spec = readers.load_layer_metric("chunk_attn_roofline.reuse")
+    trace["ops"] = {"chunk_prefix_attention_pallas_bf16_8_512_128": [0.012, 32], "fusion": [1.0, 9]}
+    peaks = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
+    view = readers.Run([], {}, trace, peaks)
+    # Four query heads a KV head: the operations bind (1.07 ms against 0.58 of bytes).
+    assert readers.KINDS[spec["reader"]["kind"]](view, spec["reader"]) == pytest.approx(
+        100.0 * 210_486_951_936 / (197e12 * 0.012)
+    )
+    # One query head a KV head: the bytes bind, and the share is theirs.
+    mha = dict(trace, work=dict(trace["work"], chunk_attn_bytes=4 * trace["work"]["chunk_attn_bytes"]))
+    assert readers.read_layer_metric("chunk_attn_roofline.reuse", readers.Run([], {}, mha, peaks)) == pytest.approx(
+        100.0 * mha["work"]["chunk_attn_bytes"] / (819e9 * 0.012)
+    )
+    # No resume in the traced seconds, or none of its kernel's events: no sample, never 0.
+    trace["ops"] = {"fusion": [1.0, 9]}
+    assert readers.read_layer_metric("chunk_attn_roofline.reuse", view) is None
+    cell_run.taps.resumes = []
+    idle = cell_run.trace_results(None)
+    idle["ops"] = {"chunk_prefix_attention_pallas_bf16_8_512_128": [0.012, 32]}
+    assert readers.read_layer_metric("chunk_attn_roofline.reuse", readers.Run([], {}, idle, peaks)) is None

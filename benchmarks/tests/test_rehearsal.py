@@ -36,7 +36,18 @@ with open(os.path.join(REPO, "BENCHMARK.json")) as f:
     BENCH = json.load(f)
 
 
-def toy_run(params, seed, program_counters=(), hit_installs=None):
+# Wider heads, so that a K of one block is the server's smallest unit and the
+# file may name it: 16 tokens x 8 heads x 64 x 2 B = 16 KiB.
+WIDE = dict(
+    TOY, hidden_size=512, num_key_value_heads=8, head_dim=64,
+    serving={
+        "block_tokens": 16, "cache_blocks": 64, "kv_bytes_per_token": 2 * 2 * 8 * 64 * 2,
+        "store_block_kib": 16, "store_unit_kib": 16, "store_values_kib": [[4, 16]],
+    },
+)
+
+
+def toy_run(params, seed, program_counters=(), hit_installs=None, toy=TOY):
     import jax
 
     if jax.devices()[0].platform != "cpu":
@@ -44,9 +55,9 @@ def toy_run(params, seed, program_counters=(), hit_installs=None):
     import run
 
     with open(os.path.join(REPO, "benchmarks", "configs", "mistral-7b-v0.3.json")) as f:
-        config = dict(TOY, program=json.load(f)["program"])
+        config = dict(toy, program=json.load(f)["program"])
     if hit_installs is not None:
-        config["serving"] = dict(TOY["serving"], hit_installs=hit_installs)
+        config["serving"] = dict(toy["serving"], hit_installs=hit_installs)
     plan = (traffic._closed_plan if params["loop"] == "closed" else traffic._open_plan)("toy", params)
     args = argparse.Namespace(workload="toy", seed=seed, seconds=4.0, trace=0)
     return run.execute(
@@ -61,7 +72,10 @@ def test_toy_cell_runs_and_checks(params):
     line, res, trace = toy_run(params, 2**31 + 7, ("generated_tokens", "kvmap_len"))
     assert trace is None and res["spans"] is None and res["counters"]["window_compiles"] == 0, res["counters"]
     assert line["correct"] and line["failed"] == 0 and line["attempted"] >= 8, line
-    assert line["server"] == {"block_kib": 16, "pool_gib": 2}
+    # Values of 2 KiB in the server's smallest unit: four units of 16 KiB a block.
+    usage = line["server"].pop("pool_usage")
+    assert line["server"] == {"block_kib": 16, "pool_gib": 2, "unit_kib": 16, "pool_units_per_block": 4}
+    assert 0.0 < usage < 0.1
     e2e = res["end_to_end"]
     assert e2e["ttft_p50_ms"] > 0 and e2e["tpot_mean_ms"] > 0 and e2e["tokens_per_s"] > 0
     assert res["counters"]["generated_tokens"] > 0 and res["counters"]["kvmap_len"] > 0
@@ -166,3 +180,47 @@ def test_a_run_whose_install_alters_a_block_is_not_correct(monkeypatch, capfd, p
     line, res, _ = toy_run(CLOSED, seed, hit_installs=policy)
     assert not line["correct"] and line["failed"] == 0 and line["attempted"] >= 8, line
     assert "installed blocks: layer 1 tensor 1 block" in capfd.readouterr().err
+
+
+def test_a_run_whose_fetch_does_not_follow_the_policy_is_not_correct(monkeypatch, capfd):
+    """The fetch is part of ``correct``: a program that counts more store
+    values fetched than the configuration says a hit installs (here two more,
+    a K and a V, noted as the install returns) would read a wrong
+    ``fetch_gbps``; the run says so, for the window's hits and the checks'."""
+    import run
+    from infinistore_tpu.engine import EngineKVAdapter
+
+    real = EngineKVAdapter.install_kv
+
+    async def install_kv(self, prefetch, caches, block_table):
+        out = await real(self, prefetch, caches, block_table)
+        prefetch.blocks_fetched += 2
+        return out
+
+    monkeypatch.setattr(EngineKVAdapter, "install_kv", install_kv)
+    line, res, _ = toy_run(CLOSED, 2**31 + 23)
+    assert not line["correct"] and line["failed"] == 0 and line["attempted"] >= 8, line
+    err = capfd.readouterr().err
+    hits = [r for r in res["rows"] if r["hit"]]
+    assert hits and all(r["fetched_values"] == 4 * r["hit_blocks"] + 2 for r in hits)
+    assert err.count("store values for a hit of") >= len(hits) + 2
+    assert "prompt 48 partial hit: fetched 10 store values for a hit of 2 blocks, the configuration's policy names 8" in err
+
+
+@pytest.mark.parametrize("usage", [None, 0.85], ids=["as-it-is", "over-the-threshold"])
+def test_a_file_that_names_its_unit_has_its_pool_checked_at_the_close(monkeypatch, capfd, usage):
+    """A configuration that gives ``store_unit_kib`` is held to its pool:
+    under the share from which the server evicts on demand the run is
+    correct, and where the server reports more it is not, with both numbers."""
+    import infinistore_tpu as its
+
+    real = its.InfinityConnection.get_stats
+    if usage is not None:
+        monkeypatch.setattr(its.InfinityConnection, "get_stats", lambda self: dict(real(self), usage=usage))
+    line, res, _ = toy_run(CLOSED, 2**31 + 29, toy=WIDE)
+    server = dict(line["server"])
+    assert server.pop("pool_usage") == (usage or pytest.approx(0.05, abs=0.05))
+    assert server == {"block_kib": 16, "pool_gib": 2, "unit_kib": 16, "pool_units_per_block": 4}
+    assert line["failed"] == 0 and line["correct"] == (usage is None), line
+    said = "the server's pool is 0.85 used at the close of the run, and it evicts on demand from 0.8" in capfd.readouterr().err
+    assert said == (usage is not None)

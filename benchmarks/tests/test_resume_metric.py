@@ -48,9 +48,13 @@ def test_no_resume_in_the_trace_is_no_sample_and_no_trace_is_none():
 
 
 def test_entry_is_the_last_and_lists_the_two_reuse_cells():
+    """The last of PR 28's entries; PR 32's three follow it."""
     with open(os.path.join(REPO, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    entry = bench["per_layer"][-1]
+    entry = bench["per_layer"][-4]
+    assert [m["name"] for m in bench["per_layer"][-3:]] == [
+        "wave_pad_page_share.reuse", "wave_pad_page_share.chat", "chunk_attn_roofline.reuse",
+    ]
     spec = readers.load_layer_metric(NAME)
     assert entry["name"] == NAME and entry == {k: spec[k] for k in entry}
     assert entry["workloads"] == ["mistral7b-prefix-reuse", "deepseek7b-prefix-reuse"]

@@ -164,9 +164,11 @@ def token_ids(req: Request, seed: int, vocab: int) -> List[int]:
     return out
 
 
-def store_bytes(plan: Plan, bytes_per_token: int) -> int:
+def store_bytes(plan: Plan, bytes_per_token: float) -> float:
     """What the store holds once every request of the plan has been served:
-    each document's prefix once, each request's own tokens and answer."""
+    each document's prefix once, each request's own tokens and answer, at
+    ``bytes_per_token`` of the server's pool a token (what a block takes of
+    it in whole allocation units, over the block's tokens)."""
     docs = {r.doc: r.prefix_tokens for r in plan.requests}
     tokens = sum(docs.values()) + sum(
         r.own_tokens + r.answer_tokens for r in plan.requests
