@@ -18,9 +18,12 @@ a cache. What a block of the cache weighs is read off the caches the program
 built and what a hit installs of it off the configuration's file
 (``cache_geometry.py``), the useful work of a traced call comes from
 the module the configuration names (``program.costs``), and a counter of the
-program's is read by the name a metric file gives it. With ``--trace 1`` the
-program's own recorder is on and its spans are laid over the profile
-(``span_readers.py``); with ``--trace 0`` it stays off.
+program's is read by the name a metric file gives it. A model whose layers
+choose says so in its file (``program.choices``): its checked rows are then
+compared with a reference that follows the program's choices and holds each
+to its own scores (``reference_and_choices``, ``compare_logits``). With
+``--trace 1`` the program's own recorder is on and its spans are laid over
+the profile (``span_readers.py``); with ``--trace 0`` it stays off.
 
 How a token is timed without editing the program: the benchmark wraps
 ``harness.wave.step_chunk`` on its own harness instance and records, per
@@ -72,6 +75,20 @@ from infinistore_tpu import tracing  # noqa: E402 - the system under test and it
 # stream's variance) is far outside.
 LOGITS_RMS_TOL = 0.025
 LOGITS_MAX_TOL = 0.15
+# Where the configuration's file names ``program.choices`` (a model whose
+# layers choose: a top-k router) the reference follows the program's sets on
+# the compared rows and says how far each set lies off its own scores: the
+# largest score left out less the smallest chosen, over the rms of that
+# token's centred scores. A score is a projection of the normed hidden state,
+# so its error over the scores' rms is what a logit's is over the logits' rms
+# (limit 0.025); a gap is a difference of two such errors, and the check takes
+# the worst of rounds x sites of them, about three deviations: 4 x 0.025. CPU
+# emulations at published widths read at most 0.021 and 0.018-0.046 over
+# 1,024 (row, site) pairs a seed at four layers; a check reads some 40 pairs
+# (PERF.md, PR 33). A program that drops a clearly better expert, or routes
+# at random, is far outside; one that swaps a near-tie is inside, and is then
+# held to the two limits above like any other.
+CHOICE_SLACK = 0.10
 DECODE_STEPS_CHECKED = 8
 TRACE_SECONDS = 8.0  # the traced run profiles this long, mid-window
 DOC_BASE_WARM, DOC_BASE_CHECK = 10_000_000, 20_000_000
@@ -130,6 +147,57 @@ def metrics_for(bench: Dict, group: str, workload: str) -> List[Dict]:
         m for m in bench[group]
         if "workloads" not in m or workload in m["workloads"]
     ]
+
+
+def reference_and_choices(prog: Dict):
+    """The configuration's reference module and, where its file names
+    ``program.choices``, that function of the program's. The two come as a
+    pair: ``choices(harness, rows) -> int [len(rows), sites, k]`` (the ids the
+    timed step chose at each of the model's discrete-choice sites while it made
+    those logits rows) beside the reference's ``logits_following(params,
+    config, tokens, rounds, choices) -> (logits, gaps [rounds, sites])``. One
+    without the other raises ``ValueError`` with both names."""
+    reference = importlib.import_module(prog["reference"])
+    named, follows = prog.get("choices"), hasattr(reference, "logits_following")
+    if bool(named) != follows:
+        raise ValueError(
+            f"program.choices is {named!r} in the configuration's file and its reference module "
+            f"{prog['reference']!r} has {'a' if follows else 'no'} logits_following: a program "
+            f"that reports its choices and a reference that follows them come together or not at all"
+        )
+    return reference, resolve(named) if named else None
+
+
+def compare_logits(got, ref, gaps=None, rms_tol=LOGITS_RMS_TOL, max_tol=LOGITS_MAX_TOL,
+                   slack=CHOICE_SLACK):
+    """The comparison with the reference, as a pure function: ``got`` and
+    ``ref`` are ``[rounds, vocab]`` float32 logits, ``gaps`` the ``[rounds,
+    sites]`` the reference gave where it followed the program's choices, or
+    None. Returns the sentences that failed (none: the rows are held correct)
+    and the numbers read, each beside its limit: rms and worst of the
+    difference as multiples of the reference logits' rms, and the widest gap
+    with its row and site."""
+    import jax.numpy as jnp
+
+    failed = []
+    scale = float(jnp.sqrt(jnp.mean(ref * ref)))
+    rms = float(jnp.sqrt(jnp.mean((got - ref) ** 2))) / scale
+    worst = float(jnp.max(jnp.abs(got - ref))) / scale
+    read = {"rms": rms, "rms_limit": rms_tol, "worst": worst, "worst_limit": max_tol, "ref_rms": scale}
+    if gaps is not None:
+        gaps = jnp.asarray(gaps, jnp.float32)
+        row, site = (int(i) for i in jnp.unravel_index(jnp.argmax(gaps), gaps.shape))
+        read.update(max_gap=float(gaps[row, site]), choice_slack=slack, gap_row=row, gap_site=site)
+        if not read["max_gap"] <= slack:
+            failed.append(
+                f"the program's choice at row {row} site {site} lies {read['max_gap']:.4f} of its "
+                f"scores' rms off the float32 reference's own (limit {slack})"
+            )
+    if not bool(jnp.all(jnp.isfinite(got))):
+        failed.append("non-finite logits")
+    if not (rms <= rms_tol and worst <= max_tol):
+        failed.append(f"logits off the float32 reference: rms {rms:.4f} worst {worst:.4f}")
+    return failed, read
 
 
 # ---------------------------------------------------------------------------
@@ -207,6 +275,7 @@ class Record:
     stats: Optional[object] = None  # RequestStats
     error: Optional[str] = None
     logits: Optional[list] = None  # kept only in the correctness phase
+    choices: Optional[list] = None  # beside ``logits``, where the file names ``program.choices``
 
     def emits(self) -> List[float]:
         """Emit times of the generated tokens seen: entry k + 1 is the
@@ -218,13 +287,16 @@ class Record:
 class Instruments:
     """The benchmark's taps on its own harness instance: ``step_chunk``
     entry stamps, ``BlockPool.alloc`` waits, prefill calls. Nothing in the
-    program is edited; the attributes are set on the instances."""
+    program is edited; the attributes are set on the instances. ``choices``
+    is the program's own function where the configuration's file names one:
+    called in the check phase after every call, never in the window."""
 
-    def __init__(self, harness, block_tokens: int):
+    def __init__(self, harness, block_tokens: int, choices=None):
         import jax
 
         self.h = harness
         self.bt = block_tokens
+        self.choices = choices
         self.by_task: Dict[object, Record] = {}
         self.keep_logits = False
         self.prefills: List[tuple] = []  # (t_start, tokens)
@@ -247,6 +319,8 @@ class Instruments:
         rows = await self._step_chunk(tokens, positions, padded_table, priority=priority)
         if rec is not None and self.keep_logits:
             rec.logits.append(rows)
+            if self.choices is not None:
+                rec.choices.append(self.choices(self.h, rows))
         return rows
 
     async def alloc(self, n):
@@ -299,6 +373,10 @@ class CellRun:
         self.program_counters = sorted(program_counters)
         self.records: List[Record] = []
         self.failed_checks: List[str] = []
+        self.compared: List[Dict] = []  # what each comparison with the reference read
+        # ``execute`` sets both from the file's ``program`` before the server
+        # starts: the reference module, and the program's ``choices`` or None.
+        self.reference = self.choices = None
 
     # -- set-up ---------------------------------------------------------------
 
@@ -320,7 +398,6 @@ class CellRun:
                     f"{attr} of the program's config is {getattr(self.cfg, attr)!r}, "
                     f"{key} of the file {self.config[key]!r}"
                 )
-        self.reference = importlib.import_module(prog["reference"])
         self.costs = importlib.import_module(prog["costs"])
         init = resolve(prog["init_params"])
         # One jitted call from the seed, on the device, in the served type.
@@ -372,7 +449,7 @@ class CellRun:
         # numbers, which sized the server before anything was built, must agree.
         self.geometry = CacheGeometry.of(self.h.caches, serving.get("hit_installs", ()))
         self.geometry.check(serving)
-        self.taps = Instruments(self.h, bt)
+        self.taps = Instruments(self.h, bt, self.choices)
         self.compiles = compiles
         self.conn = conn
         for key in set(self.program_counters) - set(self.read_program_counters()):
@@ -496,7 +573,7 @@ class CellRun:
             t_dispatch=now if t_dispatch is None else t_dispatch, t_sent=now,
         )
         if self.taps.keep_logits:
-            rec.logits = []
+            rec.logits, rec.choices = [], []
         self.records.append(rec)
         task = asyncio.current_task()
         self.taps.by_task[task] = rec
@@ -767,24 +844,29 @@ class CellRun:
 
     def against_reference(self, label: str, rec: Record, tokens: List[int]):
         """Round j of the request decodes position len - 1 + j: the logits
-        that chose generated token j, teacher-forced on the tokens it chose."""
+        that chose generated token j, teacher-forced on the tokens it chose.
+        Where the program reports its choices, the reference takes row 0's
+        (this request's) of each round on those positions and its own
+        everywhere else."""
         import jax.numpy as jnp
+        import numpy as np
 
         rounds = DECODE_STEPS_CHECKED + 1
         got = jnp.concatenate([rows[:1] for rows in rec.logits[:rounds]]).astype(jnp.float32)
-        ref = self.reference.logits(
-            self.params, self.config, tokens + rec.stats.generated[: rounds - 1], rounds
-        )
-        scale = float(jnp.sqrt(jnp.mean(ref * ref)))
-        rms = float(jnp.sqrt(jnp.mean((got - ref) ** 2))) / scale
-        worst = float(jnp.max(jnp.abs(got - ref))) / scale
-        print(f"logits {label}: rms {rms:.5f} worst {worst:.5f} (x ref rms {scale:.4f}; "
-              f"tol {LOGITS_RMS_TOL} / {LOGITS_MAX_TOL})", file=sys.stderr, flush=True)
-        self.expect(bool(jnp.all(jnp.isfinite(got))), f"{label}: non-finite logits")
-        self.expect(
-            rms <= LOGITS_RMS_TOL and worst <= LOGITS_MAX_TOL,
-            f"{label}: logits off the float32 reference: rms {rms:.4f} worst {worst:.4f}",
-        )
+        context, gaps = tokens + rec.stats.generated[: rounds - 1], None
+        if self.choices is None:
+            ref = self.reference.logits(self.params, self.config, context, rounds)
+        else:
+            chosen = np.stack([np.asarray(c)[0] for c in rec.choices[:rounds]])
+            ref, gaps = self.reference.logits_following(self.params, self.config, context, rounds, chosen)
+        failed, read = compare_logits(got, ref, gaps)
+        followed = "" if gaps is None else f"; widest choice gap {read['max_gap']:.5f} (slack {CHOICE_SLACK})"
+        print(f"logits {label}: rms {read['rms']:.5f} worst {read['worst']:.5f} (x ref rms "
+              f"{read['ref_rms']:.4f}; tol {LOGITS_RMS_TOL} / {LOGITS_MAX_TOL}){followed}",
+              file=sys.stderr, flush=True)
+        self.compared.append({"label": label, **read})
+        for sentence in failed:
+            self.expect(False, f"{label}: {sentence}")
 
     # -- the numbers ------------------------------------------------------------
 
@@ -925,6 +1007,9 @@ def execute(args, cell, config, plan, device, program_counters=()):
     pool = pool_gib(traffic.store_bytes(plan, layout.pool_bytes_per_token))
     compiles = Compiles()
     run = CellRun(args, cell, config, plan, program_counters)
+    # A file that names ``program.choices`` without a reference that follows
+    # them, or the reverse, stops here too.
+    run.reference, run.choices = reference_and_choices(config["program"])
     if args.trace:
         # The program's own spans, in traced runs only: end-to-end runs
         # never carry the recorder.
@@ -982,6 +1067,9 @@ def execute(args, cell, config, plan, device, program_counters=()):
             "block_kib": layout.block_kib, "pool_gib": pool, "unit_kib": layout.unit_kib,
             "pool_units_per_block": layout.pool_units_per_block, "pool_usage": run.pool_usage,
         },
+        # Every number the checks compared with the reference, beside its
+        # limit: ``main`` keeps this key the line's last.
+        "compared": run.compared,
     }
     return line, res, trace
 
@@ -1070,7 +1158,10 @@ def main() -> int:
                 line["metrics"][m["name"]] = {
                     "value": res["end_to_end"][m["name"]], "unit": m["unit"],
                 }
+    line["compared"] = line.pop("compared")  # last in the line, and stderr's last lines
     detail(args, cell, line, res, layer, trace)
+    for c in line["compared"]:
+        print("compared " + ", ".join(f"{k} {v}" for k, v in c.items()), file=sys.stderr, flush=True)
     print(json.dumps(line), flush=True)
     return 0
 

@@ -73,14 +73,48 @@ def test_layer_metric_file_agrees_with_benchmark_json(metric):
     (entry,) = [m for m in BENCH["per_layer"] if m["name"] == metric]
     assert set(entry) <= {"name", "unit", "better", "source", "layer", "moves", "workloads"}
     spec = readers.load_layer_metric(metric)
-    for key in entry:
+    # A metric's cells are listed once, in BENCHMARK.json: a PR that adds a
+    # cell appends its name there and edits no file under benchmarks/.
+    assert "workloads" not in spec, metric
+    for key in set(entry) - {"workloads"}:
         assert spec[key] == entry[key], (metric, key)
+    assert set(spec) == (set(entry) - {"workloads"}) | {"what", "reader"}, metric
     assert spec["reader"]["kind"] in readers.KINDS
     e2e = {m["name"]: m for m in BENCH["end_to_end"]}
     cells = [w["name"] for w in BENCH["workloads"]]
     moved = e2e[entry["moves"]]
     # The metric it moves is reported wherever this one is.
     assert set(entry.get("workloads", cells)) <= set(moved.get("workloads", cells))
+
+
+def test_a_fourth_cell_gets_its_metrics_by_being_listed_in_benchmark_json_alone():
+    """What listing a metric's cells once buys: a new closed-loop reuse cell
+    that reports ``tokens_per_s`` has its name appended to that metric's list
+    and to the lists of the per-layer metrics the reuse cells report, in a
+    copy of BENCHMARK.json and nowhere else; ``metrics_for`` then hands it
+    those metrics, and every one still finds its file and agrees with it."""
+    import copy
+
+    import run
+
+    bench, new, like = copy.deepcopy(BENCH), "made-up-long-prefix-reuse", "mistral7b-prefix-reuse"
+    appended = []
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if like in m.get("workloads", ()):
+            m["workloads"].append(new)
+            appended.append(m["name"])
+    assert appended[0] == "tokens_per_s" and len(appended) == 1 + 34
+    got = run.metrics_for(bench, "per_layer", new)
+    assert [m["name"] for m in got] == appended[1:]
+    assert [m["name"] for m in got] == [m["name"] for m in run.metrics_for(BENCH, "per_layer", like)]
+    assert [m["name"] for m in run.metrics_for(bench, "end_to_end", new)] == ["tokens_per_s", "setup_s"]
+    assert run.metrics_for(BENCH, "per_layer", new) == []  # unlisted, it reports nothing
+    for m in got:
+        spec = readers.load_layer_metric(m["name"])
+        assert all(spec[key] == m[key] for key in set(m) - {"workloads"}), m["name"]
+        assert spec["reader"]["kind"] in readers.KINDS and m["moves"] == "tokens_per_s"
+    # The counters that cell's run would snapshot come with the files too.
+    assert readers.counter_keys(m["name"] for m in got) - run.OWN_COUNTERS == {"wave_pad_pages", "wave_pages"}
 
 
 def resolves(dotted: str) -> bool:

@@ -173,6 +173,26 @@ def test_a_window_of_two_blocks():
     assert g.fetched_nbytes(14, 5) == g.installed_nbytes(5)
 
 
+def test_four_sliding_layers_and_a_full_one():
+    """Dry run 4 as shapes: ten tensors of 16 KiB a block over five layers, the
+    K and V of layers 0-3 checkpoints of the window's 128 blocks. The byte
+    comparison and a request's row follow the same policy."""
+    kv = np.broadcast_to(np.float16(0), (BLOCKS, 16, 4, 128))
+    policy = [{"layers": [0, 1, 2, 3], "tensor": t, "last_blocks": 128} for t in (0, 1)]
+    cell_run = stub_cell_run([(kv, kv)] * 5, hit_installs=policy)
+    g = cell_run.geometry
+    assert g.block_nbytes / 16 == 10_240 and g.largest_value_nbytes == 16 * 1024
+    triples = g.compared(2056)
+    assert len(triples) == g.fetched_values(2056) == 5136
+    assert {b for layer, _, b in triples if layer < 4} == set(range(1928, 2056))
+    assert {b for layer, _, b in triples if layer == 4} == set(range(2056))
+    row = hit_row(cell_run, 2056, 5136)
+    assert row["installed_bytes"] == row["fetched_bytes"] == 84_148_224
+    # A program that fetched every block of every layer is off the policy by 15,424 values.
+    assert 10 * 2056 - g.fetched_values(2056) == 15_424
+    assert len(g.compared(100)) == g.fetched_values(100) == 1000
+
+
 @pytest.mark.parametrize("policy,says", [
     ([{"layers": [3, 12], "tensor": 0, "last_blocks": 1}], r"tensor 0 of layer 12 .* 12 layers of \[3, 1, 1, "),
     ([{"layers": [1], "tensor": 1, "last_blocks": 1}], r"tensor 1 of layer 1 .* 12 layers of \[3, 1, 1, "),

@@ -47,7 +47,7 @@ WIDE = dict(
 )
 
 
-def toy_run(params, seed, program_counters=(), hit_installs=None, toy=TOY):
+def toy_run(params, seed, program_counters=(), hit_installs=None, toy=TOY, program=None):
     import jax
 
     if jax.devices()[0].platform != "cpu":
@@ -55,7 +55,7 @@ def toy_run(params, seed, program_counters=(), hit_installs=None, toy=TOY):
     import run
 
     with open(os.path.join(REPO, "benchmarks", "configs", "mistral-7b-v0.3.json")) as f:
-        config = dict(toy, program=json.load(f)["program"])
+        config = dict(toy, program=dict(json.load(f)["program"], **(program or {})))
     if hit_installs is not None:
         config["serving"] = dict(toy["serving"], hit_installs=hit_installs)
     plan = (traffic._closed_plan if params["loop"] == "closed" else traffic._open_plan)("toy", params)

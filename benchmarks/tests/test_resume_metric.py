@@ -56,7 +56,9 @@ def test_entry_is_the_last_and_lists_the_two_reuse_cells():
         "wave_pad_page_share.reuse", "wave_pad_page_share.chat", "chunk_attn_roofline.reuse",
     ]
     spec = readers.load_layer_metric(NAME)
-    assert entry["name"] == NAME and entry == {k: spec[k] for k in entry}
+    # Its cells stand in BENCHMARK.json alone (PR 33); every other key agrees with the file.
+    assert entry["name"] == NAME and "workloads" not in spec
+    assert all(spec[k] == entry[k] for k in set(entry) - {"workloads"})
     assert entry["workloads"] == ["mistral7b-prefix-reuse", "deepseek7b-prefix-reuse"]
     assert entry["moves"] == "tokens_per_s" and entry["layer"] == "Jitted model steps"
     # The patterns of the wave and prefill metrics do not take the resume's time.

@@ -39,6 +39,14 @@ DRY_RUN_3 = {
         {"layers": list(range(8)), "tensor": 1, "last_blocks": 1},
     ],
 }
+# Dry run 4 (PR 33): five layers of a K and a V of 16 tokens x 4 KV heads x 128
+# x 2 B = 16 KiB; layers 0-3 slide over a window of 2,048 tokens = 128 blocks,
+# layer 4 attends to everything. Values of one size, a power of two that is the
+# server's smallest unit: the file needs neither a unit nor a list.
+DRY_RUN_4 = {
+    "block_tokens": 16, "kv_bytes_per_token": 10_240, "store_block_kib": 16,
+    "hit_installs": [{"layers": [0, 1, 2, 3], "tensor": t, "last_blocks": 128} for t in (0, 1)],
+}
 # Documents of 8k/16k/32k tokens 4:2:1 asked four times, as dry run 2's traffic.
 LONG_DOCUMENTS = {
     "loop": "closed", "clients": 3, "schedule_seed": 3, "documents_per_client": 14,
@@ -62,7 +70,9 @@ def state_caches(blocks=3):
     # A layer's state takes 516 units and its normaliser 5 (4.03 of them):
     # 33,344 KiB against 33,282 of data, 0.19% over.
     (DRY_RUN_3, 8 * 33_282, 8 * (516 + 5) * 64, 8 * 521),
-], ids=["llama-kv", "dry-run-2", "dry-run-3"])
+    # Ten values of the unit's own size: 160 KiB a block, 10,240 B a token.
+    (DRY_RUN_4, 10 * 16, 10 * 16, 10),
+], ids=["llama-kv", "dry-run-2", "dry-run-3", "dry-run-4"])
 def test_pool_bytes_per_block(serving, data_kib, pool_kib, units):
     layout = store_layout(serving)
     assert layout.pool_bytes_per_block == pool_kib * KIB
@@ -131,7 +141,10 @@ def test_dry_run_3_gets_a_unit_of_64_kib_and_a_pool_that_holds_its_checkpoints(m
     args = argparse.Namespace(workload="made-up", seed=1, seconds=1.0, trace=0)
     plan = traffic._closed_plan("made-up", LONG_DOCUMENTS)
     with pytest.raises(ServerStarted):
-        run.execute(args, {"name": "made-up", "chips": 1}, {"name": "made-up", "serving": DRY_RUN_3}, plan, {})
+        run.execute(
+            args, {"name": "made-up", "chips": 1},
+            {"name": "made-up", "serving": DRY_RUN_3, "program": {"reference": "reference"}}, plan, {},
+        )
     assert asked["unit_kib"] == 64
     # Whole blocks saved: each document's prefix once, and the block a
     # request's own question and answer complete, if any.
@@ -146,6 +159,23 @@ def test_dry_run_3_gets_a_unit_of_64_kib_and_a_pool_that_holds_its_checkpoints(m
     # At the old coupling's weight (three units of 32 MiB a layer) the same
     # pool would have been over the threshold: finding 2 of ISSUE 32.
     assert checkpoints * 8 * 3 * 32_768 * KIB / (asked["pool_gib"] * 2**30) > POOL_EVICTS_FROM
+
+
+def test_dry_run_4_needs_no_unit_and_a_long_hit_fetches_its_windows_alone():
+    layout = store_layout(DRY_RUN_4)
+    assert (layout.unit_kib, layout.block_kib, layout.values_kib) == (16, 16, ((10, 16),))
+    kv = np.broadcast_to(np.float16(0), (3, 16, 4, 128))  # as shapes: 16 KiB a block
+    g = CacheGeometry.of([(kv, kv)] * 5, DRY_RUN_4["hit_installs"])
+    g.check(DRY_RUN_4)
+    assert g.block_nbytes == 10 * 16 * KIB and g.values_per_block == 10
+    # A hit of a 32k + 128-token prompt, 2,056 blocks: the full layer's every
+    # block and the sliding layers' last 128, 80.25 MiB where all would be 321.
+    assert g.fetched_values(2056) == 2 * (2056 + 4 * 128) == 5136
+    assert g.installed_nbytes(2056) == 5136 * 16 * KIB == 84_148_224
+    assert 2056 * g.block_nbytes == 336_855_040 and g.installed_nbytes(2056) / 2**20 == 80.25
+    assert g.installed_blocks(2056) == [[range(1928, 2056)] * 2] * 4 + [[range(0, 2056)] * 2]
+    # A hit shorter than the window installs all of every tensor.
+    assert g.fetched_values(100) == 1000 and g.installed_nbytes(100) == 100 * g.block_nbytes
 
 
 def test_the_list_is_held_to_the_caches_the_program_built():
