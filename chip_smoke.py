@@ -638,6 +638,49 @@ def check_steps_hold_kernels(cfg, params, sizes: Sizes) -> dict:
     }
 
 
+def check_steps_donate(cfg, params, sizes: Sizes) -> dict:
+    """``prefill`` and ``decode_step`` update the cache they are handed in
+    place (as the scatter above does): the input arrays are gone after the
+    call, and the result is byte-right: the same prompt and the same token
+    written through another table leave the same bytes in its blocks, and a
+    block in neither table is still zero. At the traffic's own shapes, so
+    ``prefill`` compiles once for both phases."""
+    from infinistore_tpu.models import decode_step, prefill
+    from infinistore_tpu.tpu.paged import gather_blocks
+
+    bt, mrb = cfg.block_tokens, sizes.max_req_blocks
+    n = sizes.prompt_tokens // bt
+    rng = np.random.default_rng(5)
+    prompt = jnp.asarray(rng.integers(0, cfg.vocab, size=sizes.prompt_tokens), jnp.int32)
+
+    def run(first_block):
+        table = np.arange(first_block, first_block + mrb, dtype=np.int32)
+        handed = cfg.kv_spec(sizes.num_blocks).make_caches()
+        _, caches = prefill(params, prompt, handed, jnp.asarray(table[:n]), cfg)
+        assert all(t.is_deleted() for pair in handed for t in pair), "prefill did not donate"
+        handed = caches
+        logits, caches = decode_step(
+            params, jnp.int32(7), jnp.int32(sizes.prompt_tokens), handed,
+            jnp.asarray(table), cfg, mrb,
+        )
+        assert all(t.is_deleted() for pair in handed for t in pair), "decode_step did not donate"
+        ids = jnp.asarray(np.append(table[: n + 1], first_block + mrb), jnp.int32)
+        return logits, [
+            (gather_blocks(k, ids), gather_blocks(v, ids)) for k, v in caches
+        ]
+
+    (logits_a, a), (logits_b, b) = run(3), run(sizes.num_blocks - 2 * mrb)
+    assert _bytes_equal(logits_a, logits_b), "logits differ with the table"
+    for layer, (pair_a, pair_b) in enumerate(zip(a, b)):
+        for got, want in zip(pair_a, pair_b):
+            assert _bytes_equal(got, want), f"layer {layer}: bytes differ with the table"
+            assert np.asarray(got[:n], np.float32).any(), f"layer {layer}: nothing written"
+            assert not np.asarray(got[n + 1], np.float32).any(), (
+                f"layer {layer}: a block outside the table was written"
+            )
+    return {"prefill": "donated, byte-right", "decode_step": "donated, byte-right"}
+
+
 async def check_staging_reuse(kvc, cfg, caches, written_blocks, rng, report):
     """More layers in flight than staging regions, byte-compared: the
     region-reuse rule differs by backend (tpu/layerwise.py
@@ -848,6 +891,9 @@ def main() -> int:
         )
         summary["mosaic_calls"] = phases.run(
             "steps_hold_kernels", check_steps_hold_kernels, cfg, params, sizes
+        )
+        summary["steps_donate"] = phases.run(
+            "steps_donate", check_steps_donate, cfg, params, sizes
         )
         setup_s = time.perf_counter() - t_start
         summary["traffic"] = phases.run(

@@ -199,10 +199,11 @@ class BlockPool:
 class DeviceGate:
     """Reader-writer discipline over the shared paged cache.
 
-    Exclusive: phases that MUTATE the cache arrays (load's scatters donate
-    the cache buffers on TPU; prefill/decode rewrite blocks) — two such
-    phases interleaving at await points would fork the functional cache state
-    and one side's blocks would be lost (or a donated buffer would be read).
+    Exclusive: phases that MUTATE the cache arrays (load's scatters and the
+    model's steps — prefill, resume, decode waves — all DONATE the cache
+    buffers and hand back the ones to use) — two such phases interleaving at
+    await points would fork the functional cache state and one side's blocks
+    would be lost (or a donated buffer would be read).
     Shared: gather-only phases (save snapshots, verification reads) — they
     overlap each other freely and are over in microseconds, after which the
     actual store I/O runs with no gate held at all."""
@@ -1038,9 +1039,6 @@ class ContinuousBatchingHarness:
         self._prefetch_extra_wasted = 0
         self.stats: List[RequestStats] = []
         self._prefill_per_block_s: Optional[float] = None
-        # Jitted whole-prompt pass: one compiled program per (prompt
-        # length, table size) shape, not an eager op-by-op prefill.
-        self._prefill = jax.jit(prefill, static_argnames=("config",))
 
     # -- model compute -------------------------------------------------------
 
@@ -1108,7 +1106,7 @@ class ContinuousBatchingHarness:
         """Whole-prompt prefill into this request's blocks (cache-mutating:
         caller holds the exclusive gate)."""
         t0 = time.perf_counter()
-        _, self.caches = self._prefill(
+        _, self.caches = prefill(
             self.params,
             jnp.asarray(token_ids, dtype=jnp.int32),
             self.caches,

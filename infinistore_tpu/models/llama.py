@@ -227,10 +227,19 @@ def _kv_proj(params: Params, layer: int, x, positions, config):
 # wave); ``decode_step``, ``prefill_continue`` and ``speculative_verify`` are
 # views over them. The cache is shared across sequences via the block
 # tables, exactly the paged-attention model the store serves.
+#
+# Every jitted entry here DONATES ``caches``: XLA aliases each layer's K and V
+# output to its input and rewrites the touched slots in place, where an
+# undonated cache is copied whole, every layer, every step. The rule for a
+# caller is the installs' (tpu/paged.py): the arrays handed in are deleted by
+# the call, use the returned ones; hand a copy (``jax.tree.map(jnp.copy,
+# caches)``) to keep the input. A donation declared on an inner jit is ignored
+# under an outer trace, so a caller that wraps an entry in a jit of its own
+# declares it again (``decode_step`` does).
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.jit, static_argnames=("config",))
+@functools.partial(jax.jit, static_argnames=("config",), donate_argnames=("caches",))
 def prefill(
     params: Params,
     tokens: jax.Array,  # [S] int32, S % block_tokens == 0
@@ -239,7 +248,11 @@ def prefill(
     config: LlamaConfig,
 ) -> Tuple[jax.Array, Caches]:
     """Full prompt pass; writes K/V into the paged cache blocks listed in
-    block_table. Returns (last-token logits, updated caches)."""
+    block_table. Returns (last-token logits, updated caches).
+
+    ``caches`` is donated (updated in place, the input arrays deleted). A
+    call that raises after dispatch leaves the caller without a cache, as a
+    failed install does (connector.py): there is no recovery here."""
     s = tokens.shape[0]
     bt = config.block_tokens
     positions = jnp.arange(s, dtype=jnp.int32)[None]
@@ -264,7 +277,9 @@ def prefill(
     return logits[0, -1], new_caches
 
 
-@functools.partial(jax.jit, static_argnames=("config", "max_blocks"))
+@functools.partial(
+    jax.jit, static_argnames=("config", "max_blocks"), donate_argnames=("caches",)
+)
 def decode_step(
     params: Params,
     token: jax.Array,  # [] int32
@@ -284,7 +299,9 @@ def decode_step(
 
     The one-row, one-token view of ``verify_step_ragged`` — one decode body
     to maintain. The table rides the wave as a rectangle of one row
-    (``rectangle_as_ragged``): entries past the sequence are not walked."""
+    (``rectangle_as_ragged``): entries past the sequence are not walked.
+    ``caches`` is donated, declared here again because the wave body's own
+    declaration is ignored under this outer trace."""
     if block_table.shape[0] != max_blocks:
         raise ValueError(
             f"block_table has {block_table.shape[0]} entries, expected "
@@ -346,7 +363,9 @@ def _wave_layer(
     return _ffn(weights, 0, x, config), k_cache, v_cache
 
 
-@functools.partial(jax.jit, static_argnames=("config", "max_blocks"))
+@functools.partial(
+    jax.jit, static_argnames=("config", "max_blocks"), donate_argnames=("caches",)
+)
 def verify_step_ragged(
     params: Params,
     tokens: jax.Array,  # [T] int32, the wave's chunks CONCATENATED row-major
@@ -387,7 +406,14 @@ def verify_step_ragged(
     ``block_tables`` rows beyond the real requests (bucket padding) are
     never referenced by any flat token: a padded WAVE ROW neither scatters
     nor attends, it is simply absent. Returns ([T, vocab] logits, updated
-    caches)."""
+    caches).
+
+    ``caches`` is donated: every layer's scatter lands in the input's own
+    buffers (the per-layer ``jax.jit`` below needs no donation of its own:
+    XLA updates in place across that call once the entry's parameter may
+    be aliased) and the input arrays are deleted. A call that raises after
+    dispatch leaves the caller without a cache, as a failed install does
+    (connector.py): there is no recovery here."""
     t = tokens.shape[0]
     if positions.shape != (t,) or row_of.shape != (t,):
         raise ValueError(
@@ -427,7 +453,7 @@ def verify_step_ragged(
     return logits[0], new_caches
 
 
-@functools.partial(jax.jit, static_argnames=("config",))
+@functools.partial(jax.jit, static_argnames=("config",), donate_argnames=("caches",))
 def resume_chunk(
     params: Params,
     tokens: jax.Array,  # [S_c] int32, the suffix chunk
@@ -444,7 +470,11 @@ def resume_chunk(
     belong to different requests and want a page walk each
     (``verify_step_ragged``), a miss has no pages yet (``prefill``), and a
     chunk of one request wants one walk for all its rows. The compile key
-    is the chunk's length and the table's (``max_blocks``)."""
+    is the chunk's length and the table's (``max_blocks``).
+
+    ``caches`` is donated (updated in place, the input arrays deleted). A
+    call that raises after dispatch leaves the caller without a cache, as a
+    failed install does (connector.py): there is no recovery here."""
     s_c = tokens.shape[0]
     bt = config.block_tokens
     positions = start_pos + jnp.arange(s_c, dtype=jnp.int32)

@@ -49,10 +49,11 @@ class PagedKVCacheSpec:
     def make_caches(self) -> List[Tuple[jax.Array, jax.Array]]:
         """Fresh zeroed (K, V) cache pair per layer.
 
-        Every entry is a *distinct* buffer: scatter_blocks donates its cache
-        argument (in-place update on TPU), so aliasing one zeros array across
-        K/V/layers would leave dead buffers behind the first scatter. (The CPU
-        backend ignores donation, which masks the bug in CPU-only tests.)
+        Every entry is a *distinct* buffer: the installs' scatters and the
+        model's serving steps donate the cache (in-place update), so aliasing
+        one zeros array across K/V/layers would leave dead buffers behind the
+        first call. (The CPU backend honours donation too, on jax 0.9.0: a
+        donated input reads ``is_deleted()`` there, so CPU-only tests see it.)
         """
         return [
             (

@@ -61,11 +61,11 @@ def test_compile_cache_is_placed_from_outside_or_in_the_checkout(tmp_path):
     assert run({**base, "JAX_COMPILATION_CACHE_DIR": outside}) == [outside] * 2
 
 
-def test_traffic_phase_runs_on_a_toy_width(server):
+def _toy_smoke():
     spec = importlib.util.spec_from_file_location("chip_smoke", _SMOKE)
     smoke = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(smoke)
-    from infinistore_tpu.models import LlamaConfig, init_params
+    from infinistore_tpu.models import LlamaConfig
 
     cfg = LlamaConfig(
         vocab=512, dim=128, n_layers=8, n_heads=4, n_kv_heads=2, ffn_dim=256,
@@ -75,6 +75,25 @@ def test_traffic_phase_runs_on_a_toy_width(server):
         prompt_tokens=64, suffix_tokens=32, gen_tokens=16, num_blocks=64,
         max_req_blocks=8,
     )
+    return smoke, cfg, sizes
+
+
+def test_donation_phase_runs_on_a_toy_width():
+    from infinistore_tpu.models import init_params
+
+    smoke, cfg, sizes = _toy_smoke()
+    report = smoke.check_steps_donate(
+        cfg, init_params(cfg, jax.random.PRNGKey(0)), sizes
+    )
+    assert report == {
+        "prefill": "donated, byte-right", "decode_step": "donated, byte-right",
+    }
+
+
+def test_traffic_phase_runs_on_a_toy_width(server):
+    smoke, cfg, sizes = _toy_smoke()
+    from infinistore_tpu.models import init_params
+
     conn = its.InfinityConnection(its.ClientConfig(
         host_addr="127.0.0.1", service_port=server["port"], log_level="error",
     ))

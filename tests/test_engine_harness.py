@@ -414,7 +414,7 @@ def test_ragged_wave_byte_identical_to_sequential_decode(params):
         h = ContinuousBatchingHarness.__new__(ContinuousBatchingHarness)
         h.params = params
         h.config = CFG
-        h.caches = base
+        h.caches = jax.tree.map(jnp.copy, base)  # a wave donates its cache
         h.max_req_blocks = MAX_REQ_BLOCKS
         h.gate = DeviceGate()
         return h
@@ -635,13 +635,18 @@ def test_device_gate_cancelled_writer_releases_queued_readers():
 # ---------------------------------------------------------------------------
 
 def _bare_wave_harness(params, caches=None):
-    """A harness skeleton for driving a WaveDecoder directly (no store)."""
+    """A harness skeleton for driving a WaveDecoder directly (no store).
+    It gets a COPY of ``caches``: every wave donates the harness's cache,
+    and the tests hand one base cache to several harnesses."""
     from infinistore_tpu.engine import ContinuousBatchingHarness
 
     h = ContinuousBatchingHarness.__new__(ContinuousBatchingHarness)
     h.params = params
     h.config = CFG
-    h.caches = caches if caches is not None else CFG.kv_spec(NUM_BLOCKS).make_caches()
+    h.caches = (
+        jax.tree.map(jnp.copy, caches) if caches is not None
+        else CFG.kv_spec(NUM_BLOCKS).make_caches()
+    )
     h.max_req_blocks = MAX_REQ_BLOCKS
     h.gate = DeviceGate()
     return h
@@ -668,6 +673,33 @@ def _skew_scenario(params):
     return tables, chunks, base
 
 
+def test_a_harness_wave_donates_the_harness_cache(params):
+    """A wave through the WaveDecoder updates the harness's cache in place:
+    the arrays ``h.caches`` held at dispatch are deleted by the step, what
+    the harness holds afterwards is live and in distinct buffers, and the
+    base the test kept (the harness got a copy) is untouched."""
+    from infinistore_tpu.engine import WaveDecoder
+
+    tables, chunks, base = _skew_scenario(params)
+
+    async def run():
+        h = _bare_wave_harness(params, base)
+        handed = [t for layer in h.caches for t in layer]
+        wave = WaveDecoder(h)
+        await asyncio.gather(*(
+            wave.step_chunk(toks, pos, jnp.asarray(tables[b]))
+            for b, (toks, pos) in enumerate(chunks)
+        ))
+        return handed, [t for layer in h.caches for t in layer], wave
+
+    handed, held, wave = asyncio.run(run())
+    assert wave.waves == 1
+    assert all(t.is_deleted() for t in handed)
+    assert not any(t.is_deleted() for t in held)
+    assert len({t.unsafe_buffer_pointer() for t in held}) == len(held)
+    assert not any(t.is_deleted() for layer in base for t in layer)
+
+
 def test_skew_policy_off_is_behavior_identical(params):
     """wave_skew_policy=False (the default) must reproduce the blind
     flush exactly: same coalescing, same pad accounting, same bytes, no
@@ -680,7 +712,7 @@ def test_skew_policy_off_is_behavior_identical(params):
     reset_wave_counters()
 
     async def run(**kw):
-        h = _bare_wave_harness(params, jax.tree_util.tree_map(lambda x: x, base))
+        h = _bare_wave_harness(params, base)
         wave = WaveDecoder(h, **kw)
         outs = await asyncio.gather(*(
             wave.step_chunk(toks, pos, jnp.asarray(tables[b]))

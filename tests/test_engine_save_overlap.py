@@ -254,11 +254,12 @@ def test_overlap_and_tail_read_what_happened(conn, params, case):
             await asyncio.sleep(0.05)
             adapter.release.set()
         else:
-            # Hold the LAST round instead, so that the write ends first.
+            # Hold the LAST round instead, so that the write ends first
+            # (the tap below this wrapper has noted GEN - 1 entries by then).
             inner = h.wave.step_chunk
 
             async def step_chunk(*a, **kw):
-                if len(entries) == GEN:
+                if len(entries) == GEN - 1:
                     await _until(lambda: h._saving == 0)
                 return await inner(*a, **kw)
 

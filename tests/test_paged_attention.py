@@ -119,7 +119,8 @@ def test_ragged_wave_matches_sequential_decode_step():
     next_toks = jnp.asarray([5, 9, 13], jnp.int32)
     positions = jnp.asarray([16, 8, 16], jnp.int32)
 
-    seq_caches = caches
+    # The steps donate their cache: the sequential run gets a copy of its own.
+    seq_caches = jax.tree.map(jnp.copy, caches)
     seq_logits = []
     for b in range(3):
         lg, seq_caches = decode_step(
@@ -142,6 +143,69 @@ def test_ragged_wave_matches_sequential_decode_step():
         rtol=2e-5, atol=2e-5,
     )
     _assert_caches_close(wave_caches, seq_caches)
+
+
+@pytest.mark.parametrize(
+    "entry", ["prefill", "decode_step", "verify_step_ragged", "resume_chunk"]
+)
+def test_serving_entries_donate_their_cache(entry):
+    """Every jitted entry that rewrites the paged cache donates it (jax
+    honours donation on the CPU too): the arrays handed in are deleted by
+    the call, the returned ones are live and distinct buffers, the blocks
+    the call wrote differ from the input's and every other block holds the
+    input's bytes."""
+    from infinistore_tpu.models import (
+        decode_step, prefill, resume_chunk, verify_step_ragged,
+    )
+    from infinistore_tpu.tpu.paged_attention import build_ragged_wave
+
+    cfg = _tiny_config()
+    max_blocks = 3
+    params, caches, tables = _prefilled_wave(
+        cfg, np.random.default_rng(11), prompt_lens=(16, 8, 16)
+    )
+    # A copy made on the device: on the CPU ``np.asarray`` of the input
+    # itself is a view that shares its buffer, and a shared buffer is not
+    # donated (the step then copies it, silently).
+    before = [
+        (np.asarray(k), np.asarray(v)) for k, v in jax.tree.map(jnp.copy, caches)
+    ]
+    handed = [t for layer in caches for t in layer]
+    tok, pos = jnp.asarray([5, 9, 13], jnp.int32), jnp.asarray([16, 8, 16], jnp.int32)
+    if entry == "prefill":
+        _, out = prefill(
+            params, jnp.arange(8, dtype=jnp.int32), caches, jnp.asarray([9]), cfg
+        )
+        touched = [9]
+    elif entry == "decode_step":
+        _, out = decode_step(
+            params, tok[0], pos[0], caches, jnp.asarray(tables[0]), cfg, max_blocks
+        )
+        touched = [tables[0][2]]
+    elif entry == "verify_step_ragged":
+        meta = build_ragged_wave(list(tables), np.asarray(pos) + 1, cfg.block_tokens)
+        _, out = verify_step_ragged(
+            params, tok, pos, jnp.arange(3, dtype=jnp.int32),
+            jnp.asarray(meta.pages), jnp.asarray(meta.page_rows),
+            jnp.asarray(meta.page_starts), caches, jnp.asarray(tables), cfg,
+            max_blocks,
+        )
+        touched = [tables[0][2], tables[1][1], tables[2][2]]
+    else:
+        _, out = resume_chunk(
+            params, jnp.arange(4, dtype=jnp.int32), jnp.int32(16), caches,
+            jnp.asarray(tables[0]), cfg,
+        )
+        touched = [tables[0][2]]
+    assert all(t.is_deleted() for t in handed)
+    returned = [t for layer in out for t in layer]
+    assert not any(t.is_deleted() for t in returned)
+    assert len({t.unsafe_buffer_pointer() for t in returned}) == len(returned)
+    untouched = np.setdiff1d(np.arange(16), touched)
+    for (k0, v0), (k1, v1) in zip(before, out):
+        np.testing.assert_array_equal(np.asarray(k1)[untouched], k0[untouched])
+        np.testing.assert_array_equal(np.asarray(v1)[untouched], v0[untouched])
+        assert not np.array_equal(np.asarray(k1)[touched], k0[touched])
 
 
 GEOMETRIES = [(4, 2), (4, 4), (4, 1)]  # (q heads, kv heads): GQA, MHA, MQA
@@ -196,7 +260,8 @@ def test_decode_wave_layer_chain_equals_ragged_wave_body(geom, monkeypatch):
     row_tables = jnp.take(block_tables, row_of, axis=0)
     wave_logits, wave_caches = verify_step_ragged(
         params, tokens.reshape(-1), positions.reshape(-1), row_of,
-        *rectangle_as_ragged(row_tables), caches, block_tables, cfg, max_blocks,
+        *rectangle_as_ragged(row_tables), jax.tree.map(jnp.copy, caches),
+        block_tables, cfg, max_blocks,
     )
     # The wave body traces ONE layer for all of them (models/llama.py
     # _wave_layer), so it reaches the dispatcher once.

@@ -73,6 +73,16 @@ def _compile(jitted, *args, **static):
     return exe
 
 
+def _param_shapes(s, cfg):
+    """The model's parameters as shapes placed by ``s`` (nothing is made)."""
+    from infinistore_tpu.models import llama
+
+    return jax.tree.map(
+        lambda x: s(x.shape, x.dtype),
+        jax.eval_shape(lambda: llama.init_params(cfg, jax.random.PRNGKey(0))),
+    )
+
+
 def _shapes(v5e, geom):
     _, h, kvh, d, bt, dtype = geom
     s = functools.partial(jax.ShapeDtypeStruct, sharding=v5e)
@@ -206,10 +216,7 @@ def test_resume_program_compiles_with_its_kernel(v5e, monkeypatch, case):
         ffn_dim=1024, block_tokens=bt, dtype=dtype,
     )
     s = functools.partial(jax.ShapeDtypeStruct, sharding=v5e)
-    params = jax.tree.map(
-        lambda x: s(x.shape, x.dtype),
-        jax.eval_shape(lambda: llama.init_params(cfg, jax.random.PRNGKey(0))),
-    )
+    params = _param_shapes(s, cfg)
     cache = s(cfg.kv_spec(blocks).cache_shape, cfg.dtype)
     exe = _compile(
         jax.jit(llama.resume_chunk.__wrapped__, static_argnames=("config",)),
@@ -235,10 +242,7 @@ def test_rectangle_views_compile_with_the_ragged_kernel(v5e, monkeypatch, view):
         ffn_dim=1024, block_tokens=bt, dtype=dtype,
     )
     s = functools.partial(jax.ShapeDtypeStruct, sharding=v5e)
-    params = jax.tree.map(
-        lambda x: s(x.shape, x.dtype),
-        jax.eval_shape(lambda: llama.init_params(cfg, jax.random.PRNGKey(0))),
-    )
+    params = _param_shapes(s, cfg)
     cache = s(cfg.kv_spec(NUM_BLOCKS).cache_shape, cfg.dtype)
     # Fresh jit wrappers, the wave body under decode_step included: a trace
     # taken under the CPU dispatch must not be reused, and this one must
@@ -376,10 +380,7 @@ def test_wave_program_shares_one_layer_and_one_kernel_function(v5e, monkeypatch)
         ffn_dim=1024, block_tokens=16, dtype=jnp.bfloat16,
     )
     s = functools.partial(jax.ShapeDtypeStruct, sharding=v5e)
-    params = jax.tree.map(
-        lambda x: s(x.shape, x.dtype),
-        jax.eval_shape(lambda: llama.init_params(cfg, jax.random.PRNGKey(0))),
-    )
+    params = _param_shapes(s, cfg)
     cache = s(cfg.kv_spec(640).cache_shape, cfg.dtype)
     i32 = lambda *shape: s(shape, jnp.int32)
     rows, pages, table = 8, 1024, 80
@@ -402,6 +403,73 @@ def test_wave_program_shares_one_layer_and_one_kernel_function(v5e, monkeypatch)
     assert len(calls) == 1, calls
     assert text.count("tpu_custom_call") == 1
     assert text.count('kernel_name = "_ragged_attn_kernel"') == 1
+
+
+# The three serving entries as the module declares them (their donation is
+# what is under test, so no fresh wrapper), each at a cell's attention widths
+# and cache (bf16, 16-token blocks, head_dim 128), 4 layers, FFN and vocabulary
+# kept small: one chat bucket of the wave body, the resume of a Mistral reuse
+# hit, a 1,024-token miss in DeepSeek's cell.
+# (entry, kv heads, cache blocks)
+DONATING = [
+    ("verify_step_ragged", 8, 640),
+    ("resume_chunk", 8, 1024),
+    ("prefill", 32, 320),
+]
+
+
+@pytest.mark.parametrize("case", DONATING, ids=[c[0] for c in DONATING])
+def test_serving_entries_update_the_cache_in_place(v5e, monkeypatch, case):
+    """No clock: each entry compiled for the v5e holds an
+    ``input_output_alias`` for EVERY cache tensor, the aliased bytes are the
+    whole cache's, and no ``copy``, ``copy-start`` or ``slice-start`` in the
+    program has the shape of one layer's K or V array or of a quarter of it.
+    Undonated, this wave program holds 4 ``copy`` + 4 ``copy-start`` of
+    ``bf16[640,16,8,128]`` and 28 ``slice-start`` of its quarters, the cache
+    read and written whole every step (PERF.md, PR 34). (At Mistral's
+    widths and these 4 layers XLA still stages 3 of a ``prefill``'s 8 donated
+    tensors through VMEM, asynchronously and in quarters; at 16 layers it
+    stages none. Hence DeepSeek's cell for that entry.)"""
+    from infinistore_tpu.models import llama
+
+    monkeypatch.setattr(paged, "_use_pallas", lambda: True)
+    entry, kvh, blocks = case
+    # A vocabulary no other test uses: the module's own jitted entries trace
+    # here, under the TPU dispatch, and never from or for another test.
+    cfg = llama.LlamaConfig(
+        vocab=1021, dim=4096, n_layers=4, n_heads=32, n_kv_heads=kvh,
+        ffn_dim=1024, block_tokens=16, dtype=jnp.bfloat16,
+    )
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=v5e)
+    i32 = lambda *shape: s(shape, jnp.int32)
+    params = _param_shapes(s, cfg)
+    cache = s(cfg.kv_spec(blocks).cache_shape, cfg.dtype)
+    caches = [(cache, cache)] * cfg.n_layers
+    if entry == "verify_step_ragged":
+        rows, pages, table = 8, 1024, 80
+        args = (
+            params, i32(rows), i32(rows), i32(rows), i32(pages), i32(pages + 1),
+            i32(rows), caches, i32(rows, table),
+        )
+        static = {"config": cfg, "max_blocks": table}
+    elif entry == "resume_chunk":
+        args, static = (params, i32(128), i32(), caches, i32(524)), {"config": cfg}
+    else:
+        args, static = (params, i32(1024), caches, i32(64)), {"config": cfg}
+    exe = _compile(getattr(llama, entry), *args, **static)
+    text = exe.as_text()
+    header = text.split("\n", 1)[0]
+    tensors = 2 * cfg.n_layers
+    assert len(re.findall(r"\(\d+, \{\}, (?:may|must)-alias\)", header)) == tensors, header
+    cache_bytes = tensors * int(np.prod(cache.shape)) * 2
+    assert exe.memory_analysis().alias_size_in_bytes == cache_bytes
+    whole = ",".join(map(str, cache.shape))
+    quarter = ",".join(map(str, (blocks // 4, *cache.shape[1:])))
+    moved = re.findall(
+        rf"^.* = [^=]*bf16\[(?:{whole}|{quarter})\][^=]* (?:copy|copy-start|slice-start)\(.*$",
+        text, flags=re.M,
+    )
+    assert not moved, moved[:3]
 
 
 def test_sharded_decode_compiles_for_four_chips(monkeypatch):
@@ -447,10 +515,7 @@ def test_full_width_steps_compile_and_fit_one_v5e(v5e, monkeypatch):
     )
     num_blocks, req_blocks, rows, seq = 4096, 64, 8, 1024
     s = functools.partial(jax.ShapeDtypeStruct, sharding=v5e)
-    params = jax.tree.map(
-        lambda x: s(x.shape, x.dtype),
-        jax.eval_shape(lambda: llama.init_params(cfg, jax.random.PRNGKey(0))),
-    )
+    params = _param_shapes(s, cfg)
     cache = s(cfg.kv_spec(num_blocks).cache_shape, cfg.dtype)
     caches = [(cache, cache)] * cfg.n_layers
     i32 = lambda *shape: s(shape, jnp.int32)
