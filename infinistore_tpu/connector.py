@@ -355,6 +355,11 @@ class KVConnector:
         # prefix's keys on every lookup/load/save (satellite of the adaptive
         # data-plane PR; BENCH_r05 put the 256-chain lookup at 26.1us with
         # the hashing/keying on top of it).
+        # The hit ledger (get_stats): store values (a K or a V of one block
+        # of one layer) the prefetches fetched, and what every block of every
+        # layer of the same hits would have been. They differ where the spec
+        # names sliding layers (PagedKVCacheSpec.hit_first_block).
+        self.hit_counters = {"hit_values_fetched": 0, "hit_values_whole_prefix": 0}
         self._chain_cache = _ChainHashCache()
         self._keys0_cache: Optional[Tuple[List[str], List[str]]] = None
 
@@ -637,7 +642,12 @@ class KVConnector:
         tspan = tracing.active_span()
         if tspan is not None and n > 0:
             tspan.stage("fetch_start")
-            tspan.annotate(hit_blocks=hit, fetch_blocks=n)
+            sliding, full = self.spec.hit_values(n)
+            tspan.annotate(
+                hit_blocks=hit, fetch_blocks=n, values_window=sliding, values_full=full,
+                bytes_window=sliding * self.spec.block_nbytes,
+                bytes_full=full * self.spec.block_nbytes,
+            )
         span = chains[first_block : first_block + n]
         # Mutable class cell so promote() upgrades LATER submissions even
         # on the coalescer path (the closure reads it per call).
@@ -668,6 +678,7 @@ class KVConnector:
                 retry_missing_s=retry_missing_s,
                 retry_interval_s=retry_interval_s,
                 fetch_gate=fetch_gate,
+                counters=self.hit_counters,
             )
         except StagingPoolExhausted as e:
             # The probe already ran — hand its answer to the fallback so a
@@ -918,9 +929,11 @@ class KVConnector:
 
     def get_stats(self) -> dict:
         """The store connection's per-op stats snapshot (observability
-        surface composed members re-expose — cluster.py stats())."""
+        surface composed members re-expose — cluster.py stats()), with this
+        connector's hit ledger beside it: ``hit_values_fetched`` and
+        ``hit_values_whole_prefix`` (``hit_counters``)."""
         self._require_store("get_stats")
-        return self.conn.get_stats()
+        return {**self.conn.get_stats(), **self.hit_counters}
 
     def drop(self, token_ids) -> int:
         """Remove this prompt's blocks from the store (all layers). Returns

@@ -46,6 +46,7 @@ lost to eviction (the cache-semantics path: the engine just recomputes).
 """
 
 import asyncio
+import collections
 import time
 from contextlib import asynccontextmanager
 from dataclasses import dataclass, field
@@ -56,7 +57,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import tracing
-from .models.llama import prefill, prefill_continue, verify_step_ragged
 from .tpu.paged import gather_blocks
 from .tpu.paged_attention import build_ragged_wave
 from .tpu.staging import StagingPoolExhausted
@@ -370,6 +370,12 @@ class WaveDecoder:
         hold_max_s: float = 0.002,
     ):
         self.h = harness
+        # Of the cache's shape the decoder needs the layers' windows alone:
+        # a sliding layer walks the wave's second page list.
+        spec = harness.config.kv_spec(1)
+        self._window = spec.window
+        self._layers = spec.num_layers
+        self._sliding = sum(w is not None for w in spec.windows or ())
         self.skew_policy = skew_policy
         self.defer_max_s = defer_max_s
         # BACKGROUND entries tolerate 4x the deferral age by default: the
@@ -389,6 +395,22 @@ class WaveDecoder:
         # many of them were padding: what the ragged kernel skips.
         self.wave_pages = 0
         self.wave_pad_pages = 0
+        # (layer, page) pairs the launched waves' real rows attended, and how
+        # many more a stack of full layers would have: what the sliding
+        # layers' second page list spared (0 where no layer has a window).
+        self.wave_layer_pages = 0
+        self.wave_window_pages_skipped = 0
+        # What a model's wave step hands back beside its logits
+        # (models/serving.py ``aux``), neither of which this class reads:
+        # per-row arrays, kept beside the logits rows of the last few waves
+        # (``row_aux``), and named counters, added up on the device (a wave's
+        # are results like its logits: nothing here waits for them).
+        self._row_aux = collections.OrderedDict()  # id(rows) -> (rows, aux rows)
+        # name -> device scalars not yet folded; the names a model's steps
+        # count by (``config.step_counters``) read 0 before the first wave.
+        self._step_counters = {
+            name: [] for name in getattr(harness.config, "step_counters", ())
+        }
         # Skew-policy ledger (per-decoder; the process-wide WaveCounters
         # singleton aggregates the same events for /metrics).
         self.deferrals = 0
@@ -463,6 +485,46 @@ class WaveDecoder:
             self._flush_tasks.add(task)
             task.add_done_callback(self._flush_tasks.discard)
         return await fut
+
+    # -- what the model's step returned beside its logits ---------------------
+
+    # Logits rows whose aux slice is kept: a request reads its own right after
+    # ``step_chunk`` returns, and at most a wave or two can resolve between.
+    ROW_AUX_KEPT = 64
+    # Waves whose counters are held apart before one small sum folds them.
+    COUNTERS_FOLDED_EVERY = 64
+
+    def _keep_aux(self, aux, handed: List[tuple]):
+        """``handed``: (logits rows as resolved, offset, length) per entry."""
+        for name, value in aux.get("counters", {}).items():
+            held = self._step_counters.setdefault(name, [])
+            held.append(value)
+            if len(held) >= self.COUNTERS_FOLDED_EVERY:
+                held[:] = [jnp.sum(jnp.stack(held))]
+        per_row = aux.get("rows")
+        if per_row is None:
+            return
+        for rows, off, n in handed:
+            self._row_aux[id(rows)] = (rows, per_row[off : off + n])
+        while len(self._row_aux) > self.ROW_AUX_KEPT:
+            self._row_aux.popitem(last=False)
+
+    def row_aux(self, rows):
+        """The per-row ``aux`` slice the wave returned with the logits
+        ``rows`` that ``step_chunk`` just handed a request; ``KeyError`` for
+        rows this decoder did not hand out lately."""
+        kept, per_row = self._row_aux[id(rows)]
+        if kept is not rows:
+            raise KeyError("these are not rows this decoder handed out")
+        return per_row
+
+    def step_counters(self) -> dict:
+        """The model step's named counters, summed over every wave so far
+        (a read waits for the last wave launched)."""
+        return {
+            name: int(sum(int(v) for v in held))
+            for name, held in self._step_counters.items()
+        }
 
     # -- skew-aware flush policy (docs/serving_load.md) ---------------------
 
@@ -650,18 +712,36 @@ class WaveDecoder:
             # The builder picks the page bucket (pad_to_pow2, or the
             # canonical pad_to): the per-row page-count rule lives in
             # build_ragged_wave alone.
+            row_tables = [tables[r] for r in row_of]
+            row_lens = [p + 1 for p in flat_pos]
+            bt = self.h.config.block_tokens
             meta = build_ragged_wave(
-                [tables[r] for r in row_of],
-                [p + 1 for p in flat_pos],
-                self.h.config.block_tokens,
-                pad_to=pad_pages,
-                pad_to_pow2=True,
+                row_tables, row_lens, bt, pad_to=pad_pages, pad_to_pow2=True
             )
             self.bucket_sizes.add((b_bucket, t_bucket, meta.num_pages))
             self.pad_rows += t_bucket - t_real
             self.launched_rows += t_bucket
             self.wave_pages += meta.num_pages
             self.wave_pad_pages += meta.pad_pages
+            # A sliding layer walks the wave's SECOND list: per row only the
+            # pages from its window's first on, padded to what the bucket's
+            # rows can hold at most, so the bucket stays one program.
+            window, real_pages = self._window, meta.num_pages - meta.pad_pages
+            step_kw = {}
+            if window is not None:
+                wmeta = build_ragged_wave(
+                    row_tables, row_lens, bt, window=window,
+                    pad_to=min(meta.num_pages, t_bucket * (window // bt + 1)),
+                )
+                step_kw["window_pages"] = (
+                    jnp.asarray(wmeta.pages),
+                    jnp.asarray(wmeta.page_rows),
+                    jnp.asarray(wmeta.page_starts),
+                )
+                self.wave_window_pages_skipped += self._sliding * (
+                    real_pages - (wmeta.num_pages - wmeta.pad_pages)
+                )
+            self.wave_layer_pages += self._layers * real_pages
             if self.skew_policy:
                 _WAVE_COUNTERS.bump("engine_wave_policy_waves")
                 _WAVE_COUNTERS.note_wave(t_real, t_bucket)
@@ -680,7 +760,7 @@ class WaveDecoder:
                     if wspan is not None:
                         wspan.stage("gate")
                     with tracing.device_call("its.wave_dispatch", wspan):
-                        logits, self.h.caches = verify_step_ragged(
+                        logits, self.h.caches, *aux = self.h.config.steps.wave(
                             self.h.params,
                             jnp.asarray(flat_toks, jnp.int32),
                             jnp.asarray(flat_pos, jnp.int32),
@@ -692,16 +772,21 @@ class WaveDecoder:
                             jnp.asarray(np.stack(tables)),
                             self.h.config,
                             self.h.max_req_blocks,
+                            **step_kw,
                         )
                     if wspan is not None:
                         wspan.stage("dispatched")
             self.waves += 1
             self.max_wave = max(self.max_wave, len(batch))
-            off = 0
+            off, handed = 0, []
             for toks, _, _, fut, *_ in batch:
                 if not fut.done():
-                    fut.set_result(logits[off : off + len(toks)])
+                    rows = logits[off : off + len(toks)]
+                    fut.set_result(rows)
+                    handed.append((rows, off, len(toks)))
                 off += len(toks)
+            if aux:
+                self._keep_aux(aux[0], handed)
             if wspan is not None:
                 wspan.stage("resolved")
                 wspan.finish()
@@ -992,7 +1077,11 @@ class ContinuousBatchingHarness:
         self.spec_rounds = 0  # generation waves a request participated in
         self.spec_drafted = 0  # draft tokens proposed
         self.spec_accepted = 0  # draft tokens accepted
-        self.caches = config.kv_spec(num_blocks).make_caches()
+        # The model's three steps (``config.steps``, models/serving.py) and
+        # its cache's shape come with the configuration: no model file is
+        # named here.
+        self.spec = config.kv_spec(num_blocks)
+        self.caches = self.spec.make_caches()
         self.pool = BlockPool(num_blocks)
         self.gate = DeviceGate()
         self.wave = WaveDecoder(
@@ -1072,19 +1161,23 @@ class ContinuousBatchingHarness:
                 pad_to=t * mrb,
             )
             zeros_t = jnp.zeros((t,), jnp.int32)
+            triple = tuple(
+                jnp.asarray(a) for a in (meta.pages, meta.page_rows, meta.page_starts)
+            )
+            # One token a row: its only page lies inside any window.
+            step_kw = {} if self.spec.window is None else {"window_pages": triple}
             async with self.gate.exclusive():
-                _, self.caches = verify_step_ragged(
+                _, self.caches, *_ = self.config.steps.wave(
                     self.params,
                     zeros_t,
                     zeros_t,
                     zeros_t,
-                    jnp.asarray(meta.pages),
-                    jnp.asarray(meta.page_rows),
-                    jnp.asarray(meta.page_starts),
+                    *triple,
                     self.caches,
                     jnp.asarray(np.zeros((t, mrb), np.int32)),
                     self.config,
                     mrb,
+                    **step_kw,
                 )
             bucket = (t, t, t * mrb)
             self.wave.prewarmed.add(bucket)
@@ -1106,7 +1199,7 @@ class ContinuousBatchingHarness:
         """Whole-prompt prefill into this request's blocks (cache-mutating:
         caller holds the exclusive gate)."""
         t0 = time.perf_counter()
-        _, self.caches = prefill(
+        _, self.caches = self.config.steps.prefill(
             self.params,
             jnp.asarray(token_ids, dtype=jnp.int32),
             self.caches,
@@ -1136,7 +1229,7 @@ class ContinuousBatchingHarness:
         self.resumes += 1
         self.resume_tokens += int(suffix.shape[0])
         self.resume_pages += -(-len(token_ids) // bt)
-        _, self.caches = prefill_continue(
+        _, self.caches = self.config.steps.resume(
             self.params,
             suffix,
             jnp.int32(start_block * bt),
@@ -1316,7 +1409,7 @@ class ContinuousBatchingHarness:
         fresh one-shot prefill oracle (gather-only on the shared cache)."""
         n = len(table)
         oracle_caches = self.config.kv_spec(n).make_caches()
-        _, oracle_caches = prefill(
+        _, oracle_caches = self.config.steps.prefill(
             self.params,
             jnp.asarray(token_ids, dtype=jnp.int32),
             oracle_caches,
@@ -1481,7 +1574,11 @@ class ContinuousBatchingHarness:
                         t_hold = time.perf_counter()
                         with tracing.trace_op("install") as ispan:
                             if ispan is not None:
-                                ispan.annotate(blocks=prefetch.n_blocks)
+                                sliding, full = self.spec.hit_values(prefetch.n_blocks)
+                                ispan.annotate(
+                                    blocks=prefetch.n_blocks,
+                                    values_window=sliding, values_full=full,
+                                )
                             self.caches, loaded_tokens = await self.adapter.install_kv(
                                 prefetch,
                                 self.caches,
@@ -1750,7 +1847,10 @@ class ContinuousBatchingHarness:
         ``wave_pad_fraction``, the share of launched wave rows that were
         padding, ``wave_pages`` / ``wave_pad_pages``, the flat attention
         pages launched and those of them that were the page bucket's
-        padding); the skew-aware flush policy's ledger
+        padding; ``wave_layer_pages`` / ``wave_window_pages_skipped``, the
+        (layer, page) pairs the waves' real rows attended and how many more
+        a stack of full layers would have; and whatever the model's wave
+        step counts itself, by its own names); the skew-aware flush policy's ledger
         (docs/serving_load.md: ``wave_deferrals``,
         ``wave_aging_escapes`` — deferred entries force-launched at the
         starvation bound, ``wave_held_flushes`` — whole flushes held by
@@ -1870,6 +1970,12 @@ class ContinuousBatchingHarness:
             # neither computes nor fetches (tpu/paged_attention.py).
             "wave_pages": self.wave.wave_pages,
             "wave_pad_pages": self.wave.wave_pad_pages,
+            "wave_layer_pages": self.wave.wave_layer_pages,
+            "wave_window_pages_skipped": self.wave.wave_window_pages_skipped,
+            # What the model's wave step counted itself (models/serving.py
+            # ``aux``): an expert model's ``moe_pairs`` and
+            # ``moe_distinct_experts``; nothing for a model that counts nothing.
+            **self.wave.step_counters(),
             # Skew-aware flush policy (docs/serving_load.md): the per-
             # harness deferral ledger (the process-wide WaveCounters
             # singleton aggregates the same events for /metrics), and

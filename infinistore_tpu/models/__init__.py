@@ -9,6 +9,7 @@ in tests and benchmarks, and the driver's graft entry has a jittable flagship
 step to compile.
 """
 
+from .afmoe import AfmoeConfig
 from .llama import (
     LlamaConfig,
     decode_step,
@@ -26,8 +27,11 @@ from .llama import (
     speculative_verify,
     train_step,
 )
+from .serving import ServingSteps
 
 __all__ = [
+    "AfmoeConfig",
+    "ServingSteps",
     "LlamaConfig",
     "init_params",
     "prefill",

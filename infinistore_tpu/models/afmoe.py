@@ -1,0 +1,638 @@
+"""A decoder of the ``afmoe`` family on the paged serving path.
+
+What differs from ``llama.py``, layer by layer (the equations are the
+published modelling code's; ``benchmarks/reference_afmoe.py`` writes the same
+ones out in plain float32):
+
+  h0      = E[token] * sqrt(dim)                              (mup)
+  n       = rms(h; w_in)
+  q, k    = rms_head(Wq n; w_q), rms_head(Wk n; w_k)   v = Wv n   g = Wg n
+  sliding : q, k <- rope(q, k);  key j visible to query i iff 0 <= i - j < window
+  full    : no rope;             key j visible to query i iff j <= i
+  a       = softmax(q k^T / sqrt(head_dim)) v          a <- a * sigmoid(g)
+  h       <- h + rms(Wo a; w_post_attn)
+  m       = rms(h; w_pre_mlp)
+  dense   : f = Wdown (silu(Wgate m) * Wup m)
+  expert  : s = sigmoid(Wr m) in float32;  S = top-k of (s + b)
+            w_e = route_scale * s_e / (sum_{e in S} s_e + 1e-20)
+            f = Shared(m) + sum_{e in S} w_e Expert_e(m)
+  h       <- h + rms(f; w_post_mlp)
+  logits  = Whead rms(h_L; w_final)
+
+The expert layer is told which experts it holds (``AfmoeConfig.experts_held``,
+a ``(first, count)`` span of the expert axis; all of them by default): it
+routes over ALL experts in float32 and computes its own experts' part, the
+shared expert riding with the share that holds expert 0, so the shares of a
+layer spread over chips add up to the layer. Nothing here stands in for
+absent chips. Two shapes of the one mathematics:
+
+- many tokens (``prefill``, ``resume_chunk``): the (token, expert) pairs
+  sorted by expert and ONE grouped matrix product a projection
+  (``_grouped_ffn``: the Pallas grouped matmul on the chip, ``ragged_dot``
+  elsewhere); no token dropped, no capacity factor;
+- few rows (a wave): the wave's DISTINCT chosen experts' weights streamed
+  once each through one kernel (``_moe_wave_pallas``: the scalar-prefetched
+  expert ids drive the weight blocks' index maps), every row multiplied by
+  its own combine weight for that expert (zero where it did not choose it);
+  no dense pass over all experts.
+
+The three serving entries keep the names the trace readers match
+(``prefill``, ``resume_chunk``, ``verify_step_ragged``) and donate ``caches``
+as ``llama.py``'s do. The wave returns, beside its logits, the ids every row's
+every expert layer chose and two counters (``moe_pairs``,
+``moe_distinct_experts``), all from the timed step itself (``serving.py``).
+The layerwise disagg entries of ``llama.py`` have no twin here: no cell runs
+them (ROADMAP).
+"""
+
+import functools
+from dataclasses import dataclass
+from typing import Dict, List, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ..tpu import paged
+from ..tpu.chunk_attention import chunk_prefix_attention
+from ..tpu.flash_prefill import flash_prefill_attention
+from ..tpu.paged import PagedKVCacheSpec, scatter_blocks
+from ..tpu.paged_attention import paged_decode_attention_rows
+from .llama import _rope
+from .serving import ServingSteps
+
+Params = Dict[str, jax.Array]
+Caches = List[Tuple[jax.Array, jax.Array]]
+
+SLIDING, FULL = "sliding_attention", "full_attention"
+# Tokens of a prompt whose expert products run as one grouped matmul: a
+# longer prompt is cut into equal chunks of at most this many (whole
+# multiples of 128), one after the other, so the sorted copies of a 32k
+# prompt's activations (8 a token) never stand in HBM at once.
+_MOE_CHUNK_TOKENS = 8192
+# Rows up to which the expert layer streams the rows' distinct experts
+# (rows x k slots at most) instead of sorting pairs into a grouped matmul.
+_MOE_WAVE_ROWS = 16
+_VMEM_LIMIT = 64 << 20
+
+
+@dataclass(frozen=True)
+class AfmoeConfig:
+    vocab: int = 512
+    dim: int = 64
+    n_heads: int = 4
+    n_kv_heads: int = 2
+    head_dim: int = 16
+    ffn_dim: int = 128  # the leading dense layers' width
+    moe_ffn_dim: int = 32  # one expert's width
+    n_experts: int = 8
+    experts_per_token: int = 2
+    n_shared_experts: int = 1
+    n_dense_layers: int = 1
+    layer_types: Tuple[str, ...] = (SLIDING, SLIDING, SLIDING, SLIDING, FULL)
+    sliding_window: int = 32
+    rope_theta: float = 10000.0
+    rms_eps: float = 1e-5
+    route_scale: float = 2.826
+    route_norm: bool = True
+    mup: bool = True
+    block_tokens: int = 8
+    dtype: jnp.dtype = jnp.bfloat16
+    # (first, count) of the expert axis this instance computes; None: all.
+    experts_held: Optional[Tuple[int, int]] = None
+
+    def __post_init__(self):
+        # A configuration file hands a list; jit wants the config hashable.
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        if self.experts_held is not None:
+            object.__setattr__(self, "experts_held", tuple(self.experts_held))
+        unknown = set(self.layer_types) - {SLIDING, FULL}
+        if unknown:
+            raise ValueError(f"layer_types holds {sorted(unknown)}")
+
+    @property
+    def n_layers(self) -> int:
+        return len(self.layer_types)
+
+    @property
+    def held(self) -> Tuple[int, int]:
+        return self.experts_held or (0, self.n_experts)
+
+    def window_of(self, layer: int) -> Optional[int]:
+        return self.sliding_window if self.layer_types[layer] == SLIDING else None
+
+    def kv_spec(self, num_blocks: int) -> PagedKVCacheSpec:
+        return PagedKVCacheSpec(
+            num_layers=self.n_layers,
+            num_blocks=num_blocks,
+            block_tokens=self.block_tokens,
+            num_kv_heads=self.n_kv_heads,
+            head_dim=self.head_dim,
+            dtype=self.dtype,
+            windows=tuple(self.window_of(l) for l in range(self.n_layers)),
+        )
+
+    @property
+    def steps(self) -> ServingSteps:
+        return ServingSteps(prefill, prefill_continue, verify_step_ragged)
+
+    # What the wave step counts and returns with its logits (serving.py).
+    step_counters = ("moe_pairs", "moe_distinct_experts")
+
+
+def init_params(config: AfmoeConfig, key: jax.Array) -> Params:
+    """Seeded 1/sqrt(fan_in) normal weights as a flat dict (layer-prefixed
+    keys), norms at one, the router's selection bias at zero. The held
+    experts only where the instance holds a share."""
+    keys = iter(jax.random.split(key, 4 + 12 * config.n_layers))
+    _, count = config.held
+
+    def dense(k, shape, fan_in):
+        w = jax.random.normal(k, shape, dtype=jnp.float32) / np.sqrt(fan_in)
+        return w.astype(config.dtype)
+
+    ones = lambda n: jnp.ones((n,), dtype=config.dtype)
+    d, hd, f = config.dim, config.head_dim, config.moe_ffn_dim
+    p: Params = {
+        "embed": dense(next(keys), (config.vocab, d), config.vocab),
+        "final_norm": ones(d),
+        "lm_head": dense(next(keys), (d, config.vocab), d),
+    }
+    for layer in range(config.n_layers):
+        pre = f"l{layer}."
+        for norm in ("in_norm", "post_attn_norm", "pre_mlp_norm", "post_mlp_norm"):
+            p[pre + norm] = ones(d)
+        p[pre + "q_norm"], p[pre + "k_norm"] = ones(hd), ones(hd)
+        p[pre + "wq"] = dense(next(keys), (d, config.n_heads, hd), d)
+        p[pre + "wk"] = dense(next(keys), (d, config.n_kv_heads, hd), d)
+        p[pre + "wv"] = dense(next(keys), (d, config.n_kv_heads, hd), d)
+        p[pre + "wg"] = dense(next(keys), (d, config.n_heads, hd), d)
+        p[pre + "wo"] = dense(next(keys), (config.n_heads, hd, d), config.n_heads * hd)
+        if layer < config.n_dense_layers:
+            p[pre + "w_gate_up"] = dense(next(keys), (d, 2, config.ffn_dim), d)
+            p[pre + "w_down"] = dense(next(keys), (config.ffn_dim, d), config.ffn_dim)
+            continue
+        p[pre + "router"] = dense(next(keys), (d, config.n_experts), d)
+        p[pre + "router_bias"] = jnp.zeros((config.n_experts,), jnp.float32)
+        p[pre + "w_gate"] = dense(next(keys), (count, d, f), d)
+        p[pre + "w_up"] = dense(next(keys), (count, d, f), d)
+        p[pre + "w_down_moe"] = dense(next(keys), (count, f, d), f)
+        fs = f * config.n_shared_experts
+        p[pre + "ws_gate_up"] = dense(next(keys), (d, 2, fs), d)
+        p[pre + "ws_down"] = dense(next(keys), (fs, d), fs)
+    return p
+
+
+def _rms(x: jax.Array, w: jax.Array, eps: float, dtype=None) -> jax.Array:
+    """In float32, rounded once, to ``dtype`` (x's own by default): a layer
+    passes six of these, and each branch's output is normed to the residual
+    stream's own size. The stream itself is carried in float32 within a step
+    (ten adds a token at these five layers, none of them rounded to the
+    served type); what the products take and the cache holds is the served
+    type."""
+    x32 = x.astype(jnp.float32)
+    var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
+    return (x32 * jax.lax.rsqrt(var + eps) * w.astype(jnp.float32)).astype(dtype or x.dtype)
+
+
+def _layer_weights(params: Params, layer: int) -> Params:
+    """Layer ``layer``'s weights without the layer prefix: the pytree every
+    layer of one kind hands the jitted layer body, so one trace serves them."""
+    pre = f"l{layer}."
+    return {k[len(pre):]: w for k, w in params.items() if k.startswith(pre)}
+
+
+def _embed(params: Params, tokens: jax.Array, config: AfmoeConfig) -> jax.Array:
+    x = jnp.take(params["embed"], tokens, axis=0).astype(jnp.float32)
+    if config.mup:
+        x = x * np.float32(np.sqrt(config.dim))
+    return x[None]  # [1, T, dim] float32: the residual stream
+
+
+def _head(params: Params, x: jax.Array, config: AfmoeConfig) -> jax.Array:
+    x = _rms(x, params["final_norm"], config.rms_eps, config.dtype)
+    return jnp.einsum("bsd,dv->bsv", x, params["lm_head"])
+
+
+def _attn_inputs(w: Params, x, positions, sliding: bool, config: AfmoeConfig):
+    """q [1, T, H, D], k and v [1, T, KVH, D], and the output gate's
+    pre-activation g [1, T, H, D], of the normed input."""
+    n = _rms(x, w["in_norm"], config.rms_eps, config.dtype)
+    q = _rms(jnp.einsum("bsd,dhk->bshk", n, w["wq"]), w["q_norm"], config.rms_eps)
+    k = _rms(jnp.einsum("bsd,dhk->bshk", n, w["wk"]), w["k_norm"], config.rms_eps)
+    v = jnp.einsum("bsd,dhk->bshk", n, w["wv"])
+    g = jnp.einsum("bsd,dhk->bshk", n, w["wg"])
+    if sliding:
+        q = _rope(q, positions, config.rope_theta)
+        k = _rope(k, positions, config.rope_theta)
+    return q, k, v, g
+
+
+def _attn_out(w: Params, x, attn, g, config: AfmoeConfig):
+    gated = attn.astype(jnp.float32) * jax.nn.sigmoid(g.astype(jnp.float32))
+    o = jnp.einsum("bshk,hkd->bsd", gated.astype(attn.dtype), w["wo"])
+    return x + _rms(o, w["post_attn_norm"], config.rms_eps, jnp.float32)
+
+
+def _swiglu(m, w_gate_up, w_down):
+    gate_up = jnp.einsum("bsd,dcf->bscf", m, w_gate_up)
+    return jnp.einsum(
+        "bsf,fd->bsd", jax.nn.silu(gate_up[:, :, 0]) * gate_up[:, :, 1], w_down
+    )
+
+
+# ---------------------------------------------------------------------------
+# The expert layer.
+# ---------------------------------------------------------------------------
+
+
+def route(m: jax.Array, router: jax.Array, bias: jax.Array, config: AfmoeConfig):
+    """m: [T, dim]. The ids the top-k chose ([T, k] int32, ranked by score
+    + selection bias) and their combine weights ([T, k] float32, from the
+    scores alone), over ALL experts, in float32."""
+    with jax.named_scope("afmoe_router"):
+        logits = jnp.dot(
+            m.astype(jnp.float32), router.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST,
+        )
+        scores = jax.nn.sigmoid(logits)
+        _, ids = jax.lax.top_k(scores + bias.astype(jnp.float32), config.experts_per_token)
+        chosen = jnp.take_along_axis(scores, ids, axis=1)
+        if config.route_norm:
+            chosen = chosen / (jnp.sum(chosen, axis=-1, keepdims=True) + 1e-20)
+        return ids.astype(jnp.int32), chosen * config.route_scale
+
+
+def _moe_wave_kernel(ids_ref, n_ref, x_ref, c_ref, wg_ref, wu_ref, wd_ref, out_ref):
+    """Grid (slot, F tile): slot s is the s-th distinct expert the wave's rows
+    chose; its gate, up and down tiles come in by the block specs' index maps
+    (``ids_ref[s]``), every row meets them, and the row's combine weight for
+    that expert (zero where it did not choose it) scales what it adds. Slots
+    past the wave's distinct experts repeat the last one's blocks (no copy)
+    and skip the compute."""
+    del ids_ref
+    s, j = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(jnp.logical_and(s == 0, j == 0))
+    def _init():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    @pl.when(s < n_ref[0])
+    def _fold():
+        x = x_ref[...]
+        dot = functools.partial(
+            jax.lax.dot_general, dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        h = jax.nn.silu(dot(x, wg_ref[...])) * dot(x, wu_ref[...])  # [Tp, tf] f32
+        h = h * c_ref[...][:, :1]
+        out_ref[...] += dot(h.astype(x.dtype), wd_ref[...])
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _moe_wave_pallas(x, slots, n_slots, combine, w_gate, w_up, w_down, *, interpret):
+    """x: [Tp, D]; slots: [S] int32 expert ids (held-local), the first
+    ``n_slots[0]`` real; combine: [S, Tp, 128] float32 (lanes equal); weights
+    [E, D, F], [E, D, F], [E, F, D]. Returns [Tp, D] float32."""
+    tp, d = x.shape
+    f = w_gate.shape[2]
+    tf = min(f, 512)
+    return pl.pallas_call(
+        _moe_wave_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(slots.shape[0], f // tf),
+            in_specs=[
+                pl.BlockSpec((tp, d), lambda s, j, ids, n: (0, 0)),
+                pl.BlockSpec((None, tp, 128), lambda s, j, ids, n: (s, 0, 0)),
+                pl.BlockSpec((None, d, tf), lambda s, j, ids, n: (ids[s], 0, j)),
+                pl.BlockSpec((None, d, tf), lambda s, j, ids, n: (ids[s], 0, j)),
+                pl.BlockSpec((None, tf, d), lambda s, j, ids, n: (ids[s], j, 0)),
+            ],
+            out_specs=pl.BlockSpec((tp, d), lambda s, j, ids, n: (0, 0)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((tp, d), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT,
+        ),
+        interpret=interpret,
+    )(slots, n_slots, x, combine, w_gate, w_up, w_down)
+
+
+def _wave_slots(ids, weights, config: AfmoeConfig):
+    """The wave's distinct chosen experts as kernel slots. Returns (slots [S]
+    held-local ids, the count of real slots [1], combine [S, T] float32, the
+    number of distinct experts the rows chose among ALL experts)."""
+    t, k = ids.shape
+    first, count = config.held
+    n_slots = min(t * k, config.n_experts)
+    flat = ids.reshape(-1)
+    uniq = jnp.unique(flat, size=n_slots, fill_value=jnp.max(flat))
+    fresh = jnp.concatenate([jnp.ones((1,), bool), uniq[1:] != uniq[:-1]])
+    n_real = jnp.sum(fresh, dtype=jnp.int32)
+    mine = fresh & (uniq >= first) & (uniq < first + count)
+    hits = (ids[None] == uniq[:, None, None]) & mine[:, None, None]  # [S, T, k]
+    combine = jnp.sum(jnp.where(hits, weights[None], 0.0), axis=-1)  # [S, T]
+    return jnp.clip(uniq - first, 0, count - 1), n_real.reshape(1), combine, n_real
+
+
+def _moe_wave(m, ids, weights, w: Params, config: AfmoeConfig):
+    """The few-rows form. m: [T, dim]; returns ([T, dim] float32, distinct)."""
+    t, d = m.shape
+    slots, n_real, combine, distinct = _wave_slots(ids, weights, config)
+    with jax.named_scope("afmoe_gathered_product"):
+        if paged._use_pallas():
+            tp = -(-t // 16) * 16
+            x = jnp.pad(m, ((0, tp - t), (0, 0)))
+            c = jnp.pad(combine, ((0, 0), (0, tp - t)))
+            c = jnp.broadcast_to(c[:, :, None], (*c.shape, 128))
+            out = _moe_wave_pallas(
+                x, slots, n_real, c, w["w_gate"], w["w_up"], w["w_down_moe"],
+                interpret=False,
+            )[:t]
+        else:
+            out = moe_wave_xla(m, slots, combine, w["w_gate"], w["w_up"], w["w_down_moe"])
+    return out, distinct
+
+
+@jax.jit
+def moe_wave_xla(m, slots, combine, w_gate, w_up, w_down):
+    """The wave kernel's mathematics in plain XLA (off the chip, and the
+    tests' reference for the kernel): gathers the slots' weights."""
+    f32 = jnp.float32
+    g = jnp.einsum("td,sdf->stf", m, jnp.take(w_gate, slots, axis=0), preferred_element_type=f32)
+    u = jnp.einsum("td,sdf->stf", m, jnp.take(w_up, slots, axis=0), preferred_element_type=f32)
+    h = (jax.nn.silu(g) * u * combine[:, :, None]).astype(m.dtype)
+    return jnp.einsum("stf,sfd->td", h, jnp.take(w_down, slots, axis=0), preferred_element_type=f32)
+
+
+def _grouped_matmul(lhs, rhs, group_sizes, out_dtype):
+    """lhs [M, K] sorted by group, rhs [G, K, N], group_sizes [G] (their sum
+    may fall short of M: the rows past it are nobody's). One grouped matrix
+    product: the Pallas grouped matmul on the chip, ``ragged_dot`` elsewhere."""
+    if paged._use_pallas():
+        from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+        m, k = lhs.shape
+        n = rhs.shape[2]
+        tiling = (512 if m % 512 == 0 else 128, min(k, 1024), min(n, 1024))
+        return gmm(lhs, rhs, group_sizes, preferred_element_type=out_dtype, tiling=tiling)
+    return jax.lax.ragged_dot(
+        lhs, rhs, group_sizes, preferred_element_type=out_dtype
+    )
+
+
+def _grouped_ffn(m, ids, weights, w: Params, config: AfmoeConfig):
+    """The many-tokens form for one chunk. m: [T, dim]; ids, weights: [T, k].
+    The (token, expert) pairs sorted by expert, the held experts' first; a
+    grouped product each for gate, up and down over the held experts' rows;
+    the rest of the pairs (another share's) add nothing. [T, dim] float32."""
+    t, d = m.shape
+    k = ids.shape[1]
+    first, count = config.held
+    flat = ids.reshape(-1)
+    # Held experts sort first, in their own order: (id - first) mod E.
+    order_key = jnp.mod(flat - first, config.n_experts)
+    order = jnp.argsort(order_key)
+    token = order // k
+    group_sizes = jnp.bincount(order_key, length=config.n_experts)[:count].astype(jnp.int32)
+    rows = jnp.take(m, token, axis=0)  # [T * k, dim]
+    pad = -rows.shape[0] % 128
+    if pad:
+        rows = jnp.pad(rows, ((0, pad), (0, 0)))
+    with jax.named_scope("afmoe_grouped_product"):
+        gate = _grouped_matmul(rows, w["w_gate"], group_sizes, jnp.float32)
+        up = _grouped_matmul(rows, w["w_up"], group_sizes, jnp.float32)
+        h = (jax.nn.silu(gate) * up).astype(m.dtype)
+        out = _grouped_matmul(h, w["w_down_moe"], group_sizes, jnp.float32)
+    mine = jnp.arange(out.shape[0]) < jnp.sum(group_sizes)
+    out = jnp.where(mine[:, None], out, 0.0)[: t * k]
+    out = out * jnp.take(weights.reshape(-1), order)[:, None]
+    return jnp.zeros((t, d), jnp.float32).at[token].add(out)
+
+
+def _chunks(tokens: int) -> Tuple[int, int]:
+    """(chunks, tokens a chunk) for a prompt's expert products."""
+    n = -(-tokens // _MOE_CHUNK_TOKENS)
+    return n, -(-tokens // (n * 128)) * 128
+
+
+def expert_layer(w: Params, m: jax.Array, config: AfmoeConfig):
+    """m: [T, dim], the normed input. Returns (f [T, dim] float32, ids
+    [T, k] the experts each row chose among all, distinct [] int32: how many
+    different experts the rows chose, counted for few rows only, else 0)."""
+    t = m.shape[0]
+    first, _ = config.held
+    ids, weights = route(m, w["router"], w["router_bias"], config)
+    if t <= _MOE_WAVE_ROWS:
+        out, distinct = _moe_wave(m, ids, weights, w, config)
+    else:
+        distinct = jnp.zeros((), jnp.int32)
+        n, size = _chunks(t)
+        if n == 1:
+            out = _grouped_ffn(m, ids, weights, w, config)
+        else:
+            pad = n * size - t
+            cut = lambda x: jnp.pad(x, ((0, pad), (0, 0))).reshape(n, size, x.shape[1])
+            out = jax.lax.map(
+                lambda c: _grouped_ffn(c[0], c[1], c[2], w, config),
+                (cut(m), cut(ids), cut(weights)),
+            ).reshape(n * size, -1)[:t]
+    if first == 0 and config.n_shared_experts:
+        out = out + _swiglu(m[None], w["ws_gate_up"], w["ws_down"])[0].astype(jnp.float32)
+    return out, ids, distinct
+
+
+def _mlp(w: Params, x, dense: bool, config: AfmoeConfig):
+    """The second half of a layer on x: [1, T, dim]. Returns (x_next, ids
+    [T, k] or None, distinct or None)."""
+    m = _rms(x, w["pre_mlp_norm"], config.rms_eps, config.dtype)
+    if dense:
+        f, ids, distinct = _swiglu(m, w["w_gate_up"], w["w_down"]), None, None
+    else:
+        f, ids, distinct = expert_layer(w, m[0], config)
+        f = f[None]
+    return x + _rms(f, w["post_mlp_norm"], config.rms_eps, jnp.float32), ids, distinct
+
+
+# ---------------------------------------------------------------------------
+# The three serving entries (serving.py). Each DONATES ``caches``.
+# ---------------------------------------------------------------------------
+
+
+@functools.partial(jax.jit, static_argnames=("config",), donate_argnames=("caches",))
+def prefill(
+    params: Params,
+    tokens: jax.Array,  # [S] int32, S % block_tokens == 0
+    caches: Caches,
+    block_table: jax.Array,  # [S // block_tokens] int32
+    config: AfmoeConfig,
+) -> Tuple[jax.Array, Caches]:
+    """A miss: the whole prompt, its K/V written to the table's blocks.
+    Returns (last-token logits, caches); ``caches`` is donated."""
+    s = tokens.shape[0]
+    bt = config.block_tokens
+    positions = jnp.arange(s, dtype=jnp.int32)[None]
+    x = _embed(params, tokens, config)
+    new_caches: Caches = []
+    for layer, (k_cache, v_cache) in enumerate(caches):
+        w = _layer_weights(params, layer)
+        window = config.window_of(layer)
+        q, k, v, g = _attn_inputs(w, x, positions, window is not None, config)
+        attn = flash_prefill_attention(q, k, v, causal=True, window=window)
+        x = _attn_out(w, x, attn, g, config)
+        x, _, _ = _mlp(w, x, layer < config.n_dense_layers, config)
+        blocks = lambda a: a[0].reshape(s // bt, bt, config.n_kv_heads, config.head_dim)
+        new_caches.append((
+            scatter_blocks(k_cache, block_table, blocks(k)),
+            scatter_blocks(v_cache, block_table, blocks(v)),
+        ))
+    return _head(params, x[:, -1:], config)[0, -1], new_caches
+
+
+def _wave_layer(
+    w: Params, x, positions, k_cache, v_cache, block_idx, slots, row_tables,
+    seq_lens, pages, page_rows, page_starts, config: AfmoeConfig, sliding: bool,
+    dense: bool,
+):
+    """ONE layer of the wave body on T flat rows (``llama._wave_layer``'s
+    role): insert the rows' K/V, attend each row's pages (a sliding layer its
+    windowed list), gate, residual, MLP. The layers of one kind share one
+    traced and lowered function."""
+    q, k, v, g = _attn_inputs(w, x, positions, sliding, config)
+    k_cache = k_cache.at[block_idx, slots].set(k[0].astype(k_cache.dtype))
+    v_cache = v_cache.at[block_idx, slots].set(v[0].astype(v_cache.dtype))
+    attn = paged_decode_attention_rows(
+        q[0], k_cache, v_cache, row_tables, seq_lens, pages, page_rows, page_starts,
+        window=config.sliding_window if sliding else None,
+    )[None]
+    x = _attn_out(w, x, attn, g, config)
+    x, ids, distinct = _mlp(w, x, dense, config)
+    return x, k_cache, v_cache, ids, distinct
+
+
+@functools.partial(
+    jax.jit, static_argnames=("config", "max_blocks"), donate_argnames=("caches",)
+)
+def verify_step_ragged(
+    params: Params,
+    tokens: jax.Array,  # [T] int32, the wave's chunks concatenated
+    positions: jax.Array,  # [T] int32
+    row_of: jax.Array,  # [T] int32 owning request per flat token
+    pages: jax.Array,  # [P] the wave's flat page list (RaggedWaveMeta)
+    page_rows: jax.Array,  # [P + 1]
+    page_starts: jax.Array,  # [T]
+    caches: Caches,
+    block_tables: jax.Array,  # [B, max_blocks]
+    config: AfmoeConfig,
+    max_blocks: int,
+    window_pages=None,  # the same triple for the sliding layers
+):
+    """THE wave body (``llama.verify_step_ragged``'s contract and argument
+    order), with the sliding layers on the wave's second page list. Returns
+    ``(logits [T, vocab], caches, aux)``: ``aux["rows"]`` [T, sites, k] the
+    experts every row chose at every expert layer IN THIS STEP, and
+    ``aux["counters"]``: ``moe_pairs`` (row, expert) pairs of the wave's real
+    rows over its expert layers (a tail row that repeats its predecessor is
+    padding) and ``moe_distinct_experts``, the distinct experts they touched,
+    a layer at a time. ``caches`` is donated."""
+    t = tokens.shape[0]
+    if block_tables.ndim != 2 or block_tables.shape[1] != max_blocks:
+        raise ValueError(f"block_tables must be [B, {max_blocks}], got {block_tables.shape}")
+    if window_pages is None and config.sliding_window is not None and SLIDING in config.layer_types:
+        raise ValueError("a model with sliding layers needs the wave's window_pages")
+    bt = config.block_tokens
+    x = _embed(params, tokens, config)
+    pos2d = positions[None]
+    row_tables = jnp.take(block_tables, row_of, axis=0)
+    block_idx = jnp.take_along_axis(row_tables, (positions // bt)[:, None], axis=1)[:, 0]
+    slots = positions % bt
+    seq_lens = positions + 1
+
+    layer_fn = jax.jit(_wave_layer, static_argnames=("config", "sliding", "dense"))
+    new_caches: Caches = []
+    chosen, distinct = [], jnp.zeros((), jnp.int32)
+    for layer, (k_cache, v_cache) in enumerate(caches):
+        sliding = config.window_of(layer) is not None
+        meta = window_pages if sliding else (pages, page_rows, page_starts)
+        x, k_cache, v_cache, ids, n = layer_fn(
+            _layer_weights(params, layer), x, pos2d, k_cache, v_cache, block_idx,
+            slots, row_tables, seq_lens, *meta, config=config, sliding=sliding,
+            dense=layer < config.n_dense_layers,
+        )
+        new_caches.append((k_cache, v_cache))
+        if ids is not None:
+            chosen.append(ids)
+            distinct = distinct + n
+    logits = _head(params, x, config)[0]
+    real = jnp.concatenate([
+        jnp.ones((1,), bool),
+        (positions[1:] != positions[:-1]) | (row_of[1:] != row_of[:-1]),
+    ])
+    aux = {
+        "rows": jnp.stack(chosen, axis=1),  # [T, sites, k]
+        "counters": {
+            "moe_pairs": jnp.sum(real, dtype=jnp.int32)
+            * (len(chosen) * config.experts_per_token),
+            "moe_distinct_experts": distinct,
+        },
+    }
+    return logits, new_caches, aux
+
+
+@functools.partial(jax.jit, static_argnames=("config",), donate_argnames=("caches",))
+def resume_chunk(
+    params: Params,
+    tokens: jax.Array,  # [S_c] int32, the suffix chunk
+    start_pos: jax.Array,  # [] int32
+    caches: Caches,
+    block_table: jax.Array,  # [max_blocks] int32
+    config: AfmoeConfig,
+) -> Tuple[jax.Array, Caches]:
+    """A prefix hit's question: ONE request's chunk at contiguous positions
+    over the pages in the cache (``llama.resume_chunk``'s contract). A
+    sliding layer reads no page behind its first row's window: those a hit
+    left uninstalled. ``caches`` is donated."""
+    s_c = tokens.shape[0]
+    bt = config.block_tokens
+    positions = start_pos + jnp.arange(s_c, dtype=jnp.int32)
+    pos2d = positions[None]
+    x = _embed(params, tokens, config)
+    block_idx = jnp.take(block_table, positions // bt)
+    slots = positions % bt
+    new_caches: Caches = []
+    for layer, (k_cache, v_cache) in enumerate(caches):
+        w = _layer_weights(params, layer)
+        window = config.window_of(layer)
+        q, k, v, g = _attn_inputs(w, x, pos2d, window is not None, config)
+        k_cache = k_cache.at[block_idx, slots].set(k[0].astype(k_cache.dtype))
+        v_cache = v_cache.at[block_idx, slots].set(v[0].astype(v_cache.dtype))
+        attn = chunk_prefix_attention(
+            q[0], k_cache, v_cache, block_table, start_pos, window=window
+        )[None]
+        x = _attn_out(w, x, attn, g, config)
+        x, _, _ = _mlp(w, x, layer < config.n_dense_layers, config)
+        new_caches.append((k_cache, v_cache))
+    return _head(params, x, config)[0], new_caches
+
+
+def prefill_continue(params, tokens, start_pos, caches, block_table, config, max_blocks):
+    """``llama.prefill_continue``'s signature over this file's
+    ``resume_chunk``: the harness's resume step."""
+    if block_table.shape[0] != max_blocks:
+        raise ValueError(
+            f"block_table has {block_table.shape[0]} entries, expected max_blocks={max_blocks}"
+        )
+    return resume_chunk(params, tokens, start_pos, caches, block_table, config)
+
+
+def choices(harness, rows) -> np.ndarray:
+    """``[len(rows), sites, k]``: the experts the timed wave chose at every
+    expert layer while it made the logits ``rows`` that
+    ``harness.wave.step_chunk`` just handed this request (the benchmark's
+    ``program.choices``). Read off what the wave returned with those very
+    logits; nothing is computed again."""
+    return np.asarray(harness.wave.row_aux(rows))

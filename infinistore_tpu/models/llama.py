@@ -30,6 +30,7 @@ from ..tpu.paged_attention import (
     paged_decode_attention_rows,
     rectangle_as_ragged,
 )
+from .serving import ServingSteps
 
 Params = Dict[str, jax.Array]
 Caches = List[Tuple[jax.Array, jax.Array]]
@@ -55,6 +56,12 @@ class LlamaConfig:
     @property
     def head_dim(self) -> int:
         return self.dim // self.n_heads
+
+    @property
+    def steps(self) -> ServingSteps:
+        """This file's serving steps, by role (serving.py): what the engine
+        runs for a configuration of this class."""
+        return ServingSteps(prefill, prefill_continue, verify_step_ragged)
 
     def kv_spec(self, num_blocks: int) -> PagedKVCacheSpec:
         """Paged-KV cache spec matching this model's layers/heads/dtype."""

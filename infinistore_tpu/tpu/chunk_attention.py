@@ -54,7 +54,8 @@ _TILE_ROWS = 128
 _VMEM_LIMIT = 64 << 20
 
 
-def _fold_pages(q, k, v, qpos, kpos0, m_scr, l_scr, acc_scr, g, masked):
+def _fold_pages(q, k, v, qpos, kpos0, m_scr, l_scr, acc_scr, g, masked,
+                window=None):
     """Fold one KV head's ``[T, D]`` keys and values into the running
     (max, denominator, accumulator) of its ``[R, D]`` query rows."""
     r, d = q.shape
@@ -71,6 +72,8 @@ def _fold_pages(q, k, v, qpos, kpos0, m_scr, l_scr, acc_scr, g, masked):
     if masked:
         kpos = kpos0 + jax.lax.broadcasted_iota(jnp.int32, (r, t), 1)
         valid = kpos <= qpos
+        if window is not None:
+            valid = jnp.logical_and(valid, qpos - kpos < window)
         logits = jnp.where(valid, logits, _NEG_INF)
     m_prev = m_scr[g]  # [R, 128], all lanes equal
     m_next = jnp.maximum(m_prev, jnp.max(logits, axis=1, keepdims=True))
@@ -93,7 +96,7 @@ def _chunk_attn_kernel(
     start_ref,  # scalar-prefetch: [1] int32, position of the chunk's row 0
     q_ref,  # [KVH, R, D], R = tile x G rows ordered (chunk row, group head)
     *refs,  # PG key pages, PG value pages [1, bt, KVH, D]; out; 3 scratch
-    pages, s_real, groups,
+    pages, s_real, groups, window=None,
 ):
     del table_ref
     k_refs, v_refs = refs[:pages], refs[pages : 2 * pages]
@@ -108,6 +111,12 @@ def _chunk_attn_kernel(
     # The tile's walk ends at the page of its own last real row.
     n_pages = (start + jnp.minimum(row0 + tile, s_real) + bt - 1) // bt
     kpos0 = i * step_tokens
+    if window is not None:
+        # A sliding layer: the tile's walk starts at the step that holds the
+        # oldest key its first row sees, and its slots under that key's page
+        # re-serve that page (_window_walk): by their nominal positions they
+        # lie behind every row's window, so the mask drops them.
+        kpos0 = kpos0 + _window_walk(start, row0, window, bt, pages)[0] * step_tokens
 
     @pl.when(i == 0)
     def _init():
@@ -128,7 +137,7 @@ def _chunk_attn_kernel(
         for g in range(kvh):
             _fold_pages(
                 q_ref[g], k[:, g, :], v[:, g, :],
-                qpos, kpos0, m_scr, l_scr, acc_scr, g, masked,
+                qpos, kpos0, m_scr, l_scr, acc_scr, g, masked, window,
             )
 
     # Steps wholly under the tile's first position need no mask; steps past
@@ -137,6 +146,11 @@ def _chunk_attn_kernel(
     # last real step's clamped duplicate pages sit above every row's
     # position by their nominal index, so causality masks them.
     whole = kpos0 + step_tokens - 1 <= start + row0
+    if window is not None:
+        # ... and wholly inside the window of the tile's LAST row.
+        whole = jnp.logical_and(
+            whole, kpos0 > start + jnp.minimum(row0 + tile, s_real) - 1 - window
+        )
     live = kpos0 < n_pages * bt
 
     @pl.when(jnp.logical_and(live, whole))
@@ -156,9 +170,17 @@ def _chunk_attn_kernel(
             ).astype(out_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+def _window_walk(start, row0, window: int, bt: int, pages: int):
+    """(first step, first page) of a sliding layer's walk for the tile whose
+    first row is chunk row ``row0``: the page of the oldest key that row sees
+    (position ``start + row0 - window + 1``) and the grid step holding it."""
+    first_page = jnp.maximum(start + row0 - (window - 1), 0) // bt
+    return first_page // pages, first_page
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "window"))
 def _chunk_prefix_attention_pallas(
-    q, k_cache, v_cache, block_table, start_pos, *, interpret
+    q, k_cache, v_cache, block_table, start_pos, *, interpret, window=None
 ):
     """q: [S_c, H, D]; block_table: [max_blocks]; start_pos: [] int32."""
     s, h, d = q.shape
@@ -178,13 +200,23 @@ def _chunk_prefix_attention_pallas(
         qr = jnp.pad(qr, ((0, 0), (0, s_pad - s), (0, 0), (0, 0)))
     rows = tile * groups
     qr = qr.reshape(kvh, s_pad * groups, d)
+    if window is not None:
+        # A tile's walk is its rows and the window behind the first: the
+        # steps count from the walk's first, and the table's earlier pages
+        # (a hit leaves them uninstalled) are never an operand.
+        steps = min(steps, (window + tile - 2) // (pages * bt) + 2)
 
     def page(j):
         def index(t, i, tbl, st):
             last = jnp.minimum((t + 1) * tile, s)  # rows up to the tile's end
             n_pages = jnp.minimum((st[0] + last + bt - 1) // bt, n)
-            step = jnp.minimum(i, (n_pages - 1) // pages)
-            return (tbl[jnp.minimum(step * pages + j, n_pages - 1)], 0, 0, 0)
+            if window is None:
+                step = jnp.minimum(i, (n_pages - 1) // pages)
+                return (tbl[jnp.minimum(step * pages + j, n_pages - 1)], 0, 0, 0)
+            step0, page0 = _window_walk(st[0], t * tile, window, bt, pages)
+            step = jnp.minimum(i + step0, (n_pages - 1) // pages)
+            at = jnp.clip(step * pages + j, page0, n_pages - 1)
+            return (tbl[at], 0, 0, 0)
 
         return pl.BlockSpec((1, bt, kvh, d), index)
 
@@ -192,7 +224,8 @@ def _chunk_prefix_attention_pallas(
     page_specs = [page(j) for j in range(pages)]
     out = pl.pallas_call(
         functools.partial(
-            _chunk_attn_kernel, pages=pages, s_real=s, groups=groups
+            _chunk_attn_kernel, pages=pages, s_real=s, groups=groups,
+            **({} if window is None else {"window": window}),
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
@@ -222,10 +255,13 @@ def _chunk_prefix_attention_pallas(
     return jnp.swapaxes(out, 0, 1).reshape(s, h, d)
 
 
-@jax.jit
-def chunk_prefix_attention_xla(q, k_cache, v_cache, block_table, start_pos):
+@functools.partial(jax.jit, static_argnames=("window",))
+def chunk_prefix_attention_xla(q, k_cache, v_cache, block_table, start_pos,
+                               window=None):
     """Dense semantics on any backend: gather the table's pages, mask row r
-    to positions <= start_pos + r, float32 softmax at HIGHEST precision."""
+    to positions <= start_pos + r (and, under ``window``, to its last
+    ``window`` positions: what lies behind every row's window is zeroed before
+    it is multiplied), float32 softmax at HIGHEST precision."""
     s, h, d = q.shape
     _, bt, kvh, _ = k_cache.shape
     groups = h // kvh
@@ -237,7 +273,12 @@ def chunk_prefix_attention_xla(q, k_cache, v_cache, block_table, start_pos):
         precision=jax.lax.Precision.HIGHEST,
     ) * (1.0 / np.sqrt(d))
     qpos = start_pos + jnp.arange(s, dtype=jnp.int32)
-    valid = jnp.arange(k.shape[0], dtype=jnp.int32)[None, :] <= qpos[:, None]
+    kpos = jnp.arange(k.shape[0], dtype=jnp.int32)
+    valid = kpos[None, :] <= qpos[:, None]
+    if window is not None:
+        valid &= qpos[:, None] - kpos[None, :] < window
+        seen = (kpos > start_pos - window)[:, None, None]
+        k, v = jnp.where(seen, k, 0), jnp.where(seen, v, 0)
     logits = jnp.where(valid[None, None], logits, _NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum(
@@ -247,7 +288,8 @@ def chunk_prefix_attention_xla(q, k_cache, v_cache, block_table, start_pos):
     return out.reshape(s, h, d).astype(q.dtype)
 
 
-def chunk_prefix_attention(q, k_cache, v_cache, block_table, start_pos):
+def chunk_prefix_attention(q, k_cache, v_cache, block_table, start_pos,
+                           window=None):
     """Attention of ONE request's suffix chunk over its paged context.
 
     q: [S_c, n_heads, head_dim], row r at position ``start_pos + r``;
@@ -255,11 +297,19 @@ def chunk_prefix_attention(q, k_cache, v_cache, block_table, start_pos):
     the chunk's own K/V already inserted; block_table: [max_blocks] int32,
     entries past ``ceil((start_pos + S_c) / block_tokens)`` may be any valid
     block id (they are neither read nor attended); start_pos: scalar int32.
-    Row r attends positions ``0 .. start_pos + r``. Returns [S_c, n_heads,
+    Row r attends positions ``0 .. start_pos + r``, under ``window`` (static;
+    a sliding layer) the last ``window`` of them, and no page wholly behind
+    the first row's window is read; None lowers the program it always did.
+    Returns [S_c, n_heads,
     head_dim] in q's dtype. One walk over the request's pages on TPU, gather
     + dense XLA elsewhere."""
     if paged._use_pallas():
         return _chunk_prefix_attention_pallas(
-            q, k_cache, v_cache, block_table, start_pos, interpret=False
+            q, k_cache, v_cache, block_table, start_pos, interpret=False,
+            **({} if window is None else {"window": window}),
         )
-    return chunk_prefix_attention_xla(q, k_cache, v_cache, block_table, start_pos)
+    if window is None:
+        return chunk_prefix_attention_xla(q, k_cache, v_cache, block_table, start_pos)
+    return chunk_prefix_attention_xla(
+        q, k_cache, v_cache, block_table, start_pos, window=window
+    )

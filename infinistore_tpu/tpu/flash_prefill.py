@@ -52,15 +52,27 @@ def _block_and_padded(n: int, limit: int, align: int):
     return block, -(-n // block) * block
 
 
+def _band_first_block(qb, bq: int, bk: int, window):
+    """First K block a query block of a sliding layer still sees: the block
+    of the oldest key of its first row (``qb * bq - window + 1``). The K axis
+    of a windowed grid counts from here, so blocks wholly behind the band are
+    no grid step at all."""
+    return jnp.maximum(qb * bq - (window - 1), 0) // bk
+
+
 def _flash_kernel(
-    q_ref, k_ref, v_ref, out_ref, m_scr, l_scr, acc_scr, *, causal, kv_len
+    q_ref, k_ref, v_ref, out_ref, m_scr, l_scr, acc_scr, *, causal, kv_len,
+    window=None,
 ):
     qb = pl.program_id(1)
     kb = pl.program_id(2)
     _, bq, d = q_ref.shape
     bk = k_ref.shape[1]
+    step = kb  # the K axis's own count: the band's first block is step 0
+    if window is not None:
+        kb = kb + _band_first_block(qb, bq, bk, window)
 
-    @pl.when(kb == 0)
+    @pl.when(step == 0)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
@@ -77,14 +89,15 @@ def _flash_kernel(
         @pl.when(kb <= kb_max)
         def _update():
             _flash_update(
-                qb, kb, q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, causal, kv_len
+                qb, kb, q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, causal, kv_len,
+                window,
             )
     else:
         _flash_update(
             qb, kb, q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, causal, kv_len
         )
 
-    @pl.when(kb == pl.num_programs(2) - 1)
+    @pl.when(step == pl.num_programs(2) - 1)
     def _finish():
         # Row 0 attends to at least itself under causal, so l >= 1; the
         # guard only matters for hypothetical fully-masked rows.
@@ -94,7 +107,8 @@ def _flash_kernel(
 
 
 def _flash_update(
-    qb, kb, q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, causal, kv_len
+    qb, kb, q_ref, k_ref, v_ref, m_scr, l_scr, acc_scr, causal, kv_len,
+    window=None,
 ):
     _, bq, d = q_ref.shape
     bk = k_ref.shape[1]
@@ -133,6 +147,8 @@ def _flash_update(
         if causal:
             qpos = qb * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
             valid = qpos >= kpos
+            if window is not None:
+                valid = jnp.logical_and(valid, qpos - kpos < window)
         if kv_len is not None:
             in_ctx = kpos < kv_len
             valid = in_ctx if valid is None else jnp.logical_and(valid, in_ctx)
@@ -164,9 +180,10 @@ def _flash_update(
 
 
 @functools.partial(
-    jax.jit, static_argnames=("causal", "block_q", "block_k", "interpret")
+    jax.jit, static_argnames=("causal", "block_q", "block_k", "interpret", "window")
 )
-def _flash_prefill_pallas(q, k, v, *, causal, block_q, block_k, interpret):
+def _flash_prefill_pallas(q, k, v, *, causal, block_q, block_k, interpret,
+                          window=None):
     b, s, h, d = q.shape
     t, kvh = k.shape[1], k.shape[2]
     groups = h // kvh
@@ -192,7 +209,18 @@ def _flash_prefill_pallas(q, k, v, *, causal, block_q, block_k, interpret):
     def kv_row(bh):
         return (bh // h) * kvh + (bh % h) // groups
 
-    if causal:
+    k_steps = t_pad // bk
+    if window is not None:
+        # A sliding layer: the K axis counts from the band's first block, and
+        # is as long as the widest band (a query block's rows and the window
+        # behind its first), so blocks wholly outside the band cost nothing.
+        k_steps = min(k_steps, (window + bq - 2) // bk + 2)
+
+        def kv_block(qb, kb):
+            return jnp.minimum(
+                kb + _band_first_block(qb, bq, bk, window), (qb * bq + bq - 1) // bk
+            )
+    elif causal:
         # Clamp above-diagonal steps to the diagonal block: the pipeline
         # sees the same block index as the previous step and skips the HBM
         # fetch; the kernel skips their compute (see _flash_kernel).
@@ -202,10 +230,11 @@ def _flash_prefill_pallas(q, k, v, *, causal, block_q, block_k, interpret):
         def kv_block(qb, kb):
             return kb
 
-    grid = (b * h, s_pad // bq, t_pad // bk)
+    grid = (b * h, s_pad // bq, k_steps)
     out = pl.pallas_call(
         functools.partial(
-            _flash_kernel, causal=causal, kv_len=t if t_pad != t else None
+            _flash_kernel, causal=causal, kv_len=t if t_pad != t else None,
+            **({} if window is None else {"window": window}),
         ),
         grid=grid,
         in_specs=[
@@ -225,8 +254,8 @@ def _flash_prefill_pallas(q, k, v, *, causal, block_q, block_k, interpret):
     return jnp.swapaxes(out[:, :s].reshape(b, h, s, d), 1, 2)  # [B, S, H, D]
 
 
-@functools.partial(jax.jit, static_argnames=("causal",))
-def flash_prefill_xla(q, k, v, *, causal=True):
+@functools.partial(jax.jit, static_argnames=("causal", "window"))
+def flash_prefill_xla(q, k, v, *, causal=True, window=None):
     """Dense reference semantics on any backend (f32 softmax, HIGHEST)."""
     groups = q.shape[2] // k.shape[2]
     k = jnp.repeat(k, groups, axis=2)
@@ -244,6 +273,8 @@ def flash_prefill_xla(q, k, v, *, causal=True):
     if causal:
         s, t = q.shape[1], k.shape[1]
         cm = jnp.arange(s)[:, None] >= jnp.arange(t)[None, :]
+        if window is not None:
+            cm &= jnp.arange(s)[:, None] - jnp.arange(t)[None, :] < window
         logits = jnp.where(cm[None, None], logits, _NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum(
@@ -255,7 +286,8 @@ def flash_prefill_xla(q, k, v, *, causal=True):
     return out.astype(q.dtype)
 
 
-def flash_prefill_attention(q, k, v, *, causal=True, block_q=256, block_k=256):
+def flash_prefill_attention(q, k, v, *, causal=True, block_q=256, block_k=256,
+                            window=None):
     """Prefill attention without materializing S x T logits.
 
     q: [B, S, H, D]; k/v: [B, T, KVH, D] with KVH dividing H (GQA); any S/T
@@ -273,7 +305,13 @@ def flash_prefill_attention(q, k, v, *, causal=True, block_q=256, block_k=256):
     position 0, so it requires S == T; a suffix chunk attending a longer
     context (S < T with q offset T-S) would be silently over-masked —
     rejected loudly instead (a chunk at an offset over a paged context is
-    tpu/chunk_attention.py's, behind models/llama.py prefill_continue)."""
+    tpu/chunk_attention.py's, behind models/llama.py prefill_continue).
+
+    ``window`` (static, causal only): a sliding layer, key j visible to query
+    i iff ``0 <= i - j < window``; K blocks wholly outside the band are no
+    grid step. None lowers the program it always did."""
+    if window is not None and not causal:
+        raise ValueError("a sliding window is causal")
     if causal and q.shape[1] != k.shape[1]:
         raise ValueError(
             f"causal=True assumes q and k start at position 0, so S must "
@@ -283,6 +321,8 @@ def flash_prefill_attention(q, k, v, *, causal=True, block_q=256, block_k=256):
     if paged._use_pallas():
         return _flash_prefill_pallas(
             q, k, v, causal=causal, block_q=block_q, block_k=block_k,
-            interpret=False,
+            interpret=False, **({} if window is None else {"window": window}),
         )
-    return flash_prefill_xla(q, k, v, causal=causal)
+    if window is None:
+        return flash_prefill_xla(q, k, v, causal=causal)
+    return flash_prefill_xla(q, k, v, causal=causal, window=window)

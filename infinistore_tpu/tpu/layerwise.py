@@ -275,14 +275,20 @@ class LayerwiseKVReader:
         bn = self.spec.block_nbytes
         dt = np.dtype(jax.numpy.dtype(self.spec.dtype))
 
+        # A sliding layer's hit is its last window / block_tokens blocks
+        # (PagedKVCacheSpec.hit_first_block); every other layer's is whole.
+        firsts = [self.spec.hit_first_block(layer, n) for layer in range(num_layers)]
+
         def fetch(layer: int):
             # K blocks then V blocks packed into one contiguous region span,
             # so the layer later uploads as a single device transfer.
             base = self.regions.base_offset(layer % self.regions.count)
+            first = firsts[layer]
+            m = n - first
             blocks = [
-                (key_fn(layer, "k", i), base + i * bn) for i in range(n)
+                (key_fn(layer, "k", first + i), base + i * bn) for i in range(m)
             ] + [
-                (key_fn(layer, "v", i), base + (n + i) * bn) for i in range(n)
+                (key_fn(layer, "v", first + i), base + (m + i) * bn) for i in range(m)
             ]
             return asyncio.ensure_future(
                 self.conn.read_cache_async(
@@ -323,18 +329,21 @@ class LayerwiseKVReader:
             for layer in range(num_layers):
                 await fetches.pop(layer)
                 region = layer % R
+                first = firsts[layer]
+                m = n - first
                 kv_host = (
-                    self.regions.kv_view(region, n, bn)
+                    self.regions.kv_view(region, m, bn)
                     .view(dt)
-                    .reshape((2 * n, *self.spec.block_shape))
+                    .reshape((2 * m, *self.spec.block_shape))
                 )
                 # ONE H2D per layer (K and V ride together); split on device.
                 kv_dev = jax.device_put(kv_host)
                 uploads[layer] = kv_dev
                 k_cache, v_cache = out[layer]
+                ids = ids_dev[first:] if first else ids_dev
                 out[layer] = (
-                    scatter_blocks(k_cache, ids_dev, kv_dev[:n]),
-                    scatter_blocks(v_cache, ids_dev, kv_dev[n:]),
+                    scatter_blocks(k_cache, ids, kv_dev[:m]),
+                    scatter_blocks(v_cache, ids, kv_dev[m:]),
                 )
                 if on_layer is not None:
                     on_layer(layer, out[layer])
@@ -404,6 +413,7 @@ class LayerwisePrefetch:
         retry_missing_s: float = 0.0,
         retry_interval_s: float = 0.002,
         fetch_gate=None,
+        counters: Optional[dict] = None,
     ):
         """``submit(blocks)``: optional override for the store read (the
         connector's fetch coalescer batches concurrent admissions' reads
@@ -428,6 +438,9 @@ class LayerwisePrefetch:
         blind re-probing, so the reader never burns store round trips on
         keys that cannot exist yet. Composable with ``retry_missing_s``
         (the gate bounds when to START, the retry rides any residual race).
+        ``counters``: the connector's own ledger; every layer that lands adds
+        the store values it fetched to ``hit_values_fetched`` and what every
+        block of that layer would have been to ``hit_values_whole_prefix``.
         Raises :class:`~..tpu.staging.StagingPoolExhausted` when the pool
         cannot hold even a double-buffered pipeline."""
         self.conn = conn
@@ -436,6 +449,11 @@ class LayerwisePrefetch:
         self.n_blocks = n_blocks
         self.num_layers = num_layers
         self.hit_blocks = n_blocks  # overridden by the connector's lookup
+        # Per layer, the first block of the prefix this hit fetches and
+        # installs: 0, or for a sliding layer the first of its last
+        # window / block_tokens blocks (PagedKVCacheSpec.hit_first_block).
+        self._first = [spec.hit_first_block(l, n_blocks) for l in range(num_layers)]
+        self._counters = counters
         # QoS class cell read per submission (not captured once): promote()
         # flips it when the request is ADMITTED — a speculative background
         # prefetch whose request made it into the engine is decode-blocking
@@ -533,10 +551,12 @@ class LayerwisePrefetch:
             return
         n, bn = self.n_blocks, self.spec.block_nbytes
         base = self._region_offset(layer)
+        first = self._first[layer]
+        m = n - first
         blocks = [
-            (self._key_fn(layer, "k", i), base + i * bn) for i in range(n)
+            (self._key_fn(layer, "k", first + i), base + i * bn) for i in range(m)
         ] + [
-            (self._key_fn(layer, "v", i), base + (n + i) * bn) for i in range(n)
+            (self._key_fn(layer, "v", first + i), base + (m + i) * bn) for i in range(m)
         ]
         try:
             await self._submit_with_retry(blocks)
@@ -552,7 +572,10 @@ class LayerwisePrefetch:
             # has no value) — stop refilling regions.
             self._cancel_rest()
             return
-        self.blocks_fetched += 2 * n
+        self.blocks_fetched += 2 * m
+        if self._counters is not None:
+            self._counters["hit_values_fetched"] += 2 * m
+            self._counters["hit_values_whole_prefix"] += 2 * n
         if not self._staged[layer].done():
             self._staged[layer].set_result(layer % self.regions)
         if layer == self.num_layers - 1:
@@ -742,6 +765,7 @@ class LayerwisePrefetch:
         tspan = tracing.active_span()
         fused = (
             self.regions >= self.num_layers
+            and not any(self._first)  # a sliding layer's region is part full
             and self._region_stride == self._region_bytes
             and all(f.done() and not f.cancelled() and f.exception() is None
                     for f in self._staged)
@@ -829,19 +853,22 @@ class LayerwisePrefetch:
                 # slots now) — treat as the miss it semantically is.
                 return out, 0
             off = self._region_offset(layer)
+            first = self._first[layer]
+            m = n - first
             kv_host = (
-                self.pool.buf[off : off + 2 * n * bn]
+                self.pool.buf[off : off + 2 * m * bn]
                 .view(dt)
-                .reshape((2 * n, *self.spec.block_shape))
+                .reshape((2 * m, *self.spec.block_shape))
             )
+            ids = ids_dev[first:] if first else ids_dev
 
-            def dev_one(pair, kv_host=kv_host):
+            def dev_one(pair, kv_host=kv_host, ids=ids, m=m):
                 with tracing.device_call("its.install", tspan):
                     kv_dev = jax.device_put(kv_host)
                     k_cache, v_cache = pair
                     return kv_dev, (
-                        scatter_blocks(k_cache, ids_dev, kv_dev[:n]),
-                        scatter_blocks(v_cache, ids_dev, kv_dev[n:]),
+                        scatter_blocks(k_cache, ids, kv_dev[:m]),
+                        scatter_blocks(v_cache, ids, kv_dev[m:]),
                     )
 
             # Off-loop for the same reason as the fused path: upload +
@@ -850,7 +877,7 @@ class LayerwisePrefetch:
                 None, dev_one, out[layer]
             )
             self._installing.add(layer)
-            self.blocks_installed += 2 * n
+            self.blocks_installed += 2 * m
             if on_layer is not None:
                 on_layer(layer, out[layer])
             self._release_region_async([layer], kv_dev, out[layer], loop)
@@ -932,25 +959,28 @@ class LayerwisePrefetch:
         dt = np.dtype(jax.numpy.dtype(self.spec.dtype))
         loop = asyncio.get_running_loop()
         off = self._region_offset(layer)
+        first = self._first[layer]
+        m = n - first
         kv_host = (
-            self.pool.buf[off : off + 2 * n * bn]
+            self.pool.buf[off : off + 2 * m * bn]
             .view(dt)
-            .reshape((2 * n, *self.spec.block_shape))
+            .reshape((2 * m, *self.spec.block_shape))
         )
+        ids = ids_dev[first:] if first else ids_dev
 
         def dev_one(pair):
             kv_dev = jax.device_put(kv_host)
             k_cache, v_cache = pair
             return kv_dev, (
-                scatter_blocks(k_cache, ids_dev, kv_dev[:n]),
-                scatter_blocks(v_cache, ids_dev, kv_dev[n:]),
+                scatter_blocks(k_cache, ids, kv_dev[:m]),
+                scatter_blocks(v_cache, ids, kv_dev[m:]),
             )
 
         kv_dev, out[layer] = await loop.run_in_executor(
             None, dev_one, out[layer]
         )
         self._installing.add(layer)
-        self.blocks_installed += 2 * n
+        self.blocks_installed += 2 * m
         if on_layer is not None:
             on_layer(layer, out[layer])
         self._release_region_async([layer], kv_dev, out[layer], loop)

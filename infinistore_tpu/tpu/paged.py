@@ -14,7 +14,7 @@ compute) with pure-XLA fallbacks for non-TPU backends and debugging.
 
 import functools
 from dataclasses import dataclass
-from typing import List, Tuple
+from typing import List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -33,6 +33,52 @@ class PagedKVCacheSpec:
     num_kv_heads: int
     head_dim: int
     dtype: jnp.dtype = jnp.bfloat16
+    # Per layer, the sliding window in tokens (a multiple of block_tokens)
+    # or None for a layer that attends its whole context; None for a model
+    # whose layers all do. From it alone the data plane derives what a hit
+    # installs (``hit_first_block``) and the wave its second page list.
+    windows: Optional[Tuple[Optional[int], ...]] = None
+
+    def __post_init__(self):
+        if self.windows is None:
+            return
+        if len(self.windows) != self.num_layers:
+            raise ValueError(
+                f"windows names {len(self.windows)} layers, the cache has {self.num_layers}"
+            )
+        for w in self.windows:
+            if w is not None and (w <= 0 or w % self.block_tokens):
+                raise ValueError(
+                    f"a window of {w} tokens is no whole number of {self.block_tokens}-token blocks"
+                )
+
+    @property
+    def window(self) -> Optional[int]:
+        """The window of the cache's sliding layers (one size a model), or
+        None where every layer attends its whole context."""
+        sizes = {w for w in self.windows or () if w is not None}
+        if len(sizes) > 1:
+            raise ValueError(f"sliding layers of unlike windows: {sorted(sizes)}")
+        return sizes.pop() if sizes else None
+
+    def hit_first_block(self, layer: int, n_blocks: int) -> int:
+        """First block of an ``n_blocks`` prefix that a hit fetches and
+        installs for ``layer``: 0 for a full layer, and for a sliding one the
+        first of its last ``window / block_tokens`` blocks. A question token at
+        prefix position P + i sees back to P + i - window + 1, which lies in
+        block ``n_blocks - window / block_tokens`` or later. Every block of
+        every layer is still SAVED, so that any shorter prefix can resume."""
+        w = self.windows[layer] if self.windows else None
+        return 0 if w is None else max(0, n_blocks - w // self.block_tokens)
+
+    def hit_values(self, n_blocks: int) -> Tuple[int, int]:
+        """(sliding, full): the store values (a K or a V of one block of one
+        layer) a hit of ``n_blocks`` fetches for each kind of layer."""
+        counts = [n_blocks - self.hit_first_block(l, n_blocks) for l in range(self.num_layers)]
+        sliding = sum(
+            2 * c for l, c in enumerate(counts) if self.windows and self.windows[l] is not None
+        )
+        return sliding, 2 * sum(counts) - sliding
 
     @property
     def block_shape(self) -> Tuple[int, int, int]:

@@ -110,7 +110,7 @@ def _key_positions(h: int, kvh: int, keys: int):
 
 
 def _attn_fold(first, kpos0, seq_len, scale, q, k, v, key_pos, m_scr, l_scr,
-               acc_scr):
+               acc_scr, low=None):
     """Fold ONE grid step's keys into the running (max, denominator,
     accumulator) scratch — the single copy of the online-softmax numeric
     contract every decode kernel shares (ragged, its stats twin, and
@@ -120,7 +120,10 @@ def _attn_fold(first, kpos0, seq_len, scale, q, k, v, key_pos, m_scr, l_scr,
     accumulators. ``kpos0``: position WITHIN the row's context of the
     step's first key (the ragged grid is flat, so the grid step says
     nothing). ``seq_len``: traced scalar count of the row's valid tokens.
-    ``scale``: 1 / sqrt(head_dim), the caller's (D may be padded).
+    ``scale``: 1 / sqrt(head_dim), the caller's (D may be padded). ``low``
+    (a sliding layer's rows only; None elsewhere, and then nothing is traced
+    for it): keys at positions under it lie behind the row's window and are
+    masked like keys past the sequence.
 
     q: [H, D]; k/v: [T * KVH, D], the step's pages as they lie in the cache
     (a row a (key, KV head) pair, key-major: a page is [bt * KVH, D] without
@@ -172,6 +175,8 @@ def _attn_fold(first, kpos0, seq_len, scale, q, k, v, key_pos, m_scr, l_scr,
         preferred_element_type=jnp.float32, precision=precision,
     ) * scale  # [H, T * KVH]
     valid = kpos0 + key_pos < seq_len
+    if low is not None:
+        valid = jnp.logical_and(valid, kpos0 + key_pos >= low)
     logits = jax.lax.select(valid, logits, jnp.full_like(logits, _NEG_INF))
 
     m_prev = m_scr[...]  # [H, 128] (all lanes equal)
@@ -201,12 +206,15 @@ def _attn_fold(first, kpos0, seq_len, scale, q, k, v, key_pos, m_scr, l_scr,
     acc_scr[...] = acc_scr[...] * alpha + pv  # [H, D]
 
 
-@jax.jit
-def _decode_attention_stats_xla(q, k_cache, v_cache, block_tables, seq_lens):
+@functools.partial(jax.jit, static_argnames=("window",))
+def _decode_attention_stats_xla(q, k_cache, v_cache, block_tables, seq_lens,
+                                window=None):
     """XLA form of the raw per-row statistics over RECTANGULAR tables
     ([B, M] ``block_tables``, [B] ``seq_lens``): acc [B, H, D] f32
     unnormalized, m / l [B, H, 1] f32. The fallback off the chip and the
-    reference the kernels are tested against."""
+    reference the kernels are tested against. ``window``: a row attends its
+    last ``window`` tokens only, and what lies behind is not even multiplied
+    by zero (a hit leaves those blocks of a sliding layer uninstalled)."""
     _, bt, kvh, d = k_cache.shape
     h = q.shape[1]
     groups = h // kvh
@@ -228,6 +236,9 @@ def _decode_attention_stats_xla(q, k_cache, v_cache, block_tables, seq_lens):
         )
         t = k.shape[0]
         valid = jnp.arange(t, dtype=jnp.int32) < sl
+        if window is not None:
+            valid = valid & (jnp.arange(t, dtype=jnp.int32) >= sl - window)
+            v = jnp.where(valid[:, None, None], v, 0)
         logits = jnp.where(valid[None, :], logits, _NEG_INF)
         m = jnp.max(logits, axis=1, keepdims=True)  # [H, 1]
         p = jnp.exp(logits - m)
@@ -244,12 +255,13 @@ def _decode_attention_stats_xla(q, k_cache, v_cache, block_tables, seq_lens):
     return jax.vmap(one)(q, block_tables, seq_lens)
 
 
-@jax.jit
-def paged_decode_attention_xla_batched(q, k_cache, v_cache, block_tables, seq_lens):
+@functools.partial(jax.jit, static_argnames=("window",))
+def paged_decode_attention_xla_batched(q, k_cache, v_cache, block_tables, seq_lens,
+                                       window=None):
     """Batched reference semantics, derived from the stats body (one copy of
     the numeric contract). Zero-length rows yield zeros."""
     acc, _, l = _decode_attention_stats_xla(
-        q, k_cache, v_cache, block_tables, seq_lens
+        q, k_cache, v_cache, block_tables, seq_lens, window=window
     )
     return (acc / jnp.maximum(l, 1e-30)).astype(q.dtype)
 
@@ -306,9 +318,16 @@ class RaggedWaveMeta:
         return int(self.seq_lens.shape[0])
 
 
+def window_first_page(seq_len: int, block_tokens: int, window) -> int:
+    """Index within a row's table of the first page a row of ``seq_len``
+    tokens attends: 0 without a window, else the page of the oldest token its
+    last query still sees (position ``seq_len - window``)."""
+    return 0 if window is None else max(0, int(seq_len) - window) // block_tokens
+
+
 def build_ragged_wave(
     tables, seq_lens, block_tokens: int, pad_to: int = 0,
-    pad_to_pow2: bool = False,
+    pad_to_pow2: bool = False, window=None,
 ):
     """Assemble :class:`RaggedWaveMeta` from per-row page tables.
 
@@ -318,7 +337,11 @@ def build_ragged_wave(
     page list to this static length (0 = exact). ``pad_to_pow2``: let the
     BUILDER pick the power-of-two bucket from its own page count — the
     form jit-bucketing callers (engine, bench legs) should use, so the
-    per-row page-count rule lives in exactly one place."""
+    per-row page-count rule lives in exactly one place. ``window``: the
+    page list of a wave's SLIDING layers: row r carries only the pages from
+    :func:`window_first_page` on (at most ``window / block_tokens + 1``), and
+    the kernel, told the same ``window``, counts its keys from there; the
+    pages behind cost no entry, no copy and no compute."""
     seq_lens = np.asarray(seq_lens, dtype=np.int32)
     r = len(tables)
     if r == 0 or seq_lens.shape != (r,):
@@ -333,9 +356,10 @@ def build_ragged_wave(
                 f"row {row}: table has {table.shape[0]} pages, needs {nb} "
                 f"for seq_len {int(seq_lens[row])}"
             )
-        chunks.append(table[:nb])
+        first = window_first_page(seq_lens[row], block_tokens, window)
+        chunks.append(table[first:nb])
         starts.append(total)
-        total += nb
+        total += nb - first
     if pad_to and pad_to < total:
         raise ValueError(f"pad_to={pad_to} < {total} real pages")
     if pad_to_pow2 and not pad_to:
@@ -425,7 +449,8 @@ def _ragged_steps(pages, page_starts, seq_lens, bt: int, step_pages: int):
     return n_real, row, page0, ids
 
 
-def _ragged_walk(refs, n_out: int, step_pages: int, bt: int, scale: float):
+def _ragged_walk(refs, n_out: int, step_pages: int, bt: int, scale: float,
+                 windowed: bool = False):
     """Shared body of the ragged kernels: fold this grid step's pages into
     its row's scratch, or do nothing past the wave's real steps. ``refs``
     are the kernel's: five scalar-prefetch refs — [1] real steps of the
@@ -447,12 +472,17 @@ def _ragged_walk(refs, n_out: int, step_pages: int, bt: int, scale: float):
 
     Returns (is the step its row's last, outputs, softmax scratch)."""
     nreal_ref, row_ref, page0_ref, ids_ref, seqlen_ref = refs[:5]
+    # A sliding layer's walk carries a sixth prefetched array: per row, the
+    # first position inside its window, counted like ``seq_lens`` from the
+    # row's first listed page.
+    low_ref, refs = (refs[5], refs[1:]) if windowed else (None, refs)
     q_ref, kpos_ref, k_hbm, v_hbm = refs[5:9]
     outs = refs[9 : 9 + n_out]
     m_scr, l_scr, acc_scr, k_buf, v_buf, sem = refs[9 + n_out :]
     i = pl.program_id(0)
     n_real = nreal_ref[0]
     seq_len = seqlen_ref[row_ref[i]]
+    low = low_ref[row_ref[i]] if windowed else None
     j0 = page0_ref[i]
     rows = k_hbm.shape[1]  # bt * KVH: a page
 
@@ -482,7 +512,7 @@ def _ragged_walk(refs, n_out: int, step_pages: int, bt: int, scale: float):
         slot = pages_of(i, lambda copy: copy.wait())
         _attn_fold(
             j0 == 0, j0 * bt, seq_len, scale, q_ref[0], k_buf[slot],
-            v_buf[slot], kpos_ref[...], m_scr, l_scr, acc_scr,
+            v_buf[slot], kpos_ref[...], m_scr, l_scr, acc_scr, low,
         )
 
     n_pages = jnp.maximum(1, jax.lax.div(seq_len + (bt - 1), bt))
@@ -522,7 +552,7 @@ _VMEM_LIMIT = 64 << 20
 
 
 def _ragged_call(kernel, outs, q, k_cache, v_cache, pages, page_starts,
-                 seq_lens, interpret):
+                 seq_lens, interpret, window=None):
     """One ``pallas_call`` of a ragged kernel over the wave's steps; every
     output is [R, H, width] (``outs``: a (width, dtype) each, width None for
     the head dim) and its block its row's, indexed like the query's. The cache goes in as [blocks, bt * KVH, D] — the same
@@ -530,6 +560,13 @@ def _ragged_call(kernel, outs, q, k_cache, v_cache, pages, page_starts,
     r, h, d = q.shape
     n, bt, kvh, _ = k_cache.shape
     step_pages = max(1, _STEP_TOKENS // bt)
+    lows = ()
+    if window is not None:
+        # The flat list starts each row at its first page inside the window
+        # (build_ragged_wave): lengths and the window's edge count from there.
+        behind = jnp.maximum(seq_lens - window, 0)
+        skipped = behind // bt * bt
+        seq_lens, lows = seq_lens - skipped, (behind - skipped,)
     n_real, step_row, step_page0, step_ids = _ragged_steps(
         pages, page_starts, seq_lens, bt, step_pages
     )
@@ -540,16 +577,17 @@ def _ragged_call(kernel, outs, q, k_cache, v_cache, pages, page_starts,
     if lanes != d:
         pad = lambda x: jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, lanes - d)])
         q, k_cache, v_cache = pad(q), pad(k_cache), pad(v_cache)
-    by_row = lambda i, n, rows, p0, ids, sl: (rows[i], 0, 0)
+    by_row = lambda i, n, rows, *_: (rows[i], 0, 0)
     key_pos = _key_positions(h, kvh, step_pages * bt)
     page_buffer = pltpu.VMEM((2, step_pages * bt * kvh, lanes), k_cache.dtype)
     widths = [lanes if w is None else w for w, _ in outs]
     got = pl.pallas_call(
         functools.partial(
-            kernel, step_pages=step_pages, bt=bt, scale=1.0 / np.sqrt(d)
+            kernel, step_pages=step_pages, bt=bt, scale=1.0 / np.sqrt(d),
+            **({} if window is None else {"windowed": True}),
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5,
+            num_scalar_prefetch=5 + len(lows),
             grid=(step_row.shape[0],),
             in_specs=[
                 pl.BlockSpec((1, h, lanes), by_row),
@@ -576,23 +614,25 @@ def _ragged_call(kernel, outs, q, k_cache, v_cache, pages, page_starts,
         ),
         interpret=interpret,
     )(
-        n_real, step_row, step_page0, step_ids, seq_lens, q, key_pos,
+        n_real, step_row, step_page0, step_ids, seq_lens, *lows, q, key_pos,
         k_cache.reshape(n, bt * kvh, lanes), v_cache.reshape(n, bt * kvh, lanes),
     )
     return [o if w is not None else o[..., :d] for o, (w, _) in zip(got, outs)]
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
+@functools.partial(jax.jit, static_argnames=("interpret", "window"))
 def _paged_decode_attention_pallas_ragged(
-    q, k_cache, v_cache, pages, page_rows, page_starts, seq_lens, *, interpret
+    q, k_cache, v_cache, pages, page_rows, page_starts, seq_lens, *, interpret,
+    window=None,
 ):
     """q: [R, H, D]; flat metadata per RaggedWaveMeta's layout contract
     (``page_rows`` is not read: the steps follow ``page_starts`` and
-    ``seq_lens``, _ragged_steps)."""
+    ``seq_lens``, _ragged_steps). ``window``: the list is a sliding layer's
+    (``build_ragged_wave(window=)``); None lowers the program it always did."""
     del page_rows
     (out,) = _ragged_call(
         _ragged_attn_kernel, [(None, q.dtype)],
-        q, k_cache, v_cache, pages, page_starts, seq_lens, interpret,
+        q, k_cache, v_cache, pages, page_starts, seq_lens, interpret, window,
     )
     return out
 
@@ -684,7 +724,8 @@ def rectangle_as_ragged(block_tables):
 
 
 def paged_decode_attention_rows(
-    q, k_cache, v_cache, row_tables, seq_lens, pages, page_rows, page_starts
+    q, k_cache, v_cache, row_tables, seq_lens, pages, page_rows, page_starts,
+    window=None,
 ):
     """Per-row decode attention with BOTH layouts in hand: THE way a decode
     row attends its pages. q: [R, n_heads, head_dim]; row r attends the
@@ -695,7 +736,10 @@ def paged_decode_attention_rows(
     time. A row with ``seq_lens[r] == 0`` returns zeros. On TPU the flat
     metadata routes to the ragged kernel (a grid step folds up to
     ``_STEP_TOKENS`` keys of one row, no B x max_blocks grid); elsewhere the
-    XLA body gathers ``row_tables``.
+    XLA body gathers ``row_tables``. ``window`` (static): a sliding layer,
+    whose rows attend their last ``window`` tokens; the flat metadata is then
+    the wave's windowed list (``build_ragged_wave(window=)``) while
+    ``row_tables`` stay whole, and nothing behind the window is read.
     Every caller in models/llama.py comes through here (the wave body
     verify_step_ragged, decode_step as its one-row view, the disagg
     decode_wave_layer), so a wave and the same tokens decoded one at a time
@@ -704,10 +748,14 @@ def paged_decode_attention_rows(
     if paged._use_pallas():
         return _paged_decode_attention_pallas_ragged(
             q, k_cache, v_cache, pages, page_rows, page_starts, seq_lens,
-            interpret=False,
+            interpret=False, **({} if window is None else {"window": window}),
+        )
+    if window is None:
+        return paged_decode_attention_xla_batched(
+            q, k_cache, v_cache, row_tables, seq_lens
         )
     return paged_decode_attention_xla_batched(
-        q, k_cache, v_cache, row_tables, seq_lens
+        q, k_cache, v_cache, row_tables, seq_lens, window=window
     )
 
 

@@ -255,10 +255,10 @@ def test_wave_sizes_bucket_to_powers_of_two(conn, params, monkeypatch):
     == compiles): a run whose natural wave sizes wander over 1..5 buckets
     to the power-of-two ladder, and the tail padding rows must not perturb
     any request's output (all verified)."""
-    import infinistore_tpu.engine as engine_mod
+    import infinistore_tpu.models.llama as llama_mod
 
     shapes_seen = set()
-    real = engine_mod.verify_step_ragged
+    real = llama_mod.verify_step_ragged
 
     def recording(params_, tokens, positions, row_of, pages, *a, **kw):
         shapes_seen.add(
@@ -266,7 +266,9 @@ def test_wave_sizes_bucket_to_powers_of_two(conn, params, monkeypatch):
         )
         return real(params_, tokens, positions, row_of, pages, *a, **kw)
 
-    monkeypatch.setattr(engine_mod, "verify_step_ragged", recording)
+    # The harness runs the steps its configuration names (``config.steps``,
+    # models/serving.py), and ``LlamaConfig.steps`` names this module's.
+    monkeypatch.setattr(llama_mod, "verify_step_ragged", recording)
 
     async def drive():
         h = _harness(conn, params, "engine-buckets")

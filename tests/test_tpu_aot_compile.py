@@ -549,3 +549,61 @@ def test_full_width_steps_compile_and_fit_one_v5e(v5e, monkeypatch):
     )
     assert exe.as_text().count("tpu_custom_call") >= cfg.n_layers
     assert fits_one_chip(exe)
+
+
+# The second model file's serving entries (models/afmoe.py): sliding and full
+# layers in one stack, an expert layer in both its shapes. Attention at the
+# configuration's widths (32 q / 4 kv heads x 128, window 2,048, 16-token
+# blocks, bf16), 8 experts top-2 of a small width and a small vocabulary.
+AFMOE_ENTRIES = ["verify_step_ragged", "resume_chunk", "prefill"]
+
+
+@pytest.mark.parametrize("entry", AFMOE_ENTRIES)
+def test_afmoe_entries_compile_and_update_the_cache_in_place(v5e, monkeypatch, entry):
+    """Each entry compiles for the v5e with its Mosaic kernels (the windowed
+    attention kernels, the wave's expert kernel, the grouped matmul) and
+    holds an ``input_output_alias`` for EVERY cache tensor, the aliased bytes
+    the whole cache's: the donation ``llama.py``'s entries carry."""
+    from infinistore_tpu.models import afmoe
+
+    monkeypatch.setattr(paged, "_use_pallas", lambda: True)
+    cfg = afmoe.AfmoeConfig(
+        vocab=1019, dim=2048, n_heads=32, n_kv_heads=4, head_dim=128, ffn_dim=512,
+        moe_ffn_dim=256, n_experts=8, experts_per_token=2, sliding_window=2048,
+        block_tokens=16, dtype=jnp.bfloat16,
+    )
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=v5e)
+    i32 = lambda *shape: s(shape, jnp.int32)
+    shapes = jax.eval_shape(lambda k: afmoe.init_params(cfg, k), jax.random.key(0))
+    params = jax.tree.map(lambda a: s(a.shape, a.dtype), shapes)
+    cache = s(cfg.kv_spec(640).cache_shape, cfg.dtype)
+    caches = [(cache, cache)] * cfg.n_layers
+    if entry == "verify_step_ragged":
+        rows, pages, table = 4, 1024, 320
+        args = (
+            params, i32(rows), i32(rows), i32(rows), i32(pages), i32(pages + 1), i32(rows),
+            caches, i32(rows, table),
+        )
+        windowed = (i32(rows * 129), i32(rows * 129 + 1), i32(rows))
+        static = {"config": cfg, "max_blocks": table, "window_pages": windowed}
+    elif entry == "resume_chunk":
+        args, static = (params, i32(128), i32(), caches, i32(320)), {"config": cfg}
+    else:
+        args, static = (params, i32(4224), caches, i32(264)), {"config": cfg}
+    lowered = getattr(afmoe, entry).trace(*args, **static).lower(lowering_platforms=("tpu",))
+    kernels = set(re.findall(r'kernel_name = "(\w+)"', lowered.as_text()))
+    exe = lowered.compile()
+    text = exe.as_text()
+    assert "tpu_custom_call" in text
+    tensors = 2 * cfg.n_layers
+    header = text.split("\n", 1)[0]
+    assert len(re.findall(r"\(\d+, \{\}, (?:may|must)-alias\)", header)) == tensors, header
+    assert exe.memory_analysis().alias_size_in_bytes == tensors * int(np.prod(cache.shape)) * 2
+    want = {
+        "verify_step_ragged": {"_ragged_attn_kernel", "_moe_wave_kernel"},
+        "resume_chunk": {"_chunk_attn_kernel"},
+        "prefill": {"_flash_kernel"},
+    }[entry]
+    assert want <= kernels, kernels
+    if entry != "verify_step_ragged":
+        assert kernels - want, kernels  # the grouped matmul's
