@@ -601,8 +601,7 @@ def check_steps_hold_kernels(cfg, params, sizes: Sizes) -> dict:
     ``prefill``, the ragged decode kernel in the wave step, the chunk
     kernel in the resume of a partial hit. So does the disagg decode
     layer, which rides the wave step's kernel as a rectangle."""
-    from infinistore_tpu.models import llama
-    from infinistore_tpu.tpu.paged_attention import build_ragged_wave
+    from infinistore_tpu.models import llama, serving
 
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
     spec = cfg.kv_spec(sizes.num_blocks)
@@ -613,12 +612,11 @@ def check_steps_hold_kernels(cfg, params, sizes: Sizes) -> dict:
         llama.prefill, params, i32(s), caches, i32(s // bt), config=cfg
     )
     assert in_prefill == {"_flash_kernel", "_scatter_kernel"}, in_prefill
-    meta = build_ragged_wave([np.zeros(mrb, np.int32)], [1], bt)
+    # The wave as the harness launches it: one row over one page, packed.
+    layout = serving.WaveLayout(rows=1, tables=1, pages=1)
     in_wave = _mosaic_kernels(
-        llama.verify_step_ragged, params, i32(1), i32(1), i32(1),
-        jnp.asarray(meta.pages), jnp.asarray(meta.page_rows),
-        jnp.asarray(meta.page_starts), caches, i32(1, mrb),
-        config=cfg, max_blocks=mrb,
+        serving.verify_step_ragged, params, i32(layout.size(mrb)), caches,
+        config=cfg, max_blocks=mrb, layout=layout,
     )
     assert in_wave == {"_ragged_attn_kernel"}, in_wave
     in_resume = _mosaic_kernels(

@@ -57,6 +57,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import tracing
+from .models.serving import WaveLayout, pack_wave, verify_step_ragged
 from .tpu.paged import gather_blocks
 from .tpu.paged_attention import build_ragged_wave
 from .tpu.staging import StagingPoolExhausted
@@ -85,6 +86,10 @@ class WaveCounters:
     - ``engine_wave_held_flushes``: whole flushes held back by the EWMA
       wave-size target (a hot engine refusing a degenerate 1-row wave).
     - ``engine_wave_policy_waves``: waves launched with the policy on.
+    - ``engine_wave_launches``: waves launched, policy on or off.
+    - ``engine_wave_host_transfers``: host arrays uploaded for those waves
+      plus blocking device-to-host reads made for their tokens: 2 a wave
+      (the packed operand up, the sampled ids down), whatever its rows.
     - ``engine_wave_defer_age_us_p99``: p99 deferral age at launch.
     - ``engine_wave_bucket_occupancy``: real rows / launched rows over
       policy waves (1 - pad fraction — what the deferral rule raises).
@@ -107,6 +112,10 @@ class WaveCounters:
             "engine_wave_held_flushes": 0,
             # Waves launched with the skew policy active.
             "engine_wave_policy_waves": 0,
+            # Waves launched at all, and their traffic with the device:
+            # host arrays uploaded plus blocking reads of their tokens.
+            "engine_wave_launches": 0,
+            "engine_wave_host_transfers": 0,
         }
         self._ages_us: list = []
         self._real_rows = 0
@@ -133,6 +142,8 @@ class WaveCounters:
             "engine_wave_aging_escapes": c["engine_wave_aging_escapes"],
             "engine_wave_held_flushes": c["engine_wave_held_flushes"],
             "engine_wave_policy_waves": c["engine_wave_policy_waves"],
+            "engine_wave_launches": c["engine_wave_launches"],
+            "engine_wave_host_transfers": c["engine_wave_host_transfers"],
             # p99 deferral age at launch: how long the policy actually
             # parks a request (bounded by the starvation rule).
             "engine_wave_defer_age_us_p99": round(p99, 1),
@@ -281,6 +292,18 @@ class DeviceGate:
                     self._cond.notify_all()
 
 
+class _WaveOut:
+    """What one launched wave returned beside its logits, kept while its
+    requests may still ask (``WaveDecoder.token_ids``, ``row_aux``)."""
+
+    __slots__ = ("ids", "aux_rows", "host_ids")
+
+    def __init__(self, ids, aux_rows):
+        self.ids = ids  # [T] int32 on the device, its host copy under way
+        self.aux_rows = aux_rows  # the model's per-row aux [T, ...], or None
+        self.host_ids = None  # np [T], once the first request has asked
+
+
 class WaveDecoder:
     """Coalesce decode AND verify steps from concurrent requests into
     lockstep waves.
@@ -292,7 +315,7 @@ class WaveDecoder:
     speculative-verification chunk — the committed token plus drafted
     continuations); the first arrival schedules a flush, the flush yields
     to the event loop so every ready request joins, then ONE
-    ``verify_step_ragged`` call (under the device gate's exclusive phase —
+    ``verify_step_ragged`` launch (under the device gate's exclusive phase —
     it mutates the shared cache) advances the whole MIXED wave: decoding
     requests ride as 1-token chunks beside verifying requests' K-token
     chunks, so speculation never leaves the lockstep batch.
@@ -311,6 +334,22 @@ class WaveDecoder:
     attend). Attention page metadata (tpu/paged_attention.py
     ``build_ragged_wave``) pads to a power-of-two page bucket the same
     way; the ragged kernel neither fetches nor computes the padded pages.
+
+    **One upload, one launch, one read-back a wave.** The flush writes the
+    wave's integer metadata — flat tokens, positions, owning rows, the page
+    triple, the stacked tables and, where the spec names a window, the
+    second page triple — into ONE ``int32`` host buffer whose layout is a
+    function of the bucket ``(T, B, P[, Pw])`` alone (models/serving.py
+    ``pack_wave``) and hands it to one jitted call (``launch`` ->
+    ``serving.verify_step_ragged``, which slices it at static offsets and
+    runs the model's wave body). The program returns the greedy ids
+    ``argmax(logits, -1)`` beside the logits; their copy to the host starts
+    with the launch and stays with the wave. ``step_chunk`` still resolves to
+    the request's logits rows ON THE DEVICE; the request then asks
+    ``token_ids(rows)``: a wave's first asker blocks once for the whole
+    wave's ids, every other request of that wave reads the host copy.
+    ``waves + blocking_reads`` is ``wave_host_transfers`` in ``metrics()``:
+    2 a wave, whatever its rows.
 
     ``bucket_sizes`` records the distinct (B, T, P) buckets — table rows,
     flat token rows, flat attention pages — which ARE the jit cache
@@ -400,12 +439,18 @@ class WaveDecoder:
         # layers' second page list spared (0 where no layer has a window).
         self.wave_layer_pages = 0
         self.wave_window_pages_skipped = 0
-        # What a model's wave step hands back beside its logits
-        # (models/serving.py ``aux``), neither of which this class reads:
-        # per-row arrays, kept beside the logits rows of the last few waves
-        # (``row_aux``), and named counters, added up on the device (a wave's
-        # are results like its logits: nothing here waits for them).
-        self._row_aux = collections.OrderedDict()  # id(rows) -> (rows, aux rows)
+        # What the wave program hands back beside its logits
+        # (models/serving.py): the sampled ids and the model's per-row ``aux``
+        # arrays, kept a wave at a time beside the logits rows handed out
+        # (``token_ids``, ``row_aux``), and the model's named counters, added
+        # up on the device (a wave's are results like its logits: nothing
+        # here waits for them, and this class reads neither).
+        self._handed = {}  # id(rows) -> (rows, the wave's _WaveOut, offset, length)
+        self._kept_waves = collections.deque()  # the keys of each kept wave
+        # Blocking device-to-host reads made for the waves' tokens: one a
+        # wave whose tokens anyone asked for. With the one host array a
+        # launched wave uploads (``waves``) they are ``wave_host_transfers``.
+        self.blocking_reads = 0
         # name -> device scalars not yet folded; the names a model's steps
         # count by (``config.step_counters``) read 0 before the first wave.
         self._step_counters = {
@@ -486,37 +531,58 @@ class WaveDecoder:
             task.add_done_callback(self._flush_tasks.discard)
         return await fut
 
-    # -- what the model's step returned beside its logits ---------------------
+    # -- what the wave program returned beside its logits ---------------------
 
-    # Logits rows whose aux slice is kept: a request reads its own right after
-    # ``step_chunk`` returns, and at most a wave or two can resolve between.
-    ROW_AUX_KEPT = 64
+    # Waves whose ids and aux rows are kept: a request reads its own right
+    # after ``step_chunk`` returns, and at most a wave or two can resolve
+    # between.
+    WAVES_KEPT = 8
     # Waves whose counters are held apart before one small sum folds them.
     COUNTERS_FOLDED_EVERY = 64
 
-    def _keep_aux(self, aux, handed: List[tuple]):
+    def _keep(self, out: "_WaveOut", aux: dict, handed: List[tuple]):
         """``handed``: (logits rows as resolved, offset, length) per entry."""
         for name, value in aux.get("counters", {}).items():
             held = self._step_counters.setdefault(name, [])
             held.append(value)
             if len(held) >= self.COUNTERS_FOLDED_EVERY:
                 held[:] = [jnp.sum(jnp.stack(held))]
-        per_row = aux.get("rows")
-        if per_row is None:
-            return
         for rows, off, n in handed:
-            self._row_aux[id(rows)] = (rows, per_row[off : off + n])
-        while len(self._row_aux) > self.ROW_AUX_KEPT:
-            self._row_aux.popitem(last=False)
+            self._handed[id(rows)] = (rows, out, off, n)
+        self._kept_waves.append([id(rows) for rows, _, _ in handed])
+        while len(self._kept_waves) > self.WAVES_KEPT:
+            for key in self._kept_waves.popleft():
+                self._handed.pop(key, None)
+
+    def _handed_with(self, rows):
+        kept, out, off, n = self._handed[id(rows)]
+        if kept is not rows:
+            raise KeyError("these are not rows this decoder handed out")
+        return out, off, n
+
+    def token_ids(self, rows) -> np.ndarray:
+        """The greedy ids ``[len(rows)] int32`` of the logits ``rows`` that
+        ``step_chunk`` just handed a request, on the host: the wave program's
+        own ``argmax(logits, -1)``, copied to the host since the launch. The
+        first request of a wave to ask blocks once, for the whole wave's ids;
+        every other reads that copy. ``KeyError`` for rows this decoder did
+        not hand out lately."""
+        out, off, n = self._handed_with(rows)
+        if out.host_ids is None:
+            out.host_ids = np.asarray(out.ids)
+            self.blocking_reads += 1
+            _WAVE_COUNTERS.bump("engine_wave_host_transfers")
+        return out.host_ids[off : off + n]
 
     def row_aux(self, rows):
         """The per-row ``aux`` slice the wave returned with the logits
         ``rows`` that ``step_chunk`` just handed a request; ``KeyError`` for
-        rows this decoder did not hand out lately."""
-        kept, per_row = self._row_aux[id(rows)]
-        if kept is not rows:
-            raise KeyError("these are not rows this decoder handed out")
-        return per_row
+        rows this decoder did not hand out lately, or of a model whose wave
+        returns no per-row ``aux``."""
+        out, off, n = self._handed_with(rows)
+        if out.aux_rows is None:
+            raise KeyError("this model's wave returns no per-row aux")
+        return out.aux_rows[off : off + n]
 
     def step_counters(self) -> dict:
         """The model step's named counters, summed over every wave so far
@@ -639,6 +705,48 @@ class WaveDecoder:
             self._flush_tasks.add(task)
             task.add_done_callback(self._flush_tasks.discard)
 
+    def window_meta(self, row_tables, row_lens, meta):
+        """The wave's SECOND page list, which its sliding layers walk: per row
+        only the pages from its window's first on, padded to what the bucket's
+        rows can hold at most, so the bucket stays one program. None where
+        the cache's spec names no window."""
+        if self._window is None:
+            return None
+        bt = self.h.config.block_tokens
+        return build_ragged_wave(
+            row_tables, row_lens, bt, window=self._window,
+            pad_to=min(meta.num_pages, len(row_lens) * (self._window // bt + 1)),
+        )
+
+    def launch(self, tokens, positions, row_of, meta, tables, wmeta=None):
+        """ONE wave on the device (cache-mutating: caller holds the exclusive
+        gate): the wave's integer metadata goes up as one packed ``int32``
+        operand (models/serving.py ``pack_wave``: ``tokens``, ``positions``,
+        ``row_of`` ``[T]``, ``meta``'s page triple, the ``[B, max_blocks]``
+        ``tables`` and, where the spec names a window, ``wmeta``'s triple),
+        one jitted call runs the model's wave body on it, and the sampled
+        ids start their way back to the host at once. Returns ``(logits [T,
+        vocab] on the device, the wave's _WaveOut, aux)``; ``flush`` and
+        ``prewarm_wave_buckets`` both launch through here, so what is warmed
+        is what serves."""
+        pieces = [
+            tokens, positions, row_of, meta.pages, meta.page_rows,
+            meta.page_starts, np.stack(tables),
+        ]
+        if wmeta is not None:
+            pieces += [wmeta.pages, wmeta.page_rows, wmeta.page_starts]
+        layout = WaveLayout(
+            len(tokens), len(tables), meta.num_pages,
+            None if wmeta is None else wmeta.num_pages,
+        )
+        mrb = self.h.max_req_blocks
+        logits, self.h.caches, ids, aux = verify_step_ragged(
+            self.h.params, pack_wave(layout, mrb, pieces), self.h.caches,
+            config=self.h.config, max_blocks=mrb, layout=layout,
+        )
+        ids.copy_to_host_async()
+        return logits, _WaveOut(ids, aux.get("rows")), aux
+
     async def _flush(self):
         batch: List[tuple] = []
         wspan = None
@@ -723,21 +831,9 @@ class WaveDecoder:
             self.launched_rows += t_bucket
             self.wave_pages += meta.num_pages
             self.wave_pad_pages += meta.pad_pages
-            # A sliding layer walks the wave's SECOND list: per row only the
-            # pages from its window's first on, padded to what the bucket's
-            # rows can hold at most, so the bucket stays one program.
-            window, real_pages = self._window, meta.num_pages - meta.pad_pages
-            step_kw = {}
-            if window is not None:
-                wmeta = build_ragged_wave(
-                    row_tables, row_lens, bt, window=window,
-                    pad_to=min(meta.num_pages, t_bucket * (window // bt + 1)),
-                )
-                step_kw["window_pages"] = (
-                    jnp.asarray(wmeta.pages),
-                    jnp.asarray(wmeta.page_rows),
-                    jnp.asarray(wmeta.page_starts),
-                )
+            real_pages = meta.num_pages - meta.pad_pages
+            wmeta = self.window_meta(row_tables, row_lens, meta)
+            if wmeta is not None:
                 self.wave_window_pages_skipped += self._sliding * (
                     real_pages - (wmeta.num_pages - wmeta.pad_pages)
                 )
@@ -760,22 +856,13 @@ class WaveDecoder:
                     if wspan is not None:
                         wspan.stage("gate")
                     with tracing.device_call("its.wave_dispatch", wspan):
-                        logits, self.h.caches, *aux = self.h.config.steps.wave(
-                            self.h.params,
-                            jnp.asarray(flat_toks, jnp.int32),
-                            jnp.asarray(flat_pos, jnp.int32),
-                            jnp.asarray(row_of, jnp.int32),
-                            jnp.asarray(meta.pages),
-                            jnp.asarray(meta.page_rows),
-                            jnp.asarray(meta.page_starts),
-                            self.h.caches,
-                            jnp.asarray(np.stack(tables)),
-                            self.h.config,
-                            self.h.max_req_blocks,
-                            **step_kw,
+                        logits, out, aux = self.launch(
+                            flat_toks, flat_pos, row_of, meta, tables, wmeta
                         )
                     if wspan is not None:
                         wspan.stage("dispatched")
+            _WAVE_COUNTERS.bump("engine_wave_launches")
+            _WAVE_COUNTERS.bump("engine_wave_host_transfers")
             self.waves += 1
             self.max_wave = max(self.max_wave, len(batch))
             off, handed = 0, []
@@ -785,8 +872,7 @@ class WaveDecoder:
                     fut.set_result(rows)
                     handed.append((rows, off, len(toks)))
                 off += len(toks)
-            if aux:
-                self._keep_aux(aux[0], handed)
+            self._keep(out, aux, handed)
             if wspan is not None:
                 wspan.stage("resolved")
                 wspan.finish()
@@ -1154,30 +1240,14 @@ class ContinuousBatchingHarness:
         ladder = []
         t = 1
         while t <= max_rows:
+            tables, ones, zeros = [np.zeros(mrb, np.int32)] * t, [1] * t, [0] * t
             meta = build_ragged_wave(
-                [np.zeros(mrb, dtype=np.int32)] * t,
-                [1] * t,
-                self.config.block_tokens,
-                pad_to=t * mrb,
+                tables, ones, self.config.block_tokens, pad_to=t * mrb
             )
-            zeros_t = jnp.zeros((t,), jnp.int32)
-            triple = tuple(
-                jnp.asarray(a) for a in (meta.pages, meta.page_rows, meta.page_starts)
-            )
-            # One token a row: its only page lies inside any window.
-            step_kw = {} if self.spec.window is None else {"window_pages": triple}
             async with self.gate.exclusive():
-                _, self.caches, *_ = self.config.steps.wave(
-                    self.params,
-                    zeros_t,
-                    zeros_t,
-                    zeros_t,
-                    *triple,
-                    self.caches,
-                    jnp.asarray(np.zeros((t, mrb), np.int32)),
-                    self.config,
-                    mrb,
-                    **step_kw,
+                self.wave.launch(
+                    zeros, zeros, zeros, meta, tables,
+                    self.wave.window_meta(tables, ones, meta),
                 )
             bucket = (t, t, t * mrb)
             self.wave.prewarmed.add(bucket)
@@ -1377,9 +1447,11 @@ class ContinuousBatchingHarness:
                     gspan.stage("wave_result")
                 if first_token_t is None:
                     first_token_t = time.perf_counter()
-                # ONE device->host transfer per round (the [K] argmaxes).
+                # The wave's own argmaxes: its first request to ask blocks
+                # for the wave's ONE device->host read, the others read the
+                # host copy (``WaveDecoder.token_ids``).
                 with tracing.device_call("its.readback", gspan):
-                    preds = np.asarray(jnp.argmax(rows, axis=-1))
+                    preds = self.wave.token_ids(rows)
                 now = time.perf_counter()
                 if gspan is not None:
                     gspan.stage("token")
@@ -1849,8 +1921,11 @@ class ContinuousBatchingHarness:
         pages launched and those of them that were the page bucket's
         padding; ``wave_layer_pages`` / ``wave_window_pages_skipped``, the
         (layer, page) pairs the waves' real rows attended and how many more
-        a stack of full layers would have; and whatever the model's wave
-        step counts itself, by its own names); the skew-aware flush policy's ledger
+        a stack of full layers would have; ``wave_host_transfers``, the host
+        arrays uploaded for the launched waves plus the blocking
+        device-to-host reads made for their tokens, 2 a wave; and whatever
+        the model's wave step counts itself, by its own names); the
+        skew-aware flush policy's ledger
         (docs/serving_load.md: ``wave_deferrals``,
         ``wave_aging_escapes`` — deferred entries force-launched at the
         starvation bound, ``wave_held_flushes`` — whole flushes held by
@@ -1972,6 +2047,11 @@ class ContinuousBatchingHarness:
             "wave_pad_pages": self.wave.wave_pad_pages,
             "wave_layer_pages": self.wave.wave_layer_pages,
             "wave_window_pages_skipped": self.wave.wave_window_pages_skipped,
+            # The waves' traffic with the device: host arrays uploaded for
+            # them plus blocking device-to-host reads of their tokens. Over
+            # ``decode_waves`` it reads 2 (a little under where a request's
+            # closing step rides a wave alone and reads nothing back).
+            "wave_host_transfers": self.wave.waves + self.wave.blocking_reads,
             # What the model's wave step counted itself (models/serving.py
             # ``aux``): an expert model's ``moe_pairs`` and
             # ``moe_distinct_experts``; nothing for a model that counts nothing.

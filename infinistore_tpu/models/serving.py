@@ -13,23 +13,142 @@ the harness runs what it finds there:
 ``wave(params, tokens, positions, row_of, pages, page_rows, page_starts,
 caches, block_tables, config, max_blocks[, window_pages=]) -> (logits,
 caches[, aux])``
-    a decode wave: flat rows of many requests, each over its own pages.
-    Where the cache's spec names a sliding window (``PagedKVCacheSpec.window``)
-    the decoder hands a second ``(pages, page_rows, page_starts)`` triple, the
-    wave's windowed page list, as ``window_pages``. A step may return a third
-    value, ``aux``: ``{"rows": array [T, ...], "counters": {name: scalar}}``,
-    both still on the device. The decoder keeps each request's slice of
-    ``rows`` beside the logits rows it hands back (``WaveDecoder.row_aux``)
-    and adds ``counters`` up by name into ``harness.metrics()``; it reads
+    a decode wave's BODY: flat rows of many requests, each over its own
+    pages. Where the cache's spec names a sliding window
+    (``PagedKVCacheSpec.window``) it takes a second ``(pages, page_rows,
+    page_starts)`` triple, the wave's windowed page list, as
+    ``window_pages``. A body may return a third value, ``aux``: ``{"rows":
+    array [T, ...], "counters": {name: scalar}}``, both still on the device.
+
+The engine does not call ``wave`` itself. Every decode wave it launches is ONE
+program, :func:`verify_step_ragged` below, whose traffic with the host is one
+array each way:
+
+``verify_step_ragged(params, packed, caches, config, max_blocks, layout) ->
+(logits, caches, ids, aux)``
+    ``packed`` is the wave's whole integer metadata as one ``int32`` vector
+    (:func:`pack_wave`): the body's seven index arrays, ten with a window, at
+    offsets that are a function of the bucket ``layout`` (:class:`WaveLayout`)
+    and ``max_blocks`` alone. The program slices it at those static offsets,
+    runs ``config.steps.wave`` on the pieces and returns, beside the body's
+    ``logits`` ``[T, vocab]`` and ``caches``, the greedy token ids
+    ``argmax(logits, -1)`` as ``[T] int32`` and the body's ``aux`` (``{}``
+    where it returns none). The decoder hands each request its logits rows on
+    the device and keeps the wave's ids and ``aux["rows"]`` beside them
+    (``WaveDecoder.token_ids`` reads the ids back once a wave,
+    ``WaveDecoder.row_aux`` gives a request its slice); it adds
+    ``aux["counters"]`` up by name into ``harness.metrics()`` and reads
     neither.
 
 Every step DONATES ``caches``: the caller uses the returned ones.
 """
 
-from typing import Callable, NamedTuple
+import functools
+import math
+from typing import Callable, Dict, List, NamedTuple, Optional, Sequence, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
 
 
 class ServingSteps(NamedTuple):
     prefill: Callable
     resume: Callable
     wave: Callable
+
+
+class WaveLayout(NamedTuple):
+    """A wave's bucket: the jit key of its program, and all that the packed
+    operand's layout depends on beside ``max_blocks``."""
+
+    rows: int  # T: flat token rows
+    tables: int  # B: block-table rows
+    pages: int  # P: flat attention pages
+    window_pages: Optional[int] = None  # Pw: the sliding layers' pages, if any
+
+    def fields(self, max_blocks: int) -> List[Tuple[str, Tuple[int, ...]]]:
+        """(name, shape) of every piece, in the order they lie in the operand:
+        the body's index arguments in its own order, then the window triple."""
+        t, b, p, pw = self
+        out = [
+            ("tokens", (t,)),
+            ("positions", (t,)),
+            ("row_of", (t,)),
+            ("pages", (p,)),
+            ("page_rows", (p + 1,)),
+            ("page_starts", (t,)),
+            ("block_tables", (b, max_blocks)),
+        ]
+        if pw is not None:
+            out += [
+                ("window_pages", (pw,)),
+                ("window_page_rows", (pw + 1,)),
+                ("window_page_starts", (t,)),
+            ]
+        return out
+
+    def size(self, max_blocks: int) -> int:
+        return sum(math.prod(shape) for _, shape in self.fields(max_blocks))
+
+
+def pack_wave(layout: WaveLayout, max_blocks: int, pieces: Sequence) -> np.ndarray:
+    """The wave's metadata as ONE host ``int32`` vector: ``pieces`` are the
+    arrays (or lists) of ``layout.fields(max_blocks)``, in that order. A fresh
+    buffer a call: the runtime may read it after the launch returns."""
+    fields = layout.fields(max_blocks)
+    if len(pieces) != len(fields):
+        raise ValueError(f"{layout} takes {len(fields)} pieces, got {len(pieces)}")
+    flat = []
+    for (name, shape), piece in zip(fields, pieces):
+        piece = np.asarray(piece, np.int32)
+        if piece.shape != shape:
+            raise ValueError(f"{name} must be {shape} in {layout}, got {piece.shape}")
+        flat.append(piece.reshape(-1))
+    return np.concatenate(flat)
+
+
+def unpack_wave(packed: jax.Array, layout: WaveLayout, max_blocks: int) -> Dict[str, jax.Array]:
+    """:func:`pack_wave`'s pieces by name, cut out of the operand at static
+    offsets inside the program."""
+    if packed.shape != (layout.size(max_blocks),):
+        raise ValueError(
+            f"the packed operand of {layout} at max_blocks={max_blocks} is "
+            f"[{layout.size(max_blocks)}], got {packed.shape}"
+        )
+    out, at = {}, 0
+    for name, shape in layout.fields(max_blocks):
+        n = math.prod(shape)
+        out[name] = jax.lax.reshape(jax.lax.slice(packed, (at,), (at + n,)), shape)
+        at += n
+    return out
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("config", "max_blocks", "layout"),
+    donate_argnames=("caches",),
+)
+def verify_step_ragged(params, packed, caches, config, max_blocks: int, layout: WaveLayout):
+    """THE decode wave as the engine launches it (module docstring): the
+    model's own wave body, ``config.steps.wave``, between one operand in and
+    the sampled ids out. The trace knows every model's wave program by this
+    function's name. The body is traced as the plain function behind its own
+    ``jax.jit`` (``__wrapped__``), so this program is one jit deep, as the
+    body alone is: a jit nested under this one cost every wave bucket a
+    quarter of a second of set-up on the chip's host (PERF.md, PR 36).
+    ``caches`` is donated, declared here as the body declares it."""
+    f = unpack_wave(packed, layout, max_blocks)
+    kw = {}
+    if layout.window_pages is not None:
+        kw["window_pages"] = (
+            f["window_pages"], f["window_page_rows"], f["window_page_starts"]
+        )
+    body = getattr(config.steps.wave, "__wrapped__", config.steps.wave)
+    logits, caches, *aux = body(
+        params, f["tokens"], f["positions"], f["row_of"], f["pages"],
+        f["page_rows"], f["page_starts"], caches, f["block_tables"], config,
+        max_blocks, **kw,
+    )
+    ids = jax.lax.argmax(logits, 1, jnp.int32)  # what jnp.argmax(logits, -1) computes
+    return logits, caches, ids, aux[0] if aux else {}

@@ -584,6 +584,11 @@ def _engine_wave_prometheus_lines(ws: dict) -> list:
         f"infinistore_engine_wave_held_flushes {ws['engine_wave_held_flushes']}",
         "# TYPE infinistore_engine_wave_policy_waves counter",
         f"infinistore_engine_wave_policy_waves {ws['engine_wave_policy_waves']}",
+        "# TYPE infinistore_engine_wave_launches counter",
+        f"infinistore_engine_wave_launches {ws['engine_wave_launches']}",
+        "# TYPE infinistore_engine_wave_host_transfers counter",
+        "infinistore_engine_wave_host_transfers "
+        f"{ws['engine_wave_host_transfers']}",
         "# TYPE infinistore_engine_wave_defer_age_us_p99 gauge",
         "infinistore_engine_wave_defer_age_us_p99 "
         f"{ws['engine_wave_defer_age_us_p99']}",

@@ -255,20 +255,23 @@ def test_wave_sizes_bucket_to_powers_of_two(conn, params, monkeypatch):
     == compiles): a run whose natural wave sizes wander over 1..5 buckets
     to the power-of-two ladder, and the tail padding rows must not perturb
     any request's output (all verified)."""
-    import infinistore_tpu.models.llama as llama_mod
+    import infinistore_tpu.engine as engine_mod
 
     shapes_seen = set()
-    real = llama_mod.verify_step_ragged
+    real = engine_mod.verify_step_ragged
 
-    def recording(params_, tokens, positions, row_of, pages, *a, **kw):
-        shapes_seen.add(
-            (int(a[3].shape[0]), int(tokens.shape[0]), int(pages.shape[0]))
+    def recording(params_, packed, caches, *, config, max_blocks, layout):
+        assert packed.shape == (layout.size(max_blocks),)
+        assert layout.window_pages is None  # no window in this spec
+        shapes_seen.add((layout.tables, layout.rows, layout.pages))
+        return real(
+            params_, packed, caches, config=config, max_blocks=max_blocks,
+            layout=layout,
         )
-        return real(params_, tokens, positions, row_of, pages, *a, **kw)
 
-    # The harness runs the steps its configuration names (``config.steps``,
-    # models/serving.py), and ``LlamaConfig.steps`` names this module's.
-    monkeypatch.setattr(llama_mod, "verify_step_ragged", recording)
+    # Every wave the decoder launches goes through the packed entry
+    # (models/serving.py ``verify_step_ragged``), keyed by its layout.
+    monkeypatch.setattr(engine_mod, "verify_step_ragged", recording)
 
     async def drive():
         h = _harness(conn, params, "engine-buckets")
@@ -705,7 +708,7 @@ def test_a_harness_wave_donates_the_harness_cache(params):
 def test_skew_policy_off_is_behavior_identical(params):
     """wave_skew_policy=False (the default) must reproduce the blind
     flush exactly: same coalescing, same pad accounting, same bytes, no
-    policy counters, process ledger untouched."""
+    policy counters, the process ledger's policy keys untouched."""
     from infinistore_tpu.engine import (
         WaveDecoder, reset_wave_counters, wave_counters,
     )
@@ -741,6 +744,10 @@ def test_skew_policy_off_is_behavior_identical(params):
         assert w.deferrals == 0 and w.aging_escapes == 0
         assert w.held_flushes == 0 and w.defer_ages_us == []
     st = wave_counters().status()
+    # What the ledger counts of EVERY launched wave (since PR 36): the two
+    # runs' one wave each, and its upload (nobody asked for these waves' ids).
+    assert st.pop("engine_wave_launches") == 2
+    assert st.pop("engine_wave_host_transfers") == 2
     assert all(v == 0 for v in st.values()), st
 
 

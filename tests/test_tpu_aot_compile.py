@@ -364,39 +364,46 @@ def test_ragged_kernel_precision_follows_the_operands_dtype(dtype):
             )
 
 
-def test_wave_program_shares_one_layer_and_one_kernel_function(v5e, monkeypatch):
-    """One chat bucket of ``verify_step_ragged`` (8 rows, 1,024 flat pages,
+@pytest.mark.parametrize("entry", ["body", "packed"])
+def test_wave_program_shares_one_layer_and_one_kernel_function(v5e, monkeypatch, entry):
+    """One chat bucket of ``verify_step_ragged``, the model's wave body and
+    the packed entry the decoder launches it through (8 rows, 1,024 flat pages,
     Mistral's attention widths, 16 layers; the FFN and the vocabulary, which
     the kernel never sees, kept small) lowered for a v5e: the 16 layers call
     ONE lowered layer function, which holds ONE function around the Mosaic
     kernel — the jits are what makes them share — under the name the
     benchmark's ``decode_attn_roofline`` finds the device op by. What the
     program holds once, a wave bucket's set-up pays once."""
-    from infinistore_tpu.models import llama
+    from infinistore_tpu.models import llama, serving
 
     monkeypatch.setattr(paged, "_use_pallas", lambda: True)
+    # The packed entry is a module-level jit: a vocabulary of its own, so its
+    # trace is taken here, under the TPU dispatch, and serves no other test.
     cfg = llama.LlamaConfig(
-        vocab=1024, dim=4096, n_layers=16, n_heads=32, n_kv_heads=8,
-        ffn_dim=1024, block_tokens=16, dtype=jnp.bfloat16,
+        vocab=1024 if entry == "body" else 1023, dim=4096, n_layers=16, n_heads=32,
+        n_kv_heads=8, ffn_dim=1024, block_tokens=16, dtype=jnp.bfloat16,
     )
     s = functools.partial(jax.ShapeDtypeStruct, sharding=v5e)
     params = _param_shapes(s, cfg)
     cache = s(cfg.kv_spec(640).cache_shape, cfg.dtype)
+    caches = [(cache, cache)] * cfg.n_layers
     i32 = lambda *shape: s(shape, jnp.int32)
     rows, pages, table = 8, 1024, 80
-    text = (
-        jax.jit(
+    if entry == "body":
+        traced = jax.jit(
             llama.verify_step_ragged.__wrapped__,
             static_argnames=("config", "max_blocks"),
-        )
-        .trace(
+        ).trace(
             params, i32(rows), i32(rows), i32(rows), i32(pages), i32(pages + 1),
-            i32(rows), [(cache, cache)] * cfg.n_layers, i32(rows, table),
-            config=cfg, max_blocks=table,
+            i32(rows), caches, i32(rows, table), config=cfg, max_blocks=table,
         )
-        .lower(lowering_platforms=("tpu",))
-        .as_text()
-    )
+    else:
+        layout = serving.WaveLayout(rows, rows, pages)
+        traced = serving.verify_step_ragged.trace(
+            params, i32(layout.size(table)), caches, config=cfg, max_blocks=table,
+            layout=layout,
+        )
+    text = traced.lower(lowering_platforms=("tpu",)).as_text()
     layers = re.findall(r"call @(_wave_layer\w*)\(", text)
     assert len(layers) == cfg.n_layers and len(set(layers)) == 1, layers
     calls = re.findall(r"call @(\w*paged_decode_attention_pallas_ragged\w*)\(", text)
@@ -413,6 +420,7 @@ def test_wave_program_shares_one_layer_and_one_kernel_function(v5e, monkeypatch)
 # (entry, kv heads, cache blocks)
 DONATING = [
     ("verify_step_ragged", 8, 640),
+    ("packed_wave", 8, 640),  # serving.verify_step_ragged: what the decoder launches
     ("resume_chunk", 8, 1024),
     ("prefill", 32, 320),
 ]
@@ -430,15 +438,15 @@ def test_serving_entries_update_the_cache_in_place(v5e, monkeypatch, case):
     widths and these 4 layers XLA still stages 3 of a ``prefill``'s 8 donated
     tensors through VMEM, asynchronously and in quarters; at 16 layers it
     stages none. Hence DeepSeek's cell for that entry.)"""
-    from infinistore_tpu.models import llama
+    from infinistore_tpu.models import llama, serving
 
     monkeypatch.setattr(paged, "_use_pallas", lambda: True)
     entry, kvh, blocks = case
     # A vocabulary no other test uses: the module's own jitted entries trace
     # here, under the TPU dispatch, and never from or for another test.
     cfg = llama.LlamaConfig(
-        vocab=1021, dim=4096, n_layers=4, n_heads=32, n_kv_heads=kvh,
-        ffn_dim=1024, block_tokens=16, dtype=jnp.bfloat16,
+        vocab=1021 if entry != "packed_wave" else 1013, dim=4096, n_layers=4,
+        n_heads=32, n_kv_heads=kvh, ffn_dim=1024, block_tokens=16, dtype=jnp.bfloat16,
     )
     s = functools.partial(jax.ShapeDtypeStruct, sharding=v5e)
     i32 = lambda *shape: s(shape, jnp.int32)
@@ -452,11 +460,16 @@ def test_serving_entries_update_the_cache_in_place(v5e, monkeypatch, case):
             i32(rows), caches, i32(rows, table),
         )
         static = {"config": cfg, "max_blocks": table}
+    elif entry == "packed_wave":
+        layout = serving.WaveLayout(rows=8, tables=8, pages=1024)
+        args = (params, i32(layout.size(80)), caches)
+        static = {"config": cfg, "max_blocks": 80, "layout": layout}
     elif entry == "resume_chunk":
         args, static = (params, i32(128), i32(), caches, i32(524)), {"config": cfg}
     else:
         args, static = (params, i32(1024), caches, i32(64)), {"config": cfg}
-    exe = _compile(getattr(llama, entry), *args, **static)
+    jitted = serving.verify_step_ragged if entry == "packed_wave" else getattr(llama, entry)
+    exe = _compile(jitted, *args, **static)
     text = exe.as_text()
     header = text.split("\n", 1)[0]
     tensors = 2 * cfg.n_layers
@@ -555,7 +568,7 @@ def test_full_width_steps_compile_and_fit_one_v5e(v5e, monkeypatch):
 # layers in one stack, an expert layer in both its shapes. Attention at the
 # configuration's widths (32 q / 4 kv heads x 128, window 2,048, 16-token
 # blocks, bf16), 8 experts top-2 of a small width and a small vocabulary.
-AFMOE_ENTRIES = ["verify_step_ragged", "resume_chunk", "prefill"]
+AFMOE_ENTRIES = ["verify_step_ragged", "packed_wave", "resume_chunk", "prefill"]
 
 
 @pytest.mark.parametrize("entry", AFMOE_ENTRIES)
@@ -564,11 +577,11 @@ def test_afmoe_entries_compile_and_update_the_cache_in_place(v5e, monkeypatch, e
     attention kernels, the wave's expert kernel, the grouped matmul) and
     holds an ``input_output_alias`` for EVERY cache tensor, the aliased bytes
     the whole cache's: the donation ``llama.py``'s entries carry."""
-    from infinistore_tpu.models import afmoe
+    from infinistore_tpu.models import afmoe, serving
 
     monkeypatch.setattr(paged, "_use_pallas", lambda: True)
     cfg = afmoe.AfmoeConfig(
-        vocab=1019, dim=2048, n_heads=32, n_kv_heads=4, head_dim=128, ffn_dim=512,
+        vocab=1019 if entry != "packed_wave" else 1009, dim=2048, n_heads=32, n_kv_heads=4, head_dim=128, ffn_dim=512,
         moe_ffn_dim=256, n_experts=8, experts_per_token=2, sliding_window=2048,
         block_tokens=16, dtype=jnp.bfloat16,
     )
@@ -586,11 +599,16 @@ def test_afmoe_entries_compile_and_update_the_cache_in_place(v5e, monkeypatch, e
         )
         windowed = (i32(rows * 129), i32(rows * 129 + 1), i32(rows))
         static = {"config": cfg, "max_blocks": table, "window_pages": windowed}
+    elif entry == "packed_wave":  # the same bucket as the decoder launches it
+        layout = serving.WaveLayout(rows=4, tables=4, pages=1024, window_pages=4 * 129)
+        args = (params, i32(layout.size(320)), caches)
+        static = {"config": cfg, "max_blocks": 320, "layout": layout}
     elif entry == "resume_chunk":
         args, static = (params, i32(128), i32(), caches, i32(320)), {"config": cfg}
     else:
         args, static = (params, i32(4224), caches, i32(264)), {"config": cfg}
-    lowered = getattr(afmoe, entry).trace(*args, **static).lower(lowering_platforms=("tpu",))
+    jitted = serving.verify_step_ragged if entry == "packed_wave" else getattr(afmoe, entry)
+    lowered = jitted.trace(*args, **static).lower(lowering_platforms=("tpu",))
     kernels = set(re.findall(r'kernel_name = "(\w+)"', lowered.as_text()))
     exe = lowered.compile()
     text = exe.as_text()
@@ -601,9 +619,10 @@ def test_afmoe_entries_compile_and_update_the_cache_in_place(v5e, monkeypatch, e
     assert exe.memory_analysis().alias_size_in_bytes == tensors * int(np.prod(cache.shape)) * 2
     want = {
         "verify_step_ragged": {"_ragged_attn_kernel", "_moe_wave_kernel"},
+        "packed_wave": {"_ragged_attn_kernel", "_moe_wave_kernel"},
         "resume_chunk": {"_chunk_attn_kernel"},
         "prefill": {"_flash_kernel"},
     }[entry]
     assert want <= kernels, kernels
-    if entry != "verify_step_ragged":
+    if entry not in ("verify_step_ragged", "packed_wave"):
         assert kernels - want, kernels  # the grouped matmul's

@@ -1,6 +1,7 @@
 """What one warmed wave bucket costs a run's set-up, split by where it goes.
 
-A benchmark cell warms one ``verify_step_ragged`` program for every (rows,
+A benchmark cell warms one ``verify_step_ragged`` program (the packed wave
+entry of ``models/serving.py``, as the decoder launches it) for every (rows,
 pages) bucket its traffic can land on (``benchmarks/run.py``
 ``wave_buckets()``: 24 in the chat cell), so whatever a change adds to that
 program's trace, lowering or load is paid a bucket. This tool stages each
@@ -65,7 +66,7 @@ def main() -> int:
     from jax import monitoring
 
     from infinistore_tpu import compile_cache
-    from infinistore_tpu.models import llama
+    from infinistore_tpu.models import llama, serving
 
     compile_cache.enable()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
@@ -87,9 +88,10 @@ def main() -> int:
     rows_out, kernel = [], None
     for rows, pages in buckets[: args.buckets or None]:
         t0 = time.perf_counter()
-        traced = llama.verify_step_ragged.trace(
-            params, i32(rows), i32(rows), i32(rows), i32(pages), i32(pages + 1),
-            i32(rows), caches, i32(rows, mrb), config=cfg, max_blocks=mrb,
+        layout = serving.WaveLayout(rows=rows, tables=rows, pages=pages)
+        traced = serving.verify_step_ragged.trace(
+            params, i32(layout.size(mrb)), caches, config=cfg, max_blocks=mrb,
+            layout=layout,
         )
         t1 = time.perf_counter()
         lowered = traced.lower()
