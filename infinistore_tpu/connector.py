@@ -169,7 +169,6 @@ class FetchCoalescer:
         self.calls = 0  # batched store calls issued
         self.submissions = 0  # logical submits merged into them
         self.max_batch = 0
-        self.ring_windows = 0  # flushes that opened a ring batch window
 
     def submit(self, blocks, priority: int = 0) -> "asyncio.Future":
         """Queue one logical read (list of (key, offset-from-base) pairs);
@@ -232,7 +231,6 @@ class FetchCoalescer:
         window = getattr(self.conn, "ring_batch_window", None)
         if callable(window):
             window()
-            self.ring_windows += 1
         await asyncio.gather(*(self._issue(g, p) for p, g in self._group(batch)))
 
     async def _issue(self, batch, priority: int = 0):
@@ -329,6 +327,24 @@ class KVConnector:
         self.model_id = model_id
         self.max_blocks = max_blocks
         self.ici = ici
+        # The hop's ledger (get_stats), always on. Of the hits' prefetches:
+        # store values (a K or a V of one block of one layer) fetched, and
+        # what every block of every layer of the same hits would have been
+        # (they differ where the spec names sliding layers,
+        # PagedKVCacheSpec.hit_first_block); the bytes their layer reads
+        # landed and the microseconds in which at least one such read was in
+        # flight (with the gauge and the perf_counter mark that union is
+        # kept by). Of the installs: bytes handed to the device and the
+        # summed time of the executor calls that handed them (host time, not
+        # the DMA's end). Of the saves: bytes whose D2H the writer waited for
+        # on the event loop, and those waits.
+        self.hit_counters = {
+            "hit_values_fetched": 0, "hit_values_whole_prefix": 0,
+            "hit_read_bytes": 0, "hit_read_busy_us": 0.0,
+            "hit_reads_in_flight": 0, "hit_read_busy_mark_s": 0.0,
+            "install_upload_bytes": 0, "install_upload_us": 0.0,
+            "save_d2h_bytes": 0, "save_d2h_wait_us": 0.0,
+        }
         if conn is None:
             # Pure-ICI connector: no store data plane, so don't allocate the
             # (potentially tens of MB) host staging pool it would need.
@@ -344,6 +360,7 @@ class KVConnector:
                 )
             self.pool = pool
             self._writer = LayerwiseKVWriter(conn, pool, spec, max_blocks)
+            self._writer.counters = self.hit_counters
             self._reader = LayerwiseKVReader(conn, pool, spec, max_blocks)
         # Two-phase admission path (start_fetch): its own staging pool —
         # the reader's ``_LayerRegions`` owns ``pool``'s layout outright, so
@@ -355,11 +372,6 @@ class KVConnector:
         # prefix's keys on every lookup/load/save (satellite of the adaptive
         # data-plane PR; BENCH_r05 put the 256-chain lookup at 26.1us with
         # the hashing/keying on top of it).
-        # The hit ledger (get_stats): store values (a K or a V of one block
-        # of one layer) the prefetches fetched, and what every block of every
-        # layer of the same hits would have been. They differ where the spec
-        # names sliding layers (PagedKVCacheSpec.hit_first_block).
-        self.hit_counters = {"hit_values_fetched": 0, "hit_values_whole_prefix": 0}
         self._chain_cache = _ChainHashCache()
         self._keys0_cache: Optional[Tuple[List[str], List[str]]] = None
 
@@ -930,8 +942,15 @@ class KVConnector:
     def get_stats(self) -> dict:
         """The store connection's per-op stats snapshot (observability
         surface composed members re-expose — cluster.py stats()), with this
-        connector's hit ledger beside it: ``hit_values_fetched`` and
-        ``hit_values_whole_prefix`` (``hit_counters``)."""
+        connector's ledger of the hop beside it (``hit_counters``):
+        ``hit_values_fetched`` and ``hit_values_whole_prefix``;
+        ``hit_read_bytes`` over ``hit_read_busy_us`` (the union of the time
+        in which a hit's layer read was in flight; ``hit_reads_in_flight``
+        and ``hit_read_busy_mark_s`` keep it), the store's delivered rate;
+        ``install_upload_bytes`` over ``install_upload_us``, the host's rate
+        of handing a hit's bytes to the device; ``save_d2h_bytes`` over
+        ``save_d2h_wait_us``, what a save's D2H waits on the event loop
+        delivered."""
         self._require_store("get_stats")
         return {**self.conn.get_stats(), **self.hit_counters}
 

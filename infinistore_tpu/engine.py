@@ -1075,6 +1075,17 @@ class RequestStats:
     # of a load that still needs exclusivity), the gate-free store fetch's
     # duration, and what fraction of that fetch ran while this request
     # held NO gate (1.0 = store I/O fully hidden behind other work).
+    # What the two really span: gate_hold_us is the whole `install_kv`
+    # await, which on the per-layer path also awaits every layer that had
+    # not landed yet (a store read under the exclusive gate: the
+    # `install_staged_wait` span). fetch_us runs from the prefetch's
+    # construction to the moment its LAST layer landed; with fewer staging
+    # regions than layers (at most 8), layer L >= regions starts its read
+    # only once install consumed region L - regions, so fetch_us holds the
+    # request's own alloc, primed(), gate wait and first uploads: bytes
+    # over it is not the store's rate (that is `hit_read_bytes` over
+    # `hit_read_busy_us`, the connector's get_stats(); per layer, the
+    # `fetch_layer` span's `region_free` -> `landed`).
     gate_hold_us: float = 0.0
     fetch_us: float = 0.0
     overlap_fraction: Optional[float] = None
@@ -1544,12 +1555,14 @@ class ContinuousBatchingHarness:
         self.live += 1
         self.max_live = max(self.max_live, self.live)
         # Trace root for this request (docs/observability.md): `enqueue` is
-        # stamped at admission t0, `install` when fetched bytes land in the
-        # paged cache; every store op issued below (prefetch -> coalescer ->
-        # striped scheduler -> wire) becomes a child of this span via the
-        # bound context, and so do the request's own phases: `pool_alloc`,
-        # `gate_wait`, `install`, `compute`, `save_snapshot`, `save_io`,
-        # `generate`. With tracing off each hook is a no-op call.
+        # stamped at admission t0, `alloc_done` with its blocks in hand, a
+        # hit's `primed` when its fetch pipeline is full, `install` when
+        # fetched bytes land in the paged cache; a hit's layer reads are
+        # its `fetch_layer` children (each the active span of its store
+        # read: prefetch -> coalescer -> striped scheduler -> wire), and so
+        # are the request's own phases: `pool_alloc`, `gate_wait`,
+        # `install`, `compute`, `save_snapshot`, `save_io`, `generate`.
+        # With tracing off each hook is a no-op call.
         rspan = tracing.start_span("engine_request")
         rtoken = tracing.bind_span(rspan)
         if rspan is not None:
@@ -1621,6 +1634,8 @@ class ContinuousBatchingHarness:
             lookup_s = time.perf_counter() - t0  # start_fetch includes the probe
             prefetch_settled = prefetch is None or prefetch.n_blocks == 0
             table = await self.pool.alloc(total_blocks)
+            if rspan is not None:
+                rspan.stage("alloc_done")
             if prefetch is not None:
                 # Admitted: a background-tagged speculative fetch is
                 # decode-blocking from here — upgrade its remaining
@@ -1640,6 +1655,8 @@ class ContinuousBatchingHarness:
                     # Wait for the fetch pipeline to fill WITHOUT the gate:
                     # the store I/O runs while other requests compute.
                     await prefetch.primed()
+                    if rspan is not None:
+                        rspan.stage("primed")
                     t_gate = time.perf_counter()
                     async with self.gate.exclusive(expedite=True):
                         gate_stall_us = (time.perf_counter() - t_gate) * 1e6

@@ -108,7 +108,15 @@ class LayerwiseKVWriter:
     buffers on the network concurrently — up to ``depth`` layer-groups of
     puts in flight. Puts go straight from jax's D2H buffer (registered for
     the op's lifetime), so the only host copy is the one into the server's
-    pool."""
+    pool.
+
+    Tracing (docs/observability.md): under the caller's span (the engine's
+    ``save_io``) one ``save_layer`` a layer, from its gather's dispatch to
+    its two puts' acknowledgement, and under it ``save_d2h_wait``: the
+    synchronous wait for the layer's D2H, which stands on the caller's
+    EVENT LOOP (also the ``its.save_d2h`` device-call region). Always on:
+    ``counters`` (the connector's ledger, when it set one) gains the bytes
+    and the microseconds of those waits."""
 
     def __init__(self, conn, pool: HostStagingPool, spec: PagedKVCacheSpec,
                  max_blocks: int, depth: int = 2, d2h_window: int = 4):
@@ -124,6 +132,9 @@ class LayerwiseKVWriter:
         # Layers of D2H kept in flight: device->host transfers pipeline, at
         # a device-memory cost of 2 x n x block_nbytes per window entry.
         self.d2h_window = d2h_window
+        # {"save_d2h_bytes", "save_d2h_wait_us"}: KVConnector shares its
+        # hit_counters here; a writer on its own counts nothing.
+        self.counters: Optional[dict] = None
 
     async def write(
         self,
@@ -144,12 +155,13 @@ class LayerwiseKVWriter:
         ids_dev = jax.numpy.asarray(block_ids, dtype=jax.numpy.int32)
         pool = self.pool
         bn = self.spec.block_nbytes
-        # (futures, registered transfer, blocks count) groups in flight.
+        # (futures, registered transfer, blocks count, `save_layer` span)
+        # groups in flight.
         inflight: deque = deque()
         total = 0
 
         async def drain_one() -> int:
-            futs, tr, count = inflight.popleft()
+            futs, tr, count, lspan = inflight.popleft()
             # Let BOTH puts settle before releasing the host buffers — a
             # failed K-batch must not free memory the V-batch's writev is
             # still streaming from — then surface the first failure.
@@ -157,7 +169,11 @@ class LayerwiseKVWriter:
             tr.release()
             for r in results:
                 if isinstance(r, BaseException):
+                    if lspan is not None:
+                        lspan.finish(status=f"error:{type(r).__name__}")
                     raise r
+            if lspan is not None:
+                lspan.finish()
             return count
 
         # Layer 0 is written LAST: connectors use a block's layer-0 K key as
@@ -178,6 +194,12 @@ class LayerwiseKVWriter:
                     return
                 pos, layer = nxt
                 k_cache, v_cache = caches[layer]
+                # `save_layer`: this gather's dispatch to its puts' ack; a
+                # child of the caller's span (the engine's `save_io`, which
+                # the store's write ops keep stamping).
+                lspan = tracing.start_span("save_layer")
+                if lspan is not None:
+                    lspan.annotate(layer=layer, bytes=2 * n * bn)
                 # K blocks then V blocks packed into ONE device array -> one
                 # D2H transfer per layer (the device-side concat is an HBM
                 # copy, trivial next to the host transfer it halves).
@@ -186,12 +208,12 @@ class LayerwiseKVWriter:
                         gather_blocks(k_cache, ids_dev),
                         gather_blocks(v_cache, ids_dev),
                     ])
-                ])))
+                ]), lspan))
 
         try:
             top_up()
             while staged:
-                pos, layer, tr = staged.popleft()
+                pos, layer, tr, lspan = staged[0]
                 # Keep at most depth-1 older put groups while this D2H lands.
                 while len(inflight) >= self.depth:
                     total += await drain_one()
@@ -200,7 +222,20 @@ class LayerwiseKVWriter:
                     # completed (= committed) before the sentinel ships.
                     while inflight:
                         total += await drain_one()
-                (kv_host,) = tr.wait()  # registers the packed buffer
+                # `save_d2h_wait`: the loop stands still until the gather
+                # and its D2H have landed (wait() also registers the packed
+                # buffer) — no wave can flush meanwhile.
+                dspan = tracing.start_span("save_d2h_wait", parent=lspan)
+                t_wait = time.perf_counter()
+                with tracing.device_call("its.save_d2h", dspan):
+                    (kv_host,) = tr.wait()
+                if self.counters is not None:
+                    self.counters["save_d2h_bytes"] += kv_host.nbytes
+                    self.counters["save_d2h_wait_us"] += (
+                        time.perf_counter() - t_wait
+                    ) * 1e6
+                if dspan is not None:
+                    dspan.finish()
                 base = kv_host.ctypes.data
                 pri_kw = wire.qos_kwargs(self.conn, priority)
                 futs = (
@@ -211,7 +246,8 @@ class LayerwiseKVWriter:
                         [(key_fn(layer, "v", i), i * bn) for i in range(n)],
                         bn, base + n * bn, **pri_kw)),
                 )
-                inflight.append((futs, tr, 2 * n))
+                staged.popleft()
+                inflight.append((futs, tr, 2 * n, lspan))
                 top_up()  # refill the D2H pipeline before blocking again
             while inflight:
                 total += await drain_one()
@@ -220,11 +256,16 @@ class LayerwiseKVWriter:
             # host buffers — the native reactor may be mid-writev on them
             # (a dead connection fails these futures promptly via fail_all).
             while inflight:
-                futs, tr, _ = inflight.popleft()
+                futs, tr, _, lspan = inflight.popleft()
                 try:
                     await asyncio.gather(*futs, return_exceptions=True)
                 finally:
                     tr.release()
+                    if lspan is not None:
+                        lspan.finish(status="error:aborted")
+            for _, _, _, lspan in staged:  # gathered, never shipped
+                if lspan is not None:
+                    lspan.finish(status="error:aborted")
         return total
 
 
@@ -365,6 +406,19 @@ class LayerwiseKVReader:
         return out
 
 
+def _hit_reads_step(counters: dict, step: int) -> None:
+    """One hit's layer read goes in flight (``step`` +1) or comes back (-1):
+    the time since the last change counts into ``hit_read_busy_us`` where
+    at least one read was in flight through it. The UNION of the reads'
+    time, so that ``hit_read_bytes`` over it is the rate the store
+    delivered while anyone was asking, however many asked at once."""
+    now = time.perf_counter()
+    if counters["hit_reads_in_flight"] > 0:
+        counters["hit_read_busy_us"] += (now - counters["hit_read_busy_mark_s"]) * 1e6
+    counters["hit_read_busy_mark_s"] = now
+    counters["hit_reads_in_flight"] += step
+
+
 class PrefetchDiscarded(RuntimeError):
     """install() was called on a prefetch that was discarded (or the
     prefetch was discarded out from under a waiter)."""
@@ -438,9 +492,13 @@ class LayerwisePrefetch:
         blind re-probing, so the reader never burns store round trips on
         keys that cannot exist yet. Composable with ``retry_missing_s``
         (the gate bounds when to START, the retry rides any residual race).
-        ``counters``: the connector's own ledger; every layer that lands adds
-        the store values it fetched to ``hit_values_fetched`` and what every
-        block of that layer would have been to ``hit_values_whole_prefix``.
+        ``counters``: the connector's own ledger (``KVConnector.hit_counters``,
+        every key of it); every layer that lands adds the store values it
+        fetched to ``hit_values_fetched``, what every block of that layer
+        would have been to ``hit_values_whole_prefix`` and its bytes to
+        ``hit_read_bytes``; ``hit_read_busy_us`` is the time in which at
+        least one layer read was in flight (``_hit_reads_step``), and an
+        install adds ``install_upload_bytes`` / ``install_upload_us``.
         Raises :class:`~..tpu.staging.StagingPoolExhausted` when the pool
         cannot hold even a double-buffered pipeline."""
         self.conn = conn
@@ -539,47 +597,76 @@ class LayerwisePrefetch:
         return self._lease.offset + (layer % self.regions) * self._region_stride
 
     async def _fetch_layer(self, layer: int):
-        if self._fetch_gate is not None:
-            # Announce-driven handoff: wait for the producer's per-layer
-            # publication signal before spending a store round trip.
-            await self._fetch_gate(layer)
-        if layer >= self.regions:
-            # Double buffering: refill a region only once install consumed
-            # (or discard wrote off) its previous occupant.
-            await self._consumed[layer - self.regions].wait()
-        if self._cancelled:
-            return
         n, bn = self.n_blocks, self.spec.block_nbytes
-        base = self._region_offset(layer)
         first = self._first[layer]
         m = n - first
-        blocks = [
-            (self._key_fn(layer, "k", first + i), base + i * bn) for i in range(m)
-        ] + [
-            (self._key_fn(layer, "v", first + i), base + (m + i) * bn) for i in range(m)
-        ]
-        try:
-            await self._submit_with_retry(blocks)
-        except asyncio.CancelledError:
-            self._cancel_rest()
-            raise
-        except BaseException as e:
-            if self._error is None:
-                self._error = e
+        counters = self._counters
+        # `fetch_layer`: one span a layer of a hit, a child of whatever span
+        # started the prefetch (the engine's `engine_request`) and the
+        # ACTIVE span of this task, so the store read below stamps it
+        # (`coalesce`, `submit`, `completion_ring`) and puts its id on the
+        # wire: the server's ticks hang under the layer they served.
+        # `queued` -> `region_free` is the wait for install to hand the
+        # staging region on (and an announce-driven handoff's gate),
+        # `region_free` -> `landed` the read.
+        with tracing.trace_op("fetch_layer", stage="queued") as span:
+            if span is not None:
+                span.annotate(
+                    layer=layer, region=layer % self.regions,
+                    values=2 * m, bytes=2 * m * bn,
+                )
+            if self._fetch_gate is not None:
+                # Announce-driven handoff: wait for the producer's per-layer
+                # publication signal before spending a store round trip.
+                await self._fetch_gate(layer)
+            if layer >= self.regions:
+                # Double buffering: refill a region only once install
+                # consumed (or discard wrote off) its previous occupant.
+                await self._consumed[layer - self.regions].wait()
+            if span is not None:
+                span.stage("region_free")
+            if self._cancelled:
+                if span is not None:
+                    span.finish(status="cancelled")
+                return
+            base = self._region_offset(layer)
+            blocks = [
+                (self._key_fn(layer, "k", first + i), base + i * bn) for i in range(m)
+            ] + [
+                (self._key_fn(layer, "v", first + i), base + (m + i) * bn) for i in range(m)
+            ]
+            if counters is not None:
+                _hit_reads_step(counters, +1)
+            try:
+                await self._submit_with_retry(blocks)
+            except asyncio.CancelledError:
+                self._cancel_rest()
+                raise
+            except BaseException as e:
+                if span is not None:
+                    span.finish(status=f"error:{type(e).__name__}")
+                if self._error is None:
+                    self._error = e
+                if not self._staged[layer].done():
+                    self._staged[layer].set_exception(e)
+                # One failing layer dooms the whole prefix (a partial prefix
+                # has no value) — stop refilling regions.
+                self._cancel_rest()
+                return
+            finally:
+                if counters is not None:
+                    _hit_reads_step(counters, -1)
+            if span is not None:
+                span.stage("landed")
+            self.blocks_fetched += 2 * m
+            if counters is not None:
+                counters["hit_values_fetched"] += 2 * m
+                counters["hit_values_whole_prefix"] += 2 * n
+                counters["hit_read_bytes"] += 2 * m * bn
             if not self._staged[layer].done():
-                self._staged[layer].set_exception(e)
-            # One failing layer dooms the whole prefix (a partial prefix
-            # has no value) — stop refilling regions.
-            self._cancel_rest()
-            return
-        self.blocks_fetched += 2 * m
-        if self._counters is not None:
-            self._counters["hit_values_fetched"] += 2 * m
-            self._counters["hit_values_whole_prefix"] += 2 * n
-        if not self._staged[layer].done():
-            self._staged[layer].set_result(layer % self.regions)
-        if layer == self.num_layers - 1:
-            self.fetch_finished_s = time.perf_counter()
+                self._staged[layer].set_result(layer % self.regions)
+            if layer == self.num_layers - 1:
+                self.fetch_finished_s = time.perf_counter()
 
     async def _submit_with_retry(self, blocks):
         """The store read, with the handoff mode's bounded KeyNotFound
@@ -760,9 +847,15 @@ class LayerwisePrefetch:
         bn = self.spec.block_nbytes
         dt = np.dtype(jax.numpy.dtype(self.spec.dtype))
         loop = asyncio.get_running_loop()
-        # The caller's span (the engine's `install`): the uploads and
-        # scatters below run in executor threads, which do not inherit it.
-        tspan = tracing.active_span()
+        # Children of the caller's span (the engine's `install`, whose
+        # duration is the exclusive gate's hold): `install_upload`, just
+        # before `run_in_executor` to its return (`started`: the executor
+        # thread entered, `h2d`: `device_put` returned; the thread does not
+        # inherit the span, so it is handed in, and the `its.install`
+        # device call is on it), and `install_staged_wait` for each layer
+        # that had NOT landed when the install reached it: the exclusive
+        # gate waiting for the network.
+        counters = self._counters
         fused = (
             self.regions >= self.num_layers
             and not any(self._first)  # a sliding layer's region is part full
@@ -785,9 +878,14 @@ class LayerwisePrefetch:
                 (self.num_layers * 2 * n, *self.spec.block_shape)
             )
 
-            def dev_all(caches_in):
-                with tracing.device_call("its.install", tspan):
+            def dev_all(caches_in, uspan):
+                t_up = time.perf_counter()
+                if uspan is not None:
+                    uspan.stage("started")
+                with tracing.device_call("its.install", uspan):
                     kv_all = jax.device_put(host_all)
+                    if uspan is not None:
+                        uspan.stage("h2d")
                     scattered = []
                     for layer in range(self.num_layers):
                         base = layer * 2 * n
@@ -800,11 +898,19 @@ class LayerwisePrefetch:
                                 v_cache, ids_dev, kv_all[base + n : base + 2 * n]
                             ),
                         ))
-                return kv_all, scattered
+                return kv_all, scattered, (time.perf_counter() - t_up) * 1e6
 
-            kv_all, scattered = await loop.run_in_executor(
-                None, dev_all, list(out)
-            )
+            with tracing.trace_op("install_upload") as uspan:
+                if uspan is not None:
+                    uspan.annotate(
+                        layers=self.num_layers, bytes=host_all.nbytes, fused=True
+                    )
+                kv_all, scattered, upload_us = await loop.run_in_executor(
+                    None, dev_all, list(out), uspan
+                )
+            if counters is not None:
+                counters["install_upload_bytes"] += host_all.nbytes
+                counters["install_upload_us"] += upload_us
             for layer in range(self.num_layers):
                 out[layer] = scattered[layer]
                 self._installing.add(layer)
@@ -816,10 +922,17 @@ class LayerwisePrefetch:
             )
             return out, n
         for layer in range(self.num_layers):
+            fut = self._staged[layer]
             try:
-                await asyncio.shield(self._staged[layer])
+                if fut.done():
+                    await asyncio.shield(fut)
+                else:
+                    with tracing.trace_op("install_staged_wait") as wspan:
+                        if wspan is not None:
+                            wspan.annotate(layer=layer)
+                        await asyncio.shield(fut)
             except asyncio.CancelledError:
-                if not self._staged[layer].cancelled():
+                if not fut.cancelled():
                     raise  # the INSTALLING task was cancelled, not the fetch
                 # A DEEPER layer's store failure cancels shallower pending
                 # futures (completion order is not layer order) — surface
@@ -862,20 +975,32 @@ class LayerwisePrefetch:
             )
             ids = ids_dev[first:] if first else ids_dev
 
-            def dev_one(pair, kv_host=kv_host, ids=ids, m=m):
-                with tracing.device_call("its.install", tspan):
+            def dev_one(pair, uspan, kv_host=kv_host, ids=ids, m=m):
+                t_up = time.perf_counter()
+                if uspan is not None:
+                    uspan.stage("started")
+                with tracing.device_call("its.install", uspan):
                     kv_dev = jax.device_put(kv_host)
+                    if uspan is not None:
+                        uspan.stage("h2d")
                     k_cache, v_cache = pair
-                    return kv_dev, (
+                    pair = (
                         scatter_blocks(k_cache, ids, kv_dev[:m]),
                         scatter_blocks(v_cache, ids, kv_dev[m:]),
                     )
+                return kv_dev, pair, (time.perf_counter() - t_up) * 1e6
 
             # Off-loop for the same reason as the fused path: upload +
             # scatter must not freeze other requests' fetch completions.
-            kv_dev, out[layer] = await loop.run_in_executor(
-                None, dev_one, out[layer]
-            )
+            with tracing.trace_op("install_upload") as uspan:
+                if uspan is not None:
+                    uspan.annotate(layer=layer, bytes=kv_host.nbytes, fused=False)
+                kv_dev, out[layer], upload_us = await loop.run_in_executor(
+                    None, dev_one, out[layer], uspan
+                )
+            if counters is not None:
+                counters["install_upload_bytes"] += kv_host.nbytes
+                counters["install_upload_us"] += upload_us
             self._installing.add(layer)
             self.blocks_installed += 2 * m
             if on_layer is not None:

@@ -41,6 +41,13 @@ schema and docs/observability.md to this tuple, in lockstep):
 - ``last_slice``      last payload/slice unit of server work      [native]
 - ``completion_ring`` completion drained from the native ring
 - ``install``         bytes installed into the engine's paged cache
+- ``alloc_done``      the request holds its cache blocks (``pool.alloc`` returned)
+- ``primed``          a hit's fetch pipeline is full (``prefetch.primed()`` returned)
+- ``queued``          a hit's layer read was created (its ``fetch_layer`` span opens)
+- ``region_free``     the layer's staging region is its own (no wait where it always was)
+- ``landed``          the layer's store read returned: its bytes sit staged
+- ``started``         an install's upload entered its executor thread (the hop)
+- ``h2d``             the upload's ``jax.device_put`` returned
 - ``wave_enqueue``    a generation round handed its chunk to the wave decoder
 - ``wave_result``     the wave's future handed the round its logits rows
 - ``token``           the round's sampled token(s) reached the host
@@ -50,9 +57,12 @@ schema and docs/observability.md to this tuple, in lockstep):
 - ``dispatched``      the wave's jitted step returned (dispatch, not completion)
 - ``resolved``        every rider's future holds its rows
 
-The first ten are one op's path through the store; the last eight belong
-to the engine's own spans (``generate`` stamps three per round, ``wave``
-five per launch — see docs/observability.md for the span tree).
+The first ten are one op's path through the store; the next seven cut the
+hop around it where the work happens (``engine_request``: ``alloc_done``,
+``primed``; ``fetch_layer``: ``queued``, ``region_free``, ``landed``;
+``install_upload``: ``started``, ``h2d``); the last eight belong to the
+engine's own spans (``generate`` stamps three per round, ``wave`` five per
+launch — see docs/observability.md for the span tree).
 
 Clocks: every stamp (Python and native) is CLOCK_MONOTONIC microseconds,
 so same-host client and server ticks share a timebase and merge into one
@@ -88,6 +98,13 @@ STAGES = (
     "last_slice",
     "completion_ring",
     "install",
+    "alloc_done",
+    "primed",
+    "queued",
+    "region_free",
+    "landed",
+    "started",
+    "h2d",
     "wave_enqueue",
     "wave_result",
     "token",
@@ -163,8 +180,12 @@ class Span:
     def stage(self, name: str):
         """Stamp one stage boundary NOW. Repeats are legal (a striped op
         submits many chunks); consumers use the first occurrence for
-        breakdowns and keep the rest for per-chunk visibility."""
-        self.stages.append((name, _now_us()))
+        breakdowns and keep the rest for per-chunk visibility. Lock-free
+        from any thread: one list append under the GIL, and an executor
+        call stamps the span handed to it only while the task that owns
+        the span awaits that call (``install_upload``'s ``started`` /
+        ``h2d``)."""
+        self.stages.append((name, _now_us()))  # its: allow[ITS-R001]
 
     def annotate(self, **attrs):
         """Attach routing/context attributes (member index, stripe, bytes)."""

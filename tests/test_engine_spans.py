@@ -22,6 +22,7 @@ from infinistore_tpu.engine import (
     EngineKVAdapter,
 )
 from infinistore_tpu.models import LlamaConfig, init_params
+from infinistore_tpu.tpu.staging import HostStagingPool
 
 CFG = LlamaConfig(
     vocab=128, dim=64, n_layers=2, n_heads=4, n_kv_heads=2, ffn_dim=128,
@@ -119,10 +120,11 @@ def test_request_span_tree(conn, params, traced, which):
         assert miss.save_overlap_us > 0 and hit.save_overlap_us == hit.save_tail_us == 0.0
     else:
         (inst,) = [s for s in spans if s["name"] == "install"]
-        assert inst["attrs"]["blocks"] == 3
-        assert [c[0] for c in inst["attrs"]["device_calls"]] == ["its.install"] * len(
-            inst["attrs"]["device_calls"]
-        )
+        assert inst["attrs"]["blocks"] == 3 and "device_calls" not in inst["attrs"]
+        # The call into the device is the upload's, a child of the install.
+        uploads = [s for s in spans if s["name"] == "install_upload"]
+        assert uploads and all(u["parent_id"] == inst["span_id"] for u in uploads)
+        assert all([c[0] for c in u["attrs"]["device_calls"]] == ["its.install"] for u in uploads)
         modes = {s["attrs"]["mode"] for s in spans if s["name"] == "gate_wait"}
         assert "expedite" in modes
 
@@ -187,6 +189,238 @@ def test_tracing_off_records_nothing_and_still_stamps_emits(conn, params, monkey
         assert t_before < stats.token_emit_s[0] and stats.token_emit_s[-1] < time.perf_counter()
     # ttft_us precedes the first read-back; token_emit_s[0] follows it.
     assert miss.ttft_us > 0 and hit.ttft_us > 0
+    # The hop's counters need no recorder: the miss saved, the hit read and
+    # installed, and all six moved.
+    moved = h.adapter.connector.get_stats()
+    assert all(moved[key] > 0 for key in HOP_COUNTERS), moved
+    assert moved["hit_read_bytes"] == moved["install_upload_bytes"] == _hit_bytes(hit)
+    assert moved["save_d2h_bytes"] == _hit_bytes(hit) and moved["hit_reads_in_flight"] == 0
+
+
+# The connector's ledger of the hop (docs/observability.md), always on.
+HOP_COUNTERS = (
+    "hit_read_bytes", "hit_read_busy_us", "install_upload_bytes", "install_upload_us",
+    "save_d2h_bytes", "save_d2h_wait_us",
+)
+
+
+def _hit_bytes(stats):
+    """What a hit's prefetch read: its store values (a K or a V of one block
+    of one layer: 8 tokens x 2 KV heads x 16 x float32 under this file's
+    configurations)."""
+    return stats.prefetched_blocks * 8 * 2 * 16 * 4
+
+
+# -- the store's hop, cut where the work happens ------------------------------
+
+CFG3 = LlamaConfig(
+    vocab=128, dim=64, n_layers=3, n_heads=4, n_kv_heads=2, ffn_dim=128,
+    block_tokens=8, dtype=jnp.float32,
+)
+READ_DELAY_S = 0.1
+
+
+class SlowReads:
+    """The connection with every batched read held back, so that whatever
+    waits for the store has something to wait for."""
+
+    def __init__(self, conn, delay_s=READ_DELAY_S):
+        self._conn, self._delay_s = conn, delay_s
+
+    def __getattr__(self, name):
+        return getattr(self._conn, name)
+
+    async def read_cache_async(self, *args, **kwargs):
+        await asyncio.sleep(self._delay_s)
+        return await self._conn.read_cache_async(*args, **kwargs)
+
+
+@pytest.fixture(scope="module")
+def params3():
+    return init_params(CFG3, jax.random.PRNGKey(1))
+
+
+def _harness3(conn, params3, model_id, regions=None):
+    """Three layers behind a slow store; ``regions=2`` leaves the prefetch
+    arena room for two staging regions of a 3-block hit, so layer 2 reads
+    into the region layer 0's install hands on."""
+    spec = CFG3.kv_spec(NUM_BLOCKS)
+    kvc = KVConnector(SlowReads(conn), spec, model_id, max_blocks=MAX_REQ_BLOCKS)
+    if regions is not None:
+        kvc._prefetch_pool = HostStagingPool(
+            regions * 2 * 3 * spec.block_nbytes, spec.block_nbytes, conn=kvc.conn
+        )
+    return ContinuousBatchingHarness(
+        EngineKVAdapter(kvc), params3, CFG3, NUM_BLOCKS, MAX_REQ_BLOCKS
+    )
+
+
+def _stamp(span, name):
+    (t,) = [t for stage, t in span["stages"] if stage == name]
+    return t
+
+
+def _trace(rec, stats):
+    spans = [s for s in rec.snapshot() if s["trace_id"] == stats.trace_id]
+    (root,) = [s for s in spans if s["name"] == "engine_request"]
+    return spans, root
+
+
+def test_hit_over_two_regions_is_cut_where_it_waits(conn, params3, traced):
+    h = _harness3(conn, params3, f"hop-2r-{conn.shm_active}", regions=2)
+    t_before = time.perf_counter()
+    miss, hit = _miss_then_hit(h, _prompt(11))
+    wall_us = (time.perf_counter() - t_before) * 1e6
+    assert hit.loaded_blocks == 3 and hit.computed_blocks == 0
+    spans, root = _trace(traced, hit)
+    assert all(s["status"] == "ok" for s in spans)
+
+    # Admission in order: probe, alloc, the wait for the store, gate, install.
+    order = ["enqueue", "fetch_start", "alloc_done", "primed", "install"]
+    assert [n for n, _ in root["stages"]] == order
+    assert [t for _, t in root["stages"]] == sorted(t for _, t in root["stages"])
+    _, miss_root = _trace(traced, miss)
+    assert [n for n, _ in miss_root["stages"]] == ["enqueue", "alloc_done"]
+
+    # One fetch_layer a layer, under the request; the store's op stamps the
+    # layer that asked, and the request's own span carries none of it.
+    layers = sorted(
+        (s for s in spans if s["name"] == "fetch_layer"), key=lambda s: s["attrs"]["layer"]
+    )
+    assert [s["attrs"]["layer"] for s in layers] == [0, 1, 2]
+    assert [s["attrs"]["region"] for s in layers] == [0, 1, 0]
+    value = CFG3.kv_spec(NUM_BLOCKS).block_nbytes
+    for s in layers:
+        assert s["parent_id"] == root["span_id"]
+        assert s["attrs"]["values"] == 6 and s["attrs"]["bytes"] == 6 * value
+        assert _stamp(s, "queued") <= _stamp(s, "region_free") <= _stamp(s, "landed")
+        assert _stamp(s, "landed") - _stamp(s, "region_free") >= READ_DELAY_S * 1e6 * 0.9
+        assert "coalesce" in [n for n, _ in s["stages"]]
+    submitted = [s for s in layers if "submit" in [n for n, _ in s["stages"]]]
+    assert submitted and all(s["attrs"]["op"] == "read_cache" for s in submitted)
+    assert not {"submit", "coalesce", "completion_ring"} & {n for n, _ in root["stages"]}
+    assert "op" not in root["attrs"]
+
+    # The install: an upload a layer, and the gate waited for the network
+    # exactly where a layer had not landed (layer 2, behind layer 0's region).
+    (inst,) = [s for s in spans if s["name"] == "install"]
+    uploads = sorted(
+        (s for s in spans if s["name"] == "install_upload"), key=lambda s: s["attrs"]["layer"]
+    )
+    assert [u["attrs"]["layer"] for u in uploads] == [0, 1, 2]
+    for u in uploads:
+        assert u["parent_id"] == inst["span_id"] and not u["attrs"]["fused"]
+        assert u["attrs"]["bytes"] == 6 * value
+        assert u["start_us"] <= _stamp(u, "started") <= _stamp(u, "h2d") <= u["end_us"]
+        ((name, t0, t1),) = u["attrs"]["device_calls"]
+        assert name == "its.install" and _stamp(u, "started") <= t0 <= t1 <= u["end_us"]
+    assert _stamp(layers[2], "region_free") >= uploads[0]["end_us"]
+    assert _stamp(layers[2], "region_free") - _stamp(layers[2], "queued") >= READ_DELAY_S * 1e6 * 0.9
+    for early in layers[:2]:  # a region of their own from the start
+        assert _stamp(early, "region_free") - _stamp(early, "queued") < READ_DELAY_S * 1e6 * 0.5
+    (wait,) = [s for s in spans if s["name"] == "install_staged_wait"]
+    assert wait["parent_id"] == inst["span_id"] and wait["attrs"] == {"layer": 2}
+    assert wait["duration_us"] >= READ_DELAY_S * 1e6 * 0.25
+    assert uploads[1]["end_us"] <= wait["start_us"] <= wait["end_us"] <= uploads[2]["start_us"]
+    assert _stamp(root, "primed") >= _stamp(layers[1], "landed")
+
+    # The counters, beside the spans: what was read is what the hit fetched,
+    # and the reads' union is inside the wall time.
+    c = h.adapter.connector.hit_counters
+    assert c["hit_read_bytes"] == _hit_bytes(hit) == 18 * value
+    assert 2 * READ_DELAY_S * 1e6 * 0.9 <= c["hit_read_busy_us"] <= wall_us
+    assert c["install_upload_bytes"] == 18 * value and c["hit_reads_in_flight"] == 0
+    assert 0 < c["install_upload_us"] <= sum(u["duration_us"] for u in uploads) + 1000
+
+
+def test_no_staged_wait_where_every_layer_had_landed(conn, params3, traced):
+    h = _harness3(conn, params3, f"hop-fused-{conn.shm_active}")  # a region a layer
+    _, hit = _miss_then_hit(h, _prompt(12))
+    spans, root = _trace(traced, hit)
+    assert len([s for s in spans if s["name"] == "fetch_layer"]) == 3
+    assert not [s for s in spans if s["name"] == "install_staged_wait"]
+    (inst,) = [s for s in spans if s["name"] == "install"]
+    (upload,) = [s for s in spans if s["name"] == "install_upload"]  # the fused path: one
+    assert upload["parent_id"] == inst["span_id"]
+    assert upload["attrs"]["fused"] and upload["attrs"]["layers"] == 3
+    assert upload["attrs"]["bytes"] == h.adapter.connector.hit_counters["install_upload_bytes"]
+    assert [n for n, _ in upload["stages"]] == ["started", "h2d"]
+    assert [c[0] for c in upload["attrs"]["device_calls"]] == ["its.install"]
+
+
+@pytest.mark.parametrize("which", ["miss", "hit"])
+def test_seven_parts_account_for_prefix_ready(conn, params3, traced, which):
+    """What `prefix_ready_accounted_pct` sums (benchmarks/layer_metrics): the
+    probe, the alloc, the wait for the store, the expedited gate's wait, the
+    install's hold, the compute's gate wait and the compute."""
+    h = _harness3(conn, params3, f"hop-parts-{which}-{conn.shm_active}", regions=2)
+    prefill = h._prefill_full
+
+    def slow_prefill(*args):
+        # A miss is its prefill: give it a length beside which its probe's
+        # executor hop (enqueue -> pool_alloc: under no part, a millisecond
+        # or two unless the machine is loaded) stays small.
+        time.sleep(4 * READ_DELAY_S)
+        return prefill(*args)
+
+    h._prefill_full = slow_prefill
+    miss, hit = _miss_then_hit(h, _prompt(13))
+    stats = miss if which == "miss" else hit
+    spans, root = _trace(traced, stats)
+
+    def durations(name, **attrs):
+        return sum(
+            s["duration_us"] for s in spans
+            if s["name"] == name and all(s["attrs"].get(k) == v for k, v in attrs.items())
+        )
+
+    def between(a, b):
+        stamps = dict(root["stages"])
+        return stamps[b] - stamps[a] if a in stamps and b in stamps else 0
+
+    parts = {
+        "hit_probe": between("enqueue", "fetch_start"),
+        "alloc_wait": durations("pool_alloc"),
+        "hit_store_wait": between("alloc_done", "primed"),
+        "hit_gate_wait": durations("gate_wait", mode="expedite"),
+        "install_hold": durations("install"),
+        "ready_compute_gate_wait": durations("gate_wait", mode="exclusive"),
+        "ready_compute": durations("compute"),
+    }
+    if which == "miss":
+        assert not parts["hit_probe"] and not parts["hit_store_wait"] and not parts["install_hold"]
+        assert parts["ready_compute"] >= 4 * READ_DELAY_S * 1e6
+    else:
+        assert parts["hit_store_wait"] and parts["install_hold"] and not parts["ready_compute"]
+    assert 0.9 <= sum(parts.values()) / stats.prefix_ready_us <= 1.001, (parts, stats.prefix_ready_us)
+
+
+def test_save_layers_and_their_d2h_waits(conn, params3, traced):
+    h = _harness3(conn, params3, f"hop-save-{conn.shm_active}")
+    miss = asyncio.run(asyncio.wait_for(h.run_request(_prompt(14), gen_tokens=GEN), 60))
+    spans, _ = _trace(traced, miss)
+    (io,) = [s for s in spans if s["name"] == "save_io"]
+    layers = [s for s in spans if s["name"] == "save_layer"]
+    value = CFG3.kv_spec(NUM_BLOCKS).block_nbytes
+    assert all(s["parent_id"] == io["span_id"] and s["status"] == "ok" for s in layers)
+    # Layer 0 holds the sentinel keys: gathered last, acknowledged last.
+    assert [s["attrs"]["layer"] for s in sorted(layers, key=lambda s: s["start_us"])] == [1, 2, 0]
+    assert max(layers, key=lambda s: s["end_us"])["attrs"]["layer"] == 0
+    deeper_acked = max(s["end_us"] for s in layers if s["attrs"]["layer"])
+    for s in layers:
+        assert s["attrs"]["bytes"] == 6 * value and io["start_us"] <= s["start_us"] <= s["end_us"] <= io["end_us"]
+        (wait,) = [w for w in spans if w["parent_id"] == s["span_id"]]
+        assert wait["name"] == "save_d2h_wait" and s["start_us"] <= wait["start_us"] <= wait["end_us"] <= s["end_us"]
+        ((name, t0, t1),) = wait["attrs"]["device_calls"]
+        assert name == "its.save_d2h" and wait["start_us"] <= t0 <= t1 <= wait["end_us"]
+        if s["attrs"]["layer"] == 0:  # the barrier: every deeper layer committed first
+            assert wait["start_us"] >= deeper_acked
+    # The write ops still stamp the save_io they run under (test_request_span_tree).
+    assert io["attrs"]["op"] == "write_cache"
+    c = h.adapter.connector.hit_counters
+    assert c["save_d2h_bytes"] == 18 * value
+    waits = sum(w["duration_us"] for w in spans if w["name"] == "save_d2h_wait")
+    assert 0 < c["save_d2h_wait_us"] <= waits + 1000
 
 
 def test_gate_wait_spans_under_contention(traced):
