@@ -1701,7 +1701,7 @@ def _tpu_connector_gbps(its, np, conn):
     import jax.numpy as jnp
 
     from infinistore_tpu.connector import KVConnector
-    from infinistore_tpu.tpu.layerwise import _device_put_copies
+    from infinistore_tpu.tpu.layerwise import BG_D2H_AHEAD, _device_put_copies
     from infinistore_tpu.tpu.paged import PagedKVCacheSpec, gather_blocks, scatter_blocks
     from infinistore_tpu.tpu.staging import StagedTransfer
 
@@ -1729,7 +1729,7 @@ def _tpu_connector_gbps(its, np, conn):
     ids = np.arange(n_blocks, dtype=np.int32)
     ids_dev = jnp.asarray(ids)
     nbytes = 2 * spec.num_layers * n_blocks * spec.block_nbytes
-    d2h_window = kvc._writer.d2h_window
+    d2h_window = BG_D2H_AHEAD
 
     def d2h_stage_once() -> float:
         """The writer's device stage, verbatim (layerwise.py write): gather,

@@ -2114,7 +2114,11 @@ class ClusterKVConnector:
         ]
         if not candidates:
             return 0
-        self._qos["bg_ops"] += 1
+        # The class the first copy goes out at: the caller's bound cell
+        # (the engine's awaited saves), else the members' BACKGROUND default.
+        cell = wire.SAVE_CLASS.get()
+        awaited = cell is not None and cell["value"] == wire.PRIORITY_FOREGROUND
+        self._qos["fg_ops" if awaited else "bg_ops"] += 1
         tspan = tracing.active_span()
         if tspan is not None:
             tspan.annotate(cluster_replicas=list(candidates))
@@ -2125,6 +2129,9 @@ class ClusterKVConnector:
         for i in candidates:
             if await self._begin_async(i) is None:
                 continue
+            # A copy past the first is the replication mirror: BACKGROUND
+            # whatever the caller bound (unbound, the member's own default).
+            bound = wire.SAVE_CLASS.set(None) if served else None
             try:
                 n = await self.members[i].save(
                     token_ids, caches, block_ids, first_block=first_block
@@ -2136,6 +2143,9 @@ class ClusterKVConnector:
             except BaseException:
                 self._done(i, None)  # see _read_failover: never wedge a probe
                 raise
+            finally:
+                if bound is not None:
+                    wire.SAVE_CLASS.reset(bound)
             self._done(i, None)
             served += 1
             served_ids.append(self.member_ids[i])

@@ -336,14 +336,20 @@ class KVConnector:
         # flight (with the gauge and the perf_counter mark that union is
         # kept by). Of the installs: bytes handed to the device and the
         # summed time of the executor calls that handed them (host time, not
-        # the DMA's end). Of the saves: bytes whose D2H the writer waited for
-        # on the event loop, and those waits.
+        # the DMA's end). Of the saves: bytes whose D2H the writer waited
+        # for, and those waits; put calls submitted and those of them
+        # untagged (foreground); writes whose class was flipped to foreground
+        # with layers still unsent; writes that STARTED foreground, and over
+        # those the rounds of put latency they took (submissions that
+        # followed a wait for an earlier group, plus one a write).
         self.hit_counters = {
             "hit_values_fetched": 0, "hit_values_whole_prefix": 0,
             "hit_read_bytes": 0, "hit_read_busy_us": 0.0,
             "hit_reads_in_flight": 0, "hit_read_busy_mark_s": 0.0,
             "install_upload_bytes": 0, "install_upload_us": 0.0,
             "save_d2h_bytes": 0, "save_d2h_wait_us": 0.0,
+            "save_puts": 0, "save_fg_puts": 0, "save_promotions": 0,
+            "save_fg_writes": 0, "save_fg_rounds": 0,
         }
         if conn is None:
             # Pure-ICI connector: no store data plane, so don't allocate the
@@ -472,7 +478,7 @@ class KVConnector:
 
     async def save(
         self, token_ids, caches, block_ids: np.ndarray, first_block: int = 0,
-        priority: int = wire.PRIORITY_BACKGROUND,
+        priority: Optional[int] = None,
     ) -> int:
         """Stream the request's KV blocks to the store. ``block_ids[i]`` is
         the engine's physical block holding logical block ``first_block + i``
@@ -484,7 +490,13 @@ class KVConnector:
         own request too since PR 25: ``run_request`` runs this write
         beside the request's generation, not ahead of its first token). Pass
         ``priority=wire.PRIORITY_FOREGROUND`` to opt a save out (e.g. a
-        handoff the consumer is already waiting on).
+        handoff the consumer is already waiting on): an explicit
+        ``priority`` is the whole write's class. Left out, a caller that
+        knows whether anyone is BLOCKED on this save, and may come to be
+        while it runs, can have bound a class cell in ``wire.SAVE_CLASS``
+        (the engine's ``run_request``: the answer's save foreground, the
+        prompt's write promoted at its join), which the writer reads per
+        layer; with neither, BACKGROUND.
 
         ``first_block`` serves sharded producers: under sequence-parallel
         prefill (models/long_context.py) each host holds only its chunk's
@@ -502,9 +514,13 @@ class KVConnector:
         n = min(len(chains), len(block_ids))
         if n == 0:
             return 0
+        cell = None
+        if priority is None:
+            cell = wire.SAVE_CLASS.get()
+            priority = wire.PRIORITY_BACKGROUND
         return await self._writer.write(
             caches, np.asarray(block_ids[:n]), self._key_fn(chains),
-            priority=priority,
+            priority=priority, priority_cell=cell,
         )
 
     async def load(
@@ -949,8 +965,11 @@ class KVConnector:
         and ``hit_read_busy_mark_s`` keep it), the store's delivered rate;
         ``install_upload_bytes`` over ``install_upload_us``, the host's rate
         of handing a hit's bytes to the device; ``save_d2h_bytes`` over
-        ``save_d2h_wait_us``, what a save's D2H waits on the event loop
-        delivered."""
+        ``save_d2h_wait_us``, what a save's D2H waits delivered;
+        ``save_fg_puts`` of ``save_puts`` put calls went out untagged,
+        ``save_promotions`` writes were promoted with layers still unsent,
+        and ``save_fg_rounds`` over ``save_fg_writes`` is the rounds of put
+        latency a write that started foreground took."""
         self._require_store("get_stats")
         return {**self.conn.get_stats(), **self.hit_counters}
 

@@ -61,7 +61,7 @@ from .models.serving import WaveLayout, pack_wave, verify_step_ragged
 from .tpu.paged import gather_blocks
 from .tpu.paged_attention import build_ragged_wave
 from .tpu.staging import StagingPoolExhausted
-from .wire import PRIORITY_BACKGROUND
+from .wire import PRIORITY_BACKGROUND, PRIORITY_FOREGROUND, SAVE_CLASS
 
 
 class WaveCounters:
@@ -1119,8 +1119,14 @@ class RequestStats:
     # generated. save_tail_us: how long the request, its last token out,
     # still waited for the write's acknowledgement (0.0 when it had
     # arrived). Both 0.0 where nothing was computed or nothing generated.
+    # save_tail_us is the PROMPT write's tail alone; ack_tail_us is the
+    # whole: `_generate`'s return to the last acknowledgement (the
+    # `generated` and `acknowledged` stamps of `engine_request`), so the
+    # prompt write's tail and the answer's snapshot and write (0.0 when
+    # gen_tokens == 0).
     save_overlap_us: float = 0.0
     save_tail_us: float = 0.0
+    ack_tail_us: float = 0.0
 
 
 class ContinuousBatchingHarness:
@@ -1354,21 +1360,33 @@ class ContinuousBatchingHarness:
                 return await asyncio.get_running_loop().run_in_executor(None, snap)
 
     async def _write_snapshot(
-        self, chain_ids, snapshot, first_block: int, overlaps_generate: bool = False
+        self, chain_ids, snapshot, first_block: int,
+        save_class: Optional[dict] = None,
     ):
         """A save's second phase: stream a snapshot's blocks to the store
         with NO gate held, keyed by ``chain_ids`` from logical block
         ``first_block`` on. Returns the perf_counter readings of its start
         and its end (the store's acknowledgement).
 
+        ``save_class``: the write's QoS class cell (``wire.SAVE_CLASS``,
+        bound here around the adapter call, whose signature carries no
+        class). None: the caller awaits this write in line, so it is
+        FOREGROUND from its first put. ``run_request`` hands the prompt
+        write it runs beside ``_generate`` a BACKGROUND cell and flips it at
+        the join.
+
         One ``save_io`` span, the whole ``adapter.save_kv`` await, with
         ``blocks``, ``before_first_token`` (false since PR 25: no first
         token waits for a store write) and ``overlaps_generate`` (true
         where ``run_request`` ran it as a task beside ``_generate``)."""
         n = len(snapshot[0][0])
+        overlaps_generate = save_class is not None
+        if save_class is None:
+            save_class = {"value": PRIORITY_FOREGROUND}
         t_start = time.perf_counter()
         self._saving += 1
         self.max_concurrent_saves = max(self.max_concurrent_saves, self._saving)
+        bound = SAVE_CLASS.set(save_class)
         try:
             with tracing.trace_op("save_io") as iospan:
                 await self.adapter.save_kv(
@@ -1386,6 +1404,7 @@ class ContinuousBatchingHarness:
                         overlaps_generate=overlaps_generate,
                     )
         finally:
+            SAVE_CLASS.reset(bound)
             self._saving -= 1
         return t_start, time.perf_counter()
 
@@ -1781,9 +1800,12 @@ class ContinuousBatchingHarness:
                     snapshot = await self._snapshot_blocks(
                         computed, before_first_token=True
                     )
+                    # BACKGROUND while it overlaps generation (it must not
+                    # delay decode-blocking reads), promoted at the join.
+                    prompt_class = {"value": PRIORITY_BACKGROUND}
                     prompt_write = asyncio.ensure_future(
                         self._write_snapshot(
-                            token_ids, snapshot, loaded_blocks, overlaps_generate=True
+                            token_ids, snapshot, loaded_blocks, save_class=prompt_class
                         )
                     )
                     del snapshot  # the write's alone: HBM it frees when acknowledged
@@ -1791,19 +1813,23 @@ class ContinuousBatchingHarness:
                 else:
                     await self._save_blocks(token_ids, computed, loaded_blocks)
             generated = None
-            ttft_us = save_overlap_us = save_tail_us = 0.0
+            ttft_us = save_overlap_us = save_tail_us = ack_tail_us = 0.0
             token_emit_s: List[float] = []
             if gen_tokens:
                 generated, first_token_t, token_emit_s = await self._generate(
                     token_ids, table, gen_tokens, priority=priority
                 )
+                t_generated = time.perf_counter()
+                if rspan is not None:
+                    rspan.stage("generated")
                 if first_token_t is not None:
                     ttft_us = (first_token_t - t0) * 1e6
                 if prompt_write is not None:
                     # Join: the prompt's blocks commit before the blocks
                     # whose chain extends them, and a failed write fails
-                    # the request here.
-                    t_generated = time.perf_counter()
+                    # the request here. From here the request is BLOCKED on
+                    # the write: its unsent layers go out foreground.
+                    prompt_class["value"] = PRIORITY_FOREGROUND
                     write_start, write_end = await prompt_write
                     save_overlap_us = (min(write_end, t_generated) - write_start) * 1e6
                     save_tail_us = max(write_end - t_generated, 0.0) * 1e6
@@ -1818,6 +1844,13 @@ class ContinuousBatchingHarness:
                     await self._save_blocks(
                         full_ids, table[n_blocks:full_blocks], n_blocks
                     )
+                # Every block this request computed is acknowledged (the
+                # attr: a span without it, a failed request's or an older
+                # tree's, has no tail to read).
+                if rspan is not None:
+                    rspan.stage("acknowledged")
+                    rspan.annotate(acknowledged=True)
+                ack_tail_us = (time.perf_counter() - t_generated) * 1e6
             stats = RequestStats(
                 tokens=len(token_ids),
                 hit_blocks=hit_tokens // bt,
@@ -1845,6 +1878,7 @@ class ContinuousBatchingHarness:
                 trace_id=rspan.trace_id if rspan is not None else 0,
                 save_overlap_us=save_overlap_us,
                 save_tail_us=save_tail_us,
+                ack_tail_us=ack_tail_us,
             )
             self.stats.append(stats)
             return stats

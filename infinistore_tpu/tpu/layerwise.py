@@ -6,7 +6,7 @@ is how it keeps prefill network overhead "no more than 1%"
 (reference docs/source/design.rst:54-63; the benchmark models it as
 --steps "layers", benchmark.py:188-193). Here the overlap is two-level:
 device->host copies (async, overlap with TPU compute) and network puts
-(async, up to ``depth`` layers in flight) are pipelined, and the writer ships
+(async, a window of layers in flight) are pipelined, and the writer ships
 directly from jax's D2H buffers — zero staging copies (see staging.py).
 
 Key naming follows the reference's convention of hash-chain keys per block
@@ -32,6 +32,24 @@ from .paged import PagedKVCacheSpec, gather_blocks, scatter_blocks
 from .staging import HostStagingPool
 
 KeyFn = Callable[[int, str, int], str]  # (layer, "k"|"v", block_index) -> key
+
+# What a write keeps in flight. BACKGROUND (nobody waits for it):
+# BG_PUT_GROUPS layers' puts, with BG_D2H_AHEAD layers gathered and their D2H
+# started ahead of them (device memory: 2 x n x block_nbytes a layer): the
+# bound on background bytes in flight of docs/qos.md. FOREGROUND (its caller
+# awaits the acknowledgement): as many layers' gathers, D2H and puts as fit
+# in FG_WINDOW_BYTES, never fewer than BG_PUT_GROUPS. An answer's save
+# (0.6-15 MiB over all its layers) fits whole; a promoted prompt write's
+# remainder (layers of 5-34 MiB) gets two to six layers.
+BG_PUT_GROUPS = 2
+BG_D2H_AHEAD = 4
+FG_WINDOW_BYTES = 32 << 20
+# A layer's D2H wait stands on the event loop up to this weight and moves to
+# an executor thread above it. An answer's or a question's layer (0.1-1 MiB)
+# lands in 0.2-0.4 ms, less than the thread hop costs (about 3 ms a hop on
+# the chip's host, PERF.md PR 40); a miss's layer (5-67 MiB) takes its D2H
+# and the host-side layout conversion, 2-30 ms in which no wave could flush.
+D2H_INLINE_BYTES = 2 << 20
 
 
 class PartialReadError(InfiniStoreException):
@@ -105,35 +123,41 @@ class LayerwiseKVWriter:
     Pipeline per layer: Pallas-gather blocks from the paged cache (device),
     pack K and V into one array, start ONE async D2H (the reader likewise
     uploads one packed span per layer), and ship previous layers' host
-    buffers on the network concurrently — up to ``depth`` layer-groups of
-    puts in flight. Puts go straight from jax's D2H buffer (registered for
+    buffers on the network concurrently — a window of layer-groups of puts
+    in flight (below). Puts go straight from jax's D2H buffer (registered for
     the op's lifetime), so the only host copy is the one into the server's
     pool.
 
     Tracing (docs/observability.md): under the caller's span (the engine's
     ``save_io``) one ``save_layer`` a layer, from its gather's dispatch to
     its two puts' acknowledgement, and under it ``save_d2h_wait``: the
-    synchronous wait for the layer's D2H, which stands on the caller's
-    EVENT LOOP (also the ``its.save_d2h`` device-call region). Always on:
+    wait for the layer's D2H (also the ``its.save_d2h`` device-call
+    region): on the caller's EVENT LOOP for a layer of at most
+    ``D2H_INLINE_BYTES``, in an executor thread above it, so that a miss's
+    layers do not stop the loop. Always on:
     ``counters`` (the connector's ledger, when it set one) gains the bytes
-    and the microseconds of those waits."""
+    and the microseconds of those waits, and the puts, rounds and
+    promotions of :meth:`write`.
+
+    Class and window follow whether the caller is blocked on the write
+    (docs/qos.md, "Producers"). BACKGROUND, nobody waits: ``BG_PUT_GROUPS``
+    groups of puts and ``BG_D2H_AHEAD`` staged layers, the bound on
+    background bytes in flight. FOREGROUND, the caller awaits the
+    acknowledgement: as many layers in flight as fit in ``FG_WINDOW_BYTES``
+    (never fewer than the background's), so a save lighter than the budget
+    submits every deeper layer at once and the sentinel after them: two
+    rounds of put latency, whatever the layer count."""
 
     def __init__(self, conn, pool: HostStagingPool, spec: PagedKVCacheSpec,
-                 max_blocks: int, depth: int = 2, d2h_window: int = 4):
-        if depth < 1 or d2h_window < 1:
-            raise ValueError("depth and d2h_window must be >= 1")
+                 max_blocks: int):
         self.conn = conn
         self.spec = spec
         # The writer ships straight from jax D2H buffers — the pool provides
         # only the connection to register them with; no slots are consumed.
         self.pool = pool
         self.max_blocks = max_blocks
-        self.depth = depth
-        # Layers of D2H kept in flight: device->host transfers pipeline, at
-        # a device-memory cost of 2 x n x block_nbytes per window entry.
-        self.d2h_window = d2h_window
-        # {"save_d2h_bytes", "save_d2h_wait_us"}: KVConnector shares its
-        # hit_counters here; a writer on its own counts nothing.
+        # The save keys of KVConnector.hit_counters, which the connector
+        # shares here; a writer on its own counts nothing.
         self.counters: Optional[dict] = None
 
     async def write(
@@ -142,11 +166,19 @@ class LayerwiseKVWriter:
         block_ids: np.ndarray,
         key_fn: KeyFn,
         priority: int = wire.PRIORITY_FOREGROUND,
+        priority_cell: Optional[dict] = None,
     ) -> int:
         """Returns total blocks written (K+V across layers). ``priority``:
         QoS class for the network puts — connectors tag whole-request saves
         BACKGROUND (prefill saves must not delay decode-blocking reads;
-        docs/qos.md) while the default stays untagged."""
+        docs/qos.md) while the default stays untagged.
+
+        ``priority_cell``: a mutable ``{"value": PRIORITY_*}`` in place of
+        ``priority``, read per LAYER (not captured once): the caller flips
+        it to FOREGROUND the moment it starts waiting for this write, and
+        the layers not yet submitted go out untagged and in the foreground
+        window; submissions in flight finish at their class
+        (``LayerwisePrefetch.promote``'s contract)."""
         n = len(block_ids)
         if n == 0:
             return 0
@@ -155,13 +187,31 @@ class LayerwiseKVWriter:
         ids_dev = jax.numpy.asarray(block_ids, dtype=jax.numpy.int32)
         pool = self.pool
         bn = self.spec.block_nbytes
+        loop = asyncio.get_running_loop()
+        pri_cell = priority_cell if priority_cell is not None else {"value": priority}
+        counters = self.counters
+        promoted = False
+        # Layers a foreground write keeps in flight (puts, and staged ahead).
+        layer_bytes = 2 * n * bn
+        fg_layers = max(BG_PUT_GROUPS, FG_WINDOW_BYTES // layer_bytes)
         # (futures, registered transfer, blocks count, `save_layer` span)
         # groups in flight.
         inflight: deque = deque()
         total = 0
+        # A drain that had to wait since the last submission: the next
+        # submission starts a new ROUND of put latency (`save_fg_rounds`).
+        waited = False
+
+        def foreground() -> bool:
+            return pri_cell["value"] == wire.PRIORITY_FOREGROUND
+
+        started_fg = foreground()
 
         async def drain_one() -> int:
+            nonlocal waited
             futs, tr, count, lspan = inflight.popleft()
+            if not all(f.done() for f in futs):
+                waited = True
             # Let BOTH puts settle before releasing the host buffers — a
             # failed K-batch must not free memory the V-batch's writev is
             # still streaming from — then surface the first failure.
@@ -182,13 +232,17 @@ class LayerwiseKVWriter:
         # layer did — a half-saved block then reads as absent, never as a
         # false hit.
         order = list(range(1, len(caches))) + [0] if len(caches) > 1 else [0]
-        # Stage ahead: gather + start async D2H for up to d2h_window layers
-        # before consuming the oldest — device->host transfers pipeline.
+        # Stage ahead: gather + start async D2H for up to BG_D2H_AHEAD
+        # layers (foreground: as many as the byte budget leaves beside the
+        # puts in flight, if that is more) before consuming the oldest —
+        # device->host transfers pipeline.
         staged: deque = deque()
         todo = iter(enumerate(order))
 
         def top_up():
-            while len(staged) < self.d2h_window:
+            while len(staged) < BG_D2H_AHEAD or (
+                foreground() and len(staged) + len(inflight) < fg_layers
+            ):
                 nxt = next(todo, None)
                 if nxt is None:
                     return
@@ -199,7 +253,7 @@ class LayerwiseKVWriter:
                 # the store's write ops keep stamping).
                 lspan = tracing.start_span("save_layer")
                 if lspan is not None:
-                    lspan.annotate(layer=layer, bytes=2 * n * bn)
+                    lspan.annotate(layer=layer, bytes=layer_bytes)
                 # K blocks then V blocks packed into ONE device array -> one
                 # D2H transfer per layer (the device-side concat is an HBM
                 # copy, trivial next to the host transfer it halves).
@@ -214,30 +268,61 @@ class LayerwiseKVWriter:
             top_up()
             while staged:
                 pos, layer, tr, lspan = staged[0]
-                # Keep at most depth-1 older put groups while this D2H lands.
-                while len(inflight) >= self.depth:
+                # Keep one put group fewer than the window in flight while
+                # this D2H lands.
+                while len(inflight) >= (fg_layers if foreground() else BG_PUT_GROUPS):
                     total += await drain_one()
                 if pos == len(order) - 1:
                     # Layer-0-last barrier: every deeper layer's put must have
                     # completed (= committed) before the sentinel ships.
                     while inflight:
                         total += await drain_one()
-                # `save_d2h_wait`: the loop stands still until the gather
-                # and its D2H have landed (wait() also registers the packed
-                # buffer) — no wave can flush meanwhile.
+                # `save_d2h_wait`: until the gather and its D2H have landed.
+                # A heavy layer's wait (with the host-side layout conversion
+                # under it, 2-4 GB/s: tens of ms for a miss's layer) goes to
+                # an executor thread, so that it does not pin the event
+                # loop, through which every wave flushes; the span is handed
+                # in, as to an install's upload. A light layer's is shorter
+                # than the hop and stays in line. wait() then only registers
+                # the packed buffer.
                 dspan = tracing.start_span("save_d2h_wait", parent=lspan)
                 t_wait = time.perf_counter()
-                with tracing.device_call("its.save_d2h", dspan):
-                    (kv_host,) = tr.wait()
-                if self.counters is not None:
-                    self.counters["save_d2h_bytes"] += kv_host.nbytes
-                    self.counters["save_d2h_wait_us"] += (
+
+                def landed(tr=tr, dspan=dspan):
+                    with tracing.device_call("its.save_d2h", dspan):
+                        tr.transfer.wait()
+
+                try:
+                    if layer_bytes > D2H_INLINE_BYTES:
+                        await loop.run_in_executor(None, landed)
+                    else:
+                        landed()
+                finally:
+                    if dspan is not None:
+                        dspan.finish()
+                (kv_host,) = tr.wait()
+                if counters is not None:
+                    counters["save_d2h_bytes"] += kv_host.nbytes
+                    counters["save_d2h_wait_us"] += (
                         time.perf_counter() - t_wait
                     ) * 1e6
-                if dspan is not None:
-                    dspan.finish()
                 base = kv_host.ctypes.data
-                pri_kw = wire.qos_kwargs(self.conn, priority)
+                # The class as it stands NOW: the caller may have started
+                # waiting (promoted the cell) since the last layer went out.
+                pri_kw = wire.qos_kwargs(self.conn, pri_cell["value"])
+                if counters is not None:
+                    counters["save_puts"] += 2
+                    if not pri_kw:
+                        counters["save_fg_puts"] += 2
+                    if started_fg:
+                        if pos == 0:
+                            counters["save_fg_writes"] += 1
+                        if pos == 0 or waited:
+                            counters["save_fg_rounds"] += 1
+                    elif foreground() and not promoted:
+                        promoted = True
+                        counters["save_promotions"] += 1
+                waited = False
                 futs = (
                     asyncio.ensure_future(self.conn.write_cache_async(
                         [(key_fn(layer, "k", i), i * bn) for i in range(n)],

@@ -48,6 +48,8 @@ schema and docs/observability.md to this tuple, in lockstep):
 - ``landed``          the layer's store read returned: its bytes sit staged
 - ``started``         an install's upload entered its executor thread (the hop)
 - ``h2d``             the upload's ``jax.device_put`` returned
+- ``generated``       the request's last token is on the host (``_generate`` returned)
+- ``acknowledged``    every block the request computed is written (its saves returned)
 - ``wave_enqueue``    a generation round handed its chunk to the wave decoder
 - ``wave_result``     the wave's future handed the round its logits rows
 - ``token``           the round's sampled token(s) reached the host
@@ -60,7 +62,8 @@ schema and docs/observability.md to this tuple, in lockstep):
 The first ten are one op's path through the store; the next seven cut the
 hop around it where the work happens (``engine_request``: ``alloc_done``,
 ``primed``; ``fetch_layer``: ``queued``, ``region_free``, ``landed``;
-``install_upload``: ``started``, ``h2d``); the last eight belong to the
+``install_upload``: ``started``, ``h2d``); the next two cut a request's
+tail off its ``engine_request``; the last eight belong to the
 engine's own spans (``generate`` stamps three per round, ``wave`` five per
 launch — see docs/observability.md for the span tree).
 
@@ -105,6 +108,8 @@ STAGES = (
     "landed",
     "started",
     "h2d",
+    "generated",
+    "acknowledged",
     "wave_enqueue",
     "wave_result",
     "token",

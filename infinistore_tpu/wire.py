@@ -6,9 +6,10 @@ tests that check the Python and C++ encoders agree byte-for-byte — coverage th
 reference lacks entirely (SURVEY.md §4: no protocol unit tests).
 """
 
+import contextvars
 import struct
 from dataclasses import dataclass, field
-from typing import List, Tuple
+from typing import List, Optional, Tuple
 
 MAGIC = 0x49545055  # "ITPU" little-endian
 MAX_BODY_SIZE = 4 << 20
@@ -189,6 +190,20 @@ def ring_ctrl_offset(fld: str) -> int:
 # explicit) and pre-QoS encoders never produce.
 PRIORITY_FOREGROUND = 0
 PRIORITY_BACKGROUND = 1
+
+# A save's class follows whether its caller is blocked on it (docs/qos.md,
+# "Producers"). The one caller that knows, the engine's ``run_request``,
+# binds a mutable cell ``{"value": PRIORITY_*}`` here around its adapter's
+# ``save_kv`` (whose signature carries no class and must not grow one:
+# adapters are subclassed); ``KVConnector.save`` hands the bound cell to the
+# layerwise writer, which reads it per layer, so flipping the cell promotes
+# the layers not yet submitted (``LayerwisePrefetch.promote``'s contract, on
+# the write side). A task copies its context at creation: a write started
+# as a task keeps the cell it was bound with. Unbound (None): the callee's
+# own default.
+SAVE_CLASS: contextvars.ContextVar[Optional[dict]] = contextvars.ContextVar(
+    "its_save_class", default=None
+)
 
 # End-to-end op tracing (docs/observability.md): a per-op trace context —
 # u64 trace id + u64 parent span id — rides BatchMeta/SegBatchMeta as a

@@ -12,6 +12,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from infinistore_tpu import wire
 from infinistore_tpu.connector import KVConnector
 from infinistore_tpu.engine import ContinuousBatchingHarness, EngineKVAdapter
 from infinistore_tpu.models import LlamaConfig, init_params
@@ -50,10 +51,13 @@ class ScriptedAdapter(EngineKVAdapter):
         self.tasks = []  # the task each save ran in
         self.saved = None  # host copy of the first save's snapshot
         self.events = []
+        self.classes = []  # per save: the class cell bound around it, its value at entry
 
     async def save_kv(self, token_ids, caches, block_table, first_block=0):
         self.calls += 1
         self.tasks.append(asyncio.current_task())
+        cell = wire.SAVE_CLASS.get()
+        self.classes.append((cell, cell["value"]))
         if self.calls > 1:
             return await super().save_kv(token_ids, caches, block_table, first_block=first_block)
         self.saved = [(np.asarray(k), np.asarray(v)) for k, v in caches]
@@ -236,7 +240,9 @@ def test_prefill_only_request_awaits_its_save_in_line(conn, params):
 
     task, stats = _run(drive())
     assert adapter.tasks == [task]  # no task of its own: awaited where it stood
+    assert adapter.classes[0][1] == wire.PRIORITY_FOREGROUND  # awaited: foreground from the first
     assert stats.generated is None and stats.save_overlap_us == stats.save_tail_us == 0.0
+    assert stats.ack_tail_us == 0.0
     assert h.metrics()["saves_overlapped"] == 0 and kvc.lookup(prompt) == PROMPT_BLOCKS
 
 
@@ -269,6 +275,7 @@ def test_overlap_and_tail_read_what_happened(conn, params, case):
 
     stats, whole_us = _run(drive())
     assert 0 < stats.save_overlap_us < whole_us
+    assert stats.save_tail_us <= stats.ack_tail_us < whole_us  # the whole tail holds the write's
     if held:
         # The request waited 50 ms and more for the acknowledgement.
         assert 0.04e6 < stats.save_tail_us < whole_us
@@ -277,6 +284,39 @@ def test_overlap_and_tail_read_what_happened(conn, params, case):
         assert stats.save_tail_us == 0.0
     m = h.metrics()
     assert m["saves_overlapped"] == 1 and m["max_concurrent_saves"] == 1
+
+
+@pytest.mark.parametrize("case", ["held_past_the_join", "acknowledged_before_it"])
+def test_class_cell_is_background_beside_generation_and_foreground_from_the_join(
+    conn, params, case
+):
+    held = case == "held_past_the_join"
+    h, adapter, kvc = _harness(conn, params, f"overlap-class-{case}-{conn.shm_active}", hold=held)
+    entries = _tap_step_chunk(h)
+    gen = CFG.block_tokens  # one whole answer block: a second, awaited save
+    prompt = _prompt(9)
+
+    async def drive():
+        task = asyncio.ensure_future(h.run_request(prompt, gen_tokens=gen))
+        await adapter.entered.wait()
+        cell = adapter.classes[0][0]
+        if held:
+            assert cell["value"] == wire.PRIORITY_BACKGROUND  # generating: gen rounds take longer
+            await _until(lambda: cell["value"] == wire.PRIORITY_FOREGROUND)  # the join
+            assert len(entries) >= gen and not task.done() and adapter.calls == 1
+            adapter.release.set()
+        stats = await task
+        assert wire.SAVE_CLASS.get() is None  # bound around the adapter call only
+        return stats
+
+    stats = _run(drive())
+    (prompt_cell, at_entry), (answer_cell, answer_at_entry) = adapter.classes
+    assert at_entry == wire.PRIORITY_BACKGROUND and answer_at_entry == wire.PRIORITY_FOREGROUND
+    assert prompt_cell is not answer_cell
+    assert prompt_cell["value"] == wire.PRIORITY_FOREGROUND  # flipped at the join, whoever won
+    assert adapter.tasks[0] is not adapter.tasks[1]  # the answer's save: in the request's task
+    assert stats.ack_tail_us >= stats.save_tail_us
+    assert kvc.lookup(prompt + stats.generated) == PROMPT_BLOCKS + 1
 
 
 def test_save_blocks_keeps_its_three_argument_form(conn, params):
@@ -292,3 +332,6 @@ def test_save_blocks_keeps_its_three_argument_form(conn, params):
     _run(drive())
     assert adapter.calls == 1 and kvc.lookup(prompt) == PROMPT_BLOCKS
     assert h.saves_overlapped == 0 and h._saving == 0
+    assert adapter.classes[0][1] == wire.PRIORITY_FOREGROUND  # awaited in line
+    stats = kvc.get_stats()
+    assert stats["save_fg_writes"] == 1 and stats["save_fg_puts"] == stats["save_puts"] == 4

@@ -275,12 +275,14 @@ def test_hit_over_two_regions_is_cut_where_it_waits(conn, params3, traced):
     spans, root = _trace(traced, hit)
     assert all(s["status"] == "ok" for s in spans)
 
-    # Admission in order: probe, alloc, the wait for the store, gate, install.
-    order = ["enqueue", "fetch_start", "alloc_done", "primed", "install"]
+    # Admission in order: probe, alloc, the wait for the store, gate, install;
+    # then the tail: last token on the host, every save acknowledged.
+    tail = ["generated", "acknowledged"]
+    order = ["enqueue", "fetch_start", "alloc_done", "primed", "install"] + tail
     assert [n for n, _ in root["stages"]] == order
     assert [t for _, t in root["stages"]] == sorted(t for _, t in root["stages"])
     _, miss_root = _trace(traced, miss)
-    assert [n for n, _ in miss_root["stages"]] == ["enqueue", "alloc_done"]
+    assert [n for n, _ in miss_root["stages"]] == ["enqueue", "alloc_done"] + tail
 
     # One fetch_layer a layer, under the request; the store's op stamps the
     # layer that asked, and the request's own span carries none of it.
