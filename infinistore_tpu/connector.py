@@ -18,7 +18,7 @@ block-aligned prefix — and the store's binary-search prefix match applies.
 
 import asyncio
 import hashlib
-from typing import List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -328,10 +328,13 @@ class KVConnector:
         self.max_blocks = max_blocks
         self.ici = ici
         # The hop's ledger (get_stats), always on. Of the hits' prefetches:
-        # store values (a K or a V of one block of one layer) fetched, and
+        # store values (one block of one tensor of one layer) fetched, and
         # what every block of every layer of the same hits would have been
-        # (they differ where the spec names sliding layers,
-        # PagedKVCacheSpec.hit_first_block); the bytes their layer reads
+        # (they differ where a tensor's policy is the hit's trailing blocks,
+        # CacheTensor.last_blocks), the same two in bytes and, of the bytes
+        # fetched, those of a recurrent state (kind "state": what does not
+        # grow with the prefix); of the saves, the bytes of state and of
+        # latent tensors written; the bytes their layer reads
         # landed and the microseconds in which at least one such read was in
         # flight (with the gauge and the perf_counter mark that union is
         # kept by). Of the installs: bytes handed to the device and the
@@ -344,6 +347,9 @@ class KVConnector:
         # followed a wait for an earlier group, plus one a write).
         self.hit_counters = {
             "hit_values_fetched": 0, "hit_values_whole_prefix": 0,
+            "hit_bytes_fetched": 0, "hit_bytes_whole_prefix": 0,
+            "hit_state_bytes_fetched": 0,
+            "save_state_bytes": 0, "save_latent_bytes": 0,
             "hit_read_bytes": 0, "hit_read_busy_us": 0.0,
             "hit_reads_in_flight": 0, "hit_read_busy_mark_s": 0.0,
             "install_upload_bytes": 0, "install_upload_us": 0.0,
@@ -362,7 +368,7 @@ class KVConnector:
                 # fetches and H2D uploads overlap several layers (layerwise.py
                 # _LayerRegions adapts the pipeline depth to this size).
                 pool = HostStagingPool(
-                    12 * max_blocks * spec.block_nbytes, spec.block_nbytes, conn=conn
+                    6 * spec.region_nbytes(max_blocks), spec.slot_nbytes, conn=conn
                 )
             self.pool = pool
             self._writer = LayerwiseKVWriter(conn, pool, spec, max_blocks)
@@ -373,7 +379,7 @@ class KVConnector:
         # speculative prefetches reserve from a separate arena. Lazy: only
         # engines on the pipelined path pay for it.
         self._prefetch_pool: Optional[HostStagingPool] = None
-        self._coalescer: Optional[FetchCoalescer] = None
+        self._coalescers: Dict[int, FetchCoalescer] = {}  # by value size
         # Chain-hash + sentinel-key caches: admission re-derives the same
         # prefix's keys on every lookup/load/save (satellite of the adaptive
         # data-plane PR; BENCH_r05 put the 256-chain lookup at 26.1us with
@@ -391,8 +397,16 @@ class KVConnector:
     # -- key scheme ----------------------------------------------------------
 
     def block_key(self, layer: int, kind: str, chain_hash: str) -> str:
-        """Store key for one block: ``{model}/L{layer}/{k|v}/{chain_hash}``."""
+        """Store key for one block of one tensor: ``{model}/L{layer}/{k|v}/
+        {chain_hash}``, ``kind`` the tensor's name (``CacheTensor.name``)."""
         return f"{self.model_id}/L{layer}/{kind}/{chain_hash}"
+
+    def _tensors(self):
+        """(layer, tensor) over the whole cache, the sentinel's first."""
+        return [
+            (layer, t) for layer in range(self.spec.num_layers)
+            for t in self.spec.layer_tensors(layer)
+        ]
 
     def _key_fn(self, chains: List[str]):
         def key_fn(layer: int, kind: str, block: int) -> str:
@@ -419,7 +433,8 @@ class KVConnector:
             c_chains, c_keys = cached
             if len(c_chains) >= n and c_chains[n - 1] == chains[-1]:
                 return c_keys[:n]
-        keys = [self.block_key(0, "k", c) for c in chains]
+        sentinel = self.spec.layer_tensors(0)[0].name  # "k" of a K/V cache
+        keys = [self.block_key(0, sentinel, c) for c in chains]
         self._keys0_cache = (list(chains), keys)
         return keys
 
@@ -435,14 +450,16 @@ class KVConnector:
         chains = self._chains(token_ids)
         if n_blocks is not None:
             chains = chains[:n_blocks]
-        keys = [
-            self.block_key(layer, kind, c)
-            for layer in range(self.spec.num_layers)
-            for kind in ("k", "v")
-            for c in chains
-            if (layer, kind) != (0, "k")
-        ] + [self.block_key(0, "k", c) for c in chains]
-        return [(self.spec.block_nbytes, keys)] if keys else []
+        (_, sentinel), *rest = self._tensors()
+        by_size: Dict[int, List[str]] = {}
+        for layer, t in rest:
+            by_size.setdefault(t.nbytes, []).extend(
+                self.block_key(layer, t.name, c) for c in chains
+            )
+        # The sentinel's size group goes last, the sentinels last in it.
+        last = by_size.pop(sentinel.nbytes, [])
+        by_size[sentinel.nbytes] = last + [self.block_key(0, sentinel.name, c) for c in chains]
+        return [(size, keys) for size, keys in by_size.items()] if chains else []
 
     # -- engine surface ------------------------------------------------------
 
@@ -673,16 +690,22 @@ class KVConnector:
             sliding, full = self.spec.hit_values(n)
             tspan.annotate(
                 hit_blocks=hit, fetch_blocks=n, values_window=sliding, values_full=full,
-                bytes_window=sliding * self.spec.block_nbytes,
-                bytes_full=full * self.spec.block_nbytes,
             )
+            if self.spec.uniform:
+                tspan.annotate(
+                    bytes_window=sliding * self.spec.block_nbytes,
+                    bytes_full=full * self.spec.block_nbytes,
+                )
+            else:
+                tspan.annotate(bytes=sum(
+                    self.spec.hit_nbytes(l, n) for l in range(self.spec.num_layers)
+                ))
         span = chains[first_block : first_block + n]
         # Mutable class cell so promote() upgrades LATER submissions even
         # on the coalescer path (the closure reads it per call).
         pri_cell = {"value": priority}
         if prefetch_pool is None and retry_missing_s <= 0 and fetch_gate is None:
-            coalescer = self._ensure_coalescer(pool)
-            submit = lambda blocks: coalescer.submit(
+            submit = lambda blocks, nbytes: self._ensure_coalescer(pool, nbytes).submit(
                 blocks, priority=pri_cell["value"]
             )
         else:
@@ -760,18 +783,27 @@ class KVConnector:
             # LayerwisePrefetch's default): enough for a concurrent
             # admission wave; an over-wave falls back to the gated load.
             regions = min(self.spec.num_layers, 8)
-            nbytes = 4 * regions * 2 * self.max_blocks * self.spec.block_nbytes
+            nbytes = 4 * regions * self.spec.region_nbytes(self.max_blocks)
             self._prefetch_pool = HostStagingPool(
-                nbytes, self.spec.block_nbytes, conn=self.conn
+                nbytes, self.spec.slot_nbytes, conn=self.conn
             )
         return self._prefetch_pool
 
-    def _ensure_coalescer(self, pool: HostStagingPool) -> FetchCoalescer:
-        if self._coalescer is None or self._coalescer.base_ptr != pool.base_ptr:
-            self._coalescer = FetchCoalescer(
-                self.conn, self.spec.block_nbytes, pool.base_ptr
-            )
-        return self._coalescer
+    def _ensure_coalescer(
+        self, pool: HostStagingPool, nbytes: Optional[int] = None
+    ) -> FetchCoalescer:
+        """The coalescer of store reads of ``nbytes``-byte values (a merged
+        call moves values of one size): of a K/V cache, the one."""
+        nbytes = self.spec.block_nbytes if nbytes is None else nbytes
+        held = self._coalescers.get(nbytes)
+        if held is None or held.base_ptr != pool.base_ptr:
+            held = self._coalescers[nbytes] = FetchCoalescer(self.conn, nbytes, pool.base_ptr)
+        return held
+
+    @property
+    def _coalescer(self) -> Optional[FetchCoalescer]:
+        """A K/V cache's one coalescer (None before the first prefetch)."""
+        return self._coalescers.get(self.spec.block_nbytes)
 
     def stage_layer_save(
         self, token_ids, layer: int, kv_pair, block_ids: np.ndarray,
@@ -978,10 +1010,5 @@ class KVConnector:
         the number of store keys deleted."""
         self._require_store("drop")
         chains = self._chains(token_ids)
-        keys = [
-            self.block_key(layer, kind, c)
-            for layer in range(self.spec.num_layers)
-            for kind in ("k", "v")
-            for c in chains
-        ]
+        keys = [self.block_key(layer, t.name, c) for layer, t in self._tensors() for c in chains]
         return self.conn.delete_keys(keys) if keys else 0

@@ -47,6 +47,7 @@ lost to eviction (the cache-semantics path: the engine just recomputes).
 
 import asyncio
 import collections
+import contextlib
 import time
 from contextlib import asynccontextmanager
 from dataclasses import dataclass, field
@@ -1184,6 +1185,9 @@ class ContinuousBatchingHarness:
         # its cache's shape come with the configuration: no model file is
         # named here.
         self.spec = config.kv_spec(num_blocks)
+        if self.spec.has_state and drafter is not None:
+            # A verified chunk's rejected rows would stay absorbed.
+            raise ValueError("a cache with a recurrent state decodes one token a row: no drafter")
         self.caches = self.spec.make_caches()
         self.pool = BlockPool(num_blocks)
         self.gate = DeviceGate()
@@ -1282,17 +1286,44 @@ class ContinuousBatchingHarness:
         pad[: len(table)] = table
         return pad
 
+    def _compute_by_blocks(self, token_ids, table: np.ndarray, start_block: int):
+        """A cache with a recurrent state (``PagedKVCacheSpec.has_state``):
+        ``token_ids`` from block ``start_block`` on, cut at block boundaries
+        through the model's resume step, one program a piece, so that every
+        block's slot holds the state at its end (what its save writes and a
+        later hit installs) and a miss runs the very programs a hit's resume
+        runs. Each piece that completes a block is a ``state_snapshot`` span
+        (``block``), a child of the caller's ``compute``. Returns at
+        DISPATCH. Cache-mutating: caller holds the exclusive gate."""
+        bt = self.config.block_tokens
+        padded = self._padded_table(table)
+        for start in range(start_block * bt, len(token_ids), bt):
+            piece = jnp.asarray(token_ids[start : start + bt], jnp.int32)
+            # A piece that completes its block leaves a snapshot a save can write.
+            whole = piece.shape[0] == bt
+            snapshot = tracing.trace_op("state_snapshot") if whole else contextlib.nullcontext()
+            with snapshot as span:
+                if span is not None:
+                    span.annotate(block=start // bt)
+                _, self.caches = self.config.steps.resume(
+                    self.params, piece, jnp.int32(start), self.caches, padded,
+                    self.config, self.max_req_blocks,
+                )
+
     def _prefill_full(self, token_ids, table: np.ndarray):
         """Whole-prompt prefill into this request's blocks (cache-mutating:
         caller holds the exclusive gate)."""
         t0 = time.perf_counter()
-        _, self.caches = self.config.steps.prefill(
-            self.params,
-            jnp.asarray(token_ids, dtype=jnp.int32),
-            self.caches,
-            jnp.asarray(table),
-            self.config,
-        )
+        if self.spec.has_state:
+            self._compute_by_blocks(token_ids, table, 0)
+        else:
+            _, self.caches = self.config.steps.prefill(
+                self.params,
+                jnp.asarray(token_ids, dtype=jnp.int32),
+                self.caches,
+                jnp.asarray(table),
+                self.config,
+            )
         jax.block_until_ready(self.caches[-1][0])
         # Calibrates recompute_saved_s: what one block of prefill costs
         # on this device. Min across calls — the first includes the jit
@@ -1310,12 +1341,15 @@ class ContinuousBatchingHarness:
         decode rows that each walk the padded table. Returns at DISPATCH;
         the device time is first waited for by whoever reads the cache next
         (the save's snapshot). Cache-mutating: caller holds the exclusive
-        gate."""
+        gate. A cache with a recurrent state takes the suffix a block at a
+        time (``_compute_by_blocks``)."""
         bt = self.config.block_tokens
-        suffix = jnp.asarray(token_ids[start_block * bt :], jnp.int32)
         self.resumes += 1
-        self.resume_tokens += int(suffix.shape[0])
+        self.resume_tokens += len(token_ids) - start_block * bt
         self.resume_pages += -(-len(token_ids) // bt)
+        if self.spec.has_state:
+            return self._compute_by_blocks(token_ids, table, start_block)
+        suffix = jnp.asarray(token_ids[start_block * bt :], jnp.int32)
         _, self.caches = self.config.steps.resume(
             self.params,
             suffix,
@@ -1348,8 +1382,8 @@ class ContinuousBatchingHarness:
                 def snap():
                     with tracing.device_call("its.save_snapshot", sspan):
                         s = [
-                            (gather_blocks(k, dev), gather_blocks(v, dev))
-                            for k, v in caches
+                            tuple(gather_blocks(t, dev) for t in layer)
+                            for layer in caches
                         ]
                         jax.block_until_ready(s)
                     return s
@@ -1520,7 +1554,7 @@ class ContinuousBatchingHarness:
         )
         ids = jnp.asarray(table)
         for layer in range(len(self.caches)):
-            for kind in (0, 1):
+            for kind in range(len(self.caches[layer])):
                 got = np.asarray(
                     gather_blocks(self.caches[layer][kind], ids), np.float32
                 )
@@ -1563,14 +1597,28 @@ class ContinuousBatchingHarness:
         skew-aware flush policy (docs/serving_load.md); the class is
         recorded on the stats so TTFT percentiles split by class."""
         bt = self.config.block_tokens
-        n_blocks = len(token_ids) // bt
-        total_blocks = -(-(n_blocks * bt + gen_tokens) // bt)
-        if n_blocks == 0 or total_blocks > self.max_req_blocks:
+        if self.spec.has_state:
+            # A recurrent state absorbs a token ONCE, and the first wave
+            # decodes the prompt's last token: the compute phase lands every
+            # token but that one (``landed``), so the prompt's complete
+            # blocks, which a save writes and a hit may install, are those
+            # of ``landed``; the prompt keeps a part-full last block.
+            token_ids = list(token_ids)
+            landed = token_ids[:-1]
+            n_blocks = len(landed) // bt
+            total_blocks = -(-(len(token_ids) + gen_tokens) // bt)
+            ok = len(token_ids) > 0
+        else:
+            n_blocks = len(token_ids) // bt
+            total_blocks = -(-(n_blocks * bt + gen_tokens) // bt)
+            token_ids = landed = list(token_ids)[: n_blocks * bt]
+            ok = n_blocks > 0
+        if not ok or total_blocks > self.max_req_blocks:
             raise ValueError(
                 f"prompt + generation must span 1..{self.max_req_blocks} "
-                "blocks (prompt in complete blocks)"
+                "blocks (prompt in complete blocks; with a recurrent state, any "
+                "prompt that generates)"
             )
-        token_ids = list(token_ids)[: n_blocks * bt]
         self.live += 1
         self.max_live = max(self.max_live, self.live)
         # Trace root for this request (docs/observability.md): `enqueue` is
@@ -1663,6 +1711,9 @@ class ContinuousBatchingHarness:
                 if promote is not None:
                     promote()
             prompt_table = table[:n_blocks]  # tail blocks (if any) are for generation
+            # The blocks the compute phase writes: the prompt's complete ones
+            # and, under a recurrent state, its part-full last.
+            landed_table = table[: -(-len(landed) // bt)]
             gate_hold_us = fetch_us = 0.0
             overlap = None
             if prefetch is not None:
@@ -1737,7 +1788,7 @@ class ContinuousBatchingHarness:
             admission_us = (time.perf_counter() - t0) * 1e6
             loaded_blocks = loaded_tokens // bt
             raced = hit_tokens > 0 and loaded_tokens == 0
-            if loaded_blocks < n_blocks:
+            if loaded_blocks * bt < len(landed):
                 # The compute phase's gate wait counts toward gate_stall
                 # too: misses never touch the gate at admission anymore, so
                 # without this their "queued behind other requests" signal
@@ -1763,7 +1814,7 @@ class ContinuousBatchingHarness:
                         if cspan is not None:
                             cspan.annotate(
                                 kind="prefill_full" if full else "chunked_resume",
-                                tokens=(n_blocks - loaded_blocks) * bt,
+                                tokens=len(landed) - loaded_blocks * bt,
                                 waits_for_device=full,
                             )
                             if not full:
@@ -1771,13 +1822,16 @@ class ContinuousBatchingHarness:
                                 cspan.annotate(pages=n_blocks)
 
                         def compute():
-                            with tracing.device_call("its.compute", cspan):
+                            # The thread does not inherit the span: bound
+                            # here, so that what the compute records itself
+                            # (`state_snapshot`) hangs under it.
+                            with tracing.use_span(cspan), tracing.device_call(
+                                "its.compute", cspan
+                            ):
                                 if full:
-                                    self._prefill_full(token_ids, prompt_table)
+                                    self._prefill_full(landed, landed_table)
                                 else:
-                                    self._chunked_resume(
-                                        token_ids, table, loaded_blocks
-                                    )
+                                    self._chunked_resume(landed, table, loaded_blocks)
 
                         await loop.run_in_executor(None, compute)
             prefix_ready_us = (time.perf_counter() - t0) * 1e6

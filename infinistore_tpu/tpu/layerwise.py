@@ -31,7 +31,84 @@ from ..lib import (
 from .paged import PagedKVCacheSpec, gather_blocks, scatter_blocks
 from .staging import HostStagingPool
 
-KeyFn = Callable[[int, str, int], str]  # (layer, "k"|"v", block_index) -> key
+KeyFn = Callable[[int, str, int], str]  # (layer, tensor name, block_index) -> key
+
+
+def _layer_plan(spec: PagedKVCacheSpec, layer: int, n: int, hit: bool):
+    """``[(tensor, first, m, offset)]`` for ``layer``'s tensors over an
+    ``n``-block span: tensor ``t`` moves its blocks ``first .. n - 1`` (``m``
+    of them: all ``n`` for a save, the hit policy's for a hit), packed one
+    tensor after the other from byte ``offset`` of the layer's region. A K/V
+    layer's is [K blocks | V blocks], as it always was."""
+    plan, off = [], 0
+    for t in spec.layer_tensors(layer):
+        first = t.hit_first(n) if hit else 0
+        plan.append((t, first, n - first, off))
+        off += (n - first) * t.nbytes
+    return plan
+
+
+def _plan_reads(plan, key_fn: KeyFn, layer: int, base: int):
+    """The store reads of one layer's hit: ``[(value bytes, [(key, offset),
+    ...])]``, one entry a value size (a store call moves values of one size):
+    ONE for a K/V layer, its K keys then its V keys."""
+    by_size = {}
+    for t, first, m, off in plan:
+        name, nbytes, at = t.name, t.nbytes, base + off
+        # A list, not a generator: a frame a value is the event loop's time.
+        by_size.setdefault(nbytes, []).extend(
+            [(key_fn(layer, name, first + i), at + i * nbytes) for i in range(m)]
+        )
+    return list(by_size.items())
+
+
+def _plan_views(plan, buf, base: int):
+    """Per tensor, the staged blocks as a host array ``[m, *block_shape]``:
+    zero-copy views of ``buf`` from byte ``base`` on."""
+    return [
+        buf[base + off : base + off + m * t.nbytes]
+        .view(np.dtype(jax.numpy.dtype(t.dtype)))
+        .reshape((m, *t.block_shape))
+        for t, _, m, off in plan
+    ]
+
+
+def _packs(plan) -> bool:
+    """Whether the layer's tensors ride ONE packed array (a K and a V: one
+    shape, one type, as many blocks each)."""
+    return len({(t.block_shape, jax.numpy.dtype(t.dtype), m) for t, _, m, _ in plan}) == 1
+
+
+def _plan_nbytes(plan) -> int:
+    return sum(m * t.nbytes for t, _, m, _ in plan)
+
+
+def _upload_and_scatter(plan, buf, base: int, tensors, ids_dev, uploaded=None):
+    """The layer's staged blocks (``buf`` from byte ``base``) -> device,
+    scattered into ``tensors`` (donated) at the plan's blocks. A K/V layer
+    uploads as ONE packed array and splits on the device; tensors of unlike
+    shapes go up side by side. ``uploaded()`` is called once ``device_put``
+    has returned, before the scatters. Returns (what was uploaded, the updated
+    tensors)."""
+    if _packs(plan):
+        t, _, m, _ = plan[0]
+        host = (
+            buf[base : base + _plan_nbytes(plan)]
+            .view(np.dtype(jax.numpy.dtype(t.dtype)))
+            .reshape((len(plan) * m, *t.block_shape))
+        )
+        up = jax.device_put(host)
+        parts = [up[i * m : (i + 1) * m] for i in range(len(plan))] if len(plan) > 1 else [up]
+    else:
+        up = parts = jax.device_put(_plan_views(plan, buf, base))
+    if uploaded is not None:
+        uploaded()
+    out = tuple(
+        scatter_blocks(cache, ids_dev[first:] if first else ids_dev, part)
+        for cache, part, (_, first, _, _) in zip(tensors, parts, plan)
+    )
+    return up, out
+
 
 # What a write keeps in flight. BACKGROUND (nobody waits for it):
 # BG_PUT_GROUPS layers' puts, with BG_D2H_AHEAD layers gathered and their D2H
@@ -91,7 +168,7 @@ class _LayerRegions:
     pipeline when the pool affords it."""
 
     def __init__(self, pool: HostStagingPool, spec: PagedKVCacheSpec, max_blocks: int):
-        if spec.block_nbytes > pool.block_size:
+        if spec.uniform and spec.block_nbytes > pool.block_size:
             raise ValueError(
                 f"staging pool block_size {pool.block_size} < KV block "
                 f"{spec.block_nbytes}"
@@ -99,22 +176,22 @@ class _LayerRegions:
         self.pool = pool
         self.spec = spec
         self.max_blocks = max_blocks
-        # count regions x (K + V) x max_blocks slots.
-        self.count = min(8, pool.num_slots // (2 * max_blocks))
+        # A region: (K + V) x max_blocks slots, or as many as hold the
+        # heaviest layer's hit where the layers' tensors differ.
+        self.slots = (
+            2 * max_blocks if spec.uniform
+            else -(-spec.region_nbytes(max_blocks) // pool.block_size)
+        )
+        self.count = min(8, pool.num_slots // self.slots)
         if self.count < 2:
             raise ValueError(
-                f"staging pool too small: need {4 * max_blocks} slots of "
+                f"staging pool too small: need {2 * self.slots} slots of "
                 f"{pool.block_size}B, have {pool.num_slots}"
             )
 
     def base_offset(self, region: int) -> int:
-        """Byte offset of a region's contiguous K+V span."""
-        return self.pool.slot_offset(region * 2 * self.max_blocks)
-
-    def kv_view(self, region: int, n: int, nbytes_per_block: int):
-        """Zero-copy view of the region's packed K+V span (2*n blocks)."""
-        off = self.base_offset(region)
-        return self.pool.buf[off : off + 2 * n * nbytes_per_block]
+        """Byte offset of a region's contiguous span."""
+        return self.pool.slot_offset(region * self.slots)
 
 
 class LayerwiseKVWriter:
@@ -186,14 +263,16 @@ class LayerwiseKVWriter:
             raise ValueError(f"{n} blocks > writer capacity {self.max_blocks}")
         ids_dev = jax.numpy.asarray(block_ids, dtype=jax.numpy.int32)
         pool = self.pool
-        bn = self.spec.block_nbytes
+        spec = self.spec
         loop = asyncio.get_running_loop()
         pri_cell = priority_cell if priority_cell is not None else {"value": priority}
         counters = self.counters
         promoted = False
-        # Layers a foreground write keeps in flight (puts, and staged ahead).
-        layer_bytes = 2 * n * bn
-        fg_layers = max(BG_PUT_GROUPS, FG_WINDOW_BYTES // layer_bytes)
+        # Every block saves every tensor of its layer, whatever a hit reads.
+        plans = [_layer_plan(spec, layer, n, hit=False) for layer in range(len(caches))]
+        # Layers a foreground write keeps in flight (puts, and staged ahead),
+        # at the heaviest layer's weight.
+        fg_layers = max(BG_PUT_GROUPS, FG_WINDOW_BYTES // max(_plan_nbytes(p) for p in plans))
         # (futures, registered transfer, blocks count, `save_layer` span)
         # groups in flight.
         inflight: deque = deque()
@@ -247,22 +326,20 @@ class LayerwiseKVWriter:
                 if nxt is None:
                     return
                 pos, layer = nxt
-                k_cache, v_cache = caches[layer]
                 # `save_layer`: this gather's dispatch to its puts' ack; a
                 # child of the caller's span (the engine's `save_io`, which
                 # the store's write ops keep stamping).
                 lspan = tracing.start_span("save_layer")
                 if lspan is not None:
-                    lspan.annotate(layer=layer, bytes=layer_bytes)
-                # K blocks then V blocks packed into ONE device array -> one
-                # D2H transfer per layer (the device-side concat is an HBM
-                # copy, trivial next to the host transfer it halves).
-                staged.append((pos, layer, pool.stage_out([
-                    jax.numpy.concatenate([
-                        gather_blocks(k_cache, ids_dev),
-                        gather_blocks(v_cache, ids_dev),
-                    ])
-                ]), lspan))
+                    lspan.annotate(layer=layer, bytes=_plan_nbytes(plans[layer]))
+                gathered = [gather_blocks(t, ids_dev) for t in caches[layer]]
+                if _packs(plans[layer]) and len(gathered) > 1:
+                    # K blocks then V blocks packed into ONE device array ->
+                    # one D2H transfer per layer (the device-side concat is an
+                    # HBM copy, trivial next to the host transfer it halves).
+                    # Tensors of unlike shapes go down side by side.
+                    gathered = [jax.numpy.concatenate(gathered)]
+                staged.append((pos, layer, pool.stage_out(gathered), lspan))
 
         try:
             top_up()
@@ -292,28 +369,37 @@ class LayerwiseKVWriter:
                     with tracing.device_call("its.save_d2h", dspan):
                         tr.transfer.wait()
 
+                plan = plans[layer]
                 try:
-                    if layer_bytes > D2H_INLINE_BYTES:
+                    if _plan_nbytes(plan) > D2H_INLINE_BYTES:
                         await loop.run_in_executor(None, landed)
                     else:
                         landed()
                 finally:
                     if dspan is not None:
                         dspan.finish()
-                (kv_host,) = tr.wait()
+                hosts = tr.wait()
                 if counters is not None:
-                    counters["save_d2h_bytes"] += kv_host.nbytes
+                    counters["save_d2h_bytes"] += sum(h.nbytes for h in hosts)
                     counters["save_d2h_wait_us"] += (
                         time.perf_counter() - t_wait
                     ) * 1e6
-                base = kv_host.ctypes.data
+                    for t, _, m, _ in plan:
+                        if t.kind != "kv":
+                            counters[f"save_{t.kind}_bytes"] += m * t.nbytes
+                # Where each tensor's blocks lie on the host: in the one
+                # packed array at its offset, or in an array of its own.
+                bases = (
+                    [hosts[0].ctypes.data + off for _, _, _, off in plan]
+                    if len(hosts) == 1 else [h.ctypes.data for h in hosts]
+                )
                 # The class as it stands NOW: the caller may have started
                 # waiting (promoted the cell) since the last layer went out.
                 pri_kw = wire.qos_kwargs(self.conn, pri_cell["value"])
                 if counters is not None:
-                    counters["save_puts"] += 2
+                    counters["save_puts"] += len(plan)
                     if not pri_kw:
-                        counters["save_fg_puts"] += 2
+                        counters["save_fg_puts"] += len(plan)
                     if started_fg:
                         if pos == 0:
                             counters["save_fg_writes"] += 1
@@ -323,16 +409,14 @@ class LayerwiseKVWriter:
                         promoted = True
                         counters["save_promotions"] += 1
                 waited = False
-                futs = (
+                futs = tuple(
                     asyncio.ensure_future(self.conn.write_cache_async(
-                        [(key_fn(layer, "k", i), i * bn) for i in range(n)],
-                        bn, base, **pri_kw)),
-                    asyncio.ensure_future(self.conn.write_cache_async(
-                        [(key_fn(layer, "v", i), i * bn) for i in range(n)],
-                        bn, base + n * bn, **pri_kw)),
+                        [(key_fn(layer, t.name, i), i * t.nbytes) for i in range(n)],
+                        t.nbytes, base, **pri_kw))
+                    for (t, _, _, _), base in zip(plan, bases)
                 )
                 staged.popleft()
-                inflight.append((futs, tr, 2 * n, lspan))
+                inflight.append((futs, tr, len(plan) * n, lspan))
                 top_up()  # refill the D2H pipeline before blocking again
             while inflight:
                 total += await drain_one()
@@ -398,30 +482,22 @@ class LayerwiseKVReader:
             raise ValueError(f"{n} blocks > reader capacity {self.regions.max_blocks}")
         ids_dev = jax.numpy.asarray(block_ids, dtype=jax.numpy.int32)
         pool = self.regions.pool
-        bn = self.spec.block_nbytes
-        dt = np.dtype(jax.numpy.dtype(self.spec.dtype))
 
-        # A sliding layer's hit is its last window / block_tokens blocks
-        # (PagedKVCacheSpec.hit_first_block); every other layer's is whole.
-        firsts = [self.spec.hit_first_block(layer, n) for layer in range(num_layers)]
+        # What a hit fetches of each layer's tensors: a sliding layer's last
+        # window / block_tokens blocks, a state's last block, else every block
+        # (CacheTensor.last_blocks).
+        plans = [_layer_plan(self.spec, layer, n, hit=True) for layer in range(num_layers)]
 
         def fetch(layer: int):
-            # K blocks then V blocks packed into one contiguous region span,
-            # so the layer later uploads as a single device transfer.
+            # The layer's tensors packed into one contiguous region span (K
+            # blocks then V blocks), so a K/V layer later uploads as a single
+            # device transfer; one store read a value size.
             base = self.regions.base_offset(layer % self.regions.count)
-            first = firsts[layer]
-            m = n - first
-            blocks = [
-                (key_fn(layer, "k", first + i), base + i * bn) for i in range(m)
-            ] + [
-                (key_fn(layer, "v", first + i), base + (m + i) * bn) for i in range(m)
-            ]
-            return asyncio.ensure_future(
-                self.conn.read_cache_async(
-                    blocks, bn, pool.base_ptr,
-                    **wire.qos_kwargs(self.conn, priority),
-                )
-            )
+            pri_kw = wire.qos_kwargs(self.conn, priority)
+            return asyncio.gather(*(
+                self.conn.read_cache_async(blocks, nbytes, pool.base_ptr, **pri_kw)
+                for nbytes, blocks in _plan_reads(plans[layer], key_fn, layer, base)
+            ))
 
         # Pipeline: with R regions, keep W = R-2 network fetches in flight
         # ahead of device consumption. A region is reused only once its
@@ -454,22 +530,10 @@ class LayerwiseKVReader:
                 start(f)
             for layer in range(num_layers):
                 await fetches.pop(layer)
-                region = layer % R
-                first = firsts[layer]
-                m = n - first
-                kv_host = (
-                    self.regions.kv_view(region, m, bn)
-                    .view(dt)
-                    .reshape((2 * m, *self.spec.block_shape))
-                )
-                # ONE H2D per layer (K and V ride together); split on device.
-                kv_dev = jax.device_put(kv_host)
-                uploads[layer] = kv_dev
-                k_cache, v_cache = out[layer]
-                ids = ids_dev[first:] if first else ids_dev
-                out[layer] = (
-                    scatter_blocks(k_cache, ids, kv_dev[:m]),
-                    scatter_blocks(v_cache, ids, kv_dev[m:]),
+                # ONE H2D per K/V layer (K and V ride together); split on device.
+                uploads[layer], out[layer] = _upload_and_scatter(
+                    plans[layer], pool.buf, self.regions.base_offset(layer % R),
+                    out[layer], ids_dev,
                 )
                 if on_layer is not None:
                     on_layer(layer, out[layer])
@@ -592,10 +656,11 @@ class LayerwisePrefetch:
         self.n_blocks = n_blocks
         self.num_layers = num_layers
         self.hit_blocks = n_blocks  # overridden by the connector's lookup
-        # Per layer, the first block of the prefix this hit fetches and
-        # installs: 0, or for a sliding layer the first of its last
-        # window / block_tokens blocks (PagedKVCacheSpec.hit_first_block).
-        self._first = [spec.hit_first_block(l, n_blocks) for l in range(num_layers)]
+        # Per layer, what this hit fetches and installs of each tensor, and
+        # where it lies in the layer's region: every block, a sliding layer's
+        # last window / block_tokens blocks, a state's last block
+        # (CacheTensor.last_blocks).
+        self._plans = [_layer_plan(spec, l, n_blocks, hit=True) for l in range(num_layers)]
         self._counters = counters
         # QoS class cell read per submission (not captured once): promote()
         # flips it when the request is ADMITTED — a speculative background
@@ -629,10 +694,9 @@ class LayerwisePrefetch:
             self._drained.set()
             self.fetch_finished_s = self.fetch_started_s
             return
-        bn = spec.block_nbytes
         # Region stride in whole pool slots (a region is one contiguous
-        # [K | V] span of 2*n_blocks KV blocks).
-        self._region_bytes = 2 * n_blocks * bn
+        # [K | V] span of 2*n_blocks KV blocks, or the heaviest layer's hit).
+        self._region_bytes = spec.region_nbytes(n_blocks)
         slots_per_region = -(-self._region_bytes // pool.block_size)
         self._region_stride = slots_per_region * pool.block_size
         want = min(num_layers, 8) if regions is None else regions
@@ -651,8 +715,8 @@ class LayerwisePrefetch:
         self._lease = lease
         pri_cell = self._pri_cell  # closure reads the LIVE class (promote())
         self._submit = submit or (
-            lambda blocks: conn.read_cache_async(
-                blocks, bn, pool.base_ptr,
+            lambda blocks, nbytes: conn.read_cache_async(
+                blocks, nbytes, pool.base_ptr,
                 **wire.qos_kwargs(conn, pri_cell["value"]),
             )
         )
@@ -682,9 +746,8 @@ class LayerwisePrefetch:
         return self._lease.offset + (layer % self.regions) * self._region_stride
 
     async def _fetch_layer(self, layer: int):
-        n, bn = self.n_blocks, self.spec.block_nbytes
-        first = self._first[layer]
-        m = n - first
+        plan = self._plans[layer]
+        values, nbytes = sum(m for _, _, m, _ in plan), _plan_nbytes(plan)
         counters = self._counters
         # `fetch_layer`: one span a layer of a hit, a child of whatever span
         # started the prefetch (the engine's `engine_request`) and the
@@ -698,7 +761,7 @@ class LayerwisePrefetch:
             if span is not None:
                 span.annotate(
                     layer=layer, region=layer % self.regions,
-                    values=2 * m, bytes=2 * m * bn,
+                    values=values, bytes=nbytes, kind=plan[0][0].kind,
                 )
             if self._fetch_gate is not None:
                 # Announce-driven handoff: wait for the producer's per-layer
@@ -714,16 +777,17 @@ class LayerwisePrefetch:
                 if span is not None:
                     span.finish(status="cancelled")
                 return
-            base = self._region_offset(layer)
-            blocks = [
-                (self._key_fn(layer, "k", first + i), base + i * bn) for i in range(m)
-            ] + [
-                (self._key_fn(layer, "v", first + i), base + (m + i) * bn) for i in range(m)
-            ]
+            reads = _plan_reads(plan, self._key_fn, layer, self._region_offset(layer))
             if counters is not None:
                 _hit_reads_step(counters, +1)
             try:
-                await self._submit_with_retry(blocks)
+                # One store read a value size: ONE for a K/V layer.
+                if len(reads) == 1:
+                    await self._submit_with_retry(reads[0][1], reads[0][0])
+                else:
+                    await asyncio.gather(*(
+                        self._submit_with_retry(blocks, size) for size, blocks in reads
+                    ))
             except asyncio.CancelledError:
                 self._cancel_rest()
                 raise
@@ -743,29 +807,35 @@ class LayerwisePrefetch:
                     _hit_reads_step(counters, -1)
             if span is not None:
                 span.stage("landed")
-            self.blocks_fetched += 2 * m
+            self.blocks_fetched += values
             if counters is not None:
-                counters["hit_values_fetched"] += 2 * m
-                counters["hit_values_whole_prefix"] += 2 * n
-                counters["hit_read_bytes"] += 2 * m * bn
+                whole = self.n_blocks * sum(t.nbytes for t, _, _, _ in plan)
+                counters["hit_values_fetched"] += values
+                counters["hit_values_whole_prefix"] += self.n_blocks * len(plan)
+                counters["hit_read_bytes"] += nbytes
+                counters["hit_bytes_fetched"] += nbytes
+                counters["hit_bytes_whole_prefix"] += whole
+                counters["hit_state_bytes_fetched"] += sum(
+                    m * t.nbytes for t, _, m, _ in plan if t.kind == "state"
+                )
             if not self._staged[layer].done():
                 self._staged[layer].set_result(layer % self.regions)
             if layer == self.num_layers - 1:
                 self.fetch_finished_s = time.perf_counter()
 
-    async def _submit_with_retry(self, blocks):
+    async def _submit_with_retry(self, blocks, nbytes: int):
         """The store read, with the handoff mode's bounded KeyNotFound
         re-probe loop (``retry_missing_s``; docs/disaggregation.md): a key
         the prefill side has not shipped YET is a stall, not a miss —
         until the deadline, after which the error keeps its normal
         semantics and the caller's fallback machinery takes over."""
         if self.retry_missing_s <= 0:
-            await self._submit(blocks)
+            await self._submit(blocks, nbytes)
             return
         deadline = time.perf_counter() + self.retry_missing_s
         while True:
             try:
-                await self._submit(blocks)
+                await self._submit(blocks, nbytes)
                 return
             except InfiniStoreKeyNotFound:
                 if self._cancelled or time.perf_counter() >= deadline:
@@ -929,8 +999,6 @@ class LayerwisePrefetch:
                 f"{self.num_layers}"
             )
         ids_dev = jax.numpy.asarray(np.asarray(block_ids), jax.numpy.int32)
-        bn = self.spec.block_nbytes
-        dt = np.dtype(jax.numpy.dtype(self.spec.dtype))
         loop = asyncio.get_running_loop()
         # Children of the caller's span (the engine's `install`, whose
         # duration is the exclusive gate's hold): `install_upload`, just
@@ -942,8 +1010,10 @@ class LayerwisePrefetch:
         # gate waiting for the network.
         counters = self._counters
         fused = (
-            self.regions >= self.num_layers
-            and not any(self._first)  # a sliding layer's region is part full
+            self.spec.uniform  # a K and a V of one shape a layer
+            and self.regions >= self.num_layers
+            # a sliding layer's region is part full
+            and not any(first for plan in self._plans for _, first, _, _ in plan)
             and self._region_stride == self._region_bytes
             and all(f.done() and not f.cancelled() and f.exception() is None
                     for f in self._staged)
@@ -959,7 +1029,7 @@ class LayerwisePrefetch:
                 self._lease.offset : self._lease.offset
                 + self.num_layers * self._region_bytes
             ]
-            host_all = span.view(dt).reshape(
+            host_all = span.view(np.dtype(jax.numpy.dtype(self.spec.dtype))).reshape(
                 (self.num_layers * 2 * n, *self.spec.block_shape)
             )
 
@@ -1051,43 +1121,35 @@ class LayerwisePrefetch:
                 # slots now) — treat as the miss it semantically is.
                 return out, 0
             off = self._region_offset(layer)
-            first = self._first[layer]
-            m = n - first
-            kv_host = (
-                self.pool.buf[off : off + 2 * m * bn]
-                .view(dt)
-                .reshape((2 * m, *self.spec.block_shape))
-            )
-            ids = ids_dev[first:] if first else ids_dev
+            plan = self._plans[layer]
+            nbytes = _plan_nbytes(plan)
 
-            def dev_one(pair, uspan, kv_host=kv_host, ids=ids, m=m):
+            def dev_one(tensors, uspan, plan=plan, off=off):
                 t_up = time.perf_counter()
                 if uspan is not None:
                     uspan.stage("started")
                 with tracing.device_call("its.install", uspan):
-                    kv_dev = jax.device_put(kv_host)
-                    if uspan is not None:
-                        uspan.stage("h2d")
-                    k_cache, v_cache = pair
-                    pair = (
-                        scatter_blocks(k_cache, ids, kv_dev[:m]),
-                        scatter_blocks(v_cache, ids, kv_dev[m:]),
+                    kv_dev, tensors = _upload_and_scatter(
+                        plan, self.pool.buf, off, tensors, ids_dev,
+                        uploaded=None if uspan is None else lambda: uspan.stage("h2d"),
                     )
-                return kv_dev, pair, (time.perf_counter() - t_up) * 1e6
+                return kv_dev, tensors, (time.perf_counter() - t_up) * 1e6
 
             # Off-loop for the same reason as the fused path: upload +
             # scatter must not freeze other requests' fetch completions.
             with tracing.trace_op("install_upload") as uspan:
                 if uspan is not None:
-                    uspan.annotate(layer=layer, bytes=kv_host.nbytes, fused=False)
+                    uspan.annotate(
+                        layer=layer, bytes=nbytes, fused=False, kind=plan[0][0].kind
+                    )
                 kv_dev, out[layer], upload_us = await loop.run_in_executor(
                     None, dev_one, out[layer], uspan
                 )
             if counters is not None:
-                counters["install_upload_bytes"] += kv_host.nbytes
+                counters["install_upload_bytes"] += nbytes
                 counters["install_upload_us"] += upload_us
             self._installing.add(layer)
-            self.blocks_installed += 2 * m
+            self.blocks_installed += sum(m for _, _, m, _ in plan)
             if on_layer is not None:
                 on_layer(layer, out[layer])
             self._release_region_async([layer], kv_dev, out[layer], loop)
@@ -1165,32 +1227,19 @@ class LayerwisePrefetch:
         if self._lease is None or self._lease._released:
             return out, False
         ids_dev = jax.numpy.asarray(np.asarray(block_ids), jax.numpy.int32)
-        bn = self.spec.block_nbytes
-        dt = np.dtype(jax.numpy.dtype(self.spec.dtype))
         loop = asyncio.get_running_loop()
-        off = self._region_offset(layer)
-        first = self._first[layer]
-        m = n - first
-        kv_host = (
-            self.pool.buf[off : off + 2 * m * bn]
-            .view(dt)
-            .reshape((2 * m, *self.spec.block_shape))
-        )
-        ids = ids_dev[first:] if first else ids_dev
+        plan = self._plans[layer]
 
-        def dev_one(pair):
-            kv_dev = jax.device_put(kv_host)
-            k_cache, v_cache = pair
-            return kv_dev, (
-                scatter_blocks(k_cache, ids, kv_dev[:m]),
-                scatter_blocks(v_cache, ids, kv_dev[m:]),
+        def dev_one(tensors):
+            return _upload_and_scatter(
+                plan, self.pool.buf, self._region_offset(layer), tensors, ids_dev
             )
 
         kv_dev, out[layer] = await loop.run_in_executor(
             None, dev_one, out[layer]
         )
         self._installing.add(layer)
-        self.blocks_installed += 2 * m
+        self.blocks_installed += sum(m for _, _, m, _ in plan)
         if on_layer is not None:
             on_layer(layer, out[layer])
         self._release_region_async([layer], kv_dev, out[layer], loop)

@@ -24,8 +24,41 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 @dataclass(frozen=True)
+class CacheTensor:
+    """One named per-block tensor of a layer's cache: a block of it is one
+    value in the store, under the key kind ``name``."""
+
+    name: str  # the store key's kind: "k", "v", "latent", "state", "tail"
+    block_shape: Tuple[int, ...]  # one block's shape (the cache adds a leading block axis)
+    dtype: jnp.dtype = jnp.bfloat16
+    # The hit policy: None, every block of a hit is fetched and installed;
+    # a count, the hit's trailing ``last_blocks`` blocks only (1: a recurrent
+    # state, which the last block's value replaces whole; window /
+    # block_tokens: a sliding layer). Every block is SAVED either way.
+    last_blocks: Optional[int] = None
+    # What the ledger counts it as: "kv", "latent" (grows with the prefix)
+    # or "state" (a recurrent layer's state and its convolution tail).
+    kind: str = "kv"
+
+    @functools.cached_property
+    def nbytes(self) -> int:
+        """Bytes of one block (reckoned once: the data plane asks a block at a time)."""
+        return int(np.prod(self.block_shape)) * jnp.dtype(self.dtype).itemsize
+
+    def hit_first(self, n_blocks: int) -> int:
+        """First block of an ``n_blocks`` prefix that a hit fetches."""
+        return 0 if self.last_blocks is None else max(0, n_blocks - self.last_blocks)
+
+
+@dataclass(frozen=True)
 class PagedKVCacheSpec:
-    """Shape contract for one model's paged KV cache."""
+    """Shape contract for one model's paged cache: per layer, a tuple of
+    named per-block tensors (:class:`CacheTensor`), each with its hit
+    policy. The common case, a K and a V of ONE shape for all layers, is
+    written with the scalar fields (``num_kv_heads``, ``head_dim``,
+    ``dtype``, ``windows``) and ``layers`` left None; a cache whose layers
+    differ in kind (a latent beside a recurrent state) names ``layers`` and
+    leaves the K/V fields at 0 (:meth:`of_layers`)."""
 
     num_layers: int
     num_blocks: int
@@ -38,8 +71,19 @@ class PagedKVCacheSpec:
     # whose layers all do. From it alone the data plane derives what a hit
     # installs (``hit_first_block``) and the wave its second page list.
     windows: Optional[Tuple[Optional[int], ...]] = None
+    # Per layer, its tensors; None: a K and a V of ``block_shape`` a layer,
+    # a sliding layer's with the window's policy.
+    layers: Optional[Tuple[Tuple[CacheTensor, ...], ...]] = None
 
     def __post_init__(self):
+        if self.layers is not None:
+            if len(self.layers) != self.num_layers or not all(self.layers):
+                raise ValueError(
+                    f"layers names {len(self.layers)} layers' tensors, the cache has {self.num_layers}"
+                )
+            if self.windows is not None:
+                raise ValueError("a cache of named tensors states a window as a tensor's last_blocks")
+            return
         if self.windows is None:
             return
         if len(self.windows) != self.num_layers:
@@ -52,6 +96,46 @@ class PagedKVCacheSpec:
                     f"a window of {w} tokens is no whole number of {self.block_tokens}-token blocks"
                 )
 
+    @classmethod
+    def of_layers(cls, num_blocks: int, block_tokens: int, layers) -> "PagedKVCacheSpec":
+        """A cache of per-layer kinds of named tensors."""
+        layers = tuple(tuple(tensors) for tensors in layers)
+        return cls(len(layers), num_blocks, block_tokens, 0, 0, None, None, layers)
+
+    @property
+    def uniform(self) -> bool:
+        """A K and a V of one shape for all layers: what the scalar fields say."""
+        return self.layers is None
+
+    def layer_tensors(self, layer: int) -> Tuple[CacheTensor, ...]:
+        """Layer ``layer``'s tensors, in the order of its cache tuple."""
+        return self._tensors[layer]
+
+    @functools.cached_property
+    def _tensors(self) -> Tuple[Tuple[CacheTensor, ...], ...]:
+        """Every layer's tensors, made once a spec: the data plane asks per
+        layer and per block."""
+        if self.layers is not None:
+            return self.layers
+
+        def pair(layer: int):
+            w = self.windows[layer] if self.windows else None
+            last = None if w is None else w // self.block_tokens
+            return tuple(
+                CacheTensor(name, self.block_shape, self.dtype, last) for name in ("k", "v")
+            )
+
+        return tuple(pair(layer) for layer in range(self.num_layers))
+
+    @property
+    def has_state(self) -> bool:
+        """Whether a layer keeps a recurrent state: what a block holds of it
+        is the state at the block's end, so a token is absorbed ONCE (the
+        engine lands a prompt's last token in the first wave alone)."""
+        return self.layers is not None and any(
+            t.kind == "state" for tensors in self.layers for t in tensors
+        )
+
     @property
     def window(self) -> Optional[int]:
         """The window of the cache's sliding layers (one size a model), or
@@ -63,22 +147,48 @@ class PagedKVCacheSpec:
 
     def hit_first_block(self, layer: int, n_blocks: int) -> int:
         """First block of an ``n_blocks`` prefix that a hit fetches and
-        installs for ``layer``: 0 for a full layer, and for a sliding one the
-        first of its last ``window / block_tokens`` blocks. A question token at
-        prefix position P + i sees back to P + i - window + 1, which lies in
-        block ``n_blocks - window / block_tokens`` or later. Every block of
-        every layer is still SAVED, so that any shorter prefix can resume."""
-        w = self.windows[layer] if self.windows else None
-        return 0 if w is None else max(0, n_blocks - w // self.block_tokens)
+        installs for ``layer``'s first tensor (of a K/V layer, both): 0 for a
+        full layer, and for a sliding one the first of its last ``window /
+        block_tokens`` blocks. A question token at prefix position P + i sees
+        back to P + i - window + 1, which lies in block ``n_blocks - window /
+        block_tokens`` or later. Every block of every layer is still SAVED,
+        so that any shorter prefix can resume."""
+        return self.layer_tensors(layer)[0].hit_first(n_blocks)
 
     def hit_values(self, n_blocks: int) -> Tuple[int, int]:
-        """(sliding, full): the store values (a K or a V of one block of one
-        layer) a hit of ``n_blocks`` fetches for each kind of layer."""
-        counts = [n_blocks - self.hit_first_block(l, n_blocks) for l in range(self.num_layers)]
-        sliding = sum(
-            2 * c for l, c in enumerate(counts) if self.windows and self.windows[l] is not None
+        """(trailing, whole): the store values (one block of one tensor of
+        one layer) a hit of ``n_blocks`` fetches of tensors whose policy is
+        the hit's trailing blocks (a sliding layer's K and V, a state) and of
+        those fetched in every block."""
+        trailing = whole = 0
+        for layer in range(self.num_layers):
+            for t in self.layer_tensors(layer):
+                count = n_blocks - t.hit_first(n_blocks)
+                if t.last_blocks is None:
+                    whole += count
+                else:
+                    trailing += count
+        return trailing, whole
+
+    def hit_nbytes(self, layer: int, n_blocks: int) -> int:
+        """Bytes a hit of ``n_blocks`` fetches for ``layer``."""
+        return sum(
+            (n_blocks - t.hit_first(n_blocks)) * t.nbytes for t in self.layer_tensors(layer)
         )
-        return sliding, 2 * sum(counts) - sliding
+
+    def region_nbytes(self, n_blocks: int) -> int:
+        """A staging region's size for a hit of ``n_blocks``: the most any
+        layer's hit weighs; of a K/V cache, a K and a V of every block."""
+        if self.uniform:
+            return 2 * n_blocks * self.block_nbytes
+        return max(self.hit_nbytes(l, n_blocks) for l in range(self.num_layers))
+
+    @property
+    def slot_nbytes(self) -> int:
+        """The staging pools' slot: a K block, or the cache's lightest value."""
+        if self.uniform:
+            return self.block_nbytes
+        return min(t.nbytes for tensors in self.layers for t in tensors)
 
     @property
     def block_shape(self) -> Tuple[int, int, int]:
@@ -92,8 +202,8 @@ class PagedKVCacheSpec:
     def block_nbytes(self) -> int:
         return int(np.prod(self.block_shape)) * jnp.dtype(self.dtype).itemsize
 
-    def make_caches(self) -> List[Tuple[jax.Array, jax.Array]]:
-        """Fresh zeroed (K, V) cache pair per layer.
+    def make_caches(self) -> List[Tuple[jax.Array, ...]]:
+        """Fresh zeroed tensors per layer (a K and a V, or the layer's own).
 
         Every entry is a *distinct* buffer: the installs' scatters and the
         model's serving steps donate the cache (in-place update), so aliasing
@@ -102,11 +212,11 @@ class PagedKVCacheSpec:
         donated input reads ``is_deleted()`` there, so CPU-only tests see it.)
         """
         return [
-            (
-                jnp.zeros(self.cache_shape, dtype=self.dtype),
-                jnp.zeros(self.cache_shape, dtype=self.dtype),
+            tuple(
+                jnp.zeros((self.num_blocks, *t.block_shape), dtype=t.dtype)
+                for t in self.layer_tensors(layer)
             )
-            for _ in range(self.num_layers)
+            for layer in range(self.num_layers)
         ]
 
 
@@ -158,13 +268,14 @@ def _block_spec_shape(spec_shape):
 def _gather_blocks_pallas(cache, block_ids, *, interpret):
     n = block_ids.shape[0]
     block = _block_spec_shape(cache.shape)
+    rest = (0,) * (cache.ndim - 1)  # a block of any rank: whole trailing dims
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n,),
         in_specs=[
-            pl.BlockSpec(block, lambda i, ids: (ids[i], 0, 0, 0)),
+            pl.BlockSpec(block, lambda i, ids: (ids[i], *rest)),
         ],
-        out_specs=pl.BlockSpec(block, lambda i, ids: (i, 0, 0, 0)),
+        out_specs=pl.BlockSpec(block, lambda i, ids: (i, *rest)),
     )
     return pl.pallas_call(
         _copy_kernel,
@@ -178,14 +289,15 @@ def _gather_blocks_pallas(cache, block_ids, *, interpret):
 def _scatter_blocks_pallas(cache, block_ids, blocks, *, interpret):
     n = block_ids.shape[0]
     block = _block_spec_shape(cache.shape)
+    rest = (0,) * (cache.ndim - 1)  # a block of any rank: whole trailing dims
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n,),
         in_specs=[
-            pl.BlockSpec(block, lambda i, ids: (i, 0, 0, 0)),
+            pl.BlockSpec(block, lambda i, ids: (i, *rest)),
             pl.BlockSpec(memory_space=pl.ANY),  # aliased cache, not DMA'd
         ],
-        out_specs=pl.BlockSpec(block, lambda i, ids: (ids[i], 0, 0, 0)),
+        out_specs=pl.BlockSpec(block, lambda i, ids: (ids[i], *rest)),
     )
     # Aliasing cache -> output makes this an in-place update: grid steps only
     # write the targeted blocks, everything else keeps its bytes. The alias

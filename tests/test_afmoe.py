@@ -213,34 +213,53 @@ def _layer(params, layer=1):
     return {k[len(pre):]: w for k, w in params.items() if k.startswith(pre)}
 
 
+def _family(name, params):
+    """(the family's small configuration class and its keywords, one expert
+    layer's weights, the published route scale): both families run the ONE
+    expert layer, ``afmoe.expert_layer``."""
+    if name == "afmoe":
+        return AfmoeConfig, {"dtype": jnp.float32}, _layer(params), 2.826
+    from infinistore_tpu.models import kimi_linear
+
+    kw = {"dtype": jnp.float32}
+    cfg = kimi_linear.KimiLinearConfig(**kw)
+    return (
+        kimi_linear.KimiLinearConfig, kw,
+        _layer(kimi_linear.init_params(cfg, jax.random.key(41))), 2.446,
+    )
+
+
+@pytest.mark.parametrize("family", ["afmoe", "kimi_linear"])
 @pytest.mark.parametrize("rows", [3, 40], ids=["few-rows", "many-tokens"])
-def test_four_shares_of_two_experts_add_up_to_the_uncut_layer(params, rows):
+def test_four_shares_of_two_experts_add_up_to_the_uncut_layer(params, rows, family):
     """The expert layer told it holds experts 2i and 2i + 1, four times over:
     every share routes over all 8, computes its own two, the share with
     expert 0 adds the shared expert, and the sum is the uncut layer's output,
-    which is the plain float32 computation of the published rule."""
-    w = _layer(params)
-    m = jax.random.normal(jax.random.key(rows), (rows, CFG.dim), jnp.float32)
-    whole, ids, _ = afmoe.expert_layer(w, m, CFG)
+    which is the plain float32 computation of the published rule. Under both
+    configurations that run it (the second's is a held share on the chip)."""
+    config_class, kw, w, route_scale = _family(family, params)
+    cfg = config_class(**kw)
+    m = jax.random.normal(jax.random.key(rows), (rows, cfg.dim), jnp.float32)
+    whole, ids, _ = afmoe.expert_layer(w, m, cfg)
     total = jnp.zeros_like(whole)
     for first in range(0, 8, 2):
         share = dict(w, **{k: w[k][first : first + 2] for k in ("w_gate", "w_up", "w_down_moe")})
         part, share_ids, _ = afmoe.expert_layer(
-            share, m, AfmoeConfig(dtype=jnp.float32, experts_held=(first, 2))
+            share, m, config_class(experts_held=(first, 2), **kw)
         )
         np.testing.assert_array_equal(share_ids, ids)  # every share routes over all
         total = total + part
     np.testing.assert_allclose(total, whole, atol=2e-5, rtol=0)
     # By hand: sigmoid scores, top-2, normalised and scaled weights.
     scores = jax.nn.sigmoid(m @ w["router"])
-    want = np.zeros((rows, CFG.dim), np.float32)
+    want = np.zeros((rows, cfg.dim), np.float32)
     for t in range(rows):
         top = np.argsort(-np.asarray(scores[t]))[:2]
         assert set(top.tolist()) == set(np.asarray(ids[t]).tolist())
         for e in top:
             h = jax.nn.silu(m[t] @ w["w_gate"][e]) * (m[t] @ w["w_up"][e])
             want[t] += np.asarray(
-                2.826 * scores[t, e] / (scores[t, top].sum() + 1e-20) * (h @ w["w_down_moe"][e])
+                route_scale * scores[t, e] / (scores[t, top].sum() + 1e-20) * (h @ w["w_down_moe"][e])
             )
         su = jnp.einsum("d,dcf->cf", m[t], w["ws_gate_up"])
         want[t] += np.asarray((jax.nn.silu(su[0]) * su[1]) @ w["ws_down"])
