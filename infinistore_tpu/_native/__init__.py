@@ -125,6 +125,13 @@ lib.its_conn_ring_poll_counters.argtypes = [
     c_void_p, POINTER(c_uint64), POINTER(c_uint64), POINTER(c_uint64),
     POINTER(c_uint64),
 ]
+# Put pre-touch ledger: put copy bytes, those landed on touched chunks,
+# bytes the touch thread walked, us the reactor spent in the copies
+# (lib.InfinityConnection.touch_stats).
+lib.its_conn_touch_counters.argtypes = [
+    c_void_p, POINTER(c_uint64), POINTER(c_uint64), POINTER(c_uint64),
+    POINTER(c_uint64),
+]
 # Multi-op batch grouping: bracket one event-loop tick's ring posts so a
 # coalesced flush publishes as one batch slot (docs/descriptor_ring.md).
 lib.its_conn_ring_group_begin.argtypes = [c_void_p]

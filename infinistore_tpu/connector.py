@@ -344,7 +344,9 @@ class KVConnector:
         # untagged (foreground); writes whose class was flipped to foreground
         # with layers still unsent; writes that STARTED foreground, and over
         # those the rounds of put latency they took (submissions that
-        # followed a wait for an earlier group, plus one a write).
+        # followed a wait for an earlier group, plus one a write); bytes the
+        # puts delivered and the union of the time in which one was in
+        # flight (``save_puts_in_flight`` and ``save_put_busy_mark_s`` keep it).
         self.hit_counters = {
             "hit_values_fetched": 0, "hit_values_whole_prefix": 0,
             "hit_bytes_fetched": 0, "hit_bytes_whole_prefix": 0,
@@ -356,6 +358,8 @@ class KVConnector:
             "save_d2h_bytes": 0, "save_d2h_wait_us": 0.0,
             "save_puts": 0, "save_fg_puts": 0, "save_promotions": 0,
             "save_fg_writes": 0, "save_fg_rounds": 0,
+            "save_put_bytes": 0, "save_put_busy_us": 0.0,
+            "save_puts_in_flight": 0, "save_put_busy_mark_s": 0.0,
         }
         if conn is None:
             # Pure-ICI connector: no store data plane, so don't allocate the
@@ -1001,9 +1005,15 @@ class KVConnector:
         ``save_fg_puts`` of ``save_puts`` put calls went out untagged,
         ``save_promotions`` writes were promoted with layers still unsent,
         and ``save_fg_rounds`` over ``save_fg_writes`` is the rounds of put
-        latency a write that started foreground took."""
+        latency a write that started foreground took; ``save_put_bytes``
+        over ``save_put_busy_us`` (the union of the time in which a save's
+        put was in flight), the rate the store took the saves at. Beside
+        them the connection's own put pre-touch ledger (``touch_stats``):
+        ``put_touched_bytes`` of ``put_copy_bytes``, ``put_copy_us``,
+        ``pretouch_bytes``."""
         self._require_store("get_stats")
-        return {**self.conn.get_stats(), **self.hit_counters}
+        touch = getattr(self.conn, "touch_stats", dict)
+        return {**self.conn.get_stats(), **touch(), **self.hit_counters}
 
     def drop(self, token_ids) -> int:
         """Remove this prompt's blocks from the store (all layers). Returns

@@ -1492,6 +1492,30 @@ class InfinityConnection:
             "ring_batch_windows": self._batch_windows,
         }
 
+    def touch_stats(self) -> dict:
+        """The put pre-touch's ledger (docs/design.md, "Who faults on a
+        put"; native, always on, this handle's lifetime): ``put_copy_bytes``
+        the two-phase shm put copied into mapped pools, ``put_touched_bytes``
+        the part of them that landed on chunks this connection's mapping
+        had already touched (``put_touched_share`` reads the two),
+        ``put_copy_us`` the reactor thread's time in those copies (bytes
+        over it: the copy's own rate, which is what a touched page buys),
+        ``pretouch_bytes`` the touch thread walked. All 0 on a connection
+        that never put through shm: it has started no thread."""
+        put, warm, walked, us = (ctypes.c_uint64() for _ in range(4))
+        with self._lock:
+            if self._handle is not None:
+                lib.its_conn_touch_counters(
+                    self._handle, ctypes.byref(put), ctypes.byref(warm),
+                    ctypes.byref(walked), ctypes.byref(us),
+                )
+        return {
+            "put_copy_bytes": put.value,
+            "put_touched_bytes": warm.value,
+            "put_copy_us": us.value,
+            "pretouch_bytes": walked.value,
+        }
+
     def qos_stats(self) -> dict:
         """Client-side per-class batched-op counters (the QoS ledger's
         client half; the server's scheduler counters are
@@ -2312,6 +2336,15 @@ class StripedConnection:
                 "fg_pending": self._fg_pending,
             },
         }
+
+    def touch_stats(self) -> dict:
+        """The put pre-touch's ledger summed over the stripes (each maps the
+        pools itself; see InfinityConnection.touch_stats)."""
+        out = {"put_copy_bytes": 0, "put_touched_bytes": 0, "put_copy_us": 0, "pretouch_bytes": 0}
+        for c in self.conns:
+            for k, v in c.touch_stats().items():
+                out[k] += v
+        return out
 
     def completion_stats(self) -> dict:
         """Aggregate async-bridge coalescing counters across stripes (see

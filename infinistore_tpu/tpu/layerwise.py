@@ -15,6 +15,7 @@ Key naming follows the reference's convention of hash-chain keys per block
 """
 
 import asyncio
+import functools
 import time
 from collections import deque
 from typing import Callable, List, Optional, Sequence, Tuple
@@ -415,6 +416,13 @@ class LayerwiseKVWriter:
                         t.nbytes, base, **pri_kw))
                     for (t, _, _, _), base in zip(plan, bases)
                 )
+                if counters is not None:
+                    # `save_put_bytes` over `save_put_busy_us`: what the
+                    # store took over the time a save's put was in flight.
+                    for fut, (t, _, _, _) in zip(futs, plan):
+                        _busy_step(counters, "save_put", +1)
+                        fut.add_done_callback(functools.partial(
+                            _save_put_done, counters, n * t.nbytes))
                 staged.popleft()
                 inflight.append((futs, tr, len(plan) * n, lspan))
                 top_up()  # refill the D2H pipeline before blocking again
@@ -555,17 +563,24 @@ class LayerwiseKVReader:
         return out
 
 
-def _hit_reads_step(counters: dict, step: int) -> None:
-    """One hit's layer read goes in flight (``step`` +1) or comes back (-1):
-    the time since the last change counts into ``hit_read_busy_us`` where
-    at least one read was in flight through it. The UNION of the reads'
-    time, so that ``hit_read_bytes`` over it is the rate the store
-    delivered while anyone was asking, however many asked at once."""
+def _save_put_done(counters: dict, nbytes: int, fut) -> None:
+    _busy_step(counters, "save_put", -1)
+    if not fut.cancelled() and fut.exception() is None:
+        counters["save_put_bytes"] += nbytes
+
+
+def _busy_step(counters: dict, op: str, step: int) -> None:
+    """One ``op`` (``hit_read``: a hit's layer read; ``save_put``: a put of
+    a save) goes in flight (``step`` +1) or comes back (-1): the time since
+    the last change counts into ``<op>_busy_us`` where at least one was in
+    flight through it. The UNION of their time, so that ``<op>_bytes`` over
+    it is the rate the store delivered (took) while anyone was asking,
+    however many asked at once."""
     now = time.perf_counter()
-    if counters["hit_reads_in_flight"] > 0:
-        counters["hit_read_busy_us"] += (now - counters["hit_read_busy_mark_s"]) * 1e6
-    counters["hit_read_busy_mark_s"] = now
-    counters["hit_reads_in_flight"] += step
+    if counters[f"{op}s_in_flight"] > 0:
+        counters[f"{op}_busy_us"] += (now - counters[f"{op}_busy_mark_s"]) * 1e6
+    counters[f"{op}_busy_mark_s"] = now
+    counters[f"{op}s_in_flight"] += step
 
 
 class PrefetchDiscarded(RuntimeError):
@@ -646,7 +661,7 @@ class LayerwisePrefetch:
         fetched to ``hit_values_fetched``, what every block of that layer
         would have been to ``hit_values_whole_prefix`` and its bytes to
         ``hit_read_bytes``; ``hit_read_busy_us`` is the time in which at
-        least one layer read was in flight (``_hit_reads_step``), and an
+        least one layer read was in flight (``_busy_step``), and an
         install adds ``install_upload_bytes`` / ``install_upload_us``.
         Raises :class:`~..tpu.staging.StagingPoolExhausted` when the pool
         cannot hold even a double-buffered pipeline."""
@@ -779,7 +794,7 @@ class LayerwisePrefetch:
                 return
             reads = _plan_reads(plan, self._key_fn, layer, self._region_offset(layer))
             if counters is not None:
-                _hit_reads_step(counters, +1)
+                _busy_step(counters, "hit_read", +1)
             try:
                 # One store read a value size: ONE for a K/V layer.
                 if len(reads) == 1:
@@ -804,7 +819,7 @@ class LayerwisePrefetch:
                 return
             finally:
                 if counters is not None:
-                    _hit_reads_step(counters, -1)
+                    _busy_step(counters, "hit_read", -1)
             if span is not None:
                 span.stage("landed")
             self.blocks_fetched += values

@@ -18,6 +18,7 @@
 #include <sys/uio.h>
 
 #include <atomic>
+#include <condition_variable>
 #include <cstdint>
 #include <deque>
 #include <memory>
@@ -204,6 +205,14 @@ class Connection {
     // loop wakeup instead of arming one each), so pushed/signalled is the
     // mean completion batch per wakeup the bench reports.
     void completion_counters(uint64_t* pushed, uint64_t* signalled) const;
+    // Put pre-touch ledger (docs/design.md, "Who faults on a put"): bytes the
+    // two-phase shm put copied into mapped pools, the part of them that
+    // landed on chunks this connection's mapping had already touched, bytes
+    // the touch thread itself has walked, and the reactor's time in those
+    // copies. All zero on a connection that never put through shm: it has
+    // started no thread.
+    void touch_counters(uint64_t* put_bytes, uint64_t* put_touched_bytes,
+                        uint64_t* touched_bytes, uint64_t* put_copy_us) const;
 
   private:
     struct Request;
@@ -265,6 +274,12 @@ class Connection {
     // back if it must be re-queued (put commit phase), nullptr when done.
     std::unique_ptr<Request> shm_phase(std::unique_ptr<Request> req, uint32_t status);
     void queue_release(uint64_t ticket);
+    // Reactor-side, before a put's copy: count how much of it lands on
+    // touched chunks, move the pool's frontier, start the touch thread on
+    // the first call. Never waits for a touch.
+    void touch_note_put(uint16_t pool_id, char* base, size_t pool_size, uint64_t offset,
+                        size_t len);
+    void touch_loop();
 
     ClientConfig config_;
     int fd_ = -1;
@@ -333,6 +348,35 @@ class Connection {
     std::atomic<bool> shm_ok_{false};
     mutable std::mutex shm_mu_;
     std::unordered_map<uint16_t, ShmMap> shm_pools_ ITS_GUARDED_BY(shm_mu_);
+
+    // Put pre-touch. A pool page costs THIS mapping a fault the first time
+    // it is written, whoever else has touched it, and shm_phase's memcpy
+    // would take those faults on the reactor thread. So one thread a
+    // connection, started by its first shm put (never by map_pool: a
+    // fetch-only connection maps pools and runs none), keeps the chunks
+    // from the pool's frontier to kTouchLead past it touched, with a write
+    // that changes no byte. The frontier follows the puts: it moves up with
+    // them, and to wherever a put lands on an untouched chunk. touch_mu_ is
+    // held for bookkeeping only, never across a touch, so the reactor waits
+    // for a few loads and stores at most.
+    static constexpr size_t kTouchChunk = size_t{1} << 20;
+    static constexpr size_t kTouchLead = size_t{1} << 30;
+    struct TouchPool {
+        char* base = nullptr;
+        size_t size = 0;
+        std::vector<bool> touched;  // one a kTouchChunk of the mapping
+        size_t frontier = 0;        // chunk the lead is counted from
+    };
+    std::thread touch_thread_;  // started by the reactor, joined in close()
+    std::mutex touch_mu_;
+    std::condition_variable touch_cv_;
+    bool touch_stop_ ITS_GUARDED_BY(touch_mu_) = false;
+    std::unordered_map<uint16_t, TouchPool> touch_pools_ ITS_GUARDED_BY(touch_mu_);
+    uint16_t touch_last_pool_ ITS_GUARDED_BY(touch_mu_) = 0;
+    std::atomic<uint64_t> put_copy_bytes_{0};
+    std::atomic<uint64_t> put_touched_bytes_{0};
+    std::atomic<uint64_t> touch_bytes_{0};
+    std::atomic<uint64_t> put_copy_us_{0};
 
     // Descriptor-ring state (docs/descriptor_ring.md; "dring" because the
     // PR 2 completion ring above already owns the plain ring_/ring_mu_

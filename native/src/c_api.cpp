@@ -193,6 +193,15 @@ int its_conn_drain_completions(void* c, uint64_t* tokens, int32_t* codes, int ca
 void its_conn_completion_counters(void* c, uint64_t* pushed, uint64_t* signalled) {
     static_cast<Connection*>(c)->completion_counters(pushed, signalled);
 }
+// Put pre-touch ledger (docs/design.md, "Who faults on a put"): bytes the
+// two-phase shm put copied, those that landed on chunks this connection's
+// mapping had touched, bytes the touch thread walked, the reactor's time in
+// the copies (us).
+void its_conn_touch_counters(void* c, uint64_t* put_bytes, uint64_t* put_touched_bytes,
+                             uint64_t* touched_bytes, uint64_t* put_copy_us) {
+    static_cast<Connection*>(c)->touch_counters(put_bytes, put_touched_bytes, touched_bytes,
+                                                put_copy_us);
+}
 
 // ``priority``: QoS class tag (its::Priority) — 0 foreground (default
 // scheduling, wire bytes unchanged), 1 background (yields to foreground in

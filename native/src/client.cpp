@@ -599,6 +599,20 @@ void Connection::close() {
     connected_.store(false);
     shm_ok_.store(false);
     ring_teardown();  // in-flight ring ops were failed by the reactor's fail_all
+    // The touch thread walks the pool mappings: it goes before they do. Only
+    // a connection that put through shm has one (the reactor that started
+    // it is joined above, so nobody starts one now).
+    if (touch_thread_.joinable()) {
+        {
+            std::lock_guard<std::mutex> lock(touch_mu_);
+            touch_stop_ = true;
+        }
+        touch_cv_.notify_one();
+        touch_thread_.join();
+        std::lock_guard<std::mutex> lock(touch_mu_);
+        touch_stop_ = false;
+        touch_pools_.clear();
+    }
     {
         std::lock_guard<std::mutex> lock(shm_mu_);
         for (auto& [id, m] : shm_pools_) munmap(m.base, m.size);
@@ -1368,6 +1382,7 @@ std::unique_ptr<Connection::Request> Connection::shm_phase(std::unique_ptr<Reque
     size_t n = resp.locs.size();
     bool ok = put ? n == req->tx_payload.size() : n == req->rx_addrs.size();
     std::vector<char*> at(n);
+    std::vector<ShmMap> pool_of(put ? n : 0);  // the mapping each put location lies in
     for (size_t i = 0; ok && i < n; i++) {
         const ShmLoc& l = resp.locs[i];
         char* base = nullptr;
@@ -1411,6 +1426,7 @@ std::unique_ptr<Connection::Request> Connection::shm_phase(std::unique_ptr<Reque
             break;
         }
         at[i] = base + l.offset;
+        if (put) pool_of[i] = ShmMap{base, mapped_size};
     }
     if (!ok) {
         queue_release(resp.ticket);  // abort: drop the server-side ticket
@@ -1429,7 +1445,12 @@ std::unique_ptr<Connection::Request> Connection::shm_phase(std::unique_ptr<Reque
     }
     if (put) {
         for (size_t i = 0; i < n; i++)
+            touch_note_put(resp.locs[i].pool_id, pool_of[i].base, pool_of[i].size,
+                           resp.locs[i].offset, req->tx_payload[i].iov_len);
+        uint64_t t0 = now_us();
+        for (size_t i = 0; i < n; i++)
             memcpy(at[i], req->tx_payload[i].iov_base, req->tx_payload[i].iov_len);
+        put_copy_us_.fetch_add(now_us() - t0, std::memory_order_relaxed);
         // Phase 2: publish the keys (commit-on-copy-complete).
         req->op = kOpPutCommit;
         req->body.clear();
@@ -1442,6 +1463,104 @@ std::unique_ptr<Connection::Request> Connection::shm_phase(std::unique_ptr<Reque
     queue_release(resp.ticket);
     complete(std::move(req), static_cast<int>(kStatusOk), /*take_body=*/true);
     return nullptr;
+}
+
+void Connection::touch_counters(uint64_t* put_bytes, uint64_t* put_touched_bytes,
+                                uint64_t* touched_bytes, uint64_t* put_copy_us) const {
+    *put_bytes = put_copy_bytes_.load(std::memory_order_relaxed);
+    *put_touched_bytes = put_touched_bytes_.load(std::memory_order_relaxed);
+    *touched_bytes = touch_bytes_.load(std::memory_order_relaxed);
+    *put_copy_us = put_copy_us_.load(std::memory_order_relaxed);
+}
+
+void Connection::touch_note_put(uint16_t pool_id, char* base, size_t pool_size,
+                                uint64_t offset, size_t len) {
+    if (len == 0) return;
+    put_copy_bytes_.fetch_add(len, std::memory_order_relaxed);
+    size_t first = offset / kTouchChunk, last = (offset + len - 1) / kTouchChunk;
+    bool moved = false;
+    {
+        std::lock_guard<std::mutex> lock(touch_mu_);
+        TouchPool& p = touch_pools_[pool_id];
+        if (p.base == nullptr) {
+            p.base = base;
+            p.size = pool_size;
+            p.touched.assign((pool_size + kTouchChunk - 1) / kTouchChunk, false);
+        }
+        bool warm = true;
+        for (size_t c = first; c <= last; c++) warm = warm && p.touched[c];
+        if (warm) put_touched_bytes_.fetch_add(len, std::memory_order_relaxed);
+        // The copy that follows touches every page it covers: a chunk wholly
+        // inside it is this mapping's from here on.
+        for (size_t c = first; c <= last; c++)
+            if (c * kTouchChunk >= offset && (c + 1) * kTouchChunk <= offset + len)
+                p.touched[c] = true;
+        // A cold put moves the frontier to where it landed, wherever that
+        // is; a warm one only ever moves it up.
+        if (!warm || last > p.frontier) {
+            moved = p.frontier != last || touch_last_pool_ != pool_id;
+            p.frontier = last;
+            touch_last_pool_ = pool_id;
+        }
+    }
+    // Reactor-only: close() joins the reactor before it looks at the thread.
+    if (!touch_thread_.joinable())
+        touch_thread_ = std::thread([this] { touch_loop(); });
+    else if (moved)
+        touch_cv_.notify_one();
+}
+
+// A write that changes no byte: other connections and the server store to
+// these pages at the same moment, and an atomic add of zero can lose none of
+// their stores. (A read would do on Linux, whose shmem read fault maps the
+// page writable; a sandboxed kernel may map it read-only and fault the
+// copy again. tools/putfault_probe.py reads both.) Not instrumented by
+// TSAN: the copies it runs beside are plain memcpys by design.
+__attribute__((no_sanitize("thread"))) static void touch_chunk(char* at, size_t len) {
+    static const size_t page = static_cast<size_t>(sysconf(_SC_PAGESIZE));
+    for (size_t off = 0; off < len; off += page)
+        __atomic_fetch_add(reinterpret_cast<volatile uint8_t*>(at + off), 0,
+                           __ATOMIC_RELAXED);
+}
+
+// The analysis cannot see through the condition variable's unique_lock;
+// every access to the guarded state below is under it.
+ITS_NO_THREAD_SAFETY_ANALYSIS void Connection::touch_loop() {
+    std::unique_lock<std::mutex> lock(touch_mu_);
+    while (!touch_stop_) {
+        // The first untouched chunk inside a lead: the pool of the last put
+        // that moved a frontier first, then any other.
+        TouchPool* pool = nullptr;
+        size_t chunk = 0;
+        auto find = [&](TouchPool& p) {
+            size_t end = std::min(p.touched.size(), p.frontier + kTouchLead / kTouchChunk + 1);
+            for (size_t c = p.frontier; c < end && pool == nullptr; c++) {
+                if (!p.touched[c]) {
+                    pool = &p;
+                    chunk = c;
+                }
+            }
+        };
+        auto last = touch_pools_.find(touch_last_pool_);
+        if (last != touch_pools_.end()) find(last->second);
+        for (auto it = touch_pools_.begin(); it != touch_pools_.end() && pool == nullptr; ++it)
+            find(it->second);
+        if (pool == nullptr) {
+            touch_cv_.wait(lock);
+            continue;
+        }
+        char* at = pool->base + chunk * kTouchChunk;
+        size_t len = std::min(kTouchChunk, pool->size - chunk * kTouchChunk);
+        // One chunk a slice, with the lock down: a put's bookkeeping, and
+        // close(), wait for a few hundred page touches at most.
+        lock.unlock();
+        touch_chunk(at, len);
+        touch_bytes_.fetch_add(len, std::memory_order_relaxed);
+        lock.lock();
+        // Entries are never erased while the thread runs (close() joins it
+        // first) and unordered_map keeps references across inserts.
+        pool->touched[chunk] = true;
+    }
 }
 
 void Connection::queue_release(uint64_t ticket) {

@@ -233,6 +233,100 @@ static void test_loopback_end_to_end(bool enable_shm) {
     server.stop();
 }
 
+// The put pre-touch (client.h): started by the first shm put and by nothing
+// else, it walks ahead of the puts, changes no byte under a second writer,
+// and goes before the mappings in close(), started or not, mid-walk or not;
+// the same object connects and puts again afterwards.
+static void test_put_pretouch() {
+    ServerConfig scfg;
+    scfg.bind_addr = "127.0.0.1";
+    scfg.service_port = 0;
+    scfg.prealloc_bytes = 96 << 20;
+    scfg.block_size = 16 << 10;
+    scfg.pin_memory = false;
+    scfg.enable_shm = true;
+    Server server(scfg);
+    CHECK(server.start());
+    ClientConfig ccfg;
+    ccfg.host = "127.0.0.1";
+    ccfg.port = server.port();
+    Connection writer(ccfg), second(ccfg), reader(ccfg);
+    CHECK(writer.connect() == 0 && second.connect() == 0 && reader.connect() == 0);
+    CHECK(writer.shm_active() && reader.shm_active());
+
+    const size_t n = 32, bs = 64 << 10;  // 2 MiB a put
+    std::vector<char> src(n * bs), dst(n * bs, 0);
+    for (size_t i = 0; i < src.size(); i++) src[i] = static_cast<char>(i * 131 + 5);
+    for (Connection* c : {&writer, &second, &reader}) {
+        c->register_mr(src.data(), src.size());
+        c->register_mr(dst.data(), dst.size());
+    }
+    auto keys_of = [&](const std::string& prefix) {
+        std::vector<std::string> keys;
+        for (size_t i = 0; i < n; i++) keys.push_back(prefix + std::to_string(i));
+        return keys;
+    };
+    std::vector<uint64_t> offs;
+    for (size_t i = 0; i < n; i++) offs.push_back(i * bs);
+    uint64_t put = 0, warm = 0, walked = 0, copy_us = 0;
+    auto walked_to = [&](Connection& c, uint64_t at_least) {
+        for (int i = 0; i < 5000; i++) {
+            c.touch_counters(&put, &warm, &walked, &copy_us);
+            if (walked >= at_least) return true;
+            std::this_thread::sleep_for(std::chrono::milliseconds(2));
+        }
+        return false;
+    };
+
+    writer.touch_counters(&put, &warm, &walked, &copy_us);
+    CHECK(put == 0 && warm == 0 && walked == 0);
+    CHECK(writer.put_batch(keys_of("first"), offs, bs, src.data()) == 0);
+    // The second connection writes the pages the first one's thread walks.
+    for (int r = 0; r < 8; r++)
+        CHECK(second.put_batch(keys_of("s" + std::to_string(r) + "-"), offs, bs, src.data()) == 0);
+    CHECK(walked_to(writer, 48 << 20));
+    uint64_t put0 = put, warm0 = warm;
+    CHECK(put0 == n * bs);
+    for (int r = 0; r < 8; r++)
+        CHECK(writer.put_batch(keys_of("w" + std::to_string(r) + "-"), offs, bs, src.data()) == 0);
+    writer.touch_counters(&put, &warm, &walked, &copy_us);
+    CHECK(put - put0 == 8 * n * bs);
+    CHECK((warm - warm0) * 10 >= (put - put0) * 9);
+    for (int r = 0; r < 8; r++) {
+        memset(dst.data(), 0, dst.size());
+        CHECK(reader.get_batch(keys_of("s" + std::to_string(r) + "-"), offs, bs, dst.data()) == 0);
+        CHECK(memcmp(src.data(), dst.data(), src.size()) == 0);
+        CHECK(reader.get_batch(keys_of("w" + std::to_string(r) + "-"), offs, bs, dst.data()) == 0);
+        CHECK(memcmp(src.data(), dst.data(), src.size()) == 0);
+    }
+    // A connection that only reads maps the pools and starts nothing.
+    reader.touch_counters(&put, &warm, &walked, &copy_us);
+    CHECK(put == 0 && warm == 0 && walked == 0 && copy_us == 0);
+    reader.close();  // no thread to join
+    second.close();  // a thread that ran out of work
+    // Close mid-walk, connect again on the same object, put again (close()
+    // drops the registrations with the mappings).
+    auto again = [&] {
+        writer.close();
+        CHECK(writer.connect() == 0);
+        writer.register_mr(src.data(), src.size());
+        writer.register_mr(dst.data(), dst.size());
+    };
+    again();
+    server.purge();
+    writer.touch_counters(&put, &warm, &walked, &copy_us);
+    uint64_t walked0 = walked;
+    CHECK(writer.put_batch(keys_of("again"), offs, bs, src.data()) == 0);
+    again();  // at once: the new thread has 90-odd MiB ahead of it
+    CHECK(writer.put_batch(keys_of("third"), offs, bs, src.data()) == 0);
+    CHECK(walked_to(writer, walked0 + (16 << 20)));
+    memset(dst.data(), 0, dst.size());
+    CHECK(writer.get_batch(keys_of("third"), offs, bs, dst.data()) == 0);
+    CHECK(memcmp(src.data(), dst.data(), src.size()) == 0);
+    writer.close();
+    server.stop();
+}
+
 static void test_spill_tier_demote_promote() {
     // KVStore + SpillFile: evict demotes to the file, get promotes back,
     // bytes survive the round trip, slots are freed on delete/overwrite,
@@ -1265,6 +1359,7 @@ int main() {
     test_ring_batch_slot_qos_ordering();
     test_loopback_end_to_end(/*enable_shm=*/true);
     test_loopback_end_to_end(/*enable_shm=*/false);
+    test_put_pretouch();
     test_completion_ring(/*enable_shm=*/true);
     test_completion_ring(/*enable_shm=*/false);
     test_abandoned_sync_ops_stress(/*enable_shm=*/true);

@@ -195,6 +195,14 @@ def test_tracing_off_records_nothing_and_still_stamps_emits(conn, params, monkey
     assert all(moved[key] > 0 for key in HOP_COUNTERS), moved
     assert moved["hit_read_bytes"] == moved["install_upload_bytes"] == _hit_bytes(hit)
     assert moved["save_d2h_bytes"] == _hit_bytes(hit) and moved["hit_reads_in_flight"] == 0
+    # The put's ledger (PR 42): every saved byte acknowledged over some busy
+    # time, and beside it the connection's own: the two-phase shm put copied
+    # those bytes itself, the socket path copied none.
+    assert moved["save_put_bytes"] == moved["save_d2h_bytes"] and moved["save_put_busy_us"] > 0
+    assert moved["save_puts_in_flight"] == 0
+    assert moved["put_copy_bytes"] == (moved["save_put_bytes"] if conn.shm_active else 0)
+    assert moved["put_touched_bytes"] <= moved["put_copy_bytes"]
+    assert (moved["pretouch_bytes"] > 0) == conn.shm_active
 
 
 # The connector's ledger of the hop (docs/observability.md), always on.

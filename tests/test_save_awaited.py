@@ -110,7 +110,11 @@ def _key_fn(layer, kind, i):
 
 def _writer(conn, spec=SPEC):
     w = LayerwiseKVWriter(conn, StagePool(conn), spec, max_blocks=N_BLOCKS)
-    w.counters = dict.fromkeys(SAVE_KEYS, 0) | {"save_d2h_bytes": 0, "save_d2h_wait_us": 0.0}
+    w.counters = dict.fromkeys(SAVE_KEYS, 0) | {
+        "save_d2h_bytes": 0, "save_d2h_wait_us": 0.0,
+        "save_put_bytes": 0, "save_put_busy_us": 0.0,
+        "save_puts_in_flight": 0, "save_put_busy_mark_s": 0.0,
+    }
     return w
 
 
@@ -261,6 +265,10 @@ def test_light_layers_wait_for_their_d2h_in_line_and_heavy_ones_in_an_executor(m
     on_loop = [t is threading.main_thread() for t in waited_in]
     assert all(on_loop) if weight == "light" else not any(on_loop)
     assert w.counters["save_d2h_bytes"] == 16 * LAYER_BYTES and conn.registered == {}
+    # The put ledger: every byte acknowledged, nothing left in flight, and
+    # the union of the puts' time is some time.
+    assert w.counters["save_put_bytes"] == 16 * LAYER_BYTES
+    assert w.counters["save_puts_in_flight"] == 0 and w.counters["save_put_busy_us"] > 0
 
 
 def test_connection_without_qos_gets_untagged_puts_in_both_classes():

@@ -297,3 +297,209 @@ def test_get_with_smaller_block_size_errors_cleanly(shm):
     assert np.array_equal(full, big)
     c.close()
     srv.stop()
+
+
+# ---------------------------------------------------------------------------
+# The put pre-touch (docs/design.md, "Who faults on a put"): one thread a
+# connection, started by its first shm put, keeps the pool touched ahead of
+# the puts. None of these reads a clock or a fault count: they read the
+# connection's own ledger (``touch_stats``) and the bytes.
+# ---------------------------------------------------------------------------
+
+MIB = 1 << 20
+BLOCK = 64 << 10
+ZERO_TOUCH = {"put_copy_bytes": 0, "put_touched_bytes": 0, "put_copy_us": 0, "pretouch_bytes": 0}
+
+
+def _touch_conn(port: int, shm: bool = True) -> its.InfinityConnection:
+    c = its.InfinityConnection(its.ClientConfig(
+        host_addr="127.0.0.1", service_port=port, enable_shm=shm, log_level="error",
+    ))
+    c.connect()
+    return c
+
+
+def _put(c, prefix: str, data: np.ndarray):
+    pairs = [(f"{prefix}-{i}", i * BLOCK) for i in range(data.nbytes // BLOCK)]
+    c.write_cache(pairs, BLOCK, data.ctypes.data)
+    return pairs
+
+
+def _walked(c, at_least: int) -> int:
+    """Polls until the touch thread has walked ``at_least`` bytes."""
+    for _ in range(4000):
+        walked = c.touch_stats()["pretouch_bytes"]
+        if walked >= at_least:
+            return walked
+        time.sleep(0.005)
+    raise AssertionError(f"the touch thread walked {walked} of {at_least} bytes")
+
+
+def test_pretouch_sequential_puts_land_touched():
+    """The first put is cold and starts the thread; once it has walked ahead,
+    a run of sequential puts lands on touched chunks."""
+    srv = its.start_local_server(prealloc_bytes=64 * MIB, block_bytes=16 << 10)
+    c = _touch_conn(srv.port)
+    data = np.random.randint(0, 256, size=2 * MIB, dtype=np.uint8)
+    c.register_mr(data)
+    assert c.touch_stats() == ZERO_TOUCH
+    _put(c, "first", data)
+    first = c.touch_stats()
+    assert first["put_copy_bytes"] == data.nbytes
+    _walked(c, 32 * MIB)
+    pairs = [_put(c, f"run{r}", data) for r in range(8)]
+    after = c.touch_stats()
+    put = after["put_copy_bytes"] - first["put_copy_bytes"]
+    warm = after["put_touched_bytes"] - first["put_touched_bytes"]
+    assert put == 8 * data.nbytes
+    assert warm >= 0.9 * put
+    dst = np.zeros_like(data)
+    c.register_mr(dst)
+    for run in pairs:
+        c.read_cache(run, BLOCK, dst.ctypes.data)
+        assert np.array_equal(dst, data)
+    c.close()
+    srv.stop()
+
+
+def test_pretouch_fetch_only_connection_starts_no_thread():
+    """A connection that maps the pools and only reads, and one that puts
+    over the socket, walk nothing: the thread belongs to the first shm put."""
+    srv = its.start_local_server(prealloc_bytes=32 * MIB, block_bytes=16 << 10)
+    writer = _touch_conn(srv.port)
+    data = np.random.randint(0, 256, size=MIB, dtype=np.uint8)
+    writer.register_mr(data)
+    pairs = _put(writer, "v", data)
+    reader, socket_writer = _touch_conn(srv.port), _touch_conn(srv.port, shm=False)
+    assert reader.shm_active and not socket_writer.shm_active
+    dst = np.zeros_like(data)
+    reader.register_mr(dst)
+    for _ in range(3):
+        reader.read_cache(pairs, BLOCK, dst.ctypes.data)
+    assert np.array_equal(dst, data)
+    socket_writer.register_mr(data)
+    _put(socket_writer, "s", data)
+    _walked(writer, 16 * MIB)  # time enough for a thread that should not be
+    assert reader.touch_stats() == ZERO_TOUCH and socket_writer.touch_stats() == ZERO_TOUCH
+    for c in (writer, reader, socket_writer):
+        c.close()
+    srv.stop()
+
+
+@pytest.mark.parametrize("puts", [False, True], ids=["no-thread", "thread"])
+@pytest.mark.parametrize("how", ["close", "reconnect"])
+def test_pretouch_close_and_reconnect(how, puts):
+    """close() and reconnect() with and without a started thread; with one,
+    close() lands while it is mid-walk (a 256 MiB pool, closed at once)."""
+    srv = its.start_local_server(prealloc_bytes=256 * MIB, block_bytes=16 << 10)
+    port = srv.port
+    c = _touch_conn(port)
+    data = np.random.randint(0, 256, size=MIB, dtype=np.uint8)
+    c.register_mr(data)
+    if puts:
+        _put(c, "a", data)
+    if how == "reconnect":
+        srv.stop()
+        for _ in range(50):
+            try:
+                srv = its.start_local_server(
+                    host="127.0.0.1", service_port=port,
+                    prealloc_bytes=256 * MIB, block_bytes=16 << 10,
+                )
+                break
+            except its.InfiniStoreException:
+                time.sleep(0.1)
+        else:
+            pytest.skip("could not rebind the port")
+        with pytest.raises(its.InfiniStoreException):
+            for _ in range(10):
+                _put(c, "dead", data)
+        c.reconnect()
+        # A new handle: its ledger starts over, and its first put its thread.
+        assert c.touch_stats()["pretouch_bytes"] == 0
+        pairs = _put(c, "b", data)
+        dst = np.zeros_like(data)
+        c.register_mr(dst)
+        c.read_cache(pairs, BLOCK, dst.ctypes.data)
+        assert np.array_equal(dst, data)
+    c.close()
+    srv.stop()
+
+
+def test_pretouch_far_put_moves_frontier():
+    """A put that lands outside everything touched (here: below the frontier,
+    in space another connection freed) is a cold put that succeeds and moves
+    the frontier there; nothing depends on where the allocator puts it."""
+    srv = its.start_local_server(prealloc_bytes=64 * MIB, block_bytes=16 << 10)
+    other, c = _touch_conn(srv.port, shm=False), _touch_conn(srv.port)
+    filler = np.random.randint(0, 256, size=8 * MIB, dtype=np.uint8)
+    other.register_mr(filler)
+    held = _put(other, "filler", filler)
+    data = np.random.randint(0, 256, size=MIB // 2, dtype=np.uint8)
+    c.register_mr(data)
+    _put(c, "high", data)  # past the filler: the frontier starts there
+    walked = _walked(c, 56 * MIB)  # chunks 8..63: everything past the put
+    other.delete_keys([k for k, _ in held])
+    before = c.touch_stats()
+    pairs = _put(c, "low", data)  # into the freed head of the pool
+    after = c.touch_stats()
+    assert after["put_copy_bytes"] - before["put_copy_bytes"] == data.nbytes
+    assert after["put_touched_bytes"] == before["put_touched_bytes"]  # cold
+    _walked(c, walked + 8 * MIB)  # the frontier moved: the head is walked now
+    before = c.touch_stats()
+    _put(c, "low2", data)
+    assert c.touch_stats()["put_touched_bytes"] - before["put_touched_bytes"] == data.nbytes
+    dst = np.zeros_like(data)
+    c.register_mr(dst)
+    c.read_cache(pairs, BLOCK, dst.ctypes.data)
+    assert np.array_equal(dst, data)
+    for conn in (other, c):
+        conn.close()
+    srv.stop()
+
+
+def test_pretouch_changes_no_byte_under_a_concurrent_writer():
+    """A second connection writes the pages the first one's thread is walking
+    (and its own thread walks them too): every value reads back exact."""
+    srv = its.start_local_server(prealloc_bytes=192 * MIB, block_bytes=16 << 10)
+    a, b = _touch_conn(srv.port), _touch_conn(srv.port)
+    spark = np.random.randint(0, 256, size=BLOCK, dtype=np.uint8)
+    a.register_mr(spark)
+    data = np.random.randint(0, 256, size=16 * MIB, dtype=np.uint8)
+    b.register_mr(data)
+    _put(a, "spark", spark)  # a's thread starts walking the 192 MiB from here
+    runs = [_put(b, f"w{r}", data) for r in range(8)]  # b writes 128 MiB of them
+    assert a.touch_stats()["pretouch_bytes"] > 0 and b.touch_stats()["pretouch_bytes"] > 0
+    _walked(a, 160 * MIB)
+    dst = np.zeros_like(data)
+    a.register_mr(dst)
+    for run in runs:
+        dst[:] = 0
+        a.read_cache(run, BLOCK, dst.ctypes.data)
+        assert np.array_equal(dst, data)
+    for conn in (a, b):
+        conn.close()
+    srv.stop()
+
+
+def test_pretouch_walks_an_auto_extended_pool():
+    """Puts that spill into an extension pool start a frontier there: the
+    thread walks more than the first pool holds."""
+    srv = its.start_local_server(
+        prealloc_bytes=8 * MIB, block_bytes=16 << 10, auto_increase=True,
+        extend_bytes=16 * MIB,
+    )
+    c = _touch_conn(srv.port)
+    data = np.random.randint(0, 256, size=6 * MIB, dtype=np.uint8)
+    c.register_mr(data)
+    runs = [_put(c, f"x{r}", data) for r in range(3)]  # 18 MiB on 8 + 16
+    stats = c.touch_stats()
+    assert stats["put_copy_bytes"] == 3 * data.nbytes
+    _walked(c, 10 * MIB)
+    dst = np.zeros_like(data)
+    c.register_mr(dst)
+    for run in runs:
+        c.read_cache(run, BLOCK, dst.ctypes.data)
+        assert np.array_equal(dst, data)
+    c.close()
+    srv.stop()
