@@ -333,9 +333,9 @@ class KVConnector:
         # (they differ where a tensor's policy is the hit's trailing blocks,
         # CacheTensor.last_blocks), the same two in bytes and, of the bytes
         # fetched, those of a recurrent state (kind "state": what does not
-        # grow with the prefix); of the saves, the bytes of state and of
-        # latent tensors written; the bytes their layer reads
-        # landed and the microseconds in which at least one such read was in
+        # grow with the prefix); of the saves, the bytes written and those
+        # of them by the tensor's kind (kv, state, latent); the bytes the
+        # hits' layer reads landed and the microseconds in which at least one such read was in
         # flight (with the gauge and the perf_counter mark that union is
         # kept by). Of the installs: bytes handed to the device and the
         # summed time of the executor calls that handed them (host time, not
@@ -351,6 +351,7 @@ class KVConnector:
             "hit_values_fetched": 0, "hit_values_whole_prefix": 0,
             "hit_bytes_fetched": 0, "hit_bytes_whole_prefix": 0,
             "hit_state_bytes_fetched": 0,
+            "save_bytes": 0, "save_kv_bytes": 0,
             "save_state_bytes": 0, "save_latent_bytes": 0,
             "hit_read_bytes": 0, "hit_read_busy_us": 0.0,
             "hit_reads_in_flight": 0, "hit_read_busy_mark_s": 0.0,

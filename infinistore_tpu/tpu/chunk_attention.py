@@ -39,10 +39,10 @@ from jax.experimental.pallas import tpu as pltpu
 from . import paged
 
 _NEG_INF = -1e30
-# Tokens folded per grid step: 8 pages of 16 tokens, a page per operand, so
-# the step's DMAs overlap the previous step's compute through the pipeline's
-# double buffers. On a v5e 256 ran 10-16% under 128 (and level with 512) at
-# twice the Mosaic compile time: a millisecond or two of a resume.
+# Tokens folded per grid step: 8 pages of 16 tokens, a page per operand (a
+# longer page, 1,024 tokens, is a step of its own: PERF.md, PR 43), so the
+# DMAs overlap the previous step's compute. On a v5e 256 ran 10-16% under 128
+# (level with 512) at twice the Mosaic compile time: a millisecond of a resume.
 _STEP_TOKENS = 128
 # Chunk rows a tile: a longer chunk is cut into tiles along a grid axis of
 # its own, each with its own walk up to its own last row, so what is resident

@@ -386,8 +386,10 @@ class LayerwiseKVWriter:
                         time.perf_counter() - t_wait
                     ) * 1e6
                     for t, _, m, _ in plan:
-                        if t.kind != "kv":
-                            counters[f"save_{t.kind}_bytes"] += m * t.nbytes
+                        # By kind, and their total (a caller's own dict may
+                        # name neither: the keys are made here).
+                        for key in ("save_bytes", f"save_{t.kind}_bytes"):
+                            counters[key] = counters.get(key, 0) + m * t.nbytes
                 # Where each tensor's blocks lie on the host: in the one
                 # packed array at its offset, or in an array of its own.
                 bases = (

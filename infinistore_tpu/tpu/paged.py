@@ -26,7 +26,10 @@ from jax.experimental.pallas import tpu as pltpu
 @dataclass(frozen=True)
 class CacheTensor:
     """One named per-block tensor of a layer's cache: a block of it is one
-    value in the store, under the key kind ``name``."""
+    value in the store, under the key kind ``name``. The hit policy is the
+    TENSOR's: one layer may hold tensors fetched in every block of a hit (a K
+    and a V) beside tensors fetched in its last block alone (a recurrent
+    state and its convolution tail)."""
 
     name: str  # the store key's kind: "k", "v", "latent", "state", "tail"
     block_shape: Tuple[int, ...]  # one block's shape (the cache adds a leading block axis)
@@ -57,8 +60,12 @@ class PagedKVCacheSpec:
     policy. The common case, a K and a V of ONE shape for all layers, is
     written with the scalar fields (``num_kv_heads``, ``head_dim``,
     ``dtype``, ``windows``) and ``layers`` left None; a cache whose layers
-    differ in kind (a latent beside a recurrent state) names ``layers`` and
-    leaves the K/V fields at 0 (:meth:`of_layers`)."""
+    differ in kind (a latent layer beside a recurrent one), or whose every
+    layer is MIXED (``(k, v, state, tail)``: two policies and two dtypes in
+    one layer, ``has_state`` and a page list true together), names ``layers``
+    and leaves the K/V fields at 0 (:meth:`of_layers`). Whoever asks what a
+    hit fetches asks a tensor (``CacheTensor.hit_first``; ``hit_values``,
+    ``hit_nbytes`` and the data plane's ``_layer_plan`` do)."""
 
     num_layers: int
     num_blocks: int
@@ -147,13 +154,21 @@ class PagedKVCacheSpec:
 
     def hit_first_block(self, layer: int, n_blocks: int) -> int:
         """First block of an ``n_blocks`` prefix that a hit fetches and
-        installs for ``layer``'s first tensor (of a K/V layer, both): 0 for a
-        full layer, and for a sliding one the first of its last ``window /
-        block_tokens`` blocks. A question token at prefix position P + i sees
-        back to P + i - window + 1, which lies in block ``n_blocks - window /
-        block_tokens`` or later. Every block of every layer is still SAVED,
-        so that any shorter prefix can resume."""
-        return self.layer_tensors(layer)[0].hit_first(n_blocks)
+        installs for ``layer``, where its tensors agree (of a K/V layer,
+        both): 0 for a full layer, and for a sliding one the first of its last
+        ``window / block_tokens`` blocks. A question token at prefix position
+        P + i sees back to P + i - window + 1, which lies in block ``n_blocks
+        - window / block_tokens`` or later. Every block of every layer is
+        still SAVED, so that any shorter prefix can resume. A layer whose
+        tensors differ in policy has no one answer: ``ValueError``, ask the
+        tensor (``CacheTensor.hit_first``)."""
+        firsts = {t.hit_first(n_blocks) for t in self.layer_tensors(layer)}
+        if len(firsts) > 1:
+            raise ValueError(
+                f"layer {layer}'s tensors differ in hit policy (first blocks {sorted(firsts)} of "
+                f"{n_blocks}): ask the tensor's hit_first"
+            )
+        return firsts.pop()
 
     def hit_values(self, n_blocks: int) -> Tuple[int, int]:
         """(trailing, whole): the store values (one block of one tensor of

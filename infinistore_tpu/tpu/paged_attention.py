@@ -86,11 +86,11 @@ def _varying_like(shape, dtype, *operands):
 
 # Keys a grid step of the ragged kernels folds: eight 16-token pages, one
 # v5e MXU tile along the key axis, a copy a page into the step's half of a
-# double buffer. tpu/chunk_attention.py measured the same choice for the
-# resume; here 64 keys a step ran 12-18% slower on 2k-8k contexts (PERF.md,
-# PR 31). A module constant: the pages a step follow the
-# cache's block size alone, never the wave's bucket (that would mint
-# programs).
+# double buffer; 64 keys a step ran 12-18% slower on 2k-8k contexts (PERF.md,
+# PR 31). A page LONGER than this (1,024 tokens where the block is a state's
+# snapshot interval) is a step of its own: slices of it ran slower (PR 43).
+# A module constant: the pages a step follow the cache's block size alone,
+# never the wave's bucket (that would mint programs).
 _STEP_TOKENS = 128
 
 
