@@ -125,10 +125,11 @@ lib.its_conn_ring_poll_counters.argtypes = [
     c_void_p, POINTER(c_uint64), POINTER(c_uint64), POINTER(c_uint64),
     POINTER(c_uint64),
 ]
-# Put pre-touch ledger: put copy bytes, those landed on touched chunks,
-# bytes the touch thread walked, us the reactor spent in the copies
+# The shm copies' ledger: put copy bytes (all through a pool file's
+# descriptor), the pwritev calls that took, us the reactor spent in the
+# copies, bytes located gets read through a descriptor
 # (lib.InfinityConnection.touch_stats).
-lib.its_conn_touch_counters.argtypes = [
+lib.its_conn_put_counters.argtypes = [
     c_void_p, POINTER(c_uint64), POINTER(c_uint64), POINTER(c_uint64),
     POINTER(c_uint64),
 ]

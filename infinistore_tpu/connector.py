@@ -1009,9 +1009,9 @@ class KVConnector:
         latency a write that started foreground took; ``save_put_bytes``
         over ``save_put_busy_us`` (the union of the time in which a save's
         put was in flight), the rate the store took the saves at. Beside
-        them the connection's own put pre-touch ledger (``touch_stats``):
-        ``put_touched_bytes`` of ``put_copy_bytes``, ``put_copy_us``,
-        ``pretouch_bytes``."""
+        them the connection's own put copy ledger (``touch_stats``):
+        ``put_file_bytes`` of ``put_copy_bytes``, ``put_file_calls``,
+        ``put_copy_us``, and ``put_touched_bytes``, ``pretouch_bytes``."""
         self._require_store("get_stats")
         touch = getattr(self.conn, "touch_stats", dict)
         return {**self.conn.get_stats(), **touch(), **self.hit_counters}

@@ -1493,27 +1493,38 @@ class InfinityConnection:
         }
 
     def touch_stats(self) -> dict:
-        """The put pre-touch's ledger (docs/design.md, "Who faults on a
-        put"; native, always on, this handle's lifetime): ``put_copy_bytes``
-        the two-phase shm put copied into mapped pools, ``put_touched_bytes``
-        the part of them that landed on chunks this connection's mapping
-        had already touched (``put_touched_share`` reads the two),
-        ``put_copy_us`` the reactor thread's time in those copies (bytes
-        over it: the copy's own rate, which is what a touched page buys),
-        ``pretouch_bytes`` the touch thread walked. All 0 on a connection
-        that never put through shm: it has started no thread."""
-        put, warm, walked, us = (ctypes.c_uint64() for _ in range(4))
+        """The put copy's ledger (docs/design.md, "A put's copy rides the
+        pool's file"; native, always on, this handle's lifetime):
+        ``put_copy_bytes`` the two-phase shm put copied into pools,
+        ``put_file_bytes`` the part of them that went through a pool file's
+        descriptor (``put_file_share`` reads the two), ``put_file_calls``
+        the ``pwritev`` calls that took (one a run of values that lie side
+        by side), ``put_copy_us`` the reactor thread's time in those copies
+        (bytes over it: the copy's own rate). ``put_touched_bytes`` is the
+        bytes of a put's copy that took no first-touch fault on the reactor
+        thread: a descriptor's copy takes none, so it reads
+        ``put_file_bytes``; ``pretouch_bytes`` is 0 (no thread walks the
+        pool any more; both keys stay for the metric that reads them).
+        ``get_file_bytes``: the bytes located gets (``GetLoc``: a read into
+        a plain buffer) copied out through a descriptor, ``preadv``. All 0
+        on a connection that never moved a payload through shm."""
+        filed, calls, us, got = (ctypes.c_uint64() for _ in range(4))
         with self._lock:
             if self._handle is not None:
-                lib.its_conn_touch_counters(
-                    self._handle, ctypes.byref(put), ctypes.byref(warm),
-                    ctypes.byref(walked), ctypes.byref(us),
+                lib.its_conn_put_counters(
+                    self._handle, ctypes.byref(filed), ctypes.byref(calls),
+                    ctypes.byref(us), ctypes.byref(got),
                 )
+        # One native counter behind three keys: every two-phase copy goes
+        # through the descriptor, and none of them faults on the reactor.
         return {
-            "put_copy_bytes": put.value,
-            "put_touched_bytes": warm.value,
+            "put_copy_bytes": filed.value,
+            "put_touched_bytes": filed.value,
             "put_copy_us": us.value,
-            "pretouch_bytes": walked.value,
+            "pretouch_bytes": 0,
+            "put_file_bytes": filed.value,
+            "put_file_calls": calls.value,
+            "get_file_bytes": got.value,
         }
 
     def qos_stats(self) -> dict:
@@ -1545,6 +1556,9 @@ class InfinityConnection:
           ``pools``, ``pinned`` — store occupancy and pool directory size;
         - ``connections``, ``conns_accepted`` — live vs lifetime-accepted
           data-plane connections;
+        - ``get_into_file_bytes`` — bytes ``GetInto`` (a read into an
+          ``alloc_shm_mr`` buffer) copied out of pool files with ``preadv`` on
+          their descriptors, not through the server's mapping;
         - ``spill``: ``entries``, ``bytes``, ``capacity``, ``promotions``,
           ``dropped`` — the disk spill tier;
         - ``qos``: ``fg_ops``/``bg_ops``, ``fg_slices``/``bg_slices``,
@@ -2338,12 +2352,12 @@ class StripedConnection:
         }
 
     def touch_stats(self) -> dict:
-        """The put pre-touch's ledger summed over the stripes (each maps the
+        """The put copy's ledger summed over the stripes (each maps the
         pools itself; see InfinityConnection.touch_stats)."""
-        out = {"put_copy_bytes": 0, "put_touched_bytes": 0, "put_copy_us": 0, "pretouch_bytes": 0}
+        out: dict = {}
         for c in self.conns:
             for k, v in c.touch_stats().items():
-                out[k] += v
+                out[k] = out.get(k, 0) + v
         return out
 
     def completion_stats(self) -> dict:

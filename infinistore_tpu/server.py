@@ -135,6 +135,10 @@ def _prometheus_text(stats: dict, membership_status: dict = None,
         f"infinistore_connections {stats['connections']}",
         "# TYPE infinistore_connections_accepted counter",
         f"infinistore_connections_accepted {stats['conns_accepted']}",
+        # Bytes GetInto read out of pool files through their descriptors
+        # (docs/design.md, "A put's copy rides the pool's file").
+        "# TYPE infinistore_get_into_file_bytes counter",
+        f"infinistore_get_into_file_bytes {stats['get_into_file_bytes']}",
         "# TYPE infinistore_pools gauge",
         f"infinistore_pools {stats['pools']}",
         "# TYPE infinistore_pool_pinned gauge",

@@ -18,7 +18,6 @@
 #include <sys/uio.h>
 
 #include <atomic>
-#include <condition_variable>
 #include <cstdint>
 #include <deque>
 #include <memory>
@@ -205,14 +204,14 @@ class Connection {
     // loop wakeup instead of arming one each), so pushed/signalled is the
     // mean completion batch per wakeup the bench reports.
     void completion_counters(uint64_t* pushed, uint64_t* signalled) const;
-    // Put pre-touch ledger (docs/design.md, "Who faults on a put"): bytes the
-    // two-phase shm put copied into mapped pools, the part of them that
-    // landed on chunks this connection's mapping had already touched, bytes
-    // the touch thread itself has walked, and the reactor's time in those
-    // copies. All zero on a connection that never put through shm: it has
-    // started no thread.
-    void touch_counters(uint64_t* put_bytes, uint64_t* put_touched_bytes,
-                        uint64_t* touched_bytes, uint64_t* put_copy_us) const;
+    // The shm copies' ledger (docs/design.md, "A put's copy rides the pool's
+    // file"): bytes the two-phase shm put copied into pools (every one
+    // through a pool file's descriptor), the pwritev calls that took, the
+    // reactor's time in those copies, and the bytes located gets read
+    // through a descriptor. All zero on a connection that never moved a
+    // payload through shm.
+    void put_counters(uint64_t* put_file_bytes, uint64_t* put_file_calls, uint64_t* put_copy_us,
+                      uint64_t* get_file_bytes) const;
 
   private:
     struct Request;
@@ -220,6 +219,7 @@ class Connection {
     struct ShmMap {
         char* base = nullptr;
         size_t size = 0;
+        int fd = -1;  // the pool file, open for as long as the mapping stands
     };
 
     void reactor();
@@ -269,17 +269,18 @@ class Connection {
     // Reactor-side: drain the completion ring, completing parked requests.
     // Returns false on a corrupt ring (fails the connection).
     bool drain_cq();
-    char* map_pool(uint16_t pool_id, const std::string& name, uint64_t size);
+    // The pool's mapping and descriptor, made on first use; base == nullptr
+    // where the segment cannot be opened or mapped.
+    ShmMap map_pool(uint16_t pool_id, const std::string& name, uint64_t size);
     // Reactor-side: handle a PutAlloc/GetLoc response. Returns the request
     // back if it must be re-queued (put commit phase), nullptr when done.
     std::unique_ptr<Request> shm_phase(std::unique_ptr<Request> req, uint32_t status);
     void queue_release(uint64_t ticket);
-    // Reactor-side, before a put's copy: count how much of it lands on
-    // touched chunks, move the pool's frontier, start the touch thread on
-    // the first call. Never waits for a touch.
-    void touch_note_put(uint16_t pool_id, char* base, size_t pool_size, uint64_t offset,
-                        size_t len);
-    void touch_loop();
+    // Reactor-side: phase one of a put (write: mem[i] into the file fds[i]
+    // at locs[i].offset) or a located get's copy (the other way). False
+    // when a transfer failed (nothing is published or completed yet).
+    bool copy_through_fd(bool write, const std::vector<ShmLoc>& locs,
+                         const std::vector<int>& fds, const std::vector<iovec>& mem);
 
     ClientConfig config_;
     int fd_ = -1;
@@ -349,34 +350,12 @@ class Connection {
     mutable std::mutex shm_mu_;
     std::unordered_map<uint16_t, ShmMap> shm_pools_ ITS_GUARDED_BY(shm_mu_);
 
-    // Put pre-touch. A pool page costs THIS mapping a fault the first time
-    // it is written, whoever else has touched it, and shm_phase's memcpy
-    // would take those faults on the reactor thread. So one thread a
-    // connection, started by its first shm put (never by map_pool: a
-    // fetch-only connection maps pools and runs none), keeps the chunks
-    // from the pool's frontier to kTouchLead past it touched, with a write
-    // that changes no byte. The frontier follows the puts: it moves up with
-    // them, and to wherever a put lands on an untouched chunk. touch_mu_ is
-    // held for bookkeeping only, never across a touch, so the reactor waits
-    // for a few loads and stores at most.
-    static constexpr size_t kTouchChunk = size_t{1} << 20;
-    static constexpr size_t kTouchLead = size_t{1} << 30;
-    struct TouchPool {
-        char* base = nullptr;
-        size_t size = 0;
-        std::vector<bool> touched;  // one a kTouchChunk of the mapping
-        size_t frontier = 0;        // chunk the lead is counted from
-    };
-    std::thread touch_thread_;  // started by the reactor, joined in close()
-    std::mutex touch_mu_;
-    std::condition_variable touch_cv_;
-    bool touch_stop_ ITS_GUARDED_BY(touch_mu_) = false;
-    std::unordered_map<uint16_t, TouchPool> touch_pools_ ITS_GUARDED_BY(touch_mu_);
-    uint16_t touch_last_pool_ ITS_GUARDED_BY(touch_mu_) = 0;
-    std::atomic<uint64_t> put_copy_bytes_{0};
-    std::atomic<uint64_t> put_touched_bytes_{0};
-    std::atomic<uint64_t> touch_bytes_{0};
+    // The shm copies' ledger (put_counters). The copies need no state: they
+    // go through ShmMap::fd, and the mapping stays for the bounds check.
+    std::atomic<uint64_t> put_file_bytes_{0};
+    std::atomic<uint64_t> put_file_calls_{0};
     std::atomic<uint64_t> put_copy_us_{0};
+    std::atomic<uint64_t> get_file_bytes_{0};
 
     // Descriptor-ring state (docs/descriptor_ring.md; "dring" because the
     // PR 2 completion ring above already owns the plain ring_/ring_mu_

@@ -4,6 +4,7 @@
 // lands everywhere at once.
 #pragma once
 
+#include <errno.h>
 #include <sys/uio.h>
 
 #include <algorithm>
@@ -52,6 +53,29 @@ struct ScatterCursor {
         }
     }
 };
+
+// Move the regions v[first, end) to (write) or from ONE contiguous range of a
+// file that starts at `off`: as few vectored calls as kMaxIov a call allows,
+// a short transfer resumed where it stopped, EINTR retried. False on an
+// error or an early end of file; `calls`, if given, counts the system calls.
+inline bool file_transfer(bool write, int fd, const std::vector<iovec>& v, size_t first,
+                          size_t end, uint64_t off, uint64_t* calls = nullptr) {
+    constexpr size_t kMaxIov = 256;
+    iovec iov[kMaxIov];
+    ScatterCursor cur{first, 0};
+    while (true) {
+        while (cur.idx < end && cur.off == v[cur.idx].iov_len) cur = {cur.idx + 1, 0};
+        if (cur.idx >= end) return true;
+        int cnt = static_cast<int>(cur.fill(v, iov, std::min(kMaxIov, end - cur.idx)));
+        ssize_t moved = write ? pwritev(fd, iov, cnt, static_cast<off_t>(off))
+                              : preadv(fd, iov, cnt, static_cast<off_t>(off));
+        if (moved < 0 && errno == EINTR) continue;
+        if (calls != nullptr) ++*calls;
+        if (moved <= 0) return false;
+        cur.advance(v, static_cast<size_t>(moved));
+        off += static_cast<uint64_t>(moved);
+    }
+}
 
 // Build the remaining iovec view of a framed message (fixed header, metadata
 // body, then payload regions) given `sent` bytes already written.

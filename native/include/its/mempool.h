@@ -63,6 +63,9 @@ class MemoryPool {
     bool pinned() const { return pinned_; }
     // Empty when the pool is anonymous (shm backing unavailable/disabled).
     const std::string& shm_name() const { return shm_name_; }
+    // The segment's descriptor, -1 for an anonymous pool: a copy out of the
+    // pool can ride it instead of this process's mapping (Server's GetInto).
+    int shm_fd() const { return shm_backed_ ? shm_fd_ : -1; }
 
   private:
     char* base_ = nullptr;
@@ -142,6 +145,11 @@ class MM {
     bool shm_enabled() const { return shm_prefix_ != nullptr; }
     // Translate a pool pointer into (pool_id, offset) for the directory.
     PoolLoc locate(const void* ptr) const;
+    // The descriptor of the pool file `loc` lies in, -1 where the pool is
+    // anonymous memory (or loc was not found).
+    int shm_fd(const PoolLoc& loc) const {
+        return loc.found ? pools_[loc.pool_id]->shm_fd() : -1;
+    }
 
   private:
     std::string next_shm_name();

@@ -183,6 +183,9 @@ class Server {
     void queue_cont(Conn* c);
     void suspend_for_cont(Conn* c);
     void run_cont_slice(Conn* c);
+    // GetInto's copy of blocks [first, first + count) into the client's
+    // segment: through the pool file's descriptor where the pool is a file.
+    void copy_out(Conn* c, char* seg_base, size_t first, size_t count);
     void run_getloc_slice(Conn* c);
     void run_putalloc_slice(Conn* c);
     // Shared promote+pin slice for GetLoc and GetInto's pin phase; the
@@ -283,6 +286,9 @@ class Server {
     std::vector<std::unique_ptr<Conn>> graveyard_;
     std::unordered_map<uint8_t, OpStats> stats_;
     uint64_t conns_accepted_ = 0;
+    // Bytes GetInto read out of pools through their descriptors (stats:
+    // get_into_file_bytes).
+    uint64_t get_file_bytes_ = 0;
 
     // Descriptor-ring plane: connections with an attached ring (drained
     // every loop pass) and the server half of the ring ledger

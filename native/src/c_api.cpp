@@ -193,14 +193,14 @@ int its_conn_drain_completions(void* c, uint64_t* tokens, int32_t* codes, int ca
 void its_conn_completion_counters(void* c, uint64_t* pushed, uint64_t* signalled) {
     static_cast<Connection*>(c)->completion_counters(pushed, signalled);
 }
-// Put pre-touch ledger (docs/design.md, "Who faults on a put"): bytes the
-// two-phase shm put copied, those that landed on chunks this connection's
-// mapping had touched, bytes the touch thread walked, the reactor's time in
-// the copies (us).
-void its_conn_touch_counters(void* c, uint64_t* put_bytes, uint64_t* put_touched_bytes,
-                             uint64_t* touched_bytes, uint64_t* put_copy_us) {
-    static_cast<Connection*>(c)->touch_counters(put_bytes, put_touched_bytes, touched_bytes,
-                                                put_copy_us);
+// The shm copies' ledger (docs/design.md, "A put's copy rides the pool's
+// file"): bytes the two-phase shm put copied (all through a pool file's
+// descriptor), the pwritev calls that took, the reactor's time in the copies
+// (us), and the bytes located gets read through a descriptor.
+void its_conn_put_counters(void* c, uint64_t* put_file_bytes, uint64_t* put_file_calls,
+                           uint64_t* put_copy_us, uint64_t* get_file_bytes) {
+    static_cast<Connection*>(c)->put_counters(put_file_bytes, put_file_calls, put_copy_us,
+                                              get_file_bytes);
 }
 
 // ``priority``: QoS class tag (its::Priority) — 0 foreground (default

@@ -211,28 +211,37 @@ void Connection::shm_handshake() {
         if (resp.pools.empty()) return;
         size_t mapped = 0;
         for (const auto& p : resp.pools)
-            if (map_pool(p.pool_id, p.name, p.size) != nullptr) mapped++;
+            if (map_pool(p.pool_id, p.name, p.size).base != nullptr) mapped++;
         shm_ok_.store(mapped == resp.pools.size());
     } catch (const std::exception& e) {
         ITS_LOG_WARN("shm handshake parse failed: %s", e.what());
     }
 }
 
-char* Connection::map_pool(uint16_t pool_id, const std::string& name, uint64_t size) {
+Connection::ShmMap Connection::map_pool(uint16_t pool_id, const std::string& name,
+                                        uint64_t size) {
     {
         std::lock_guard<std::mutex> lock(shm_mu_);
         auto it = shm_pools_.find(pool_id);
-        if (it != shm_pools_.end()) return it->second.base;
+        if (it != shm_pools_.end()) return it->second;
     }
     int fd = shm_open(name.c_str(), O_RDWR, 0);
-    if (fd < 0) return nullptr;
+    if (fd < 0) return {};
     void* mem = mmap(nullptr, size, PROT_READ | PROT_WRITE, MAP_SHARED, fd, 0);
-    ::close(fd);
-    if (mem == MAP_FAILED) return nullptr;
+    if (mem == MAP_FAILED) {
+        ::close(fd);
+        return {};
+    }
+    // The descriptor stays open beside the mapping: the payload copies go
+    // through it (shm_phase). Both go together, in close().
     std::lock_guard<std::mutex> lock(shm_mu_);
-    auto [it, inserted] = shm_pools_.emplace(pool_id, ShmMap{static_cast<char*>(mem), size});
-    if (!inserted) munmap(mem, size);  // lost a race; keep the existing mapping
-    return it->second.base;
+    auto [it, inserted] =
+        shm_pools_.emplace(pool_id, ShmMap{static_cast<char*>(mem), size, fd});
+    if (!inserted) {  // lost a race; keep the existing mapping
+        munmap(mem, size);
+        ::close(fd);
+    }
+    return it->second;
 }
 
 // Create the descriptor-ring segment and ask the server to attach it.
@@ -599,23 +608,12 @@ void Connection::close() {
     connected_.store(false);
     shm_ok_.store(false);
     ring_teardown();  // in-flight ring ops were failed by the reactor's fail_all
-    // The touch thread walks the pool mappings: it goes before they do. Only
-    // a connection that put through shm has one (the reactor that started
-    // it is joined above, so nobody starts one now).
-    if (touch_thread_.joinable()) {
-        {
-            std::lock_guard<std::mutex> lock(touch_mu_);
-            touch_stop_ = true;
-        }
-        touch_cv_.notify_one();
-        touch_thread_.join();
-        std::lock_guard<std::mutex> lock(touch_mu_);
-        touch_stop_ = false;
-        touch_pools_.clear();
-    }
     {
         std::lock_guard<std::mutex> lock(shm_mu_);
-        for (auto& [id, m] : shm_pools_) munmap(m.base, m.size);
+        for (auto& [id, m] : shm_pools_) {
+            munmap(m.base, m.size);
+            ::close(m.fd);
+        }
         shm_pools_.clear();
     }
     std::lock_guard<std::mutex> lock(mr_mu_);
@@ -1345,9 +1343,10 @@ bool Connection::read_ready() {
     }
 }
 
-// Handle a shm fast-path response on the reactor thread: memcpy payload
-// between user memory and the mapped pools, then either requeue the request
-// as a commit (put) or release the server-side pins and complete (get).
+// Handle a shm fast-path response on the reactor thread: move the payload
+// between user memory and the pools, through the pool file's descriptor
+// (copy_through_fd), then either requeue the request as a commit (put) or
+// release the server-side pins and complete (get).
 std::unique_ptr<Connection::Request> Connection::shm_phase(std::unique_ptr<Request> req,
                                                            uint32_t status) {
     bool put = req->op == kOpPutAlloc;
@@ -1381,26 +1380,21 @@ std::unique_ptr<Connection::Request> Connection::shm_phase(std::unique_ptr<Reque
     }
     size_t n = resp.locs.size();
     bool ok = put ? n == req->tx_payload.size() : n == req->rx_addrs.size();
-    std::vector<char*> at(n);
-    std::vector<ShmMap> pool_of(put ? n : 0);  // the mapping each put location lies in
+    std::vector<int> fd_of(n);  // the pool file each location lies in
+    std::vector<iovec> mem(n);  // and the caller's memory it is copied from / to
     for (size_t i = 0; ok && i < n; i++) {
         const ShmLoc& l = resp.locs[i];
-        char* base = nullptr;
-        size_t mapped_size = 0;
+        ShmMap pool;
         {
             std::lock_guard<std::mutex> lock(shm_mu_);
             auto it = shm_pools_.find(l.pool_id);
-            if (it != shm_pools_.end()) {
-                base = it->second.base;
-                mapped_size = it->second.size;
-            }
+            if (it != shm_pools_.end()) pool = it->second;
         }
-        if (base == nullptr) {
+        if (pool.base == nullptr) {
             // Auto-extended pool: map on demand from the embedded directory.
             for (const auto& p : resp.pools) {
                 if (p.pool_id == l.pool_id) {
-                    base = map_pool(p.pool_id, p.name, p.size);
-                    mapped_size = p.size;
+                    pool = map_pool(p.pool_id, p.name, p.size);
                     break;
                 }
             }
@@ -1418,39 +1412,41 @@ std::unique_ptr<Connection::Request> Connection::shm_phase(std::unique_ptr<Reque
             return nullptr;
         }
         // Bounds-check against the mapping: a malformed location must not
-        // drive memcpy out of the pool (the socket path bounds everything
+        // drive a copy out of the pool (the socket path bounds everything
         // through validated iovecs; this is the shm equivalent).
         size_t span = put ? req->tx_payload[i].iov_len : static_cast<size_t>(l.size);
-        if (base == nullptr || l.offset > mapped_size || span > mapped_size - l.offset) {
+        if (pool.base == nullptr || l.offset > pool.size || span > pool.size - l.offset) {
             ok = false;
             break;
         }
-        at[i] = base + l.offset;
-        if (put) pool_of[i] = ShmMap{base, mapped_size};
+        fd_of[i] = pool.fd;
+        mem[i] = put ? req->tx_payload[i] : iovec{req->rx_addrs[i], span};
     }
     if (!ok) {
         queue_release(resp.ticket);  // abort: drop the server-side ticket
         return fall_back(std::move(req));
     }
-    // Section covers the abandoned check AND the memcpys against caller
+    // Section covers the abandoned check AND the copies against caller
     // memory: a timed-out waiter blocks until we exit it (bounded loop).
     IoSection sec(io_seq_);
     if (req->sync != nullptr && req->sync->abandoned.load()) {
         // Timed-out waiter: tx_payload/rx_addrs point at memory the caller
-        // may have freed — abort the ticket instead of memcpy'ing.
+        // may have freed — abort the ticket instead of copying.
         queue_release(resp.ticket);
         complete(std::move(req), static_cast<int>(kStatusUnavailable),
                  /*take_body=*/true);
         return nullptr;
     }
+    uint64_t t0 = put ? now_us() : 0;
+    bool copied = copy_through_fd(put, resp.locs, fd_of, mem);
+    if (put) put_copy_us_.fetch_add(now_us() - t0, std::memory_order_relaxed);
+    if (!copied) {
+        // A put has published nothing and a get has completed nothing: drop
+        // the ticket and run the whole op again over the socket.
+        queue_release(resp.ticket);
+        return fall_back(std::move(req));
+    }
     if (put) {
-        for (size_t i = 0; i < n; i++)
-            touch_note_put(resp.locs[i].pool_id, pool_of[i].base, pool_of[i].size,
-                           resp.locs[i].offset, req->tx_payload[i].iov_len);
-        uint64_t t0 = now_us();
-        for (size_t i = 0; i < n; i++)
-            memcpy(at[i], req->tx_payload[i].iov_base, req->tx_payload[i].iov_len);
-        put_copy_us_.fetch_add(now_us() - t0, std::memory_order_relaxed);
         // Phase 2: publish the keys (commit-on-copy-complete).
         req->op = kOpPutCommit;
         req->body.clear();
@@ -1459,108 +1455,49 @@ std::unique_ptr<Connection::Request> Connection::shm_phase(std::unique_ptr<Reque
         req->prime();
         return req;
     }
-    for (size_t i = 0; i < n; i++) memcpy(req->rx_addrs[i], at[i], resp.locs[i].size);
     queue_release(resp.ticket);
     complete(std::move(req), static_cast<int>(kStatusOk), /*take_body=*/true);
     return nullptr;
 }
 
-void Connection::touch_counters(uint64_t* put_bytes, uint64_t* put_touched_bytes,
-                                uint64_t* touched_bytes, uint64_t* put_copy_us) const {
-    *put_bytes = put_copy_bytes_.load(std::memory_order_relaxed);
-    *put_touched_bytes = put_touched_bytes_.load(std::memory_order_relaxed);
-    *touched_bytes = touch_bytes_.load(std::memory_order_relaxed);
+void Connection::put_counters(uint64_t* put_file_bytes, uint64_t* put_file_calls,
+                              uint64_t* put_copy_us, uint64_t* get_file_bytes) const {
+    *put_file_bytes = put_file_bytes_.load(std::memory_order_relaxed);
+    *put_file_calls = put_file_calls_.load(std::memory_order_relaxed);
     *put_copy_us = put_copy_us_.load(std::memory_order_relaxed);
+    *get_file_bytes = get_file_bytes_.load(std::memory_order_relaxed);
 }
 
-void Connection::touch_note_put(uint16_t pool_id, char* base, size_t pool_size,
-                                uint64_t offset, size_t len) {
-    if (len == 0) return;
-    put_copy_bytes_.fetch_add(len, std::memory_order_relaxed);
-    size_t first = offset / kTouchChunk, last = (offset + len - 1) / kTouchChunk;
-    bool moved = false;
-    {
-        std::lock_guard<std::mutex> lock(touch_mu_);
-        TouchPool& p = touch_pools_[pool_id];
-        if (p.base == nullptr) {
-            p.base = base;
-            p.size = pool_size;
-            p.touched.assign((pool_size + kTouchChunk - 1) / kTouchChunk, false);
+// The copy of a two-phase put (write) or of a located get. The pool is a
+// posix_fallocate'd tmpfs file: its pages exist, only THIS process's mapping
+// of them is cold, and an access through a cold mapping costs a first-touch
+// fault a page on whichever thread makes it (docs/design.md, "A put's copy
+// rides the pool's file"). pwritev / preadv copy to and from the same pages
+// inside the system call and touch no page-table entry of anybody.
+// Locations that lie side by side in one pool (a first-fit allocator hands a
+// put's keys out so) go out as ONE call. An error returns false with nothing
+// published or completed: every byte of a put is in the file before the
+// caller sends the commit.
+bool Connection::copy_through_fd(bool write, const std::vector<ShmLoc>& locs,
+                                 const std::vector<int>& fds, const std::vector<iovec>& mem) {
+    for (size_t first = 0, end; first < mem.size(); first = end) {
+        // [first, end): one run of locations that are contiguous in one file.
+        uint64_t bytes = mem[first].iov_len;
+        for (end = first + 1; end < mem.size() && fds[end] == fds[first] &&
+                              locs[end].offset == locs[first].offset + bytes;
+             end++)
+            bytes += mem[end].iov_len;
+        uint64_t calls = 0;
+        bool ok = file_transfer(write, fds[first], mem, first, end, locs[first].offset, &calls);
+        if (write) put_file_calls_.fetch_add(calls, std::memory_order_relaxed);
+        if (!ok) {
+            ITS_LOG_WARN("shm %s: the pool file's descriptor failed (%s)", write ? "put" : "get",
+                         strerror(errno));
+            return false;
         }
-        bool warm = true;
-        for (size_t c = first; c <= last; c++) warm = warm && p.touched[c];
-        if (warm) put_touched_bytes_.fetch_add(len, std::memory_order_relaxed);
-        // The copy that follows touches every page it covers: a chunk wholly
-        // inside it is this mapping's from here on.
-        for (size_t c = first; c <= last; c++)
-            if (c * kTouchChunk >= offset && (c + 1) * kTouchChunk <= offset + len)
-                p.touched[c] = true;
-        // A cold put moves the frontier to where it landed, wherever that
-        // is; a warm one only ever moves it up.
-        if (!warm || last > p.frontier) {
-            moved = p.frontier != last || touch_last_pool_ != pool_id;
-            p.frontier = last;
-            touch_last_pool_ = pool_id;
-        }
+        (write ? put_file_bytes_ : get_file_bytes_).fetch_add(bytes, std::memory_order_relaxed);
     }
-    // Reactor-only: close() joins the reactor before it looks at the thread.
-    if (!touch_thread_.joinable())
-        touch_thread_ = std::thread([this] { touch_loop(); });
-    else if (moved)
-        touch_cv_.notify_one();
-}
-
-// A write that changes no byte: other connections and the server store to
-// these pages at the same moment, and an atomic add of zero can lose none of
-// their stores. (A read would do on Linux, whose shmem read fault maps the
-// page writable; a sandboxed kernel may map it read-only and fault the
-// copy again. tools/putfault_probe.py reads both.) Not instrumented by
-// TSAN: the copies it runs beside are plain memcpys by design.
-__attribute__((no_sanitize("thread"))) static void touch_chunk(char* at, size_t len) {
-    static const size_t page = static_cast<size_t>(sysconf(_SC_PAGESIZE));
-    for (size_t off = 0; off < len; off += page)
-        __atomic_fetch_add(reinterpret_cast<volatile uint8_t*>(at + off), 0,
-                           __ATOMIC_RELAXED);
-}
-
-// The analysis cannot see through the condition variable's unique_lock;
-// every access to the guarded state below is under it.
-ITS_NO_THREAD_SAFETY_ANALYSIS void Connection::touch_loop() {
-    std::unique_lock<std::mutex> lock(touch_mu_);
-    while (!touch_stop_) {
-        // The first untouched chunk inside a lead: the pool of the last put
-        // that moved a frontier first, then any other.
-        TouchPool* pool = nullptr;
-        size_t chunk = 0;
-        auto find = [&](TouchPool& p) {
-            size_t end = std::min(p.touched.size(), p.frontier + kTouchLead / kTouchChunk + 1);
-            for (size_t c = p.frontier; c < end && pool == nullptr; c++) {
-                if (!p.touched[c]) {
-                    pool = &p;
-                    chunk = c;
-                }
-            }
-        };
-        auto last = touch_pools_.find(touch_last_pool_);
-        if (last != touch_pools_.end()) find(last->second);
-        for (auto it = touch_pools_.begin(); it != touch_pools_.end() && pool == nullptr; ++it)
-            find(it->second);
-        if (pool == nullptr) {
-            touch_cv_.wait(lock);
-            continue;
-        }
-        char* at = pool->base + chunk * kTouchChunk;
-        size_t len = std::min(kTouchChunk, pool->size - chunk * kTouchChunk);
-        // One chunk a slice, with the lock down: a put's bookkeeping, and
-        // close(), wait for a few hundred page touches at most.
-        lock.unlock();
-        touch_chunk(at, len);
-        touch_bytes_.fetch_add(len, std::memory_order_relaxed);
-        lock.lock();
-        // Entries are never erased while the thread runs (close() joins it
-        // first) and unordered_map keeps references across inserts.
-        pool->touched[chunk] = true;
-    }
+    return true;
 }
 
 void Connection::queue_release(uint64_t ticket) {

@@ -382,6 +382,7 @@ std::string Server::stats_json() {
               ",\"pinned\":" + (mm_->pinned() ? std::string("true") : std::string("false")) +
               ",\"connections\":" + std::to_string(conns_.size()) +
               ",\"conns_accepted\":" + std::to_string(conns_accepted_) +
+              ",\"get_into_file_bytes\":" + std::to_string(get_file_bytes_) +
               ",\"spill\":{\"entries\":" + std::to_string(kv_->spilled_entries()) +
               ",\"bytes\":" + std::to_string(kv_->spilled_bytes()) +
               ",\"capacity\":" + std::to_string(kv_->spill_capacity()) +
@@ -1463,14 +1464,7 @@ void Server::run_cont_slice(Conn* c) {
         return;
     }
     size_t chunk = std::min(budget_blocks, n - ct.copied);
-    for (size_t i = 0; i < chunk; i++) {
-        size_t k = ct.copied + i;
-        stream_copy(seg.base + ct.m.offsets[k], ct.blocks[k]->data(),
-                    ct.blocks[k]->size());
-    }
-    // The client reads these bytes the moment the completion publishes;
-    // drain the write-combining buffers before the CQE / response leaves.
-    stream_copy_fence();
+    copy_out(c, seg.base, ct.copied, chunk);
     ct.copied += chunk;
     if (ct.copied == n) {
         if (ct.from_ring) {
@@ -1494,6 +1488,40 @@ void Server::run_cont_slice(Conn* c) {
         c->reset_read();
         send_resp(c, kStatusOk, std::move(body), {}, {});
     }
+}
+
+// A value's first read would cost THIS process a first-touch fault a page if
+// it went through the pool's mapping (the two-phase put's bytes were written
+// by the client, through the pool file's descriptor: no mapping has touched
+// them), and the reactor is one thread: every op of every connection waits
+// out those faults (docs/design.md, "A put's copy rides the pool's file").
+// So where the pool is a file the copy is a preadv on its descriptor
+// (file_transfer), one call a run of blocks that lie side by side in it, into
+// the segment's slots; a block of an anonymous pool, and a run whose read
+// failed, goes through the mapping as before.
+void Server::copy_out(Conn* c, char* seg_base, size_t first, size_t count) {
+    Conn::SegCont& ct = *c->cont;
+    std::vector<iovec> slots(count);
+    for (size_t i = 0; i < count; i++)
+        slots[i] = iovec{seg_base + ct.m.offsets[first + i], ct.blocks[first + i]->size()};
+    for (size_t k = 0, stop; k < count; k = stop) {
+        // [k, stop): blocks that lie side by side in one pool.
+        const char* src = static_cast<const char*>(ct.blocks[first + k]->data());
+        uint64_t bytes = slots[k].iov_len;
+        for (stop = k + 1; stop < count && ct.blocks[first + stop]->data() == src + bytes; stop++)
+            bytes += slots[stop].iov_len;
+        PoolLoc loc = mm_->locate(src);
+        int fd = mm_->shm_fd(loc);
+        if (fd >= 0 && file_transfer(/*write=*/false, fd, slots, k, stop, loc.offset)) {
+            get_file_bytes_ += bytes;
+            continue;
+        }
+        for (size_t i = k; i < stop; i++)
+            stream_copy(slots[i].iov_base, ct.blocks[first + i]->data(), slots[i].iov_len);
+    }
+    // The client reads these bytes the moment the completion publishes;
+    // drain the write-combining buffers before the CQE / response leaves.
+    stream_copy_fence();
 }
 
 void Server::arm(Conn* c, bool want_write) {

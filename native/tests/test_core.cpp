@@ -6,6 +6,7 @@
 //
 // Deliberately dependency-free (no gtest in the image): tiny CHECK macro,
 // main() runs every case, nonzero exit on failure.
+#include <dirent.h>
 #include <fcntl.h>
 #include <string.h>
 #include <sys/eventfd.h>
@@ -233,11 +234,32 @@ static void test_loopback_end_to_end(bool enable_shm) {
     server.stop();
 }
 
-// The put pre-touch (client.h): started by the first shm put and by nothing
-// else, it walks ahead of the puts, changes no byte under a second writer,
-// and goes before the mappings in close(), started or not, mid-walk or not;
-// the same object connects and puts again afterwards.
-static void test_put_pretouch() {
+// How many descriptors this process holds on pool files (/dev/shm/its.*,
+// the descriptor rings apart).
+static size_t pool_fds() {
+    size_t n = 0;
+    DIR* d = opendir("/proc/self/fd");
+    CHECK(d != nullptr);
+    while (dirent* e = readdir(d)) {
+        char link[256];
+        std::string path = std::string("/proc/self/fd/") + e->d_name;
+        ssize_t len = readlink(path.c_str(), link, sizeof(link) - 1);
+        if (len <= 0) continue;
+        std::string target(link, static_cast<size_t>(len));
+        if (target.rfind("/dev/shm/its.", 0) == 0 && target.find(".ring") == std::string::npos)
+            n++;
+    }
+    closedir(d);
+    return n;
+}
+
+// The shm copies ride the pool file's descriptor (client.h): every byte of
+// a two-phase put is counted as written through it, side-by-side values go
+// out as one call, a second writer's bytes and the first's read back exact
+// (the located gets through the reader's descriptor), a connection that only
+// reads puts nothing, and close() gives back every descriptor, after puts
+// or not; the same object connects and puts again.
+static void test_put_file() {
     ServerConfig scfg;
     scfg.bind_addr = "127.0.0.1";
     scfg.service_port = 0;
@@ -247,12 +269,14 @@ static void test_put_pretouch() {
     scfg.enable_shm = true;
     Server server(scfg);
     CHECK(server.start());
+    size_t fds0 = pool_fds();  // the server's own
     ClientConfig ccfg;
     ccfg.host = "127.0.0.1";
     ccfg.port = server.port();
     Connection writer(ccfg), second(ccfg), reader(ccfg);
     CHECK(writer.connect() == 0 && second.connect() == 0 && reader.connect() == 0);
     CHECK(writer.shm_active() && reader.shm_active());
+    CHECK(pool_fds() == fds0 + 3);  // one a mapped pool a connection
 
     const size_t n = 32, bs = 64 << 10;  // 2 MiB a put
     std::vector<char> src(n * bs), dst(n * bs, 0);
@@ -268,30 +292,23 @@ static void test_put_pretouch() {
     };
     std::vector<uint64_t> offs;
     for (size_t i = 0; i < n; i++) offs.push_back(i * bs);
-    uint64_t put = 0, warm = 0, walked = 0, copy_us = 0;
-    auto walked_to = [&](Connection& c, uint64_t at_least) {
-        for (int i = 0; i < 5000; i++) {
-            c.touch_counters(&put, &warm, &walked, &copy_us);
-            if (walked >= at_least) return true;
-            std::this_thread::sleep_for(std::chrono::milliseconds(2));
-        }
-        return false;
-    };
+    uint64_t filed = 0, calls = 0, copy_us = 0, got = 0;
 
-    writer.touch_counters(&put, &warm, &walked, &copy_us);
-    CHECK(put == 0 && warm == 0 && walked == 0);
+    writer.put_counters(&filed, &calls, &copy_us, &got);
+    CHECK(filed == 0 && calls == 0);
+    // An empty pool hands a put's 32 values out side by side: one call.
     CHECK(writer.put_batch(keys_of("first"), offs, bs, src.data()) == 0);
-    // The second connection writes the pages the first one's thread walks.
-    for (int r = 0; r < 8; r++)
+    writer.put_counters(&filed, &calls, &copy_us, &got);
+    CHECK(filed == n * bs && calls == 1);
+    // Two writers of one pool file, turn by turn.
+    for (int r = 0; r < 8; r++) {
         CHECK(second.put_batch(keys_of("s" + std::to_string(r) + "-"), offs, bs, src.data()) == 0);
-    CHECK(walked_to(writer, 48 << 20));
-    uint64_t put0 = put, warm0 = warm;
-    CHECK(put0 == n * bs);
-    for (int r = 0; r < 8; r++)
         CHECK(writer.put_batch(keys_of("w" + std::to_string(r) + "-"), offs, bs, src.data()) == 0);
-    writer.touch_counters(&put, &warm, &walked, &copy_us);
-    CHECK(put - put0 == 8 * n * bs);
-    CHECK((warm - warm0) * 10 >= (put - put0) * 9);
+    }
+    writer.put_counters(&filed, &calls, &copy_us, &got);
+    CHECK(filed == 9 * n * bs);
+    second.put_counters(&filed, &calls, &copy_us, &got);
+    CHECK(filed == 8 * n * bs);
     for (int r = 0; r < 8; r++) {
         memset(dst.data(), 0, dst.size());
         CHECK(reader.get_batch(keys_of("s" + std::to_string(r) + "-"), offs, bs, dst.data()) == 0);
@@ -299,31 +316,36 @@ static void test_put_pretouch() {
         CHECK(reader.get_batch(keys_of("w" + std::to_string(r) + "-"), offs, bs, dst.data()) == 0);
         CHECK(memcmp(src.data(), dst.data(), src.size()) == 0);
     }
-    // A connection that only reads maps the pools and starts nothing.
-    reader.touch_counters(&put, &warm, &walked, &copy_us);
-    CHECK(put == 0 && warm == 0 && walked == 0 && copy_us == 0);
-    reader.close();  // no thread to join
-    second.close();  // a thread that ran out of work
-    // Close mid-walk, connect again on the same object, put again (close()
-    // drops the registrations with the mappings).
+    // A connection that only reads holds its descriptor, puts nothing, and
+    // copied every located get out through it.
+    reader.put_counters(&filed, &calls, &copy_us, &got);
+    CHECK(filed == 0 && calls == 0 && copy_us == 0);
+    CHECK(got == 16 * n * bs);
+    reader.close();
+    second.close();
+    CHECK(pool_fds() == fds0 + 1);
+    // Close, connect again on the same object, put again (close() drops the
+    // registrations with the mappings), over and over: no descriptor stays.
     auto again = [&] {
         writer.close();
+        CHECK(pool_fds() == fds0);
         CHECK(writer.connect() == 0);
         writer.register_mr(src.data(), src.size());
         writer.register_mr(dst.data(), dst.size());
     };
     again();
     server.purge();
-    writer.touch_counters(&put, &warm, &walked, &copy_us);
-    uint64_t walked0 = walked;
-    CHECK(writer.put_batch(keys_of("again"), offs, bs, src.data()) == 0);
-    again();  // at once: the new thread has 90-odd MiB ahead of it
+    for (int r = 0; r < 20; r++) {
+        CHECK(writer.put_batch(keys_of("again"), offs, bs, src.data()) == 0);
+        again();
+    }
+    CHECK(pool_fds() == fds0 + 1);
     CHECK(writer.put_batch(keys_of("third"), offs, bs, src.data()) == 0);
-    CHECK(walked_to(writer, walked0 + (16 << 20)));
     memset(dst.data(), 0, dst.size());
     CHECK(writer.get_batch(keys_of("third"), offs, bs, dst.data()) == 0);
     CHECK(memcmp(src.data(), dst.data(), src.size()) == 0);
     writer.close();
+    CHECK(pool_fds() == fds0);
     server.stop();
 }
 
@@ -1359,7 +1381,7 @@ int main() {
     test_ring_batch_slot_qos_ordering();
     test_loopback_end_to_end(/*enable_shm=*/true);
     test_loopback_end_to_end(/*enable_shm=*/false);
-    test_put_pretouch();
+    test_put_file();
     test_completion_ring(/*enable_shm=*/true);
     test_completion_ring(/*enable_shm=*/false);
     test_abandoned_sync_ops_stress(/*enable_shm=*/true);

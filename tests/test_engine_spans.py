@@ -197,12 +197,13 @@ def test_tracing_off_records_nothing_and_still_stamps_emits(conn, params, monkey
     assert moved["save_d2h_bytes"] == _hit_bytes(hit) and moved["hit_reads_in_flight"] == 0
     # The put's ledger (PR 42): every saved byte acknowledged over some busy
     # time, and beside it the connection's own: the two-phase shm put copied
-    # those bytes itself, the socket path copied none.
+    # those bytes itself, through the pool file's descriptor (PR 44: nothing
+    # walks the pool any more), and the socket path copied none.
     assert moved["save_put_bytes"] == moved["save_d2h_bytes"] and moved["save_put_busy_us"] > 0
     assert moved["save_puts_in_flight"] == 0
     assert moved["put_copy_bytes"] == (moved["save_put_bytes"] if conn.shm_active else 0)
-    assert moved["put_touched_bytes"] <= moved["put_copy_bytes"]
-    assert (moved["pretouch_bytes"] > 0) == conn.shm_active
+    assert moved["put_file_bytes"] == moved["put_touched_bytes"] == moved["put_copy_bytes"]
+    assert (moved["put_file_calls"] > 0) == conn.shm_active and moved["pretouch_bytes"] == 0
 
 
 # The connector's ledger of the hop (docs/observability.md), always on.

@@ -9,8 +9,13 @@ the behaviors that differ from the socket path.
 """
 
 import asyncio
+import contextlib
+import fcntl
+import os
+import re
 import socket
 import struct
+import threading
 import time
 
 import numpy as np
@@ -300,18 +305,22 @@ def test_get_with_smaller_block_size_errors_cleanly(shm):
 
 
 # ---------------------------------------------------------------------------
-# The put pre-touch (docs/design.md, "Who faults on a put"): one thread a
-# connection, started by its first shm put, keeps the pool touched ahead of
-# the puts. None of these reads a clock or a fault count: they read the
-# connection's own ledger (``touch_stats``) and the bytes.
+# A put's copy rides the pool file's descriptor (docs/design.md, "A put's
+# copy rides the pool's file"): ``pwritev`` at the location's offset, not a
+# ``memcpy`` into the client's mapping, and no thread walks the pool. None of
+# these reads a clock or a fault count: they read the connection's own ledger
+# (``touch_stats``), the process's descriptors and threads, and the bytes.
 # ---------------------------------------------------------------------------
 
 MIB = 1 << 20
 BLOCK = 64 << 10
-ZERO_TOUCH = {"put_copy_bytes": 0, "put_touched_bytes": 0, "put_copy_us": 0, "pretouch_bytes": 0}
+ZERO_PUT = {
+    "put_copy_bytes": 0, "put_touched_bytes": 0, "put_copy_us": 0, "pretouch_bytes": 0,
+    "put_file_bytes": 0, "put_file_calls": 0, "get_file_bytes": 0,
+}
 
 
-def _touch_conn(port: int, shm: bool = True) -> its.InfinityConnection:
+def _put_conn(port: int, shm: bool = True) -> its.InfinityConnection:
     c = its.InfinityConnection(its.ClientConfig(
         host_addr="127.0.0.1", service_port=port, enable_shm=shm, log_level="error",
     ))
@@ -325,87 +334,129 @@ def _put(c, prefix: str, data: np.ndarray):
     return pairs
 
 
-def _walked(c, at_least: int) -> int:
-    """Polls until the touch thread has walked ``at_least`` bytes."""
-    for _ in range(4000):
-        walked = c.touch_stats()["pretouch_bytes"]
-        if walked >= at_least:
-            return walked
-        time.sleep(0.005)
-    raise AssertionError(f"the touch thread walked {walked} of {at_least} bytes")
+def _pool_fds(but=()) -> dict:
+    """This process's descriptors on pool files (``/dev/shm/its.<pid>.<id>.<n>``;
+    rings and client segments are named otherwise), those in ``but`` apart:
+    fd -> path. The in-process server holds one a pool too."""
+    out = {}
+    for name in os.listdir("/proc/self/fd"):
+        try:
+            target = os.readlink(f"/proc/self/fd/{name}")
+        except OSError:
+            continue
+        if re.fullmatch(r"/dev/shm/its\.\d+\.[0-9a-f]+\.\d+", target) and int(name) not in but:
+            out[int(name)] = target
+    return out
 
 
-def test_pretouch_sequential_puts_land_touched():
-    """The first put is cold and starts the thread; once it has walked ahead,
-    a run of sequential puts lands on touched chunks."""
-    srv = its.start_local_server(prealloc_bytes=64 * MIB, block_bytes=16 << 10)
-    c = _touch_conn(srv.port)
-    data = np.random.randint(0, 256, size=2 * MIB, dtype=np.uint8)
-    c.register_mr(data)
-    assert c.touch_stats() == ZERO_TOUCH
-    _put(c, "first", data)
-    first = c.touch_stats()
-    assert first["put_copy_bytes"] == data.nbytes
-    _walked(c, 32 * MIB)
-    pairs = [_put(c, f"run{r}", data) for r in range(8)]
-    after = c.touch_stats()
-    put = after["put_copy_bytes"] - first["put_copy_bytes"]
-    warm = after["put_touched_bytes"] - first["put_touched_bytes"]
-    assert put == 8 * data.nbytes
-    assert warm >= 0.9 * put
+@contextlib.contextmanager
+def _name_taken(path: str):
+    """Holds the pool name an extension would take, so that the extension
+    falls back to anonymous memory. Locked as a live segment is: another
+    test's server sweeps unlocked ``its.*`` files away when it starts."""
+    assert not os.path.exists(path)
+    fd = os.open(path, os.O_CREAT | os.O_EXCL | os.O_RDWR, 0o600)
+    fcntl.flock(fd, fcntl.LOCK_EX | fcntl.LOCK_NB)
+    try:
+        yield
+    finally:
+        os.close(fd)
+        with contextlib.suppress(FileNotFoundError):
+            os.unlink(path)
+
+
+def _threads() -> int:
+    return len(os.listdir("/proc/self/task"))
+
+
+def _reads_back(c, runs, data: np.ndarray):
     dst = np.zeros_like(data)
     c.register_mr(dst)
-    for run in pairs:
+    for run in runs:
+        dst[:] = 0
         c.read_cache(run, BLOCK, dst.ctypes.data)
         assert np.array_equal(dst, data)
+
+
+def test_put_file_sequential_puts_read_back_exact():
+    """Every byte of a run of puts went through the descriptor (the first put
+    as well: there is nothing to warm), and every value reads back exact."""
+    srv = its.start_local_server(prealloc_bytes=64 * MIB, block_bytes=16 << 10)
+    c = _put_conn(srv.port)
+    data = np.random.randint(0, 256, size=2 * MIB, dtype=np.uint8)
+    c.register_mr(data)
+    assert c.touch_stats() == ZERO_PUT
+    runs = [_put(c, "first", data)]
+    first = c.touch_stats()
+    assert first["put_file_bytes"] == first["put_copy_bytes"] == data.nbytes
+    runs += [_put(c, f"run{r}", data) for r in range(8)]
+    after = c.touch_stats()
+    assert after["put_file_bytes"] == after["put_copy_bytes"] == 9 * data.nbytes
+    # What the accepted metric reads: no byte of a copy faulted on the reactor.
+    assert after["put_touched_bytes"] == after["put_file_bytes"] and after["pretouch_bytes"] == 0
+    _reads_back(c, runs, data)
     c.close()
     srv.stop()
 
 
-def test_pretouch_fetch_only_connection_starts_no_thread():
+def test_put_file_fetch_only_connection_copies_nothing_and_starts_no_thread():
     """A connection that maps the pools and only reads, and one that puts
-    over the socket, walk nothing: the thread belongs to the first shm put."""
+    over the socket, copy nothing through a descriptor; a shm put starts no
+    thread beside the connection's reactor."""
     srv = its.start_local_server(prealloc_bytes=32 * MIB, block_bytes=16 << 10)
-    writer = _touch_conn(srv.port)
+    writer = _put_conn(srv.port)
     data = np.random.randint(0, 256, size=MIB, dtype=np.uint8)
     writer.register_mr(data)
+    threads = _threads()
     pairs = _put(writer, "v", data)
-    reader, socket_writer = _touch_conn(srv.port), _touch_conn(srv.port, shm=False)
+    assert _threads() == threads
+    reader, socket_writer = _put_conn(srv.port), _put_conn(srv.port, shm=False)
     assert reader.shm_active and not socket_writer.shm_active
-    dst = np.zeros_like(data)
-    reader.register_mr(dst)
+    threads = _threads()
     for _ in range(3):
-        reader.read_cache(pairs, BLOCK, dst.ctypes.data)
-    assert np.array_equal(dst, data)
+        _reads_back(reader, [pairs], data)
     socket_writer.register_mr(data)
     _put(socket_writer, "s", data)
-    _walked(writer, 16 * MIB)  # time enough for a thread that should not be
-    assert reader.touch_stats() == ZERO_TOUCH and socket_writer.touch_stats() == ZERO_TOUCH
+    assert _threads() == threads
+    # The reader's located gets came out through its descriptor; it put nothing.
+    assert reader.touch_stats() == dict(ZERO_PUT, get_file_bytes=3 * data.nbytes)
+    assert socket_writer.touch_stats() == ZERO_PUT
     for c in (writer, reader, socket_writer):
         c.close()
     srv.stop()
 
 
-@pytest.mark.parametrize("puts", [False, True], ids=["no-thread", "thread"])
+@pytest.mark.parametrize("puts", [False, True], ids=["no-put", "put"])
 @pytest.mark.parametrize("how", ["close", "reconnect"])
-def test_pretouch_close_and_reconnect(how, puts):
-    """close() and reconnect() with and without a started thread; with one,
-    close() lands while it is mid-walk (a 256 MiB pool, closed at once)."""
-    srv = its.start_local_server(prealloc_bytes=256 * MIB, block_bytes=16 << 10)
+def test_put_file_close_and_reconnect_close_every_descriptor(how, puts):
+    """close() and reconnect(), after puts and without: fifty rounds leave
+    the process with the descriptors it had (the server's own)."""
+    pool = dict(prealloc_bytes=16 * MIB, block_bytes=16 << 10)
+    others = _pool_fds()  # what earlier tests of this process left open
+    srv = its.start_local_server(**pool)
     port = srv.port
-    c = _touch_conn(port)
     data = np.random.randint(0, 256, size=MIB, dtype=np.uint8)
+    if how == "close":
+        base = len(_pool_fds(others))
+        for r in range(50):
+            c = _put_conn(port)
+            assert len(_pool_fds(others)) == base + 1
+            if puts:
+                c.register_mr(data)
+                _put(c, f"a{r % 8}", data)
+            c.close()
+            assert len(_pool_fds(others)) == base
+        srv.stop()
+        return
+    c = _put_conn(port)
     c.register_mr(data)
-    if puts:
-        _put(c, "a", data)
-    if how == "reconnect":
+    for r in range(50):
+        if puts:
+            _put(c, "a", data)
         srv.stop()
         for _ in range(50):
             try:
-                srv = its.start_local_server(
-                    host="127.0.0.1", service_port=port,
-                    prealloc_bytes=256 * MIB, block_bytes=16 << 10,
-                )
+                srv = its.start_local_server(host="127.0.0.1", service_port=port, **pool)
                 break
             except its.InfiniStoreException:
                 time.sleep(0.1)
@@ -415,91 +466,254 @@ def test_pretouch_close_and_reconnect(how, puts):
             for _ in range(10):
                 _put(c, "dead", data)
         c.reconnect()
-        # A new handle: its ledger starts over, and its first put its thread.
-        assert c.touch_stats()["pretouch_bytes"] == 0
-        pairs = _put(c, "b", data)
-        dst = np.zeros_like(data)
-        c.register_mr(dst)
-        c.read_cache(pairs, BLOCK, dst.ctypes.data)
-        assert np.array_equal(dst, data)
+        # A new handle: its ledger starts over; the old one's descriptor went
+        # with its mapping (the server's and the new handle's are left).
+        assert c.touch_stats() == ZERO_PUT
+        assert len(_pool_fds(others)) == 2
+    pairs = _put(c, "b", data)
+    _reads_back(c, [pairs], data)
     c.close()
+    assert len(_pool_fds(others)) == 1
     srv.stop()
 
 
-def test_pretouch_far_put_moves_frontier():
-    """A put that lands outside everything touched (here: below the frontier,
-    in space another connection freed) is a cold put that succeeds and moves
-    the frontier there; nothing depends on where the allocator puts it."""
+def test_put_file_far_put_lands_where_the_allocator_says():
+    """A put far from any earlier one (here: below them all, in space another
+    connection freed) is a put like any other: counted, one call, exact."""
     srv = its.start_local_server(prealloc_bytes=64 * MIB, block_bytes=16 << 10)
-    other, c = _touch_conn(srv.port, shm=False), _touch_conn(srv.port)
+    other, c = _put_conn(srv.port, shm=False), _put_conn(srv.port)
     filler = np.random.randint(0, 256, size=8 * MIB, dtype=np.uint8)
     other.register_mr(filler)
     held = _put(other, "filler", filler)
     data = np.random.randint(0, 256, size=MIB // 2, dtype=np.uint8)
     c.register_mr(data)
-    _put(c, "high", data)  # past the filler: the frontier starts there
-    walked = _walked(c, 56 * MIB)  # chunks 8..63: everything past the put
+    high = _put(c, "high", data)  # past the filler
     other.delete_keys([k for k, _ in held])
     before = c.touch_stats()
-    pairs = _put(c, "low", data)  # into the freed head of the pool
+    low = _put(c, "low", data)  # into the freed head of the pool
     after = c.touch_stats()
-    assert after["put_copy_bytes"] - before["put_copy_bytes"] == data.nbytes
-    assert after["put_touched_bytes"] == before["put_touched_bytes"]  # cold
-    _walked(c, walked + 8 * MIB)  # the frontier moved: the head is walked now
-    before = c.touch_stats()
-    _put(c, "low2", data)
-    assert c.touch_stats()["put_touched_bytes"] - before["put_touched_bytes"] == data.nbytes
-    dst = np.zeros_like(data)
-    c.register_mr(dst)
-    c.read_cache(pairs, BLOCK, dst.ctypes.data)
-    assert np.array_equal(dst, data)
+    assert after["put_file_bytes"] - before["put_file_bytes"] == data.nbytes
+    assert after["put_file_calls"] - before["put_file_calls"] == 1
+    _reads_back(c, [high, low], data)
     for conn in (other, c):
         conn.close()
     srv.stop()
 
 
-def test_pretouch_changes_no_byte_under_a_concurrent_writer():
-    """A second connection writes the pages the first one's thread is walking
-    (and its own thread walks them too): every value reads back exact."""
+def test_put_file_two_writers_of_one_pool_read_back_exact():
+    """Two connections write the same pool file at the same time, a thread
+    each: every value of either reads back exact through the other."""
     srv = its.start_local_server(prealloc_bytes=192 * MIB, block_bytes=16 << 10)
-    a, b = _touch_conn(srv.port), _touch_conn(srv.port)
-    spark = np.random.randint(0, 256, size=BLOCK, dtype=np.uint8)
-    a.register_mr(spark)
-    data = np.random.randint(0, 256, size=16 * MIB, dtype=np.uint8)
-    b.register_mr(data)
-    _put(a, "spark", spark)  # a's thread starts walking the 192 MiB from here
-    runs = [_put(b, f"w{r}", data) for r in range(8)]  # b writes 128 MiB of them
-    assert a.touch_stats()["pretouch_bytes"] > 0 and b.touch_stats()["pretouch_bytes"] > 0
-    _walked(a, 160 * MIB)
-    dst = np.zeros_like(data)
-    a.register_mr(dst)
-    for run in runs:
-        dst[:] = 0
-        a.read_cache(run, BLOCK, dst.ctypes.data)
-        assert np.array_equal(dst, data)
+    a, b = _put_conn(srv.port), _put_conn(srv.port)
+    da = np.random.randint(0, 256, size=8 * MIB, dtype=np.uint8)
+    db = np.random.randint(0, 256, size=8 * MIB, dtype=np.uint8)
+    a.register_mr(da)
+    b.register_mr(db)
+    runs = {"a": [], "b": []}
+
+    def work(c, tag, data):
+        runs[tag] = [_put(c, f"{tag}{r}", data) for r in range(8)]
+
+    threads = [threading.Thread(target=work, args=args) for args in ((a, "a", da), (b, "b", db))]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    for c, data in ((a, da), (b, db)):
+        stats = c.touch_stats()
+        assert stats["put_file_bytes"] == stats["put_copy_bytes"] == 8 * data.nbytes
+    _reads_back(a, runs["b"], db)
+    _reads_back(b, runs["a"], da)
     for conn in (a, b):
         conn.close()
     srv.stop()
 
 
-def test_pretouch_walks_an_auto_extended_pool():
-    """Puts that spill into an extension pool start a frontier there: the
-    thread walks more than the first pool holds."""
+def test_put_file_auto_extended_pool_gets_its_own_descriptor():
+    """Puts that spill into an extension pool map it on demand and keep its
+    descriptor beside the first pool's; close() gives both back."""
+    others = _pool_fds()
     srv = its.start_local_server(
         prealloc_bytes=8 * MIB, block_bytes=16 << 10, auto_increase=True,
         extend_bytes=16 * MIB,
     )
-    c = _touch_conn(srv.port)
+    c = _put_conn(srv.port)
+    assert len(_pool_fds(others)) == 2  # the server's and the connection's
     data = np.random.randint(0, 256, size=6 * MIB, dtype=np.uint8)
     c.register_mr(data)
     runs = [_put(c, f"x{r}", data) for r in range(3)]  # 18 MiB on 8 + 16
     stats = c.touch_stats()
-    assert stats["put_copy_bytes"] == 3 * data.nbytes
-    _walked(c, 10 * MIB)
-    dst = np.zeros_like(data)
-    c.register_mr(dst)
-    for run in runs:
-        c.read_cache(run, BLOCK, dst.ctypes.data)
-        assert np.array_equal(dst, data)
+    assert stats["put_file_bytes"] == stats["put_copy_bytes"] == 3 * data.nbytes
+    assert len(_pool_fds(others)) == 4 and len(set(_pool_fds(others).values())) == 2
+    _reads_back(c, runs, data)
     c.close()
+    assert len(_pool_fds(others)) == 2
+    srv.stop()
+
+
+def test_put_into_an_anonymous_pool_rides_the_socket_path():
+    """An extension pool that could not be a file (its name is taken) is
+    anonymous memory: it has no descriptor, the server answers a put that
+    lands there with a retry, and the put goes over the socket, whole."""
+    others = _pool_fds()
+    srv = its.start_local_server(
+        prealloc_bytes=8 * MIB, block_bytes=16 << 10, auto_increase=True,
+        extend_bytes=16 * MIB,
+    )
+    (first,) = set(_pool_fds(others).values())
+    assert first.endswith(".0")
+    with _name_taken(first[: -len(".0")] + ".1"):  # the name the extension would take
+        c = _put_conn(srv.port)
+        data = np.random.randint(0, 256, size=6 * MIB, dtype=np.uint8)
+        c.register_mr(data)
+        runs = [_put(c, "x0", data)]  # fits the mapped pool: through its file
+        assert c.touch_stats()["put_file_bytes"] == data.nbytes and c.shm_active
+        held = _pool_fds(others)
+        runs += [_put(c, f"x{r}", data) for r in (1, 2)]  # spills: over the socket
+        stats = c.touch_stats()
+        assert stats["put_file_bytes"] == stats["put_copy_bytes"] == data.nbytes
+        assert not c.shm_active and _pool_fds(others) == held  # no descriptor more
+        _reads_back(c, runs, data)
+        c.close()
+    srv.stop()
+
+
+@pytest.mark.parametrize("op", ["put", "get"])
+def test_put_file_failed_transfer_aborts_the_ticket_and_falls_back(op):
+    """A ``pwritev`` / ``preadv`` that fails (the test swaps the connection's
+    descriptor for one not open that way) publishes and completes nothing:
+    the ticket is released, the op is sent again over the socket, and the
+    value reads back whole."""
+    from infinistore_tpu._native import lib
+
+    srv = its.start_local_server(prealloc_bytes=32 * MIB, block_bytes=16 << 10)
+    before = _pool_fds()
+    c = _put_conn(srv.port)
+    (fd,) = set(_pool_fds()) - set(before)
+    data = np.random.randint(0, 256, size=MIB, dtype=np.uint8)
+    c.register_mr(data)
+    runs = [_put(c, "ok", data)]
+    null = os.open("/dev/null", os.O_RDONLY if op == "put" else os.O_WRONLY)
+    os.dup2(null, fd)  # same number, so no other file can take it meanwhile
+    os.close(null)
+    if op == "put":
+        runs.append(_put(c, "failed", data))
+    else:
+        _reads_back(c, runs, data)
+    stats = c.touch_stats()
+    assert stats["put_file_bytes"] == stats["put_copy_bytes"] == data.nbytes  # the first put's
+    assert stats["put_file_calls"] == (2 if op == "put" else 1) and stats["get_file_bytes"] == 0
+    assert not c.shm_active
+    runs.append(_put(c, "after", data))  # the socket path from here on
+    assert c.touch_stats()["put_file_calls"] == stats["put_file_calls"]
+    _reads_back(c, runs, data)
+    # Nothing of the aborted ticket is left pinned on the server.
+    assert lib.its_server_kvmap_len(srv.handle) == len(runs) * len(runs[0])
+    for _ in range(100):  # the get's release is fire-and-forget
+        if lib.its_server_usage(srv.handle) == len(runs) * data.nbytes / (32 * MIB):
+            break
+        time.sleep(0.01)
+    assert lib.its_server_usage(srv.handle) == len(runs) * data.nbytes / (32 * MIB)
+    c.close()
+    srv.stop()
+
+
+def test_put_file_contiguous_values_go_out_as_one_call():
+    """A put's values that lie side by side in the pool go out as ONE
+    vectored call whatever their number; values scattered over holes take a
+    call a run."""
+    srv = its.start_local_server(prealloc_bytes=64 * MIB, block_bytes=16 << 10)
+    other, c = _put_conn(srv.port, shm=False), _put_conn(srv.port)
+    data = np.random.randint(0, 256, size=8 * MIB, dtype=np.uint8)  # 128 values
+    other.register_mr(data)
+    c.register_mr(data)
+    held = _put(other, "filler", data)
+    runs = [_put(c, "side-by-side", data)]
+    assert c.touch_stats()["put_file_calls"] == 1
+    # Free every other value of the filler: 64 holes of one value each.
+    other.delete_keys([k for k, _ in held[::2]])
+    half = data[: 4 * MIB]
+    before = c.touch_stats()
+    holes = _put(c, "holes", half)
+    after = c.touch_stats()
+    assert after["put_file_bytes"] - before["put_file_bytes"] == half.nbytes
+    assert after["put_file_calls"] - before["put_file_calls"] == len(holes)
+    _reads_back(c, runs, data)
+    _reads_back(c, [holes], half)
+    for conn in (other, c):
+        conn.close()
+    srv.stop()
+
+
+# ---------------------------------------------------------------------------
+# The reads' copies ride the descriptor too: a located get (``GetLoc``, into
+# a plain buffer) is the client's ``preadv``; a get into a client segment
+# (``GetInto``) is the SERVER's, out of its own descriptor of the pool.
+# ---------------------------------------------------------------------------
+
+
+def test_located_gets_read_through_the_descriptor():
+    """``get_file_bytes`` counts every byte a plain-buffer read copied out."""
+    srv = its.start_local_server(prealloc_bytes=32 * MIB, block_bytes=16 << 10)
+    writer, reader = _put_conn(srv.port), _put_conn(srv.port)
+    data = np.random.randint(0, 256, size=2 * MIB, dtype=np.uint8)
+    writer.register_mr(data)
+    runs = [_put(writer, f"v{r}", data) for r in range(3)]
+    _reads_back(reader, runs, data)
+    assert reader.touch_stats()["get_file_bytes"] == 3 * data.nbytes
+    assert writer.touch_stats()["get_file_bytes"] == 0
+    for c in (writer, reader):
+        c.close()
+    srv.stop()
+
+
+def test_get_into_reads_through_the_servers_descriptor():
+    """A read into an ``alloc_shm_mr`` buffer is the server's copy: out of
+    the pool file's descriptor, into slots that lie in another order than the
+    values do in the pool; the server counts the bytes (``get_into_file_bytes``)."""
+    srv = its.start_local_server(prealloc_bytes=32 * MIB, block_bytes=16 << 10)
+    c = _put_conn(srv.port)
+    data = np.random.randint(0, 256, size=2 * MIB, dtype=np.uint8)
+    c.register_mr(data)
+    pairs = _put(c, "v", data)  # the two-phase put: no mapping touched the pages
+    buf = c.alloc_shm_mr(data.nbytes)
+    n = len(pairs)
+    buf[:] = 0
+    back = [(key, (n - 1 - i) * BLOCK) for i, (key, _) in enumerate(pairs)]
+    c.read_cache(back, BLOCK, buf.ctypes.data)
+    assert np.array_equal(buf.reshape(n, BLOCK)[::-1].reshape(-1), data)
+    buf[:] = 0
+    c.read_cache(pairs, BLOCK, buf.ctypes.data)  # and side by side, as they lie
+    assert np.array_equal(buf, data)
+    stats = c.get_stats()
+    assert stats["ops"]["I"]["count"] == 2 and stats["get_into_file_bytes"] == 2 * data.nbytes
+    assert c.touch_stats()["get_file_bytes"] == 0  # the client copied nothing
+    c.close()
+    srv.stop()
+
+
+def test_get_into_from_an_anonymous_pool_goes_through_the_mapping():
+    """Blocks of a pool that is no file have no descriptor: the server copies
+    them out of its memory as before, and counts only the file's bytes."""
+    others = _pool_fds()
+    srv = its.start_local_server(
+        prealloc_bytes=8 * MIB, block_bytes=16 << 10, auto_increase=True,
+        extend_bytes=16 * MIB,
+    )
+    (first,) = set(_pool_fds(others).values())
+    with _name_taken(first[: -len(".0")] + ".1"):
+        c = _put_conn(srv.port)
+        buf = c.alloc_shm_mr(6 * MIB)
+        data = np.random.randint(0, 256, size=6 * MIB, dtype=np.uint8)
+        runs = []
+        for r in range(3):  # 18 MiB on 8 + 16: the segment's puts spill too
+            buf[:] = data
+            runs.append(_put(c, f"x{r}", buf))
+        for run in runs:
+            buf[:] = 0
+            c.read_cache(run, BLOCK, buf.ctypes.data)
+            assert np.array_equal(buf, data)
+        read = c.get_stats()["get_into_file_bytes"]
+        assert 0 < read < 3 * data.nbytes and read % BLOCK == 0
+        c.close()
     srv.stop()
