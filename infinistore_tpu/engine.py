@@ -431,6 +431,10 @@ class WaveDecoder:
         # Wave-row padding ledger (engine_wave_pad_fraction).
         self.pad_rows = 0
         self.launched_rows = 0
+        # Launched waves that carried ONE real flat row: the waves whose
+        # dense FFN pads its row to reach the matrix unit (models/llama.py
+        # ``_ffn``).
+        self.one_row_waves = 0
         # Flat attention pages launched (bucket padding included) and how
         # many of them were padding: what the ragged kernel skips.
         self.wave_pages = 0
@@ -865,6 +869,7 @@ class WaveDecoder:
             _WAVE_COUNTERS.bump("engine_wave_launches")
             _WAVE_COUNTERS.bump("engine_wave_host_transfers")
             self.waves += 1
+            self.one_row_waves += t_real == 1
             self.max_wave = max(self.max_wave, len(batch))
             off, handed = 0, []
             for toks, _, _, fut, *_ in batch:
@@ -2022,7 +2027,10 @@ class ContinuousBatchingHarness:
         ``wave_prewarmed_buckets`` — the canonical ladder
         ``prewarm_wave_buckets`` compiled at startup — and
         ``wave_pad_fraction``, the share of launched wave rows that were
-        padding, ``wave_pages`` / ``wave_pad_pages``, the flat attention
+        padding, ``wave_one_row_waves``, the launched waves that carried
+        ONE real flat row (a lone request's steps: the waves whose dense FFN
+        pads its row, models/llama.py ``_ffn``), ``wave_pages`` /
+        ``wave_pad_pages``, the flat attention
         pages launched and those of them that were the page bucket's
         padding; ``wave_layer_pages`` / ``wave_window_pages_skipped``, the
         (layer, page) pairs the waves' real rows attended and how many more
@@ -2145,6 +2153,10 @@ class ContinuousBatchingHarness:
                 if self.wave.launched_rows
                 else 0.0
             ),
+            # Launched waves of one real row (of ``decode_waves``): every
+            # step of a lone request, and the share of waves whose dense
+            # FFN runs on a padded row (models/llama.py ``_ffn``).
+            "wave_one_row_waves": self.wave.one_row_waves,
             # Flat attention pages the waves launched, and how many were
             # the power-of-two bucket's padding: steps the ragged kernel
             # neither computes nor fetches (tpu/paged_attention.py).

@@ -187,6 +187,13 @@ def _block(params: Params, layer: int, x, k, v, q_positions, mask, config):
     return _ffn(params, layer, x, config)
 
 
+# Rows a ONE-row dense FFN runs on (``_ffn``): the fewest at which the chip
+# reads w_gate_up as fast as it does for any wave of several rows. 2, 8 and 16
+# read alike (tools/ffn_rows_probe.py on a v5e: 0.24 ms a layer at ffn 11008
+# against 0.68 for the one-row product; PERF.md section 6, PR 45).
+ONE_ROW_FFN_ROWS = 2
+
+
 def _ffn(params: Params, layer: int, x, config):
     """FFN half of the block (dense or soft-MoE), shared by the dense path
     and the fused-decode path."""
@@ -207,9 +214,21 @@ def _ffn(params: Params, layer: int, x, config):
         ffn = jax.nn.silu(gate_up[:, :, :, 0]) * gate_up[:, :, :, 1]  # [B,S,E,F]
         out = jnp.einsum("bse,bsef,efd->bsd", gates, ffn, params[pre + "w_down_moe"])
         return x + out
+    if h.shape[0] * h.shape[1] == 1:
+        # ONE row (a lone request's decode wave): XLA lowers a one-row
+        # product to a multiply-and-reduce on the vector unit, which reads
+        # w_gate_up [dim, 2, ffn] at a third (ffn 11008) to two thirds
+        # (14336) of the rate the matrix-unit fusion of a two-row wave reads
+        # the same buffer at. So the row rides with zero rows beside it and
+        # row 0 is kept: the same operands, the same float32 accumulation,
+        # the weights as they lie (tools/ffn_rows_probe.py chose the count;
+        # docs/design.md, "A one-row wave's FFN"). Zeros, not a broadcast of
+        # the row: nothing here folds back into a one-row product.
+        h = jnp.pad(h, ((0, 0), (0, ONE_ROW_FFN_ROWS - 1), (0, 0)))
     gate_up = jnp.einsum("bsd,dcf->bscf", h, params[pre + "w_gate_up"])
     ffn = jax.nn.silu(gate_up[:, :, 0]) * gate_up[:, :, 1]
-    return x + jnp.einsum("bsf,fd->bsd", ffn, params[pre + "w_down"])
+    out = jnp.einsum("bsf,fd->bsd", ffn, params[pre + "w_down"])
+    return x + out[:, : x.shape[1]]
 
 
 def _q_proj(params: Params, layer: int, x, positions, config):

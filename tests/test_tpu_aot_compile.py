@@ -412,6 +412,49 @@ def test_wave_program_shares_one_layer_and_one_kernel_function(v5e, monkeypatch,
     assert text.count('kernel_name = "_ragged_attn_kernel"') == 1
 
 
+# The benchmark's two dense FFN widths (hidden 4096): DeepSeek's 11008 is 86
+# lane tiles, no multiple of 512; Mistral's 14336 is.
+@pytest.mark.parametrize("ffn_dim", [11008, 14336])
+def test_one_row_wave_reads_gate_up_on_the_matrix_unit(v5e, monkeypatch, ffn_dim):
+    """A ONE-row bucket of the wave body at a cell's real FFN width (one
+    layer, small vocabulary), compiled for a v5e: the gate/up product is a
+    convolution on ``ONE_ROW_FFN_ROWS`` rows inside an output fusion (the
+    matrix unit, as a two-row wave's is), not the loop fusion of the vector
+    unit's multiply-and-reduce over ``w_gate_up [dim, 2, ffn]``, which read
+    the weights at a third of HBM's peak at 11008 (PERF.md section 6, PR
+    45); nor is ``w_gate_up`` copied whole."""
+    from infinistore_tpu.models import llama
+
+    monkeypatch.setattr(paged, "_use_pallas", lambda: True)
+    cfg = llama.LlamaConfig(
+        vocab=1024, dim=4096, n_layers=1, n_heads=32, n_kv_heads=8, ffn_dim=ffn_dim,
+        block_tokens=16, dtype=jnp.bfloat16,
+    )
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=v5e)
+    cache = s(cfg.kv_spec(NUM_BLOCKS).cache_shape, cfg.dtype)
+    i32 = lambda *shape: s(shape, jnp.int32)
+    exe = _compile(
+        jax.jit(
+            llama.verify_step_ragged.__wrapped__, static_argnames=("config", "max_blocks")
+        ),
+        _param_shapes(s, cfg), i32(1), i32(1), i32(1), i32(PAGES), i32(PAGES + 1), i32(1),
+        [(cache, cache)], i32(1, TABLE), config=cfg, max_blocks=TABLE,
+    )
+    text = exe.as_text()
+    rows = llama.ONE_ROW_FFN_ROWS
+    product = [
+        line for line in text.splitlines()
+        if "dcf->bscf/dot_general" in line and re.search(r" (fusion|convolution|reduce)\(", line)
+    ]
+    assert any(
+        re.search(rf"bf16\[{rows},2,{ffn_dim}\]\S* convolution\(", line) for line in product
+    ), product
+    # The multiply-and-reduce was a kLoop fusion under the same op_name.
+    assert all(" convolution(" in line or "kind=kOutput" in line for line in product), product
+    entry = text[text.index("ENTRY"):]
+    assert not re.search(rf"= bf16\[[\d,]*2,{ffn_dim}\]\S* copy\(", entry)
+
+
 # The three serving entries as the module declares them (their donation is
 # what is under test, so no fresh wrapper), each at a cell's attention widths
 # and cache (bf16, 16-token blocks, head_dim 128), 4 layers, FFN and vocabulary
