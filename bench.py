@@ -3165,217 +3165,6 @@ def _disagg_metrics(its, np) -> dict:
     return out
 
 
-def _serving_trace_metrics(its, np) -> dict:
-    """Skew-aware vs skew-blind wave flush under the trace-driven serving
-    load (docs/serving_load.md, ROADMAP-6): the SAME skewed loadgen trace
-    (Zipf prefix popularity, heavy-tailed log-normal lengths, bursts,
-    mixed prefill/decode, BACKGROUND-tagged outliers) replays through two
-    continuous-batching harnesses differing ONLY in ``wave_skew_policy``.
-    Order-alternating paired rounds, min(median-of-ratios, ratio-of-sums)
-    — the weather rule. Gated in tools/bench_check.py:
-
-    - ``serving_p99_ttft_skew_ratio`` > 1.0 — FOREGROUND p99 TTFT, blind
-      over aware (deferral keeps outliers out of foreground waves);
-    - ``serving_wave_pad_fraction`` strictly below the blind run's — the
-      bucket-economics receipt (fewer padded rows launched);
-    - mechanism receipts: deferrals fired, aging escapes fired under the
-      outlier-flood leg (the starvation bound is live, not decorative),
-      and zero wrong bytes — every replay runs the oracle verifier.
-
-    The unit of measurement is a cold-start CONVERGENCE BLOCK, not a
-    single replay: clear the process jit cache, replay the trace K
-    times, and score the block at the MEDIAN per-replay p99 over the
-    post-cold replays (replay 0 pays the shared prefill/embed
-    cold-compile storm in both modes and is excluded). The design is
-    forced by the mechanism under test: each distinct (B, T, P) wave
-    bucket costs one ~1 s XLA compile on first launch. A blind flush
-    jit-buckets each dimension independently, so serving mints the
-    organic bucket PRODUCT — ~25 distinct triples under this trace,
-    discovered stochastically across rounds: measured curves plateau
-    at ~0.8-1.2 s p99 for most post-cold rounds. The skew policy
-    instead launches every wave on the declared canonical ladder
-    (engine.WaveDecoder docstring) and ``prewarm_wave_buckets``
-    compiles that ladder at harness startup, so aware rounds are
-    STRUCTURALLY compile-free (~0.1 s floor) — the recompile stall is
-    scheduled out of serving, not dodged by luck. Median-over-rounds
-    keeps one lucky mint-free blind round (p ~ 1/6) from deciding a
-    block. Every round uses a fresh store namespace (model_id), so
-    rounds are i.i.d.; blocks order-alternate and pool like the other
-    legs' paired rounds (the weather rule)."""
-    import asyncio
-
-    import jax
-    import jax.numpy as jnp
-
-    from infinistore_tpu import loadgen
-    from infinistore_tpu.connector import KVConnector
-    from infinistore_tpu.engine import (
-        ContinuousBatchingHarness,
-        EngineKVAdapter,
-        NGramDrafter,
-        reset_wave_counters,
-        wave_counters,
-    )
-    from infinistore_tpu.models import LlamaConfig, init_params
-
-    # dim 128 / ffn 512: big enough that a padded bucket row costs real
-    # compute on this host (~46 us/row marginal vs ~20 us at dim 64), so
-    # the pad rows the policy avoids translate into TTFT — at toy sizes
-    # the per-wave fixed overhead drowns the per-row savings and the
-    # deferral latency shows up as pure loss.
-    cfg = LlamaConfig(
-        vocab=128, dim=128, n_layers=2, n_heads=4, n_kv_heads=2,
-        ffn_dim=512, block_tokens=8, dtype=jnp.float32,
-    )
-    num_blocks, max_blocks = 96, 8
-    # Block-PAIRS (each block is K=4 replays, so 2 pairs = 16 replays).
-    pairs, max_pairs = 2, 4
-    params = init_params(cfg, jax.random.PRNGKey(0))
-    trace = loadgen.preset("skewed", seed=11, duration_s=0.4)
-    flood = loadgen.preset("outlier_flood", seed=13, duration_s=0.15)
-    reset_wave_counters()
-    srv = its.start_local_server(
-        prealloc_bytes=256 << 20, block_bytes=64 << 10, enable_shm=True
-    )
-    out = {}
-    run_id = [0]
-    try:
-
-        # Leg-level policy knobs (the engine defaults stay untouched): a
-        # TIGHT foreground starvation bound — 8 ms, not the engine's
-        # 25 ms — caps how much TTFT a re-deferred FOREGROUND verify
-        # chunk can ever eat (the bound lands directly in p99 TTFT when
-        # an entry thrashes at a bucket boundary), while BACKGROUND
-        # outliers still defer 4x that. defer_pad_frac 0.40 only defers
-        # entries whose marginal pad is truly lopsided, cutting deferral
-        # churn ~3x vs the 0.25 default with the same pad-fraction win.
-        async def replay_once(
-            skew: bool, tr, defer_max_s=0.008, pad_frac=0.40
-        ):
-            run_id[0] += 1
-            conn = its.InfinityConnection(
-                its.ClientConfig(
-                    host_addr="127.0.0.1", service_port=srv.port,
-                    log_level="error",
-                )
-            )
-            conn.connect()
-            try:
-                kvc = KVConnector(
-                    conn, cfg.kv_spec(num_blocks),
-                    f"serving-{run_id[0]}", max_blocks=max_blocks,
-                )
-                h = ContinuousBatchingHarness(
-                    EngineKVAdapter(kvc), params, cfg, num_blocks,
-                    max_blocks, verify=True,
-                    wave_skew_policy=skew, wave_defer_max_s=defer_max_s,
-                    wave_hold_max_s=0.002, wave_defer_pad_frac=pad_frac,
-                )
-                h.drafter = NGramDrafter(max_draft=4)
-                # Aware harnesses compile their declared bucket ladder
-                # up front (the startup cost a real deployment pays
-                # once); a blind harness has no declared set — no-op.
-                await h.prewarm_wave_buckets()
-                stats = await loadgen.replay(tr, h, concurrency=8)
-                errs = [s for s in stats if isinstance(s, Exception)]
-                assert not errs, f"serving replay failed: {errs[:3]}"
-                wrong = sum(1 for s in stats if not s.verified)
-                return h.metrics(), wrong
-            finally:
-                conn.close()
-
-        async def drive() -> dict:
-            K = 4  # replays per block: replay 0 = cold storm, 1..3 converge
-
-            ratios = []
-            sums = {"aware": 0.0, "blind": 0.0}
-            pads = {"aware": [], "blind": []}
-            floors = {"aware": [], "blind": []}
-            deferrals = held = wrong_total = 0
-            flip = [0]
-
-            async def block(tag: str) -> float:
-                # One cold-start convergence block (see the docstring):
-                # jit cache cleared, K replays, scored at the median
-                # post-cold p99.
-                nonlocal deferrals, held, wrong_total
-                jax.clear_caches()
-                rounds = []
-                for k in range(K):
-                    m, wrong = await replay_once(tag == "aware", trace)
-                    wrong_total += wrong
-                    if k == 0:
-                        continue  # both modes' shared cold-compile storm
-                    rounds.append(m["p99_ttft_fg_us"])
-                    pads[tag].append(m["wave_pad_fraction"])
-                    if tag == "aware":
-                        deferrals += m["wave_deferrals"]
-                        held += m["wave_held_flushes"]
-                score = sorted(rounds)[len(rounds) // 2]
-                floors[tag].append(score)
-                sums[tag] += score
-                return score
-
-            async def one_pair():
-                flip[0] ^= 1
-                order = ("aware", "blind") if flip[0] else ("blind", "aware")
-                sample = {}
-                for tag in order:
-                    sample[tag] = await block(tag)
-                ratios.append(sample["blind"] / max(sample["aware"], 1.0))
-
-            def estimate() -> float:
-                med = sorted(ratios)[len(ratios) // 2]
-                return min(med, sums["blind"] / max(sums["aware"], 1.0))
-
-            for _ in range(pairs):
-                await one_pair()
-            while estimate() <= 1.0 and len(ratios) < max_pairs:
-                await one_pair()
-
-            # Outlier-flood sub-leg: permanent heavy-tail pressure with a
-            # tight starvation bound AND the aggressive 0.25 pad-frac —
-            # aging escapes must fire (deferral under flood never
-            # strands; the bound is load-bearing, not decorative).
-            fm, fwrong = await replay_once(
-                True, flood, defer_max_s=0.004, pad_frac=0.25
-            )
-            wrong_total += fwrong
-
-            med = lambda xs: sorted(xs)[len(xs) // 2]
-            return {
-                "serving_trace_requests": len(trace.requests),
-                "serving_flood_requests": len(flood.requests),
-                "serving_pairs": len(ratios),
-                "serving_block_replays": K,
-                "serving_p99_ttft_aware_ms": round(
-                    med(floors["aware"]) / 1e3, 2
-                ),
-                "serving_p99_ttft_blind_ms": round(
-                    med(floors["blind"]) / 1e3, 2
-                ),
-                "serving_p99_ttft_skew_ratio": round(estimate(), 3),
-                "serving_wave_pad_fraction": round(med(pads["aware"]), 4),
-                "serving_wave_pad_fraction_blind": round(
-                    med(pads["blind"]), 4
-                ),
-                "serving_wave_deferrals": deferrals,
-                "serving_wave_held_flushes": held,
-                "serving_wave_aging_escapes": fm["wave_aging_escapes"],
-                "serving_flood_deferrals": fm["wave_deferrals"],
-                "serving_wrong_bytes": wrong_total,
-            }
-
-        out.update(asyncio.run(drive()))
-    finally:
-        # Process-wide ledger last (the /metrics vocabulary), without
-        # clobbering the per-round receipts above.
-        for key, val in wave_counters().status().items():
-            out.setdefault(key, val)
-        srv.stop()
-    return out
-
-
 def _run_check(files) -> int:
     """`bench.py --check RECEIPT.json [...]`: run the data-plane regression
     gate (tools/bench_check.py) over existing receipts instead of measuring.
@@ -3445,7 +3234,6 @@ def main(argv=None) -> int:
     tiering = _tiering_metrics(its, np)
     recovery = _recovery_metrics(its, np)
     disagg = _disagg_metrics(its, np)
-    serving = _serving_trace_metrics(its, np)
     # Device legs: only on the chip, and there every failure raises — a
     # broken backend, a Pallas lowering error or an OOM must end the run,
     # not become a string in the receipt. On any other platform the legs
@@ -3674,13 +3462,6 @@ def main(argv=None) -> int:
         # first token is issued with layers still in flight, zero wrong
         # bytes, zero fallback recomputes on the clean legs.
         **disagg,
-        # Skew-aware wave flush under trace-driven serving load
-        # (docs/serving_load.md, ROADMAP-6): the skewed loadgen trace
-        # replayed aware-vs-blind as order-alternating paired rounds.
-        # Gated in tools/bench_check.py: FOREGROUND p99 TTFT ratio > 1.0,
-        # aware pad fraction strictly below blind, deferrals fired, aging
-        # escapes fired under the outlier flood, zero wrong bytes.
-        **serving,
         "tpu_backend": backend,
         "device_kind": jax.devices()[0].device_kind,
         "device_count": len(jax.devices()),

@@ -60,8 +60,7 @@ def parse_args(argv=None):
         "--trace", default=None, metavar="FILE|PRESET",
         help="replay a loadgen trace (JSON file or preset name: skewed, "
              "uniform, outlier_flood) through the continuous-batching "
-             "engine harness against the server — the same traces "
-             "bench.py's serving leg grades (docs/serving_load.md)",
+             "engine harness against the server (docs/serving_load.md)",
     )
     p.add_argument(
         "--trace-seed", type=int, default=0,
@@ -70,11 +69,6 @@ def parse_args(argv=None):
     p.add_argument(
         "--trace-duration", type=float, default=0.4,
         help="trace duration in seconds when --trace names a preset",
-    )
-    p.add_argument(
-        "--skew-policy", action=argparse.BooleanOptionalAction, default=True,
-        help="replay with the skew-aware wave flush policy "
-             "(wave_skew_policy; docs/serving_load.md) on or off",
     )
     p.add_argument(
         "--pacing-mbps", type=int, default=0,
@@ -192,11 +186,9 @@ def _measure_wave_coalescing(conn, keys, offsets, block_size, dst, wave: int) ->
 
 def _run_trace(args) -> dict:
     """``--trace`` mode: replay a loadgen trace (file or preset) through
-    the continuous-batching engine harness against the server — the same
-    workload definition ``bench.py``'s ``_serving_trace_metrics`` leg
-    grades, through the CLI entry point (docs/serving_load.md). Reports
-    the harness's serving metrics (TTFT percentiles, wave pad fraction,
-    the wave-policy ledger) plus the trace's own shape."""
+    the continuous-batching engine harness against the server
+    (docs/serving_load.md). Reports the harness's serving metrics (TTFT
+    percentiles, wave pad fraction, waves) plus the trace's own shape."""
     import os
 
     try:
@@ -242,7 +234,6 @@ def _run_trace(args) -> dict:
             EngineKVAdapter(kvc),
             init_params(cfg, jax.random.PRNGKey(0)),
             cfg, num_blocks, max_blocks, verify=args.verify,
-            wave_skew_policy=args.skew_policy,
         )
         h.drafter = NGramDrafter(max_draft=4)
         t0 = time.perf_counter()
@@ -262,7 +253,6 @@ def _run_trace(args) -> dict:
             "trace_background": sum(
                 1 for r in trace.requests if r.priority != 0
             ),
-            "skew_policy": bool(args.skew_policy),
             "replay_wall_s": round(wall, 3),
             "requests_per_s": round(len(trace.requests) / wall, 1),
             "verified": bool(m["all_verified"]) if args.verify else None,
@@ -272,10 +262,6 @@ def _run_trace(args) -> dict:
             "p99_ttft_fg_us": m["p99_ttft_fg_us"],
             "wave_pad_fraction": round(m["wave_pad_fraction"], 4),
             "decode_waves": m["decode_waves"],
-            "wave_deferrals": m["wave_deferrals"],
-            "wave_aging_escapes": m["wave_aging_escapes"],
-            "wave_held_flushes": m["wave_held_flushes"],
-            "wave_defer_age_us_p99": m["wave_defer_age_us_p99"],
         }
     finally:
         conn.close()
@@ -398,14 +384,13 @@ def main(argv=None) -> int:
         else:
             print(
                 f"replayed {result['trace_requests']} requests "
-                f"({result['trace']}) in {result['replay_wall_s']}s "
-                f"(skew_policy={'on' if result['skew_policy'] else 'off'})"
+                f"({result['trace']}) in {result['replay_wall_s']}s"
             )
             print(
                 f"p99 TTFT: {result['p99_ttft_us']}us (fg "
                 f"{result['p99_ttft_fg_us']}us), pad fraction "
-                f"{result['wave_pad_fraction']}, deferrals "
-                f"{result['wave_deferrals']}"
+                f"{result['wave_pad_fraction']}, waves "
+                f"{result['decode_waves']}"
             )
             if result["verified"] is not None:
                 print(f"data verified: {result['verified']}")

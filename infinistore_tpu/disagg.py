@@ -679,7 +679,7 @@ class DisaggHarness:
         clamped to ``req_blocks`` so every prompt fits the harness's
         per-request table. The trace-driven counterpart of
         :meth:`heterogeneous_prompts` — one workload definition grades
-        the engine waves, the bench serving leg, AND the disagg handoff."""
+        the engine waves, the ``--trace`` replay AND the disagg handoff."""
         prompts = trace.prompts(
             self.config.block_tokens, vocab=self.config.vocab,
             max_blocks=self.req_blocks,

@@ -51,7 +51,7 @@ import contextlib
 import time
 from contextlib import asynccontextmanager
 from dataclasses import dataclass, field
-from typing import List, Optional, Sequence
+from typing import List, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -60,118 +60,9 @@ import numpy as np
 from . import tracing
 from .models.serving import WaveLayout, pack_wave, verify_step_ragged
 from .tpu.paged import gather_blocks
-from .tpu.paged_attention import build_ragged_wave
+from .tpu.paged_attention import RaggedWaveMeta, build_ragged_wave
 from .tpu.staging import StagingPoolExhausted
 from .wire import PRIORITY_BACKGROUND, PRIORITY_FOREGROUND, SAVE_CLASS
-
-
-class WaveCounters:
-    """Process-wide skew-aware wave-policy ledger (ITS-C010,
-    docs/serving_load.md).
-
-    The ``engine_wave_*`` vocabulary the manage plane's /metrics exporter
-    re-serves (``server.py _engine_wave_prometheus_lines``) and ``GET
-    /wave`` snapshots — kept in lockstep with both and with the
-    serving-load docs by the counters checker. Per-harness figures live
-    in ``ContinuousBatchingHarness.metrics``; this singleton aggregates
-    across every decoder in the process so dashboards see engine flows
-    without holding a harness reference. Key vocabulary (every key
-    ``engine_wave_``-prefixed, documented in docs/serving_load.md):
-
-    - ``engine_wave_deferrals``: chunks re-queued to ride a later wave
-      because launching them now would bump the (T, P) jit bucket past
-      the marginal-pad threshold.
-    - ``engine_wave_aging_escapes``: deferred chunks force-launched
-      because their deferral age crossed the QoS-aware starvation bound
-      (``wave_defer_max_s``) — the proof deferral never starves.
-    - ``engine_wave_held_flushes``: whole flushes held back by the EWMA
-      wave-size target (a hot engine refusing a degenerate 1-row wave).
-    - ``engine_wave_policy_waves``: waves launched with the policy on.
-    - ``engine_wave_launches``: waves launched, policy on or off.
-    - ``engine_wave_host_transfers``: host arrays uploaded for those waves
-      plus blocking device-to-host reads made for their tokens: 2 a wave
-      (the packed operand up, the sampled ids down), whatever its rows.
-    - ``engine_wave_defer_age_us_p99``: p99 deferral age at launch.
-    - ``engine_wave_bucket_occupancy``: real rows / launched rows over
-      policy waves (1 - pad fraction — what the deferral rule raises).
-    """
-
-    def __init__(self):
-        # Written only on the engine loop (the flush/launch path runs
-        # there); the manage-plane server thread snapshots via status().
-        # its: guard[_c, _ages_us, _real_rows, _launched_rows: single_writer]
-        self._c = {
-            # Requests re-queued to ride a later wave because launching
-            # them now would bump the (T, P) jit bucket past the pad
-            # threshold.
-            "engine_wave_deferrals": 0,
-            # Deferred requests force-launched because their deferral age
-            # crossed the starvation bound (wave_defer_max_s, QoS-aware).
-            "engine_wave_aging_escapes": 0,
-            # Whole flushes held back by the EWMA wave-size target (a hot
-            # engine refusing to launch a degenerate under-target wave).
-            "engine_wave_held_flushes": 0,
-            # Waves launched with the skew policy active.
-            "engine_wave_policy_waves": 0,
-            # Waves launched at all, and their traffic with the device:
-            # host arrays uploaded plus blocking reads of their tokens.
-            "engine_wave_launches": 0,
-            "engine_wave_host_transfers": 0,
-        }
-        self._ages_us: list = []
-        self._real_rows = 0
-        self._launched_rows = 0
-
-    def bump(self, key: str, n: int = 1):
-        self._c[key] += n
-
-    def note_defer_age(self, age_us: float):
-        """Record a previously-deferred entry's age at launch (bounded)."""
-        if len(self._ages_us) < 8192:
-            self._ages_us.append(age_us)
-
-    def note_wave(self, real_rows: int, launched_rows: int):
-        self._real_rows += real_rows
-        self._launched_rows += launched_rows
-
-    def status(self) -> dict:
-        c = self._c
-        ages = sorted(self._ages_us)
-        p99 = ages[min(len(ages) - 1, int(len(ages) * 0.99))] if ages else 0.0
-        return {
-            "engine_wave_deferrals": c["engine_wave_deferrals"],
-            "engine_wave_aging_escapes": c["engine_wave_aging_escapes"],
-            "engine_wave_held_flushes": c["engine_wave_held_flushes"],
-            "engine_wave_policy_waves": c["engine_wave_policy_waves"],
-            "engine_wave_launches": c["engine_wave_launches"],
-            "engine_wave_host_transfers": c["engine_wave_host_transfers"],
-            # p99 deferral age at launch: how long the policy actually
-            # parks a request (bounded by the starvation rule).
-            "engine_wave_defer_age_us_p99": round(p99, 1),
-            # Fraction of launched wave rows that were REAL (1 - pad
-            # fraction), over policy-launched waves: the bucket-economics
-            # figure the deferral rule exists to raise.
-            "engine_wave_bucket_occupancy": (
-                round(self._real_rows / self._launched_rows, 4)
-                if self._launched_rows
-                else 0.0
-            ),
-        }
-
-
-_WAVE_COUNTERS = WaveCounters()
-
-
-def wave_counters() -> WaveCounters:
-    """The process-wide wave-policy ledger (see :class:`WaveCounters`)."""
-    return _WAVE_COUNTERS
-
-
-def reset_wave_counters() -> WaveCounters:
-    """Fresh ledger (test isolation); returns the new one."""
-    global _WAVE_COUNTERS
-    _WAVE_COUNTERS = WaveCounters()
-    return _WAVE_COUNTERS
 
 
 class BlockPool:
@@ -305,6 +196,19 @@ class _WaveOut:
         self.host_ids = None  # np [T], once the first request has asked
 
 
+class _Wave(NamedTuple):
+    """One assembled wave (``WaveDecoder._assemble``): ``launch``'s operands
+    and the rows of them that are real."""
+
+    tokens: List[int]  # [T], the tail the last real row repeated
+    positions: List[int]  # [T]
+    row_of: List[int]  # [T], the table row each flat token reads
+    meta: RaggedWaveMeta  # every layer's pages
+    tables: List[np.ndarray]  # [B], the tail the last real table repeated
+    wmeta: Optional[RaggedWaveMeta]  # the sliding layers' pages
+    real_rows: int
+
+
 class WaveDecoder:
     """Coalesce decode AND verify steps from concurrent requests into
     lockstep waves.
@@ -361,54 +265,9 @@ class WaveDecoder:
     1 - sum(len_i) / T_bucket). ``wave_pages``/``wave_pad_pages`` count the
     flat attention pages launched and how many were the page bucket's
     padding (``RaggedWaveMeta.pad_pages``): the share the kernel skips.
-
-    **Skew-aware flush policy** (``skew_policy=True``, off by default;
-    docs/serving_load.md): blind first-arrival flush lets one 8:1-skew
-    outlier bump the whole wave's (T, P) jit bucket and pad every other
-    row. With the policy on, the flush PARTITIONS the taken batch: an
-    entry whose rows/pages would bump the power-of-two bucket AND whose
-    marginal pad cost exceeds ``defer_pad_frac`` rides the next wave —
-    UNLESS its deferral age crossed the starvation bound
-    (``defer_max_s`` for FOREGROUND entries, ``defer_max_bg_s`` for
-    BACKGROUND ones — the QoS class ``step_chunk`` carries), in which
-    case it launches now (an *aging escape*). An EWMA arrival-rate
-    wave-size target additionally holds a degenerate under-target flush
-    for up to ``hold_max_s`` while arrivals are hot, so a busy engine
-    stops launching 1-row waves. Deferred entries return to the FRONT
-    of the queue and a timed kick guarantees a re-flush even with no
-    new arrivals — deferral is never stranding, and each flush keeps at
-    least its smallest entry, so progress is unconditional. The policy
-    is scheduling-only: it changes which wave a chunk rides, never its
-    bytes — the byte-identity property vs sequential decode holds with
-    deferral on (tested).
-
-    **Canonical bucket ladder** (policy on): a blind flush jit-buckets
-    each dimension independently, so serving mints the organic
-    (B, T, P) PRODUCT one ~1 s XLA compile at a time — measured traces
-    reach ~25 distinct triples, discovered stochastically across
-    rounds. With the policy on every launch instead lands on the
-    declared bucket ``(T, T, T * max_req_blocks)``: table rows pad up
-    to the flat-row rung (free — a padded table row neither scatters
-    nor attends) and pages pad to the rung maximum (padded pages fold
-    fully masked), leaving T — the only dimension whose padding costs
-    real compute — on its power-of-two ladder, already bounded by the
-    deferral rule. One jit entry per rung means the whole compiled
-    working set is known AT STARTUP:
-    ``ContinuousBatchingHarness.prewarm_wave_buckets`` compiles the
-    ladder before serving, so the policy path never pays a mid-serving
-    recompile stall. The padding is masked/unreferenced either way, so
-    byte identity is unchanged.
     """
 
-    def __init__(
-        self,
-        harness: "ContinuousBatchingHarness",
-        skew_policy: bool = False,
-        defer_max_s: float = 0.025,
-        defer_max_bg_s: Optional[float] = None,
-        defer_pad_frac: float = 0.25,
-        hold_max_s: float = 0.002,
-    ):
+    def __init__(self, harness: "ContinuousBatchingHarness"):
         self.h = harness
         # Of the cache's shape the decoder needs the layers' windows alone:
         # a sliding layer walks the wave's second page list.
@@ -416,16 +275,6 @@ class WaveDecoder:
         self._window = spec.window
         self._layers = spec.num_layers
         self._sliding = sum(w is not None for w in spec.windows or ())
-        self.skew_policy = skew_policy
-        self.defer_max_s = defer_max_s
-        # BACKGROUND entries tolerate 4x the deferral age by default: the
-        # starvation bound is QoS-aware (docs/qos.md), so deferring a
-        # heavy background outlier never costs a foreground TTFT.
-        self.defer_max_bg_s = (
-            defer_max_bg_s if defer_max_bg_s is not None else defer_max_s * 4
-        )
-        self.defer_pad_frac = defer_pad_frac
-        self.hold_max_s = hold_max_s
         self._pending: List[tuple] = []
         self._flush_scheduled = False
         # Wave-row padding ledger (engine_wave_pad_fraction).
@@ -461,18 +310,6 @@ class WaveDecoder:
         self._step_counters = {
             name: [] for name in getattr(harness.config, "step_counters", ())
         }
-        # Skew-policy ledger (per-decoder; the process-wide WaveCounters
-        # singleton aggregates the same events for /metrics).
-        self.deferrals = 0
-        self.aging_escapes = 0
-        self.held_flushes = 0
-        self.defer_ages_us: List[float] = []
-        # EWMA of chunk inter-arrival seconds (policy on only): the
-        # wave-size target is hold_max_s / interval — what a full hold
-        # window would coalesce at the current arrival rate.
-        self._ewma_interval: Optional[float] = None
-        self._last_arrival: Optional[float] = None
-        self._kick_handle = None
         # Strong references: the event loop holds only weak refs to tasks,
         # so a fire-and-forget flush could be GC'd mid-flight and strand
         # every waiter with _flush_scheduled stuck True. A SET, not a slot:
@@ -482,20 +319,11 @@ class WaveDecoder:
         self._flush_tasks = set()
         self.waves = 0
         self.max_wave = 0
-        self.bucket_sizes = set()  # distinct PADDED (B, K) buckets (= compiles)
-        # Canonical (B, T, P) buckets prewarm_wave_buckets compiled at
-        # startup — organic bucket_sizes stays launch-driven so the two
-        # sets can be compared (serving must mint nothing beyond the
-        # declared ladder with the policy on).
-        self.prewarmed = set()
+        self.bucket_sizes = set()  # distinct PADDED (B, T, P) buckets (= compiles)
 
-    async def step(
-        self, token: int, position: int, padded_table, priority: int = 0
-    ) -> jax.Array:
+    async def step(self, token: int, position: int, padded_table) -> jax.Array:
         """Advance this request by one token; returns its logits row."""
-        rows = await self.step_chunk(
-            [token], [position], padded_table, priority=priority
-        )
+        rows = await self.step_chunk([token], [position], padded_table)
         return rows[0]
 
     async def step_chunk(
@@ -507,28 +335,13 @@ class WaveDecoder:
     ) -> jax.Array:
         """Advance this request by a token chunk (tokens[0] committed,
         tokens[1:] speculative); returns its [len(tokens), vocab] logits
-        rows — row j follows tokens[:j+1]. ``priority`` is the request's
-        QoS class (wire.PRIORITY_*): the skew policy's starvation bound
-        is tighter for FOREGROUND entries; with the policy off it is
-        recorded and ignored."""
+        rows — row j follows tokens[:j+1]. ``priority`` is taken and ignored:
+        every entry rides the next wave, whatever its class."""
         if not tokens or len(tokens) != len(positions):
             raise ValueError("need non-empty tokens with matching positions")
-        now = time.perf_counter()
-        if self.skew_policy:
-            if self._last_arrival is not None:
-                dt = now - self._last_arrival
-                self._ewma_interval = (
-                    dt if self._ewma_interval is None
-                    else 0.2 * dt + 0.8 * self._ewma_interval
-                )
-            self._last_arrival = now
         fut = asyncio.get_running_loop().create_future()
-        # Entry layout: (tokens, positions, table, future, enqueue_t,
-        # qos_priority, defer_count). The trailing three fields are
-        # policy metadata — the policy-off path never reads them.
-        self._pending.append(
-            (list(tokens), list(positions), padded_table, fut, now, priority, 0)
-        )
+        # A queue entry: (tokens, positions, table, future).
+        self._pending.append((list(tokens), list(positions), padded_table, fut))
         if not self._flush_scheduled:
             self._flush_scheduled = True
             task = asyncio.ensure_future(self._flush())
@@ -576,7 +389,6 @@ class WaveDecoder:
         if out.host_ids is None:
             out.host_ids = np.asarray(out.ids)
             self.blocking_reads += 1
-            _WAVE_COUNTERS.bump("engine_wave_host_transfers")
         return out.host_ids[off : off + n]
 
     def row_aux(self, rows):
@@ -597,131 +409,60 @@ class WaveDecoder:
             for name, held in self._step_counters.items()
         }
 
-    # -- skew-aware flush policy (docs/serving_load.md) ---------------------
+    def _assemble(self, batch: List[tuple]) -> "_Wave":
+        """The taken ``batch`` as one wave's host-side operands, and the
+        decoder's pad and page ledgers moved by it: no ``await``, no device.
 
-    def _defer_bound_s(self, priority: int) -> float:
-        """Starvation bound for one entry's QoS class."""
-        return (
-            self.defer_max_bg_s if priority == PRIORITY_BACKGROUND
-            else self.defer_max_s
-        )
-
-    def _target_rows(self) -> float:
-        """EWMA wave-size target: the rows a full hold window would
-        coalesce at the observed arrival rate (1.0 when idle/unknown —
-        an idle engine never holds a flush)."""
-        if not self._ewma_interval or self._ewma_interval <= 0:
-            return 1.0
-        return min(32.0, self.hold_max_s / self._ewma_interval)
-
-    def _entry_pages(self, entry) -> int:
-        """Attention pages this entry's flat rows contribute (the same
-        per-row rule build_ragged_wave applies: ceil((pos+1)/bt))."""
+        Ragged assembly (class docstring): the chunks concatenated into one
+        flat token list, padded only at the tail to the power-of-two row
+        bucket by repeating the last flat row (same-bytes scatter,
+        cache-safe). Table rows pad to a power-of-two B: no flat token
+        references a padded row, so it neither scatters nor attends. Tables
+        arrive host-resident (``_padded_table``): converting a DEVICE array
+        here would pay a blocking sync per request per wave."""
+        tokens: List[int] = []
+        positions: List[int] = []
+        row_of: List[int] = []
+        for r, (toks, pos, _tbl, _fut) in enumerate(batch):
+            tokens.extend(toks)
+            positions.extend(pos)
+            row_of.extend([r] * len(toks))
+        t_real = len(tokens)
+        t_bucket = 1 << (t_real - 1).bit_length()
+        tokens.extend([tokens[-1]] * (t_bucket - t_real))
+        positions.extend([positions[-1]] * (t_bucket - t_real))
+        row_of.extend([row_of[-1]] * (t_bucket - t_real))
+        b_bucket = 1 << (len(batch) - 1).bit_length()
+        tables = [np.asarray(b[2], dtype=np.int32) for b in batch]
+        tables.extend([tables[-1]] * (b_bucket - len(batch)))
+        # The builder picks the page bucket (pad_to_pow2): the per-row
+        # page-count rule lives in build_ragged_wave alone.
+        row_tables = [tables[r] for r in row_of]
+        row_lens = [p + 1 for p in positions]
         bt = self.h.config.block_tokens
-        return sum(-(-(p + 1) // bt) for p in entry[1])
-
-    def _partition(self, batch: List[tuple], now: float):
-        """Split a taken batch into (take, defer) under the skew rule.
-
-        Aged entries (deferral age past their QoS bound) always launch.
-        Remaining entries are admitted smallest-chunk-first; one is
-        deferred only when adding it bumps the power-of-two row or page
-        bucket AND the resulting marginal pad fraction exceeds
-        ``defer_pad_frac``. The first admitted entry is unconditional,
-        so a flush with any entry at all always launches at least one —
-        deferral can delay a chunk, never starve it."""
-        aged, flex = [], []
-        for e in batch:
-            age = now - e[4]
-            if age >= self._defer_bound_s(e[5]):
-                aged.append(e)
-            else:
-                flex.append(e)
-        for e in aged:
-            if e[6] > 0:
-                # Previously deferred, now force-launched by age: the
-                # starvation rule fired.
-                self.aging_escapes += 1
-                _WAVE_COUNTERS.bump("engine_wave_aging_escapes")
-        # EWMA hold: a hot engine flushing a degenerate under-target wave
-        # holds the WHOLE batch for the next kick instead — but never past
-        # hold_max_s of the oldest entry's age, and never when an aged
-        # entry must launch.
-        if not aged and flex:
-            rows = sum(len(e[0]) for e in flex)
-            oldest = max(now - e[4] for e in flex)
-            if rows < self._target_rows() and oldest < self.hold_max_s:
-                self.held_flushes += 1
-                _WAVE_COUNTERS.bump("engine_wave_held_flushes")
-                return [], [e[:6] + (e[6] + 1,) for e in flex]
-        kept = {id(e) for e in aged}
-        kept_rows = sum(len(e[0]) for e in aged)
-        kept_pages = sum(self._entry_pages(e) for e in aged)
-        deferred_ids = set()
-        for e in sorted(flex, key=lambda e: len(e[0])):
-            r, p = len(e[0]), self._entry_pages(e)
-            if kept_rows or kept_pages:
-                t_new = 1 << (kept_rows + r - 1).bit_length()
-                t_old = 1 << (kept_rows - 1).bit_length()
-                p_new = 1 << (kept_pages + p - 1).bit_length()
-                p_old = 1 << (kept_pages - 1).bit_length()
-                bump_t = t_new > t_old and (
-                    (t_new - (kept_rows + r)) / t_new > self.defer_pad_frac
-                )
-                bump_p = p_new > p_old and (
-                    (p_new - (kept_pages + p)) / p_new > self.defer_pad_frac
-                )
-                if bump_t or bump_p:
-                    deferred_ids.add(id(e))
-                    continue
-            kept.add(id(e))
-            kept_rows += r
-            kept_pages += p
-        take, defer = [], []
-        for e in batch:  # preserve arrival order on both sides
-            if id(e) in deferred_ids:
-                self.deferrals += 1
-                _WAVE_COUNTERS.bump("engine_wave_deferrals")
-                defer.append(e[:6] + (e[6] + 1,))
-            else:
-                take.append(e)
-        return take, defer
-
-    def _schedule_kick(self, deferred: List[tuple], now: float):
-        """Guarantee a future flush for re-queued entries even if no new
-        chunk ever arrives: a timed kick at (roughly) the earliest
-        starvation deadline, clamped to the hold window."""
-        if self._kick_handle is not None:
-            return
-        remaining = min(
-            max(self._defer_bound_s(e[5]) - (now - e[4]), 0.0)
-            for e in deferred
-        )
-        delay = max(min(remaining, self.hold_max_s), 0.0005)
-        self._kick_handle = asyncio.get_running_loop().call_later(
-            delay, self._kick
-        )
-
-    def _kick(self):
-        self._kick_handle = None
-        if self._pending and not self._flush_scheduled:
-            self._flush_scheduled = True
-            task = asyncio.ensure_future(self._flush())
-            self._flush_tasks.add(task)
-            task.add_done_callback(self._flush_tasks.discard)
-
-    def window_meta(self, row_tables, row_lens, meta):
-        """The wave's SECOND page list, which its sliding layers walk: per row
-        only the pages from its window's first on, padded to what the bucket's
-        rows can hold at most, so the bucket stays one program. None where
-        the cache's spec names no window."""
-        if self._window is None:
-            return None
-        bt = self.h.config.block_tokens
-        return build_ragged_wave(
-            row_tables, row_lens, bt, window=self._window,
-            pad_to=min(meta.num_pages, len(row_lens) * (self._window // bt + 1)),
-        )
+        meta = build_ragged_wave(row_tables, row_lens, bt, pad_to_pow2=True)
+        # The wave's SECOND page list, which its sliding layers walk: per
+        # row only the pages from its window's first on, padded to what the
+        # bucket's rows can hold at most, so the bucket stays one program.
+        # None where the cache's spec names no window.
+        wmeta = None
+        if self._window is not None:
+            wmeta = build_ragged_wave(
+                row_tables, row_lens, bt, window=self._window,
+                pad_to=min(meta.num_pages, t_bucket * (self._window // bt + 1)),
+            )
+        self.bucket_sizes.add((b_bucket, t_bucket, meta.num_pages))
+        self.pad_rows += t_bucket - t_real
+        self.launched_rows += t_bucket
+        self.wave_pages += meta.num_pages
+        self.wave_pad_pages += meta.pad_pages
+        real_pages = meta.num_pages - meta.pad_pages
+        if wmeta is not None:
+            self.wave_window_pages_skipped += self._sliding * (
+                real_pages - (wmeta.num_pages - wmeta.pad_pages)
+            )
+        self.wave_layer_pages += self._layers * real_pages
+        return _Wave(tokens, positions, row_of, meta, tables, wmeta, t_real)
 
     def launch(self, tokens, positions, row_of, meta, tables, wmeta=None):
         """ONE wave on the device (cache-mutating: caller holds the exclusive
@@ -731,9 +472,7 @@ class WaveDecoder:
         ``tables`` and, where the spec names a window, ``wmeta``'s triple),
         one jitted call runs the model's wave body on it, and the sampled
         ids start their way back to the host at once. Returns ``(logits [T,
-        vocab] on the device, the wave's _WaveOut, aux)``; ``flush`` and
-        ``prewarm_wave_buckets`` both launch through here, so what is warmed
-        is what serves."""
+        vocab] on the device, the wave's _WaveOut, aux)``."""
         pieces = [
             tokens, positions, row_of, meta.pages, meta.page_rows,
             meta.page_starts, np.stack(tables),
@@ -752,6 +491,21 @@ class WaveDecoder:
         ids.copy_to_host_async()
         return logits, _WaveOut(ids, aux.get("rows")), aux
 
+    def _resolve(self, batch: List[tuple], wave: "_Wave", logits, out, aux):
+        """Hand every entry of a launched wave its logits rows (only real
+        rows' futures resolve) and keep what the wave returned beside them."""
+        self.waves += 1
+        self.one_row_waves += wave.real_rows == 1
+        self.max_wave = max(self.max_wave, len(batch))
+        off, handed = 0, []
+        for toks, _, _, fut in batch:
+            if not fut.done():
+                rows = logits[off : off + len(toks)]
+                fut.set_result(rows)
+                handed.append((rows, off, len(toks)))
+            off += len(toks)
+        self._keep(out, aux, handed)
+
     async def _flush(self):
         batch: List[tuple] = []
         wspan = None
@@ -766,91 +520,17 @@ class WaveDecoder:
             if not batch:
                 return
             # `wave`: a trace of its own (it serves many requests), one
-            # span per flush that launches — a flush the policy holds back
-            # whole never finishes its span, so it is never recorded.
+            # span per flush.
             if tracing.enabled():
                 wspan = tracing.Span("wave")
                 wspan.stage("taken")
-            if self.skew_policy:
-                now = time.perf_counter()
-                batch, deferred = self._partition(batch, now)
-                if deferred:
-                    # Front of the queue: deferred entries are older than
-                    # anything arriving after the take, and the kick
-                    # guarantees a re-flush even with no new arrivals.
-                    self._pending[:0] = deferred
-                    self._schedule_kick(deferred, now)
-                if not batch:
-                    return
-                for e in batch:
-                    if e[6] > 0:
-                        age_us = (now - e[4]) * 1e6
-                        self.defer_ages_us.append(age_us)
-                        _WAVE_COUNTERS.note_defer_age(age_us)
-            # Ragged assembly (class docstring): concatenate the chunks
-            # into one flat token list; pad only at the tail to the
-            # power-of-two row bucket by repeating the last flat row
-            # (same-bytes scatter, cache-safe); only real rows' futures
-            # resolve.
-            flat_toks: List[int] = []
-            flat_pos: List[int] = []
-            row_of: List[int] = []
-            for r, (toks, pos, _tbl, _fut, *_) in enumerate(batch):
-                flat_toks.extend(toks)
-                flat_pos.extend(pos)
-                row_of.extend([r] * len(toks))
-            t_real = len(flat_toks)
-            t_bucket = 1 << (t_real - 1).bit_length()
-            flat_toks.extend([flat_toks[-1]] * (t_bucket - t_real))
-            flat_pos.extend([flat_pos[-1]] * (t_bucket - t_real))
-            row_of.extend([row_of[-1]] * (t_bucket - t_real))
-            # Table rows pad to a power-of-two B: no flat token references
-            # a padded row, so it neither scatters nor attends. Tables
-            # arrive host-resident (_padded_table) — converting a DEVICE
-            # array here would pay a blocking sync per request per wave.
-            # Policy on: canonical bucket ladder (class docstring) — B
-            # pads to the flat-row rung and pages to the rung maximum,
-            # so the launch lands on (T, T, T * max_req_blocks), the one
-            # declared jit bucket per rung that prewarm_wave_buckets
-            # compiled at startup. Both pads are compute-free; t_bucket
-            # >= one flat row per entry >= len(batch) always covers.
-            if self.skew_policy:
-                b_bucket = t_bucket
-                pad_pages = t_bucket * self.h.max_req_blocks
-            else:
-                b_bucket = 1 << (len(batch) - 1).bit_length()
-                pad_pages = 0
-            tables = [np.asarray(b[2], dtype=np.int32) for b in batch]
-            tables.extend([tables[-1]] * (b_bucket - len(batch)))
-            # The builder picks the page bucket (pad_to_pow2, or the
-            # canonical pad_to): the per-row page-count rule lives in
-            # build_ragged_wave alone.
-            row_tables = [tables[r] for r in row_of]
-            row_lens = [p + 1 for p in flat_pos]
-            bt = self.h.config.block_tokens
-            meta = build_ragged_wave(
-                row_tables, row_lens, bt, pad_to=pad_pages, pad_to_pow2=True
-            )
-            self.bucket_sizes.add((b_bucket, t_bucket, meta.num_pages))
-            self.pad_rows += t_bucket - t_real
-            self.launched_rows += t_bucket
-            self.wave_pages += meta.num_pages
-            self.wave_pad_pages += meta.pad_pages
-            real_pages = meta.num_pages - meta.pad_pages
-            wmeta = self.window_meta(row_tables, row_lens, meta)
-            if wmeta is not None:
-                self.wave_window_pages_skipped += self._sliding * (
-                    real_pages - (wmeta.num_pages - wmeta.pad_pages)
-                )
-            self.wave_layer_pages += self._layers * real_pages
-            if self.skew_policy:
-                _WAVE_COUNTERS.bump("engine_wave_policy_waves")
-                _WAVE_COUNTERS.note_wave(t_real, t_bucket)
+            wave = self._assemble(batch)
             if wspan is not None:
                 wspan.stage("assembled")
                 wspan.annotate(
-                    entries=len(batch), real_rows=t_real, rows=t_bucket,
-                    pages=meta.num_pages, pad_pages=meta.pad_pages,
+                    entries=len(batch), real_rows=wave.real_rows,
+                    rows=len(wave.tokens), pages=wave.meta.num_pages,
+                    pad_pages=wave.meta.pad_pages,
                 )
 
             # The flush task inherited the context of the request that
@@ -862,23 +542,12 @@ class WaveDecoder:
                         wspan.stage("gate")
                     with tracing.device_call("its.wave_dispatch", wspan):
                         logits, out, aux = self.launch(
-                            flat_toks, flat_pos, row_of, meta, tables, wmeta
+                            wave.tokens, wave.positions, wave.row_of,
+                            wave.meta, wave.tables, wave.wmeta,
                         )
                     if wspan is not None:
                         wspan.stage("dispatched")
-            _WAVE_COUNTERS.bump("engine_wave_launches")
-            _WAVE_COUNTERS.bump("engine_wave_host_transfers")
-            self.waves += 1
-            self.one_row_waves += t_real == 1
-            self.max_wave = max(self.max_wave, len(batch))
-            off, handed = 0, []
-            for toks, _, _, fut, *_ in batch:
-                if not fut.done():
-                    rows = logits[off : off + len(toks)]
-                    fut.set_result(rows)
-                    handed.append((rows, off, len(toks)))
-                off += len(toks)
-            self._keep(out, aux, handed)
+            self._resolve(batch, wave, logits, out, aux)
             if wspan is not None:
                 wspan.stage("resolved")
                 wspan.finish()
@@ -893,7 +562,7 @@ class WaveDecoder:
             exc = e if isinstance(e, Exception) else RuntimeError(
                 f"decode wave aborted: {e!r}"
             )
-            for _, _, _, fut, *_ in stranded:
+            for _, _, _, fut in stranded:
                 if not fut.done():
                     fut.set_exception(exc)
             if not isinstance(e, Exception):
@@ -1105,8 +774,7 @@ class RequestStats:
     # actually beats recomputing.
     prefix_ready_us: float = 0.0
     # t0 -> the first wave handed this request its logits rows (0.0 when
-    # gen_tokens == 0): the serving-side latency figure the skew-aware
-    # flush policy is graded on (docs/serving_load.md). It PRECEDES the
+    # gen_tokens == 0): the serving-side latency figure. It PRECEDES the
     # read-back that puts the sampled token on the host; the emit time
     # itself is token_emit_s[0]. And the request's QoS class
     # (wire.PRIORITY_*), so TTFT percentiles split by class.
@@ -1163,22 +831,12 @@ class ContinuousBatchingHarness:
         verify: bool = False,
         verify_tol: float = 2e-4,
         drafter: Optional[NGramDrafter] = None,
-        wave_skew_policy: bool = False,
-        wave_defer_max_s: float = 0.025,
-        wave_defer_max_bg_s: Optional[float] = None,
-        wave_defer_pad_frac: float = 0.25,
-        wave_hold_max_s: float = 0.002,
     ):
         """``drafter``: enables speculative decoding in the serving loop —
         each generation round verifies the drafted chunk in one wave row
         (verify_step_ragged), emitting every greedy-accepted token plus
         the model's continuation, so tokens/round can exceed 1 with output
-        identical to plain greedy decode.
-
-        ``wave_skew_policy`` + the ``wave_defer_*`` / ``wave_hold_max_s``
-        knobs: the WaveDecoder's skew-aware deferral flush policy
-        (docs/serving_load.md). Off by default — the False path is
-        behavior-identical to the blind first-arrival flush (tested)."""
+        identical to plain greedy decode."""
         self.adapter = adapter
         self.params = params
         self.config = config
@@ -1196,14 +854,7 @@ class ContinuousBatchingHarness:
         self.caches = self.spec.make_caches()
         self.pool = BlockPool(num_blocks)
         self.gate = DeviceGate()
-        self.wave = WaveDecoder(
-            self,
-            skew_policy=wave_skew_policy,
-            defer_max_s=wave_defer_max_s,
-            defer_max_bg_s=wave_defer_max_bg_s,
-            defer_pad_frac=wave_defer_pad_frac,
-            hold_max_s=wave_hold_max_s,
-        )
+        self.wave = WaveDecoder(self)
         self.max_req_blocks = max_req_blocks
         self.verify = verify
         # float-exact stores hold 2e-4; a quantizing adapter (int8 blocks,
@@ -1242,44 +893,6 @@ class ContinuousBatchingHarness:
         self._prefill_per_block_s: Optional[float] = None
 
     # -- model compute -------------------------------------------------------
-
-    async def prewarm_wave_buckets(self, max_rows: int = 64) -> list:
-        """Precompile the skew policy's declared wave-bucket ladder.
-
-        With ``wave_skew_policy`` on, every wave launches on the
-        canonical ``(T, T, T * max_req_blocks)`` bucket (WaveDecoder
-        docstring), so the jit working set is KNOWN AT STARTUP: one
-        bucket per power-of-two row rung up to ``max_rows``. This runs
-        one throwaway wave per rung — the real ``verify_step_ragged``
-        program, zero tokens at position 0 — so every bucket compile
-        lands here instead of stalling a serving round (the mid-serving
-        XLA recompile is the tail-latency pathology
-        docs/serving_load.md measures). The dummy scatter rides block
-        0 slot 0, harmless under the cache invariant: a request only
-        attends slots its own prefill/decode populated. No-op
-        (returns ``[]``) with the policy off — a blind flush has no
-        declared shape set, which is exactly why it keeps compiling
-        mid-serving. Returns the prewarmed ladder."""
-        if not self.wave.skew_policy:
-            return []
-        mrb = self.max_req_blocks
-        ladder = []
-        t = 1
-        while t <= max_rows:
-            tables, ones, zeros = [np.zeros(mrb, np.int32)] * t, [1] * t, [0] * t
-            meta = build_ragged_wave(
-                tables, ones, self.config.block_tokens, pad_to=t * mrb
-            )
-            async with self.gate.exclusive():
-                self.wave.launch(
-                    zeros, zeros, zeros, meta, tables,
-                    self.wave.window_meta(tables, ones, meta),
-                )
-            bucket = (t, t, t * mrb)
-            self.wave.prewarmed.add(bucket)
-            ladder.append(bucket)
-            t <<= 1
-        return ladder
 
     def _padded_table(self, table: np.ndarray) -> np.ndarray:
         """Host-resident padded table. Numpy ON PURPOSE: the WaveDecoder
@@ -1464,9 +1077,7 @@ class ContinuousBatchingHarness:
         snapshot = await self._snapshot_blocks(phys_blocks, before_first_token)
         await self._write_snapshot(chain_ids, snapshot, first_block)
 
-    async def _generate(
-        self, token_ids, table: np.ndarray, gen_tokens: int, priority: int = 0
-    ):
+    async def _generate(self, token_ids, table: np.ndarray, gen_tokens: int):
         """Greedy generation through the shared WaveDecoder: every live
         request advances one round per lockstep wave (the continuous-
         batching inner loop). The first round re-decodes the last prompt
@@ -1509,8 +1120,7 @@ class ContinuousBatchingHarness:
                 if gspan is not None:
                     gspan.stage("wave_enqueue")
                 rows = await self.wave.step_chunk(
-                    chunk, list(range(pos, pos + len(chunk))), padded,
-                    priority=priority,
+                    chunk, list(range(pos, pos + len(chunk))), padded
                 )
                 if gspan is not None:
                     gspan.stage("wave_result")
@@ -1542,7 +1152,7 @@ class ContinuousBatchingHarness:
             # persists), one more step lands it; otherwise its block is an
             # incomplete tail with no chain key — skip the wasted wave.
             if (len(token_ids) + gen_tokens) % self.config.block_tokens == 0:
-                await self.wave.step(tok, pos, padded, priority=priority)
+                await self.wave.step(tok, pos, padded)
         return out, first_token_t, emit_s
 
     def _verify_request(self, token_ids, table: np.ndarray) -> bool:
@@ -1598,9 +1208,8 @@ class ContinuousBatchingHarness:
 
         ``priority``: the request's QoS class (wire.PRIORITY_*).
         BACKGROUND requests tag their speculative store prefetch
-        background and tolerate a longer wave-deferral age under the
-        skew-aware flush policy (docs/serving_load.md); the class is
-        recorded on the stats so TTFT percentiles split by class."""
+        background; the class is recorded on the stats so TTFT percentiles
+        split by class."""
         bt = self.config.block_tokens
         if self.spec.has_state:
             # A recurrent state absorbs a token ONCE, and the first wave
@@ -1876,7 +1485,7 @@ class ContinuousBatchingHarness:
             token_emit_s: List[float] = []
             if gen_tokens:
                 generated, first_token_t, token_emit_s = await self._generate(
-                    token_ids, table, gen_tokens, priority=priority
+                    token_ids, table, gen_tokens
                 )
                 t_generated = time.perf_counter()
                 if rspan is not None:
@@ -2023,9 +1632,7 @@ class ContinuousBatchingHarness:
         (``resumes``, ``resume_tokens`` — suffix tokens they computed —
         and ``resume_pages`` — context pages their attention walked); the
         ragged wave-decode story (``decode_waves``, ``max_wave_size``,
-        ``wave_buckets`` — distinct padded (B, T, P) jit buckets —
-        ``wave_prewarmed_buckets`` — the canonical ladder
-        ``prewarm_wave_buckets`` compiled at startup — and
+        ``wave_buckets`` — distinct padded (B, T, P) jit buckets — and
         ``wave_pad_fraction``, the share of launched wave rows that were
         padding, ``wave_one_row_waves``, the launched waves that carried
         ONE real flat row (a lone request's steps: the waves whose dense FFN
@@ -2037,12 +1644,7 @@ class ContinuousBatchingHarness:
         a stack of full layers would have; ``wave_host_transfers``, the host
         arrays uploaded for the launched waves plus the blocking
         device-to-host reads made for their tokens, 2 a wave; and whatever
-        the model's wave step counts itself, by its own names); the
-        skew-aware flush policy's ledger
-        (docs/serving_load.md: ``wave_deferrals``,
-        ``wave_aging_escapes`` — deferred entries force-launched at the
-        starvation bound, ``wave_held_flushes`` — whole flushes held by
-        the EWMA wave-size target, ``wave_defer_age_us_p99``) and
+        the model's wave step counts itself, by its own names);
         serving latency (``p50_ttft_us``, ``p99_ttft_us``,
         ``p99_ttft_fg_us`` — time to first generated token, overall and
         FOREGROUND-class only); generation/speculation (``generated_tokens``,
@@ -2141,9 +1743,6 @@ class ContinuousBatchingHarness:
             # the ragged wave step (jit keys on shape): the compile-count
             # story.
             "wave_buckets": sorted(self.wave.bucket_sizes),
-            # The canonical ladder prewarm_wave_buckets compiled at
-            # startup (policy on): serving must mint nothing beyond it.
-            "wave_prewarmed_buckets": sorted(self.wave.prewarmed),
             # Share of launched wave rows that were padding (ragged
             # assembly pads only the flat tail; the old rectangle padded
             # every short chunk to the widest one) — the attribution key
@@ -2173,16 +1772,8 @@ class ContinuousBatchingHarness:
             # ``aux``): an expert model's ``moe_pairs`` and
             # ``moe_distinct_experts``; nothing for a model that counts nothing.
             **self.wave.step_counters(),
-            # Skew-aware flush policy (docs/serving_load.md): the per-
-            # harness deferral ledger (the process-wide WaveCounters
-            # singleton aggregates the same events for /metrics), and
-            # time-to-first-token — the latency figure the policy is
-            # graded on, split so the FOREGROUND class's tail is visible
-            # next to the mixed one.
-            "wave_deferrals": self.wave.deferrals,
-            "wave_aging_escapes": self.wave.aging_escapes,
-            "wave_held_flushes": self.wave.held_flushes,
-            "wave_defer_age_us_p99": _p(sorted(self.wave.defer_ages_us), 0.99),
+            # Time to the first token, split so the FOREGROUND class's
+            # tail is visible next to the mixed one.
             "p50_ttft_us": _p(ttft, 0.50),
             "p99_ttft_us": _p(ttft, 0.99),
             "p99_ttft_fg_us": _p(ttft_fg, 0.99),

@@ -5,9 +5,8 @@ workload, but wave economics are decided by SKEW: Ragged Paged Attention
 is an argument about not paying for the skewed tail, and Zipf working
 sets are the access shape millions of real users actually produce. This
 module emits a deterministic, seeded, REPLAYABLE trace of an open-loop
-serving workload so the engine harness, ``bench.py``'s serving leg, the
-``benchmark.py --trace`` CLI mode, and the ``DisaggHarness`` all grade
-against one traffic shape:
+serving workload so the engine harness, the ``benchmark.py --trace`` CLI
+mode, and the ``DisaggHarness`` all grade against one traffic shape:
 
 - **Zipf prefix popularity** over a synthetic million-user population:
   each request draws a shared-prefix family with P(rank k) proportional
@@ -16,8 +15,8 @@ against one traffic shape:
 - **Log-normal lengths with a heavy tail**: prompt and output lengths
   are log-normal; a configurable outlier fraction multiplies the draw
   into the tail, and requests past ``bg_outlier_blocks`` total blocks
-  are tagged ``PRIORITY_BACKGROUND`` (the QoS class the skew-aware wave
-  flush policy's starvation bound keys on).
+  are tagged ``PRIORITY_BACKGROUND`` (the QoS class their store reads and
+  writes carry, docs/qos.md).
 - **Diurnal rate curve + burst storms**: the open-loop arrival rate is
   ``base_rate_rps * diurnal(t) * burst(t)`` — a sinusoidal day cycle
   with storm windows that multiply the rate — sampled by thinning a
@@ -49,10 +48,8 @@ from .wire import PRIORITY_BACKGROUND, PRIORITY_FOREGROUND
 TRACE_VERSION = 1
 
 # Named workload shapes (docs/serving_load.md). "skewed" is the default
-# serving mix the bench leg grades the flush policy under; "uniform" is
-# the null shape (no skew — a policy regression detector); "outlier_flood"
-# keeps a permanent stream of heavy background outliers in flight — the
-# starvation-bound leg (aging escapes must fire, never stranding).
+# serving mix; "uniform" is the null shape (no skew); "outlier_flood"
+# keeps a permanent stream of heavy background outliers in flight.
 PRESETS: Dict[str, dict] = {
     "skewed": dict(
         n_prefixes=64, zipf_s=1.2, base_rate_rps=200.0,

@@ -112,7 +112,6 @@ def _prometheus_text(stats: dict, membership_status: dict = None,
                      gossip_status: dict = None, tier_status: dict = None,
                      prof_status: dict = None, timeseries_status: dict = None,
                      disagg_status: dict = None,
-                     engine_wave_status: dict = None,
                      exemplars: bool = False) -> bytes:
     """Render the stats snapshot in Prometheus exposition format (the
     reference exposes no metrics at all — SURVEY.md §5.1/§5.5). With a
@@ -334,8 +333,6 @@ def _prometheus_text(stats: dict, membership_status: dict = None,
         lines += _tier_prometheus_lines(tier_status)
     if disagg_status is not None:
         lines += _disagg_prometheus_lines(disagg_status)
-    if engine_wave_status is not None:
-        lines += _engine_wave_prometheus_lines(engine_wave_status)
     if slo_status is not None:
         lines += _slo_prometheus_lines(slo_status)
     if prof_status is not None:
@@ -569,50 +566,6 @@ def _disagg_status():
     if dsd is None:
         return None
     return dsd.counters().status()
-
-
-def _engine_wave_prometheus_lines(ws: dict) -> list:
-    """Skew-aware wave-policy counter families for /metrics, from the flat
-    ``engine.WaveCounters.status`` snapshot (the same dict ``GET /wave``
-    serves). The counters checker (ITS-C010, tools/analysis/counters.py)
-    holds this exporter to the ``engine_wave_*`` ledger vocabulary both
-    ways — a deferral the dashboards cannot see is observability drift
-    (docs/serving_load.md)."""
-    return [
-        "# TYPE infinistore_engine_wave_deferrals counter",
-        f"infinistore_engine_wave_deferrals {ws['engine_wave_deferrals']}",
-        "# TYPE infinistore_engine_wave_aging_escapes counter",
-        "infinistore_engine_wave_aging_escapes "
-        f"{ws['engine_wave_aging_escapes']}",
-        "# TYPE infinistore_engine_wave_held_flushes counter",
-        f"infinistore_engine_wave_held_flushes {ws['engine_wave_held_flushes']}",
-        "# TYPE infinistore_engine_wave_policy_waves counter",
-        f"infinistore_engine_wave_policy_waves {ws['engine_wave_policy_waves']}",
-        "# TYPE infinistore_engine_wave_launches counter",
-        f"infinistore_engine_wave_launches {ws['engine_wave_launches']}",
-        "# TYPE infinistore_engine_wave_host_transfers counter",
-        "infinistore_engine_wave_host_transfers "
-        f"{ws['engine_wave_host_transfers']}",
-        "# TYPE infinistore_engine_wave_defer_age_us_p99 gauge",
-        "infinistore_engine_wave_defer_age_us_p99 "
-        f"{ws['engine_wave_defer_age_us_p99']}",
-        "# TYPE infinistore_engine_wave_bucket_occupancy gauge",
-        "infinistore_engine_wave_bucket_occupancy "
-        f"{ws['engine_wave_bucket_occupancy']}",
-    ]
-
-
-def _engine_wave_status():
-    """The process-wide wave-policy counter snapshot, or None when no
-    engine has run here. Lazy on purpose (same discipline as
-    ``_disagg_status``): ``infinistore_tpu.engine`` pulls in the jax
-    stack, and the core client/server API must stay importable without
-    it — so this only *observes* an already-imported module
-    (``sys.modules``), never imports one."""
-    eng = sys.modules.get("infinistore_tpu.engine")
-    if eng is None:
-        return None
-    return eng.wave_counters().status()
 
 
 def _prof_prometheus_lines(ps: dict) -> list:
@@ -924,7 +877,6 @@ class ManageServer:
                     if self.history is not None else None
                 )
                 ds = _disagg_status()
-                ws = _engine_wave_status()
                 try:
                     stats = await asyncio.to_thread(_lib.get_server_stats)
                 except Exception:
@@ -939,8 +891,6 @@ class ManageServer:
                         + (_gossip_prometheus_lines(gs) if gs is not None else [])
                         + (_tier_prometheus_lines(ts) if ts is not None else [])
                         + (_disagg_prometheus_lines(ds) if ds is not None else [])
-                        + (_engine_wave_prometheus_lines(ws)
-                           if ws is not None else [])
                         + _slo_prometheus_lines(slo)
                         + (_prof_prometheus_lines(ps) if ps is not None else [])
                         + (_timeseries_prometheus_lines(hs)
@@ -958,7 +908,6 @@ class ManageServer:
                     stats, membership_status=ms, slo_status=slo,
                     event_counts=counts, gossip_status=gs, tier_status=ts,
                     prof_status=ps, timeseries_status=hs, disagg_status=ds,
-                    engine_wave_status=ws,
                     exemplars=params.get("exemplars") == ["1"],
                 )
             if path == "/health" and method == "GET":
@@ -1070,19 +1019,6 @@ class ManageServer:
                         200, {"enabled": False, "error": "no handoff has run"}
                     )
                 return _http_response(200, {"enabled": True, **ds})
-            if path == "/wave" and method == "GET":
-                # Skew-aware wave flush policy (docs/serving_load.md): the
-                # flat engine_wave_* counter snapshot — the
-                # engine.WaveCounters.status vocabulary /metrics exports as
-                # infinistore_engine_wave_* (ITS-C010). Served only when an
-                # engine has run in this process; the module stays
-                # unimported (and jax unloaded) otherwise.
-                ws = _engine_wave_status()
-                if ws is None:
-                    return _http_response(
-                        200, {"enabled": False, "error": "no engine has run"}
-                    )
-                return _http_response(200, {"enabled": True, **ws})
             if path == "/membership" and method == "GET":
                 return self._membership_get()
             if path == "/membership" and method == "POST":
@@ -1094,7 +1030,7 @@ class ManageServer:
             if path in ("/purge", "/kvmap_len", "/stats", "/usage", "/metrics",
                         "/selftest", "/health", "/trace", "/membership",
                         "/slo", "/events", "/gossip", "/bootstrap", "/tiers",
-                        "/profile", "/timeseries", "/disagg", "/wave"):
+                        "/profile", "/timeseries", "/disagg"):
                 return _http_response(405, {"error": "method not allowed"})
             return _http_response(404, {"error": "not found"})
         except Exception as e:  # control plane must not die on a bad request

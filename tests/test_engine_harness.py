@@ -601,15 +601,25 @@ def test_device_gate_expedite_jumps_queued_writers():
 def test_device_gate_cancelled_writer_releases_queued_readers():
     """A reader queued behind a WAITING writer must wake when that writer's
     task is cancelled (e.g. a timed-out request) — not sleep forever on a
-    free gate."""
+    free gate. Ordered by the gate's own state, not by sleeps: the first
+    reader holds until released, so the writer cannot get in before it is
+    cancelled however slow the box."""
 
     async def run():
         gate = DeviceGate()
         got = []
+        release = asyncio.Event()
+
+        async def until(cond):
+            for _ in range(1000):
+                if cond():
+                    return
+                await asyncio.sleep(0)
+            raise AssertionError("the gate never reached the state waited for")
 
         async def hold_shared():
             async with gate.shared():
-                await asyncio.sleep(0.05)
+                await release.wait()
 
         async def writer():
             async with gate.exclusive():
@@ -620,13 +630,18 @@ def test_device_gate_cancelled_writer_releases_queued_readers():
                 got.append("r2")
 
         r1 = asyncio.ensure_future(hold_shared())
-        await asyncio.sleep(0.01)
+        await until(lambda: gate._shared == 1)
         w = asyncio.ensure_future(writer())
-        await asyncio.sleep(0.01)
+        await until(lambda: gate._exclusive_waiting == 1)
         r2 = asyncio.ensure_future(late_reader())
-        await asyncio.sleep(0.01)
+        for _ in range(5):  # the late reader parks behind the waiting writer
+            await asyncio.sleep(0)
+        assert got == [] and not r2.done()
         w.cancel()
-        await asyncio.gather(r1, r2, w, return_exceptions=True)
+        await asyncio.gather(w, return_exceptions=True)
+        await asyncio.wait_for(r2, 5)  # woken though r1 still holds: readers overlap
+        release.set()
+        await r1
         assert got == ["r2"], got
         async with gate.exclusive():  # gate still fully functional
             got.append("w2")
@@ -636,7 +651,7 @@ def test_device_gate_cancelled_writer_releases_queued_readers():
 
 
 # ---------------------------------------------------------------------------
-# Skew-aware wave flush policy (docs/serving_load.md, ROADMAP-6)
+# One flush path: whatever is ready rides the next wave
 # ---------------------------------------------------------------------------
 
 def _bare_wave_harness(params, caches=None):
@@ -658,8 +673,8 @@ def _bare_wave_harness(params, caches=None):
 
 
 def _skew_scenario(params):
-    """Two 1-token decode rows + one 3-token chunk whose admission bumps
-    the T bucket 2 -> 8 at pad 3/8 > 0.25: the canonical deferral case."""
+    """Two 1-token decode rows + one 3-token chunk, whose admission bumps
+    the T bucket 2 -> 8: a wave skewed in length."""
     from infinistore_tpu.models import prefill
 
     rng = np.random.default_rng(61)
@@ -705,67 +720,18 @@ def test_a_harness_wave_donates_the_harness_cache(params):
     assert not any(t.is_deleted() for layer in base for t in layer)
 
 
-def test_skew_policy_off_is_behavior_identical(params):
-    """wave_skew_policy=False (the default) must reproduce the blind
-    flush exactly: same coalescing, same pad accounting, same bytes, no
-    policy counters, the process ledger's policy keys untouched."""
-    from infinistore_tpu.engine import (
-        WaveDecoder, reset_wave_counters, wave_counters,
-    )
+def test_a_length_skewed_wave_is_one_wave_and_matches_sequential_decode(params):
+    """The flush takes whatever is ready: two one-token rows beside a
+    three-token chunk ride ONE wave of 5 real flat rows bucketed to 8, and
+    its logits and cache are those of advancing each request alone, to
+    float32 rounding."""
+    from infinistore_tpu.engine import WaveDecoder
 
     tables, chunks, base = _skew_scenario(params)
-    reset_wave_counters()
-
-    async def run(**kw):
-        h = _bare_wave_harness(params, base)
-        wave = WaveDecoder(h, **kw)
-        outs = await asyncio.gather(*(
-            wave.step_chunk(toks, pos, jnp.asarray(tables[b]))
-            for b, (toks, pos) in enumerate(chunks)
-        ))
-        return [np.asarray(o) for o in outs], h.caches, wave
-
-    default_outs, default_caches, default_wave = asyncio.run(run())
-    off_outs, off_caches, off_wave = asyncio.run(run(skew_policy=False))
-    assert default_wave.skew_policy is False  # the default IS off
-    for a, b in zip(default_outs, off_outs):
-        np.testing.assert_array_equal(a, b)
-    for layer in range(CFG.n_layers):
-        for kind in (0, 1):
-            np.testing.assert_array_equal(
-                np.asarray(default_caches[layer][kind]),
-                np.asarray(off_caches[layer][kind]),
-            )
-    for w in (default_wave, off_wave):
-        # Exactly the blind flush of the byte-identity pin: one 3-entry
-        # wave, 5 real flat rows bucketed to 8.
-        assert w.max_wave == 3
-        assert (w.launched_rows, w.pad_rows) == (8, 3)
-        assert w.deferrals == 0 and w.aging_escapes == 0
-        assert w.held_flushes == 0 and w.defer_ages_us == []
-    st = wave_counters().status()
-    # What the ledger counts of EVERY launched wave (since PR 36): the two
-    # runs' one wave each, and its upload (nobody asked for these waves' ids).
-    assert st.pop("engine_wave_launches") == 2
-    assert st.pop("engine_wave_host_transfers") == 2
-    assert all(v == 0 for v in st.values()), st
-
-
-def test_skew_policy_defers_outlier_and_stays_byte_identical(params):
-    """Policy on: the bucket-bumping 3-token chunk rides a later wave
-    (deferral counted, process ledger bumped) while logits AND cache
-    stay those of per-request sequential decode, to float32 rounding —
-    the scheduling-only guarantee."""
-    from infinistore_tpu.engine import (
-        WaveDecoder, reset_wave_counters, wave_counters,
-    )
-
-    tables, chunks, base = _skew_scenario(params)
-    reset_wave_counters()
 
     async def wave_run():
         h = _bare_wave_harness(params, base)
-        wave = WaveDecoder(h, skew_policy=True, hold_max_s=0.0)
+        wave = WaveDecoder(h)
         outs = await asyncio.gather(*(
             wave.step_chunk(toks, pos, jnp.asarray(tables[b]))
             for b, (toks, pos) in enumerate(chunks)
@@ -784,144 +750,69 @@ def test_skew_policy_defers_outlier_and_stays_byte_identical(params):
 
     wave_outs, wave_caches, wave = asyncio.run(wave_run())
     seq_outs, seq_caches = asyncio.run(seq_run())
-    assert wave.deferrals >= 1, "the outlier chunk was never deferred"
-    assert wave.max_wave == 2, "the outlier rode the first wave anyway"
-    assert wave.waves == 2
-    # The deferred wave's rows: wave 1 = 2 rows -> bucket 2 (0 pad),
-    # wave 2 = 3 rows -> bucket 4 (1 pad). Blind flush padded 3 of 8.
-    assert (wave.launched_rows, wave.pad_rows) == (6, 1)
-    assert len(wave.defer_ages_us) >= 1
+    assert (wave.waves, wave.max_wave) == (1, 3)
+    assert (wave.launched_rows, wave.pad_rows) == (8, 3)
+    assert wave.one_row_waves == 0 and not wave._pending
     for b in range(3):
         _assert_same_to_f32_rounding(
             wave_outs[b], seq_outs[b],
-            err_msg=f"request {b} logits diverged under deferral",
+            err_msg=f"request {b} logits diverged in the skewed wave",
         )
     for layer in range(CFG.n_layers):
         for kind in (0, 1):
             _assert_same_to_f32_rounding(
                 wave_caches[layer][kind], seq_caches[layer][kind],
-                err_msg=f"cache diverged under deferral (layer {layer})",
+                err_msg=f"cache diverged (layer {layer})",
             )
-    st = wave_counters().status()
-    assert st["engine_wave_deferrals"] >= 1
-    assert st["engine_wave_policy_waves"] == 2
-    assert st["engine_wave_defer_age_us_p99"] > 0
-    assert 0 < st["engine_wave_bucket_occupancy"] <= 1
 
 
-def test_skew_policy_aging_escape_under_outlier_flood(params):
-    """Starvation-proof: a permanent flood of small decode rows would
-    justify deferring the bucket-bumping outlier forever, but once its
-    age crosses wave_defer_max_s it force-launches (an aging escape) —
-    every future resolves, nothing strands."""
-    from infinistore_tpu.engine import WaveDecoder, reset_wave_counters
+@pytest.mark.parametrize("rows", [1, 2, 4])
+def test_concurrent_one_token_steps_launch_one_wave_on_their_bucket(params, rows):
+    """What the benchmark's warm-up stands on (benchmarks/run.py): ``rows``
+    concurrent one-token ``step_chunk`` calls on a fresh decoder launch ONE
+    wave and mint the bucket ``(rows, rows, pages)``."""
+    from infinistore_tpu.engine import WaveDecoder
 
-    tables, chunks, base = _skew_scenario(params)
-    reset_wave_counters()
+    bt, pages = CFG.block_tokens, 2 * rows
+    share = [pages // rows + (i < pages % rows) for i in range(rows)]
+    table = np.zeros(MAX_REQ_BLOCKS, np.int32)
 
     async def run():
-        h = _bare_wave_harness(params, base)
-        wave = WaveDecoder(
-            h, skew_policy=True, defer_max_s=0.02, hold_max_s=0.0
+        wave = WaveDecoder(_bare_wave_harness(params))
+        await asyncio.gather(
+            *(wave.step_chunk([0], [n * bt - 1], table) for n in share)
         )
-        toks, pos = chunks[1]
-        outlier = asyncio.ensure_future(
-            wave.step_chunk(toks, pos, jnp.asarray(tables[1]))
-        )
-        floods = 0
-        for _ in range(300):
-            if outlier.done():
-                break
-            await asyncio.gather(
-                wave.step(5, 16, jnp.asarray(tables[0])),
-                wave.step(13, 16, jnp.asarray(tables[2])),
-            )
-            floods += 1
-        logits = np.asarray(await asyncio.wait_for(outlier, 30))
-        return wave, logits, floods
+        return wave
 
-    wave, logits, floods = asyncio.run(run())
-    assert floods >= 1
-    assert wave.deferrals >= 1, "the flood never deferred the outlier"
-    assert wave.aging_escapes >= 1, (
-        "the outlier resolved without an aging escape — the starvation "
-        "bound never fired"
-    )
-    assert np.isfinite(logits).all() and logits.shape[0] == 3
-    # The escape is bounded: its recorded deferral age crossed the bound
-    # (that is WHY it launched), and the decoder is drained.
-    assert max(wave.defer_ages_us) >= 0.02 * 1e6
-    assert not wave._pending
+    wave = asyncio.run(run())
+    assert wave.waves == 1 and wave.max_wave == rows
+    assert wave.bucket_sizes == {(rows, rows, pages)}
+    assert (wave.launched_rows, wave.pad_rows) == (rows, 0)
 
 
-def test_skew_policy_end_to_end_verified(conn, params):
-    """Integration: a verify=True harness with the policy on serves a
-    shared-prefix workload — every request oracle-verified, TTFT
-    percentiles and the wave-policy ledger exposed via metrics()."""
-    h = ContinuousBatchingHarness(
-        EngineKVAdapter(KVConnector(
-            conn, CFG.kv_spec(NUM_BLOCKS), "engine-skew",
-            max_blocks=MAX_REQ_BLOCKS,
-        )),
-        params, CFG, NUM_BLOCKS, MAX_REQ_BLOCKS, verify=True,
-        wave_skew_policy=True, wave_hold_max_s=0.0,
-    )
-    assert h.wave.skew_policy is True
-    prompts = _prompts(6, shared_blocks=1, total_blocks=2, seed=3)
-    m = asyncio.run(h.run(prompts, concurrency=6, gen_tokens=CFG.block_tokens))
-    assert m["all_verified"], "a request diverged with the skew policy on"
-    assert m["requests"] == 6
-    for k in ("wave_deferrals", "wave_aging_escapes", "wave_held_flushes",
-              "wave_defer_age_us_p99", "p50_ttft_us", "p99_ttft_us",
-              "p99_ttft_fg_us"):
-        assert k in m, f"metrics() missing {k}"
-    assert m["p99_ttft_us"] > 0
-    assert m["p99_ttft_fg_us"] > 0  # default priority is FOREGROUND
+def test_step_chunk_takes_a_priority_and_ignores_it(params):
+    """The benchmark passes ``priority=`` on every step: a BACKGROUND and a
+    FOREGROUND entry ride one wave, and each gets the rows it gets without
+    the keyword."""
+    from infinistore_tpu.engine import WaveDecoder
+    from infinistore_tpu.wire import PRIORITY_BACKGROUND
+
+    tables, chunks, base = _skew_scenario(params)
+
+    async def run(classes):
+        wave = WaveDecoder(_bare_wave_harness(params, base))
+        outs = await asyncio.gather(*(
+            wave.step_chunk(toks, pos, tables[b], **kw)
+            for b, ((toks, pos), kw) in enumerate(zip(chunks, classes))
+        ))
+        return [np.asarray(o) for o in outs], wave
+
+    tagged, wave = asyncio.run(run(
+        [{"priority": PRIORITY_BACKGROUND}, {"priority": 0}, {"priority": PRIORITY_BACKGROUND}]
+    ))
+    plain, _ = asyncio.run(run([{}, {}, {}]))
+    assert (wave.waves, wave.max_wave) == (1, 3)
+    for a, b in zip(tagged, plain):
+        np.testing.assert_array_equal(a, b)
 
 
-def test_skew_policy_canonical_buckets_and_prewarm(conn, params):
-    """Policy on: every launched wave lands on the DECLARED canonical
-    bucket (T, T, T * max_req_blocks) — table rows pad to the flat-row
-    rung (free: a padded table row neither scatters nor attends), pages
-    pad to the rung maximum (masked) — and prewarm_wave_buckets()
-    compiles exactly that ladder at startup, so serving can never mint
-    a jit bucket startup didn't declare. Policy off: prewarm is a no-op
-    (a blind flush has no declared shape set)."""
-    h = ContinuousBatchingHarness(
-        EngineKVAdapter(KVConnector(
-            conn, CFG.kv_spec(NUM_BLOCKS), "engine-canon",
-            max_blocks=MAX_REQ_BLOCKS,
-        )),
-        params, CFG, NUM_BLOCKS, MAX_REQ_BLOCKS, verify=True,
-        wave_skew_policy=True, wave_hold_max_s=0.0,
-    )
-
-    async def drive():
-        ladder = await h.prewarm_wave_buckets(max_rows=16)
-        prompts = _prompts(5, shared_blocks=1, total_blocks=2, seed=47)
-        m = await h.run(prompts, concurrency=5, gen_tokens=6)
-        return ladder, m
-
-    ladder, m = asyncio.run(drive())
-    mrb = MAX_REQ_BLOCKS
-    assert ladder == [(t, t, t * mrb) for t in (1, 2, 4, 8, 16)]
-    assert m["wave_prewarmed_buckets"] == ladder
-    assert m["all_verified"], "canonical padding corrupted a request"
-    assert m["wave_buckets"], "no waves decoded"
-    for b, t, p in m["wave_buckets"]:
-        assert (b, t, p) == (t, t, t * mrb), (
-            f"off-ladder launch {(b, t, p)} — the canonical rule leaked"
-        )
-        # 5 concurrent 1-token chunks never exceed the declared ladder.
-        assert (b, t, p) in set(ladder), f"{(b, t, p)} was never declared"
-
-    # Policy off: nothing to prewarm, organic pow2 buckets untouched.
-    h_blind = ContinuousBatchingHarness(
-        EngineKVAdapter(KVConnector(
-            conn, CFG.kv_spec(NUM_BLOCKS), "engine-canon-off",
-            max_blocks=MAX_REQ_BLOCKS,
-        )),
-        params, CFG, NUM_BLOCKS, MAX_REQ_BLOCKS,
-    )
-    assert asyncio.run(h_blind.prewarm_wave_buckets()) == []
-    assert h_blind.wave.prewarmed == set()

@@ -16,6 +16,7 @@ import numpy as np
 import pytest
 
 import infinistore_tpu as its
+from infinistore_tpu import tracing
 from infinistore_tpu.connector import KVConnector
 from infinistore_tpu.engine import ContinuousBatchingHarness, EngineKVAdapter
 from infinistore_tpu.models import LlamaConfig, init_params
@@ -333,14 +334,19 @@ def test_engine_raced_eviction_falls_back_to_recompute(conn, params):
 
 
 def test_engine_hit_admission_not_slower_than_miss(conn):
-    """THE regression the split exists for: with store I/O off the gate and
-    overlapped, a prefix hit's end-to-end prefix residency (admission +
-    install, no compute) must not be slower than a miss's (admission +
-    full prefill) — a store that loses to recompute is pointless.
+    """THE regression the split exists for: a prefix hit must not lose to
+    recomputing, and what keeps it from losing is structural: store I/O is
+    off the gate and overlapped with the admission. Held here from the spans
+    and stamps the engine records, pair by pair, with no clock compared
+    against another: every layer's store read of a hit BEGINS before the
+    request asks for the exclusive gate; none begins inside the install's
+    hold; ``gate_hold_us`` covers the install and nothing else of the
+    request; a miss never holds the gate for store work. The ratio itself
+    (``ttft_hit_p50_ms`` against ``ttft_miss_p50_ms``) is the chip's to
+    read, in the benchmark's reuse cells.
 
-    Uses a model big enough that recompute has real cost (the toy 2-layer
-    dim-64 config prefills in under a millisecond, below the store's
-    fixed per-request cost — no store on earth wins that race)."""
+    Uses a model big enough that recompute has real cost (four layers: a
+    hit is four store reads and four uploads)."""
     big = LlamaConfig(
         vocab=256, dim=256, n_layers=4, n_heads=4, n_kv_heads=2, ffn_dim=512,
         block_tokens=16, dtype=jnp.float32,
@@ -364,34 +370,72 @@ def test_engine_hit_admission_not_slower_than_miss(conn):
     async def drive():
         seeds = [prompt(100 + i) for i in range(pairs)]
         for p in seeds:
-            await h.run_request(p)  # seed + warm the prefill's jit cache
-        await h.run_request(seeds[0])  # warm the hit path too (the install's programs)
+            await h.run_request(p)  # seed the store
         h.stats.clear()
+        rec.clear()
         for i, p in enumerate(seeds):
             await h.run_request(p)  # hit
             await h.run_request(prompt(200 + i))  # miss (cold prompt)
         return h.metrics()
 
-    m = asyncio.run(drive())
+    rec = tracing.configure(enabled=True, capacity=1 << 16, slow_op_us=0)
+    try:
+        m = asyncio.run(drive())
+        recorded = rec.snapshot()
+    finally:
+        tracing.configure(enabled=False)
     assert m["hit_rate"] > 0
-    # Judged pair by pair: hit i and miss i run back to back, so whatever
-    # else loads this host (the suite runs six workers wide) loads both,
-    # where median against median of six samples a side compared moments
-    # that were far apart. The median of the twelve ratios is the typical
-    # hit against its own neighbour: ~0.6 on a quiet host, and a
-    # regression that slows the typical hit (store I/O back under the
-    # gate, gate contention on most admissions) moves it, which a
-    # fastest-few statistic would not see.
-    hits = [s.prefix_ready_us for s in h.stats[0::2]]
-    misses = [s.prefix_ready_us for s in h.stats[1::2]]
-    assert all(s.loaded_blocks for s in h.stats[0::2])
-    assert not any(s.loaded_blocks for s in h.stats[1::2])
-    ratios = sorted(hit / miss for hit, miss in zip(hits, misses))
-    median = (ratios[pairs // 2 - 1] + ratios[pairs // 2]) / 2
-    assert median <= 1.0, (
-        f"the typical prefix hit is {median:.2f}x its neighbouring recompute: "
-        f"hits {[round(x) for x in hits]}us, misses {[round(x) for x in misses]}us"
-    )
+    hits, misses = h.stats[0::2], h.stats[1::2]
+    assert len(hits) == len(misses) == pairs
+    assert all(s.loaded_blocks == MAX_REQ_BLOCKS for s in hits)
+    assert not any(s.loaded_blocks for s in misses)
+
+    def trace_of(stats):
+        spans = [s for s in recorded if s["trace_id"] == stats.trace_id]
+        (root,) = [s for s in spans if s["name"] == "engine_request"]
+        return spans, root, dict(root["stages"])
+
+    for i, s in enumerate(hits):
+        spans, root, stamps = trace_of(s)
+        fetches = [x for x in spans if x["name"] == "fetch_layer"]
+        (wait,) = [
+            x for x in spans
+            if x["name"] == "gate_wait" and x["attrs"]["mode"] == "expedite"
+        ]
+        (inst,) = [x for x in spans if x["name"] == "install"]
+        assert sorted(x["attrs"]["layer"] for x in fetches) == list(range(big.n_layers))
+        # Every layer's read was submitted (`region_free`) before the request
+        # asked for the gate: the store's round trips ran beside the
+        # admission, and the pipeline was full (`primed`) by then.
+        begun = [dict(x["stages"])["region_free"] for x in fetches]
+        assert max(begun) <= stamps["primed"] <= wait["start_us"], (i, begun, wait)
+        # The exclusive hold runs from the gate's grant to the `install`
+        # stamp: no store read begins inside it, ...
+        hold = (wait["end_us"], stamps["install"])
+        assert not [t for t in begun if hold[0] <= t <= hold[1]], (i, begun, hold)
+        # ... it holds the install span and, of this request, nothing that is
+        # not the install's own child: no compute, no save, no fetch ...
+        assert hold[0] <= inst["start_us"] <= inst["end_us"] <= hold[1], (i, inst, hold)
+        inside = [
+            x for x in spans
+            if hold[0] <= x["start_us"] <= hold[1] and x is not inst and x is not root
+        ]
+        assert {x["name"] for x in inside} <= {"install_staged_wait", "install_upload"}, (
+            i, [x["name"] for x in inside],
+        )
+        assert all(x["parent_id"] == inst["span_id"] for x in inside)
+        # ... and `gate_hold_us`, stamped around it, covers the install (the
+        # span clock counts whole us).
+        assert inst["end_us"] - inst["start_us"] <= s.gate_hold_us + 2, (i, s, inst)
+        assert s.fetch_us > 0 and 0.0 < s.overlap_fraction <= 1.0
+        assert not [x for x in spans if x["name"] == "compute"]
+    for i, s in enumerate(misses):
+        spans, root, stamps = trace_of(s)
+        # A miss holds the gate for its compute alone: no store work under it.
+        assert s.gate_hold_us == 0.0 and s.fetch_us == 0.0, (i, s)
+        names = {x["name"] for x in spans}
+        assert "compute" in names and not names & {"install", "fetch_layer"}, (i, names)
+        assert "install" not in stamps
 
 
 def test_engine_overlap_metrics_are_non_degenerate(conn, params):

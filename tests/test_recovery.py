@@ -36,6 +36,7 @@ from infinistore_tpu import telemetry  # noqa: E402
 from infinistore_tpu.cluster import (  # noqa: E402
     CircuitBreaker,
     ClusterKVConnector,
+    rendezvous_ranked,
 )
 from infinistore_tpu.membership import DurableLog, MemberState, Membership  # noqa: E402
 from infinistore_tpu.tpu import PagedKVCacheSpec, gather_blocks  # noqa: E402
@@ -269,12 +270,24 @@ class _Pool:
         self.prompts = []
         self.src = np.array([3, 9], np.int32)
 
-    def seed_roots(self, n_roots, rng_seed=5):
+    def seed_roots(self, n_roots, rng_seed=5, due_to=None, n_due=0):
+        """Save ``n_roots`` prompts. With ``due_to``, a member id that is
+        about to join: exactly ``n_due`` of them are roots whose placement
+        will include it, whatever ports the servers drew (the ids, and with
+        them the rendezvous scores, are the ports')."""
         rng = np.random.default_rng(rng_seed)
-        self.prompts = [
-            rng.integers(0, 1000, size=2 * SPEC.block_tokens).tolist()
-            for _ in range(n_roots)
-        ]
+        ids = self.cluster.member_ids + [due_to]
+        quota = {True: n_due, False: n_roots - n_due}
+        self.prompts = []
+        while len(self.prompts) < n_roots:
+            p = rng.integers(0, 1000, size=2 * SPEC.block_tokens).tolist()
+            if due_to is not None:
+                ranked = rendezvous_ranked(ids, self.cluster._root_of(p))
+                due = len(ids) - 1 in ranked[: self.cluster.replicas]
+                if not quota[due]:
+                    continue
+                quota[due] -= 1
+            self.prompts.append(p)
         for i, p in enumerate(self.prompts):
             self.contents[i] = _mk_caches(i)
             asyncio.run(self.cluster.save(p, self.contents[i], self.src))
@@ -447,13 +460,15 @@ class TestJournalRecovery:
         migrated roots the worker wedges — the in-process analogue of the
         fleet client's kill -9 hook) and rebuild: the recovered cluster
         must flag the resume, kick the reconciler on construction, and
-        settle with zero debt — moving only the remainder."""
+        settle with zero debt — moving only the remainder. The joiner is
+        due 5 of the 10 roots by construction: a joiner due 2 or fewer
+        (one draw of ports in twenty) never reaches the crash point."""
         jp = str(tmp_path / "a.journal")
         pool = _Pool(3, journal_path=jp)
         extra_srv = extra_conn = None
         try:
-            pool.seed_roots(10)
             extra_srv = _start_server()
+            pool.seed_roots(10, due_to=f"127.0.0.1:{extra_srv.port}", n_due=5)
             pool.servers.append(extra_srv)
             extra_conn = _connect(extra_srv.port)
             pool.conns.append(extra_conn)
@@ -478,7 +493,7 @@ class TestJournalRecovery:
             cluster.add_member(
                 extra_conn, member_id=f"127.0.0.1:{extra_srv.port}"
             )
-            assert crashed.wait(timeout=20.0)
+            assert crashed.wait(timeout=60.0), state
             moved_before = cluster.resharder.progress()["reshard_moved_roots"]
             assert moved_before >= 2
             pool.rebuild(jp)  # the "restart": un-finalized journal replay
@@ -497,7 +512,7 @@ class TestJournalRecovery:
                     1 for r in pool.cluster._catalog.values()
                     if r.holders.get(joiner_id, 0) > 0
                 )
-            assert joiner_holds == 2 + resumed
+            assert joiner_holds == 2 + resumed == 5
             reads, misses, wrong = pool.sweep()
             assert (misses, wrong) == (0, 0)
         finally:

@@ -142,6 +142,19 @@ def test_manage_unknown_and_wrong_method(cli_server):
     assert e.value.code == 405
 
 
+def test_manage_serves_no_wave_route(cli_server):
+    """The store server serves no engine's state: ``GET /wave`` is a 404
+    like any unknown route, and no ``/metrics`` line names an engine's
+    waves."""
+    with pytest.raises(urllib.error.HTTPError) as e:
+        _manage(cli_server, "/wave")
+    assert e.value.code == 404
+    with urllib.request.urlopen(
+        f"http://127.0.0.1:{cli_server['manage_port']}/metrics", timeout=10
+    ) as resp:
+        assert "infinistore_engine_wave_" not in resp.read().decode()
+
+
 def test_benchmark_cli_rdma(cli_server):
     out = subprocess.run(
         [

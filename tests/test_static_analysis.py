@@ -1843,99 +1843,6 @@ class TestCountersDisagg:
 
 
 # ---------------------------------------------------------------------------
-# counters ITS-C010: skew-aware wave-policy vocabulary lockstep
-# ---------------------------------------------------------------------------
-
-C010_ENGINE = '''\
-class WaveCounters:
-    def __init__(self):
-        self._c = {"engine_wave_deferrals": 0, "engine_wave_policy_waves": 0}
-
-    def status(self):
-        c = self._c
-        return {**c, "engine_wave_aging_escapes": 1,
-                "engine_wave_defer_age_us_p99": 0.0}
-'''
-
-C010_MANAGE_OK = '''\
-def _engine_wave_prometheus_lines(ws):
-    return [
-        f"a {ws['engine_wave_deferrals']}",
-        f"b {ws['engine_wave_policy_waves']}",
-        f"c {ws['engine_wave_aging_escapes']}",
-        f"d {ws['engine_wave_defer_age_us_p99']}",
-    ]
-
-route = "/wave"   # served from _engine_wave_status()
-'''
-
-C010_DOCS = (
-    "| engine_wave_deferrals | engine_wave_policy_waves | "
-    "engine_wave_aging_escapes | engine_wave_defer_age_us_p99 |\n"
-)
-
-
-class TestCountersEngineWave:
-    def scan(self, tmp_path, manage_src=C010_MANAGE_OK,
-             engine_src=C010_ENGINE, docs=C010_DOCS):
-        ctx = make_tree(tmp_path, {
-            "manage.py": manage_src,
-            "engine.py": engine_src,
-            "docs/serving_load.md": docs,
-        })
-        return counters._scan_engine_wave(
-            ctx, "manage.py", engine_rel="engine.py",
-            docs_rel="docs/serving_load.md",
-        )
-
-    def test_complete_vocabulary_is_clean(self, tmp_path):
-        assert self.scan(tmp_path) == []
-
-    def test_unexported_status_key_fires(self, tmp_path):
-        manage = C010_MANAGE_OK.replace(
-            "        f\"c {ws['engine_wave_aging_escapes']}\",\n", "")
-        found = self.scan(tmp_path, manage_src=manage)
-        assert any(
-            f.rule == "ITS-C010"
-            and f.key.endswith(":engine_wave_aging_escapes")
-            for f in found
-        )
-
-    def test_unexported_init_ledger_key_fires(self, tmp_path):
-        # Keys living only in the __init__ counter dict are vocabulary too.
-        manage = C010_MANAGE_OK.replace(
-            "        f\"a {ws['engine_wave_deferrals']}\",\n", "")
-        found = self.scan(tmp_path, manage_src=manage)
-        assert any(f.key.endswith(":engine_wave_deferrals") for f in found)
-
-    def test_stale_exporter_key_fires(self, tmp_path):
-        manage = C010_MANAGE_OK.replace("engine_wave_policy_waves",
-                                        "engine_wave_gone_key")
-        keys = {f.key for f in self.scan(tmp_path, manage_src=manage)}
-        assert any(k.endswith("stale:engine_wave_gone_key") for k in keys)
-        assert any(k.endswith(":engine_wave_policy_waves") for k in keys)
-
-    def test_undocumented_wave_key_fires(self, tmp_path):
-        docs = C010_DOCS.replace("engine_wave_defer_age_us_p99", "")
-        found = self.scan(tmp_path, docs=docs)
-        assert any(
-            f.key.endswith("undocumented:engine_wave_defer_age_us_p99")
-            for f in found
-        )
-
-    def test_missing_wave_route_fires(self, tmp_path):
-        manage = C010_MANAGE_OK.replace('"/wave"', '"/nope"').replace(
-            "_engine_wave_status", "nothing")
-        found = self.scan(tmp_path, manage_src=manage)
-        assert any(f.key.endswith("wave-route") for f in found)
-
-    def test_real_wave_vocabulary_is_clean(self):
-        ctx = core.Context(str(REPO))
-        found = [f for f in counters.scan(ctx) if f.rule == "ITS-C010"]
-        assert found == []
-
-
-# ---------------------------------------------------------------------------
 # modelcheck (ITS-M*)
 # ---------------------------------------------------------------------------
 

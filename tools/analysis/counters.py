@@ -63,15 +63,6 @@ and cross-checks them:
   snapshot no longer emits; the manage plane must keep serving ``GET
   /disagg`` from the process disagg counters.
 
-- ITS-C010 skew-aware wave-policy vocabulary drift
-  (docs/serving_load.md): every ``engine_wave_*`` key of the
-  ``engine.WaveCounters`` ledger (``__init__`` literal + ``status``
-  snapshot) must be consumed by the /metrics wave exporter
-  (``server.py _engine_wave_prometheus_lines``) and enumerated in
-  docs/serving_load.md — and the exporter must not consume keys the
-  snapshot no longer emits; the manage plane must keep serving ``GET
-  /wave`` from the process wave counters.
-
 Dynamic per-op entries (``"ops": {"W": {...}}``) appear as ``ops.*`` on
 both sides.
 """
@@ -110,8 +101,6 @@ LEDGERS: List[Tuple[str, str]] = [
     ("infinistore_tpu/telemetry.py", "MetricsHistory.status"),
     ("infinistore_tpu/disagg.py", "DisaggCounters.__init__"),
     ("infinistore_tpu/disagg.py", "DisaggCounters.status"),
-    ("infinistore_tpu/engine.py", "WaveCounters.__init__"),
-    ("infinistore_tpu/engine.py", "WaveCounters.status"),
 ]
 
 # The elastic-membership status snapshot (ITS-C005): the dict-literal
@@ -169,15 +158,6 @@ DISAGG_REL = "infinistore_tpu/disagg.py"
 DISAGG_LEDGERS = ["DisaggCounters.__init__", "DisaggCounters.status"]
 DISAGG_EXPORT_FN = "_disagg_prometheus_lines"
 DISAGG_DOCS_REL = "docs/disaggregation.md"
-
-# The skew-aware wave-flush policy plane (ITS-C010, docs/serving_load.md):
-# the WaveCounters ledger's ``engine_wave_*`` keys must reach the /metrics
-# wave exporter both ways, be enumerated in the serving-load docs, and keep
-# the /wave manage route.
-ENGINE_WAVE_REL = "infinistore_tpu/engine.py"
-ENGINE_WAVE_LEDGERS = ["WaveCounters.__init__", "WaveCounters.status"]
-ENGINE_WAVE_EXPORT_FN = "_engine_wave_prometheus_lines"
-ENGINE_WAVE_DOCS_REL = "docs/serving_load.md"
 
 # Trace-surface exporters (docs/observability.md): the /trace payload
 # builder consumes the native ring's counters from the stats snapshot, and
@@ -497,7 +477,6 @@ def scan(
     findings += _scan_tiering(ctx, manage_rel)
     findings += _scan_profiling(ctx, manage_rel)
     findings += _scan_disagg(ctx, manage_rel)
-    findings += _scan_engine_wave(ctx, manage_rel)
     return findings
 
 
@@ -567,78 +546,6 @@ def _scan_disagg(
                     "disagg counters — the prefill->decode handoff surface "
                     "(docs/disaggregation.md)",
             key=f"ITS-C009:{manage_rel}:disagg-route",
-        ))
-    return findings
-
-
-def _scan_engine_wave(
-    ctx: Context,
-    manage_rel: str = MANAGE_REL,
-    engine_rel: str = ENGINE_WAVE_REL,
-    docs_rel: str = ENGINE_WAVE_DOCS_REL,
-) -> List[Finding]:
-    """ITS-C010: the skew-aware wave-policy vocabulary in lockstep —
-    ``engine_wave_*`` ledger keys vs the /metrics wave exporter (both
-    directions), the serving-load docs, and the /wave manage route
-    (docs/serving_load.md)."""
-    findings: List[Finding] = []
-    if not ctx.exists(engine_rel):
-        return findings
-    docs = ctx.read(docs_rel) if ctx.exists(docs_rel) else ""
-    doc_words = set(re.findall(r"[A-Za-z_][A-Za-z0-9_]*", docs))
-
-    ledger_key_set: Set[str] = set()
-    ledger_line = 1
-    for dotted in ENGINE_WAVE_LEDGERS:
-        keys, line = ledger_keys(ctx, engine_rel, dotted)
-        ledger_key_set |= {k.rsplit(".", 1)[-1] for k in keys}
-        ledger_line = line or ledger_line
-    ledger_key_set = {
-        k for k in ledger_key_set if k.startswith("engine_wave_")
-    }
-    consumed = {
-        k for k in metrics_consumed_keys(
-            ctx, manage_rel, fn_name=ENGINE_WAVE_EXPORT_FN
-        )
-        if k.startswith("engine_wave_")
-    }
-    for key in sorted(ledger_key_set - consumed):
-        findings.append(Finding(
-            rule="ITS-C010", file=manage_rel, line=1,
-            message=f"wave-policy counter key {key!r} is not exported by "
-                    f"the /metrics wave exporter ({ENGINE_WAVE_EXPORT_FN}) "
-                    "— a flush-policy counter dashboards cannot see is "
-                    "observability drift (docs/serving_load.md)",
-            key=f"ITS-C010:{manage_rel}:{key}",
-        ))
-    for key in sorted(consumed - ledger_key_set):
-        findings.append(Finding(
-            rule="ITS-C010", file=manage_rel, line=1,
-            message=f"/metrics wave exporter consumes key {key!r} which "
-                    "the WaveCounters snapshot no longer emits (KeyError "
-                    "at scrape time)",
-            key=f"ITS-C010:{manage_rel}:stale:{key}",
-        ))
-    for key in sorted(ledger_key_set):
-        if key not in doc_words:
-            findings.append(Finding(
-                rule="ITS-C010", file=engine_rel, line=ledger_line,
-                message=f"wave-policy counter key {key!r} is undocumented "
-                        f"in {docs_rel} — the wave counter vocabulary table "
-                        "must enumerate it",
-                key=f"ITS-C010:{engine_rel}:undocumented:{key}",
-            ))
-    manage_src = ctx.read(manage_rel)
-    if (
-        not re.search(r'[\'"]/wave[\'"]', manage_src)
-        or "_engine_wave_status" not in manage_src
-    ):
-        findings.append(Finding(
-            rule="ITS-C010", file=manage_rel, line=1,
-            message="manage plane must serve GET /wave from the process "
-                    "wave counters — the skew-aware flush-policy surface "
-                    "(docs/serving_load.md)",
-            key=f"ITS-C010:{manage_rel}:wave-route",
         ))
     return findings
 

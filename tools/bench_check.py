@@ -663,56 +663,6 @@ CHECKS = [
             "(both must be 0 on the clean legs)"
         ),
     ),
-    # Skew-aware wave flush under trace-driven serving load
-    # (docs/serving_load.md, ROADMAP-6). The ratio rides the weather rule
-    # over order-alternating cold-start convergence BLOCKS of the SAME
-    # skewed loadgen trace (jit cache cleared per block, scored at the
-    # MEDIAN post-cold per-replay p99 — min(median-of-ratios,
-    # ratio-of-sums) across block pairs): the prewarmed canonical
-    # bucket ladder must cut converged-floor FOREGROUND p99 TTFT
-    # (the blind flusher keeps minting fresh organic (B, T, P) buckets
-    # and re-pays XLA compiles every round), and the pad fraction — the
-    # bucket-economics figure the policy exists to move — must be
-    # strictly below the skew-blind run's.
-    Check(
-        "serving_ttft",
-        ["serving_p99_ttft_skew_ratio", "serving_wave_pad_fraction",
-         "serving_wave_pad_fraction_blind"],
-        lambda m: (
-            m["serving_p99_ttft_skew_ratio"] > 1.0
-            and m["serving_wave_pad_fraction"]
-            < m["serving_wave_pad_fraction_blind"]
-        ),
-        lambda m: (
-            f"skew-aware FOREGROUND p99 TTFT "
-            f"{m['serving_p99_ttft_skew_ratio']:.3f}x vs blind (must "
-            f"exceed 1.0) at pad fraction "
-            f"{m['serving_wave_pad_fraction']:.4f} vs blind "
-            f"{m['serving_wave_pad_fraction_blind']:.4f} (must be "
-            "strictly below)"
-        ),
-    ),
-    # The mechanism, not just the stopwatch: deferrals actually fired on
-    # the measured rounds, the starvation bound produced aging escapes
-    # under the outlier flood (deferral under permanent pressure never
-    # strands), and the oracle verifier found zero wrong bytes — the
-    # policy is scheduling-only by receipt, not by assertion.
-    Check(
-        "serving_mechanism",
-        ["serving_wave_deferrals", "serving_wave_aging_escapes",
-         "serving_wrong_bytes"],
-        lambda m: (
-            m["serving_wave_deferrals"] >= 1
-            and m["serving_wave_aging_escapes"] > 0
-            and m["serving_wrong_bytes"] == 0
-        ),
-        lambda m: (
-            f"{m['serving_wave_deferrals']:.0f} deferrals on measured "
-            f"rounds (>= 1), {m['serving_wave_aging_escapes']:.0f} aging "
-            f"escapes under the outlier flood (> 0), "
-            f"wrong_bytes={m['serving_wrong_bytes']:.0f} (must be 0)"
-        ),
-    ),
     Check(
         # Gate the bridge's OWN overhead, not asyncio's: the receipt measures
         # asyncio_efd_floor_us — a pure eventfd+add_reader wake with zero

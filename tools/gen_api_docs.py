@@ -39,8 +39,7 @@ SURFACE = [
     ("infinistore_tpu.connector", ["KVConnector", "token_chain_hashes"]),
     ("infinistore_tpu.engine", [
         "EngineKVAdapter", "ContinuousBatchingHarness", "BlockPool",
-        "WaveDecoder", "DeviceGate", "RequestStats", "WaveCounters",
-        "wave_counters", "reset_wave_counters",
+        "WaveDecoder", "DeviceGate", "RequestStats",
     ]),
     ("infinistore_tpu.cluster", [
         "ClusterKVConnector", "rendezvous_owner", "rendezvous_ranked",
