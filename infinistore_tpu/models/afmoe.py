@@ -76,6 +76,10 @@ _MOE_CHUNK_TOKENS = 8192
 # (rows x k slots at most) instead of sorting pairs into a grouped matmul.
 _MOE_WAVE_ROWS = 16
 _VMEM_LIMIT = 64 << 20
+# The wave kernel's tile along an expert's width, where the width is whole
+# tiles of it; else the width whole (768 = 6 x 128: one contiguous block an
+# expert and projection).
+_MOE_WAVE_F_TILE = 512
 
 
 @dataclass(frozen=True)
@@ -140,6 +144,9 @@ class AfmoeConfig:
 
     # What the wave step counts and returns with its logits (serving.py).
     step_counters = ("moe_pairs", "moe_distinct_experts")
+    # How ``route`` turns the router's logits into ids and combine weights: a
+    # property of the family, read off the configuration's class.
+    router = "sigmoid"
 
 
 def init_params(config: AfmoeConfig, key: jax.Array) -> Params:
@@ -248,15 +255,26 @@ def _swiglu(m, w_gate_up, w_down):
 # ---------------------------------------------------------------------------
 
 
-def route(m: jax.Array, router: jax.Array, bias: jax.Array, config: AfmoeConfig):
-    """m: [T, dim]. The ids the top-k chose ([T, k] int32, ranked by score
-    + selection bias) and their combine weights ([T, k] float32, from the
-    scores alone), over ALL experts, in float32."""
+def _router_logits(m: jax.Array, router: jax.Array) -> jax.Array:
+    return jnp.dot(
+        m.astype(jnp.float32), router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    )
+
+
+def route(m: jax.Array, router: jax.Array, bias: Optional[jax.Array], config):
+    """m: [T, dim]. The ids the top-k chose ([T, k] int32) and their combine
+    weights ([T, k] float32), over ALL experts, in float32. Which router is
+    the configuration's (``config.router``): ``"sigmoid"`` ranks by sigmoid
+    score + selection bias and weighs by the scores alone; ``"softmax_topk"``
+    takes the k largest LOGITS and a softmax over those k alone (no bias, no
+    scale)."""
+    if config.router == "softmax_topk":
+        with jax.named_scope("softmax_topk_router"):
+            top, ids = jax.lax.top_k(_router_logits(m, router), config.experts_per_token)
+            return ids.astype(jnp.int32), jax.nn.softmax(top, axis=-1)
     with jax.named_scope("afmoe_router"):
-        logits = jnp.dot(
-            m.astype(jnp.float32), router.astype(jnp.float32),
-            precision=jax.lax.Precision.HIGHEST,
-        )
+        logits = _router_logits(m, router)
         scores = jax.nn.sigmoid(logits)
         _, ids = jax.lax.top_k(scores + bias.astype(jnp.float32), config.experts_per_token)
         chosen = jnp.take_along_axis(scores, ids, axis=1)
@@ -291,6 +309,12 @@ def _moe_wave_kernel(ids_ref, n_ref, x_ref, c_ref, wg_ref, wu_ref, wd_ref, out_r
         out_ref[...] += dot(h.astype(x.dtype), wd_ref[...])
 
 
+def _wave_f_tile(f: int) -> int:
+    """The wave kernel's tile along an expert's width ``f``."""
+    tf = min(f, _MOE_WAVE_F_TILE)
+    return tf if f % tf == 0 else f
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _moe_wave_pallas(x, slots, n_slots, combine, w_gate, w_up, w_down, *, interpret):
     """x: [Tp, D]; slots: [S] int32 expert ids (held-local), the first
@@ -298,7 +322,7 @@ def _moe_wave_pallas(x, slots, n_slots, combine, w_gate, w_up, w_down, *, interp
     [E, D, F], [E, D, F], [E, F, D]. Returns [Tp, D] float32."""
     tp, d = x.shape
     f = w_gate.shape[2]
-    tf = min(f, 512)
+    tf = _wave_f_tile(f)
     return pl.pallas_call(
         _moe_wave_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -426,7 +450,7 @@ def expert_layer(w: Params, m: jax.Array, config: AfmoeConfig):
     different experts the rows chose, counted for few rows only, else 0)."""
     t = m.shape[0]
     first, _ = config.held
-    ids, weights = route(m, w["router"], w["router_bias"], config)
+    ids, weights = route(m, w["router"], w.get("router_bias"), config)
     if t <= _MOE_WAVE_ROWS:
         out, distinct = _moe_wave(m, ids, weights, w, config)
     else:

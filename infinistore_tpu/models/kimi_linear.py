@@ -208,6 +208,7 @@ class KimiLinearConfig:
     # the expert layer's two, and the rows whose state crossed into a new
     # block (engine metrics: ``state_carries``).
     step_counters = ("moe_pairs", "moe_distinct_experts", "state_carries")
+    router = "sigmoid"  # ``afmoe.route``'s kind
 
 
 def init_params(config: KimiLinearConfig, key: jax.Array) -> Params:

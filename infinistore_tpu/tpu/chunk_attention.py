@@ -52,6 +52,12 @@ _STEP_TOKENS = 128
 _TILE_ROWS = 128
 # Above the compiler's default scoped limit, far under a core's VMEM.
 _VMEM_LIMIT = 64 << 20
+# The longest page a grid step takes whole: a page of more tokens (2,048 where
+# the block is a state's snapshot interval and the K/V layer one in ten) is
+# walked in slices of this many, each a block of its own of the page's array,
+# so what is resident stays what a 1,024-token page asks (at 2,048 tokens x 8
+# KV heads a whole page a step wants 73 MiB of VMEM: PERF.md, PR 47).
+_PAGE_SLICE_TOKENS = 1024
 
 
 def _fold_pages(q, k, v, qpos, kpos0, m_scr, l_scr, acc_scr, g, masked,
@@ -184,9 +190,13 @@ def _chunk_prefix_attention_pallas(
 ):
     """q: [S_c, H, D]; block_table: [max_blocks]; start_pos: [] int32."""
     s, h, d = q.shape
-    _, bt, kvh, _ = k_cache.shape
+    _, page_tokens, kvh, _ = k_cache.shape
     groups = h // kvh
-    n = block_table.shape[0]
+    # ``bt``, ``n`` and ``pages`` count what a grid step's operand is: a page,
+    # or one of the ``per`` slices a long page is walked in.
+    per = page_tokens // _PAGE_SLICE_TOKENS if page_tokens % _PAGE_SLICE_TOKENS == 0 else 1
+    bt = page_tokens // per
+    n = block_table.shape[0] * per
     pages = max(1, min(n, _STEP_TOKENS // bt))
     steps = -(-n // pages)
     # Rows of one KV head together, (chunk row, group head) row-major; the
@@ -207,16 +217,20 @@ def _chunk_prefix_attention_pallas(
         steps = min(steps, (window + tile - 2) // (pages * bt) + 2)
 
     def page(j):
+        def block(tbl, at):
+            if per == 1:
+                return (tbl[at], 0, 0, 0)
+            return (tbl[at // per], at % per, 0, 0)
+
         def index(t, i, tbl, st):
             last = jnp.minimum((t + 1) * tile, s)  # rows up to the tile's end
             n_pages = jnp.minimum((st[0] + last + bt - 1) // bt, n)
             if window is None:
                 step = jnp.minimum(i, (n_pages - 1) // pages)
-                return (tbl[jnp.minimum(step * pages + j, n_pages - 1)], 0, 0, 0)
+                return block(tbl, jnp.minimum(step * pages + j, n_pages - 1))
             step0, page0 = _window_walk(st[0], t * tile, window, bt, pages)
             step = jnp.minimum(i + step0, (n_pages - 1) // pages)
-            at = jnp.clip(step * pages + j, page0, n_pages - 1)
-            return (tbl[at], 0, 0, 0)
+            return block(tbl, jnp.clip(step * pages + j, page0, n_pages - 1))
 
         return pl.BlockSpec((1, bt, kvh, d), index)
 

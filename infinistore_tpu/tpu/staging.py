@@ -87,10 +87,15 @@ class StagedTransfer:
         self._hosts: Optional[List[np.ndarray]] = None
 
     def wait(self) -> List[np.ndarray]:
-        """Block until device data is host-visible; returns zero-copy host
-        views (one np.ndarray per input array)."""
+        """Block until device data is host-visible; returns host arrays (one
+        np.ndarray per input array), each C-contiguous: whoever ships them
+        addresses block ``i`` at ``base + i * nbytes``. The view jax hands
+        back is that already, zero-copy, for every array but one the TPU
+        runtime returns in the device's own dimension order (an int32
+        ``[4, 100, 128]`` came back with strides ``(512, 2048, 4)``: PERF.md,
+        PR 47); such a one is copied once."""
         if self._hosts is None:
-            self._hosts = [np.asarray(arr) for arr in self._arrays]
+            self._hosts = [np.ascontiguousarray(np.asarray(arr)) for arr in self._arrays]
         return self._hosts
 
 
