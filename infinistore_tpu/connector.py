@@ -339,7 +339,8 @@ class KVConnector:
         # flight (with the gauge and the perf_counter mark that union is
         # kept by). Of the installs: bytes handed to the device and the
         # summed time of the executor calls that handed them (host time, not
-        # the DMA's end). Of the saves: bytes whose D2H the writer waited
+        # the DMA's end), those calls and the layers they carried (one call
+        # a run of staged layers). Of the saves: bytes whose D2H the writer waited
         # for, and those waits; put calls submitted and those of them
         # untagged (foreground); writes whose class was flipped to foreground
         # with layers still unsent; writes that STARTED foreground, and over
@@ -356,6 +357,7 @@ class KVConnector:
             "hit_read_bytes": 0, "hit_read_busy_us": 0.0,
             "hit_reads_in_flight": 0, "hit_read_busy_mark_s": 0.0,
             "install_upload_bytes": 0, "install_upload_us": 0.0,
+            "install_layers": 0, "install_dispatches": 0,
             "save_d2h_bytes": 0, "save_d2h_wait_us": 0.0,
             "save_puts": 0, "save_fg_puts": 0, "save_promotions": 0,
             "save_fg_writes": 0, "save_fg_rounds": 0,
@@ -784,11 +786,13 @@ class KVConnector:
 
     def _ensure_prefetch_pool(self) -> HostStagingPool:
         if self._prefetch_pool is None:
-            # ~4 full-depth pipelines (capped at 8 regions each, matching
-            # LayerwisePrefetch's default): enough for a concurrent
-            # admission wave; an over-wave falls back to the gated load.
-            regions = min(self.spec.num_layers, 8)
-            nbytes = 4 * regions * self.spec.region_nbytes(self.max_blocks)
+            # ~4 full-depth prefetches of the longest hit (a region a
+            # layer, each its own layer's size in whole slots, matching
+            # LayerwisePrefetch's default; of a K/V cache 4 x layers x
+            # region_nbytes): enough for a concurrent admission wave; an
+            # over-wave falls back to the gated load.
+            slot = self.spec.slot_nbytes
+            nbytes = 4 * slot * sum(self.spec.hit_slots(self.max_blocks, slot))
             self._prefetch_pool = HostStagingPool(
                 nbytes, self.spec.slot_nbytes, conn=self.conn
             )
@@ -1001,7 +1005,9 @@ class KVConnector:
         in which a hit's layer read was in flight; ``hit_reads_in_flight``
         and ``hit_read_busy_mark_s`` keep it), the store's delivered rate;
         ``install_upload_bytes`` over ``install_upload_us``, the host's rate
-        of handing a hit's bytes to the device; ``save_d2h_bytes`` over
+        of handing a hit's bytes to the device, in ``install_dispatches``
+        executor calls that carried ``install_layers`` layers (one call a
+        run of staged layers); ``save_d2h_bytes`` over
         ``save_d2h_wait_us``, what a save's D2H waits delivered;
         ``save_fg_puts`` of ``save_puts`` put calls went out untagged,
         ``save_promotions`` writes were promoted with layers still unsent,

@@ -75,8 +75,8 @@ def _plan_views(plan, buf, base: int):
 
 
 def _packs(plan) -> bool:
-    """Whether the layer's tensors ride ONE packed array (a K and a V: one
-    shape, one type, as many blocks each)."""
+    """Whether a save's gathers of the layer's tensors go down as ONE packed
+    array (a K and a V: one shape, one type, as many blocks each)."""
     return len({(t.block_shape, jax.numpy.dtype(t.dtype), m) for t, _, m, _ in plan}) == 1
 
 
@@ -84,31 +84,29 @@ def _plan_nbytes(plan) -> int:
     return sum(m * t.nbytes for t, _, m, _ in plan)
 
 
-def _upload_and_scatter(plan, buf, base: int, tensors, ids_dev, uploaded=None):
-    """The layer's staged blocks (``buf`` from byte ``base``) -> device,
-    scattered into ``tensors`` (donated) at the plan's blocks. A K/V layer
-    uploads as ONE packed array and splits on the device; tensors of unlike
-    shapes go up side by side. ``uploaded()`` is called once ``device_put``
-    has returned, before the scatters. Returns (what was uploaded, the updated
-    tensors)."""
-    if _packs(plan):
-        t, _, m, _ = plan[0]
-        host = (
-            buf[base : base + _plan_nbytes(plan)]
-            .view(np.dtype(jax.numpy.dtype(t.dtype)))
-            .reshape((len(plan) * m, *t.block_shape))
-        )
-        up = jax.device_put(host)
-        parts = [up[i * m : (i + 1) * m] for i in range(len(plan))] if len(plan) > 1 else [up]
-    else:
-        up = parts = jax.device_put(_plan_views(plan, buf, base))
+def _upload_and_scatter(plans, buf, bases, tensors, ids, uploaded=None):
+    """A run of layers' staged blocks (layer ``i`` of the run: ``plans[i]``,
+    in ``buf`` from byte ``bases[i]``) -> device in ONE ``jax.device_put``,
+    scattered into ``tensors[i]`` (donated) at the plan's blocks of ``ids``
+    (host int32). What goes up is every tensor's host view, unlike shapes and
+    types side by side, and behind them ``ids`` from each distinct first
+    block the run's tensors start at: nothing is packed and nothing is cut on
+    the device, where a slice costs a millisecond whatever its size (PERF.md,
+    PR 48). ``uploaded()`` is called once ``device_put`` has returned, before
+    the scatters. Returns (what was uploaded, the updated tensors a layer)."""
+    views = [v for plan, base in zip(plans, bases) for v in _plan_views(plan, buf, base)]
+    firsts = sorted({first for plan in plans for _, first, _, _ in plan})
+    up = jax.device_put(views + [ids[first:] for first in firsts])
     if uploaded is not None:
         uploaded()
-    out = tuple(
-        scatter_blocks(cache, ids_dev[first:] if first else ids_dev, part)
-        for cache, part, (_, first, _, _) in zip(tensors, parts, plan)
-    )
-    return up, out
+    parts, ids_from = iter(up), dict(zip(firsts, up[len(views) :]))
+    return up, [
+        tuple(
+            scatter_blocks(cache, ids_from[first], next(parts))
+            for cache, (_, first, _, _) in zip(layer_tensors, plan)
+        )
+        for plan, layer_tensors in zip(plans, tensors)
+    ]
 
 
 # What a write keeps in flight. BACKGROUND (nobody waits for it):
@@ -122,6 +120,12 @@ def _upload_and_scatter(plan, buf, base: int, tensors, ids_dev, uploaded=None):
 BG_PUT_GROUPS = 2
 BG_D2H_AHEAD = 4
 FG_WINDOW_BYTES = 32 << 20
+# What a speculative prefetch (BACKGROUND class, its request not admitted
+# yet) keeps on the wire: as many layers' reads as fit, never fewer than one.
+# A hit of light layers goes out whole, as it always did; of a heavy one
+# (Mistral's longest: about 32 MiB a layer, 16 layers) one layer at a time, so
+# that promote() finds the rest unsent and sends it foreground.
+SPECULATIVE_READ_BYTES = 32 << 20
 # A layer's D2H wait stands on the event loop up to this weight and moves to
 # an executor thread above it. An answer's or a question's layer (0.1-1 MiB)
 # lands in 0.2-0.4 ms, less than the thread hop costs (about 3 ms a hop on
@@ -199,8 +203,8 @@ class LayerwiseKVWriter:
     """Stream a request's KV blocks to the store, one layer at a time.
 
     Pipeline per layer: Pallas-gather blocks from the paged cache (device),
-    pack K and V into one array, start ONE async D2H (the reader likewise
-    uploads one packed span per layer), and ship previous layers' host
+    pack K and V into one array, start ONE async D2H (the readers upload a
+    layer's tensors as host views, unpacked: `_upload_and_scatter`), and ship previous layers' host
     buffers on the network concurrently — a window of layer-groups of puts
     in flight (below). Puts go straight from jax's D2H buffer (registered for
     the op's lifetime), so the only host copy is the one into the server's
@@ -490,7 +494,7 @@ class LayerwiseKVReader:
             return list(caches)
         if n > self.regions.max_blocks:
             raise ValueError(f"{n} blocks > reader capacity {self.regions.max_blocks}")
-        ids_dev = jax.numpy.asarray(block_ids, dtype=jax.numpy.int32)
+        ids = np.asarray(block_ids, np.int32)
         pool = self.regions.pool
 
         # What a hit fetches of each layer's tensors: a sliding layer's last
@@ -499,9 +503,8 @@ class LayerwiseKVReader:
         plans = [_layer_plan(self.spec, layer, n, hit=True) for layer in range(num_layers)]
 
         def fetch(layer: int):
-            # The layer's tensors packed into one contiguous region span (K
-            # blocks then V blocks), so a K/V layer later uploads as a single
-            # device transfer; one store read a value size.
+            # The layer's tensors one after the other in one region span (K
+            # blocks then V blocks); one store read a value size.
             base = self.regions.base_offset(layer % self.regions.count)
             pri_kw = wire.qos_kwargs(self.conn, priority)
             return asyncio.gather(*(
@@ -511,7 +514,7 @@ class LayerwiseKVReader:
 
         # Pipeline: with R regions, keep W = R-2 network fetches in flight
         # ahead of device consumption. A region is reused only once its
-        # previous occupant's UPLOAD (the single K+V device_put) has landed —
+        # previous occupant's UPLOAD (the layer's one device_put) has landed —
         # never its scatters, which queue on the device and must not gate the
         # host loop. The barrier targets a transfer dispatched W layers ago,
         # so several H2D uploads stay in flight instead of serializing.
@@ -540,10 +543,10 @@ class LayerwiseKVReader:
                 start(f)
             for layer in range(num_layers):
                 await fetches.pop(layer)
-                # ONE H2D per K/V layer (K and V ride together); split on device.
-                uploads[layer], out[layer] = _upload_and_scatter(
-                    plans[layer], pool.buf, self.regions.base_offset(layer % R),
-                    out[layer], ids_dev,
+                # ONE device_put a layer: its tensors' host views side by side.
+                uploads[layer], (out[layer],) = _upload_and_scatter(
+                    [plans[layer]], pool.buf, [self.regions.base_offset(layer % R)],
+                    [out[layer]], ids,
                 )
                 if on_layer is not None:
                     on_layer(layer, out[layer])
@@ -602,13 +605,21 @@ class LayerwisePrefetch:
     admission, before the engine has even allocated device blocks — the
     block table is only needed at :meth:`install`.
 
-    Layout: ``regions`` staging regions, each one contiguous packed
-    [K blocks | V blocks] span, reserved from the pool as ONE lease.
-    Layer L fetches into region ``L % regions``; when ``regions <
-    num_layers`` the pipeline wraps and a region is refilled only after
-    :meth:`install` consumed its occupant (double buffering). Completion
-    per layer feeds install's per-layer loop, so install can stream layer
-    L to the device while layer L+1 is still on the network.
+    Layout: ``regions`` staging regions, each one contiguous span of a
+    layer's tensors one after the other ([K blocks | V blocks]), reserved
+    from the pool as ONE lease. A region a layer by default, each as large
+    as its own layer's hit: every layer's read starts at construction and
+    none waits for the install, so the whole hit is fetched before the gate
+    and :meth:`install` hands every staged layer to the device in one
+    executor call. (A speculative prefetch, BACKGROUND class, keeps
+    ``SPECULATIVE_READ_BYTES`` of reads in flight, of a heavy hit one
+    layer's, until :meth:`promote`, which sends the rest foreground.) Only
+    where the pool cannot hold that (or ``regions=``
+    asks for fewer) does the pipeline wrap: regions of the heaviest layer's
+    size, layer L fetches into region ``L % regions`` and a region is
+    refilled only after :meth:`install` consumed its occupant (double
+    buffering); install then goes run by run, each run the layers that sit
+    staged when it looks, and waits under the gate for the rest.
 
     Cancellation (:meth:`discard`) is safe at ANY point before install:
     in-flight store reads are drained (they write into leased memory),
@@ -664,7 +675,9 @@ class LayerwisePrefetch:
         would have been to ``hit_values_whole_prefix`` and its bytes to
         ``hit_read_bytes``; ``hit_read_busy_us`` is the time in which at
         least one layer read was in flight (``_busy_step``), and an
-        install adds ``install_upload_bytes`` / ``install_upload_us``.
+        install adds ``install_upload_bytes`` / ``install_upload_us`` and,
+        a run of staged layers, their count to ``install_layers`` and one
+        to ``install_dispatches``.
         Raises :class:`~..tpu.staging.StagingPoolExhausted` when the pool
         cannot hold even a double-buffered pipeline."""
         self.conn = conn
@@ -690,6 +703,12 @@ class LayerwisePrefetch:
         self._pri_cell = (
             priority_cell if priority_cell is not None else {"value": priority}
         )
+        # Set once the reads go out foreground (from the start, or by
+        # promote()): until then the prefetch is speculative and keeps
+        # SPECULATIVE_READ_BYTES of layer reads in flight (`_fetch_layer`), so
+        # that what its request's admission finds unsent is most of a heavy
+        # hit, not none of it.
+        self._admitted: Optional[asyncio.Future] = None  # no reads, no hold
         self.blocks_fetched = 0  # K+V blocks landed in staging
         self.blocks_installed = 0  # K+V blocks scattered to the device
         self.retry_missing_s = retry_missing_s
@@ -711,25 +730,41 @@ class LayerwisePrefetch:
             self._drained.set()
             self.fetch_finished_s = self.fetch_started_s
             return
-        # Region stride in whole pool slots (a region is one contiguous
-        # [K | V] span of 2*n_blocks KV blocks, or the heaviest layer's hit).
-        self._region_bytes = spec.region_nbytes(n_blocks)
-        slots_per_region = -(-self._region_bytes // pool.block_size)
-        self._region_stride = slots_per_region * pool.block_size
-        want = min(num_layers, 8) if regions is None else regions
+        # Regions in whole pool slots. A region a layer is as large as ITS
+        # layer's hit (a state-only layer's few MiB beside a K/V layer's
+        # hundreds: the host pays for what the hit weighs, not for layers x
+        # the heaviest); fewer regions wrap, so each must hold any layer:
+        # the heaviest's hit (a K/V cache: 2*n_blocks blocks, either way).
+        layer_slots = spec.hit_slots(n_blocks, pool.block_size)[:num_layers]
+        slots_per_region = max(layer_slots)
+        want = num_layers if regions is None else regions
         want = max(2, min(want, num_layers)) if num_layers > 1 else 1
         # Degrade to a shallower pipeline before giving up: fewer regions
-        # only means more install/fetch handoffs, not less data.
+        # only means more install/fetch handoffs, not less data. (Of unlike
+        # layers the first wrap, N-1 regions of the heaviest's size, is as a
+        # rule LARGER than a region a layer was: those attempts fail at once
+        # and the loop settles where r x the heaviest fits, often at 2.)
         lease = None
         for r in range(want, (1 if num_layers == 1 else 2) - 1, -1):
             try:
-                lease = pool.reserve(r * slots_per_region)
+                lease = pool.reserve(
+                    sum(layer_slots) if r == num_layers else r * slots_per_region
+                )
                 self.regions = r
                 break
             except Exception:
                 if r <= (1 if num_layers == 1 else 2):
                     raise
         self._lease = lease
+        if self.regions == num_layers:
+            firsts = [sum(layer_slots[:layer]) for layer in range(num_layers)]
+        else:
+            firsts = [(layer % self.regions) * slots_per_region for layer in range(num_layers)]
+        # Per layer, the byte offset of its region in the lease.
+        self._region_at = [first * pool.block_size for first in firsts]
+        self._speculative_ahead = max(
+            1, SPECULATIVE_READ_BYTES // max(_plan_nbytes(plan) for plan in self._plans)
+        )
         pri_cell = self._pri_cell  # closure reads the LIVE class (promote())
         self._submit = submit or (
             lambda blocks, nbytes: conn.read_cache_async(
@@ -738,6 +773,9 @@ class LayerwisePrefetch:
             )
         )
         loop = asyncio.get_running_loop()
+        self._admitted = loop.create_future()
+        if self._pri_cell["value"] == wire.PRIORITY_FOREGROUND:
+            self._admitted.set_result(None)
         self._staged = [loop.create_future() for _ in range(num_layers)]
         for fut in self._staged:
             # Defensively retrieve exceptions: a prefetch discarded before
@@ -760,7 +798,7 @@ class LayerwisePrefetch:
     # -- fetch phase (gate-free) --------------------------------------------
 
     def _region_offset(self, layer: int) -> int:
-        return self._lease.offset + (layer % self.regions) * self._region_stride
+        return self._lease.offset + self._region_at[layer]
 
     async def _fetch_layer(self, layer: int):
         plan = self._plans[layer]
@@ -790,6 +828,19 @@ class LayerwisePrefetch:
                 await self._consumed[layer - self.regions].wait()
             if span is not None:
                 span.stage("region_free")
+            ahead = self._speculative_ahead
+            if layer >= ahead and not self._admitted.done():
+                # Speculative (BACKGROUND class: the request is not admitted
+                # yet): this layer's read goes out when the layer `ahead`
+                # before it has landed, or at promote(). A read already at
+                # the server keeps its class, and with a region a layer every
+                # read would be there: a heavy hit whole at the aged
+                # background trickle, with nothing left for promote() to send
+                # foreground.
+                await asyncio.wait(
+                    [self._staged[layer - ahead], self._admitted],
+                    return_when=asyncio.FIRST_COMPLETED,
+                )
             if self._cancelled:
                 if span is not None:
                     span.finish(status="cancelled")
@@ -923,8 +974,13 @@ class LayerwisePrefetch:
         layer fetches are decode-blocking and must not drain at the aged
         background trickle. Submissions already in flight finish at their
         original class (bounded by the aging escapes); later ones go out
-        untagged. No-op on an already-foreground prefetch. Idempotent."""
+        untagged: of a heavy hit every layer but the one in flight, since a
+        speculative prefetch holds back what ``SPECULATIVE_READ_BYTES`` does
+        not cover. No-op on an
+        already-foreground prefetch. Idempotent."""
         self._pri_cell["value"] = wire.PRIORITY_FOREGROUND
+        if self._admitted is not None and not self._admitted.done():
+            self._admitted.set_result(None)
 
     async def primed(self) -> None:
         """Wait (gate-free) until the fetch pipeline is full: every staging
@@ -936,8 +992,11 @@ class LayerwisePrefetch:
         with proper miss/partial semantics from :meth:`install`."""
         if self.n_blocks == 0:
             return
-        idx = min(self.num_layers, self.regions) - 1
-        await asyncio.wait([self._staged[idx]])
+        # Every one of them, not the last alone: reads need not land in layer
+        # order (a speculative prefetch's one background read lands behind
+        # the foreground rest), and a layer still on the wire at the install
+        # is waited for under the gate.
+        await asyncio.wait(self._staged[: min(self.num_layers, self.regions)])
 
     async def discard(self) -> None:
         """Cancel the prefetch and return every staging slot to the pool.
@@ -995,10 +1054,14 @@ class LayerwisePrefetch:
         caches and 0 loaded; ``on_layer`` fires per layer in order).
 
         This is the only phase that needs the engine's exclusive cache
-        gate; per-layer host bytes usually sit staged already, so the hold
-        is device-transfer time, not store time. When every layer is
-        staged in back-to-back regions the whole prefix rides ONE device
-        upload."""
+        gate; the host bytes usually sit staged already, so the hold is
+        device-transfer time, not store time. The device is reached once a
+        RUN of staged layers, not once a layer: at layer L every layer from
+        L on that has landed and is healthy goes up in ONE executor call (one
+        ``jax.device_put`` of the run's host views, then its scatters), so a
+        fully staged hit is one thread hop whatever its layer count or cache
+        kind, and a layer still on the network is waited for and starts the
+        next run."""
         if self._discarded:
             raise PrefetchDiscarded("install() after discard()")
         out = list(caches)
@@ -1015,85 +1078,33 @@ class LayerwisePrefetch:
                 f"cache list has {len(caches)} layers, prefetch fetched "
                 f"{self.num_layers}"
             )
-        ids_dev = jax.numpy.asarray(np.asarray(block_ids), jax.numpy.int32)
+        ids = np.asarray(block_ids, np.int32)
         loop = asyncio.get_running_loop()
         # Children of the caller's span (the engine's `install`, whose
-        # duration is the exclusive gate's hold): `install_upload`, just
-        # before `run_in_executor` to its return (`started`: the executor
-        # thread entered, `h2d`: `device_put` returned; the thread does not
-        # inherit the span, so it is handed in, and the `its.install`
-        # device call is on it), and `install_staged_wait` for each layer
-        # that had NOT landed when the install reached it: the exclusive
-        # gate waiting for the network.
+        # duration is the exclusive gate's hold): one `install_upload` a run,
+        # just before `run_in_executor` to its return (`started`: the
+        # executor thread entered, `h2d`: `device_put` returned; the thread
+        # does not inherit the span, so it is handed in, and the
+        # `its.install` device call is on it), and `install_staged_wait` for
+        # each layer that had NOT landed when the install reached it: the
+        # exclusive gate waiting for the network.
         counters = self._counters
-        fused = (
-            self.spec.uniform  # a K and a V of one shape a layer
-            and self.regions >= self.num_layers
-            # a sliding layer's region is part full
-            and not any(first for plan in self._plans for _, first, _, _ in plan)
-            and self._region_stride == self._region_bytes
-            and all(f.done() and not f.cancelled() and f.exception() is None
-                    for f in self._staged)
-        )
-        if fused:
-            # Back-to-back regions, fully staged: one packed
-            # [L x (K | V)] span -> ONE H2D transfer for the whole prefix.
-            # The device work runs in an executor so the EVENT LOOP — and
-            # every other request's in-flight fetch completion — never
-            # stalls behind it; the caller's gate still serializes the
-            # cache mutation across the await.
-            span = self.pool.buf[
-                self._lease.offset : self._lease.offset
-                + self.num_layers * self._region_bytes
-            ]
-            host_all = span.view(np.dtype(jax.numpy.dtype(self.spec.dtype))).reshape(
-                (self.num_layers * 2 * n, *self.spec.block_shape)
-            )
 
-            def dev_all(caches_in, uspan):
-                t_up = time.perf_counter()
-                if uspan is not None:
-                    uspan.stage("started")
-                with tracing.device_call("its.install", uspan):
-                    kv_all = jax.device_put(host_all)
-                    if uspan is not None:
-                        uspan.stage("h2d")
-                    scattered = []
-                    for layer in range(self.num_layers):
-                        base = layer * 2 * n
-                        k_cache, v_cache = caches_in[layer]
-                        scattered.append((
-                            scatter_blocks(
-                                k_cache, ids_dev, kv_all[base : base + n]
-                            ),
-                            scatter_blocks(
-                                v_cache, ids_dev, kv_all[base + n : base + 2 * n]
-                            ),
-                        ))
-                return kv_all, scattered, (time.perf_counter() - t_up) * 1e6
-
-            with tracing.trace_op("install_upload") as uspan:
-                if uspan is not None:
-                    uspan.annotate(
-                        layers=self.num_layers, bytes=host_all.nbytes, fused=True
-                    )
-                kv_all, scattered, upload_us = await loop.run_in_executor(
-                    None, dev_all, list(out), uspan
+        def dev_run(first, tensors, uspan):
+            t_up = time.perf_counter()
+            if uspan is not None:
+                uspan.stage("started")
+            layers = range(first, first + len(tensors))
+            with tracing.device_call("its.install", uspan):
+                uploads, scattered = _upload_and_scatter(
+                    self._plans[first : layers.stop], self.pool.buf,
+                    [self._region_offset(layer) for layer in layers], tensors, ids,
+                    uploaded=None if uspan is None else lambda: uspan.stage("h2d"),
                 )
-            if counters is not None:
-                counters["install_upload_bytes"] += host_all.nbytes
-                counters["install_upload_us"] += upload_us
-            for layer in range(self.num_layers):
-                out[layer] = scattered[layer]
-                self._installing.add(layer)
-                self.blocks_installed += 2 * n
-                if on_layer is not None:
-                    on_layer(layer, out[layer])
-            self._release_region_async(
-                list(range(self.num_layers)), kv_all, list(out), loop
-            )
-            return out, n
-        for layer in range(self.num_layers):
+            return uploads, scattered, (time.perf_counter() - t_up) * 1e6
+
+        layer = 0
+        while layer < self.num_layers:
             fut = self._staged[layer]
             try:
                 if fut.done():
@@ -1137,39 +1148,37 @@ class LayerwisePrefetch:
                 # lease went back to the pool (another prefetch may own the
                 # slots now) — treat as the miss it semantically is.
                 return out, 0
-            off = self._region_offset(layer)
-            plan = self._plans[layer]
-            nbytes = _plan_nbytes(plan)
-
-            def dev_one(tensors, uspan, plan=plan, off=off):
-                t_up = time.perf_counter()
-                if uspan is not None:
-                    uspan.stage("started")
-                with tracing.device_call("its.install", uspan):
-                    kv_dev, tensors = _upload_and_scatter(
-                        plan, self.pool.buf, off, tensors, ids_dev,
-                        uploaded=None if uspan is None else lambda: uspan.stage("h2d"),
-                    )
-                return kv_dev, tensors, (time.perf_counter() - t_up) * 1e6
-
-            # Off-loop for the same reason as the fused path: upload +
-            # scatter must not freeze other requests' fetch completions.
+            # The run: this layer and those behind it that sit staged now. A
+            # layer that shares a region with one of them cannot be among
+            # them: its read starts only once that one is consumed.
+            end = layer + 1
+            while end < self.num_layers and self.layer_ready(end):
+                end += 1
+            nbytes = sum(_plan_nbytes(plan) for plan in self._plans[layer:end])
+            # Off-loop: upload + scatter must not freeze other requests'
+            # fetch completions; the caller's gate still serializes the
+            # cache mutation across the await.
             with tracing.trace_op("install_upload") as uspan:
                 if uspan is not None:
-                    uspan.annotate(
-                        layer=layer, bytes=nbytes, fused=False, kind=plan[0][0].kind
-                    )
-                kv_dev, out[layer], upload_us = await loop.run_in_executor(
-                    None, dev_one, out[layer], uspan
+                    uspan.annotate(layer=layer, layers=end - layer, bytes=nbytes)
+                uploads, scattered, upload_us = await loop.run_in_executor(
+                    None, dev_run, layer, out[layer:end], uspan
                 )
             if counters is not None:
                 counters["install_upload_bytes"] += nbytes
                 counters["install_upload_us"] += upload_us
-            self._installing.add(layer)
-            self.blocks_installed += sum(m for _, _, m, _ in plan)
-            if on_layer is not None:
-                on_layer(layer, out[layer])
-            self._release_region_async([layer], kv_dev, out[layer], loop)
+                counters["install_layers"] += end - layer
+                counters["install_dispatches"] += 1
+            out[layer:end] = scattered
+            for done in range(layer, end):
+                self._installing.add(done)
+                self.blocks_installed += sum(m for _, _, m, _ in self._plans[done])
+                if on_layer is not None:
+                    on_layer(done, out[done])
+            self._release_region_async(
+                list(range(layer, end)), uploads, scattered, loop
+            )
+            layer = end
         return out, n
 
     # -- per-layer handles (watermark-gated decode admission) ----------------
@@ -1243,16 +1252,16 @@ class LayerwisePrefetch:
             return out, False
         if self._lease is None or self._lease._released:
             return out, False
-        ids_dev = jax.numpy.asarray(np.asarray(block_ids), jax.numpy.int32)
         loop = asyncio.get_running_loop()
         plan = self._plans[layer]
 
         def dev_one(tensors):
             return _upload_and_scatter(
-                plan, self.pool.buf, self._region_offset(layer), tensors, ids_dev
+                [plan], self.pool.buf, [self._region_offset(layer)], [tensors],
+                np.asarray(block_ids, np.int32),
             )
 
-        kv_dev, out[layer] = await loop.run_in_executor(
+        kv_dev, (out[layer],) = await loop.run_in_executor(
             None, dev_one, out[layer]
         )
         self._installing.add(layer)

@@ -198,6 +198,13 @@ class PagedKVCacheSpec:
             return 2 * n_blocks * self.block_nbytes
         return max(self.hit_nbytes(l, n_blocks) for l in range(self.num_layers))
 
+    def hit_slots(self, n_blocks: int, slot_nbytes: int) -> List[int]:
+        """Per layer, the whole staging slots of ``slot_nbytes`` that the
+        layer's hit of ``n_blocks`` takes (a prefetch's region a layer)."""
+        return [
+            -(-self.hit_nbytes(l, n_blocks) // slot_nbytes) for l in range(self.num_layers)
+        ]
+
     @property
     def slot_nbytes(self) -> int:
         """The staging pools' slot: a K block, or the cache's lightest value."""

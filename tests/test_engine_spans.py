@@ -190,7 +190,7 @@ def test_tracing_off_records_nothing_and_still_stamps_emits(conn, params, monkey
     # ttft_us precedes the first read-back; token_emit_s[0] follows it.
     assert miss.ttft_us > 0 and hit.ttft_us > 0
     # The hop's counters need no recorder: the miss saved, the hit read and
-    # installed, and all six moved.
+    # installed, and all eight moved.
     moved = h.adapter.connector.get_stats()
     assert all(moved[key] > 0 for key in HOP_COUNTERS), moved
     assert moved["hit_read_bytes"] == moved["install_upload_bytes"] == _hit_bytes(hit)
@@ -209,7 +209,7 @@ def test_tracing_off_records_nothing_and_still_stamps_emits(conn, params, monkey
 # The connector's ledger of the hop (docs/observability.md), always on.
 HOP_COUNTERS = (
     "hit_read_bytes", "hit_read_busy_us", "install_upload_bytes", "install_upload_us",
-    "save_d2h_bytes", "save_d2h_wait_us",
+    "install_layers", "install_dispatches", "save_d2h_bytes", "save_d2h_wait_us",
 )
 
 
@@ -312,16 +312,18 @@ def test_hit_over_two_regions_is_cut_where_it_waits(conn, params3, traced):
     assert not {"submit", "coalesce", "completion_ring"} & {n for n, _ in root["stages"]}
     assert "op" not in root["attrs"]
 
-    # The install: an upload a layer, and the gate waited for the network
-    # exactly where a layer had not landed (layer 2, behind layer 0's region).
+    # The install: an upload a RUN of staged layers (layers 0 and 1, each in
+    # a region of its own, go up in one executor call), and the gate waited
+    # for the network exactly where a layer had not landed (layer 2, behind
+    # layer 0's region: the run the wrap forces).
     (inst,) = [s for s in spans if s["name"] == "install"]
     uploads = sorted(
         (s for s in spans if s["name"] == "install_upload"), key=lambda s: s["attrs"]["layer"]
     )
-    assert [u["attrs"]["layer"] for u in uploads] == [0, 1, 2]
+    assert [(u["attrs"]["layer"], u["attrs"]["layers"]) for u in uploads] == [(0, 2), (2, 1)]
     for u in uploads:
-        assert u["parent_id"] == inst["span_id"] and not u["attrs"]["fused"]
-        assert u["attrs"]["bytes"] == 6 * value
+        assert u["parent_id"] == inst["span_id"]
+        assert u["attrs"]["bytes"] == u["attrs"]["layers"] * 6 * value
         assert u["start_us"] <= _stamp(u, "started") <= _stamp(u, "h2d") <= u["end_us"]
         ((name, t0, t1),) = u["attrs"]["device_calls"]
         assert name == "its.install" and _stamp(u, "started") <= t0 <= t1 <= u["end_us"]
@@ -332,29 +334,39 @@ def test_hit_over_two_regions_is_cut_where_it_waits(conn, params3, traced):
     (wait,) = [s for s in spans if s["name"] == "install_staged_wait"]
     assert wait["parent_id"] == inst["span_id"] and wait["attrs"] == {"layer": 2}
     assert wait["duration_us"] >= READ_DELAY_S * 1e6 * 0.25
-    assert uploads[1]["end_us"] <= wait["start_us"] <= wait["end_us"] <= uploads[2]["start_us"]
+    assert uploads[0]["end_us"] <= wait["start_us"] <= wait["end_us"] <= uploads[1]["start_us"]
     assert _stamp(root, "primed") >= _stamp(layers[1], "landed")
 
     # The counters, beside the spans: what was read is what the hit fetched,
-    # and the reads' union is inside the wall time.
+    # the reads' union is inside the wall time, and the dispatches and their
+    # layers are the spans'.
     c = h.adapter.connector.hit_counters
     assert c["hit_read_bytes"] == _hit_bytes(hit) == 18 * value
     assert 2 * READ_DELAY_S * 1e6 * 0.9 <= c["hit_read_busy_us"] <= wall_us
     assert c["install_upload_bytes"] == 18 * value and c["hit_reads_in_flight"] == 0
     assert 0 < c["install_upload_us"] <= sum(u["duration_us"] for u in uploads) + 1000
+    assert (c["install_layers"], c["install_dispatches"]) == (3, 2)
 
 
 def test_no_staged_wait_where_every_layer_had_landed(conn, params3, traced):
-    h = _harness3(conn, params3, f"hop-fused-{conn.shm_active}")  # a region a layer
+    h = _harness3(conn, params3, f"hop-one-{conn.shm_active}")  # a region a layer
     _, hit = _miss_then_hit(h, _prompt(12))
     spans, root = _trace(traced, hit)
-    assert len([s for s in spans if s["name"] == "fetch_layer"]) == 3
+    fetches = sorted(
+        (s for s in spans if s["name"] == "fetch_layer"), key=lambda s: s["attrs"]["layer"]
+    )
+    assert [s["attrs"]["region"] for s in fetches] == [0, 1, 2]
     assert not [s for s in spans if s["name"] == "install_staged_wait"]
     (inst,) = [s for s in spans if s["name"] == "install"]
-    (upload,) = [s for s in spans if s["name"] == "install_upload"]  # the fused path: one
+    # Every layer staged before the gate (`primed` waits for the last): ONE
+    # run, one executor call, one span.
+    assert _stamp(root, "primed") >= _stamp(fetches[-1], "landed")
+    (upload,) = [s for s in spans if s["name"] == "install_upload"]
     assert upload["parent_id"] == inst["span_id"]
-    assert upload["attrs"]["fused"] and upload["attrs"]["layers"] == 3
-    assert upload["attrs"]["bytes"] == h.adapter.connector.hit_counters["install_upload_bytes"]
+    assert (upload["attrs"]["layer"], upload["attrs"]["layers"]) == (0, 3)
+    c = h.adapter.connector.hit_counters
+    assert upload["attrs"]["bytes"] == c["install_upload_bytes"]
+    assert (c["install_layers"], c["install_dispatches"]) == (3, 1)
     assert [n for n, _ in upload["stages"]] == ["started", "h2d"]
     assert [c[0] for c in upload["attrs"]["device_calls"]] == ["its.install"]
 

@@ -550,9 +550,15 @@ def test_values_of_the_published_sizes_pass_staging_upload_and_d2h(conn):
         assert await kvc.save(tokens, filled, np.array([1, 3], np.int32)) == 2 * 5
         assert kvc.lookup(tokens) == 2
         prefetch = await kvc.start_fetch_async(tokens)
+        # A region a layer, each as large as ITS layer's hit in whole slots of
+        # 50 KiB (328 for the K/V layer's 16 MiB, 84 for the state layer's
+        # 4,196 KiB), not two of the heaviest; the arena holds four such hits.
+        assert prefetch.regions == 2 and prefetch._lease.num_slots == 328 + 84
+        assert kvc._prefetch_pool.num_slots == 4 * (328 + 84)
         await prefetch.primed()
         out, loaded = await prefetch.install(spec.make_caches(), np.array([0, 2], np.int32))
         assert loaded == 2 and prefetch.blocks_fetched == 2 + 2 + 3
+        assert kvc.hit_counters["install_layers"] == 2
         again, n = await kvc.load(tokens, spec.make_caches(), np.array([2, 0], np.int32))
         assert n == 2
         return out, again

@@ -323,8 +323,9 @@ def test_a_hit_fetches_n_latents_and_one_state_a_layer_and_every_block_saves_all
 
 def test_values_of_the_published_sizes_pass_staging_upload_and_d2h(conn):
     """A state of 2,048 KiB in float32, a latent block of 1,152 KiB and a
-    tail of 72 KiB, saved and read back byte for byte through both reads:
-    the prefetch's install and the one-phase load."""
+    tail of 72 KiB, saved and read back byte for byte through all three
+    reads: the prefetch's install, its layer-by-layer ``install_layer`` and
+    the one-phase load (one upload helper: host views, host-cut ids)."""
     layers = [
         (CacheTensor("state", (32, 128, 128), jnp.float32, 1, "state"),
          CacheTensor("tail", (288, 128), jnp.bfloat16, 1, "state")),
@@ -353,10 +354,14 @@ def test_values_of_the_published_sizes_pass_staging_upload_and_d2h(conn):
         assert loaded == 2 and prefetch.blocks_fetched == 2 + 2  # two latents, one state, one tail
         again, n = await kvc.load(tokens, spec.make_caches(), np.array([2, 0], np.int32))
         assert n == 2
-        return out, again
+        layered, by_layer = await kvc.start_fetch_async(tokens), spec.make_caches()
+        for layer in range(spec.num_layers):
+            by_layer, ok = await layered.install_layer(by_layer, np.array([2, 0], np.int32), layer)
+            assert ok
+        return out, again, by_layer
 
-    out, again = asyncio.run(drive())
-    for got, (last, first) in ((out, (2, 0)), (again, (0, 2))):
+    out, again, by_layer = asyncio.run(drive())
+    for got, (last, first) in ((out, (2, 0)), (again, (0, 2)), (by_layer, (0, 2))):
         # The LAST block's state and tail alone; both blocks' latents.
         np.testing.assert_array_equal(np.asarray(got[0][0])[last], want[0][0][3])
         np.testing.assert_array_equal(np.asarray(got[0][1])[last], want[0][1][3])

@@ -20,7 +20,11 @@ from infinistore_tpu import tracing
 from infinistore_tpu.connector import KVConnector
 from infinistore_tpu.engine import ContinuousBatchingHarness, EngineKVAdapter
 from infinistore_tpu.models import LlamaConfig, init_params
-from infinistore_tpu.tpu.layerwise import PrefetchDiscarded
+from infinistore_tpu.tpu.layerwise import (
+    LayerwisePrefetch,
+    PartialReadError,
+    PrefetchDiscarded,
+)
 from infinistore_tpu.tpu.paged import PagedKVCacheSpec, gather_blocks
 from infinistore_tpu.tpu.staging import HostStagingPool, StagingPoolExhausted
 
@@ -158,7 +162,7 @@ def test_start_fetch_install_roundtrips_bytes(conn):
 def test_prefetch_wraps_regions_when_pool_is_shallow(conn):
     """regions < num_layers: the pipeline double-buffers — a region refills
     only after install consumed its occupant — and the bytes still land
-    exactly (the non-fused, layer-streaming install path)."""
+    exactly (install goes run by run: the layers staged when it looks)."""
     kvc = KVConnector(conn, SPEC, "pf-wrap", max_blocks=8)
     caches = _rand_caches(2)
     toks = list(range(32))
@@ -181,6 +185,290 @@ def test_prefetch_wraps_regions_when_pool_is_shallow(conn):
         await _drain_pool(tiny)
 
     asyncio.run(drive())
+
+
+@pytest.mark.parametrize("regions, runs", [(None, [(0, 3)]), (2, [(0, 2), (2, 1)])])
+def test_install_is_one_dispatch_a_run_of_staged_layers(conn, regions, runs):
+    """The device is reached once a RUN of staged layers: every layer staged
+    (a region a layer, the default) is one executor call for the whole hit;
+    two regions of three layers are the two runs the wrap forces. The
+    connector's ``install_layers`` / ``install_dispatches`` count what the
+    ``install_upload`` spans show, and ``on_layer`` fires once a layer, in
+    layer order, with the layer's updated tensors."""
+    kvc = KVConnector(conn, SPEC, f"pf-runs-{regions}", max_blocks=8)
+    caches = _rand_caches(7)
+    toks = list(range(32))
+    src = np.array([5, 0, 12, 3], np.int32)
+    dst = np.array([7, 2, 15, 4], np.int32)
+    n = 4
+    pool = None
+    if regions is not None:
+        pool = HostStagingPool(
+            regions * 2 * n * SPEC.block_nbytes, SPEC.block_nbytes, conn=conn
+        )
+    seen = []
+
+    async def drive():
+        await kvc.save(toks, caches, src)
+        h = kvc.start_fetch(toks, prefetch_pool=pool)
+        assert h.regions == (regions or SPEC.num_layers)
+        await h.primed()  # a region a layer: waits for every layer's read
+        assert all(h.layer_ready(l) for l in range(h.regions))
+        out, loaded = await h.install(
+            SPEC.make_caches(), dst, on_layer=lambda l, tensors: seen.append((l, tensors))
+        )
+        assert loaded == n and h.blocks_installed == 2 * n * SPEC.num_layers
+        await _drain_pool(pool or kvc._prefetch_pool)
+        return out
+
+    rec = tracing.configure(enabled=True, capacity=4096, slow_op_us=0)
+    rec.clear()
+    try:
+        out = asyncio.run(drive())
+        uploads = sorted(
+            (s for s in rec.snapshot() if s["name"] == "install_upload"),
+            key=lambda s: s["start_us"],
+        )
+    finally:
+        tracing.configure(enabled=False)
+    assert [(u["attrs"]["layer"], u["attrs"]["layers"]) for u in uploads] == runs
+    c = kvc.hit_counters
+    assert c["install_dispatches"] == len(uploads) == len(runs)
+    assert c["install_layers"] == sum(u["attrs"]["layers"] for u in uploads) == SPEC.num_layers
+    assert c["install_upload_bytes"] == sum(u["attrs"]["bytes"] for u in uploads)
+    assert [l for l, _ in seen] == list(range(SPEC.num_layers))
+    for layer, tensors in seen:
+        assert tensors is out[layer]
+        for side in (0, 1):
+            want = np.asarray(gather_blocks(caches[layer][side], jnp.asarray(src)))
+            got = np.asarray(gather_blocks(tensors[side], jnp.asarray(dst, jnp.int32)))
+            np.testing.assert_array_equal(want, got)
+
+
+@pytest.mark.parametrize("missing", [False, True], ids=["store_error", "key_not_found"])
+@pytest.mark.parametrize("k", [1, 2])
+def test_store_failure_at_layer_k_keeps_the_layers_before_it(conn, k, missing):
+    """Layer ``k``'s read fails once every other layer has landed: the run
+    before it was scattered (its inputs DONATED), the layers from ``k`` on are
+    the caller's own arrays, and that list is the one valid one: in
+    ``PartialReadError.caches``, or returned with 0 blocks where the store
+    said the keys are gone (a miss)."""
+    kvc = KVConnector(conn, SPEC, f"pf-fail-{k}-{missing}", max_blocks=8)
+    caches = _rand_caches(8)
+    toks = list(range(32))
+    ids = np.array([1, 6, 9, 13], np.int32)
+    n = 4
+
+    async def drive():
+        await kvc.save(toks, caches, ids)
+        pool = kvc._ensure_prefetch_pool()
+        fail_now = asyncio.Event()
+
+        async def submit(blocks, nbytes):
+            if f"/L{k}/" in blocks[0][0]:
+                await fail_now.wait()
+                if missing:
+                    raise its.InfiniStoreKeyNotFound("gone")
+                raise its.InfiniStoreException("store down")
+            await conn.read_cache_async(blocks, nbytes, pool.base_ptr)
+
+        h = LayerwisePrefetch(
+            conn, pool, SPEC, kvc._key_fn(kvc._chains(toks)[:n]), n, SPEC.num_layers,
+            submit=submit, counters=kvc.hit_counters,
+        )
+        while not all(h.layer_ready(l) for l in range(SPEC.num_layers) if l != k):
+            await asyncio.sleep(0.005)
+        fail_now.set()
+        fresh = SPEC.make_caches()
+        if missing:
+            out, loaded = await h.install(fresh, ids)
+            assert loaded == 0
+        else:
+            with pytest.raises(PartialReadError) as err:
+                await h.install(fresh, ids)
+            out = err.value.caches
+            assert isinstance(err.value.cause, its.InfiniStoreException)
+        assert len(out) == SPEC.num_layers
+        for layer in range(SPEC.num_layers):
+            for side in (0, 1):
+                got = np.asarray(gather_blocks(out[layer][side], jnp.asarray(ids)))
+                if layer < k:  # the run before the failure: scattered
+                    want = np.asarray(gather_blocks(caches[layer][side], jnp.asarray(ids)))
+                    np.testing.assert_array_equal(want, got)
+                else:  # untouched: still the caller's array
+                    assert out[layer][side] is fresh[layer][side]
+                    assert not got.any()
+        assert h.blocks_installed == 2 * n * k
+        assert kvc.hit_counters["install_layers"] == k
+        assert kvc.hit_counters["install_dispatches"] == 1
+        await _drain_pool(pool)
+
+    asyncio.run(asyncio.wait_for(drive(), 30))
+
+
+@pytest.mark.parametrize(
+    "speculative,heavy", [(False, True), (True, True), (True, False)],
+    ids=["foreground", "speculative", "speculative-light"],
+)
+def test_speculative_prefetch_sends_one_layer_at_a_time_until_promoted(
+    conn, monkeypatch, speculative, heavy
+):
+    """A region a layer starts every layer's read at construction; a
+    speculative prefetch (BACKGROUND class: its request is not admitted yet)
+    keeps ``SPECULATIVE_READ_BYTES`` of reads in flight (of a heavy hit ONE
+    layer's), so that ``promote()`` finds the rest unsent and sends it
+    foreground: a read at the server keeps the class it went out with. A hit
+    whose layers all fit goes out whole. Either way the same bytes reach the
+    same blocks."""
+    from infinistore_tpu import wire
+    from infinistore_tpu.tpu import layerwise
+
+    if heavy:  # a layer's hit (4 blocks of a K and a V) is the whole window
+        monkeypatch.setattr(layerwise, "SPECULATIVE_READ_BYTES", SPEC.region_nbytes(4))
+    kvc = KVConnector(conn, SPEC, f"pf-spec-{speculative}-{heavy}", max_blocks=8)
+    caches = _rand_caches(9)
+    toks = list(range(32))
+    ids = np.array([2, 11, 4, 8], np.int32)
+    n = 4
+    bg, fg = wire.PRIORITY_BACKGROUND, wire.PRIORITY_FOREGROUND
+
+    async def drive():
+        await kvc.save(toks, caches, ids)
+        pool = kvc._ensure_prefetch_pool()
+        cell = {"value": bg if speculative else fg}
+        sent, land = [], [asyncio.Event() for _ in range(SPEC.num_layers)]
+
+        async def submit(blocks, nbytes):
+            sent.append(cell["value"])
+            await land[len(sent) - 1].wait()
+            await conn.read_cache_async(blocks, nbytes, pool.base_ptr)
+
+        async def settle():
+            for _ in range(5):
+                await asyncio.sleep(0)
+
+        h = LayerwisePrefetch(
+            conn, pool, SPEC, kvc._key_fn(kvc._chains(toks)[:n]), n, SPEC.num_layers,
+            submit=submit, priority_cell=cell,
+        )
+        await settle()
+        if speculative and not heavy:
+            assert sent == [bg] * SPEC.num_layers  # light layers: all at once
+            for ev in land:
+                ev.set()
+            await h.primed()
+        elif speculative:
+            assert sent == [bg]  # layer 0 alone
+            land[0].set()
+            while not h.layer_ready(0):
+                await asyncio.sleep(0.005)
+            await settle()
+            assert sent == [bg, bg]  # layer 1 once layer 0 landed
+            h.promote()
+            await settle()
+            assert sent == [bg, bg, fg]  # the rest at admission, foreground
+            # The background read lands BEHIND the foreground rest: primed()
+            # waits for it too (else the install would, under the gate).
+            primed = asyncio.ensure_future(h.primed())
+            land[2].set()
+            while not h.layer_ready(2):
+                await asyncio.sleep(0.005)
+            await settle()
+            assert not primed.done()
+            land[1].set()
+            await primed
+        else:
+            assert sent == [fg] * SPEC.num_layers  # all at construction
+            for ev in land:
+                ev.set()
+            await h.primed()
+        assert all(h.layer_ready(l) for l in range(SPEC.num_layers))
+        out, loaded = await h.install(SPEC.make_caches(), ids)
+        assert loaded == n
+        for layer in range(SPEC.num_layers):
+            for side in (0, 1):
+                want = np.asarray(gather_blocks(caches[layer][side], jnp.asarray(ids)))
+                got = np.asarray(gather_blocks(out[layer][side], jnp.asarray(ids)))
+                np.testing.assert_array_equal(want, got)
+        await _drain_pool(pool)
+
+    asyncio.run(asyncio.wait_for(drive(), 30))
+
+
+def test_speculative_prefetch_discarded_while_it_holds_reads_back(conn, monkeypatch):
+    """Discard with layers still held back: none of them is sent, every
+    task ends and the lease goes back."""
+    from infinistore_tpu import wire
+
+    from infinistore_tpu.tpu import layerwise
+
+    monkeypatch.setattr(layerwise, "SPECULATIVE_READ_BYTES", SPEC.region_nbytes(4))
+    kvc = KVConnector(conn, SPEC, "pf-spec-discard", max_blocks=8)
+    toks = list(range(32))
+    ids = np.array([2, 11, 4, 8], np.int32)
+
+    async def drive():
+        await kvc.save(toks, _rand_caches(10), ids)
+        pool = kvc._ensure_prefetch_pool()
+        sent, land = [], asyncio.Event()
+
+        async def submit(blocks, nbytes):
+            sent.append(blocks[0][0])
+            await land.wait()
+            await conn.read_cache_async(blocks, nbytes, pool.base_ptr)
+
+        h = LayerwisePrefetch(
+            conn, pool, SPEC, kvc._key_fn(kvc._chains(toks)[:4]), 4, SPEC.num_layers,
+            submit=submit, priority=wire.PRIORITY_BACKGROUND,
+        )
+        await asyncio.sleep(0.01)
+        assert len(sent) == 1
+        discard = asyncio.ensure_future(h.discard())
+        await asyncio.sleep(0.01)
+        land.set()  # the read in flight drains into leased memory
+        await asyncio.wait_for(discard, 10)
+        assert len(sent) == 1
+        with pytest.raises(PrefetchDiscarded):
+            await h.install(SPEC.make_caches(), ids)
+        await _drain_pool(pool)
+
+    asyncio.run(asyncio.wait_for(drive(), 30))
+
+
+def test_prefetch_pool_holds_four_full_depth_prefetches(conn):
+    """The connector's arena is sized by the prefetch's own rule, a region a
+    layer however many layers (ten here): four prefetches of the longest hit
+    reserve ``num_layers`` regions each, none wraps, and the fifth is
+    backpressure."""
+    spec = PagedKVCacheSpec(
+        num_layers=10, num_blocks=16, block_tokens=8, num_kv_heads=1, head_dim=16,
+        dtype=jnp.float32,
+    )
+    kvc = KVConnector(conn, spec, "pf-four", max_blocks=8)
+    caches = spec.make_caches()
+    prompts = [list(range(1000 * i, 1000 * i + 64)) for i in range(5)]
+    ids = np.arange(8, dtype=np.int32)
+
+    async def drive():
+        for toks in prompts:
+            await kvc.save(toks, caches, ids)
+        handles = [kvc.start_fetch(toks) for toks in prompts[:4]]
+        assert [h.n_blocks for h in handles] == [8] * 4
+        assert [h.regions for h in handles] == [spec.num_layers] * 4
+        pool = kvc._prefetch_pool
+        assert pool.slots_in_use == pool.num_slots
+        with pytest.raises(StagingPoolExhausted):
+            kvc.start_fetch(prompts[4])
+        for h in handles:
+            await h.primed()
+            assert all(h.layer_ready(layer) for layer in range(spec.num_layers))
+            out, loaded = await h.install(spec.make_caches(), ids)
+            assert loaded == 8
+        assert kvc.hit_counters["install_layers"] == 4 * spec.num_layers
+        assert kvc.hit_counters["install_dispatches"] == 4
+        await _drain_pool(pool)
+
+    asyncio.run(asyncio.wait_for(drive(), 30))
 
 
 def test_discard_returns_pool_to_baseline_and_counts_waste(conn):
