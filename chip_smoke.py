@@ -615,7 +615,7 @@ def check_steps_hold_kernels(cfg, params, sizes: Sizes) -> dict:
     # The wave as the harness launches it: one row over one page, packed.
     layout = serving.WaveLayout(rows=1, tables=1, pages=1)
     in_wave = _mosaic_kernels(
-        serving.verify_step_ragged, params, i32(layout.size(mrb)), caches,
+        serving.verify_step_ragged, params, i32(layout.size(mrb)), i32(serving.FEED_ROWS), caches,
         config=cfg, max_blocks=mrb, layout=layout,
     )
     assert in_wave == {"_ragged_attn_kernel"}, in_wave

@@ -51,14 +51,21 @@ import contextlib
 import time
 from contextlib import asynccontextmanager
 from dataclasses import dataclass, field
-from typing import List, NamedTuple, Optional, Sequence
+from typing import Dict, List, NamedTuple, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from . import tracing
-from .models.serving import WaveLayout, pack_wave, verify_step_ragged
+from .models.serving import (
+    FEED_ROWS,
+    WaveLayout,
+    fed_token,
+    no_feed,
+    pack_wave,
+    verify_step_ragged,
+)
 from .tpu.paged import gather_blocks
 from .tpu.paged_attention import RaggedWaveMeta, build_ragged_wave
 from .tpu.staging import StagingPoolExhausted
@@ -129,6 +136,13 @@ class DeviceGate:
         # admission expedites at most once, so the lane drains.
         self._expedite_waiting = 0
 
+    @property
+    def idle(self) -> bool:
+        """Nobody holds the gate or waits for it: ``exclusive()`` would be
+        granted without a suspension, and a wave launched now stands in no
+        other phase's way."""
+        return not self._exclusive and self._exclusive_waiting == 0 and self._shared == 0
+
     @asynccontextmanager
     async def exclusive(self, expedite: bool = False):
         # `gate_wait`: entry to acquired, whoever asked is its parent.
@@ -188,12 +202,33 @@ class _WaveOut:
     """What one launched wave returned beside its logits, kept while its
     requests may still ask (``WaveDecoder.token_ids``, ``row_aux``)."""
 
-    __slots__ = ("ids", "aux_rows", "host_ids")
+    __slots__ = ("ids", "feed", "aux_rows", "host_ids")
 
-    def __init__(self, ids, aux_rows):
+    def __init__(self, ids, feed, aux_rows):
         self.ids = ids  # [T] int32 on the device, its host copy under way
+        self.feed = feed  # [FEED_ROWS]: what the next wave's fed rows read, on the device
         self.aux_rows = aux_rows  # the model's per-row aux [T, ...], or None
         self.host_ids = None  # np [T], once the first request has asked
+
+
+class _Ahead(NamedTuple):
+    """A stream's row in a launched wave that its request has not been handed."""
+
+    position: int
+    rows: jax.Array  # its logits rows [1, vocab], as ``step_chunk`` will resolve to them
+    out: _WaveOut  # the wave it rode
+    offset: int  # its flat row there: what ``fed_token`` names
+
+
+class _Stream:
+    """A request's declared run of one-token rounds (``WaveDecoder.stream``)."""
+
+    __slots__ = ("table", "left", "ahead")
+
+    def __init__(self, table, rounds: int):
+        self.table = table  # the padded table its request hands in every round
+        self.left = rounds  # ``step_chunk`` calls its request has yet to make
+        self.ahead: Optional[_Ahead] = None
 
 
 class _Wave(NamedTuple):
@@ -255,6 +290,42 @@ class WaveDecoder:
     wave's ids, every other request of that wave reads the host copy.
     ``waves + blocking_reads`` is ``wave_host_transfers`` in ``metrics()``:
     2 a wave, whatever its rows.
+
+    **And one wave ahead.** The token a round samples is four bytes that are
+    on the device when its wave ends, and all that the next wave wants of
+    them is to read them as its ``tokens``. A request that will come back
+    says so (``stream``: so many one-token rounds, always with this table;
+    ``_generate`` does, unless a drafter writes its chunks), and the decoder
+    then launches the row of round k + 1 while the request still reads round
+    k's token: the row's token slot names its row of the wave before it
+    (``serving.fed_token``) and the program reads the id out of that wave's
+    ``feed``, one ``[FEED_ROWS]`` array whatever either bucket is, so one
+    program a bucket as before, with fed rows and rows whose token the host
+    sends side by side. The request's ``step_chunk(token, position + 1,
+    table)`` is then matched to the row already launched (position and table;
+    anything else is an error) and resolves to that wave's logits rows, with
+    no device time to wait for, but not before the flush that took it has
+    launched the stream's NEXT row: the request goes on to block the event
+    loop in ``token_ids``, and by then the wave after the one it reads is
+    behind it on the device. Depth is one: a stream's row n + 2 is launched
+    only when its request has been handed row n + 1. A stream's first call has
+    no row yet: it rides the flush's wave with its token from the host, and
+    is taken again by the next flush (started at once) as if it had been
+    launched ahead. Rows fed in one wave all read the wave launched LAST; a
+    stream whose row rode an older one comes back through the host once.
+    Nothing is launched for a call that will not come (a stream's last round,
+    a stream that ended), so no row is wasted and no recurrent state advances
+    unasked. **When it does not engage**, by what the flush finds and by no
+    option: a bare ``step`` / ``step_chunk`` (no stream: a test, a warm-up, a
+    drafter's chunk); a row past ``FEED_ROWS``; ``harness.arriving`` non-zero
+    (a request between its admission and its first wave will ask for the
+    device, and a wave queued ahead of its need would stand in front of its
+    install, prefill or snapshot by up to a step); the device gate not idle
+    (a mutator holds or wants it, or a save's snapshot reads under it: the
+    launch would have to wait, and nothing between a flush's sort and its
+    launch may suspend). Then every call resolves as it always did and comes
+    back with its token. ``waves_ahead`` counts the launched waves with a fed
+    row (``wave_ahead_waves`` in ``metrics()``).
 
     ``bucket_sizes`` records the distinct (B, T, P) buckets — table rows,
     flat token rows, flat attention pages — which ARE the jit cache
@@ -320,6 +391,14 @@ class WaveDecoder:
         self.waves = 0
         self.max_wave = 0
         self.bucket_sizes = set()  # distinct PADDED (B, T, P) buckets (= compiles)
+        # One wave ahead (class docstring): the declared streams by the id of
+        # the table each holds, the wave launched last (whose ``feed`` the
+        # next one's fed rows read), what a wave with no fed row is handed in
+        # its place, and the launched waves that had a fed row.
+        self._streams: Dict[int, _Stream] = {}
+        self._last: Optional[_WaveOut] = None
+        self._no_feed = no_feed()
+        self.waves_ahead = 0
 
     async def step(self, token: int, position: int, padded_table) -> jax.Array:
         """Advance this request by one token; returns its logits row."""
@@ -340,14 +419,39 @@ class WaveDecoder:
         if not tokens or len(tokens) != len(positions):
             raise ValueError("need non-empty tokens with matching positions")
         fut = asyncio.get_running_loop().create_future()
+        stream = self._streams.get(id(padded_table))
+        if stream is not None:
+            stream.left -= 1
         # A queue entry: (tokens, positions, table, future).
-        self._pending.append((list(tokens), list(positions), padded_table, fut))
+        self._enqueue([(list(tokens), list(positions), padded_table, fut)])
+        return await fut
+
+    def _enqueue(self, entries: List[tuple]):
+        self._pending.extend(entries)
         if not self._flush_scheduled:
             self._flush_scheduled = True
             task = asyncio.ensure_future(self._flush())
             self._flush_tasks.add(task)
             task.add_done_callback(self._flush_tasks.discard)
-        return await fut
+
+    @contextlib.contextmanager
+    def stream(self, padded_table, rounds: int):
+        """A request's way of saying that it will come back: inside the block
+        it calls ``step_chunk`` ``rounds`` times, one token a call at
+        consecutive positions, every time with ``padded_table`` (the object:
+        the decoder knows the stream by it) and, from its second call on, with
+        the token its last call's row sampled. The decoder may then launch the
+        row of call k + 1 while the request still reads call k's token (class
+        docstring, "one wave ahead"); a call that does not fit what was
+        launched for it is an error. Leaving the block ends the stream: no row
+        of it is launched afterwards."""
+        stream = _Stream(padded_table, rounds)
+        self._streams[id(padded_table)] = stream
+        try:
+            yield
+        finally:
+            stream.left, stream.ahead = 0, None
+            del self._streams[id(padded_table)]
 
     # -- what the wave program returned beside its logits ---------------------
 
@@ -464,15 +568,18 @@ class WaveDecoder:
         self.wave_layer_pages += self._layers * real_pages
         return _Wave(tokens, positions, row_of, meta, tables, wmeta, t_real)
 
-    def launch(self, tokens, positions, row_of, meta, tables, wmeta=None):
+    def launch(self, tokens, positions, row_of, meta, tables, wmeta=None, prev_ids=None):
         """ONE wave on the device (cache-mutating: caller holds the exclusive
         gate): the wave's integer metadata goes up as one packed ``int32``
         operand (models/serving.py ``pack_wave``: ``tokens``, ``positions``,
         ``row_of`` ``[T]``, ``meta``'s page triple, the ``[B, max_blocks]``
         ``tables`` and, where the spec names a window, ``wmeta``'s triple),
         one jitted call runs the model's wave body on it, and the sampled
-        ids start their way back to the host at once. Returns ``(logits [T,
-        vocab] on the device, the wave's _WaveOut, aux)``."""
+        ids start their way back to the host at once. ``prev_ids``: the
+        ``feed`` of the wave whose rows the ``fed_token`` slots of ``tokens``
+        name, already on the device; None where every token is the host's.
+        Returns ``(logits [T, vocab] on the device, the wave's _WaveOut,
+        aux)``."""
         pieces = [
             tokens, positions, row_of, meta.pages, meta.page_rows,
             meta.page_starts, np.stack(tables),
@@ -484,27 +591,93 @@ class WaveDecoder:
             None if wmeta is None else wmeta.num_pages,
         )
         mrb = self.h.max_req_blocks
-        logits, self.h.caches, ids, aux = verify_step_ragged(
-            self.h.params, pack_wave(layout, mrb, pieces), self.h.caches,
+        logits, self.h.caches, ids, feed, aux = verify_step_ragged(
+            self.h.params, pack_wave(layout, mrb, pieces),
+            self._no_feed if prev_ids is None else prev_ids, self.h.caches,
             config=self.h.config, max_blocks=mrb, layout=layout,
         )
         ids.copy_to_host_async()
-        return logits, _WaveOut(ids, aux.get("rows")), aux
+        return logits, _WaveOut(ids, feed, aux.get("rows")), aux
 
-    def _resolve(self, batch: List[tuple], wave: "_Wave", logits, out, aux):
-        """Hand every entry of a launched wave its logits rows (only real
-        rows' futures resolve) and keep what the wave returned beside them."""
+    def _sort(self, batch: List[tuple]):
+        """The taken ``batch`` by what each entry wants of this flush.
+
+        ``taken``: ``(future, rows)`` of the calls whose row was launched
+        ahead of them; they resolve to those rows once this flush's wave is
+        on the device. ``launched``: the entries that ride this flush's wave:
+        the calls that have no row yet, as they came, then, where
+        ``ahead_ok``, one ``fed`` entry (no future, its token a
+        ``fed_token``) for each taken call whose stream goes on and whose row
+        rode the wave launched last. ``ahead_ok``: the engage rule (class
+        docstring), asked once a flush, and only where a stream is in it."""
+        ahead_ok = (
+            any(id(table) in self._streams for _, _, table, _ in batch)
+            and self.h.arriving == 0
+            and self.h.gate.idle
+        )
+        taken, launched, fed = [], [], []
+        for entry in batch:
+            toks, pos, table, fut = entry
+            if fut.done():  # its request was cancelled while it queued
+                continue
+            stream = self._streams.get(id(table))
+            if stream is None or stream.ahead is None:
+                launched.append(entry)
+                continue
+            ahead, stream.ahead = stream.ahead, None
+            if len(toks) != 1 or pos[0] != ahead.position:
+                fut.set_exception(RuntimeError(
+                    f"a declared stream came back with {len(toks)} token(s) at position "
+                    f"{pos[0]}: its row was launched for one token at {ahead.position}"
+                ))
+                continue
+            taken.append((fut, ahead.rows))
+            if (
+                ahead_ok and stream.left > 0
+                and ahead.out is self._last and ahead.offset < FEED_ROWS
+            ):
+                fed.append(([fed_token(ahead.offset)], [ahead.position + 1], table, None))
+        return taken, launched + fed, len(fed), ahead_ok
+
+    def _resolve(self, launched: List[tuple], fed: int, ahead_ok: bool,
+                 wave: "_Wave", logits, out, aux) -> List[tuple]:
+        """A launched wave's rows to their entries, and what the wave
+        returned beside them kept. A call whose stream ends here, or has none,
+        resolves now (only real rows' futures resolve). A call whose stream
+        goes on keeps its row as the stream's ``ahead`` and is returned, to be
+        enqueued again: the flush that takes it launches the stream's next row
+        and only then resolves it, so the request blocks in its read-back
+        with that row already behind its wave on the device. Each of the last
+        ``fed`` entries, which no call waits for yet, becomes its stream's
+        ``ahead``."""
         self.waves += 1
+        self.waves_ahead += fed > 0
         self.one_row_waves += wave.real_rows == 1
-        self.max_wave = max(self.max_wave, len(batch))
-        off, handed = 0, []
-        for toks, _, _, fut in batch:
-            if not fut.done():
+        self.max_wave = max(self.max_wave, len(launched))
+        self._last = out
+        off, handed, again = 0, [], []
+        for entry in launched:
+            toks, pos, table, fut = entry
+            if fut is None or not fut.done():
                 rows = logits[off : off + len(toks)]
-                fut.set_result(rows)
                 handed.append((rows, off, len(toks)))
+                stream = self._streams.get(id(table))
+                if stream is not None and (fut is None or (
+                    ahead_ok and stream.left > 0 and len(toks) == 1 and off < FEED_ROWS
+                )):
+                    stream.ahead = _Ahead(pos[0], rows, out, off)
+                    if fut is not None:
+                        again.append(entry)
+                elif fut is not None:
+                    fut.set_result(rows)
             off += len(toks)
         self._keep(out, aux, handed)
+        return again
+
+    @staticmethod
+    def _hand(taken: List[tuple]):
+        for fut, rows in taken:
+            fut.set_result(rows)
 
     async def _flush(self):
         batch: List[tuple] = []
@@ -519,35 +692,49 @@ class WaveDecoder:
             self._flush_scheduled = False
             if not batch:
                 return
-            # `wave`: a trace of its own (it serves many requests), one
-            # span per flush.
-            if tracing.enabled():
-                wspan = tracing.Span("wave")
-                wspan.stage("taken")
-            wave = self._assemble(batch)
-            if wspan is not None:
-                wspan.stage("assembled")
-                wspan.annotate(
-                    entries=len(batch), real_rows=wave.real_rows,
-                    rows=len(wave.tokens), pages=wave.meta.num_pages,
-                    pad_pages=wave.meta.pad_pages,
-                )
+            taken, launched, fed, ahead_ok = self._sort(batch)
+            again: List[tuple] = []
+            if not fed:
+                # No next row of theirs rides this flush's wave: their rows
+                # are theirs now, whatever the gate makes the wave wait for.
+                self._hand(taken)
+            if launched:
+                # `wave`: a trace of its own (it serves many requests), one
+                # span per launched flush.
+                if tracing.enabled():
+                    wspan = tracing.Span("wave")
+                    wspan.stage("taken")
+                wave = self._assemble(launched)
+                if wspan is not None:
+                    wspan.stage("assembled")
+                    wspan.annotate(
+                        entries=len(launched), real_rows=wave.real_rows,
+                        rows=len(wave.tokens), pages=wave.meta.num_pages,
+                        pad_pages=wave.meta.pad_pages, fed_rows=fed,
+                    )
 
-            # The flush task inherited the context of the request that
-            # scheduled it: bind the wave's own span, so the gate wait
-            # below is the wave's child and not that request's.
-            with tracing.override_span(wspan):
-                async with self.h.gate.exclusive():
-                    if wspan is not None:
-                        wspan.stage("gate")
-                    with tracing.device_call("its.wave_dispatch", wspan):
-                        logits, out, aux = self.launch(
-                            wave.tokens, wave.positions, wave.row_of,
-                            wave.meta, wave.tables, wave.wmeta,
-                        )
-                    if wspan is not None:
-                        wspan.stage("dispatched")
-            self._resolve(batch, wave, logits, out, aux)
+                # The flush task inherited the context of the request that
+                # scheduled it: bind the wave's own span, so the gate wait
+                # below is the wave's child and not that request's.
+                with tracing.override_span(wspan):
+                    async with self.h.gate.exclusive():
+                        if wspan is not None:
+                            wspan.stage("gate")
+                        with tracing.device_call("its.wave_dispatch", wspan):
+                            logits, out, aux = self.launch(
+                                wave.tokens, wave.positions, wave.row_of,
+                                wave.meta, wave.tables, wave.wmeta,
+                                self._last.feed if fed else None,
+                            )
+                        if wspan is not None:
+                            wspan.stage("dispatched")
+                again = self._resolve(launched, fed, ahead_ok, wave, logits, out, aux)
+            if fed:
+                # After the launch: a request handed its rows blocks the loop
+                # in its read-back, and its stream's next row is then behind it.
+                self._hand(taken)
+            if again:
+                self._enqueue(again)
             if wspan is not None:
                 wspan.stage("resolved")
                 wspan.finish()
@@ -556,7 +743,9 @@ class WaveDecoder:
                 wspan.finish(status=f"error:{type(e).__name__}")
             # A dead flush (model error, or cancellation/GC at shutdown)
             # must strand NO waiter: fail the taken batch and anything still
-            # pending, and clear the flag so a later step() starts fresh.
+            # pending (a call whose row was launched ahead is in one of the
+            # two like any other), and clear the flag so a later step()
+            # starts fresh.
             self._flush_scheduled = False
             stranded, self._pending = batch + self._pending, []
             exc = e if isinstance(e, Exception) else RuntimeError(
@@ -864,6 +1053,13 @@ class ContinuousBatchingHarness:
         # overlapping store writes.
         self.live = 0
         self.max_live = 0
+        # Requests admitted and not yet in a wave: each will ask for the
+        # device (an install, a prefill or a resume, the prompt snapshot's
+        # wait) before its first round, and a wave launched ahead of its need
+        # would stand in front of it. While it is non-zero the decoder
+        # launches none (``WaveDecoder``, "one wave ahead"). Not ``live``,
+        # which also holds the requests in their save's tail.
+        self.arriving = 0
         self._saving = 0
         self.max_concurrent_saves = 0
         # Prompt saves whose store write ran as a task beside the request's
@@ -1111,7 +1307,16 @@ class ContinuousBatchingHarness:
         out: List[int] = []
         emit_s: List[float] = []
         first_token_t: Optional[float] = None
-        with tracing.trace_op("generate") as gspan:
+        closing = (len(token_ids) + gen_tokens) % self.config.block_tokens == 0
+        # Without a drafter every round is one token at the next position, and
+        # this loop says so: the decoder may then have round k + 1's row on the
+        # device while round k's token is read here (``WaveDecoder.stream``).
+        # A drafter's next chunk is the host's to draft.
+        declared = (
+            self.wave.stream(padded, gen_tokens + closing)
+            if self.drafter is None else contextlib.nullcontext()
+        )
+        with tracing.trace_op("generate") as gspan, declared:
             while len(out) < gen_tokens:
                 chunk = [tok]
                 if self.drafter is not None:
@@ -1151,7 +1356,7 @@ class ContinuousBatchingHarness:
             # it completes a block (which the extended-chain save below
             # persists), one more step lands it; otherwise its block is an
             # incomplete tail with no chain key — skip the wasted wave.
-            if (len(token_ids) + gen_tokens) % self.config.block_tokens == 0:
+            if closing:
                 await self.wave.step(tok, pos, padded)
         return out, first_token_t, emit_s
 
@@ -1235,6 +1440,8 @@ class ContinuousBatchingHarness:
             )
         self.live += 1
         self.max_live = max(self.max_live, self.live)
+        self.arriving += 1
+        arriving = True
         # Trace root for this request (docs/observability.md): `enqueue` is
         # stamped at admission t0, `alloc_done` with its blocks in hand, a
         # hit's `primed` when its fetch pipeline is full, `install` when
@@ -1484,6 +1691,10 @@ class ContinuousBatchingHarness:
             ttft_us = save_overlap_us = save_tail_us = ack_tail_us = 0.0
             token_emit_s: List[float] = []
             if gen_tokens:
+                # In the waves from here: the first round's entry is taken by
+                # the next flush, which may launch ahead beside it.
+                self.arriving -= 1
+                arriving = False
                 generated, first_token_t, token_emit_s = await self._generate(
                     token_ids, table, gen_tokens
                 )
@@ -1570,6 +1781,8 @@ class ContinuousBatchingHarness:
                 rspan.finish(status=f"error:{type(e).__name__}")
             raise
         finally:
+            if arriving:  # it never reached a wave; before anything here awaits
+                self.arriving -= 1
             tracing.unbind_span(rtoken)
             if rspan is not None:
                 rspan.finish()  # idempotent: an error finish above wins
@@ -1636,7 +1849,9 @@ class ContinuousBatchingHarness:
         ``wave_pad_fraction``, the share of launched wave rows that were
         padding, ``wave_one_row_waves``, the launched waves that carried
         ONE real flat row (a lone request's steps: the waves whose dense FFN
-        pads its row, models/llama.py ``_ffn``), ``wave_pages`` /
+        pads its row, models/llama.py ``_ffn``), ``wave_ahead_waves``, the
+        launched waves with a row fed from the wave before them on the device
+        (``WaveDecoder``, "one wave ahead"), ``wave_pages`` /
         ``wave_pad_pages``, the flat attention
         pages launched and those of them that were the page bucket's
         padding; ``wave_layer_pages`` / ``wave_window_pages_skipped``, the
@@ -1756,6 +1971,10 @@ class ContinuousBatchingHarness:
             # step of a lone request, and the share of waves whose dense
             # FFN runs on a padded row (models/llama.py ``_ffn``).
             "wave_one_row_waves": self.wave.one_row_waves,
+            # Launched waves (of ``decode_waves``) with at least one row that
+            # took its token from the wave before it on the device: the waves
+            # launched while their requests still read the last one's tokens.
+            "wave_ahead_waves": self.wave.waves_ahead,
             # Flat attention pages the waves launched, and how many were
             # the power-of-two bucket's padding: steps the ragged kernel
             # neither computes nor fetches (tpu/paged_attention.py).

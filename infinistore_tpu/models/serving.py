@@ -24,8 +24,8 @@ The engine does not call ``wave`` itself. Every decode wave it launches is ONE
 program, :func:`verify_step_ragged` below, whose traffic with the host is one
 array each way:
 
-``verify_step_ragged(params, packed, caches, config, max_blocks, layout) ->
-(logits, caches, ids, aux)``
+``verify_step_ragged(params, packed, prev_ids, caches, config, max_blocks,
+layout) -> (logits, caches, ids, feed, aux)``
     ``packed`` is the wave's whole integer metadata as one ``int32`` vector
     (:func:`pack_wave`): the body's seven index arrays, ten with a window, at
     offsets that are a function of the bucket ``layout`` (:class:`WaveLayout`)
@@ -40,6 +40,16 @@ array each way:
     ``aux["counters"]`` up by name into ``harness.metrics()`` and reads
     neither.
 
+    **A row may take its token from the device.** ``prev_ids`` is an earlier
+    wave's ``feed``: its first :data:`FEED_ROWS` ids, padded to that many, so
+    one shape whatever that wave's bucket was. A token slot of the packed
+    operand that holds :func:`fed_token` ``(src)``, a negative number, is
+    read as ``prev_ids[src]``; every other slot is the token itself. That is
+    what lets the decoder launch a stream's next wave before the host has seen
+    the token that wave starts from (``WaveDecoder``, "one wave ahead"). The
+    model's wave body gets ``tokens`` as it always did. A wave with no such
+    row is handed :func:`no_feed`.
+
 Every step DONATES ``caches``: the caller uses the returned ones.
 """
 
@@ -50,6 +60,25 @@ from typing import Callable, Dict, List, NamedTuple, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+
+# The rows of a wave whose sampled ids the NEXT wave's program can read on the
+# device: the length of ``feed`` and of ``prev_ids``, one for every bucket, so
+# that the operand adds nothing to a program's jit key. A wave's rows past it
+# are fed by the host, as every row was.
+FEED_ROWS = 64
+
+
+def fed_token(src: int) -> int:
+    """The token slot that reads row ``src`` of ``prev_ids``."""
+    if not 0 <= src < FEED_ROWS:
+        raise ValueError(f"only rows 0..{FEED_ROWS - 1} of a wave can feed the next, got {src}")
+    return -(src + 1)
+
+
+def no_feed() -> jax.Array:
+    """``prev_ids`` for a wave whose every token comes from the host."""
+    return jnp.zeros((FEED_ROWS,), jnp.int32)
 
 
 class ServingSteps(NamedTuple):
@@ -129,16 +158,26 @@ def unpack_wave(packed: jax.Array, layout: WaveLayout, max_blocks: int) -> Dict[
     static_argnames=("config", "max_blocks", "layout"),
     donate_argnames=("caches",),
 )
-def verify_step_ragged(params, packed, caches, config, max_blocks: int, layout: WaveLayout):
+def verify_step_ragged(
+    params, packed, prev_ids, caches, config, max_blocks: int, layout: WaveLayout
+):
     """THE decode wave as the engine launches it (module docstring): the
-    model's own wave body, ``config.steps.wave``, between one operand in and
-    the sampled ids out. The trace knows every model's wave program by this
+    model's own wave body, ``config.steps.wave``, between one operand in (and
+    an earlier wave's ``feed``, for the rows that take their token from it)
+    and the sampled ids out. The trace knows every model's wave program by this
     function's name. The body is traced as the plain function behind its own
     ``jax.jit`` (``__wrapped__``), so this program is one jit deep, as the
     body alone is: a jit nested under this one cost every wave bucket a
     quarter of a second of set-up on the chip's host (PERF.md, PR 36).
     ``caches`` is donated, declared here as the body declares it."""
+    if prev_ids.shape != (FEED_ROWS,):
+        raise ValueError(f"prev_ids is a wave's feed, [{FEED_ROWS}], got {prev_ids.shape}")
     f = unpack_wave(packed, layout, max_blocks)
+    # A slot is a token or ``fed_token(src)``. ``lax`` calls and an index, no
+    # ``jnp`` function that is a jit of its own (the set-up rule above).
+    slots = f["tokens"]
+    src = jax.lax.clamp(0, -slots - 1, FEED_ROWS - 1)
+    tokens = jax.lax.select(slots < 0, prev_ids.at[src].get(mode="promise_in_bounds"), slots)
     kw = {}
     if layout.window_pages is not None:
         kw["window_pages"] = (
@@ -146,9 +185,11 @@ def verify_step_ragged(params, packed, caches, config, max_blocks: int, layout: 
         )
     body = getattr(config.steps.wave, "__wrapped__", config.steps.wave)
     logits, caches, *aux = body(
-        params, f["tokens"], f["positions"], f["row_of"], f["pages"],
+        params, tokens, f["positions"], f["row_of"], f["pages"],
         f["page_rows"], f["page_starts"], caches, f["block_tables"], config,
         max_blocks, **kw,
     )
     ids = jax.lax.argmax(logits, 1, jnp.int32)  # what jnp.argmax(logits, -1) computes
-    return logits, caches, ids, aux[0] if aux else {}
+    short = max(FEED_ROWS - layout.rows, 0)
+    feed = jax.lax.pad(ids[:FEED_ROWS], jnp.int32(0), [(0, short, 0)])
+    return logits, caches, ids, feed, aux[0] if aux else {}

@@ -260,12 +260,12 @@ def test_wave_sizes_bucket_to_powers_of_two(conn, params, monkeypatch):
     shapes_seen = set()
     real = engine_mod.verify_step_ragged
 
-    def recording(params_, packed, caches, *, config, max_blocks, layout):
+    def recording(params_, packed, prev_ids, caches, *, config, max_blocks, layout):
         assert packed.shape == (layout.size(max_blocks),)
         assert layout.window_pages is None  # no window in this spec
         shapes_seen.add((layout.tables, layout.rows, layout.pages))
         return real(
-            params_, packed, caches, config=config, max_blocks=max_blocks,
+            params_, packed, prev_ids, caches, config=config, max_blocks=max_blocks,
             layout=layout,
         )
 

@@ -574,7 +574,9 @@ def test_falcon_h1_entries_compile_and_update_every_cache_tensor_in_place(v5e, m
     ]
     if entry == "packed_wave":
         layout = serving.WaveLayout(rows=4, tables=4, pages=128)
-        jitted, args = serving.verify_step_ragged, (params, i32(layout.size(table)), caches)
+        jitted, args = serving.verify_step_ragged, (
+            params, i32(layout.size(table)), i32(serving.FEED_ROWS), caches,
+        )
         static = {"config": cfg, "max_blocks": table, "layout": layout}
     else:
         tokens = 1024 if entry == "resume_chunk_block" else 127

@@ -400,8 +400,8 @@ def test_wave_program_shares_one_layer_and_one_kernel_function(v5e, monkeypatch,
     else:
         layout = serving.WaveLayout(rows, rows, pages)
         traced = serving.verify_step_ragged.trace(
-            params, i32(layout.size(table)), caches, config=cfg, max_blocks=table,
-            layout=layout,
+            params, i32(layout.size(table)), i32(serving.FEED_ROWS), caches, config=cfg,
+            max_blocks=table, layout=layout,
         )
     text = traced.lower(lowering_platforms=("tpu",)).as_text()
     layers = re.findall(r"call @(_wave_layer\w*)\(", text)
@@ -505,7 +505,7 @@ def test_serving_entries_update_the_cache_in_place(v5e, monkeypatch, case):
         static = {"config": cfg, "max_blocks": table}
     elif entry == "packed_wave":
         layout = serving.WaveLayout(rows=8, tables=8, pages=1024)
-        args = (params, i32(layout.size(80)), caches)
+        args = (params, i32(layout.size(80)), i32(serving.FEED_ROWS), caches)
         static = {"config": cfg, "max_blocks": 80, "layout": layout}
     elif entry == "resume_chunk":
         args, static = (params, i32(128), i32(), caches, i32(524)), {"config": cfg}
@@ -644,7 +644,7 @@ def test_afmoe_entries_compile_and_update_the_cache_in_place(v5e, monkeypatch, e
         static = {"config": cfg, "max_blocks": table, "window_pages": windowed}
     elif entry == "packed_wave":  # the same bucket as the decoder launches it
         layout = serving.WaveLayout(rows=4, tables=4, pages=1024, window_pages=4 * 129)
-        args = (params, i32(layout.size(320)), caches)
+        args = (params, i32(layout.size(320)), i32(serving.FEED_ROWS), caches)
         static = {"config": cfg, "max_blocks": 320, "layout": layout}
     elif entry == "resume_chunk":
         args, static = (params, i32(128), i32(), caches, i32(320)), {"config": cfg}

@@ -10,6 +10,7 @@ rows ``step_chunk`` hands back, and the count of transfers to 2 a wave.
 """
 
 import asyncio
+import contextlib
 
 import jax
 import jax.numpy as jnp
@@ -26,7 +27,7 @@ from infinistore_tpu.engine import (
     NGramDrafter,
     WaveDecoder,
 )
-from infinistore_tpu.models import AfmoeConfig, LlamaConfig, afmoe, llama, serving
+from infinistore_tpu.models import AfmoeConfig, LlamaConfig, afmoe, falcon_h1, llama, serving
 from infinistore_tpu.models.serving import WaveLayout, pack_wave, unpack_wave
 
 NUM_BLOCKS, MAX_REQ_BLOCKS = 64, 16
@@ -120,8 +121,9 @@ def test_the_packed_entry_is_the_body_bit_for_bit_on_the_decoders_own_wave(model
     tables, chunks, caches = mixed_wave(cfg, params)
     real, seen = engine_mod.verify_step_ragged, []
 
-    def twice(params_, packed, caches_, *, config, max_blocks, layout):
+    def twice(params_, packed, prev_ids, caches_, *, config, max_blocks, layout):
         f = {k: jnp.asarray(v) for k, v in unpack_wave(packed, layout, max_blocks).items()}
+        assert min(f["tokens"]) >= 0, "a bare step_chunk's token comes from the host"
         kw = {}
         if layout.window_pages is not None:
             kw["window_pages"] = (
@@ -132,16 +134,20 @@ def test_the_packed_entry_is_the_body_bit_for_bit_on_the_decoders_own_wave(model
             f["page_starts"], jax.tree.map(jnp.copy, caches_), f["block_tables"], config,
             max_blocks, **kw,
         )
-        logits, got_caches, ids, aux = real(
-            params_, packed, caches_, config=config, max_blocks=max_blocks, layout=layout
+        logits, got_caches, ids, feed, aux = real(
+            params_, packed, prev_ids, caches_, config=config, max_blocks=max_blocks,
+            layout=layout,
         )
         same_bits(logits, want_logits, "logits")
         same_bits(got_caches, want_caches, "caches")
         same_bits(aux, want_aux[0] if want_aux else {}, "aux")
         assert ids.dtype == jnp.int32 and ids.shape == (layout.rows,)
         np.testing.assert_array_equal(np.asarray(ids), np.argmax(np.asarray(logits), axis=-1))
+        # What the next wave's fed rows would read: the ids, padded to one shape.
+        assert feed.dtype == jnp.int32 and feed.shape == (serving.FEED_ROWS,)
+        np.testing.assert_array_equal(np.asarray(feed)[: layout.rows], np.asarray(ids))
         seen.append(layout)
-        return logits, got_caches, ids, aux
+        return logits, got_caches, ids, feed, aux
 
     monkeypatch.setattr(engine_mod, "verify_step_ragged", twice)
 
@@ -319,3 +325,327 @@ def test_one_wave_program_a_bucket_as_before(conn):
         x & (x - 1) == 0 for bucket in buckets for x in bucket
     ), buckets
     assert serving.verify_step_ragged._cache_size() - before == len(buckets)
+
+
+# ---------------------------------------------------------------------------
+# One wave ahead: a declared stream's next row is launched with its token read
+# on the device from the wave before it.
+# ---------------------------------------------------------------------------
+
+AHEAD_MODELS = {
+    "llama": MODELS["llama"],
+    # A state and a conv tail beside K/V pages in every layer: a row launched
+    # twice, or once too often, would show in the state.
+    "falcon_h1": (
+        falcon_h1.FalconH1Config(dtype=jnp.float32, rope_theta=1e4),
+        lambda cfg: falcon_h1.init_params(cfg, jax.random.key(49)),
+    ),
+}
+
+
+@pytest.fixture(scope="module", params=sorted(AHEAD_MODELS))
+def ahead_model(request):
+    cfg, init = AHEAD_MODELS[request.param]
+    return cfg, init(cfg)
+
+
+def landed_prompts(cfg, params, lengths, seed):
+    """Requests whose prompts are on the device as ``run_request`` leaves them
+    before the first wave: all but the last token under a recurrent state, cut
+    at block edges through the model's resume step; otherwise the prompt's
+    whole blocks through its prefill. Returns ``(tables, first round's (token, position) a
+    request, caches)``."""
+    bt = cfg.block_tokens
+    rng = np.random.default_rng(seed)
+    has_state = cfg.kv_spec(1).has_state
+    tables = np.zeros((len(lengths), MAX_REQ_BLOCKS), np.int32)
+    caches = cfg.kv_spec(NUM_BLOCKS).make_caches()
+    starts, first = [], 1
+    for r, n in enumerate(lengths):
+        tables[r] = np.arange(first, first + MAX_REQ_BLOCKS)
+        first += MAX_REQ_BLOCKS
+        if not has_state:
+            n = n // bt * bt
+        prompt = rng.integers(0, cfg.vocab, size=n)
+        if has_state:
+            for at in range(0, n - 1, bt):
+                _, caches = cfg.steps.resume(
+                    params, jnp.asarray(prompt[at : min(at + bt, n - 1)], jnp.int32),
+                    jnp.int32(at), caches, tables[r], cfg, MAX_REQ_BLOCKS,
+                )
+        else:
+            _, caches = cfg.steps.prefill(
+                params, jnp.asarray(prompt, jnp.int32), caches,
+                jnp.asarray(tables[r, : n // bt]), cfg,
+            )
+        starts.append((int(prompt[-1]), n - 1))
+    return tables, starts, caches
+
+
+async def request_loop(wave, table, tok, pos, rounds, declare=True, seen=None):
+    """``_generate``'s loop without a drafter: a round's token is on the host
+    before the next round is asked for."""
+    out = []
+    declared = wave.stream(table, rounds) if declare else contextlib.nullcontext()
+    with declared:
+        for _ in range(rounds):
+            rows = await asyncio.wait_for(wave.step_chunk([tok], [pos], table), 60)
+            ids = wave.token_ids(rows)
+            if seen is not None:
+                seen.append((wave.waves, wave.waves_ahead))
+            out.append((np.asarray(rows), int(ids[0])))
+            tok, pos = int(ids[0]), pos + 1
+    return out
+
+
+def streaming_decoder(cfg, params, caches):
+    h = bare_harness(cfg, params, caches)
+    h.arriving = 0
+    return WaveDecoder(h)
+
+
+def test_a_wave_launched_ahead_is_the_host_fed_wave_bit_for_bit(ahead_model):
+    """Three requests decode two blocks' worth of rounds in lockstep, once as
+    declared streams (every wave but the first launched while its requests
+    still read the wave before it, its tokens read on the device) and once as
+    bare ``step_chunk`` calls that carry the tokens up from the host: the same
+    waves, every logits row and id equal in every bit, and every cache tensor
+    (K/V pages, and the state and tail where the model keeps them) after."""
+    cfg, params = ahead_model
+    bt = cfg.block_tokens
+    rounds = 2 * bt
+    # Part-full last blocks, so the rounds cross block edges at different waves.
+    tables, starts, caches = landed_prompts(cfg, params, (2 * bt, bt + 3, 3 * bt - 2), seed=491)
+
+    async def run(declare):
+        wave = streaming_decoder(cfg, params, caches)
+        outs = await asyncio.gather(*(
+            request_loop(wave, tables[r], tok, pos, rounds, declare)
+            for r, (tok, pos) in enumerate(starts)
+        ))
+        return wave, outs
+
+    ahead, got = asyncio.run(run(True))
+    plain, want = asyncio.run(run(False))
+    assert (ahead.waves, ahead.launched_rows, ahead.pad_rows) == (rounds, 4 * rounds, rounds)
+    assert (plain.waves, plain.launched_rows, plain.bucket_sizes) == (
+        rounds, 4 * rounds, ahead.bucket_sizes
+    )
+    assert (ahead.waves_ahead, plain.waves_ahead) == (rounds - 1, 0)
+    assert ahead.blocking_reads == plain.blocking_reads == rounds
+    for r in range(3):
+        assert [tok for _, tok in got[r]] == [tok for _, tok in want[r]]
+        for k in range(rounds):
+            assert got[r][k][0].tobytes() == want[r][k][0].tobytes(), (r, k)
+    same_bits(ahead.h.caches, plain.h.caches, "caches")
+
+
+def test_the_fed_operand_reads_the_named_row_and_nothing_else(model):
+    """The program itself: a slot ``fed_token(src)`` is read as
+    ``prev_ids[src]``, a token slot is the token, and the wave is in every bit
+    the wave whose tokens the host wrote out."""
+    cfg, params = model
+    tables, chunks, caches = mixed_wave(cfg, params)
+    wave = WaveDecoder(bare_harness(cfg, params, caches))
+    batch = [(toks, pos, tables[r], None) for r, (toks, pos) in enumerate(chunks)]
+    w = wave._assemble(batch)
+    prev = np.zeros(serving.FEED_ROWS, np.int32)
+    prev[[0, 5, serving.FEED_ROWS - 1]] = w.tokens[0], w.tokens[4], w.tokens[2]
+    slots = list(w.tokens)
+    slots[0], slots[2] = serving.fed_token(0), serving.fed_token(serving.FEED_ROWS - 1)
+    slots[4:] = [serving.fed_token(5)] * (len(slots) - 4)  # the last real row and its repeats
+    want = wave.launch(w.tokens, w.positions, w.row_of, w.meta, w.tables, w.wmeta)
+    want_caches = wave.h.caches
+    wave.h.caches = jax.tree.map(jnp.copy, caches)
+    got = wave.launch(slots, w.positions, w.row_of, w.meta, w.tables, w.wmeta, jnp.asarray(prev))
+    same_bits(got[0], want[0], "logits")
+    same_bits((got[1].ids, got[1].feed), (want[1].ids, want[1].feed), "ids")
+    same_bits(wave.h.caches, want_caches, "caches")
+    with pytest.raises(ValueError):
+        serving.fed_token(serving.FEED_ROWS)
+
+
+def test_a_last_round_launches_no_row_and_a_drafter_launches_none_ahead(conn, model):
+    """Through the harness: a lone request's every round but the first rides a
+    wave launched ahead, no row is launched that no round takes (a closing
+    step is a round), and under a drafter nothing is launched ahead at all."""
+    cfg, params = model
+    bt = cfg.block_tokens
+    (p,) = prompts(cfg, 1, 2, seed=492)
+    for gen, rows in ((5, 5), (bt, bt + 1)):
+        h = harness(conn, cfg, params, f"ahead-last-{type(cfg).__name__}-{gen}")
+        asyncio.run(h.run_request(p, gen_tokens=gen))
+        m = h.metrics()
+        assert h.wave.launched_rows - h.wave.pad_rows == rows == m["decode_waves"]
+        assert m["wave_ahead_waves"] == rows - 1
+        assert h.arriving == 0 and not h.wave._streams
+    h = harness(
+        conn, cfg, params, f"ahead-drafter-{type(cfg).__name__}", drafter=NGramDrafter(max_draft=3)
+    )
+    repetitive = (p[:4] * (2 * bt))[: 2 * bt]
+    asyncio.run(h.run_request(repetitive, gen_tokens=6))
+    m = h.metrics()
+    assert "wave_ahead_waves" in m and m["wave_ahead_waves"] == 0
+    assert m["spec_drafted_tokens"] > 0
+
+
+def test_an_arriving_request_holds_the_launch_and_its_first_wave_carries_everyone():
+    """While the harness counts a request between its admission and its first
+    wave, a stream's waves are launched as they always were (its request back
+    with the token first). The newcomer's first wave carries the stream's row
+    too, and from the next wave on both are launched ahead, in ONE wave."""
+    cfg, init = MODELS["llama"]
+    params = init(cfg)
+    bt = cfg.block_tokens
+    tables, starts, caches = landed_prompts(cfg, params, (2 * bt, 2 * bt), seed=493)
+
+    async def run():
+        wave = streaming_decoder(cfg, params, caches)
+        h, seen, late = wave.h, [], []
+
+        async def newcomer():
+            h.arriving += 1  # admitted: it will want the device for its prefill
+            while wave.waves < 3:
+                await asyncio.sleep(0)
+            held = (wave.waves, wave.waves_ahead)
+            h.arriving -= 1  # its first round is next
+            await request_loop(wave, tables[1], *starts[1], 6, seen=late)
+            return held
+
+        first = asyncio.ensure_future(request_loop(wave, tables[0], *starts[0], 12, seen=seen))
+        held = await newcomer()
+        await first
+        return wave, held, seen, late
+
+    wave, held, seen, late = asyncio.run(run())
+    assert held[0] >= 3 and held[1] == 0, "a wave was launched ahead in front of an arrival"
+    # The newcomer's first wave is the next one, both rows the host's; when its
+    # first token is read the wave after that is on the device, both rows fed.
+    assert late[0] == (held[0] + 2, 1)
+    # 12 + 6 rounds in 12 waves: no stream rode a wave of its own.
+    assert (wave.waves, wave.max_wave, wave.launched_rows - wave.pad_rows) == (12, 2, 18)
+    assert wave.waves_ahead == 12 - (held[0] + 1)
+
+
+def test_a_bare_step_chunk_is_launched_as_before_and_rides_beside_a_stream():
+    """Bare calls (the benchmark's warm-up, a test, a drafter's chunk) resolve
+    as they always did, one wave a call; beside a stream they ride its waves,
+    their tokens the host's while the stream's come from the device."""
+    cfg, init = MODELS["llama"]
+    params = init(cfg)
+    bt = cfg.block_tokens
+    tables, starts, caches = landed_prompts(cfg, params, (2 * bt, 2 * bt), seed=494)
+
+    async def run():
+        wave = streaming_decoder(cfg, params, caches)
+        for k in range(3):
+            await wave.step_chunk([7 + k], [2 * bt + k], tables[1])
+        alone = (wave.waves, wave.waves_ahead, wave.max_wave)
+        stream = asyncio.ensure_future(request_loop(wave, tables[0], *starts[0], 8))
+        for k in range(3, 6):
+            await wave.step_chunk([7 + k, 8 + k], [2 * bt + k, 2 * bt + k + 1], tables[1])
+        await stream
+        return wave, alone
+
+    wave, alone = asyncio.run(run())
+    assert alone == (3, 0, 1)
+    assert wave.max_wave == 2, "the bare chunks rode waves of their own"
+    assert wave.waves_ahead >= 6
+
+
+def test_a_stream_that_never_comes_back_strands_nobody():
+    """One of two streams stops after its second round (its request was
+    cancelled): the row launched ahead for it is never taken, the other stream
+    runs to its end, and nothing more is launched for the one that left."""
+    cfg, init = MODELS["llama"]
+    params = init(cfg)
+    bt = cfg.block_tokens
+    tables, starts, caches = landed_prompts(cfg, params, (2 * bt, 2 * bt), seed=495)
+
+    async def run():
+        wave = streaming_decoder(cfg, params, caches)
+
+        async def leaves():
+            (tok, pos), table = starts[1], tables[1]
+            with pytest.raises(asyncio.CancelledError):
+                with wave.stream(table, 10):
+                    for _ in range(2):
+                        rows = await wave.step_chunk([tok], [pos], table)
+                        tok, pos = int(wave.token_ids(rows)[0]), pos + 1
+                    raise asyncio.CancelledError
+
+        stays, _ = await asyncio.gather(request_loop(wave, tables[0], *starts[0], 10), leaves())
+        return wave, stays
+
+    wave, stays = asyncio.run(run())
+    assert len(stays) == 10 and not wave._streams and not wave._pending
+    # Ten rows of the one that stayed; two taken and one launched ahead of the other.
+    assert wave.launched_rows - wave.pad_rows == 13
+
+
+def test_a_dead_flush_fails_every_waiter_ahead_or_not(monkeypatch):
+    """The program raises at its fourth launch: the stream whose row that wave
+    would have carried and the bare call beside it both get the error, no
+    future is left pending, and the decoder launches again afterwards."""
+    cfg, init = MODELS["llama"]
+    params = init(cfg)
+    bt = cfg.block_tokens
+    tables, starts, caches = landed_prompts(cfg, params, (2 * bt, 2 * bt), seed=496)
+    real, calls = engine_mod.verify_step_ragged, []
+
+    def dies_once(*args, **kw):
+        calls.append(1)
+        if len(calls) == 4:
+            raise RuntimeError("the device said no")
+        return real(*args, **kw)
+
+    monkeypatch.setattr(engine_mod, "verify_step_ragged", dies_once)
+
+    async def run():
+        wave = streaming_decoder(cfg, params, caches)
+
+        async def bare():
+            while len(calls) < 3:
+                await asyncio.sleep(0)
+            return await wave.step_chunk([9], [2 * bt], tables[1])
+
+        got = await asyncio.gather(
+            request_loop(wave, tables[0], *starts[0], 10), bare(), return_exceptions=True
+        )
+        assert not wave._pending and not wave._streams and not wave._flush_scheduled
+        again = await request_loop(wave, tables[0], *starts[0], 3)
+        return got, again
+
+    got, again = asyncio.run(run())
+    assert all(isinstance(e, RuntimeError) and "said no" in str(e) for e in got), got
+    assert len(again) == 3
+
+
+def test_a_stream_that_comes_back_elsewhere_is_an_error():
+    """A declared stream's call that does not fit the row launched for it (a
+    position that is not the next one) is refused, not served from the host."""
+    cfg, init = MODELS["llama"]
+    params = init(cfg)
+    bt = cfg.block_tokens
+    tables, starts, caches = landed_prompts(cfg, params, (2 * bt,), seed=497)
+
+    async def run():
+        wave = streaming_decoder(cfg, params, caches)
+        (tok, pos), table = starts[0], tables[0]  # the stream is known by the table OBJECT
+        with wave.stream(table, 5):
+            rows = await wave.step_chunk([tok], [pos], table)
+            tok = int(wave.token_ids(rows)[0])
+            with pytest.raises(RuntimeError, match="launched for one token at"):
+                await wave.step_chunk([tok], [pos + 2], table)
+
+    asyncio.run(run())
+
+
+def test_the_counter_is_in_the_harness_metrics(conn):
+    cfg, init = MODELS["llama"]
+    h = harness(conn, cfg, init(cfg), "ahead-metric")
+    assert h.metrics()["wave_ahead_waves"] == 0
+    ps = prompts(cfg, 3, 2, seed=498)
+    m = asyncio.run(h.run(ps, concurrency=3, gen_tokens=6))
+    assert 0 < m["wave_ahead_waves"] < m["decode_waves"]
+    assert m["wave_host_transfers"] == 2 * m["decode_waves"]

@@ -90,8 +90,8 @@ def main() -> int:
         t0 = time.perf_counter()
         layout = serving.WaveLayout(rows=rows, tables=rows, pages=pages)
         traced = serving.verify_step_ragged.trace(
-            params, i32(layout.size(mrb)), caches, config=cfg, max_blocks=mrb,
-            layout=layout,
+            params, i32(layout.size(mrb)), i32(serving.FEED_ROWS), caches, config=cfg,
+            max_blocks=mrb, layout=layout,
         )
         t1 = time.perf_counter()
         lowered = traced.lower()
