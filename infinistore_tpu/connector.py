@@ -331,7 +331,9 @@ class KVConnector:
         # store values (one block of one tensor of one layer) fetched, and
         # what every block of every layer of the same hits would have been
         # (they differ where a tensor's policy is the hit's trailing blocks,
-        # CacheTensor.last_blocks), the same two in bytes and, of the bytes
+        # CacheTensor.last_blocks; the values fetched under that policy are
+        # counted apart: the part of a hit that does not grow with the
+        # prefix), the same two in bytes and, of the bytes
         # fetched, those of a recurrent state (kind "state": what does not
         # grow with the prefix); of the saves, the bytes written and those
         # of them by the tensor's kind (kv, state, latent); the bytes the
@@ -350,6 +352,7 @@ class KVConnector:
         # flight (``save_puts_in_flight`` and ``save_put_busy_mark_s`` keep it).
         self.hit_counters = {
             "hit_values_fetched": 0, "hit_values_whole_prefix": 0,
+            "hit_window_values_fetched": 0,
             "hit_bytes_fetched": 0, "hit_bytes_whole_prefix": 0,
             "hit_state_bytes_fetched": 0,
             "save_bytes": 0, "save_kv_bytes": 0,
@@ -1000,7 +1003,9 @@ class KVConnector:
         """The store connection's per-op stats snapshot (observability
         surface composed members re-expose — cluster.py stats()), with this
         connector's ledger of the hop beside it (``hit_counters``):
-        ``hit_values_fetched`` and ``hit_values_whole_prefix``;
+        ``hit_values_fetched`` and ``hit_values_whole_prefix``, and of the
+        first ``hit_window_values_fetched`` (a sliding layer's K and V, a
+        state: tensors a hit installs in its trailing blocks only);
         ``hit_read_bytes`` over ``hit_read_busy_us`` (the union of the time
         in which a hit's layer read was in flight; ``hit_reads_in_flight``
         and ``hit_read_busy_mark_s`` keep it), the store's delivered rate;

@@ -671,7 +671,9 @@ class LayerwisePrefetch:
         (the gate bounds when to START, the retry rides any residual race).
         ``counters``: the connector's own ledger (``KVConnector.hit_counters``,
         every key of it); every layer that lands adds the store values it
-        fetched to ``hit_values_fetched``, what every block of that layer
+        fetched to ``hit_values_fetched`` (those of tensors a hit installs in
+        its trailing blocks only, a sliding layer's K and V or a state, to
+        ``hit_window_values_fetched`` too), what every block of that layer
         would have been to ``hit_values_whole_prefix`` and its bytes to
         ``hit_read_bytes``; ``hit_read_busy_us`` is the time in which at
         least one layer read was in flight (``_busy_step``), and an
@@ -880,6 +882,9 @@ class LayerwisePrefetch:
                 whole = self.n_blocks * sum(t.nbytes for t, _, _, _ in plan)
                 counters["hit_values_fetched"] += values
                 counters["hit_values_whole_prefix"] += self.n_blocks * len(plan)
+                counters["hit_window_values_fetched"] += sum(
+                    m for t, _, m, _ in plan if t.last_blocks is not None
+                )
                 counters["hit_read_bytes"] += nbytes
                 counters["hit_bytes_fetched"] += nbytes
                 counters["hit_bytes_whole_prefix"] += whole

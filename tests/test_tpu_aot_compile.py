@@ -18,6 +18,8 @@ prefix hit also the benchmark's two reuse cells (``RESUMES``).
 """
 
 import functools
+import json
+import os
 import re
 
 import jax
@@ -660,6 +662,83 @@ def test_afmoe_entries_compile_and_update_the_cache_in_place(v5e, monkeypatch, e
     header = text.split("\n", 1)[0]
     assert len(re.findall(r"\(\d+, \{\}, (?:may|must)-alias\)", header)) == tensors, header
     assert exe.memory_analysis().alias_size_in_bytes == tensors * int(np.prod(cache.shape)) * 2
+    want = {
+        "verify_step_ragged": {"_ragged_attn_kernel", "_moe_wave_kernel"},
+        "packed_wave": {"_ragged_attn_kernel", "_moe_wave_kernel"},
+        "resume_chunk": {"_chunk_attn_kernel"},
+        "prefill": {"_flash_kernel"},
+    }[entry]
+    assert want <= kernels, kernels
+    if entry not in ("verify_step_ragged", "packed_wave"):
+        assert kernels - want, kernels  # the grouped matmul's
+
+
+# The sixth model file's serving entries (models/mellum.py) at the published
+# widths of its configuration's file: hidden 2,304, 32 q / 4 kv heads x 128,
+# 64 experts of width 896 = 7 x 128 (the wave kernel takes it whole, the
+# grouped matmul tiles it), window 1,024, 16-token blocks, both rotations with
+# every published constant; one period of the layer pattern and a small
+# vocabulary, so that the four compile in a minute.
+MELLUM_ENTRIES = ["verify_step_ragged", "packed_wave", "resume_chunk", "prefill"]
+
+
+@pytest.mark.parametrize("entry", MELLUM_ENTRIES)
+def test_mellum_entries_compile_at_published_widths_and_update_the_cache_in_place(v5e, monkeypatch, entry):
+    """Each entry compiles for the v5e with its Mosaic kernels, holds an
+    ``input_output_alias`` for EVERY cache tensor (the aliased bytes the whole
+    cache's), and moves no array of a layer's K or V shape, or of a quarter
+    of it, through a ``copy``, ``copy-start`` or ``slice-start``."""
+    from infinistore_tpu.models import mellum, serving
+
+    monkeypatch.setattr(paged, "_use_pallas", lambda: True)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "benchmarks", "configs", "mellum2-12b-a2.5b.json")) as f:
+        real = json.load(f)
+    fields = {k: real[v] for k, v in real["program"]["fields"].items()}
+    fields.update(
+        vocab=1031 if entry != "packed_wave" else 1033, layer_types=real["layer_types"][:4]
+    )
+    cfg = mellum.MellumConfig(block_tokens=16, dtype=jnp.bfloat16, **fields)
+    assert (cfg.dim, cfg.moe_ffn_dim, cfg.n_experts, cfg.sliding_window) == (2304, 896, 64, 1024)
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=v5e)
+    i32 = lambda *shape: s(shape, jnp.int32)
+    shapes = jax.eval_shape(lambda k: mellum.init_params(cfg, k), jax.random.key(0))
+    params = jax.tree.map(lambda a: s(a.shape, a.dtype), shapes)
+    blocks = real["serving"]["cache_blocks"]  # 6,656: a small cache XLA stages through VMEM in quarters
+    cache = s(cfg.kv_spec(blocks).cache_shape, cfg.dtype)
+    caches = [(cache, cache)] * cfg.n_layers
+    if entry == "verify_step_ragged":
+        rows, pages, table = 4, 2048, 528
+        args = (
+            params, i32(rows), i32(rows), i32(rows), i32(pages), i32(pages + 1), i32(rows),
+            caches, i32(rows, table),
+        )
+        windowed = (i32(rows * 65), i32(rows * 65 + 1), i32(rows))
+        static = {"config": cfg, "max_blocks": table, "window_pages": windowed}
+    elif entry == "packed_wave":  # the same bucket as the decoder launches it
+        layout = serving.WaveLayout(rows=4, tables=4, pages=2048, window_pages=4 * 65)
+        args = (params, i32(layout.size(528)), i32(serving.FEED_ROWS), caches)
+        static = {"config": cfg, "max_blocks": 528, "layout": layout}
+    elif entry == "resume_chunk":
+        args, static = (params, i32(128), i32(), caches, i32(528)), {"config": cfg}
+    else:
+        args, static = (params, i32(8320), caches, i32(520)), {"config": cfg}
+    jitted = serving.verify_step_ragged if entry == "packed_wave" else getattr(mellum, entry)
+    lowered = jitted.trace(*args, **static).lower(lowering_platforms=("tpu",))
+    kernels = set(re.findall(r'kernel_name = "(\w+)"', lowered.as_text()))
+    exe = lowered.compile()
+    text = exe.as_text()
+    tensors = 2 * cfg.n_layers
+    header = text.split("\n", 1)[0]
+    assert len(re.findall(r"\(\d+, \{\}, (?:may|must)-alias\)", header)) == tensors, header
+    assert exe.memory_analysis().alias_size_in_bytes == tensors * int(np.prod(cache.shape)) * 2
+    whole = ",".join(map(str, cache.shape))
+    quarter = ",".join(map(str, (blocks // 4, *cache.shape[1:])))
+    moved = re.findall(
+        rf"^.* = [^=]*bf16\[(?:{whole}|{quarter})\][^=]* (?:copy|copy-start|slice-start)\(.*$",
+        text, flags=re.M,
+    )
+    assert not moved, moved[:3]
     want = {
         "verify_step_ragged": {"_ragged_attn_kernel", "_moe_wave_kernel"},
         "packed_wave": {"_ragged_attn_kernel", "_moe_wave_kernel"},
