@@ -29,7 +29,10 @@ absent chips. Two shapes of the one mathematics:
 - many tokens (``prefill``, ``resume_chunk``): the (token, expert) pairs
   sorted by expert and ONE grouped matrix product a projection
   (``_grouped_ffn``: the Pallas grouped matmul on the chip, ``ragged_dot``
-  elsewhere); no token dropped, no capacity factor;
+  elsewhere); no token dropped, no capacity factor. Its tiles follow the
+  product's widths (``_gmm_tiling``): 128 rows, because a group pays for
+  every row tile it touches whole, and K whole, so that a group's weights
+  are fetched once however many row tiles it spans;
 - few rows (a wave): the wave's DISTINCT chosen experts' weights streamed
   once each through one kernel (``_moe_wave_pallas``: the scalar-prefetched
   expert ids drive the weight blocks' index maps), every row multiplied by
@@ -80,6 +83,10 @@ _VMEM_LIMIT = 64 << 20
 # tiles of it; else the width whole (768 = 6 x 128: one contiguous block an
 # expert and projection).
 _MOE_WAVE_F_TILE = 512
+# The grouped product's row tile and the most elements of a weight tile
+# (``_gmm_tiling``): 4.5 MiB in bfloat16, twice over in VMEM's 16 MiB.
+_GMM_ROW_TILE = 128
+_GMM_WEIGHT_TILE = 2304 * 1024
 
 
 @dataclass(frozen=True)
@@ -393,16 +400,42 @@ def moe_wave_xla(m, slots, combine, w_gate, w_up, w_down):
     return jnp.einsum("stf,sfd->td", h, jnp.take(w_down, slots, axis=0), preferred_element_type=f32)
 
 
+def _lane_tile(width: int, most: int) -> int:
+    """A K or N tile of a grouped product over ``width``: the width whole
+    where it is at most ``most``, else the largest multiple of 128 lanes that
+    DIVIDES it (2,304 under 2,047: 1,152, no last tile a quarter full and
+    masked), else 1,024 with a ragged last tile."""
+    if width <= most:
+        return width
+    whole = [t for t in range(128, most + 1, 128) if width % t == 0]
+    return whole[-1] if whole else 1024
+
+
+def _gmm_tiling(k: int, n: int) -> Tuple[int, int, int]:
+    """The ``(tm, tk, tn)`` handed to the Pallas grouped matmul, from the
+    product's two widths alone (tools/gmm_tile_probe.py is the sweep behind
+    it). The grid visits a group once a row tile it touches, one whole ``tm x
+    tk x tn`` pass a step, and fetches an operand's tile only when its index
+    moves. So: rows of ``_GMM_ROW_TILE``, the matrix unit's, because a group
+    of 4 to 32 rows pays for the whole tile; K WHOLE, so that the steps of
+    one group share one weight tile however many row tiles the group spans
+    (cut K and every visit reads the weights again; a K past 9,216, which no
+    configuration has, is cut as before the rule); N as wide as keeps the
+    weight tile within ``_GMM_WEIGHT_TILE`` elements of VMEM."""
+    tk = k if k <= _GMM_WEIGHT_TILE // 256 else _lane_tile(k, 1024)
+    return _GMM_ROW_TILE, tk, _lane_tile(n, _GMM_WEIGHT_TILE // tk)
+
+
 def _grouped_matmul(lhs, rhs, group_sizes, out_dtype):
-    """lhs [M, K] sorted by group, rhs [G, K, N], group_sizes [G] (their sum
-    may fall short of M: the rows past it are nobody's). One grouped matrix
-    product: the Pallas grouped matmul on the chip, ``ragged_dot`` elsewhere."""
+    """lhs [M, K] sorted by group, M whole row tiles, rhs [G, K, N],
+    group_sizes [G] (their sum may fall short of M: the rows past it are
+    nobody's and cost nothing). One grouped matrix product: the Pallas
+    grouped matmul (megablox ``gmm``) on the chip, in 128-row tiles with K
+    whole (``_gmm_tiling``), ``ragged_dot`` elsewhere."""
     if paged._use_pallas():
         from jax.experimental.pallas.ops.tpu.megablox import gmm
 
-        m, k = lhs.shape
-        n = rhs.shape[2]
-        tiling = (512 if m % 512 == 0 else 128, min(k, 1024), min(n, 1024))
+        tiling = _gmm_tiling(lhs.shape[1], rhs.shape[2])
         return gmm(lhs, rhs, group_sizes, preferred_element_type=out_dtype, tiling=tiling)
     return jax.lax.ragged_dot(
         lhs, rhs, group_sizes, preferred_element_type=out_dtype
@@ -424,7 +457,7 @@ def _grouped_ffn(m, ids, weights, w: Params, config: AfmoeConfig):
     token = order // k
     group_sizes = jnp.bincount(order_key, length=config.n_experts)[:count].astype(jnp.int32)
     rows = jnp.take(m, token, axis=0)  # [T * k, dim]
-    pad = -rows.shape[0] % 128
+    pad = -rows.shape[0] % _GMM_ROW_TILE
     if pad:
         rows = jnp.pad(rows, ((0, pad), (0, 0)))
     with jax.named_scope("afmoe_grouped_product"):

@@ -284,6 +284,104 @@ def test_the_wave_kernel_streams_the_distinct_experts():
 
 
 # ---------------------------------------------------------------------------
+# The grouped product's tiles.
+# ---------------------------------------------------------------------------
+
+# (a product of the benchmark's traffic, its (token, expert) pairs, the rows a
+# group can expect of them, the model's width, an expert's width, the tiles of
+# gate / up and of down)
+GROUPED_PRODUCTS = [
+    ("kimi-miss-piece", 8192, 32, 2304, 1024, (128, 2304, 1024), (128, 1024, 2304)),
+    ("kimi-hit-question", 1024, 4, 2304, 1024, (128, 2304, 1024), (128, 1024, 2304)),
+    ("mellum-hit-question", 1024, 16, 2304, 896, (128, 2304, 896), (128, 896, 2304)),
+    ("mellum-miss-chunk", 33792, 528, 2304, 896, (128, 2304, 896), (128, 896, 2304)),
+    ("granite-miss-piece", 20480, 284, 4096, 768, (128, 4096, 384), (128, 768, 2048)),
+    ("trinity-hit-question", 1024, 8, 2048, 1024, (128, 2048, 1024), (128, 1024, 2048)),
+    ("trinity-miss-chunk", 44032, 344, 2048, 1024, (128, 2048, 1024), (128, 1024, 2048)),
+]
+
+
+@pytest.mark.parametrize("case", GROUPED_PRODUCTS, ids=[c[0] for c in GROUPED_PRODUCTS])
+def test_the_grouped_products_tiles_are_a_function_of_its_widths(case):
+    """``_gmm_tiling`` at the seven products of the benchmark's routed cells:
+    128 rows whatever a group can expect (4 to 528: the sweep found no shape
+    a larger tile serves once K is whole; ``_grouped_ffn`` pads the pairs to
+    it); K whole; the N tile a whole divisor of its width (a multiple of 128
+    lanes) that keeps the weight tile within 2,304 x 1,024 elements."""
+    _, _pairs, _rows_a_group, dim, width, up, down = case
+    assert afmoe._gmm_tiling(dim, width) == up and afmoe._gmm_tiling(width, dim) == down
+    for (tm, tk, tn), (k, n) in ((up, (dim, width)), (down, (width, dim))):
+        assert tm == 128 and tk == k and n % tn == 0 and tn % 128 == 0
+        assert tk * tn <= 2304 * 1024 and (tn == n or tk * tn * 2 > 2304 * 1024)
+
+
+def test_the_tile_rule_keeps_a_small_width_whole_and_cuts_an_odd_one_at_1024():
+    """Widths of the tests' size stay one tile; a K no multiple of 128
+    divides stays whole up to 9,216 and beyond it is cut in 1,024s, the last
+    tile ragged where they do not divide it, as before the rule; 2,304 is
+    cut in whole halves or thirds, never at 1,024."""
+    assert afmoe._gmm_tiling(64, 32) == (128, 64, 32)
+    assert afmoe._gmm_tiling(1100, 2304) == (128, 1100, 1152)
+    assert afmoe._gmm_tiling(9216, 2048) == (128, 9216, 256)
+    assert afmoe._gmm_tiling(18432, 1024) == afmoe._gmm_tiling(20000, 1024) == (128, 1024, 1024)
+    assert afmoe._lane_tile(2304, 1024) == 768 and afmoe._lane_tile(2304, 2303) == 1152
+
+
+@pytest.mark.parametrize(
+    "sizes,k,n",
+    [
+        ((40, 0, 7, 100), 2304, 256),  # short of M, an empty group, 7 rows of a 128-row tile
+        ((3, 130, 0, 120), 256, 2304),  # the down product's widths; a group over a tile's edge
+        ((300, 0, 150, 61), 4096, 768),  # the N tile half the width; a group of three tiles
+    ],
+    ids=["gate-up", "down", "two-n-tiles"],
+)
+def test_gmm_under_the_rules_tiles_against_ragged_dot(sizes, k, n):
+    """The Pallas grouped matmul (interpret mode) under ``_gmm_tiling``'s
+    triple against ``ragged_dot``: group sizes that sum short of M (the rows
+    past them are nobody's), an empty group, groups smaller than the tile."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    rng = np.random.default_rng(51)
+    real = sum(sizes)
+    tiling = afmoe._gmm_tiling(k, n)
+    m = real + -real % tiling[0] + tiling[0]  # a whole tile of nobody's rows
+    assert tiling[1] == k and n % tiling[2] == 0
+    f = lambda *s: jnp.asarray(rng.standard_normal(s) / 8, jnp.float32).astype(jnp.bfloat16)
+    lhs, rhs, group_sizes = f(m, k), f(len(sizes), k, n), jnp.asarray(sizes, jnp.int32)
+    got = gmm(lhs, rhs, group_sizes, preferred_element_type=jnp.float32, tiling=tiling, interpret=True)
+    want = jax.lax.ragged_dot(lhs, rhs, group_sizes, preferred_element_type=jnp.float32)
+    assert got.dtype == jnp.float32
+    np.testing.assert_allclose(got[:real], want[:real], atol=2e-3, rtol=2e-3)
+
+
+@pytest.mark.parametrize("tokens", [40, 200])
+def test_the_grouped_ffn_pads_its_pairs_to_the_row_tile(monkeypatch, tokens):
+    """``_grouped_ffn`` on its Pallas branch (the grouped matmul in interpret
+    mode) equals its ``ragged_dot`` branch where the pairs are no whole row
+    tiles: 80 pairs (one tile, most of it nobody's) and 400 (four)."""
+    from jax.experimental.pallas.ops.tpu import megablox
+
+    from infinistore_tpu.tpu import paged
+
+    w = _layer(afmoe.init_params(CFG, jax.random.key(7)))
+    m = jax.random.normal(jax.random.key(tokens), (tokens, CFG.dim), jnp.float32)
+    ids, weights = afmoe.route(m, w["router"], w.get("router_bias"), CFG)
+    want = afmoe._grouped_ffn(m, ids, weights, w, CFG)
+    seen, gmm = [], megablox.gmm
+
+    def interpreted(lhs, rhs, group_sizes, **kw):
+        seen.append((lhs.shape[0], kw["tiling"]))
+        return gmm(lhs, rhs, group_sizes, interpret=True, **kw)
+
+    monkeypatch.setattr(megablox, "gmm", interpreted)
+    monkeypatch.setattr(paged, "_use_pallas", lambda: True)
+    got = afmoe._grouped_ffn(m, ids, weights, w, CFG)
+    assert len(seen) == 3 and all(t[0] == 128 and rows % 128 == 0 for rows, t in seen), seen
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=0)
+
+
+# ---------------------------------------------------------------------------
 # The windowed kernels.
 # ---------------------------------------------------------------------------
 

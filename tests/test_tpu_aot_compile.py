@@ -748,3 +748,63 @@ def test_mellum_entries_compile_at_published_widths_and_update_the_cache_in_plac
     assert want <= kernels, kernels
     if entry not in ("verify_step_ragged", "packed_wave"):
         assert kernels - want, kernels  # the grouped matmul's
+
+
+# The grouped expert product under the tiles its rule hands it (models/afmoe.py
+# ``_gmm_tiling``), at the published widths of the routed configurations'
+# files and the token counts the benchmark's traffic gives them: one expert
+# layer's three products alone, so that each compiles in seconds.
+GROUPED_FFNS = [
+    ("kimi-linear-48b-a3b", "miss-piece", 1024),
+    ("kimi-linear-48b-a3b", "hit-question", 128),
+    ("mellum2-12b-a2.5b", "hit-question", 128),
+    ("mellum2-12b-a2.5b", "miss-chunk", 4224),
+    ("granite-4.0-h-small", "miss-piece", 2048),
+    ("trinity-mini", "miss-chunk", 5504),
+]
+
+
+@pytest.mark.parametrize("case", GROUPED_FFNS, ids=[f"{c[0]}-{c[1]}" for c in GROUPED_FFNS])
+def test_grouped_ffn_compiles_under_its_tile_rule(v5e, monkeypatch, case):
+    """``_grouped_ffn`` compiles for the v5e with three grouped matmuls whose
+    tiles are whole divisors of the padded pair count and of both widths, K
+    whole (VMEM holds them: the compiler refuses a triple it cannot place)."""
+    import importlib
+
+    from jax.experimental.pallas.ops.tpu import megablox
+
+    from infinistore_tpu.models import afmoe
+
+    name, _, tokens = case
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "benchmarks", "configs", f"{name}.json")) as f:
+        real = json.load(f)
+    module, _, cls = real["program"]["config_class"].partition(":")
+    fields = {k: real[v] for k, v in real["program"]["fields"].items()}
+    cfg = getattr(importlib.import_module(module), cls)(
+        block_tokens=real["serving"]["block_tokens"], dtype=jnp.bfloat16, **fields
+    )
+    seen, gmm = [], megablox.gmm
+
+    def recorded(lhs, rhs, group_sizes, **kw):
+        seen.append((lhs.shape, rhs.shape, kw["tiling"]))
+        return gmm(lhs, rhs, group_sizes, **kw)
+
+    monkeypatch.setattr(megablox, "gmm", recorded)
+    monkeypatch.setattr(paged, "_use_pallas", lambda: True)
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=v5e)
+    k, held = cfg.experts_per_token, cfg.held[1]
+    w = {
+        "w_gate": s((held, cfg.dim, cfg.moe_ffn_dim), jnp.bfloat16),
+        "w_up": s((held, cfg.dim, cfg.moe_ffn_dim), jnp.bfloat16),
+        "w_down_moe": s((held, cfg.moe_ffn_dim, cfg.dim), jnp.bfloat16),
+    }
+    exe = _compile(
+        jax.jit(afmoe._grouped_ffn, static_argnames=("config",)),
+        s((tokens, cfg.dim), jnp.bfloat16), s((tokens, k), jnp.int32),
+        s((tokens, k), jnp.float32), w, config=cfg,
+    )
+    assert exe.as_text().count("tpu_custom_call") >= 3
+    assert len(seen) == 3
+    for (m, kk), (_, _, n), (tm, tk, tn) in seen:
+        assert tm == 128 and m % tm == 0 and kk == tk and n % tn == 0, seen
