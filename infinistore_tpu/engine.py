@@ -106,6 +106,41 @@ class BlockPool:
             self._cond.notify_all()
 
 
+# Who may hold the device gate, and so who a wait stood behind: the one
+# vocabulary of ``DeviceGate``'s ledger, its ``gate_*`` keys in
+# ``ContinuousBatchingHarness.metrics()`` and the ``gate_wait`` span's
+# ``holder`` / ``behind_us`` (docs/observability.md, "Inside the engine").
+GATE_HOLDERS = ("wave", "prefill", "resume", "install", "snapshot", "verify")
+# What is left of a wait once every holder's part is taken out: the gate was
+# nobody's and the waiter had not yet been woken.
+GATE_FREE = "free"
+
+
+def _gate_now_us() -> int:
+    """The gate's one clock: ``perf_counter`` in whole microseconds, so that
+    holds and waits add up exactly."""
+    return time.perf_counter_ns() // 1000
+
+
+class GateHold:
+    """What one acquisition of the gate measured, handed to the ``async
+    with`` body: ``asked_us`` (the gate's clock at entry; ``asked_s`` the
+    same as a ``perf_counter`` reading) and ``waited_us`` (entry to
+    acquired) from the start, ``held_us`` (acquired to released) once the
+    body is left."""
+
+    __slots__ = ("asked_us", "waited_us", "held_us")
+
+    def __init__(self, asked_us: int, waited_us: int):
+        self.asked_us = asked_us
+        self.waited_us = waited_us
+        self.held_us = 0
+
+    @property
+    def asked_s(self) -> float:
+        return self.asked_us / 1e6
+
+
 class DeviceGate:
     """Reader-writer discipline over the shared paged cache.
 
@@ -116,7 +151,21 @@ class DeviceGate:
     would be lost (or a donated buffer would be read).
     Shared: gather-only phases (save snapshots, verification reads) — they
     overlap each other freely and are over in microseconds, after which the
-    actual store I/O runs with no gate held at all."""
+    actual store I/O runs with no gate held at all.
+
+    The gate keeps a ledger of who held it, always on. Every acquisition
+    names its ``holder`` (one of ``GATE_HOLDERS``). ``held_us[kind]`` /
+    ``holds[kind]``: how long that kind has held the gate, and how many holds
+    it has ended. A stretch of shared holds, from the first one in to the last
+    one out, is ONE hold's time however many overlap, under the kind that
+    opened it. A waiter reads the ledger when it starts to wait and when it
+    acquires: the difference, kind by kind, is how long it stood behind each,
+    and what is left of its wait is ``GATE_FREE``. ``wait_us[waiter][behind]``
+    adds those up and ``waits[waiter]`` counts them, so a waiter's parts and
+    ``free`` make its whole wait to the microsecond. A cancelled wait adds
+    nothing. With the recorder on, the ``gate_wait`` span carries the same
+    for its one wait: ``holder``, ``behind_us`` (the parts that are not 0)
+    and, written at release, ``held_us``."""
 
     def __init__(self):
         self._cond = asyncio.Condition()
@@ -135,6 +184,16 @@ class DeviceGate:
         # path's latency at install cost. No starvation in practice: each
         # admission expedites at most once, so the lane drains.
         self._expedite_waiting = 0
+        # The ledger (class docstring). ``_holding``: the kind the time since
+        # ``_since_us`` goes to, None while the gate is nobody's.
+        self.held_us: Dict[str, int] = dict.fromkeys(GATE_HOLDERS, 0)
+        self.holds: Dict[str, int] = dict.fromkeys(GATE_HOLDERS, 0)
+        self.waits: Dict[str, int] = dict.fromkeys(GATE_HOLDERS, 0)
+        self.wait_us: Dict[str, Dict[str, int]] = {
+            waiter: dict.fromkeys(GATE_HOLDERS + (GATE_FREE,), 0) for waiter in GATE_HOLDERS
+        }
+        self._holding: Optional[str] = None
+        self._since_us = 0
 
     @property
     def idle(self) -> bool:
@@ -143,22 +202,103 @@ class DeviceGate:
         other phase's way."""
         return not self._exclusive and self._exclusive_waiting == 0 and self._shared == 0
 
-    @asynccontextmanager
-    async def exclusive(self, expedite: bool = False):
-        # `gate_wait`: entry to acquired, whoever asked is its parent.
+    def counters(self) -> Dict[str, int]:
+        """The ledger under flat keys, plain monotone counters (microseconds
+        and counts): ``gate_held_us_<kind>``, ``gate_holds_<kind>``,
+        ``gate_waits_<waiter>``, ``gate_wait_us_<waiter>`` (the whole) and
+        ``gate_wait_us_<waiter>_behind_<kind>``, ``<kind>`` over
+        ``GATE_HOLDERS`` and ``free``: the parts of a waiter add up to its
+        whole exactly."""
+        out: Dict[str, int] = {}
+        for kind in GATE_HOLDERS:
+            out[f"gate_held_us_{kind}"] = self.held_us[kind]
+            out[f"gate_holds_{kind}"] = self.holds[kind]
+            out[f"gate_waits_{kind}"] = self.waits[kind]
+            out[f"gate_wait_us_{kind}"] = sum(self.wait_us[kind].values())
+            for behind, us in self.wait_us[kind].items():
+                out[f"gate_wait_us_{kind}_behind_{behind}"] = us
+        return out
+
+    def _held_by_now(self, now_us: int) -> Dict[str, int]:
+        """``held_us`` with the running hold's part up to ``now_us``: what a
+        waiter reads when it starts to wait and when it acquires."""
+        held = dict(self.held_us)
+        if self._holding is not None:
+            held[self._holding] += now_us - self._since_us
+        return held
+
+    def _begin_wait(self, holder: str, mode: str, ready):
+        """Entry: the ``gate_wait`` span (whoever asked is its parent), the
+        clock, and the ledger as it stands where the gate is not the
+        caller's for the asking. An unknown ``holder`` raises."""
+        if holder not in self.held_us:
+            raise ValueError(f"unknown gate holder {holder!r}: one of {GATE_HOLDERS}")
         span = tracing.start_span("gate_wait")
         if span is not None:
-            span.annotate(mode="expedite" if expedite else "exclusive")
+            span.annotate(mode=mode, holder=holder)
+        asked_us = _gate_now_us()
+        return span, asked_us, None if ready() else self._held_by_now(asked_us)
+
+    def _acquired(self, holder: str, span, asked_us: int, before) -> GateHold:
+        """The wait is over (under ``_cond``): cut it by who held the gate
+        meanwhile, add it to the ledger, close the span."""
+        now_us = _gate_now_us()
+        free = waited = now_us - asked_us
+        row = self.wait_us[holder]
+        behind = None if span is None else {}
+        if before is not None:
+            for kind, us in self._held_by_now(now_us).items():
+                part = us - before[kind]
+                if part:
+                    row[kind] += part
+                    free -= part
+                    if behind is not None:
+                        behind[kind] = part
+        row[GATE_FREE] += free
+        self.waits[holder] += 1
+        if self._holding is None:  # an exclusive hold, or the first shared one in
+            self._holding, self._since_us = holder, now_us
+        if span is not None:
+            if free:
+                behind[GATE_FREE] = free
+            span.annotate(behind_us=behind)
+            span.finish()
+        return GateHold(asked_us, waited)
+
+    def _released(self, holder: str, span, hold: GateHold):
+        """A hold ends (under ``_cond``): its time to the ledger where the
+        gate is nobody's now, and to its own span (an attr written after
+        ``finish``: the recorder's ring reads it, the slow-op copy was taken
+        before)."""
+        now_us = _gate_now_us()
+        hold.held_us = now_us - hold.asked_us - hold.waited_us
+        self.holds[holder] += 1
+        if not self._exclusive and self._shared == 0:
+            self.held_us[self._holding] += now_us - self._since_us
+            self._holding = None
+        if span is not None:
+            span.annotate(held_us=hold.held_us)
+
+    @asynccontextmanager
+    async def exclusive(self, holder: str, expedite: bool = False):
+        """Hold the gate alone as ``holder``; yields its ``GateHold``."""
+
+        def ready():
+            return (
+                not self._exclusive
+                and self._shared == 0
+                and (expedite or self._expedite_waiting == 0)
+            )
+
+        span, asked_us, before = self._begin_wait(
+            holder, "expedite" if expedite else "exclusive", ready
+        )
         async with self._cond:
             self._exclusive_waiting += 1
             if expedite:
                 self._expedite_waiting += 1
             try:
-                await self._cond.wait_for(
-                    lambda: not self._exclusive
-                    and self._shared == 0
-                    and (expedite or self._expedite_waiting == 0)
-                )
+                await self._cond.wait_for(ready)
             finally:
                 self._exclusive_waiting -= 1
                 if expedite:
@@ -167,33 +307,35 @@ class DeviceGate:
                 # writer that shared() waiters queued behind; without this
                 # notify they would sleep forever on a free gate.
                 self._cond.notify_all()
+            hold = self._acquired(holder, span, asked_us, before)
             self._exclusive = True
-        if span is not None:
-            span.finish()
         try:
-            yield
+            yield hold
         finally:
             async with self._cond:
                 self._exclusive = False
+                self._released(holder, span, hold)
                 self._cond.notify_all()
 
     @asynccontextmanager
-    async def shared(self):
-        span = tracing.start_span("gate_wait")
-        if span is not None:
-            span.annotate(mode="shared")
+    async def shared(self, holder: str):
+        """Hold the gate beside other shared holders as ``holder``; yields
+        its ``GateHold``."""
+
+        def ready():
+            return not self._exclusive and self._exclusive_waiting == 0
+
+        span, asked_us, before = self._begin_wait(holder, "shared", ready)
         async with self._cond:
-            await self._cond.wait_for(
-                lambda: not self._exclusive and self._exclusive_waiting == 0
-            )
+            await self._cond.wait_for(ready)
+            hold = self._acquired(holder, span, asked_us, before)
             self._shared += 1
-        if span is not None:
-            span.finish()
         try:
-            yield
+            yield hold
         finally:
             async with self._cond:
                 self._shared -= 1
+                self._released(holder, span, hold)
                 if self._shared == 0:
                     self._cond.notify_all()
 
@@ -717,7 +859,7 @@ class WaveDecoder:
                 # scheduled it: bind the wave's own span, so the gate wait
                 # below is the wave's child and not that request's.
                 with tracing.override_span(wspan):
-                    async with self.h.gate.exclusive():
+                    async with self.h.gate.exclusive(holder="wave"):
                         if wspan is not None:
                             wspan.stage("gate")
                         with tracing.device_call("its.wave_dispatch", wspan):
@@ -931,7 +1073,9 @@ class RequestStats:
     # not store-bound. gate_stall_us totals EVERY exclusive-gate wait the
     # request paid (install at admission, then the compute phase), so
     # misses — which no longer touch the gate at admission — still report
-    # their queue time.
+    # their queue time. Both gate figures are the gate's own readings
+    # (``GateHold.waited_us`` / ``.held_us``: ``DeviceGate`` is the one place
+    # that times the gate).
     store_io_us: float = 0.0
     gate_stall_us: float = 0.0
     # Two-phase admission (prefetch path): how long the exclusive gate was
@@ -1053,6 +1197,9 @@ class ContinuousBatchingHarness:
         # overlapping store writes.
         self.live = 0
         self.max_live = 0
+        # With the recorder on: the open `no_request_live` span while `live`
+        # is 0 after a request has left (run_request).
+        self._nobody_live: Optional[tracing.Span] = None
         # Requests admitted and not yet in a wave: each will ask for the
         # device (an install, a prefill or a resume, the prompt snapshot's
         # wait) before its first round, and a wave launched ahead of its need
@@ -1190,7 +1337,7 @@ class ContinuousBatchingHarness:
                 sspan.annotate(
                     blocks=len(phys_blocks), before_first_token=before_first_token
                 )
-            async with self.gate.shared():
+            async with self.gate.shared(holder="snapshot"):
                 caches = self.caches  # stable under the shared gate
 
                 def snap():
@@ -1440,6 +1587,9 @@ class ContinuousBatchingHarness:
             )
         self.live += 1
         self.max_live = max(self.max_live, self.live)
+        if self._nobody_live is not None:  # the stretch with no request live ends here
+            self._nobody_live.finish()
+            self._nobody_live = None
         self.arriving += 1
         arriving = True
         # Trace root for this request (docs/observability.md): `enqueue` is
@@ -1548,10 +1698,7 @@ class ContinuousBatchingHarness:
                     await prefetch.primed()
                     if rspan is not None:
                         rspan.stage("primed")
-                    t_gate = time.perf_counter()
-                    async with self.gate.exclusive(expedite=True):
-                        gate_stall_us = (time.perf_counter() - t_gate) * 1e6
-                        t_hold = time.perf_counter()
+                    async with self.gate.exclusive(holder="install", expedite=True) as held:
                         with tracing.trace_op("install") as ispan:
                             if ispan is not None:
                                 sliding, full = self.spec.hit_values(prefetch.n_blocks)
@@ -1566,7 +1713,7 @@ class ContinuousBatchingHarness:
                             )
                         if rspan is not None:
                             rspan.stage("install")
-                        gate_hold_us = (time.perf_counter() - t_hold) * 1e6
+                    gate_stall_us, gate_hold_us = held.waited_us, held.held_us
                     prefetch_settled = True
                     t_end = prefetch.fetch_finished_s or time.perf_counter()
                     fetch_dur = max(t_end - prefetch.fetch_started_s, 0.0)
@@ -1575,7 +1722,7 @@ class ContinuousBatchingHarness:
                         # Fraction of the fetch that ran before this request
                         # acquired the gate = store I/O hidden behind other
                         # work instead of serializing the device.
-                        overlapped = min(t_end, t_gate) - prefetch.fetch_started_s
+                        overlapped = min(t_end, held.asked_s) - prefetch.fetch_started_s
                         overlap = min(1.0, max(0.0, overlapped / fetch_dur))
                 # The store's own cost: probe + gate-free fetch + the
                 # install's H2D/scatter. Unlike the pre-split pipeline,
@@ -1589,10 +1736,7 @@ class ContinuousBatchingHarness:
                     t_l = time.perf_counter()
                     hit_tokens = self.adapter.get_num_matched_tokens(token_ids)
                     lookup_s = time.perf_counter() - t_l
-                t_gate = time.perf_counter()
-                async with self.gate.exclusive():
-                    gate_stall_us = (time.perf_counter() - t_gate) * 1e6
-                    t_io = time.perf_counter()
+                async with self.gate.exclusive(holder="install") as held:
                     # One phase: the span holds the store fetch too.
                     with tracing.trace_op("install") as ispan:
                         self.caches, loaded_tokens = await self.adapter.load_kv(
@@ -1604,8 +1748,8 @@ class ContinuousBatchingHarness:
                             ispan.annotate(blocks=loaded_tokens // bt, one_phase=True)
                     if rspan is not None and loaded_tokens:
                         rspan.stage("install")
-                    gate_hold_us = (time.perf_counter() - t_io) * 1e6
-                    store_io_us = lookup_s * 1e6 + gate_hold_us
+                gate_stall_us, gate_hold_us = held.waited_us, held.held_us
+                store_io_us = lookup_s * 1e6 + gate_hold_us
             admission_us = (time.perf_counter() - t0) * 1e6
             loaded_blocks = loaded_tokens // bt
             raced = hit_tokens > 0 and loaded_tokens == 0
@@ -1614,9 +1758,9 @@ class ContinuousBatchingHarness:
                 # too: misses never touch the gate at admission anymore, so
                 # without this their "queued behind other requests" signal
                 # (the thing gate_stall exists to expose) would read 0.
-                t_g2 = time.perf_counter()
-                async with self.gate.exclusive():
-                    gate_stall_us += (time.perf_counter() - t_g2) * 1e6
+                full = loaded_blocks == 0
+                async with self.gate.exclusive(holder="prefill" if full else "resume") as held:
+                    gate_stall_us += held.waited_us
                     # Compute runs in an executor thread: the jitted call
                     # (and its block_until_ready) would otherwise pin the
                     # EVENT LOOP for the whole forward — freezing every
@@ -1630,7 +1774,6 @@ class ContinuousBatchingHarness:
                     # is DISPATCHED (its device time is first waited for by
                     # whoever touches the cache next: the save's snapshot).
                     loop = asyncio.get_running_loop()
-                    full = loaded_blocks == 0
                     with tracing.trace_op("compute") as cspan:
                         if cspan is not None:
                             cspan.annotate(
@@ -1658,7 +1801,7 @@ class ContinuousBatchingHarness:
             prefix_ready_us = (time.perf_counter() - t0) * 1e6
             verified = None
             if self.verify:
-                async with self.gate.shared():
+                async with self.gate.shared(holder="verify"):
                     verified = self._verify_request(token_ids, prompt_table)
             # Save ONLY the computed suffix — the loaded prefix came from the
             # store and re-writing it would double write traffic for every
@@ -1802,6 +1945,11 @@ class ContinuousBatchingHarness:
             if table is not None:
                 await self.pool.free(table)
             self.live -= 1
+            if self.live == 0 and tracing.enabled():
+                # `no_request_live`: a trace of its own (nobody's child), from
+                # here to the next admission; what the device idles under it
+                # is no phase's of any request.
+                self._nobody_live = tracing.Span("no_request_live")
 
     async def run(
         self,
@@ -1859,7 +2007,18 @@ class ContinuousBatchingHarness:
         a stack of full layers would have; ``wave_host_transfers``, the host
         arrays uploaded for the launched waves plus the blocking
         device-to-host reads made for their tokens, 2 a wave; and whatever
-        the model's wave step counts itself, by its own names);
+        the model's wave step counts itself, by its own names); the device
+        gate's ledger, plain monotone counters in microseconds and counts
+        with ``<kind>`` and ``<waiter>`` over ``GATE_HOLDERS`` (``wave``,
+        ``prefill``, ``resume``, ``install``, ``snapshot``, ``verify``):
+        ``gate_held_us_<kind>`` and ``gate_holds_<kind>`` (how long that kind
+        held the gate, a stretch of overlapping shared holds once, and the
+        holds it ended), ``gate_waits_<waiter>`` and ``gate_wait_us_<waiter>``
+        (its acquisitions and what they waited in all) and
+        ``gate_wait_us_<waiter>_behind_<kind>``, ``<kind>`` also ``free``
+        (the part of those waits that stood behind a hold of that kind;
+        ``free``: the gate was nobody's and the waiter not yet woken), which
+        add up to ``gate_wait_us_<waiter>`` exactly (``DeviceGate``);
         serving latency (``p50_ttft_us``, ``p99_ttft_us``,
         ``p99_ttft_fg_us`` — time to first generated token, overall and
         FOREGROUND-class only); generation/speculation (``generated_tokens``,
@@ -1991,6 +2150,9 @@ class ContinuousBatchingHarness:
             # ``aux``): an expert model's ``moe_pairs`` and
             # ``moe_distinct_experts``; nothing for a model that counts nothing.
             **self.wave.step_counters(),
+            # The device gate's ledger (``DeviceGate.counters``): who held
+            # it for how long, and every wait cut by whom it stood behind.
+            **self.gate.counters(),
             # Time to the first token, split so the FOREGROUND class's
             # tail is visible next to the mixed one.
             "p50_ttft_us": _p(ttft, 0.50),

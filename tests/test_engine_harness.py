@@ -531,13 +531,13 @@ def test_device_gate_excludes_mutators():
         order = []
 
         async def reader(name, hold):
-            async with gate.shared():
+            async with gate.shared(holder="snapshot"):
                 order.append(f"{name}+")
                 await asyncio.sleep(hold)
                 order.append(f"{name}-")
 
         async def writer():
-            async with gate.exclusive():
+            async with gate.exclusive(holder="prefill"):
                 order.append("w+")
                 order.append("w-")
 
@@ -572,16 +572,16 @@ def test_device_gate_expedite_jumps_queued_writers():
         order = []
 
         async def holder():
-            async with gate.exclusive():
+            async with gate.exclusive(holder="prefill"):
                 order.append("hold")
                 await asyncio.sleep(0.03)
 
         async def normal():
-            async with gate.exclusive():
+            async with gate.exclusive(holder="prefill"):
                 order.append("prefill")
 
         async def install():
-            async with gate.exclusive(expedite=True):
+            async with gate.exclusive(holder="install", expedite=True):
                 order.append("install")
 
         h = asyncio.ensure_future(holder())
@@ -618,15 +618,15 @@ def test_device_gate_cancelled_writer_releases_queued_readers():
             raise AssertionError("the gate never reached the state waited for")
 
         async def hold_shared():
-            async with gate.shared():
+            async with gate.shared(holder="snapshot"):
                 await release.wait()
 
         async def writer():
-            async with gate.exclusive():
+            async with gate.exclusive(holder="prefill"):
                 got.append("w")
 
         async def late_reader():
-            async with gate.shared():
+            async with gate.shared(holder="snapshot"):
                 got.append("r2")
 
         r1 = asyncio.ensure_future(hold_shared())
@@ -643,7 +643,7 @@ def test_device_gate_cancelled_writer_releases_queued_readers():
         release.set()
         await r1
         assert got == ["r2"], got
-        async with gate.exclusive():  # gate still fully functional
+        async with gate.exclusive(holder="prefill"):  # gate still fully functional
             got.append("w2")
         assert got == ["r2", "w2"], got
 

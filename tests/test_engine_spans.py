@@ -454,20 +454,20 @@ def test_gate_wait_spans_under_contention(traced):
         acquired = asyncio.Event()
 
         async def writer():
-            async with gate.exclusive():
+            async with gate.exclusive(holder="prefill"):
                 acquired.set()
                 await asyncio.sleep(hold_s)
 
         async def reader():
             await acquired.wait()
             with tracing.trace_op("reader") as span:
-                async with gate.shared():
+                async with gate.shared(holder="snapshot"):
                     pass
             return span
 
         async def installer():
             await acquired.wait()
-            async with gate.exclusive(expedite=True):
+            async with gate.exclusive(holder="install", expedite=True):
                 pass
 
         _, span, _ = await asyncio.gather(writer(), reader(), installer())
@@ -498,14 +498,14 @@ def test_gate_makes_no_span_with_tracing_off(monkeypatch):
 
     async def drive():
         async def writer():
-            async with gate.exclusive():
+            async with gate.exclusive(holder="prefill"):
                 order.append("writer in")
                 await asyncio.sleep(0.01)
                 order.append("writer out")
 
         async def reader():
             await asyncio.sleep(0)
-            async with gate.shared():
+            async with gate.shared(holder="snapshot"):
                 order.append("reader in")
 
         await asyncio.gather(writer(), reader())
