@@ -46,7 +46,6 @@ lost to eviction (the cache-semantics path: the engine just recomputes).
 """
 
 import asyncio
-import collections
 import contextlib
 import time
 from contextlib import asynccontextmanager
@@ -341,8 +340,8 @@ class DeviceGate:
 
 
 class _WaveOut:
-    """What one launched wave returned beside its logits, kept while its
-    requests may still ask (``WaveDecoder.token_ids``, ``row_aux``)."""
+    """What one launched wave returned beside its logits, alive while a
+    ``WaveRows`` of it is (``WaveDecoder.token_ids``, ``row_aux``)."""
 
     __slots__ = ("ids", "feed", "aux_rows", "host_ids")
 
@@ -353,13 +352,59 @@ class _WaveOut:
         self.host_ids = None  # np [T], once the first request has asked
 
 
+class WaveRows:
+    """A request's rows of a launched wave's logits, as ``step_chunk``
+    resolves to them: ``[n, vocab]``, on the device, and not cut out of the
+    wave's ``[T, vocab]`` array until somebody reads them. A request that
+    asks its decoder for the sampled ids (``WaveDecoder.token_ids``) or the
+    rows' ``aux`` (``row_aux``) hands this back and dispatches nothing; one
+    that reads the logits (an index, ``np.asarray``, any ``jnp`` function)
+    gets ``logits[off : off + n]``, cut on the first read, kept, and counted
+    in the decoder's ``row_slices``. Once cut the handle holds the slice
+    alone and lets the wave's whole array go; rows that ARE the whole array
+    (a wave of one entry with no padded row) cost no dispatch and no count."""
+
+    __slots__ = ("decoder", "out", "off", "n", "shape", "dtype", "_logits", "_rows")
+
+    def __init__(self, decoder: "WaveDecoder", logits: jax.Array, out: _WaveOut, off: int, n: int):
+        self.decoder = decoder  # the one that handed these out, and counts their slice
+        self.out = out  # the wave they rode
+        self.off, self.n = off, n  # their flat rows there
+        self.shape, self.dtype = (n, *logits.shape[1:]), logits.dtype
+        self._logits, self._rows = logits, None
+
+    def rows(self) -> jax.Array:
+        """The logits rows themselves, cut out of the wave's on the first call."""
+        if self._rows is None:
+            if self.n == self._logits.shape[0]:
+                self._rows = self._logits
+            else:
+                self._rows = self._logits[self.off : self.off + self.n]
+                self.decoder.row_slices += 1
+            self._logits = None
+        return self._rows
+
+    def __len__(self) -> int:
+        return self.n
+
+    def __getitem__(self, index):
+        return self.rows()[index]
+
+    def __array__(self, dtype=None, copy=None):
+        return np.asarray(self.rows(), dtype=dtype)
+
+    def __jax_array__(self) -> jax.Array:
+        return self.rows()
+
+
 class _Ahead(NamedTuple):
     """A stream's row in a launched wave that its request has not been handed."""
 
     position: int
-    rows: jax.Array  # its logits rows [1, vocab], as ``step_chunk`` will resolve to them
-    out: _WaveOut  # the wave it rode
-    offset: int  # its flat row there: what ``fed_token`` names
+    # Its logits rows [1, vocab], as ``step_chunk`` will resolve to them; they
+    # know the wave it rode (``out``) and its flat row there (``off``: what
+    # ``fed_token`` names).
+    rows: WaveRows
 
 
 class _Stream:
@@ -426,12 +471,18 @@ class WaveDecoder:
     ``serving.verify_step_ragged``, which slices it at static offsets and
     runs the model's wave body). The program returns the greedy ids
     ``argmax(logits, -1)`` beside the logits; their copy to the host starts
-    with the launch and stays with the wave. ``step_chunk`` still resolves to
-    the request's logits rows ON THE DEVICE; the request then asks
-    ``token_ids(rows)``: a wave's first asker blocks once for the whole
-    wave's ids, every other request of that wave reads the host copy.
-    ``waves + blocking_reads`` is ``wave_host_transfers`` in ``metrics()``:
-    2 a wave, whatever its rows.
+    with the launch and stays with the wave. ``step_chunk`` resolves to a
+    ``WaveRows``: the request's rows of the wave's logits, which stay on the
+    device and are cut out of the wave's array only when, and only if,
+    somebody reads them (a slice is a device call of its own, about as dear
+    to the host as the launch's share a row, and a flush would pay one an
+    entry on the thread that has the next wave to launch). The request asks
+    ``token_ids(rows)``, which dispatches nothing: a wave's first asker
+    blocks once for the whole wave's ids, every other request of that wave
+    reads the host copy. ``waves + blocking_reads`` is
+    ``wave_host_transfers`` in ``metrics()``: 2 a wave, whatever its rows;
+    ``row_slices`` (``wave_row_slices``) counts the slices readers of logits
+    caused (the benchmark's check phase, a test): none in a serving loop.
 
     **And one wave ahead.** The token a round samples is four bytes that are
     on the device when its wave ends, and all that the next wave wants of
@@ -508,12 +559,13 @@ class WaveDecoder:
         self.wave_window_pages_skipped = 0
         # What the wave program hands back beside its logits
         # (models/serving.py): the sampled ids and the model's per-row ``aux``
-        # arrays, kept a wave at a time beside the logits rows handed out
-        # (``token_ids``, ``row_aux``), and the model's named counters, added
-        # up on the device (a wave's are results like its logits: nothing
-        # here waits for them, and this class reads neither).
-        self._handed = {}  # id(rows) -> (rows, the wave's _WaveOut, offset, length)
-        self._kept_waves = collections.deque()  # the keys of each kept wave
+        # arrays stay with the wave (``_WaveOut``), which the rows handed out
+        # know (``WaveRows``: ``token_ids``, ``row_aux``); the model's named
+        # counters are added up on the device (a wave's are results like its
+        # logits: nothing here waits for them, and this class reads neither).
+        # Slices of a wave's logits dispatched for a reader of them
+        # (``WaveRows.rows``): none for a request that asks for ids alone.
+        self.row_slices = 0
         # Blocking device-to-host reads made for the waves' tokens: one a
         # wave whose tokens anyone asked for. With the one host array a
         # launched wave uploads (``waves``) they are ``wave_host_transfers``.
@@ -543,7 +595,8 @@ class WaveDecoder:
         self.waves_ahead = 0
 
     async def step(self, token: int, position: int, padded_table) -> jax.Array:
-        """Advance this request by one token; returns its logits row."""
+        """Advance this request by one token; returns its logits row (read,
+        so cut: a caller that wants the id alone takes ``step_chunk``)."""
         rows = await self.step_chunk([token], [position], padded_table)
         return rows[0]
 
@@ -553,11 +606,13 @@ class WaveDecoder:
         positions: Sequence[int],
         padded_table,
         priority: int = 0,
-    ) -> jax.Array:
+    ) -> WaveRows:
         """Advance this request by a token chunk (tokens[0] committed,
         tokens[1:] speculative); returns its [len(tokens), vocab] logits
-        rows — row j follows tokens[:j+1]. ``priority`` is taken and ignored:
-        every entry rides the next wave, whatever its class."""
+        rows — row j follows tokens[:j+1] — as a ``WaveRows``: hand it to
+        ``token_ids`` / ``row_aux``, or read it as the array it stands for.
+        ``priority`` is taken and ignored: every entry rides the next wave,
+        whatever its class."""
         if not tokens or len(tokens) != len(positions):
             raise ValueError("need non-empty tokens with matching positions")
         fut = asyncio.get_running_loop().create_future()
@@ -597,55 +652,44 @@ class WaveDecoder:
 
     # -- what the wave program returned beside its logits ---------------------
 
-    # Waves whose ids and aux rows are kept: a request reads its own right
-    # after ``step_chunk`` returns, and at most a wave or two can resolve
-    # between.
-    WAVES_KEPT = 8
     # Waves whose counters are held apart before one small sum folds them.
     COUNTERS_FOLDED_EVERY = 64
 
-    def _keep(self, out: "_WaveOut", aux: dict, handed: List[tuple]):
-        """``handed``: (logits rows as resolved, offset, length) per entry."""
+    def _keep(self, aux: dict):
         for name, value in aux.get("counters", {}).items():
             held = self._step_counters.setdefault(name, [])
             held.append(value)
             if len(held) >= self.COUNTERS_FOLDED_EVERY:
                 held[:] = [jnp.sum(jnp.stack(held))]
-        for rows, off, n in handed:
-            self._handed[id(rows)] = (rows, out, off, n)
-        self._kept_waves.append([id(rows) for rows, _, _ in handed])
-        while len(self._kept_waves) > self.WAVES_KEPT:
-            for key in self._kept_waves.popleft():
-                self._handed.pop(key, None)
 
-    def _handed_with(self, rows):
-        kept, out, off, n = self._handed[id(rows)]
-        if kept is not rows:
+    def _mine(self, rows) -> WaveRows:
+        if not isinstance(rows, WaveRows) or rows.decoder is not self:
             raise KeyError("these are not rows this decoder handed out")
-        return out, off, n
+        return rows
 
-    def token_ids(self, rows) -> np.ndarray:
+    def token_ids(self, rows: WaveRows) -> np.ndarray:
         """The greedy ids ``[len(rows)] int32`` of the logits ``rows`` that
-        ``step_chunk`` just handed a request, on the host: the wave program's
-        own ``argmax(logits, -1)``, copied to the host since the launch. The
+        ``step_chunk`` handed a request, on the host: the wave program's own
+        ``argmax(logits, -1)``, copied to the host since the launch. The
         first request of a wave to ask blocks once, for the whole wave's ids;
-        every other reads that copy. ``KeyError`` for rows this decoder did
-        not hand out lately."""
-        out, off, n = self._handed_with(rows)
+        every other reads that copy. Nothing is dispatched: the rows know
+        their wave. ``KeyError`` for anything this decoder did not hand out."""
+        rows = self._mine(rows)
+        out = rows.out
         if out.host_ids is None:
             out.host_ids = np.asarray(out.ids)
             self.blocking_reads += 1
-        return out.host_ids[off : off + n]
+        return out.host_ids[rows.off : rows.off + rows.n]
 
-    def row_aux(self, rows):
+    def row_aux(self, rows: WaveRows):
         """The per-row ``aux`` slice the wave returned with the logits
-        ``rows`` that ``step_chunk`` just handed a request; ``KeyError`` for
-        rows this decoder did not hand out lately, or of a model whose wave
+        ``rows`` that ``step_chunk`` handed a request; ``KeyError`` for
+        anything this decoder did not hand out, or of a model whose wave
         returns no per-row ``aux``."""
-        out, off, n = self._handed_with(rows)
-        if out.aux_rows is None:
+        rows = self._mine(rows)
+        if rows.out.aux_rows is None:
             raise KeyError("this model's wave returns no per-row aux")
-        return out.aux_rows[off : off + n]
+        return rows.out.aux_rows[rows.off : rows.off + rows.n]
 
     def step_counters(self) -> dict:
         """The model step's named counters, summed over every wave so far
@@ -776,15 +820,16 @@ class WaveDecoder:
             taken.append((fut, ahead.rows))
             if (
                 ahead_ok and stream.left > 0
-                and ahead.out is self._last and ahead.offset < FEED_ROWS
+                and ahead.rows.out is self._last and ahead.rows.off < FEED_ROWS
             ):
-                fed.append(([fed_token(ahead.offset)], [ahead.position + 1], table, None))
+                fed.append(([fed_token(ahead.rows.off)], [ahead.position + 1], table, None))
         return taken, launched + fed, len(fed), ahead_ok
 
     def _resolve(self, launched: List[tuple], fed: int, ahead_ok: bool,
                  wave: "_Wave", logits, out, aux) -> List[tuple]:
-        """A launched wave's rows to their entries, and what the wave
-        returned beside them kept. A call whose stream ends here, or has none,
+        """A launched wave's rows to their entries, each a ``WaveRows`` on the
+        wave's ``logits`` (no device call: nothing is cut until a reader asks),
+        and the wave's counters kept. A call whose stream ends here, or has none,
         resolves now (only real rows' futures resolve). A call whose stream
         goes on keeps its row as the stream's ``ahead`` and is returned, to be
         enqueued again: the flush that takes it launches the stream's next row
@@ -797,23 +842,22 @@ class WaveDecoder:
         self.one_row_waves += wave.real_rows == 1
         self.max_wave = max(self.max_wave, len(launched))
         self._last = out
-        off, handed, again = 0, [], []
+        off, again = 0, []
         for entry in launched:
             toks, pos, table, fut = entry
             if fut is None or not fut.done():
-                rows = logits[off : off + len(toks)]
-                handed.append((rows, off, len(toks)))
+                rows = WaveRows(self, logits, out, off, len(toks))
                 stream = self._streams.get(id(table))
                 if stream is not None and (fut is None or (
                     ahead_ok and stream.left > 0 and len(toks) == 1 and off < FEED_ROWS
                 )):
-                    stream.ahead = _Ahead(pos[0], rows, out, off)
+                    stream.ahead = _Ahead(pos[0], rows)
                     if fut is not None:
                         again.append(entry)
                 elif fut is not None:
                     fut.set_result(rows)
             off += len(toks)
-        self._keep(out, aux, handed)
+        self._keep(aux)
         return again
 
     @staticmethod
@@ -1504,7 +1548,7 @@ class ContinuousBatchingHarness:
             # persists), one more step lands it; otherwise its block is an
             # incomplete tail with no chain key — skip the wasted wave.
             if closing:
-                await self.wave.step(tok, pos, padded)
+                await self.wave.step_chunk([tok], [pos], padded)
         return out, first_token_t, emit_s
 
     def _verify_request(self, token_ids, table: np.ndarray) -> bool:
@@ -2006,7 +2050,11 @@ class ContinuousBatchingHarness:
         (layer, page) pairs the waves' real rows attended and how many more
         a stack of full layers would have; ``wave_host_transfers``, the host
         arrays uploaded for the launched waves plus the blocking
-        device-to-host reads made for their tokens, 2 a wave; and whatever
+        device-to-host reads made for their tokens, 2 a wave;
+        ``wave_row_slices``, the slices of a wave's logits dispatched because
+        somebody read the rows ``step_chunk`` handed out (``WaveRows``; a
+        request that asks for its ids alone causes none: 0 in a serving
+        loop); and whatever
         the model's wave step counts itself, by its own names); the device
         gate's ledger, plain monotone counters in microseconds and counts
         with ``<kind>`` and ``<waiter>`` over ``GATE_HOLDERS`` (``wave``,
@@ -2146,6 +2194,10 @@ class ContinuousBatchingHarness:
             # ``decode_waves`` it reads 2 (a little under where a request's
             # closing step rides a wave alone and reads nothing back).
             "wave_host_transfers": self.wave.waves + self.wave.blocking_reads,
+            # Slices of a wave's logits cut for a reader of the rows
+            # ``step_chunk`` handed out (``WaveRows``): over ``decode_waves``
+            # a serving loop, which asks for ids alone, reads 0.
+            "wave_row_slices": self.wave.row_slices,
             # What the model's wave step counted itself (models/serving.py
             # ``aux``): an expert model's ``moe_pairs`` and
             # ``moe_distinct_experts``; nothing for a model that counts nothing.

@@ -816,3 +816,48 @@ def test_step_chunk_takes_a_priority_and_ignores_it(params):
         np.testing.assert_array_equal(a, b)
 
 
+
+
+@pytest.mark.parametrize("streams", [1, 2, 3])
+def test_generation_cuts_no_logits_row_and_samples_what_the_logits_say(conn, params, streams):
+    """One, two and three requests decode side by side through ``_generate``:
+    the rows ``step_chunk`` hands a request are a handle it only asks for its
+    ids (``WaveDecoder.token_ids``), so a run ends with no slice of a wave's
+    logits dispatched, closing step included (an answer that ends on a block's
+    edge). A second run, whose every ``step_chunk`` is tapped by a reader of
+    the logits themselves, emits the same tokens, each the argmax of the rows
+    read, and pays one slice a real rider."""
+    bt = CFG.block_tokens
+    prompts = _prompts(streams, shared_blocks=0, total_blocks=2, seed=53)
+    gen = bt  # prompt and answer end on a block's edge: one closing step a request
+
+    async def drive(model_id, tap):
+        h = _harness(conn, params, model_id, verify=False)
+        read = []
+        if tap:
+            step_chunk = h.wave.step_chunk
+
+            async def tapped(tokens, positions, table, priority=0):
+                rows = await step_chunk(tokens, positions, table, priority=priority)
+                read.append((np.argmax(np.asarray(rows), axis=-1), h.wave.token_ids(rows)))
+                return rows
+
+            h.wave.step_chunk = tapped
+        stats = await asyncio.gather(*(h.run_request(p, gen_tokens=gen) for p in prompts))
+        return h, [s.generated for s in stats], read
+
+    h, tokens, _ = asyncio.run(drive(f"rows-unread-{streams}", False))
+    m = h.metrics()
+    assert m["generated_tokens"] == streams * gen and m["decode_waves"] >= gen + 1
+    assert (m["wave_row_slices"], h.wave.row_slices) == (0, 0)
+    assert m["wave_host_transfers"] <= 2 * m["decode_waves"]
+    tapped_h, tapped_tokens, read = asyncio.run(drive(f"rows-read-{streams}", True))
+    assert tapped_tokens == tokens
+    assert len(read) == streams * (gen + 1)
+    for from_logits, ids in read:
+        np.testing.assert_array_equal(from_logits, ids)
+    # A wave of one entry in a one-row bucket hands its logits over whole;
+    # every other rider's read is a slice.
+    lone = tapped_h.wave.one_row_waves
+    assert tapped_h.wave.row_slices == len(read) - lone
+    assert (tapped_h.wave.row_slices > 0) == (streams > 1)

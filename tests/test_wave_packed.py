@@ -26,6 +26,7 @@ from infinistore_tpu.engine import (
     EngineKVAdapter,
     NGramDrafter,
     WaveDecoder,
+    WaveRows,
 )
 from infinistore_tpu.models import AfmoeConfig, LlamaConfig, afmoe, falcon_h1, llama, serving
 from infinistore_tpu.models.serving import WaveLayout, pack_wave, unpack_wave
@@ -103,6 +104,9 @@ def mixed_wave(cfg, params):
     return tables, chunks, caches
 
 
+MIXED_OFFSETS = (0, 1, 4)  # the flat row each of ``mixed_wave``'s chunks starts at
+
+
 def same_bits(got, want, what):
     got, want = jax.tree.leaves(got), jax.tree.leaves(want)
     assert len(got) == len(want), what
@@ -168,39 +172,209 @@ def test_the_packed_entry_is_the_body_bit_for_bit_on_the_decoders_own_wave(model
     assert bool(wave.wave_window_pages_skipped) == windowed
 
 
+def keeping_logits(wave):
+    """``wave`` with every launched wave's whole ``logits`` noted, as
+    ``(logits, its _WaveOut)``."""
+    launch, launched = wave.launch, []
+
+    def noted(*a, **kw):
+        got = launch(*a, **kw)
+        launched.append(got[:2])
+        return got
+
+    wave.launch = noted
+    return launched
+
+
 def test_the_ids_are_the_argmax_of_the_rows_handed_back_and_a_wave_reads_once(model):
     """One-token rows and a drafter's chunk: ``token_ids(rows)`` is the argmax
     of the logits rows ``step_chunk`` resolved to, and the three requests of
-    the wave cost ONE blocking read between them."""
+    the wave cost ONE blocking read between them. What ``step_chunk`` resolves
+    to is a handle on those rows: asking for ids dispatches nothing, and read
+    as an array, however and however often, it is bit for bit the slice of the
+    wave's logits, cut once."""
     cfg, params = model
     tables, chunks, caches = mixed_wave(cfg, params)
     reads = []
 
     async def one(wave, r, toks, pos):
         rows = await wave.step_chunk(toks, pos, tables[r])
-        assert isinstance(rows, jax.Array) and rows.shape == (len(toks), cfg.vocab)
+        assert isinstance(rows, WaveRows) and rows.shape == (len(toks), cfg.vocab)
+        assert len(rows) == len(toks) and rows.dtype == cfg.dtype
         ids = wave.token_ids(rows)
-        reads.append(wave.blocking_reads)
+        reads.append((wave.blocking_reads, wave.row_slices))
         assert isinstance(ids, np.ndarray) and ids.dtype == np.int32
         np.testing.assert_array_equal(ids, np.asarray(jnp.argmax(rows, axis=-1)))
         return rows
 
     async def run():
         wave = WaveDecoder(bare_harness(cfg, params, caches))
+        launched = keeping_logits(wave)
         handed = await asyncio.gather(*(
             one(wave, r, toks, pos) for r, (toks, pos) in enumerate(chunks)
         ))
-        return wave, handed
+        return wave, handed, launched
 
-    wave, handed = asyncio.run(run())
+    wave, handed, launched = asyncio.run(run())
     assert wave.waves == 1 and wave.max_wave == 3
-    assert reads == [1, 1, 1], "a later request of the wave read the device again"
-    assert (wave.waves, wave.blocking_reads) == (1, 1)
+    # The ids were asked for before anybody read a logit: no slice by then
+    # for the first request, and one a request that went on to read its rows.
+    assert reads == [(1, 0), (1, 1), (1, 2)], "a later request of the wave read the device again"
+    assert (wave.waves, wave.blocking_reads, wave.row_slices) == (1, 1, 3)
+    ((logits, out),) = launched
+    for rows, off in zip(handed, MIXED_OFFSETS):
+        want = logits[off : off + len(rows)]
+        assert (rows.out, rows.off, rows.n) == (out, off, len(want))
+        for got, cut in (
+            (np.asarray(rows), want), (np.asarray(rows, np.float32), want.astype(jnp.float32)),
+            (jnp.asarray(rows), want), (rows[:1], want[:1]), (rows[0], want[0]),
+            (rows[0][0], want[0][0]), (jnp.argmax(rows, -1), jnp.argmax(want, -1)),
+            (jnp.concatenate([rows[:1], rows[-1:]]), jnp.concatenate([want[:1], want[-1:]])),
+        ):
+            same_bits(got, cut, "a read of the handle")
+        assert rows.rows() is rows.rows() and isinstance(rows.rows(), jax.Array)
+        assert rows._logits is None, "a handle that was cut still holds the wave's whole logits"
+    assert wave.row_slices == 3, "a handle read again was cut again"
     # Asking again costs nothing; rows of no wave are no key.
     wave.token_ids(handed[1])
-    assert wave.blocking_reads == 1
+    assert (wave.blocking_reads, wave.row_slices) == (1, 3)
     with pytest.raises(KeyError):
         wave.token_ids(jnp.zeros((1, cfg.vocab), jnp.float32))
+
+
+def test_rows_that_are_the_whole_wave_cost_no_slice(model):
+    """A wave of one entry and no padded row: its rows ARE the wave's logits,
+    handed over as they are, read or not."""
+    cfg, params = model
+    tables, chunks, caches = mixed_wave(cfg, params)
+
+    async def run():
+        wave = WaveDecoder(bare_harness(cfg, params, caches))
+        launched = keeping_logits(wave)
+        rows = await wave.step_chunk(*chunks[0], tables[0])
+        return wave, rows, launched
+
+    wave, rows, ((logits, _),) = asyncio.run(run())
+    assert logits.shape == rows.shape == (1, cfg.vocab)
+    assert rows.rows() is logits and wave.row_slices == 0
+    same_bits(np.asarray(rows), logits, "the one row")
+
+
+def test_a_drafters_chunk_and_a_step_get_their_logits_and_count_a_slice_each(model):
+    """Readers of logits are served as they were: ``step()`` returns its row,
+    a chunk read as an array is its rows, one slice each; the entry beside
+    them that asks for its ids alone causes none."""
+    cfg, params = model
+    tables, chunks, caches = mixed_wave(cfg, params)
+
+    async def run():
+        wave = WaveDecoder(bare_harness(cfg, params, caches))
+        launched = keeping_logits(wave)
+
+        async def ids_only():
+            return wave.token_ids(await wave.step_chunk(*chunks[2], tables[2]))
+
+        row, chunk, ids = await asyncio.gather(
+            wave.step(chunks[0][0][0], chunks[0][1][0], tables[0]),
+            wave.step_chunk(*chunks[1], tables[1]),
+            ids_only(),
+        )
+        return wave, row, chunk, ids, launched
+
+    wave, row, chunk, ids, ((logits, _),) = asyncio.run(run())
+    assert wave.waves == 1 and wave.row_slices == 1, "step() reads its row; nobody else has yet"
+    assert isinstance(row, jax.Array) and row.shape == (cfg.vocab,)
+    same_bits(row, logits[0], "step()'s row")
+    same_bits(np.asarray(chunk), logits[1:4], "the chunk's rows")
+    assert wave.row_slices == 2
+    np.testing.assert_array_equal(ids, np.argmax(np.asarray(logits[4:5]), axis=-1))
+    assert wave.row_slices == 2
+
+
+def test_the_benchmarks_four_uses_of_a_handle(model):
+    """What ``benchmarks/run.py`` does with what ``step_chunk`` returns: the
+    warm-up's ``jnp.argmax(h, -1)``, the check phase's ``h[0][0]`` and
+    ``jnp.concatenate([h[:1] ...])`` and, for a routed model, ``choices(harness,
+    h)``, which comes back through ``row_aux`` and reads no logits."""
+    cfg, params = model
+    tables, chunks, caches = mixed_wave(cfg, params)
+
+    async def run():
+        h = bare_harness(cfg, params, caches)
+        h.wave = WaveDecoder(h)
+        launched = keeping_logits(h.wave)
+        handed = await asyncio.gather(*(
+            h.wave.step_chunk(toks, pos, tables[r]) for r, (toks, pos) in enumerate(chunks)
+        ))
+        return h, handed, launched
+
+    h, handed, ((logits, out),) = asyncio.run(run())
+    if out.aux_rows is None:
+        with pytest.raises(KeyError):
+            h.wave.row_aux(handed[0])
+    else:
+        for rows, off in zip(handed, MIXED_OFFSETS):
+            got = afmoe.choices(h, rows)
+            assert isinstance(got, np.ndarray) and got.shape[0] == len(rows)
+            same_bits(got, out.aux_rows[off : off + len(rows)], "choices")
+    assert h.wave.row_slices == 0, "the choices are the wave's aux: no logits were read"
+    for rows, off in zip(handed, MIXED_OFFSETS):
+        np.testing.assert_array_equal(
+            np.asarray(jnp.argmax(rows, axis=-1)), np.argmax(np.asarray(logits), -1)[off : off + len(rows)]
+        )
+        same_bits(np.asarray(rows[0][0], np.float32), np.asarray(logits[off][0], np.float32), "h[0][0]")
+    got = jnp.concatenate([rows[:1] for rows in handed]).astype(jnp.float32)
+    same_bits(got, logits[np.asarray(MIXED_OFFSETS)].astype(jnp.float32), "the rounds' first rows")
+    assert h.wave.row_slices == 3
+
+
+def test_only_this_decoders_handles_are_keys(model):
+    """``token_ids`` and ``row_aux`` take what THIS decoder handed out: an
+    array, a slice of a handle or another decoder's handle is a ``KeyError``."""
+    cfg, params = model
+    tables, chunks, caches = mixed_wave(cfg, params)
+
+    async def run():
+        mine = WaveDecoder(bare_harness(cfg, params, caches))
+        other = WaveDecoder(bare_harness(cfg, params, caches))
+        return mine, other, await mine.step_chunk(*chunks[1], tables[1]), \
+            await other.step_chunk(*chunks[1], tables[1])
+
+    mine, other, rows, foreign = asyncio.run(run())
+    assert len(mine.token_ids(rows)) == len(other.token_ids(foreign)) == 3
+    for ask in (mine.token_ids, mine.row_aux):
+        for not_mine in (foreign, rows[:1], rows.rows(), np.asarray(rows), None):
+            with pytest.raises(KeyError):
+                ask(not_mine)
+
+
+def test_a_handle_kept_past_many_later_waves_still_answers(model):
+    """Nothing is evicted any more: a handle knows its wave for as long as it
+    lives, ids, aux and logits, whatever was launched since."""
+    cfg, params = model
+    tables, chunks, caches = mixed_wave(cfg, params)
+
+    async def run():
+        wave = WaveDecoder(bare_harness(cfg, params, caches))
+        launched = keeping_logits(wave)
+        old = await asyncio.gather(*(
+            wave.step_chunk(toks, pos, tables[r]) for r, (toks, pos) in enumerate(chunks)
+        ))
+        toks, pos = chunks[0]
+        for k in range(1, 13):
+            await wave.step_chunk([7 + k], [pos[0] + k], tables[0])
+        return wave, old, launched
+
+    wave, old, launched = asyncio.run(run())
+    assert wave.waves == 13 and wave.blocking_reads == 0
+    logits, out = launched[0]
+    ids = np.argmax(np.asarray(logits), axis=-1)
+    for rows, off in zip(old, MIXED_OFFSETS):
+        np.testing.assert_array_equal(wave.token_ids(rows), ids[off : off + len(rows)])
+        same_bits(np.asarray(rows), logits[off : off + len(rows)], "an old handle's rows")
+        if out.aux_rows is not None:
+            same_bits(wave.row_aux(rows), out.aux_rows[off : off + len(rows)], "an old handle's aux")
+    assert wave.blocking_reads == 1
 
 
 def harness(conn, cfg, params, name, **kw):

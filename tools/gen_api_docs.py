@@ -39,7 +39,7 @@ SURFACE = [
     ("infinistore_tpu.connector", ["KVConnector", "token_chain_hashes"]),
     ("infinistore_tpu.engine", [
         "EngineKVAdapter", "ContinuousBatchingHarness", "BlockPool",
-        "WaveDecoder", "DeviceGate", "GateHold", "RequestStats",
+        "WaveDecoder", "WaveRows", "DeviceGate", "GateHold", "RequestStats",
     ]),
     ("infinistore_tpu.cluster", [
         "ClusterKVConnector", "rendezvous_owner", "rendezvous_ranked",
