@@ -38,7 +38,7 @@ TOY = dict(
     },
 )
 CLOSED = {
-    "loop": "closed", "clients": 2, "schedule_seed": 7, "documents_per_client": 12,
+    "loop": "closed", "clients": 2, "schedule_seed": 7, "documents_per_client": 24,
     "asks_per_document": 4, "prefix_tokens": {"64": 2, "128": 1}, "question_tokens": 5,
     "answer_tokens": 20,
 }

@@ -75,7 +75,7 @@ def test_the_cells_files_are_the_ones_the_issue_names():
     assert cache_geometry.pool_gib(traffic.store_bytes(plan, layout.pool_bytes_per_token)) == 16
     # The one new metric is a data file over a reader kind the harness has.
     on = [m["name"] for m in bench["per_layer"] if CELL in m.get("workloads", [])]
-    assert len(on) == 69 and on[-1] == "hit_window_share.reuse"
+    assert "hit_window_share.reuse" in on  # by name: neither its place nor the cell's count is pinned
     spec = readers.load_layer_metric("hit_window_share.reuse")["reader"]
     assert spec == {"kind": "counter", "key": "hit_window_values_fetched", "per": "hit_values_fetched", "scale": 100.0}
     assert CELL in [m for m in bench["end_to_end"] if m["name"] == "tokens_per_s"][0]["workloads"]
@@ -106,10 +106,12 @@ def test_toy_mellum_cell_runs_and_checks(capfd):
     import run
 
     plan = traffic._closed_plan("toy", CLOSED)
-    counters = readers.counter_keys([
+    # ``install_layers`` over ``install_dispatches`` had a file until PR 55 (it read each
+    # configuration's depth in every cell); the program still counts both, so they are named here.
+    counters = (readers.counter_keys([
         "hit_window_share.reuse", "hit_fetch_share.reuse", "moe_distinct_experts_share.reuse",
-        "install_layers_per_dispatch", "window_pages_skipped_share.reuse",
-    ]) - run.OWN_COUNTERS
+        "window_pages_skipped_share.reuse",
+    ]) | {"install_layers", "install_dispatches"}) - run.OWN_COUNTERS
     args = argparse.Namespace(workload="toy", seed=2**31 + 50, seconds=4.0, trace=0)
     line, res, _ = run.execute(
         args, {"name": "toy", "chips": 1}, TOY, plan, run.device_line(jax), counters
@@ -127,7 +129,10 @@ def test_toy_mellum_cell_runs_and_checks(capfd):
     # values 6 / (n + 6) = 60% and 43% are the sliding layers'.
     assert 0.4375 <= c["hit_values_fetched"] / c["hit_values_whole_prefix"] <= 0.625, c
     assert 3 / 7 <= c["hit_window_values_fetched"] / c["hit_values_fetched"] <= 0.6, c
-    assert c["install_layers"] == 4 * c["install_dispatches"] > 0, c
+    # One dispatch a hit hands the device all four layers where every layer was staged before
+    # the gate; after race (2) above a later hit's dispatch carries fewer (one run in four of
+    # this test alone read 272 layers in 70 dispatches), so the CPU holds the bound, not the equality.
+    assert 0 < c["install_layers"] <= 4 * c["install_dispatches"], c
     assert 0 < c["wave_window_pages_skipped"] < c["wave_layer_pages"], c
     assert 0 < c["moe_distinct_experts"] <= c["moe_pairs"] and c["moe_pairs"] % 8 == 0, c
     view = readers.Run(res["rows"], c, None, {})

@@ -9,6 +9,7 @@ import os
 
 import pytest
 
+import accepted
 import readers
 import traffic
 
@@ -109,7 +110,10 @@ def test_toy_cell_with_spans_on(params, suffix):
         spec for spec in (readers.load_layer_metric(m["name"]) for m in run.metrics_for(BENCH, "per_layer", cell))
         if spec["reader"]["kind"] in ("spans", "trace_idle_in")
     ]
-    assert len(specs) == 12 and all(s["name"].endswith(suffix) for s in specs)
+    # By name: the span metrics PR 24 brought that the cell still lists; how many more it
+    # lists by now is not pinned, and each of them has to find its sample too.
+    assert {m + suffix for m in ("after_ready_accounted_pct", "first_wave_wait_p50_ms", "save_snapshot_p50_ms",
+                                 "decode_wave_wait_mean_ms", "idle_in_readback_pct")} <= {s["name"] for s in specs}
     view = readers.Run(res["rows"], res["counters"], trace, {}, spans=res["spans"])
     values = {s["name"]: readers.read_layer_metric(s["name"], view) for s in specs}
     for spec in specs:
@@ -121,7 +125,9 @@ def test_toy_cell_with_spans_on(params, suffix):
     # The program's emit stamps and the benchmark's patch tell the same time
     # (a loose bound: this is a shared CPU), and the four parts cover the
     # time from the prefix being ready to the first token.
-    assert values["emit_stamp_skew_p95_ms" + suffix] < 20.0
+    # (``emit_stamp_skew_p95_ms``'s file went with PR 55, 0.02-0.03 ms in every cell on the
+    # chip; its reader as the file stood.)
+    assert readers.KINDS["spans"](view, accepted.RETIRED_READERS["emit_stamp_skew_p95_ms" + suffix]) < 20.0
     assert 50.0 < values["after_ready_accounted_pct" + suffix] <= 100.5, values
     assert values["first_wave_wait_p50_ms" + suffix] > 0
 

@@ -143,8 +143,16 @@ def old_statements(got, ref):
 
 
 def accepted_files():
+    """The configurations whose file names NO ``program.choices``, chosen by
+    what the file holds: a configuration whose layers choose (every one with
+    a router) names them, is no case, and trips nothing."""
     bench = run.load_json(os.path.join(run.REPO, "BENCHMARK.json"))
-    return [os.path.join(run.REPO, c["file"]) for c in bench["configs"]]
+    paths = [os.path.join(run.REPO, c["file"]) for c in bench["configs"]]
+    return [p for p in paths if "choices" not in run.load_json(p)["program"]]
+
+
+def test_the_two_dense_files_are_among_the_cases():
+    assert {"mistral-7b-v0.3.json", "deepseek-llm-7b.json"} <= set(map(os.path.basename, accepted_files()))
 
 
 @pytest.mark.parametrize("rms,worst,passes", [
@@ -152,7 +160,7 @@ def accepted_files():
 ], ids=["as-the-chip-read", "rms-over", "worst-over", "not-finite"])
 @pytest.mark.parametrize("path", accepted_files(), ids=os.path.basename)
 def test_an_accepted_file_is_compared_as_it_was(path, rms, worst, passes):
-    """Neither accepted file names ``program.choices``: its rows get the plain
+    """Such a file names no ``program.choices``: its rows get the plain
     reference and the statements they got, to the digit, on logits made to
     read a recorded rms and worst."""
     config = run.load_json(path)

@@ -6,18 +6,11 @@
 All times in us on the spans' clock.
 """
 
-import json
-import os
-
 import pytest
 
+import accepted
 import readers
 
-HERE = os.path.dirname(os.path.abspath(__file__))
-REPO = os.path.dirname(os.path.dirname(HERE))
-with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-    BENCH = json.load(f)
-REUSE_CELLS = ["mistral7b-prefix-reuse", "deepseek7b-prefix-reuse", "trinity-mini-long-prefix-reuse"]
 A, B, FAILED = 31, 32, 33
 
 
@@ -63,16 +56,10 @@ def parent_spans():
 
 
 def test_file_agrees_with_its_benchmark_json_entry():
-    spec = readers.load_layer_metric(METRIC)
-    assert set(spec) == {"name", "unit", "better", "source", "layer", "moves", "what", "reader"}
-    (entry,) = [m for m in BENCH["per_layer"] if m["name"] == METRIC]
-    assert set(entry) == {"name", "unit", "better", "source", "layer", "moves", "workloads"}
-    assert all(spec[k] == entry[k] for k in set(entry) - {"workloads"})
-    assert entry["workloads"] == REUSE_CELLS and entry["moves"] == "tokens_per_s"
+    spec, entry = accepted.agreed(METRIC)
+    assert entry["workloads"] == accepted.cells_reporting("tokens_per_s") and entry["moves"] == "tokens_per_s"
     assert spec["layer"] == "Traffic / scheduler"
     assert spec["reader"]["kind"] == "spans" and spec["source"] == "program_span"
-    # The last entry of the list: put at its end, nothing in between.
-    assert BENCH["per_layer"][-1]["name"] == METRIC
 
 
 def test_it_reads_the_mean_of_the_tails_that_reached_their_acknowledgement():

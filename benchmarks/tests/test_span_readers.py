@@ -10,6 +10,7 @@ import os
 
 import pytest
 
+import accepted
 import readers
 import span_readers
 
@@ -33,9 +34,14 @@ def view(profile=True, rows=None):
     return readers.Run(FIX["rows"] if rows is None else rows, {}, None, {}, spans=spans)
 
 
+# Three of the twenty-four went with PR 55 (a constant on the chip in every cell that listed
+# them); their readers stand in ``accepted.py`` as the files stood.
+RETIRED = ("alloc_wait_p95_ms.chat", "emit_stamp_skew_p95_ms.reuse", "emit_stamp_skew_p95_ms.chat")
+
+
 def read(name, run):
-    spec = readers.load_layer_metric(name)
-    return span_readers.KINDS[spec["reader"]["kind"]](run, spec["reader"])
+    reader = accepted.RETIRED_READERS[name] if name in RETIRED else readers.load_layer_metric(name)["reader"]
+    return span_readers.KINDS[reader["kind"]](run, reader)
 
 
 # ms or %; the arithmetic is in the comments of the fixture's generator
@@ -119,6 +125,8 @@ def test_span_metric_file_is_listed_in_benchmark_json(path):
         assert listed[part]["workloads"] == entry["workloads"]
 
 
-def test_twelve_metrics_two_suffixes():
+def test_each_of_the_twelve_has_a_file_under_both_suffixes_or_is_named_as_retired():
+    """By name: the span metrics that came after PR 24 are other tests'."""
     names = {os.path.basename(p)[: -len(".json")] for p in SPAN_FILES}
-    assert names == {m + s for m in EXPECTED for s in (".reuse", ".chat")}
+    wanted = {m + s for m in EXPECTED for s in (".reuse", ".chat")}
+    assert wanted - set(RETIRED) <= names and not set(RETIRED) & names and set(RETIRED) <= wanted
