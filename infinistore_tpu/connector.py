@@ -335,8 +335,9 @@ class KVConnector:
         # counted apart: the part of a hit that does not grow with the
         # prefix), the same two in bytes and, of the bytes
         # fetched, those of a recurrent state (kind "state": what does not
-        # grow with the prefix); of the saves, the bytes written and those
-        # of them by the tensor's kind (kv, state, latent); the bytes the
+        # grow with the prefix) and those of a latent layer's index keys
+        # (kind "index"); of the saves, the bytes written and those
+        # of them by the tensor's kind (kv, state, latent, index); the bytes the
         # hits' layer reads landed and the microseconds in which at least one such read was in
         # flight (with the gauge and the perf_counter mark that union is
         # kept by). Of the installs: bytes handed to the device and the
@@ -354,9 +355,9 @@ class KVConnector:
             "hit_values_fetched": 0, "hit_values_whole_prefix": 0,
             "hit_window_values_fetched": 0,
             "hit_bytes_fetched": 0, "hit_bytes_whole_prefix": 0,
-            "hit_state_bytes_fetched": 0,
+            "hit_state_bytes_fetched": 0, "hit_index_bytes_fetched": 0,
             "save_bytes": 0, "save_kv_bytes": 0,
-            "save_state_bytes": 0, "save_latent_bytes": 0,
+            "save_state_bytes": 0, "save_latent_bytes": 0, "save_index_bytes": 0,
             "hit_read_bytes": 0, "hit_read_busy_us": 0.0,
             "hit_reads_in_flight": 0, "hit_read_busy_mark_s": 0.0,
             "install_upload_bytes": 0, "install_upload_us": 0.0,

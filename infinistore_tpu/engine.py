@@ -1225,9 +1225,17 @@ class ContinuousBatchingHarness:
         # its cache's shape come with the configuration: no model file is
         # named here.
         self.spec = config.kv_spec(num_blocks)
-        if self.spec.has_state and drafter is not None:
-            # A verified chunk's rejected rows would stay absorbed.
-            raise ValueError("a cache with a recurrent state decodes one token a row: no drafter")
+        # A prompt is computed a block at a time (``_compute_by_blocks``)
+        # where a recurrent state absorbs a token once, and where the model's
+        # resume step takes no more than a block's part (``ServingSteps``).
+        self.by_blocks = self.spec.has_state or config.steps.resume_in_block
+        if self.by_blocks and drafter is not None:
+            # The first wave lands the prompt's last token, one token a row;
+            # with a state, a verified chunk's rejected rows would stay absorbed.
+            raise ValueError(
+                "a model served a block at a time (a recurrent state, or a resume step "
+                "inside one block) decodes one token a row: no drafter"
+            )
         self.caches = self.spec.make_caches()
         self.pool = BlockPool(num_blocks)
         self.gate = DeviceGate()
@@ -1292,14 +1300,15 @@ class ContinuousBatchingHarness:
         return pad
 
     def _compute_by_blocks(self, token_ids, table: np.ndarray, start_block: int):
-        """A cache with a recurrent state (``PagedKVCacheSpec.has_state``):
-        ``token_ids`` from block ``start_block`` on, cut at block boundaries
-        through the model's resume step, one program a piece, so that every
-        block's slot holds the state at its end (what its save writes and a
-        later hit installs) and a miss runs the very programs a hit's resume
-        runs. Each piece that completes a block is a ``state_snapshot`` span
-        (``block``), a child of the caller's ``compute``. Returns at
-        DISPATCH. Cache-mutating: caller holds the exclusive gate."""
+        """A model served a block at a time (``self.by_blocks``): ``token_ids``
+        from block ``start_block`` on, cut at block boundaries through the
+        model's resume step, one program a piece, so that a miss runs the very
+        programs a hit's resume runs and, where the cache has a recurrent
+        state, every block's slot holds the state at its end (what its save
+        writes and a later hit installs). Each piece that completes a block
+        is a ``state_snapshot`` span (``block``), a child of the caller's
+        ``compute``. Returns at DISPATCH. Cache-mutating: caller holds the
+        exclusive gate."""
         bt = self.config.block_tokens
         padded = self._padded_table(table)
         for start in range(start_block * bt, len(token_ids), bt):
@@ -1319,7 +1328,7 @@ class ContinuousBatchingHarness:
         """Whole-prompt prefill into this request's blocks (cache-mutating:
         caller holds the exclusive gate)."""
         t0 = time.perf_counter()
-        if self.spec.has_state:
+        if self.by_blocks:
             self._compute_by_blocks(token_ids, table, 0)
         else:
             _, self.caches = self.config.steps.prefill(
@@ -1346,13 +1355,13 @@ class ContinuousBatchingHarness:
         decode rows that each walk the padded table. Returns at DISPATCH;
         the device time is first waited for by whoever reads the cache next
         (the save's snapshot). Cache-mutating: caller holds the exclusive
-        gate. A cache with a recurrent state takes the suffix a block at a
-        time (``_compute_by_blocks``)."""
+        gate. A model served a block at a time takes the suffix so too
+        (``_compute_by_blocks``)."""
         bt = self.config.block_tokens
         self.resumes += 1
         self.resume_tokens += len(token_ids) - start_block * bt
         self.resume_pages += -(-len(token_ids) // bt)
-        if self.spec.has_state:
+        if self.by_blocks:
             return self._compute_by_blocks(token_ids, table, start_block)
         suffix = jnp.asarray(token_ids[start_block * bt :], jnp.int32)
         _, self.caches = self.config.steps.resume(
@@ -1607,9 +1616,9 @@ class ContinuousBatchingHarness:
         background; the class is recorded on the stats so TTFT percentiles
         split by class."""
         bt = self.config.block_tokens
-        if self.spec.has_state:
-            # A recurrent state absorbs a token ONCE, and the first wave
-            # decodes the prompt's last token: the compute phase lands every
+        if self.by_blocks:
+            # The first wave decodes the prompt's last token (a recurrent
+            # state absorbs a token ONCE): the compute phase lands every
             # token but that one (``landed``), so the prompt's complete
             # blocks, which a save writes and a hit may install, are those
             # of ``landed``; the prompt keeps a part-full last block.
@@ -1626,7 +1635,7 @@ class ContinuousBatchingHarness:
         if not ok or total_blocks > self.max_req_blocks:
             raise ValueError(
                 f"prompt + generation must span 1..{self.max_req_blocks} "
-                "blocks (prompt in complete blocks; with a recurrent state, any "
+                "blocks (prompt in complete blocks; served a block at a time, any "
                 "prompt that generates)"
             )
         self.live += 1
@@ -2200,7 +2209,10 @@ class ContinuousBatchingHarness:
             "wave_row_slices": self.wave.row_slices,
             # What the model's wave step counted itself (models/serving.py
             # ``aux``): an expert model's ``moe_pairs`` and
-            # ``moe_distinct_experts``; nothing for a model that counts nothing.
+            # ``moe_distinct_experts``, a selecting model's
+            # ``dsa_keys_selected`` of ``dsa_keys_in_context`` (the positions
+            # its rows' attention kept of those they could have read);
+            # nothing for a model that counts nothing.
             **self.wave.step_counters(),
             # The device gate's ledger (``DeviceGate.counters``): who held
             # it for how long, and every wait cut by whom it stood behind.

@@ -85,6 +85,12 @@ class ServingSteps(NamedTuple):
     prefill: Callable
     resume: Callable
     wave: Callable
+    # Whether ``resume`` takes a chunk INSIDE ONE BLOCK only. The engine then
+    # cuts every prompt at block boundaries through it, a miss and a hit's
+    # suffix alike, keeps the prompt's part-full last block and lands its last
+    # token in the first wave: what it does for a cache with a recurrent
+    # state, whose models' chunks lie in one block for the state's sake.
+    resume_in_block: bool = False
 
 
 class WaveLayout(NamedTuple):

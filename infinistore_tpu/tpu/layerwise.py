@@ -891,6 +891,9 @@ class LayerwisePrefetch:
                 counters["hit_state_bytes_fetched"] += sum(
                     m * t.nbytes for t, _, m, _ in plan if t.kind == "state"
                 )
+                counters["hit_index_bytes_fetched"] += sum(
+                    m * t.nbytes for t, _, m, _ in plan if t.kind == "index"
+                )
             if not self._staged[layer].done():
                 self._staged[layer].set_result(layer % self.regions)
             if layer == self.num_layers - 1:

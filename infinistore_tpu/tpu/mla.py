@@ -146,12 +146,14 @@ def latent_decode_rows(q, latent, row_tables, seq_lens, *, rank: int, scale: flo
 
 
 def latent_chunk_attention(q, latent, block_table, start_pos, w_kvb, *, rank: int,
-                           nope: int, scale: float):
+                           nope: int, scale: float, bias=None):
     """q: [S, H, nope + rope] (the chunk's queries, unabsorbed); latent:
     [blocks, rank + rope, bt] with the chunk's own rows already written;
     block_table: [max_blocks] int32; start_pos: [] int32, the chunk's first
     position; w_kvb: [rank, H, nope + v] the latents' up-projection to each
-    head's keys and values. Query i attends positions <= start_pos + i.
+    head's keys and values. Query i attends positions <= start_pos + i, and
+    where ``bias`` is given ([max_blocks, S, bt] float32, page-major:
+    ``tpu/dsa.py``'s selection, 0 or -1e30) those of them it leaves at 0.
     Returns [S, H, v] float32. The loop walks the request's real pages only."""
     s, h, _ = q.shape
     bt = latent.shape[2]
@@ -168,7 +170,11 @@ def latent_chunk_attention(q, latent, block_table, start_pos, w_kvb, *, rank: in
         sc = einsum_f32("shd,chd->hsc", q_n, kv[..., :nope])
         sc = sc + einsum_f32("shd,dc->hsc", q_r, page[rank:])
         k_pos = j * bt + jnp.arange(bt, dtype=jnp.int32)
-        sc = jnp.where(k_pos[None, None, :] <= q_pos[None, :, None], sc * scale, _NEG)
+        seen = k_pos[None, None, :] <= q_pos[None, :, None]
+        sc = sc * scale
+        if bias is not None:
+            sc = sc + jnp.take(bias, j, axis=0)[None]
+        sc = jnp.where(seen, sc, _NEG)
         m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
         alpha = jnp.exp(m - m_new)
         p = jnp.exp(sc - m_new)

@@ -39,8 +39,9 @@ class CacheTensor:
     # state, which the last block's value replaces whole; window /
     # block_tokens: a sliding layer). Every block is SAVED either way.
     last_blocks: Optional[int] = None
-    # What the ledger counts it as: "kv", "latent" (grows with the prefix)
-    # or "state" (a recurrent layer's state and its convolution tail).
+    # What the ledger counts it as: "kv", "latent", "index" (a latent layer's
+    # index keys; all three grow with the prefix) or "state" (a recurrent
+    # layer's state and its convolution tail).
     kind: str = "kv"
 
     @functools.cached_property

@@ -750,6 +750,78 @@ def test_mellum_entries_compile_at_published_widths_and_update_the_cache_in_plac
         assert kernels - want, kernels  # the grouped matmul's
 
 
+# The seventh model file's serving entries (models/glm_dsa.py) at the published
+# widths of its configuration's file: hidden 6,144, 64 heads over a query
+# latent of 2,048 and a latent cache of 512 + 64, an indexer of 32 x 128 that
+# keeps 2,048 positions, 16 of 256 experts of width 2,048, pages of 1,024
+# tokens, a table of 33 blocks; one dense and one expert layer and a small
+# vocabulary, so that the four compile in a minute.
+GLM_ENTRIES = ["verify_step_ragged", "packed_wave", "miss-piece", "hit-question"]
+
+
+@pytest.mark.parametrize("entry", GLM_ENTRIES)
+def test_glm_dsa_entries_compile_at_published_widths_and_update_both_cache_tensors_in_place(v5e, monkeypatch, entry):
+    """Each entry compiles for the v5e with its Mosaic kernels (the scoring
+    pass, the selection, the masked latent decode; a piece's grouped matmul),
+    holds an ``input_output_alias`` for BOTH tensors of every layer (the
+    aliased bytes the whole cache's), and moves no array of a latent or an
+    index cache's shape through a ``copy``, ``copy-start`` or ``slice-start``."""
+    from infinistore_tpu.models import glm_dsa, serving
+
+    monkeypatch.setattr(paged, "_use_pallas", lambda: True)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "benchmarks", "configs", "glm-5.json")) as f:
+        real = json.load(f)
+    fields = {k: real[v] for k, v in real["program"]["fields"].items()}
+    fields.update(vocab=1031 if entry != "packed_wave" else 1033, n_layers=2)
+    cfg = glm_dsa.GlmDsaConfig(block_tokens=real["serving"]["block_tokens"], dtype=jnp.bfloat16, **fields)
+    assert (cfg.dim, cfg.n_heads, cfg.latent_width, cfg.index_topk, cfg.held) == (6144, 64, 576, 2048, (0, 16))
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=v5e)
+    i32 = lambda *shape: s(shape, jnp.int32)
+    shapes = jax.eval_shape(lambda k: glm_dsa.init_params(cfg, k), jax.random.key(0))
+    params = jax.tree.map(lambda a: s(a.shape, a.dtype), shapes)
+    blocks, table = real["serving"]["cache_blocks"], 33
+    spec = cfg.kv_spec(blocks)
+    caches = [
+        tuple(s((blocks, *t.block_shape), t.dtype) for t in spec.layer_tensors(layer))
+        for layer in range(cfg.n_layers)
+    ]
+    if entry == "verify_step_ragged":
+        rows = 4
+        args = (
+            params, i32(rows), i32(rows), i32(rows), i32(128), i32(129), i32(rows), caches,
+            i32(rows, table),
+        )
+        jitted, static = glm_dsa.verify_step_ragged, {"config": cfg, "max_blocks": table}
+    elif entry == "packed_wave":  # the same bucket as the decoder launches it
+        layout = serving.WaveLayout(rows=4, tables=4, pages=128)
+        args = (params, i32(layout.size(table)), i32(serving.FEED_ROWS), caches)
+        jitted = serving.verify_step_ragged
+        static = {"config": cfg, "max_blocks": table, "layout": layout}
+    else:
+        rows = 1024 if entry == "miss-piece" else 127
+        args, static = (params, i32(rows), i32(), caches, i32(table)), {"config": cfg}
+        jitted = glm_dsa.resume_chunk
+    lowered = jitted.trace(*args, **static).lower(lowering_platforms=("tpu",))
+    kernels = set(re.findall(r'kernel_name = "(\w+)"', lowered.as_text()))
+    exe = lowered.compile()
+    text = exe.as_text()
+    header = text.split("\n", 1)[0]
+    assert len(re.findall(r"\(\d+, \{\}, (?:may|must)-alias\)", header)) == 2 * cfg.n_layers, header
+    assert exe.memory_analysis().alias_size_in_bytes == cfg.n_layers * blocks * (1152 + 256) * 1024
+    moved = re.findall(
+        rf"^.* = [^=]*bf16\[{blocks},(?:576|128),1024\][^=]* (?:copy|copy-start|slice-start)\(.*$",
+        text, flags=re.M,
+    )
+    assert not moved, moved[:3]
+    if entry in ("verify_step_ragged", "packed_wave"):
+        want = {"_index_decode_kernel", "_select_kernel", "_sparse_decode_kernel", "_moe_wave_kernel"}
+    else:
+        want = {"_index_chunk_kernel", "_select_kernel"}
+        assert kernels - want, kernels  # the grouped matmul's
+    assert want <= kernels, kernels
+
+
 # The grouped expert product under the tiles its rule hands it (models/afmoe.py
 # ``_gmm_tiling``), at the published widths of the routed configurations'
 # files and the token counts the benchmark's traffic gives them: one expert
