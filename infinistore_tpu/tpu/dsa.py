@@ -27,13 +27,17 @@ select  per row the ``min(len, k)`` positions ``s < len`` of largest score,
 attend  ``tpu/mla.py``'s two latent attentions with that bias added to the
         scores, so every page is still READ and a position outside the set
         weighs nothing: ``mla_sparse_decode_pallas`` (the absorbed form) and
-        ``mla.latent_chunk_attention(..., bias=)``. Reading only the selected
-        latents wants a gather of 1,152-byte rows out of a cache whose token
-        axis is the minor one; PERF.md (PR 56) says what that would cost.
+        ``mla.latent_chunk_attention(..., bias=)`` (on the chip ONE kernel,
+        ``mla_chunk_attention_pallas``, the bias a block fetched once a head
+        group and the scores in VMEM; the page loop in plain XLA elsewhere).
+        Reading only the selected latents wants a gather of 1,152-byte rows
+        out of a cache whose token axis is the minor one; PERF.md (PR 56, and
+        PR 57 for what it would add now) says what that would cost.
 
 Scores and biases travel PAGE-MAJOR, ``[max_blocks, rows, block_tokens]``: a
 page of a row tile is then a leading-axis index for every kernel here (no
-dynamic slice along lanes), and a chunk's page loop indexes it the same way.
+dynamic slice along lanes), and the chunk's attention (its kernel's page axis,
+its XLA twin's loop) indexes it the same way.
 """
 
 import functools

@@ -5,7 +5,8 @@ one dense layer and two expert layers, seeded float32 weights.
 
 - the selection (the sort, and the Pallas search interpreted) against
   ``lax.top_k`` as SETS, ties, short rows and empty rows included; the scoring
-  kernels and the masked latent decode against plain mathematics;
+  kernels, the masked latent decode and the chunk's attention under a bias
+  (the page loop and its Pallas kernel, interpreted) against plain mathematics;
 - the interleaved rotation, the program's and the reference's, against a
   product of complex numbers;
 - the program through the harness, the connector and a store (a miss and its
@@ -185,29 +186,82 @@ def test_the_masked_latent_decode_attends_the_selected_positions_alone(form):
         np.testing.assert_allclose(got[t], want, rtol=1e-4, atol=1e-4)
 
 
-def test_the_chunk_attention_under_a_bias_attends_the_selected_positions_alone():
-    s, h, rank, rope, nope, vdim, bt, blocks = 8, 4, 32, 8, 16, 16, 8, 12
+def _chunk_attention(form, *args, **kwargs):
+    """``mla.latent_chunk_attention``'s two forms: the page loop in plain XLA
+    and the Pallas kernel, interpreted."""
+    if form == "xla":
+        return mla.latent_chunk_attention_xla(*args, **kwargs)
+    return mla.mla_chunk_attention_pallas(*args, **kwargs, interpret=True)
+
+
+def _chunk_case(start, s, k, pages=17, h=4, rank=32, rope=8, nope=16, vdim=16, bt=8, blocks=24):
     keys = jax.random.split(jax.random.key(565), 5)
     q = jax.random.normal(keys[0], (s, h, nope + rope), jnp.float32)
     latent = jax.random.normal(keys[1], (blocks, rank + rope, bt), jnp.float32)
     w_kvb = jax.random.normal(keys[2], (rank, h, nope + vdim), jnp.float32) / np.sqrt(rank)
-    table = jnp.asarray([7, 2, 9, 0], jnp.int32)
-    start = 16  # the chunk is the table's third block
+    table = jax.random.permutation(keys[4], blocks)[:pages].astype(jnp.int32)
     lens = start + jnp.arange(s, dtype=jnp.int32) + 1
-    bias = dsa.select_xla(jax.random.normal(keys[3], (4, s, bt), jnp.float32), lens, k=5)
-    got = np.asarray(mla.latent_chunk_attention(
-        q, latent, table, jnp.int32(start), w_kvb, rank=rank, nope=nope, scale=0.2, bias=bias
+    bias = dsa.select_xla(jax.random.normal(keys[3], (pages, s, bt), jnp.float32), lens, k=k)
+    return q, latent, table, w_kvb, bias, (rank, nope)
+
+
+# (first position, rows, positions a row keeps): the table's third block; a
+# chunk that starts mid-block; a hit's 127-row question (sixteen pages of 8);
+# a selection that leaves every row ONE position.
+BIASED_CHUNKS = {
+    "third-block": (16, 8, 5), "mid-block": (19, 5, 5), "question-127": (1, 127, 5),
+    "one-position": (16, 8, 1),
+}
+
+
+@pytest.mark.parametrize("case", BIASED_CHUNKS)
+@pytest.mark.parametrize("form", ["xla", "pallas"])
+def test_the_chunk_attention_under_a_bias_attends_the_selected_positions_alone(form, case):
+    start, s, k = BIASED_CHUNKS[case]
+    q, latent, table, w_kvb, bias, (rank, nope) = _chunk_case(start, s, k)
+    got = np.asarray(_chunk_attention(
+        form, q, latent, table, jnp.int32(start), w_kvb, rank=rank, nope=nope, scale=0.2, bias=bias
     ))
     ctx = np.concatenate([np.asarray(latent[b]).T for b in np.asarray(table)])
     kv = np.einsum("cr,rhd->chd", ctx[:, :rank], np.asarray(w_kvb))
     for i, chosen in enumerate(_sets(bias)):
         ids = sorted(chosen)
-        assert len(ids) == 5 and max(ids) <= start + i
+        assert len(ids) == min(k, start + i + 1) and max(ids) <= start + i
         sc = np.einsum("hd,chd->hc", np.asarray(q[i, :, :nope]), kv[ids][..., :nope])
         sc = (sc + np.asarray(q[i, :, nope:]) @ ctx[ids][:, rank:].T) * 0.2
         p = np.exp(sc - sc.max(axis=1, keepdims=True))
         want = np.einsum("hc,chd->hd", p / p.sum(axis=1, keepdims=True), kv[ids][..., nope:])
         np.testing.assert_allclose(got[i], want, rtol=1e-4, atol=1e-4)
+        if k == 1:  # one position: the row IS that position's values
+            np.testing.assert_allclose(got[i], kv[ids[0]][..., nope:], rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("form", ["xla", "pallas"])
+def test_the_chunk_attention_under_a_bias_reads_no_page_past_its_context(form):
+    """A table of 17 entries, a context of three pages: the fourteen pages
+    behind it hold NaN and neither form reads one."""
+    start, s, k = 16, 8, 5
+    q, latent, table, w_kvb, bias, (rank, nope) = _chunk_case(start, s, k)
+    kw = dict(rank=rank, nope=nope, scale=0.2, bias=bias)
+    want = _chunk_attention(form, q, latent, table, jnp.int32(start), w_kvb, **kw)
+    poisoned = latent.at[table[3:]].set(jnp.nan)
+    got = _chunk_attention(form, q, poisoned, table, jnp.int32(start), w_kvb, **kw)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("case", BIASED_CHUNKS)
+def test_the_chunk_kernel_is_the_page_loop_it_replaced(case):
+    """The kernel against its twin directly: one arithmetic (float32 here), the
+    sums in another order."""
+    start, s, k = BIASED_CHUNKS[case]
+    q, latent, table, w_kvb, bias, (rank, nope) = _chunk_case(start, s, k)
+    args = (q, latent, table, jnp.int32(start), w_kvb)
+    kw = dict(rank=rank, nope=nope, scale=0.2, bias=bias)
+    np.testing.assert_allclose(
+        _chunk_attention("pallas", *args, **kw), _chunk_attention("xla", *args, **kw),
+        rtol=2e-5, atol=2e-5,
+    )
 
 
 @pytest.mark.parametrize("first", [0, 16])

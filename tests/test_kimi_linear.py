@@ -5,8 +5,10 @@ expert layers (KDA, KDA, KDA, MLA, KDA), seeded float32 weights.
 
 - the chunked KDA program against the token-by-token recurrence for any cut
   into chunks, with an initial state, and the state at every boundary;
-- the absorbed latent decode (plain XLA and the Pallas kernel, interpreted)
-  against unabsorbed MLA;
+- the absorbed latent decode and the chunk's unabsorbed attention (each in
+  plain XLA and as its Pallas kernel, interpreted) against unabsorbed MLA: a
+  chunk from mid-block, across a boundary, of a question's 127 rows, over a
+  table whose later pages are NaN, with no bias and under one of zeros;
 - the program through the harness, the connector and a store (a miss and its
   decode through the cache across a block boundary, a full hit, a partial
   hit) against ``benchmarks/reference_kimi_linear.py`` following the choices
@@ -164,18 +166,94 @@ def test_the_absorbed_latent_decode_is_unabsorbed_mla(form):
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=0)
 
 
-def test_the_chunk_against_the_paged_prefix_is_unabsorbed_mla():
-    q1, latent, w_kvb, tables, _, (rank, nope, vdim) = _mla_case(rows=1)
-    start, s = 11, 6  # a chunk inside block 1 of the row's table
+def _chunk_attention(form, *args, **kwargs):
+    """The chunk's latent attention in one of its two forms (``tpu/mla.py``:
+    the page loop in plain XLA, the Pallas kernel interpreted)."""
+    if form == "xla":
+        return mla.latent_chunk_attention_xla(*args, **kwargs)
+    return mla.mla_chunk_attention_pallas(*args, **kwargs, interpret=True)
+
+
+# (first position, rows): inside block 1 of the row's table; from a block's
+# first token; from mid-block across the boundary into the next; the 127 rows
+# of a hit's question (blocks of 8: it spans sixteen pages).
+CHUNKS = [(11, 6), (8, 8), (5, 9), (1, 127)]
+
+
+@pytest.mark.parametrize("chunk", CHUNKS, ids=lambda c: f"{c[1]}-rows-from-{c[0]}")
+@pytest.mark.parametrize("form", ["xla", "pallas"])
+def test_the_chunk_against_the_paged_prefix_is_unabsorbed_mla(form, chunk):
+    start, s = chunk
+    q1, latent, w_kvb, tables, _, (rank, nope, vdim) = _mla_case(rows=1, blocks=20, table=16)
     q = jax.random.normal(jax.random.key(9), (s, 4, q1.shape[-1]))
-    got = mla.latent_chunk_attention(
-        q, latent, tables[0], jnp.int32(start), w_kvb, rank=rank, nope=nope,
+    got = _chunk_attention(
+        form, q, latent, tables[0], jnp.int32(start), w_kvb, rank=rank, nope=nope,
         scale=float(q.shape[-1] ** -0.5),
     )
-    for i in range(s):
-        want = _unabsorbed(q[i : i + 1], latent, w_kvb, tables[:1],
-                           jnp.asarray([start + i + 1]), (rank, nope, vdim))
-        np.testing.assert_allclose(got[i], want[0], atol=2e-5, rtol=0)
+    # ``_unabsorbed`` a row, every row at once: the whole table's context,
+    # a row's later positions masked.
+    ctx = np.concatenate([np.asarray(latent[b], np.float64).T for b in np.asarray(tables[0])])
+    kv = np.einsum("cr,rhd->chd", ctx[:, :rank], np.asarray(w_kvb, np.float64))
+    qs = np.asarray(q, np.float64)
+    sc = np.einsum("shd,chd->shc", qs[..., :nope], kv[..., :nope])
+    sc = (sc + np.einsum("shd,cd->shc", qs[..., nope:], ctx[:, rank:])) / np.sqrt(q.shape[-1])
+    later = np.arange(ctx.shape[0])[None, :] > start + np.arange(s)[:, None]
+    sc = np.where(later[:, None, :], -np.inf, sc)
+    p = np.exp(sc - sc.max(axis=-1, keepdims=True))
+    want = np.einsum("shc,chd->shd", p / p.sum(axis=-1, keepdims=True), kv[..., nope:])
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=0)
+    first = _unabsorbed(q[:1], latent, w_kvb, tables[:1], jnp.asarray([start + 1]), (rank, nope, vdim))
+    np.testing.assert_allclose(got[0], first[0], atol=2e-5, rtol=0)
+
+
+@pytest.mark.parametrize("form", ["xla", "pallas"])
+def test_the_chunk_reads_no_page_past_its_context(form):
+    """The table's entries past the chunk's last position name pages of NaN:
+    neither form reads one (the loop stops at the context's pages; the kernel's
+    grid clamps a later step to the last real page and computes nothing)."""
+    q1, latent, w_kvb, tables, _, (rank, nope, vdim) = _mla_case(rows=1)
+    start, s = 11, 4  # the context ends in block 1 of 4
+    q = jax.random.normal(jax.random.key(10), (s, 4, q1.shape[-1]))
+    kw = dict(rank=rank, nope=nope, scale=float(q.shape[-1] ** -0.5))
+    want = _chunk_attention(form, q, latent, tables[0], jnp.int32(start), w_kvb, **kw)
+    poisoned = latent.at[tables[0, 2:]].set(jnp.nan)
+    got = _chunk_attention(form, q, poisoned, tables[0], jnp.int32(start), w_kvb, **kw)
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("form", ["xla", "pallas"])
+def test_the_chunk_without_a_bias_is_the_chunk_under_a_bias_of_zeros(form):
+    """``bias=None`` is a branch of the program, not a tensor of zeros: the
+    two give the same result to the bit."""
+    q1, latent, w_kvb, tables, _, (rank, nope, vdim) = _mla_case(rows=1)
+    start, s = 9, 7
+    q = jax.random.normal(jax.random.key(11), (s, 4, q1.shape[-1]))
+    kw = dict(rank=rank, nope=nope, scale=float(q.shape[-1] ** -0.5))
+    plain = _chunk_attention(form, q, latent, tables[0], jnp.int32(start), w_kvb, **kw)
+    zeros = jnp.zeros((tables.shape[1], s, latent.shape[2]), jnp.float32)
+    under = _chunk_attention(form, q, latent, tables[0], jnp.int32(start), w_kvb, bias=zeros, **kw)
+    np.testing.assert_array_equal(plain, under)
+
+
+def test_the_chunk_dispatcher_takes_the_kernel_on_the_chip_alone(monkeypatch):
+    """``latent_chunk_attention`` asks ``paged._use_pallas`` and nothing else:
+    off the chip it IS the page loop."""
+    from infinistore_tpu.tpu import paged
+
+    q1, latent, w_kvb, tables, _, (rank, nope, vdim) = _mla_case(rows=1)
+    q = jax.random.normal(jax.random.key(12), (5, 4, q1.shape[-1]))
+    args = (q, latent, tables[0], jnp.int32(3), w_kvb)
+    kw = dict(rank=rank, nope=nope, scale=0.2)
+    assert not paged._use_pallas()
+    np.testing.assert_array_equal(
+        mla.latent_chunk_attention(*args, **kw), mla.latent_chunk_attention_xla(*args, **kw)
+    )
+    called = []
+    monkeypatch.setattr(paged, "_use_pallas", lambda: True)
+    monkeypatch.setattr(mla, "mla_chunk_attention_pallas", lambda *a, **k: called.append(k) or "kernel")
+    assert mla.latent_chunk_attention(*args, **kw) == "kernel"
+    assert called == [dict(kw, bias=None)]
 
 
 # ---------------------------------------------------------------------------

@@ -750,6 +750,36 @@ def test_mellum_entries_compile_at_published_widths_and_update_the_cache_in_plac
         assert kernels - want, kernels  # the grouped matmul's
 
 
+def _page_loops(text, heads, rows, vdim):
+    """The ``while`` instructions of a compiled program whose carry holds the
+    XLA page loop's running max / sum / accumulator (``mla.latent_chunk
+    _attention_xla``: ``[H, S, 1]``, ``[H, S, 1]``, ``[H, S, v]`` float32)."""
+    stat, acc = rf"f32\[{heads},{rows},1\]", rf"f32\[{heads},{rows},{vdim}\]"
+    return re.findall(rf"^\s*%?[\w.\-]+ = \(.*{stat}.*{stat}.*{acc}.*\) while\(.*$", text, flags=re.M)
+
+
+def _chunk_program(jitted, args, static):
+    """(kernel names, compiled text, bytes of temporaries) of a chunk program."""
+    lowered = jitted.trace(*args, **static).lower(lowering_platforms=("tpu",))
+    kernels = set(re.findall(r'kernel_name = "(\w+)"', lowered.as_text()))
+    exe = lowered.compile()
+    return kernels, exe.as_text(), exe.memory_analysis().temp_size_in_bytes
+
+
+def _with_the_page_loop(monkeypatch, jitted, args, static):
+    """The same chunk program as the parent of PR 57 compiled it: the latent
+    attention a loop over pages in plain XLA."""
+    from infinistore_tpu.tpu import mla
+
+    with monkeypatch.context() as m:
+        m.setattr(mla, "latent_chunk_attention", mla.latent_chunk_attention_xla)
+        jax.clear_caches()
+        try:
+            return _chunk_program(jitted, args, static)
+        finally:
+            jax.clear_caches()
+
+
 # The seventh model file's serving entries (models/glm_dsa.py) at the published
 # widths of its configuration's file: hidden 6,144, 64 heads over a query
 # latent of 2,048 and a latent cache of 512 + 64, an indexer of 32 x 128 that
@@ -762,7 +792,8 @@ GLM_ENTRIES = ["verify_step_ragged", "packed_wave", "miss-piece", "hit-question"
 @pytest.mark.parametrize("entry", GLM_ENTRIES)
 def test_glm_dsa_entries_compile_at_published_widths_and_update_both_cache_tensors_in_place(v5e, monkeypatch, entry):
     """Each entry compiles for the v5e with its Mosaic kernels (the scoring
-    pass, the selection, the masked latent decode; a piece's grouped matmul),
+    pass, the selection, the masked latent decode; a piece's latent attention
+    and grouped matmul, and no loop over pages),
     holds an ``input_output_alias`` for BOTH tensors of every layer (the
     aliased bytes the whole cache's), and moves no array of a latent or an
     index cache's shape through a ``copy``, ``copy-start`` or ``slice-start``."""
@@ -809,17 +840,84 @@ def test_glm_dsa_entries_compile_at_published_widths_and_update_both_cache_tenso
     header = text.split("\n", 1)[0]
     assert len(re.findall(r"\(\d+, \{\}, (?:may|must)-alias\)", header)) == 2 * cfg.n_layers, header
     assert exe.memory_analysis().alias_size_in_bytes == cfg.n_layers * blocks * (1152 + 256) * 1024
+    if entry in ("miss-piece", "hit-question"):
+        # The chunk's latent attention is the kernel: no loop over pages is
+        # left in the program (no clock: the carry's shapes name it), and the
+        # page loop's score tensors are not among the temporaries.
+        assert "_chunk_kernel" in kernels, kernels
+        assert text.count("mla_chunk_attention_pallas") >= cfg.n_layers
+        assert not _page_loops(text, cfg.n_heads, rows, cfg.v_head_dim)
+        if entry == "miss-piece":
+            was, loop_text, loop_temp = _with_the_page_loop(monkeypatch, jitted, args, static)
+            assert "_chunk_kernel" not in was
+            assert _page_loops(loop_text, cfg.n_heads, rows, cfg.v_head_dim)
+            # 64 x 1,024 x 1,024 float32 is 268 MB a score tensor, but XLA had
+            # laid the loop's over the buffers of the selection (its scores and
+            # bias, 276 MB, are the program's peak with or without an
+            # attention): the loop stood 88.6 MB above that floor, the kernel
+            # stands on it.
+            assert loop_temp - exe.memory_analysis().temp_size_in_bytes >= 64 << 20
     moved = re.findall(
         rf"^.* = [^=]*bf16\[{blocks},(?:576|128),1024\][^=]* (?:copy|copy-start|slice-start)\(.*$",
         text, flags=re.M,
     )
+    # Since PR 57 no loop stands between a piece's layers, and XLA's memory
+    # space assignment PREFETCHES index pages into its fast memory (a result
+    # in ``S(1)``) under the attention kernel: a read the scoring pass would
+    # have made, not a cache laid out again.
+    moved = [m for m in moved if "S(1)}" not in re.split(r" (?:copy|copy-start|slice-start)\(", m)[0]]
     assert not moved, moved[:3]
     if entry in ("verify_step_ragged", "packed_wave"):
         want = {"_index_decode_kernel", "_select_kernel", "_sparse_decode_kernel", "_moe_wave_kernel"}
     else:
-        want = {"_index_chunk_kernel", "_select_kernel"}
+        want = {"_index_chunk_kernel", "_select_kernel", "_chunk_kernel"}
         assert kernels - want, kernels  # the grouped matmul's
     assert want <= kernels, kernels
+
+
+# The third model file's chunk program (models/kimi_linear.py) at the published
+# widths of its configuration's file, one KDA and one latent layer and a small
+# vocabulary: the same kernel at 32 heads of 128 / 64 / 128 and NO bias.
+@pytest.mark.parametrize("rows", [1024, 127], ids=["miss-piece", "hit-question"])
+def test_kimi_linear_chunk_program_attends_through_the_kernel_and_no_page_loop(v5e, monkeypatch, rows):
+    """``kimi_linear.resume_chunk`` compiles for the v5e with the chunk's
+    latent attention as ``_chunk_kernel`` (the one ``glm_dsa`` runs under a
+    bias), no ``while`` whose carry is the page loop's, and for a whole block
+    temporaries smaller than the page loop's program's by a score tensor's
+    part that XLA had not overlaid (32 x 1,024 x 1,024 float32 is 134 MB)."""
+    from infinistore_tpu.models import kimi_linear as kl
+
+    monkeypatch.setattr(paged, "_use_pallas", lambda: True)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "benchmarks", "configs", "kimi-linear-48b-a3b.json")) as f:
+        real = json.load(f)
+    fields = {k: real[v] for k, v in real["program"]["fields"].items()}
+    group = dict(fields.pop("linear_attn"), kda_layers=[1], full_attn_layers=[2])
+    fields.update(vocab=1033, n_layers=2, linear_attn=group, route_tail=0)
+    cfg = kl.KimiLinearConfig(block_tokens=real["serving"]["block_tokens"], dtype=jnp.bfloat16, **fields)
+    assert (cfg.n_heads, cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim) == (
+        32, 512, 128, 64, 128
+    )
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=v5e)
+    i32 = lambda *shape: s(shape, jnp.int32)
+    shapes = jax.eval_shape(lambda k: kl.init_params(cfg, k), jax.random.key(0))
+    params = jax.tree.map(lambda a: s(a.shape, a.dtype), shapes)
+    blocks, table = 40, 33
+    spec = cfg.kv_spec(blocks)
+    caches = [
+        tuple(s((blocks, *t.block_shape), t.dtype) for t in spec.layer_tensors(layer))
+        for layer in range(cfg.n_layers)
+    ]
+    args, static = (params, i32(rows), i32(), caches, i32(table)), {"config": cfg}
+    kernels, text, temp = _chunk_program(kl.resume_chunk, args, static)
+    assert "_chunk_kernel" in kernels, kernels
+    assert "mla_chunk_attention_pallas" in text
+    assert not _page_loops(text, cfg.n_heads, rows, cfg.v_head_dim)
+    if rows == 1024:
+        was, loop_text, loop_temp = _with_the_page_loop(monkeypatch, kl.resume_chunk, args, static)
+        assert "_chunk_kernel" not in was
+        assert _page_loops(loop_text, cfg.n_heads, rows, cfg.v_head_dim)
+        assert loop_temp > temp, (loop_temp, temp)
 
 
 # The grouped expert product under the tiles its rule hands it (models/afmoe.py

@@ -1,7 +1,8 @@
-"""On the chip: each Pallas kernel of ``infinistore_tpu/tpu/dsa.py`` against
-its XLA twin at the shapes ``benchmarks/configs/glm-5.json`` gives them, then
-the model's chunk and wave programs with the kernels on against the same
-programs on the XLA paths, over the same weights and cache.
+"""On the chip: each Pallas kernel of ``infinistore_tpu/tpu/dsa.py``, and the
+chunk's latent attention of ``tpu/mla.py``, against its XLA twin at the shapes
+``benchmarks/configs/glm-5.json`` gives them, then the model's chunk and wave
+programs with the kernels on against the same programs on the XLA paths, over
+the same weights and cache.
 
 Tier-1 holds the kernels in interpret mode at toy shapes
 (``tests/test_glm_dsa.py``) and compiles them for a v5e
@@ -10,14 +11,20 @@ COMPILED kernels compute what their twins do at 33 pages of 1,024 tokens: the
 scoring pass of a wave and of a piece (relative rms error over the valid
 positions), the k-th-value search against the sort as SETS (``lax.top_k``'s,
 ties by position: equal or not, nothing between), the latent decode under a
-selection's bias, and a model of three published-width layers through three
-whole pieces, a part piece and one wave.
+selection's bias, the chunk's latent attention (``mla_chunk_attention_pallas``
+against the page loop it replaced, a block's rows over 1 / 8 / 32 pages with a
+selection's bias and without, a 127-row question, and the same kernel at
+``kimi-linear-48b-a3b``'s head sizes; both sides TIMED, the milliseconds a
+page-step printed: the probe ISSUE 57 asks for before any cell is run), and a
+model of three published-width layers through three whole pieces, a part piece
+and one wave.
 
     chiprun --chips 1 -- python3 tools/dsa_kernel_check.py
 
 One line a check, then ``{"ok": ...}``; exit code 1 unless the kernels' sets
 are equal and their errors under ``--tol`` (default 0.02: bf16 products summed
-in another order). The model's lines are reported and not held: two scoring
+in another order; the chunk's attention under ``--chunk-tol``, 0.0015: what the
+masked decode reads). The model's lines are reported and not held: two scoring
 passes that sum in another order part a row's near-ties, and the places in
 which the wave's sets then differ are printed beside the logits. Needs a TPU:
 the kernels have no compiled form elsewhere.
@@ -27,6 +34,7 @@ import argparse
 import json
 import os
 import sys
+import time
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
@@ -36,7 +44,7 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 from infinistore_tpu.models import glm_dsa  # noqa: E402
-from infinistore_tpu.tpu import dsa, paged  # noqa: E402
+from infinistore_tpu.tpu import dsa, mla, paged  # noqa: E402
 
 BF, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 
@@ -92,6 +100,46 @@ def kernels(real: dict, key) -> dict:
     return out
 
 
+def _ms(fn, *args, reps: int = 3) -> float:
+    jax.block_until_ready(fn(*args))
+    start = time.perf_counter()
+    for _ in range(reps):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - start) / reps * 1e3
+
+
+def chunk(real: dict, kimi: dict, key) -> dict:
+    """The chunk's latent attention, kernel against page loop: the error over
+    the whole result and the milliseconds a page-step of both."""
+    bt, blocks, k = real["serving"]["block_tokens"], real["serving"]["cache_blocks"], real["index_topk"]
+    p, out = 33, {}
+    ks = jax.random.split(key, 6)
+    table = jax.random.permutation(ks[0], blocks)[:p].astype(I32)
+    cases = [("glm5", real, True, bt, n) for n in (1, 8, 32)]
+    cases += [("glm5", real, False, bt, n) for n in (1, 8, 32)]
+    cases += [("glm5", real, True, 127, 8), ("kimi", kimi, False, bt, 8)]
+    for name, cfg, biased, s, n in cases:
+        heads, rank, rope = cfg["num_attention_heads"], cfg["kv_lora_rank"], cfg["qk_rope_head_dim"]
+        nope, vdim = cfg["qk_nope_head_dim"], cfg["v_head_dim"]
+        latent = jax.random.normal(ks[1], (blocks, rank + rope, bt), F32).astype(BF)
+        q = jax.random.normal(ks[2], (s, heads, nope + rope), F32).astype(BF)
+        w_kvb = (jax.random.normal(ks[3], (rank, heads, nope + vdim), F32) * rank**-0.5).astype(BF)
+        start = jnp.asarray(n * bt - s, I32)
+        bias = None
+        if biased:
+            lens = start + jnp.arange(s, dtype=I32) + 1
+            bias = dsa.select(jax.random.normal(ks[4], (p, s, bt), F32), lens, k)
+        kw = dict(rank=rank, nope=nope, scale=float((nope + rope) ** -0.5))
+        twin = jax.jit(lambda q, l, t, at, w, b: mla.latent_chunk_attention_xla(q, l, t, at, w, bias=b, **kw))
+        kern = lambda q, l, t, at, w, b: mla.mla_chunk_attention_pallas(q, l, t, at, w, bias=b, **kw)
+        args = (q, latent, table, start, w_kvb, bias)
+        tag = f"chunk_{name}_{'bias' if biased else 'nobias'}_{s}x{n}"
+        out[f"{tag}_rel"] = rel(kern(*args), twin(*args))
+        out[f"{tag}_ms_a_page_step"] = [round(_ms(kern, *args) / n, 4), round(_ms(twin, *args) / n, 4)]
+    return out
+
+
 def model(real: dict, key) -> dict:
     """Three layers at the published widths (a vocabulary of 2,048): three
     whole pieces, a piece of 1,000 rows and one wave, kernels on and off."""
@@ -134,17 +182,26 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--seed", type=int, default=5)
     ap.add_argument("--tol", type=float, default=0.02)
+    ap.add_argument("--chunk-tol", type=float, default=0.0015)
     args = ap.parse_args(argv)
     if jax.default_backend() != "tpu":
         print("dsa_kernel_check: needs a TPU (the kernels have no compiled form elsewhere)", file=sys.stderr)
         return 2
     with open(os.path.join(REPO, "benchmarks", "configs", "glm-5.json")) as f:
         real = json.load(f)
+    with open(os.path.join(REPO, "benchmarks", "configs", "kimi-linear-48b-a3b.json")) as f:
+        kimi = json.load(f)
     key = jax.random.key(args.seed)
     held = kernels(real, key)
-    for name, value in {**held, **model(real, jax.random.fold_in(key, 1))}.items():
+    timed = chunk(real, kimi, jax.random.fold_in(key, 2))
+    held.update({name: value for name, value in timed.items() if name.endswith("_rel")})
+    for name, value in {**timed, **held, **model(real, jax.random.fold_in(key, 1))}.items():
         print(json.dumps({name: value}), flush=True)
-    ok = all(value is True if name.endswith("_equal") else value < args.tol for name, value in held.items())
+    ok = all(
+        value is True if name.endswith("_equal")
+        else value < (args.chunk_tol if name.startswith("chunk_") else args.tol)
+        for name, value in held.items()
+    )
     print(json.dumps({"ok": ok}))
     return 0 if ok else 1
 
