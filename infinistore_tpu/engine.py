@@ -1272,6 +1272,12 @@ class ContinuousBatchingHarness:
         self.resumes = 0
         self.resume_tokens = 0
         self.resume_pages = 0
+        # Rows the prompt pieces of a model served a block at a time computed
+        # (``_compute_by_blocks``): ``metrics()`` adds them to the step counter
+        # the model names for them (``config.prompt_rows_counter``: a model
+        # whose prompt steps run a part of its stack counts the rows EVERY
+        # program computed beside those its wave counts under that name).
+        self.prompt_rows = 0
         # Admissions that wanted a prefetch but found the staging arena
         # full and fell back to the one-phase gated load (backpressure).
         self.prefetch_fallbacks = 0
@@ -1288,6 +1294,15 @@ class ContinuousBatchingHarness:
         self._prefill_per_block_s: Optional[float] = None
 
     # -- model compute -------------------------------------------------------
+
+    def _step_counters(self) -> dict:
+        """The wave's named counters, the prompt pieces' rows added to the one
+        the model names for them (``prompt_rows``)."""
+        counters = self.wave.step_counters()
+        name = getattr(self.config, "prompt_rows_counter", None)
+        if name is not None:
+            counters[name] = counters.get(name, 0) + self.prompt_rows
+        return counters
 
     def _padded_table(self, table: np.ndarray) -> np.ndarray:
         """Host-resident padded table. Numpy ON PURPOSE: the WaveDecoder
@@ -1323,6 +1338,7 @@ class ContinuousBatchingHarness:
                     self.params, piece, jnp.int32(start), self.caches, padded,
                     self.config, self.max_req_blocks,
                 )
+            self.prompt_rows += piece.shape[0]
 
     def _prefill_full(self, token_ids, table: np.ndarray):
         """Whole-prompt prefill into this request's blocks (cache-mutating:
@@ -2213,7 +2229,7 @@ class ContinuousBatchingHarness:
             # ``dsa_keys_selected`` of ``dsa_keys_in_context`` (the positions
             # its rows' attention kept of those they could have read);
             # nothing for a model that counts nothing.
-            **self.wave.step_counters(),
+            **self._step_counters(),
             # The device gate's ledger (``DeviceGate.counters``): who held
             # it for how long, and every wait cut by whom it stood behind.
             **self.gate.counters(),

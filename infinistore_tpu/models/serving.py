@@ -10,6 +10,18 @@ the harness runs what it finds there:
 -> (logits, caches)``
     a prefix hit's question: one request's chunk at contiguous positions
     against the pages already in the cache.
+
+    **A prompt step may hand back no logits.** The engine reads the logits of
+    neither ``prefill`` nor ``resume`` (a first token comes from the first
+    wave), so a model whose prompt rows need only a part of its stack returns
+    ``(None, caches)`` from both: one whose later layers read an earlier
+    layer's K and V and no state of their own runs its prompt through the
+    layers that write a cache and stops (``models/sambay.py``). Its cache then
+    names fewer layers than the model has (``kv_spec``: the layers that keep
+    anything), which is all the engine and the data plane ever see of depth.
+    Such a model may name a step counter for the rows its prompt pieces compute
+    (``config.prompt_rows_counter``): the engine adds each piece's rows to it
+    on the host, beside what the wave counts under the same name.
 ``wave(params, tokens, positions, row_of, pages, page_rows, page_starts,
 caches, block_tables, config, max_blocks[, window_pages=]) -> (logits,
 caches[, aux])``
@@ -82,6 +94,10 @@ def no_feed() -> jax.Array:
 
 
 class ServingSteps(NamedTuple):
+    """A model's three steps by role (module docstring). ``prefill`` and
+    ``resume`` return ``(logits, caches)``, and may return ``None`` for the
+    logits: the engine reads neither's."""
+
     prefill: Callable
     resume: Callable
     wave: Callable

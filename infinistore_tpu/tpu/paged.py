@@ -287,6 +287,20 @@ def _block_spec_shape(spec_shape):
     return (1, *spec_shape[1:])
 
 
+# A grid step holds a block in and a block out, each double-buffered. Mosaic's
+# scoped VMEM is 16 MiB unless told otherwise, which four blocks of up to 4 MiB
+# fit; a larger block (a 2,048-token page of ten 128-wide heads is 5 MiB) asks
+# for its own limit, and every smaller one lowers the program it always did.
+_SCOPED_VMEM = 16 << 20
+
+
+def _copy_params(cache) -> dict:
+    need = 4 * int(np.prod(cache.shape[1:])) * cache.dtype.itemsize
+    if need <= _SCOPED_VMEM:
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(vmem_limit_bytes=need + (4 << 20))}
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _gather_blocks_pallas(cache, block_ids, *, interpret):
     n = block_ids.shape[0]
@@ -305,6 +319,7 @@ def _gather_blocks_pallas(cache, block_ids, *, interpret):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, *cache.shape[1:]), cache.dtype),
         interpret=interpret,
+        **_copy_params(cache),
     )(block_ids, cache)
 
 
@@ -331,6 +346,7 @@ def _scatter_blocks_pallas(cache, block_ids, blocks, *, interpret):
         out_shape=jax.ShapeDtypeStruct(cache.shape, cache.dtype),
         input_output_aliases={2: 0},
         interpret=interpret,
+        **_copy_params(cache),
     )(block_ids, blocks, cache)
 
 

@@ -978,3 +978,109 @@ def test_grouped_ffn_compiles_under_its_tile_rule(v5e, monkeypatch, case):
     assert len(seen) == 3
     for (m, kk), (_, _, n), (tm, tk, tn) in seen:
         assert tm == 128 and m % tm == 0 and kk == tk and n % tn == 0, seen
+
+
+# The eighth model file's serving entries (models/sambay.py) at the published
+# widths of its configuration's file: hidden 2,560, 40 q / 20 kv heads of 64
+# (20 query pairs over 10 K/V pairs of 128), MLP 10,240, Mamba-1 mixers of
+# 5,120 channels x 16 states, window 512, the file's blocks; TWO periods of the
+# layer pattern (m s m s m F g c) and a small vocabulary, so that each
+# compiles in seconds.
+SAMBAY_ENTRIES = ["verify_step_ragged", "packed_wave", "packed_wave_one_row", "miss-piece", "hit-question"]
+
+
+def _sambay_config(entry):
+    from infinistore_tpu.models import sambay
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "benchmarks", "configs", "phi-4-mini-flash-reasoning.json")) as f:
+        real = json.load(f)
+    fields = {k: real[v] for k, v in real["program"]["fields"].items()}
+    fields.update(vocab=1031 if not entry.startswith("packed") else 1033, n_layers=8)
+    cfg = sambay.SambaYConfig(block_tokens=real["serving"]["block_tokens"], dtype=jnp.bfloat16, **fields)
+    assert (cfg.dim, cfg.pair_dim, cfg.kv_pairs, cfg.ssm_width, cfg.sliding_window) == (2560, 128, 10, 5120, 512)
+    return real, cfg
+
+
+@pytest.mark.parametrize("entry", SAMBAY_ENTRIES)
+def test_sambay_entries_compile_at_published_widths_and_update_every_cache_tensor_in_place(v5e, monkeypatch, entry):
+    """Each entry compiles for the v5e with its Mosaic kernels (a piece: the
+    selective scan and the flash kernel's band, no page walk; a wave: the
+    ragged decode kernel over 2,048-token pages of ten 128-wide heads), holds
+    an ``input_output_alias`` for EVERY cache tensor (the aliased bytes the
+    whole cache's), and moves no array of a cache tensor's shape through a
+    ``copy``, ``copy-start`` or ``transpose``."""
+    from infinistore_tpu.models import sambay, serving
+
+    monkeypatch.setattr(paged, "_use_pallas", lambda: True)
+    real, cfg = _sambay_config(entry)
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=v5e)
+    i32 = lambda *shape: s(shape, jnp.int32)
+    shapes = jax.eval_shape(lambda k: sambay.init_params(cfg, k), jax.random.key(0))
+    params = jax.tree.map(lambda a: s(a.shape, a.dtype), shapes)
+    blocks = real["serving"]["cache_blocks"]
+    spec = cfg.kv_spec(blocks)
+    caches = [
+        tuple(s((blocks, *t.block_shape), t.dtype) for t in spec.layer_tensors(layer))
+        for layer in range(spec.num_layers)
+    ]
+    table = -(-(32768 + 128 + 64) // cfg.block_tokens)
+    if entry == "verify_step_ragged":
+        rows, pages = 4, 64
+        jitted = sambay.verify_step_ragged
+        args = (
+            params, i32(rows), i32(rows), i32(rows), i32(pages), i32(pages + 1), i32(rows),
+            caches, i32(rows, table),
+        )
+        static = {"config": cfg, "max_blocks": table}
+    elif entry.startswith("packed_wave"):  # the buckets as the decoder launches them
+        rows = 1 if entry.endswith("one_row") else 4
+        layout = serving.WaveLayout(rows=rows, tables=rows, pages=16 * rows)
+        jitted = serving.verify_step_ragged
+        args = (params, i32(layout.size(table)), i32(serving.FEED_ROWS), caches)
+        static = {"config": cfg, "max_blocks": table, "layout": layout}
+    else:
+        rows = cfg.block_tokens if entry == "miss-piece" else 127
+        jitted, args, static = sambay.resume_chunk, (params, i32(rows), i32(), caches, i32(table)), {"config": cfg}
+    lowered = jitted.trace(*args, **static).lower(lowering_platforms=("tpu",))
+    kernels = set(re.findall(r'kernel_name = "(\w+)"', lowered.as_text()))
+    exe = lowered.compile()
+    text = exe.as_text()
+    tensors = [t for layer in caches for t in layer]
+    header = text.split("\n", 1)[0]
+    assert len(re.findall(r"\(\d+, \{\}, (?:may|must)-alias\)", header)) == len(tensors), header
+    nbytes = sum(int(np.prod(t.shape)) * jnp.dtype(t.dtype).itemsize for t in tensors)
+    assert exe.memory_analysis().alias_size_in_bytes == nbytes
+    whole = "|".join(sorted({",".join(map(str, t.shape)) for t in tensors}))
+    moved = re.findall(
+        rf"^.* = [^=]*(?:bf16|f32)\[(?:{whole})\][^=]* (?:copy|copy-start|transpose)\(.*$", text, flags=re.M,
+    )
+    assert not moved, moved[:3]
+    want = {"_ragged_attn_kernel"} if "piece" not in entry and "question" not in entry else {"_scan_kernel", "_flash_kernel"}
+    assert kernels == want, kernels
+
+
+@pytest.mark.parametrize("rows", [2048, 127], ids=["miss-piece", "hit-question"])
+def test_the_selective_scan_kernel_compiles_at_the_published_widths(v5e, rows):
+    from infinistore_tpu.tpu import selective_scan
+
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=v5e)
+    c, n, f32 = 5120, 16, jnp.float32
+    _compile(
+        selective_scan.selective_scan_pallas,
+        s((rows, c), jnp.bfloat16), s((rows, c), f32), s((c, n), f32), s((rows, n), f32), s((rows, n), f32),
+        s((c,), f32), s((n, c), f32),
+    )
+
+
+def test_a_five_mib_page_passes_the_block_copy_kernels(v5e):
+    """A 2,048-token page of ten 128-wide heads is 5 MiB: a grid step's four
+    blocks pass Mosaic's 16 MiB of scoped VMEM, so the copy kernels ask for
+    their own limit (``paged._copy_params``); a 4 MiB block asks for none."""
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=v5e)
+    assert paged._copy_params(s((80, 16384, 128), jnp.bfloat16)) == {}
+    cache, ids = s((80, 20480, 128), jnp.bfloat16), s((17,), jnp.int32)
+    assert paged._copy_params(cache)["compiler_params"].vmem_limit_bytes == 24 << 20
+    _compile(paged._gather_blocks_pallas, cache, ids, interpret=False)
+    exe = _compile(paged._scatter_blocks_pallas, cache, ids, s((17, 20480, 128), jnp.bfloat16), interpret=False)
+    assert exe.memory_analysis().alias_size_in_bytes > 0
