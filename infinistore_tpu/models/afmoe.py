@@ -33,17 +33,20 @@ absent chips. Two shapes of the one mathematics:
   product's widths (``_gmm_tiling``): 128 rows, because a group pays for
   every row tile it touches whole, and K whole, so that a group's weights
   are fetched once however many row tiles it spans;
-- few rows (a wave): the wave's DISTINCT chosen experts' weights streamed
-  once each through one kernel (``_moe_wave_pallas``: the scalar-prefetched
-  expert ids drive the weight blocks' index maps), every row multiplied by
-  its own combine weight for that expert (zero where it did not choose it);
-  no dense pass over all experts.
+- few rows (a wave): the weights of the wave's DISTINCT chosen experts that
+  are HELD HERE streamed once each through one kernel (``_moe_wave_pallas``:
+  the scalar-prefetched expert ids drive the weight blocks' index maps, and
+  a grid step past the real slots names the block before it, so nothing is
+  copied for it), every row multiplied by its own combine weight for that
+  expert (zero where it did not choose it); no dense pass over all experts,
+  no read for an expert held elsewhere.
 
 The three serving entries keep the names the trace readers match
 (``prefill``, ``resume_chunk``, ``verify_step_ragged``) and donate ``caches``
 as ``llama.py``'s do. The wave returns, beside its logits, the ids every row's
-every expert layer chose and two counters (``moe_pairs``,
-``moe_distinct_experts``), all from the timed step itself (``serving.py``).
+every expert layer chose and three counters (``moe_pairs``,
+``moe_distinct_experts``, ``moe_streamed_experts``), all from the timed step
+itself (``serving.py``).
 The layerwise disagg entries of ``llama.py`` have no twin here: no cell runs
 them (ROADMAP).
 """
@@ -150,7 +153,7 @@ class AfmoeConfig:
         return ServingSteps(prefill, prefill_continue, verify_step_ragged)
 
     # What the wave step counts and returns with its logits (serving.py).
-    step_counters = ("moe_pairs", "moe_distinct_experts")
+    step_counters = ("moe_pairs", "moe_distinct_experts", "moe_streamed_experts")
     # How ``route`` turns the router's logits into ids and combine weights: a
     # property of the family, read off the configuration's class.
     router = "sigmoid"
@@ -291,12 +294,13 @@ def route(m: jax.Array, router: jax.Array, bias: Optional[jax.Array], config):
 
 
 def _moe_wave_kernel(ids_ref, n_ref, x_ref, c_ref, wg_ref, wu_ref, wd_ref, out_ref):
-    """Grid (slot, F tile): slot s is the s-th distinct expert the wave's rows
-    chose; its gate, up and down tiles come in by the block specs' index maps
-    (``ids_ref[s]``), every row meets them, and the row's combine weight for
-    that expert (zero where it did not choose it) scales what it adds. Slots
-    past the wave's distinct experts repeat the last one's blocks (no copy)
-    and skip the compute."""
+    """Grid (slot, F tile): slot s is the s-th distinct HELD expert the wave's
+    rows chose; its gate, up and down tiles come in by the block specs' index
+    maps (``_wave_block``), every row meets them, and the row's combine
+    weight for that expert (zero where it did not choose it) scales what it
+    adds. A step past the ``n_ref[0]`` real slots names the block the last
+    real step named, whatever the expert's width in tiles, so the pipeline
+    copies nothing there, and skips the compute."""
     del ids_ref
     s, j = pl.program_id(0), pl.program_id(1)
 
@@ -322,6 +326,19 @@ def _wave_f_tile(f: int) -> int:
     return tf if f % tf == 0 else f
 
 
+def _wave_block(s, j, ids, n, tiles: int):
+    """(slot, expert, F tile) whose blocks grid step ``(s, j)`` of the wave
+    kernel names: ``(s, ids[s], j)`` on the ``n[0]`` real slots, and past
+    them what the last real step named, ``(n - 1, ids[n - 1], tiles - 1)``,
+    in BOTH coordinates: a block index that stands still is not copied again,
+    one that moves in ``j`` alone is (a whole expert a padded slot, where an
+    expert is several tiles wide). With no real slot it is slot 0's last
+    tile at every step: fetched once, never used."""
+    last = jnp.maximum(n[0] - 1, 0)
+    at = jnp.minimum(s, last)
+    return at, ids[at], jnp.where(s < n[0], j, tiles - 1)
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _moe_wave_pallas(x, slots, n_slots, combine, w_gate, w_up, w_down, *, interpret):
     """x: [Tp, D]; slots: [S] int32 expert ids (held-local), the first
@@ -330,6 +347,16 @@ def _moe_wave_pallas(x, slots, n_slots, combine, w_gate, w_up, w_down, *, interp
     tp, d = x.shape
     f = w_gate.shape[2]
     tf = _wave_f_tile(f)
+    block = functools.partial(_wave_block, tiles=f // tf)
+
+    def in_cols(s, j, ids, n):  # gate, up: [E, D, F] by (expert, 0, tile)
+        _, e, tile = block(s, j, ids, n)
+        return e, 0, tile
+
+    def in_rows(s, j, ids, n):  # down: [E, F, D] by (expert, tile, 0)
+        _, e, tile = block(s, j, ids, n)
+        return e, tile, 0
+
     return pl.pallas_call(
         _moe_wave_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -337,10 +364,10 @@ def _moe_wave_pallas(x, slots, n_slots, combine, w_gate, w_up, w_down, *, interp
             grid=(slots.shape[0], f // tf),
             in_specs=[
                 pl.BlockSpec((tp, d), lambda s, j, ids, n: (0, 0)),
-                pl.BlockSpec((None, tp, 128), lambda s, j, ids, n: (s, 0, 0)),
-                pl.BlockSpec((None, d, tf), lambda s, j, ids, n: (ids[s], 0, j)),
-                pl.BlockSpec((None, d, tf), lambda s, j, ids, n: (ids[s], 0, j)),
-                pl.BlockSpec((None, tf, d), lambda s, j, ids, n: (ids[s], j, 0)),
+                pl.BlockSpec((None, tp, 128), lambda s, j, ids, n: (block(s, j, ids, n)[0], 0, 0)),
+                pl.BlockSpec((None, d, tf), in_cols),
+                pl.BlockSpec((None, d, tf), in_cols),
+                pl.BlockSpec((None, tf, d), in_rows),
             ],
             out_specs=pl.BlockSpec((tp, d), lambda s, j, ids, n: (0, 0)),
         ),
@@ -354,26 +381,34 @@ def _moe_wave_pallas(x, slots, n_slots, combine, w_gate, w_up, w_down, *, interp
 
 
 def _wave_slots(ids, weights, config: AfmoeConfig):
-    """The wave's distinct chosen experts as kernel slots. Returns (slots [S]
-    held-local ids, the count of real slots [1], combine [S, T] float32, the
-    number of distinct experts the rows chose among ALL experts)."""
+    """The wave's distinct chosen experts HELD HERE as kernel slots, in
+    ascending order, compacted to the front. Returns (slots [S] held-local
+    ids with ``S = min(T * k, count)``: a wave cannot choose more distinct
+    held experts than are held; the count of real slots [1]; combine [S, T]
+    float32, zero past the real slots; the number of distinct experts the
+    rows chose among ALL experts). An expert held elsewhere gets no slot: it
+    costs neither a read nor a product."""
     t, k = ids.shape
     first, count = config.held
-    n_slots = min(t * k, config.n_experts)
-    flat = ids.reshape(-1)
-    uniq = jnp.unique(flat, size=n_slots, fill_value=jnp.max(flat))
+    # Held experts sort first, in their own order, as ``_grouped_ffn`` has it.
+    key = jnp.mod(ids - first, config.n_experts)
+    flat = key.reshape(-1)
+    uniq = jnp.unique(flat, size=min(t * k, config.n_experts), fill_value=jnp.max(flat))
     fresh = jnp.concatenate([jnp.ones((1,), bool), uniq[1:] != uniq[:-1]])
-    n_real = jnp.sum(fresh, dtype=jnp.int32)
-    mine = fresh & (uniq >= first) & (uniq < first + count)
-    hits = (ids[None] == uniq[:, None, None]) & mine[:, None, None]  # [S, T, k]
+    n_slots = min(t * k, count)
+    slots = uniq[:n_slots]
+    mine = fresh[:n_slots] & (slots < count)
+    hits = (key[None] == slots[:, None, None]) & mine[:, None, None]  # [S, T, k]
     combine = jnp.sum(jnp.where(hits, weights[None], 0.0), axis=-1)  # [S, T]
-    return jnp.clip(uniq - first, 0, count - 1), n_real.reshape(1), combine, n_real
+    n_held, distinct = jnp.sum(mine, dtype=jnp.int32), jnp.sum(fresh, dtype=jnp.int32)
+    return jnp.minimum(slots, count - 1), n_held.reshape(1), combine, distinct
 
 
 def _moe_wave(m, ids, weights, w: Params, config: AfmoeConfig):
-    """The few-rows form. m: [T, dim]; returns ([T, dim] float32, distinct)."""
+    """The few-rows form. m: [T, dim]; returns ([T, dim] float32, the
+    layer's ``expert_counts``)."""
     t, d = m.shape
-    slots, n_real, combine, distinct = _wave_slots(ids, weights, config)
+    slots, n_held, combine, distinct = _wave_slots(ids, weights, config)
     with jax.named_scope("afmoe_gathered_product"):
         if paged._use_pallas():
             tp = -(-t // 16) * 16
@@ -381,12 +416,12 @@ def _moe_wave(m, ids, weights, w: Params, config: AfmoeConfig):
             c = jnp.pad(combine, ((0, 0), (0, tp - t)))
             c = jnp.broadcast_to(c[:, :, None], (*c.shape, 128))
             out = _moe_wave_pallas(
-                x, slots, n_real, c, w["w_gate"], w["w_up"], w["w_down_moe"],
+                x, slots, n_held, c, w["w_gate"], w["w_up"], w["w_down_moe"],
                 interpret=False,
             )[:t]
         else:
             out = moe_wave_xla(m, slots, combine, w["w_gate"], w["w_up"], w["w_down_moe"])
-    return out, distinct
+    return out, expert_counts(distinct, n_held[0])
 
 
 @jax.jit
@@ -477,17 +512,30 @@ def _chunks(tokens: int) -> Tuple[int, int]:
     return n, -(-tokens // (n * 128)) * 128
 
 
+def expert_counts(distinct=0, streamed=0) -> Dict[str, jax.Array]:
+    """What one expert layer of a wave adds to the step's counters, under the
+    names ``step_counters`` reports them: ``moe_distinct_experts``, the
+    different experts the rows chose among ALL the router's, and
+    ``moe_streamed_experts``, those of them held here: the slots whose
+    weights the wave kernel reads. Both zero for many rows (the grouped
+    products) and as the sum a wave step starts from."""
+    return {
+        "moe_distinct_experts": jnp.asarray(distinct, jnp.int32),
+        "moe_streamed_experts": jnp.asarray(streamed, jnp.int32),
+    }
+
+
 def expert_layer(w: Params, m: jax.Array, config: AfmoeConfig):
     """m: [T, dim], the normed input. Returns (f [T, dim] float32, ids
-    [T, k] the experts each row chose among all, distinct [] int32: how many
-    different experts the rows chose, counted for few rows only, else 0)."""
+    [T, k] the experts each row chose among all, the layer's
+    ``expert_counts``: counted for few rows only, else 0)."""
     t = m.shape[0]
     first, _ = config.held
     ids, weights = route(m, w["router"], w.get("router_bias"), config)
     if t <= _MOE_WAVE_ROWS:
-        out, distinct = _moe_wave(m, ids, weights, w, config)
+        out, counts = _moe_wave(m, ids, weights, w, config)
     else:
-        distinct = jnp.zeros((), jnp.int32)
+        counts = expert_counts()
         n, size = _chunks(t)
         if n == 1:
             out = _grouped_ffn(m, ids, weights, w, config)
@@ -500,19 +548,19 @@ def expert_layer(w: Params, m: jax.Array, config: AfmoeConfig):
             ).reshape(n * size, -1)[:t]
     if first == 0 and config.n_shared_experts:
         out = out + _swiglu(m[None], w["ws_gate_up"], w["ws_down"])[0].astype(jnp.float32)
-    return out, ids, distinct
+    return out, ids, counts
 
 
 def _mlp(w: Params, x, dense: bool, config: AfmoeConfig):
     """The second half of a layer on x: [1, T, dim]. Returns (x_next, ids
-    [T, k] or None, distinct or None)."""
+    [T, k] or None, the expert layer's counts or None)."""
     m = _rms(x, w["pre_mlp_norm"], config.rms_eps, config.dtype)
     if dense:
-        f, ids, distinct = _swiglu(m, w["w_gate_up"], w["w_down"]), None, None
+        f, ids, counts = _swiglu(m, w["w_gate_up"], w["w_down"]), None, None
     else:
-        f, ids, distinct = expert_layer(w, m[0], config)
+        f, ids, counts = expert_layer(w, m[0], config)
         f = f[None]
-    return x + _rms(f, w["post_mlp_norm"], config.rms_eps, jnp.float32), ids, distinct
+    return x + _rms(f, w["post_mlp_norm"], config.rms_eps, jnp.float32), ids, counts
 
 
 # ---------------------------------------------------------------------------
@@ -567,8 +615,8 @@ def _wave_layer(
         window=config.sliding_window if sliding else None,
     )[None]
     x = _attn_out(w, x, attn, g, config)
-    x, ids, distinct = _mlp(w, x, dense, config)
-    return x, k_cache, v_cache, ids, distinct
+    x, ids, counts = _mlp(w, x, dense, config)
+    return x, k_cache, v_cache, ids, counts
 
 
 @functools.partial(
@@ -594,8 +642,9 @@ def verify_step_ragged(
     experts every row chose at every expert layer IN THIS STEP, and
     ``aux["counters"]``: ``moe_pairs`` (row, expert) pairs of the wave's real
     rows over its expert layers (a tail row that repeats its predecessor is
-    padding) and ``moe_distinct_experts``, the distinct experts they touched,
-    a layer at a time. ``caches`` is donated."""
+    padding), ``moe_distinct_experts``, the distinct experts they touched, a
+    layer at a time, and ``moe_streamed_experts``, those of them held here
+    (``expert_counts``). ``caches`` is donated."""
     t = tokens.shape[0]
     if block_tables.ndim != 2 or block_tables.shape[1] != max_blocks:
         raise ValueError(f"block_tables must be [B, {max_blocks}], got {block_tables.shape}")
@@ -611,7 +660,7 @@ def verify_step_ragged(
 
     layer_fn = jax.jit(_wave_layer, static_argnames=("config", "sliding", "dense"))
     new_caches: Caches = []
-    chosen, distinct = [], jnp.zeros((), jnp.int32)
+    chosen, counts = [], expert_counts()
     for layer, (k_cache, v_cache) in enumerate(caches):
         sliding = config.window_of(layer) is not None
         meta = window_pages if sliding else (pages, page_rows, page_starts)
@@ -623,7 +672,7 @@ def verify_step_ragged(
         new_caches.append((k_cache, v_cache))
         if ids is not None:
             chosen.append(ids)
-            distinct = distinct + n
+            counts = jax.tree.map(jnp.add, counts, n)
     logits = _head(params, x, config)[0]
     real = jnp.concatenate([
         jnp.ones((1,), bool),
@@ -634,7 +683,7 @@ def verify_step_ragged(
         "counters": {
             "moe_pairs": jnp.sum(real, dtype=jnp.int32)
             * (len(chosen) * config.experts_per_token),
-            "moe_distinct_experts": distinct,
+            **counts,
         },
     }
     return logits, new_caches, aux

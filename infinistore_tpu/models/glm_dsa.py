@@ -50,7 +50,7 @@ import numpy as np
 
 from ..tpu import dsa, mla
 from ..tpu.paged import CacheTensor, PagedKVCacheSpec
-from .afmoe import _layer_weights, _rms
+from .afmoe import _layer_weights, _rms, expert_counts
 from .kimi_linear import _embed, _head, _mlp, choices  # noqa: F401 - ``choices`` is this file's too
 from .serving import ServingSteps
 
@@ -142,11 +142,12 @@ class GlmDsaConfig:
         return ServingSteps(prefill, prefill_continue, verify_step_ragged, resume_in_block=True)
 
     # What the wave step counts and returns with its logits (serving.py): the
-    # expert layer's two, and over its real rows and layers the positions the
+    # expert layer's three, and over its real rows and layers the positions the
     # selection kept of those it could have (float32: a window's sum passes
     # 2^31).
     step_counters = (
-        "moe_pairs", "moe_distinct_experts", "dsa_keys_selected", "dsa_keys_in_context",
+        "moe_pairs", "moe_distinct_experts", "moe_streamed_experts", "dsa_keys_selected",
+        "dsa_keys_in_context",
     )
     router = "sigmoid"  # ``afmoe.route``'s kind
 
@@ -420,9 +421,9 @@ def verify_step_ragged(
     row chose at every expert layer in this step, then, a layer, the positions
     its selection kept as bits (``_packed_set``: the reference follows both,
     ``benchmarks/reference_glm_dsa.py``), and ``aux["counters"]``: ``moe_pairs``,
-    ``moe_distinct_experts`` (``afmoe.verify_step_ragged``'s) and, over the
-    real rows and the layers, ``dsa_keys_selected`` of ``dsa_keys_in_context``
-    (float32). ``caches`` is donated."""
+    ``moe_distinct_experts``, ``moe_streamed_experts``
+    (``afmoe.verify_step_ragged``'s) and, over the real rows and the layers,
+    ``dsa_keys_selected`` of ``dsa_keys_in_context`` (float32). ``caches`` is donated."""
     del pages, page_rows, page_starts
     if block_tables.ndim != 2 or block_tables.shape[1] != max_blocks:
         raise ValueError(f"block_tables must be [B, {max_blocks}], got {block_tables.shape}")
@@ -436,7 +437,7 @@ def verify_step_ragged(
         (positions[1:] != positions[:-1]) | (row_of[1:] != row_of[:-1]),
     ])
     new_caches: Caches = []
-    chosen, sets, distinct = [], [], jnp.zeros((), jnp.int32)
+    chosen, sets, counts = [], [], expert_counts()
     selected = jnp.zeros((), jnp.float32)
     for layer, (latent, index) in enumerate(caches):
         w = _layer_weights(params, layer)
@@ -449,7 +450,7 @@ def verify_step_ragged(
         x, ids, n = _mlp(w, x, layer < config.n_dense_layers, config)
         if ids is not None:
             chosen.append(ids)
-            distinct = distinct + n
+            counts = jax.tree.map(jnp.add, counts, n)
         new_caches.append((latent, index))
     logits = _head(params, x, config)
     aux = {
@@ -457,7 +458,7 @@ def verify_step_ragged(
         "counters": {
             "moe_pairs": jnp.sum(real, dtype=jnp.int32)
             * (len(chosen) * config.experts_per_token),
-            "moe_distinct_experts": distinct,
+            **counts,
             "dsa_keys_selected": selected,
             "dsa_keys_in_context": jnp.sum(jnp.where(real, positions + 1, 0), dtype=jnp.float32)
             * config.n_layers,

@@ -70,7 +70,7 @@ from ..tpu import kda, ssd
 from ..tpu.chunk_attention import chunk_prefix_attention
 from ..tpu.paged import CacheTensor, PagedKVCacheSpec
 from ..tpu.paged_attention import paged_decode_attention_rows
-from .afmoe import _layer_weights, _rms, choices, expert_layer  # noqa: F401 - ``choices``: the file's ``program.choices``
+from .afmoe import _layer_weights, _rms, choices, expert_counts, expert_layer  # noqa: F401 - ``choices``: the file's ``program.choices``
 from .kimi_linear import _split_routes
 from .serving import ServingSteps
 
@@ -223,10 +223,13 @@ class GraniteHybridConfig:
         return ServingSteps(prefill, prefill_continue, verify_step_ragged)
 
     # What the wave step counts and returns with its logits (serving.py): the
-    # expert layer's two (``afmoe.verify_step_ragged``'s), the pairs among them
+    # expert layer's three (``afmoe.verify_step_ragged``'s), the pairs among them
     # that fall on the experts held here, and the rows whose state crossed
     # into a new block.
-    step_counters = ("moe_pairs", "moe_distinct_experts", "moe_held_pairs", "state_carries")
+    step_counters = (
+        "moe_pairs", "moe_distinct_experts", "moe_streamed_experts", "moe_held_pairs",
+        "state_carries",
+    )
 
 
 def init_params(config: GraniteHybridConfig, key: jax.Array) -> Params:
@@ -294,11 +297,11 @@ def _head(params: Params, x: jax.Array, config: GraniteHybridConfig) -> jax.Arra
 
 def _experts(w: Params, h, config: GraniteHybridConfig):
     """The second half of a layer on h: [T, dim] float32. Returns (y, ids [T,
-    k] the experts each row chose among all, distinct)."""
+    k] the experts each row chose among all, the expert layer's counts)."""
     m = _rms(h, w["pre_mlp_norm"], config.rms_eps, config.dtype)
     with jax.named_scope("granite_expert_layer"):
-        f, ids, distinct = expert_layer(w, m, config)
-    return h + np.float32(config.residual_multiplier) * f, ids, distinct
+        f, ids, counts = expert_layer(w, m, config)
+    return h + np.float32(config.residual_multiplier) * f, ids, counts
 
 
 def _qkv(w: Params, n, config: GraniteHybridConfig):
@@ -493,8 +496,8 @@ def _wave_mamba(w: Params, x, states, tails, src, dst, fresh, config: GraniteHyb
             states = jax.lax.dynamic_update_index_in_dim(states, state[t].astype(states.dtype), dst[t], 0)
             tails = jax.lax.dynamic_update_index_in_dim(tails, tail[t], dst[t], 0)
         x = _ssm_out(w, x, o, z, config)
-    x, ids, distinct = _experts(w, x, config)
-    return x, states, tails, ids, distinct
+    x, ids, counts = _experts(w, x, config)
+    return x, states, tails, ids, counts
 
 
 def _wave_attention(
@@ -512,8 +515,8 @@ def _wave_attention(
             q, k_cache, v_cache, row_tables, seq_lens, pages, page_rows, page_starts
         )
         x = _attn_out(w, x, attn, config)
-    x, ids, distinct = _experts(w, x, config)
-    return x, k_cache, v_cache, ids, distinct
+    x, ids, counts = _experts(w, x, config)
+    return x, k_cache, v_cache, ids, counts
 
 
 @functools.partial(
@@ -542,10 +545,11 @@ def verify_step_ragged(
     ``aux["rows"]`` [T, sites, k] the experts every row chose at every layer IN
     THIS STEP (with ``route_tail``, followed by the sets the tokens before it
     chose in theirs, as the cache kept them: ``kimi_linear.py``), and
-    ``aux["counters"]``: ``moe_pairs``, ``moe_distinct_experts``
-    (``afmoe.verify_step_ragged``'s), ``moe_held_pairs`` (the real rows' (row,
-    choice) pairs that fall on the experts held here) and ``state_carries``,
-    the real rows that crossed into a new block. ``caches`` is donated."""
+    ``aux["counters"]``: ``moe_pairs``, ``moe_distinct_experts``,
+    ``moe_streamed_experts`` (``afmoe.verify_step_ragged``'s),
+    ``moe_held_pairs`` (the real rows' (row, choice) pairs that fall on the
+    experts held here) and ``state_carries``, the real rows that crossed into
+    a new block. ``caches`` is donated."""
     if block_tables.ndim != 2 or block_tables.shape[1] != max_blocks:
         raise ValueError(f"block_tables must be [B, {max_blocks}], got {block_tables.shape}")
     bt = config.block_tokens
@@ -560,7 +564,7 @@ def verify_step_ragged(
     mamba_fn = jax.jit(_wave_mamba, static_argnames=("config",))
     attention_fn = jax.jit(_wave_attention, static_argnames=("config",))
     new_caches: Caches = []
-    chosen, distinct = [], jnp.zeros((), jnp.int32)
+    chosen, counts = [], expert_counts()
     before = None
     for layer, cache in enumerate(caches):
         w = _layer_weights(params, layer)
@@ -574,7 +578,7 @@ def verify_step_ragged(
             x, *cache, ids, n = mamba_fn(w, x, *cache, src, dst, fresh, config=config)
         cache = tuple(cache)
         chosen.append(ids)
-        distinct = distinct + n
+        counts = jax.tree.map(jnp.add, counts, n)
         if routes is not None:
             # Each row's tail moves on by its own sets, as its state does.
             t, tail = tokens.shape[0], config.route_tail
@@ -599,7 +603,7 @@ def verify_step_ragged(
         "rows": rows,  # [T, sites x (1 + route_tail), k]
         "counters": {
             "moe_pairs": jnp.sum(real, dtype=jnp.int32) * (len(chosen) * k),
-            "moe_distinct_experts": distinct,
+            **counts,
             "moe_held_pairs": jnp.sum(held, dtype=jnp.int32),
             "state_carries": jnp.sum(real & (slots == 0) & ~fresh, dtype=jnp.int32),
         },

@@ -69,7 +69,7 @@ import numpy as np
 
 from ..tpu import kda, mla
 from ..tpu.paged import CacheTensor, PagedKVCacheSpec
-from .afmoe import _layer_weights, _rms, _swiglu, expert_layer
+from .afmoe import _layer_weights, _rms, _swiglu, expert_counts, expert_layer
 from .serving import ServingSteps
 
 Params = Dict[str, jax.Array]
@@ -205,9 +205,9 @@ class KimiLinearConfig:
         return ServingSteps(prefill, prefill_continue, verify_step_ragged)
 
     # What the wave step counts and returns with its logits (serving.py):
-    # the expert layer's two, and the rows whose state crossed into a new
+    # the expert layer's three, and the rows whose state crossed into a new
     # block (engine metrics: ``state_carries``).
-    step_counters = ("moe_pairs", "moe_distinct_experts", "state_carries")
+    step_counters = ("moe_pairs", "moe_distinct_experts", "moe_streamed_experts", "state_carries")
     router = "sigmoid"  # ``afmoe.route``'s kind
 
 
@@ -291,13 +291,13 @@ def _head(params: Params, x: jax.Array, config: KimiLinearConfig) -> jax.Array:
 
 def _mlp(w: Params, x, dense: bool, config: KimiLinearConfig):
     """The second half of a layer on x: [T, dim] float32. Returns (x_next,
-    ids [T, k] or None, distinct or None)."""
+    ids [T, k] or None, the expert layer's counts or None)."""
     m = _rms(x, w["pre_mlp_norm"], config.rms_eps, config.dtype)
     if dense:
         f = _swiglu(m[None], w["w_gate_up"], w["w_down"])[0].astype(jnp.float32)
         return x + f, None, None
-    f, ids, distinct = expert_layer(w, m, config)
-    return x + f, ids, distinct
+    f, ids, counts = expert_layer(w, m, config)
+    return x + f, ids, counts
 
 
 # ---------------------------------------------------------------------------
@@ -530,7 +530,8 @@ def verify_step_ragged(
     block's slot. Returns ``(logits [T, vocab], caches, aux)``: ``aux["rows"]``
     [T, sites, k] the experts every row chose at every expert layer IN THIS
     STEP (with ``route_tail``, followed by the sets the tokens before it chose
-    in theirs, as the cache kept them), and ``aux["counters"]``: ``moe_pairs``, ``moe_distinct_experts``
+    in theirs, as the cache kept them), and ``aux["counters"]``: ``moe_pairs``,
+    ``moe_distinct_experts``, ``moe_streamed_experts``
     (``afmoe.verify_step_ragged``'s) and ``state_carries``, the real rows that
     crossed into a new block. ``caches`` is donated."""
     del pages, page_rows, page_starts
@@ -548,7 +549,7 @@ def verify_step_ragged(
 
     kda_fn = jax.jit(_wave_kda, static_argnames=("config",))
     new_caches: Caches = []
-    chosen, distinct = [], jnp.zeros((), jnp.int32)
+    chosen, counts = [], expert_counts()
     before = None
     for layer, cache in enumerate(caches):
         w = _layer_weights(params, layer)
@@ -562,7 +563,7 @@ def verify_step_ragged(
         x, ids, n = _mlp(w, x, layer < config.n_dense_layers, config)
         if ids is not None:
             chosen.append(ids)
-            distinct = distinct + n
+            counts = jax.tree.map(jnp.add, counts, n)
         if routes is not None:
             # Each row's tail moves on by its own sets, as its state does.
             t, tail = tokens.shape[0], config.route_tail
@@ -586,7 +587,7 @@ def verify_step_ragged(
         "counters": {
             "moe_pairs": jnp.sum(real, dtype=jnp.int32)
             * (len(chosen) * config.experts_per_token),
-            "moe_distinct_experts": distinct,
+            **counts,
             "state_carries": jnp.sum(real & (slots == 0) & ~fresh, dtype=jnp.int32),
         },
     }

@@ -48,7 +48,9 @@ from ..tpu.chunk_attention import chunk_prefix_attention
 from ..tpu.flash_prefill import flash_prefill_attention
 from ..tpu.paged import PagedKVCacheSpec, scatter_blocks
 from ..tpu.paged_attention import paged_decode_attention_rows
-from .afmoe import FULL, SLIDING, _layer_weights, _rms, choices, expert_layer  # noqa: F401 - ``choices``: the file's ``program.choices``
+from .afmoe import (  # noqa: F401 - ``choices``: the file's ``program.choices``
+    FULL, SLIDING, _layer_weights, _rms, choices, expert_counts, expert_layer,
+)
 from .serving import ServingSteps
 
 Params = Dict[str, jax.Array]
@@ -175,7 +177,7 @@ class MellumConfig:
     router = "softmax_topk"
     n_shared_experts = 0
     # What the wave step counts and returns with its logits (serving.py).
-    step_counters = ("moe_pairs", "moe_distinct_experts")
+    step_counters = ("moe_pairs", "moe_distinct_experts", "moe_streamed_experts")
     # What the published config says of the family and this file takes as
     # given (a configuration's file holds them to its own keys).
     attention_bias = False
@@ -277,11 +279,11 @@ def _attn_inputs(w: Params, x, positions, kind: str, config: MellumConfig):
 def _close(w: Params, x, attn, config: MellumConfig):
     """The residual adds of one layer on x: [1, T, dim] float32, attention's
     output projected and then the expert layer of the normed sum. Returns
-    (x_next, ids [T, k], distinct)."""
+    (x_next, ids [T, k], the expert layer's counts)."""
     x = x + jnp.einsum("bshk,hkd->bsd", attn, w["wo"], preferred_element_type=jnp.float32)
     m = _rms(x, w["post_norm"], config.rms_eps, config.dtype)
-    f, ids, distinct = expert_layer(w, m[0], config)
-    return x + f[None], ids, distinct
+    f, ids, counts = expert_layer(w, m[0], config)
+    return x + f[None], ids, counts
 
 
 # ---------------------------------------------------------------------------
@@ -338,8 +340,8 @@ def _wave_layer(
             q[0], k_cache, v_cache, row_tables, seq_lens, pages, page_rows, page_starts,
             window=config.sliding_window if kind == SLIDING else None,
         )[None]
-    x, ids, distinct = _close(w, x, attn, config)
-    return x, k_cache, v_cache, ids, distinct
+    x, ids, counts = _close(w, x, attn, config)
+    return x, k_cache, v_cache, ids, counts
 
 
 @functools.partial(
@@ -362,8 +364,8 @@ def verify_step_ragged(
     """THE wave body (``afmoe.verify_step_ragged``'s contract, argument order
     and ``aux``): ``(logits [T, vocab], caches, aux)`` with ``aux["rows"]``
     [T, sites, k] the experts every row chose at every layer in this step and
-    ``aux["counters"]`` ``moe_pairs`` / ``moe_distinct_experts``, folded on
-    the device. ``caches`` is donated."""
+    ``aux["counters"]`` ``moe_pairs`` / ``moe_distinct_experts`` /
+    ``moe_streamed_experts``, folded on the device. ``caches`` is donated."""
     if block_tables.ndim != 2 or block_tables.shape[1] != max_blocks:
         raise ValueError(f"block_tables must be [B, {max_blocks}], got {block_tables.shape}")
     if window_pages is None and SLIDING in config.layer_types:
@@ -378,7 +380,7 @@ def verify_step_ragged(
 
     layer_fn = jax.jit(_wave_layer, static_argnames=("config", "kind"))
     new_caches: Caches = []
-    chosen, distinct = [], jnp.zeros((), jnp.int32)
+    chosen, counts = [], expert_counts()
     for layer, (k_cache, v_cache) in enumerate(caches):
         kind = config.layer_types[layer]
         meta = window_pages if kind == SLIDING else (pages, page_rows, page_starts)
@@ -388,7 +390,7 @@ def verify_step_ragged(
         )
         new_caches.append((k_cache, v_cache))
         chosen.append(ids)
-        distinct = distinct + n
+        counts = jax.tree.map(jnp.add, counts, n)
     logits = _head(params, x, config)[0]
     # A tail row that repeats its predecessor is the bucket's padding.
     real = jnp.concatenate([
@@ -400,7 +402,7 @@ def verify_step_ragged(
         "counters": {
             "moe_pairs": jnp.sum(real, dtype=jnp.int32)
             * (len(chosen) * config.experts_per_token),
-            "moe_distinct_experts": distinct,
+            **counts,
         },
     }
     return logits, new_caches, aux

@@ -266,21 +266,107 @@ def test_four_shares_of_two_experts_add_up_to_the_uncut_layer(params, rows, fami
     np.testing.assert_allclose(whole, want, atol=2e-5, rtol=0)
 
 
-def test_the_wave_kernel_streams_the_distinct_experts():
+# (the share of 16 experts held, the experts the router may not choose): all;
+# the first half; a sixteenth; a share the rows choose NOTHING of.
+HELD_SHARES = {
+    "all": (None, ()),
+    "the-first-half": ((0, 8), ()),
+    "a-sixteenth": ((5, 1), tuple(range(0, 5)) + tuple(range(6, 14))),
+    "none-chosen": ((12, 4), tuple(range(12, 16))),
+}
+
+
+@pytest.mark.parametrize("share", HELD_SHARES)
+def test_the_waves_slots_are_the_distinct_held_choices(share):
+    """``_wave_slots`` hands the kernel the distinct chosen experts that are
+    HELD, ascending, compacted to the front, ``S = min(T * k, count)`` of
+    them; ``distinct`` still counts among ALL experts; and the layer over
+    those slots is the loop over the held experts the rows chose."""
+    held, barred = HELD_SHARES[share]
+    kw = dict(n_experts=16, experts_per_token=2, dtype=jnp.float32)
+    whole, cfg = AfmoeConfig(**kw), AfmoeConfig(experts_held=held, **kw)
+    first, count = cfg.held
+    w = _layer(afmoe.init_params(whole, jax.random.key(59)))
+    # A barred expert's selection bias keeps it out of every row's top-k.
+    w["router_bias"] = w["router_bias"].at[jnp.asarray(barred, jnp.int32)].set(-10.0)
+    w.update({k: w[k][first : first + count] for k in ("w_gate", "w_up", "w_down_moe")})
+    rows, k = 4, 2
+    m = jax.random.normal(jax.random.key(4), (rows, cfg.dim), jnp.float32)
+    ids, weights = afmoe.route(m, w["router"], w["router_bias"], cfg)
+    chosen = np.asarray(ids)
+    assert not set(chosen.reshape(-1).tolist()) & set(barred)
+    want = sorted({e - first for e in chosen.reshape(-1).tolist() if first <= e < first + count})
+    assert (share == "none-chosen") == (not want) and (share != "a-sixteenth" or want == [0])
+
+    slots, n, combine, distinct = afmoe._wave_slots(ids, weights, cfg)
+    assert slots.shape == (min(rows * k, count),) and combine.shape == (slots.shape[0], rows)
+    assert n.shape == (1,) and int(n[0]) == len(want)
+    assert np.asarray(slots)[: len(want)].tolist() == want
+    assert 0 <= int(jnp.min(slots)) and int(jnp.max(slots)) < count
+    assert int(distinct) == len(set(chosen.reshape(-1).tolist()))
+    by_hand = np.zeros(combine.shape, np.float32)
+    for s, e in enumerate(want):
+        by_hand[s] = np.where(chosen == first + e, np.asarray(weights), 0.0).sum(-1)
+    np.testing.assert_array_equal(combine, by_hand)  # zero past the real slots
+
+    got, got_ids, counts = afmoe.expert_layer(w, m, cfg)
+    np.testing.assert_array_equal(got_ids, ids)
+    assert {name: int(v) for name, v in counts.items()} == {
+        "moe_distinct_experts": int(distinct), "moe_streamed_experts": len(want),
+    }
+    loop = np.zeros((rows, cfg.dim), np.float32)
+    for t in range(rows):
+        for e, weight in zip(chosen[t].tolist(), np.asarray(weights[t]).tolist()):
+            if first <= e < first + count:
+                h = jax.nn.silu(m[t] @ w["w_gate"][e - first]) * (m[t] @ w["w_up"][e - first])
+                loop[t] += weight * np.asarray(h @ w["w_down_moe"][e - first])
+        if first == 0:
+            su = jnp.einsum("d,dcf->cf", m[t], w["ws_gate_up"])
+            loop[t] += np.asarray((jax.nn.silu(su[0]) * su[1]) @ w["ws_down"])
+    np.testing.assert_allclose(got, loop, atol=2e-5, rtol=0)
+
+
+@pytest.mark.parametrize("tiles", [1, 2, 4])
+@pytest.mark.parametrize("n", [0, 1, 5], ids=["no-slot", "one-slot", "every-slot"])
+def test_a_step_past_the_real_slots_names_the_block_before_it(n, tiles):
+    """The index maps' rule as a plain function of the grid step (what
+    interpret mode cannot show: it computes the same with or without the
+    copies): a real step names ``(s, ids[s], j)``; every step past the ``n``
+    real slots names what the last real step named, in BOTH coordinates, so
+    the walk of the whole grid changes block ``n * tiles`` times and the
+    pipeline copies that many tiles, not ``S * tiles``."""
+    ids = jnp.asarray([3, 0, 7, 2, 5], jnp.int32)
+    walk = [
+        tuple(int(v) for v in afmoe._wave_block(s, j, ids, jnp.asarray([n], jnp.int32), tiles))
+        for s in range(ids.shape[0]) for j in range(tiles)
+    ]
+    real = [(s, int(ids[s]), j) for s in range(n) for j in range(tiles)]
+    assert walk[: n * tiles] == real
+    parked = real[-1] if real else (0, 3, tiles - 1)
+    assert set(walk[n * tiles :]) <= {parked}
+    assert 1 + sum(a != b for a, b in zip(walk, walk[1:])) == max(n * tiles, 1)
+
+
+@pytest.mark.parametrize("width", [256, 1024, 2048], ids=["one-tile", "two-tiles", "four-tiles"])
+@pytest.mark.parametrize("n", [0, 3, 6], ids=["no-slot", "padded", "every-slot"])
+def test_the_wave_kernel_streams_the_distinct_experts(n, width):
     """``_moe_wave_pallas`` (interpret mode) against the gathered XLA form,
-    with slots past the distinct experts repeating the last one."""
+    with ``n`` of six slots real (the combine weights past them are zero, and
+    whatever ids stand there are never read)."""
+    assert width // afmoe._wave_f_tile(width) == {256: 1, 1024: 2, 2048: 4}[width]
     rng = np.random.default_rng(354)
     f = lambda *s: jnp.asarray(rng.standard_normal(s) / 8, jnp.float32)
-    e, d, width, t = 8, 128, 256, 16
+    e, d, t = 8, 128, 16
     x, wg, wu, wd = f(t, d), f(e, d, width), f(e, d, width), f(e, width, d)
-    slots = jnp.asarray([1, 4, 6, 6, 6, 6], jnp.int32)
-    combine = jnp.asarray(rng.random((6, t)), jnp.float32).at[3:].set(0.0)
+    slots = jnp.asarray([1, 4, 6, 7, 7, 7], jnp.int32)
+    combine = jnp.asarray(rng.random((6, t)), jnp.float32).at[n:].set(0.0)
     got = afmoe._moe_wave_pallas(
-        x, slots, jnp.asarray([3], jnp.int32),
+        x, slots, jnp.asarray([n], jnp.int32),
         jnp.broadcast_to(combine[:, :, None], (6, t, 128)), wg, wu, wd, interpret=True,
     )
     want = afmoe.moe_wave_xla(x, slots, combine, wg, wu, wd)
     np.testing.assert_allclose(got, want, atol=1e-4, rtol=1e-4)
+    assert n or not np.asarray(got).any()
 
 
 # ---------------------------------------------------------------------------

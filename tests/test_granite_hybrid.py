@@ -225,24 +225,28 @@ def test_the_two_shares_add_up_to_the_uncut_references_layer(rows, params):
     np.testing.assert_allclose(total, want, atol=2e-5 * float(jnp.max(jnp.abs(want))), rtol=0)
 
 
-def test_the_waves_expert_kernel_takes_a_width_of_768_whole():
+@pytest.mark.parametrize("f", [768, 1024], ids=["768-whole", "two-tiles"])
+@pytest.mark.parametrize("n", [4, 0], ids=["padded", "no-slot"])
+def test_the_waves_expert_kernel_takes_a_width_of_768_whole(f, n):
     """768 = 6 x 128 is no whole number of 512-wide tiles: the kernel takes the
     width as one tile (the accepted widths keep theirs), and its result is the
-    XLA twin's."""
+    XLA twin's; so is it at two tiles, with ``n`` of its six slots real (the
+    held experts the rows chose: four of them, and none)."""
     assert [afmoe._wave_f_tile(f) for f in (32, 512, 768, 1024)] == [32, 512, 768, 512]
     keys = jax.random.split(jax.random.key(7), 5)
-    e, d, f, tp = 6, 128, 768, 16
+    e, d, tp = 6, 128, 16
     x = jax.random.normal(keys[0], (tp, d), jnp.float32)
     wg, wu = (jax.random.normal(k, (e, d, f), jnp.float32) / np.sqrt(d) for k in keys[1:3])
     wd = jax.random.normal(keys[3], (e, f, d), jnp.float32) / np.sqrt(f)
     slots = jnp.asarray([0, 2, 3, 5, 5, 5], jnp.int32)
-    combine = jax.random.uniform(keys[4], (6, tp), jnp.float32).at[4:].set(0.0)
+    combine = jax.random.uniform(keys[4], (6, tp), jnp.float32).at[n:].set(0.0)
     got = afmoe._moe_wave_pallas(
-        x, slots, jnp.asarray([4], jnp.int32), jnp.broadcast_to(combine[:, :, None], (6, tp, 128)),
+        x, slots, jnp.asarray([n], jnp.int32), jnp.broadcast_to(combine[:, :, None], (6, tp, 128)),
         wg, wu, wd, interpret=True,
     )
     want = afmoe.moe_wave_xla(x, slots, combine, wg, wu, wd)
-    np.testing.assert_allclose(got, want, atol=1e-4 * float(jnp.max(jnp.abs(want))), rtol=0)
+    np.testing.assert_allclose(got, want, atol=1e-4 * max(float(jnp.max(jnp.abs(want))), 1.0), rtol=0)
+    assert n or not np.asarray(got).any()
 
 
 # ---------------------------------------------------------------------------

@@ -375,19 +375,24 @@ def test_expert_layer_without_shared_or_dense_against_a_loop_over_experts(params
     w = afmoe._layer_weights(params, 1)
     assert "ws_gate_up" not in w and "router_bias" not in w and "w_gate_up" not in w
     m = jax.random.normal(jax.random.key(rows), (rows, CFG.dim), jnp.float32)
-    got, ids, distinct = afmoe.expert_layer(w, m, CFG)
+    got, ids, counts = afmoe.expert_layer(w, m, CFG)
     want_ids, want = experts_by_loop(w, m, CFG.experts_per_token)
     np.testing.assert_array_equal(np.sort(np.asarray(ids), -1), np.sort(want_ids, -1))
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=0)
-    if rows <= 16:
-        assert int(distinct) == len(set(want_ids.reshape(-1).tolist()))
+    # Every expert is held: what a wave streams is what its rows chose.
+    distinct = len(set(want_ids.reshape(-1).tolist())) if rows <= 16 else 0
+    assert {name: int(v) for name, v in counts.items()} == {
+        "moe_distinct_experts": distinct, "moe_streamed_experts": distinct,
+    }
 
 
 @pytest.mark.parametrize("width", [640, 1024], ids=["whole-width", "two-tiles"])
-def test_the_wave_kernel_at_a_width_of_whole_tiles_and_at_one_that_is_not(width):
+@pytest.mark.parametrize("n", [4, 0], ids=["padded", "no-slot"])
+def test_the_wave_kernel_at_a_width_of_whole_tiles_and_at_one_that_is_not(width, n):
     """``_moe_wave_pallas`` (interpret mode) against the gathered XLA form:
     640 = 5 x 128 is no multiple of the kernel's 512-wide tile and is taken
-    whole, as the configuration's 896 = 7 x 128 is; 1,024 is two tiles."""
+    whole, as the configuration's 896 = 7 x 128 is; 1,024 is two tiles. With
+    ``n`` of the six slots real: four and two padded, and none."""
     assert afmoe._wave_f_tile(width) == (640 if width == 640 else 512)
     assert afmoe._wave_f_tile(REAL["moe_intermediate_size"]) == 896
     rng = np.random.default_rng(width)
@@ -395,9 +400,9 @@ def test_the_wave_kernel_at_a_width_of_whole_tiles_and_at_one_that_is_not(width)
     e, d, t = 8, 128, 16
     x, wg, wu, wd = f(t, d), f(e, d, width), f(e, d, width), f(e, width, d)
     slots = jnp.asarray([0, 3, 5, 7, 7, 7], jnp.int32)
-    combine = jnp.asarray(rng.random((6, t)), jnp.float32).at[4:].set(0.0)
+    combine = jnp.asarray(rng.random((6, t)), jnp.float32).at[n:].set(0.0)
     got = afmoe._moe_wave_pallas(
-        x, slots, jnp.asarray([4], jnp.int32),
+        x, slots, jnp.asarray([n], jnp.int32),
         jnp.broadcast_to(combine[:, :, None], (6, t, 128)), wg, wu, wd, interpret=True,
     )
     want = afmoe.moe_wave_xla(x, slots, combine, wg, wu, wd)
