@@ -42,9 +42,9 @@ p (``kimi_linear.py`` says the same of its state; rows that repeat their
 predecessor, a wave's padding, read and write the same bytes).
 
 The three serving entries keep the names the trace readers match: ``prefill``
-(a miss: the prompt cut at block boundaries through ``resume_chunk``, the very
-programs a hit's resume runs, so a full hit's first token equals the miss's to
-the bit), ``resume_chunk`` and ``verify_step_ragged``; each donates ``caches``.
+(a miss: ``serving.prefill_by_blocks``, the prompt cut at block boundaries
+through ``resume_chunk``), ``resume_chunk`` and ``verify_step_ragged``; each
+donates ``caches``.
 """
 
 import functools
@@ -59,9 +59,10 @@ from ..tpu import kda, ssd
 from ..tpu.chunk_attention import chunk_prefix_attention
 from ..tpu.paged import CacheTensor, PagedKVCacheSpec
 from ..tpu.paged_attention import paged_decode_attention_rows
-from .afmoe import _layer_weights, _rms
-from .llama import _rope
-from .serving import ServingSteps
+from .layers import layer_weights, rms, rope, set_slots, slots_of
+from .serving import (
+    ServingSteps, chunk_index, prefill_by_blocks, real_rows, resume_step, wave_index, wave_sources,
+)
 
 Params = Dict[str, jax.Array]
 Caches = List[Tuple[jax.Array, ...]]
@@ -219,13 +220,13 @@ def _embed(params: Params, tokens: jax.Array, config: FalconH1Config) -> jax.Arr
 
 
 def _head(params: Params, x: jax.Array, config: FalconH1Config) -> jax.Array:
-    x = _rms(x, params["final_norm"], config.rms_eps, config.dtype)
+    x = rms(x, params["final_norm"], config.rms_eps, config.dtype)
     return _scaled(jnp.dot(x, params["lm_head"]), config.lm_head_multiplier)
 
 
 def _mlp(w: Params, h, config: FalconH1Config):
     """h + MLP(rms(h)) on h: [T, dim] float32."""
-    m = _rms(h, w["pre_mlp_norm"], config.rms_eps, config.dtype)
+    m = rms(h, w["pre_mlp_norm"], config.rms_eps, config.dtype)
     gate_up = jnp.einsum("td,dcf->tcf", m, w["w_gate_up"])
     gate, down = config.mlp_multipliers
     act = jax.nn.silu(_scaled(gate_up[:, 0], gate)) * gate_up[:, 1]
@@ -234,13 +235,13 @@ def _mlp(w: Params, h, config: FalconH1Config):
 
 def _qkv(w: Params, n, positions, config: FalconH1Config):
     """The attention's inputs from the normed n: [T, dim]: q [T, H, D] and
-    the cache's rows k, v [T, KVH, D], q and k rotated (``llama._rope``: the
+    the cache's rows k, v [T, KVH, D], q and k rotated (``layers.rope``: the
     angles in float32), k multiplied; rounded once, after the rotation."""
     f32 = jnp.float32
     a = _scaled(n, config.attention_in_multiplier)
     project = lambda name: jnp.einsum("td,dhk->thk", a, w[name], preferred_element_type=f32)
-    q = _rope(project("wq"), positions, config.rope_theta)
-    k = _rope(project("wk") * np.float32(config.key_multiplier), positions, config.rope_theta)
+    q = rope(project("wq"), positions, config.rope_theta)
+    k = rope(project("wk") * np.float32(config.key_multiplier), positions, config.rope_theta)
     return q.astype(config.dtype), k.astype(config.dtype), project("wv").astype(config.dtype)
 
 
@@ -262,12 +263,7 @@ def _ssm_inputs(w: Params, n, tail, config: FalconH1Config):
     ]).astype(np.float32)
     u = jnp.dot(_scaled(n, config.ssm_in_multiplier), w["w_in"]).astype(f32) * scale
     z, pre, dt = u[:, :width], u[:, width : width + conv].astype(config.dtype), u[:, width + conv :]
-    if tail.ndim == 3:  # a wave: one position a row, each with its own tail
-        rows = jnp.concatenate([tail.astype(pre.dtype), pre[:, None]], axis=1)
-        y = jnp.sum(rows.astype(f32) * w["conv_w"].astype(f32)[None], axis=1)
-        new_tail = rows[:, 1:]
-    else:
-        y, new_tail = kda.short_conv(pre, tail, w["conv_w"])
+    y, new_tail = kda.short_conv(pre, tail, w["conv_w"])  # a wave's tails: one a row
     y = jax.nn.silu(y + w["conv_b"].astype(f32)).astype(config.dtype)
     x = y[:, :width].reshape(t, heads, config.ssm_head_dim)
     b = y[:, width : width + group].reshape(t, config.ssm_groups, config.ssm_state)
@@ -303,12 +299,7 @@ def _mix(w: Params, x, mamba, attn, config: FalconH1Config):
 
 @functools.partial(jax.jit, static_argnames=("config",), donate_argnames=("caches",))
 def resume_chunk(
-    params: Params,
-    tokens: jax.Array,  # [S_c] int32, S_c <= block_tokens
-    start_pos: jax.Array,  # [] int32
-    caches: Caches,
-    block_table: jax.Array,  # [max_blocks] int32
-    config: FalconH1Config,
+    params: Params, tokens, start_pos, caches: Caches, block_table, config: FalconH1Config
 ) -> Tuple[jax.Array, Caches]:
     """ONE request's chunk at contiguous positions INSIDE ONE BLOCK (the
     caller cuts at block boundaries): a hit's question, and every piece of a
@@ -320,19 +311,14 @@ def resume_chunk(
     takes a first token from the first wave, never from a chunk, and the head
     over every row of a 1,024-token piece would be two fifths of its work at
     the published widths. ``caches`` is donated."""
-    s_c = tokens.shape[0]
     bt = config.block_tokens
-    if s_c > bt:
-        raise ValueError(f"a chunk of {s_c} tokens does not lie in one {bt}-token block")
-    block = block_table[start_pos // bt]
-    before = block_table[jnp.maximum(start_pos - 1, 0) // bt]
-    fresh = start_pos == 0
-    positions = start_pos + jnp.arange(s_c, dtype=jnp.int32)
+    block, before, fresh = chunk_index(tokens, start_pos, block_table, bt)
+    positions = start_pos + jnp.arange(tokens.shape[0], dtype=jnp.int32)
     x = _embed(params, tokens, config)
     new_caches: Caches = []
     for layer, (k_cache, v_cache, states, tails) in enumerate(caches):
-        w = _layer_weights(params, layer)
-        n = _rms(x, w["in_norm"], config.rms_eps, config.dtype)
+        w = layer_weights(params, layer)
+        n = rms(x, w["in_norm"], config.rms_eps, config.dtype)
         q, k, v = _qkv(w, n, positions, config)
         # The chunk lies in one block: one slice written in place.
         at = (block, start_pos % bt, 0, 0)
@@ -352,29 +338,8 @@ def resume_chunk(
     return _head(params, x[-1:], config), new_caches
 
 
-def prefill_continue(params, tokens, start_pos, caches, block_table, config, max_blocks):
-    """The harness's resume step (``llama.prefill_continue``'s signature)."""
-    if block_table.shape[0] != max_blocks:
-        raise ValueError(
-            f"block_table has {block_table.shape[0]} entries, expected max_blocks={max_blocks}"
-        )
-    return resume_chunk(params, tokens, start_pos, caches, block_table, config)
-
-
-def prefill(params, tokens, caches, block_table, config: FalconH1Config):
-    """A miss: every token given, cut at block boundaries through the chunk
-    program a hit's resume runs, so that each block's slot holds the state at
-    its end. ``block_table`` covers the tokens (a last block may be part
-    full). Returns (last-token logits, caches); ``caches`` is donated."""
-    bt = config.block_tokens
-    tokens = jnp.asarray(tokens, jnp.int32)
-    table = jnp.asarray(block_table, jnp.int32)
-    logits = None
-    for start in range(0, tokens.shape[0], bt):
-        logits, caches = resume_chunk(
-            params, tokens[start : start + bt], jnp.int32(start), caches, table, config
-        )
-    return logits[-1], caches
+prefill_continue = resume_step(resume_chunk)
+prefill = prefill_by_blocks(resume_chunk)
 
 
 def _wave_layer(
@@ -390,31 +355,22 @@ def _wave_layer(
     block ``dst``), add both, then the MLP. ``verify_step_ragged`` runs it a
     layer under one ``jax.jit`` of its own, so the layers share one traced and
     one lowered function."""
-    n = _rms(x, w["in_norm"], config.rms_eps, config.dtype)
+    n = rms(x, w["in_norm"], config.rms_eps, config.dtype)
     q, k, v = _qkv(w, n, positions, config)
     k_cache = k_cache.at[dst, slots].set(k.astype(k_cache.dtype))
     v_cache = v_cache.at[dst, slots].set(v.astype(v_cache.dtype))
     attn = paged_decode_attention_rows(
         q, k_cache, v_cache, row_tables, seq_lens, pages, page_rows, page_starts
     )
-    # A row a slice, read and written in place: a gather by row makes XLA:TPU
-    # cut the whole state array in two along its 256-wide minor axis first (a
-    # copy of every block's state, 2 ms a layer and wave on the chip: PERF.md,
-    # PR 43). A wave's rows are few.
-    rows = range(x.shape[0])
-    slots_of = lambda cache, ids: jnp.stack(
-        [jax.lax.dynamic_index_in_dim(cache, ids[t], 0, keepdims=False) for t in rows]
-    )
+    # A row a slice, read and written in place (``layers.slots_of``).
     state = jnp.where(fresh[:, None, None, None], 0.0, slots_of(states, src))
     tail = jnp.where(fresh[:, None, None], jnp.zeros((), tails.dtype), slots_of(tails, src))
     xs, b, c, dt, z, tail = _ssm_inputs(
         w, n, tail.reshape(x.shape[0], config.conv_taps - 1, -1), config
     )
     o, state = ssd.ssd_step(xs, dt, w["A_log"], b, c, w["D"], state)
-    tail = tail.astype(tails.dtype).reshape(-1, *tails.shape[1:])
-    for t in rows:
-        states = jax.lax.dynamic_update_index_in_dim(states, state[t], dst[t], 0)
-        tails = jax.lax.dynamic_update_index_in_dim(tails, tail[t], dst[t], 0)
+    states = set_slots(states, dst, state)
+    tails = set_slots(tails, dst, tail.reshape(-1, *tails.shape[1:]))
     x = _mlp(w, _mix(w, x, _ssm_out(w, o, z, config), attn, config), config)
     return x, k_cache, v_cache, states, tails
 
@@ -423,19 +379,10 @@ def _wave_layer(
     jax.jit, static_argnames=("config", "max_blocks"), donate_argnames=("caches",)
 )
 def verify_step_ragged(
-    params: Params,
-    tokens: jax.Array,  # [T] int32: one token a request (a state absorbs a token once)
-    positions: jax.Array,  # [T] int32
-    row_of: jax.Array,  # [T] int32 owning request per flat token
-    pages: jax.Array,  # [P] int32 the wave's flat page list (RaggedWaveMeta)
-    page_rows: jax.Array,  # [P + 1]
-    page_starts: jax.Array,  # [T]
-    caches: Caches,
-    block_tables: jax.Array,  # [B, max_blocks]
-    config: FalconH1Config,
-    max_blocks: int,
+    params: Params, tokens, positions, row_of, pages, page_rows, page_starts, caches: Caches,
+    block_tables, config: FalconH1Config, max_blocks: int,
 ):
-    """THE wave body (``llama.verify_step_ragged``'s contract and argument
+    """THE wave body (``serving.py``: ``wave``'s contract and argument
     order). ONE table serves both halves of a row: its flat page list (built
     from the table on the host) is what its attention walks, and by its
     position the table names the block its state comes from (position p - 1's)
@@ -444,29 +391,20 @@ def verify_step_ragged(
     slot. Returns ``(logits [T, vocab], caches, aux)``; ``aux["counters"]``:
     ``state_carries``, the real rows that crossed into a new block. ``caches``
     is donated."""
-    if block_tables.ndim != 2 or block_tables.shape[1] != max_blocks:
-        raise ValueError(f"block_tables must be [B, {max_blocks}], got {block_tables.shape}")
     bt = config.block_tokens
     x = _embed(params, tokens, config)
-    row_tables = jnp.take(block_tables, row_of, axis=0)
-    at = lambda pos: jnp.take_along_axis(row_tables, (pos // bt)[:, None], axis=1)[:, 0]
-    dst = at(positions)
-    src = at(jnp.maximum(positions - 1, 0))
-    fresh = positions == 0
-    slots = positions % bt
+    row_tables, dst, slots = wave_index(positions, row_of, block_tables, max_blocks, bt)
+    src, fresh = wave_sources(positions, row_tables, bt)
 
     layer_fn = jax.jit(_wave_layer, static_argnames=("config",))
     new_caches: Caches = []
     for layer, cache in enumerate(caches):
         x, *cache = layer_fn(
-            _layer_weights(params, layer), x, positions, *cache, src, dst, fresh, slots,
+            layer_weights(params, layer), x, positions, *cache, src, dst, fresh, slots,
             row_tables, positions + 1, pages, page_rows, page_starts, config=config,
         )
         new_caches.append(tuple(cache))
-    real = jnp.concatenate([
-        jnp.ones((1,), bool),
-        (positions[1:] != positions[:-1]) | (row_of[1:] != row_of[:-1]),
-    ])
+    real = real_rows(positions, row_of)
     aux = {"counters": {
         "state_carries": jnp.sum(real & (slots == 0) & ~fresh, dtype=jnp.int32),
     }}

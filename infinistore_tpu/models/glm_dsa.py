@@ -17,7 +17,7 @@ theta ^ (-2i / rope)``, unscaled:
             S_t = the min(t + 1, index_topk) positions s <= t of largest I[t, s]
   mixer   : softmax over S_t of (q_n . k_n[s] + q_r . k_r[s]) / sqrt(nope + rope);  Wo (p v)
   dense   : Wdown (silu(Wgate m) * Wup m)
-  expert  : ``afmoe.expert_layer`` (sigmoid scores, top-k of s + b, route_scale
+  expert  : ``tpu/moe.py`` ``expert_layer`` (sigmoid scores, top-k of s + b, route_scale
             x s_e / sum of the chosen, a shared expert, the held share)
   logits  = Whead rms(h_L; w_final)
 
@@ -36,7 +36,7 @@ stay in float32 from the projection's accumulator through norm and rotation
 and are rounded ONCE.
 
 The three serving entries keep the names the trace readers match:
-``prefill`` (a miss cut at block boundaries through ``resume_chunk``),
+``prefill`` (``serving.prefill_by_blocks``: a miss cut at block boundaries through ``resume_chunk``),
 ``resume_chunk`` and ``verify_step_ragged``; each donates ``caches``.
 """
 
@@ -50,9 +50,11 @@ import numpy as np
 
 from ..tpu import dsa, mla
 from ..tpu.paged import CacheTensor, PagedKVCacheSpec
-from .afmoe import _layer_weights, _rms, expert_counts
-from .kimi_linear import _embed, _head, _mlp, choices  # noqa: F401 - ``choices`` is this file's too
-from .serving import ServingSteps
+from .layers import embed, head, layer_weights, mlp, rms
+from .layers import choices  # re-exported: this file's ``program.choices`` (benchmarks/configs/)
+from .serving import (
+    ExpertTally, ServingSteps, chunk_index, prefill_by_blocks, real_rows, resume_step, wave_index,
+)
 
 Params = Dict[str, jax.Array]
 Caches = List[Tuple[jax.Array, jax.Array]]
@@ -145,11 +147,8 @@ class GlmDsaConfig:
     # expert layer's three, and over its real rows and layers the positions the
     # selection kept of those it could have (float32: a window's sum passes
     # 2^31).
-    step_counters = (
-        "moe_pairs", "moe_distinct_experts", "moe_streamed_experts", "dsa_keys_selected",
-        "dsa_keys_in_context",
-    )
-    router = "sigmoid"  # ``afmoe.route``'s kind
+    step_counters = (*ExpertTally.counters, "dsa_keys_selected", "dsa_keys_in_context")
+    router = "sigmoid"  # ``moe.route``'s kind
 
 
 def init_params(config: GlmDsaConfig, key: jax.Array) -> Params:
@@ -248,10 +247,10 @@ def _mixer_inputs(w: Params, n, positions, config: GlmDsaConfig):
     (the index cache's row) and its head weights [T, Hi] float32."""
     f32, dt = jnp.float32, config.dtype
     r, nope = config.kv_lora_rank, config.qk_nope_head_dim
-    c_q = _rms(mla.einsum_f32("td,dr->tr", n, w["w_qa"]), w["q_norm"], config.rms_eps, dt)
+    c_q = rms(mla.einsum_f32("td,dr->tr", n, w["w_qa"]), w["q_norm"], config.rms_eps, dt)
     q = mla.einsum_f32("tr,rhk->thk", c_q, w["w_qb"])
     kva = mla.einsum_f32("td,dr->tr", n, w["w_kva"])
-    c = _rms(kva[:, :r], w["kv_norm"], config.rms_eps, dt)
+    c = rms(kva[:, :r], w["kv_norm"], config.rms_eps, dt)
     with jax.named_scope("mla_rope"):
         q = rotate(q, positions, nope, config, dt)
         k_r = rotate(kva[:, r:], positions, 0, config, dt)
@@ -285,12 +284,7 @@ def _mixer_out(w: Params, x, attn, config: GlmDsaConfig):
 
 @functools.partial(jax.jit, static_argnames=("config",), donate_argnames=("caches",))
 def resume_chunk(
-    params: Params,
-    tokens: jax.Array,  # [S_c] int32, S_c <= block_tokens
-    start_pos: jax.Array,  # [] int32
-    caches: Caches,
-    block_table: jax.Array,  # [max_blocks] int32
-    config: GlmDsaConfig,
+    params: Params, tokens, start_pos, caches: Caches, block_table, config: GlmDsaConfig
 ) -> Tuple[jax.Array, Caches]:
     """ONE request's chunk at contiguous positions INSIDE ONE BLOCK (the
     caller cuts at block boundaries): a hit's question, and every piece of a
@@ -298,17 +292,14 @@ def resume_chunk(
     the block, scores the context so far, selects per row and attends over
     each row's own set. Returns (logits [S_c, vocab], caches); ``caches`` is
     donated."""
-    s_c = tokens.shape[0]
     bt = config.block_tokens
-    if s_c > bt:
-        raise ValueError(f"a chunk of {s_c} tokens does not lie in one {bt}-token block")
-    block = block_table[start_pos // bt]
-    positions = start_pos + jnp.arange(s_c, dtype=jnp.int32)
-    x = _embed(params, tokens)
+    block, _, _ = chunk_index(tokens, start_pos, block_table, bt)
+    positions = start_pos + jnp.arange(tokens.shape[0], dtype=jnp.int32)
+    x = embed(params, tokens)
     new_caches: Caches = []
     for layer, (latent, index) in enumerate(caches):
-        w = _layer_weights(params, layer)
-        n = _rms(x, w["in_norm"], config.rms_eps, config.dtype)
+        w = layer_weights(params, layer)
+        n = rms(x, w["in_norm"], config.rms_eps, config.dtype)
         q, rows, q_i, k_i, w_i = _mixer_inputs(w, n, positions, config)
         # The chunk lies in one block: one slice written in place (a scatter
         # by index makes XLA re-lay the whole cache out, twice).
@@ -325,41 +316,20 @@ def resume_chunk(
                 nope=config.qk_nope_head_dim, scale=_scale(config), bias=bias,
             )
         x = _mixer_out(w, x, attn, config)
-        x, _, _ = _mlp(w, x, layer < config.n_dense_layers, config)
+        x, _, _ = mlp(w, x, layer < config.n_dense_layers, config)
         new_caches.append((latent, index))
-    return _head(params, x, config), new_caches
+    return head(params, x, config), new_caches
 
 
-def prefill_continue(params, tokens, start_pos, caches, block_table, config, max_blocks):
-    """The harness's resume step (``llama.prefill_continue``'s signature)."""
-    if block_table.shape[0] != max_blocks:
-        raise ValueError(
-            f"block_table has {block_table.shape[0]} entries, expected max_blocks={max_blocks}"
-        )
-    return resume_chunk(params, tokens, start_pos, caches, block_table, config)
-
-
-def prefill(params, tokens, caches, block_table, config: GlmDsaConfig):
-    """A miss: every token given, cut at block boundaries through the chunk
-    program a hit's resume runs. ``block_table`` covers the tokens (a last
-    block may be part full). Returns (last-token logits, caches); ``caches``
-    is donated."""
-    bt = config.block_tokens
-    tokens = jnp.asarray(tokens, jnp.int32)
-    table = jnp.asarray(block_table, jnp.int32)
-    logits = None
-    for start in range(0, tokens.shape[0], bt):
-        logits, caches = resume_chunk(
-            params, tokens[start : start + bt], jnp.int32(start), caches, table, config
-        )
-    return logits[-1], caches
+prefill_continue = resume_step(resume_chunk)
+prefill = prefill_by_blocks(resume_chunk)
 
 
 def _wave_mixer(w: Params, x, latent, index, dst, slots, row_tables, positions,
                 config: GlmDsaConfig):
     """One layer's mixer over a wave's rows. Returns (x_next, latent, index,
     the selection's bias [max_blocks, T, block_tokens])."""
-    n = _rms(x, w["in_norm"], config.rms_eps, config.dtype)
+    n = rms(x, w["in_norm"], config.rms_eps, config.dtype)
     q, rows, q_i, k_i, w_i = _mixer_inputs(w, n, positions, config)
     # A row a slice, in place: a scatter by (block, slot) makes XLA re-lay the
     # whole cache out and back every wave. A wave's rows are few.
@@ -401,67 +371,43 @@ def _packed_set(bias, k: int):
     jax.jit, static_argnames=("config", "max_blocks"), donate_argnames=("caches",)
 )
 def verify_step_ragged(
-    params: Params,
-    tokens: jax.Array,  # [T] int32
-    positions: jax.Array,  # [T] int32
-    row_of: jax.Array,  # [T] int32 owning request per flat token
-    pages: jax.Array,  # the wave's flat page list: unused, each row walks its table
-    page_rows: jax.Array,
-    page_starts: jax.Array,
-    caches: Caches,
-    block_tables: jax.Array,  # [B, max_blocks]
-    config: GlmDsaConfig,
-    max_blocks: int,
+    params: Params, tokens, positions, row_of, pages, page_rows, page_starts, caches: Caches,
+    block_tables, config: GlmDsaConfig, max_blocks: int,
 ):
-    """THE wave body (``llama.verify_step_ragged``'s contract and argument
-    order). Each row writes its latent and its index key in place, scores its
-    whole context through its block table, selects, and attends in the
-    absorbed form. Returns ``(logits [T, vocab], caches, aux)``:
-    ``aux["rows"]`` [T, sites + layers x words / k, k] int32: the experts every
-    row chose at every expert layer in this step, then, a layer, the positions
-    its selection kept as bits (``_packed_set``: the reference follows both,
-    ``benchmarks/reference_glm_dsa.py``), and ``aux["counters"]``: ``moe_pairs``,
-    ``moe_distinct_experts``, ``moe_streamed_experts``
-    (``afmoe.verify_step_ragged``'s) and, over the real rows and the layers,
-    ``dsa_keys_selected`` of ``dsa_keys_in_context`` (float32). ``caches`` is donated."""
+    """THE wave body (``serving.py``: ``wave``'s contract and argument order).
+    Each row writes its latent and its index key in place, scores its whole
+    context through its block table, selects, and attends in the absorbed
+    form. Returns ``(logits [T, vocab], caches, aux)``: ``serving.ExpertTally``'s
+    ``aux``, its ``rows`` [T, sites + layers x words / k, k] int32 followed, a
+    layer, by the positions its selection kept as bits (``_packed_set``: the
+    reference follows both, ``benchmarks/reference_glm_dsa.py``) and, among its
+    counters, over the real rows and the layers, ``dsa_keys_selected`` of
+    ``dsa_keys_in_context`` (float32). ``caches`` is donated."""
     del pages, page_rows, page_starts
-    if block_tables.ndim != 2 or block_tables.shape[1] != max_blocks:
-        raise ValueError(f"block_tables must be [B, {max_blocks}], got {block_tables.shape}")
-    bt = config.block_tokens
-    x = _embed(params, tokens)
-    row_tables = jnp.take(block_tables, row_of, axis=0)
-    dst = jnp.take_along_axis(row_tables, (positions // bt)[:, None], axis=1)[:, 0]
-    slots = positions % bt
-    real = jnp.concatenate([
-        jnp.ones((1,), bool),
-        (positions[1:] != positions[:-1]) | (row_of[1:] != row_of[:-1]),
-    ])
+    x = embed(params, tokens)
+    row_tables, dst, slots = wave_index(
+        positions, row_of, block_tables, max_blocks, config.block_tokens
+    )
+    real = real_rows(positions, row_of)
     new_caches: Caches = []
-    chosen, sets, counts = [], [], expert_counts()
+    tally, sets = ExpertTally(), []
     selected = jnp.zeros((), jnp.float32)
     for layer, (latent, index) in enumerate(caches):
-        w = _layer_weights(params, layer)
+        w = layer_weights(params, layer)
         x, latent, index, bias = _wave_mixer(
             w, x, latent, index, dst, slots, row_tables, positions, config
         )
         kept = jnp.sum(bias == 0.0, axis=(0, 2), dtype=jnp.float32)
         selected = selected + jnp.sum(jnp.where(real, kept, 0.0))
         sets.append(_packed_set(bias, config.experts_per_token))
-        x, ids, n = _mlp(w, x, layer < config.n_dense_layers, config)
-        if ids is not None:
-            chosen.append(ids)
-            counts = jax.tree.map(jnp.add, counts, n)
+        x, ids, n = mlp(w, x, layer < config.n_dense_layers, config)
+        tally.add(ids, n)
         new_caches.append((latent, index))
-    logits = _head(params, x, config)
-    aux = {
-        "rows": jnp.concatenate([jnp.stack(chosen, axis=1)] + sets, axis=1),
-        "counters": {
-            "moe_pairs": jnp.sum(real, dtype=jnp.int32)
-            * (len(chosen) * config.experts_per_token),
-            **counts,
-            "dsa_keys_selected": selected,
-            "dsa_keys_in_context": jnp.sum(jnp.where(real, positions + 1, 0), dtype=jnp.float32)
-            * config.n_layers,
-        },
-    }
+    logits = head(params, x, config)
+    aux = tally.aux(real, config.experts_per_token)
+    aux["rows"] = jnp.concatenate([aux["rows"]] + sets, axis=1)
+    aux["counters"]["dsa_keys_selected"] = selected
+    aux["counters"]["dsa_keys_in_context"] = (
+        jnp.sum(jnp.where(real, positions + 1, 0), dtype=jnp.float32) * config.n_layers
+    )
     return logits, new_caches, aux

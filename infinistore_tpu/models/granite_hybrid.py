@@ -12,9 +12,9 @@ float32, the recurrence a token at a time. Pre-norm:
   h       = x + residual_multiplier mixer(rms(x; w_in))
   y       = h + residual_multiplier (sum_{e in top-k} g_e Expert_e(m) + Shared(m)),  m = rms(h; w_post)
   router  : l = W_r m in float32; the k largest logits; g = softmax over those
-            k alone (``afmoe.route``, ``router = "softmax_topk"``)
+            k alone (``moe.route``, ``router = "softmax_topk"``)
   Expert  : W_out (silu(a) b), [a, b] = W_in m; ``Shared`` the same at its own
-            width; ``afmoe.expert_layer``, the one code three families run,
+            width; ``tpu/moe.py`` ``expert_layer``, every routed family's one code,
             told the share it holds (``experts_held``)
   Attn    : q = Wq n, k = Wk n, v = Wv n, NO rotation; causal softmax(
             attention_multiplier q k^T) v, H / KVH query heads a KV head; Wo.
@@ -53,9 +53,9 @@ p (rows that repeat their predecessor, a wave's padding, read and write the
 same bytes).
 
 The three serving entries keep the names the trace readers match: ``prefill``
-(a miss: the prompt cut at block boundaries through ``resume_chunk``, the very
-programs a hit's resume runs, so a full hit's first token equals the miss's to
-the bit), ``resume_chunk`` and ``verify_step_ragged``; each donates ``caches``.
+(a miss: ``serving.prefill_by_blocks``, the prompt cut at block boundaries
+through ``resume_chunk``), ``resume_chunk`` and ``verify_step_ragged``; each
+donates ``caches``.
 """
 
 import functools
@@ -70,9 +70,16 @@ from ..tpu import kda, ssd
 from ..tpu.chunk_attention import chunk_prefix_attention
 from ..tpu.paged import CacheTensor, PagedKVCacheSpec
 from ..tpu.paged_attention import paged_decode_attention_rows
-from .afmoe import _layer_weights, _rms, choices, expert_counts, expert_layer  # noqa: F401 - ``choices``: the file's ``program.choices``
-from .kimi_linear import _split_routes
-from .serving import ServingSteps
+from ..tpu.moe import expert_layer
+from .layers import (
+    chunk_routes, folded_tail_shape, layer_weights, rms, routes_shape, rows_with_routes, set_slots,
+    slots_of, split_routes, tail_folded, tail_rows, wave_routes,
+)
+from .layers import choices  # re-exported: this file's ``program.choices`` (benchmarks/configs/)
+from .serving import (
+    ExpertTally, ServingSteps, chunk_index, prefill_by_blocks, real_rows, resume_step,
+    wave_index, wave_sources,
+)
 
 Params = Dict[str, jax.Array]
 Caches = List[Tuple[jax.Array, ...]]
@@ -129,7 +136,7 @@ class GraniteHybridConfig:
                 f"a block of {self.block_tokens} tokens is no whole number of {self.ssm_chunk}-token chunks"
             )
 
-    # What ``afmoe.expert_layer`` asks of a configuration beside the fields.
+    # What ``moe.expert_layer`` asks of a configuration beside the fields.
     router = "softmax_topk"
     n_shared_experts = 1
 
@@ -170,16 +177,8 @@ class GraniteHybridConfig:
 
     @property
     def tail_shape(self) -> Tuple[int, int]:
-        """The convolution tail's ``[taps - 1, conv_width]`` rows as the cache
-        keeps them: folded to 128 lanes where they divide (``kimi_linear``'s
-        reason: the array then lies row-major on the chip), the rows rounded
-        up to four, so that a block's tail is whole KiB in the served type
-        (the rows past the real ones stay zero)."""
-        total = (self.conv_taps - 1) * self.conv_width
-        if total % 128:
-            return (self.conv_taps - 1, self.conv_width)
-        rows = total // 128
-        return (rows + -rows % 4, 128)
+        """The convolution tail's rows as the cache keeps them."""
+        return folded_tail_shape(self.conv_taps, self.conv_width)
 
     @property
     def sites(self) -> int:
@@ -188,11 +187,7 @@ class GraniteHybridConfig:
 
     @property
     def routes_shape(self) -> Tuple[int, int]:
-        """``[route_tail, sites, k]`` ids as the cache keeps them: folded to
-        128 lanes where they divide."""
-        total = self.route_tail * self.sites * self.experts_per_token
-        lanes = 128 if total % 128 == 0 else total
-        return (total // lanes, lanes)
+        return routes_shape(self.route_tail, self.sites, self.experts_per_token)
 
     def layer_cache(self, layer: int) -> Tuple[CacheTensor, ...]:
         if self.layer_types[layer] == ATTENTION:
@@ -223,13 +218,9 @@ class GraniteHybridConfig:
         return ServingSteps(prefill, prefill_continue, verify_step_ragged)
 
     # What the wave step counts and returns with its logits (serving.py): the
-    # expert layer's three (``afmoe.verify_step_ragged``'s), the pairs among them
-    # that fall on the experts held here, and the rows whose state crossed
-    # into a new block.
-    step_counters = (
-        "moe_pairs", "moe_distinct_experts", "moe_streamed_experts", "moe_held_pairs",
-        "state_carries",
-    )
+    # expert layers' (``ExpertTally``), the pairs among them that fall on the
+    # experts held here, and the rows whose state crossed into a new block.
+    step_counters = (*ExpertTally.counters, "moe_held_pairs", "state_carries")
 
 
 def init_params(config: GraniteHybridConfig, key: jax.Array) -> Params:
@@ -290,7 +281,7 @@ def _embed(params: Params, tokens: jax.Array, config: GraniteHybridConfig) -> ja
 
 def _head(params: Params, x: jax.Array, config: GraniteHybridConfig) -> jax.Array:
     """The tied head: the embedding's rows against the normed stream."""
-    x = _rms(x, params["final_norm"], config.rms_eps, config.dtype)
+    x = rms(x, params["final_norm"], config.rms_eps, config.dtype)
     logits = jnp.einsum("td,vd->tv", x, params["embed"], preferred_element_type=jnp.float32)
     return (logits / np.float32(config.logits_scaling)).astype(config.dtype)
 
@@ -298,7 +289,7 @@ def _head(params: Params, x: jax.Array, config: GraniteHybridConfig) -> jax.Arra
 def _experts(w: Params, h, config: GraniteHybridConfig):
     """The second half of a layer on h: [T, dim] float32. Returns (y, ids [T,
     k] the experts each row chose among all, the expert layer's counts)."""
-    m = _rms(h, w["pre_mlp_norm"], config.rms_eps, config.dtype)
+    m = rms(h, w["pre_mlp_norm"], config.rms_eps, config.dtype)
     with jax.named_scope("granite_expert_layer"):
         f, ids, counts = expert_layer(w, m, config)
     return h + np.float32(config.residual_multiplier) * f, ids, counts
@@ -319,25 +310,6 @@ def _attn_out(w: Params, x, attn, config: GraniteHybridConfig):
     return x + np.float32(config.residual_multiplier) * a
 
 
-def _tail_rows(tail, config: GraniteHybridConfig):
-    """The cache's folded tail(s) ``[..., rows, lanes]`` as ``[..., taps - 1,
-    conv_width]``: the real rows of the fold."""
-    taps, width = config.conv_taps - 1, config.conv_width
-    lead = tail.shape[:-2]
-    return tail.reshape(*lead, -1)[..., : taps * width].reshape(*lead, taps, width)
-
-
-def _tail_folded(tail, like, config: GraniteHybridConfig):
-    """``[..., taps - 1, conv_width]`` as the cache keeps it (``like``: the
-    cache's tail tensor), zeros in the fold's spare rows."""
-    lead = tail.shape[:-2]
-    flat = tail.astype(like.dtype).reshape(*lead, -1)
-    spare = int(np.prod(like.shape[1:])) - flat.shape[-1]
-    if spare:
-        flat = jnp.pad(flat, [(0, 0)] * len(lead) + [(0, spare)])
-    return flat.reshape(*lead, *like.shape[1:])
-
-
 def _ssm_inputs(w: Params, n, tail, config: GraniteHybridConfig):
     """The mixer's inputs from the normed n: [T, dim]. ``tail``: [taps - 1,
     conv_width] the rows before the convolution that came before n's (per ROW
@@ -350,12 +322,7 @@ def _ssm_inputs(w: Params, n, tail, config: GraniteHybridConfig):
     width, conv = config.ssm_width, config.conv_width
     u = jnp.dot(n, w["w_in"], preferred_element_type=f32)
     z, pre, dt = u[:, :width], u[:, width : width + conv].astype(config.dtype), u[:, width + conv :]
-    if tail.ndim == 3:  # a wave: one position a row, each with its own tail
-        rows = jnp.concatenate([tail.astype(pre.dtype), pre[:, None]], axis=1)
-        y = jnp.sum(rows.astype(f32) * w["conv_w"].astype(f32)[None], axis=1)
-        new_tail = rows[:, 1:]
-    else:
-        y, new_tail = kda.short_conv(pre, tail, w["conv_w"])
+    y, new_tail = kda.short_conv(pre, tail, w["conv_w"])  # a wave's tails: one a row
     y = jax.nn.silu(y + w["conv_b"].astype(f32)).astype(config.dtype)
     group = config.ssm_groups * config.ssm_state
     x = y[:, :width].reshape(t, config.ssm_heads, config.ssm_head_dim)
@@ -383,12 +350,7 @@ def _ssm_out(w: Params, x, o, z, config: GraniteHybridConfig):
 
 @functools.partial(jax.jit, static_argnames=("config",), donate_argnames=("caches",))
 def resume_chunk(
-    params: Params,
-    tokens: jax.Array,  # [S_c] int32, S_c <= block_tokens
-    start_pos: jax.Array,  # [] int32
-    caches: Caches,
-    block_table: jax.Array,  # [max_blocks] int32
-    config: GraniteHybridConfig,
+    params: Params, tokens, start_pos, caches: Caches, block_table, config: GraniteHybridConfig
 ) -> Tuple[jax.Array, Caches]:
     """ONE request's chunk at contiguous positions INSIDE ONE BLOCK (the
     caller cuts at block boundaries): a hit's question, and every piece of a
@@ -399,20 +361,15 @@ def resume_chunk(
     (``chunk_prefix_attention``). Returns (the LAST row's logits [1, vocab],
     caches): the engine takes a first token from the first wave, never from a
     chunk. ``caches`` is donated."""
-    s_c = tokens.shape[0]
     bt = config.block_tokens
-    if s_c > bt:
-        raise ValueError(f"a chunk of {s_c} tokens does not lie in one {bt}-token block")
-    block = block_table[start_pos // bt]
-    before = block_table[jnp.maximum(start_pos - 1, 0) // bt]
-    fresh = start_pos == 0
+    block, before, fresh = chunk_index(tokens, start_pos, block_table, bt)
     x = _embed(params, tokens, config)
     new_caches: Caches = []
     chosen = []
     for layer, cache in enumerate(caches):
-        w = _layer_weights(params, layer)
-        n = _rms(x, w["in_norm"], config.rms_eps, config.dtype)
-        cache, routes = _split_routes(cache, layer, config)
+        w = layer_weights(params, layer)
+        n = rms(x, w["in_norm"], config.rms_eps, config.dtype)
+        cache, routes = split_routes(cache, layer, config)
         if config.layer_types[layer] == ATTENTION:
             with jax.named_scope("granite_attention_mixer"):
                 k_cache, v_cache = cache
@@ -429,48 +386,23 @@ def resume_chunk(
                 states, tails = cache
                 state = jnp.where(fresh, 0.0, states[before])
                 tail = jnp.where(fresh, jnp.zeros((), tails.dtype), tails[before])
-                xs, b, c, dt, z, tail = _ssm_inputs(w, n, _tail_rows(tail, config), config)
+                xs, b, c, dt, z, tail = _ssm_inputs(w, n, tail_rows(tail, config), config)
                 o, state = ssd.ssd_chunk(xs, dt, w["A_log"], b, c, w["D"], state, chunk=config.ssm_chunk)
                 x = _ssm_out(w, x, o, z, config)
                 cache = (
                     states.at[block].set(state),
-                    tails.at[block].set(_tail_folded(tail, tails, config)),
+                    tails.at[block].set(tail_folded(tail, tails)),
                 )
         x, ids, _ = _experts(w, x, config)
         chosen.append(ids)
         if routes is not None:
-            # The last ``route_tail`` tokens' sets, the chunk's own the newest.
-            old = jnp.where(fresh, -1, routes[before]).reshape(config.route_tail, -1)
-            mine = jnp.stack(chosen, axis=1).reshape(s_c, -1)
-            kept = jnp.concatenate([old, mine])[-config.route_tail :]
-            cache += (routes.at[block].set(kept.reshape(routes.shape[1:])),)
+            cache += (chunk_routes(routes, chosen, block, before, fresh, config),)
         new_caches.append(cache)
     return _head(params, x[-1:], config), new_caches
 
 
-def prefill_continue(params, tokens, start_pos, caches, block_table, config, max_blocks):
-    """The harness's resume step (``llama.prefill_continue``'s signature)."""
-    if block_table.shape[0] != max_blocks:
-        raise ValueError(
-            f"block_table has {block_table.shape[0]} entries, expected max_blocks={max_blocks}"
-        )
-    return resume_chunk(params, tokens, start_pos, caches, block_table, config)
-
-
-def prefill(params, tokens, caches, block_table, config: GraniteHybridConfig):
-    """A miss: every token given, cut at block boundaries through the chunk
-    program a hit's resume runs, so that each block's slot holds the state at
-    its end. ``block_table`` covers the tokens (a last block may be part
-    full). Returns (last-token logits, caches); ``caches`` is donated."""
-    bt = config.block_tokens
-    tokens = jnp.asarray(tokens, jnp.int32)
-    table = jnp.asarray(block_table, jnp.int32)
-    logits = None
-    for start in range(0, tokens.shape[0], bt):
-        logits, caches = resume_chunk(
-            params, tokens[start : start + bt], jnp.int32(start), caches, table, config
-        )
-    return logits[-1], caches
+prefill_continue = resume_step(resume_chunk)
+prefill = prefill_by_blocks(resume_chunk)
 
 
 def _wave_mamba(w: Params, x, states, tails, src, dst, fresh, config: GraniteHybridConfig):
@@ -478,23 +410,15 @@ def _wave_mamba(w: Params, x, states, tails, src, dst, fresh, config: GraniteHyb
     own: move each row's state on by its token (from block ``src`` to block
     ``dst``), then the expert layer. The layers of one kind share one traced
     and lowered function."""
-    n = _rms(x, w["in_norm"], config.rms_eps, config.dtype)
+    n = rms(x, w["in_norm"], config.rms_eps, config.dtype)
     with jax.named_scope("granite_mamba_mixer"):
-        # A row a slice, read and written in place: a gather by row makes
-        # XLA:TPU copy every block's state first (``falcon_h1.py``; PERF.md,
-        # PR 43). A wave's rows are few.
-        rows = range(x.shape[0])
-        slots_of = lambda cache, ids: jnp.stack(
-            [jax.lax.dynamic_index_in_dim(cache, ids[t], 0, keepdims=False) for t in rows]
-        )
+        # A row a slice, read and written in place (``layers.slots_of``).
         state = jnp.where(fresh[:, None, None, None], 0.0, slots_of(states, src))
         tail = jnp.where(fresh[:, None, None], jnp.zeros((), tails.dtype), slots_of(tails, src))
-        xs, b, c, dt, z, tail = _ssm_inputs(w, n, _tail_rows(tail, config), config)
+        xs, b, c, dt, z, tail = _ssm_inputs(w, n, tail_rows(tail, config), config)
         o, state = ssd.ssd_step(xs, dt, w["A_log"], b, c, w["D"], state)
-        tail = _tail_folded(tail, tails, config)
-        for t in rows:
-            states = jax.lax.dynamic_update_index_in_dim(states, state[t].astype(states.dtype), dst[t], 0)
-            tails = jax.lax.dynamic_update_index_in_dim(tails, tail[t], dst[t], 0)
+        states = set_slots(states, dst, state)
+        tails = set_slots(tails, dst, tail_folded(tail, tails))
         x = _ssm_out(w, x, o, z, config)
     x, ids, counts = _experts(w, x, config)
     return x, states, tails, ids, counts
@@ -506,7 +430,7 @@ def _wave_attention(
 ):
     """ONE attention layer of the wave body: insert the rows' K and V, attend
     each row's pages (the ragged decode kernel), then the expert layer."""
-    n = _rms(x, w["in_norm"], config.rms_eps, config.dtype)
+    n = rms(x, w["in_norm"], config.rms_eps, config.dtype)
     with jax.named_scope("granite_attention_mixer"):
         q, k, v = _qkv(w, n, config)
         k_cache = k_cache.at[dst, slots].set(k.astype(k_cache.dtype))
@@ -523,52 +447,35 @@ def _wave_attention(
     jax.jit, static_argnames=("config", "max_blocks"), donate_argnames=("caches",)
 )
 def verify_step_ragged(
-    params: Params,
-    tokens: jax.Array,  # [T] int32: one token a request (a state absorbs a token once)
-    positions: jax.Array,  # [T] int32
-    row_of: jax.Array,  # [T] int32 owning request per flat token
-    pages: jax.Array,  # [P] int32 the wave's flat page list (RaggedWaveMeta)
-    page_rows: jax.Array,  # [P + 1]
-    page_starts: jax.Array,  # [T]
-    caches: Caches,
-    block_tables: jax.Array,  # [B, max_blocks]
-    config: GraniteHybridConfig,
-    max_blocks: int,
+    params: Params, tokens, positions, row_of, pages, page_rows, page_starts, caches: Caches,
+    block_tables, config: GraniteHybridConfig, max_blocks: int,
 ):
-    """THE wave body (``llama.verify_step_ragged``'s contract and argument
+    """THE wave body (``serving.py``: ``wave``'s contract and argument
     order). ONE table serves both kinds of layer: a row's flat page list
     (built from the table on the host) is what an attention layer walks, and
     by its position the table names the block a Mamba layer's state comes from
     (position p - 1's) and the block it goes to (p's, where the row's K and V
     land too): a row that crosses a block boundary carries its running state
     into the new block's slot. Returns ``(logits [T, vocab], caches, aux)``:
-    ``aux["rows"]`` [T, sites, k] the experts every row chose at every layer IN
-    THIS STEP (with ``route_tail``, followed by the sets the tokens before it
-    chose in theirs, as the cache kept them: ``kimi_linear.py``), and
-    ``aux["counters"]``: ``moe_pairs``, ``moe_distinct_experts``,
-    ``moe_streamed_experts`` (``afmoe.verify_step_ragged``'s),
+    ``serving.ExpertTally``'s ``aux`` (with ``route_tail`` its ``rows`` are
+    followed by the sets the tokens before each row chose in theirs, as the
+    cache kept them: ``layers.wave_routes``) and, among its counters,
     ``moe_held_pairs`` (the real rows' (row, choice) pairs that fall on the
     experts held here) and ``state_carries``, the real rows that crossed into
     a new block. ``caches`` is donated."""
-    if block_tables.ndim != 2 or block_tables.shape[1] != max_blocks:
-        raise ValueError(f"block_tables must be [B, {max_blocks}], got {block_tables.shape}")
     bt = config.block_tokens
     x = _embed(params, tokens, config)
-    row_tables = jnp.take(block_tables, row_of, axis=0)
-    at = lambda pos: jnp.take_along_axis(row_tables, (pos // bt)[:, None], axis=1)[:, 0]
-    dst = at(positions)
-    src = at(jnp.maximum(positions - 1, 0))
-    fresh = positions == 0
-    slots = positions % bt
+    row_tables, dst, slots = wave_index(positions, row_of, block_tables, max_blocks, bt)
+    src, fresh = wave_sources(positions, row_tables, bt)
 
     mamba_fn = jax.jit(_wave_mamba, static_argnames=("config",))
     attention_fn = jax.jit(_wave_attention, static_argnames=("config",))
     new_caches: Caches = []
-    chosen, counts = [], expert_counts()
-    before = None
+    tally = ExpertTally()
+    found = None
     for layer, cache in enumerate(caches):
-        w = _layer_weights(params, layer)
-        cache, routes = _split_routes(cache, layer, config)
+        w = layer_weights(params, layer)
+        cache, routes = split_routes(cache, layer, config)
         if config.layer_types[layer] == ATTENTION:
             x, *cache, ids, n = attention_fn(
                 w, x, *cache, dst, slots, row_tables, positions + 1, pages, page_rows,
@@ -577,35 +484,18 @@ def verify_step_ragged(
         else:
             x, *cache, ids, n = mamba_fn(w, x, *cache, src, dst, fresh, config=config)
         cache = tuple(cache)
-        chosen.append(ids)
-        counts = jax.tree.map(jnp.add, counts, n)
+        tally.add(ids, n)
         if routes is not None:
-            # Each row's tail moves on by its own sets, as its state does.
-            t, tail = tokens.shape[0], config.route_tail
-            before = jnp.where(fresh[:, None, None], -1, routes[src]).reshape(t, tail, -1)
-            mine = jnp.stack(chosen, axis=1).reshape(t, 1, -1)
-            kept = jnp.concatenate([before[:, 1:], mine], axis=1)
-            cache += (routes.at[dst].set(kept.reshape(t, *routes.shape[1:])),)
+            routes, found = wave_routes(routes, tally.chosen, src, dst, fresh, config)
+            cache += (routes,)
         new_caches.append(cache)
     logits = _head(params, x, config)
-    real = jnp.concatenate([
-        jnp.ones((1,), bool),
-        (positions[1:] != positions[:-1]) | (row_of[1:] != row_of[:-1]),
-    ])
-    rows = jnp.stack(chosen, axis=1)  # [T, sites, k]
+    real = real_rows(positions, row_of)
+    aux = tally.aux(real, config.experts_per_token)
     first, count = config.held
-    held = (rows >= first) & (rows < first + count) & real[:, None, None]
-    k = config.experts_per_token
-    if before is not None:
-        # ... and the sets of the tokens before each row, the nearest first.
-        rows = jnp.concatenate([rows, before[:, ::-1].reshape(rows.shape[0], -1, k)], axis=1)
-    aux = {
-        "rows": rows,  # [T, sites x (1 + route_tail), k]
-        "counters": {
-            "moe_pairs": jnp.sum(real, dtype=jnp.int32) * (len(chosen) * k),
-            **counts,
-            "moe_held_pairs": jnp.sum(held, dtype=jnp.int32),
-            "state_carries": jnp.sum(real & (slots == 0) & ~fresh, dtype=jnp.int32),
-        },
-    }
+    held = (aux["rows"] >= first) & (aux["rows"] < first + count) & real[:, None, None]
+    aux["counters"]["moe_held_pairs"] = jnp.sum(held, dtype=jnp.int32)
+    aux["counters"]["state_carries"] = jnp.sum(real & (slots == 0) & ~fresh, dtype=jnp.int32)
+    if found is not None:
+        aux["rows"] = rows_with_routes(aux["rows"], found, config.experts_per_token)
     return logits, new_caches, aux

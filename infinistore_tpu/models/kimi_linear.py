@@ -17,7 +17,7 @@ float32, the recurrence a token at a time. Pre-norm, no positional rotation:
             [k_n, v] = W_kvb c;  k = [k_n, k_r];  causal softmax(q k^T / sqrt(192)) v
             mixer = Wo a
   dense   : Wdown (silu(Wgate m) * Wup m)
-  expert  : ``afmoe.expert_layer``, the one code both families run: sigmoid
+  expert  : ``tpu/moe.py`` ``expert_layer``, the one code every routed family runs: sigmoid
             scores in float32, top-k of (s + b), route_scale x s_e / sum of the
             chosen, one shared expert added unweighted, the held share
             (``experts_held``) of the routed ones
@@ -53,10 +53,9 @@ running state moves on and the block behind keeps its end state; rows that
 repeat their predecessor, a wave's padding, read and write the same bytes).
 
 The three serving entries keep the names the trace readers match: ``prefill``
-(a miss: the prompt cut at block boundaries through ``resume_chunk``, the
-very programs a hit's resume runs, so a full hit's first token equals the
-miss's to the bit), ``resume_chunk`` and ``verify_step_ragged``; each donates
-``caches``.
+(a miss: ``serving.prefill_by_blocks``, the prompt cut at block boundaries
+through ``resume_chunk``), ``resume_chunk`` and ``verify_step_ragged``; each
+donates ``caches``.
 """
 
 import functools
@@ -69,8 +68,15 @@ import numpy as np
 
 from ..tpu import kda, mla
 from ..tpu.paged import CacheTensor, PagedKVCacheSpec
-from .afmoe import _layer_weights, _rms, _swiglu, expert_counts, expert_layer
-from .serving import ServingSteps
+from .layers import (
+    chunk_routes, embed, head, layer_weights, mlp, rms, routes_shape, rows_with_routes,
+    split_routes, wave_routes,
+)
+from .layers import choices  # re-exported: this file's ``program.choices`` (benchmarks/configs/)
+from .serving import (
+    ExpertTally, ServingSteps, chunk_index, prefill_by_blocks, real_rows, resume_step,
+    wave_index, wave_sources,
+)
 
 Params = Dict[str, jax.Array]
 Caches = List[Tuple[jax.Array, ...]]
@@ -179,11 +185,7 @@ class KimiLinearConfig:
 
     @property
     def routes_shape(self) -> Tuple[int, int]:
-        """``[route_tail, sites, k]`` ids as the cache keeps them: folded to
-        128 lanes where they divide."""
-        total = self.route_tail * self.sites * self.experts_per_token
-        lanes = 128 if total % 128 == 0 else total
-        return (total // lanes, lanes)
+        return routes_shape(self.route_tail, self.sites, self.experts_per_token)
 
     @property
     def tail_shape(self) -> Tuple[int, int]:
@@ -207,8 +209,8 @@ class KimiLinearConfig:
     # What the wave step counts and returns with its logits (serving.py):
     # the expert layer's three, and the rows whose state crossed into a new
     # block (engine metrics: ``state_carries``).
-    step_counters = ("moe_pairs", "moe_distinct_experts", "moe_streamed_experts", "state_carries")
-    router = "sigmoid"  # ``afmoe.route``'s kind
+    step_counters = (*ExpertTally.counters, "state_carries")
+    router = "sigmoid"  # ``moe.route``'s kind
 
 
 def init_params(config: KimiLinearConfig, key: jax.Array) -> Params:
@@ -279,27 +281,6 @@ def init_params(config: KimiLinearConfig, key: jax.Array) -> Params:
     return p
 
 
-def _embed(params: Params, tokens: jax.Array) -> jax.Array:
-    # [T, dim] float32: the residual stream, carried unrounded within a step.
-    return jnp.take(params["embed"], tokens, axis=0).astype(jnp.float32)
-
-
-def _head(params: Params, x: jax.Array, config: KimiLinearConfig) -> jax.Array:
-    x = _rms(x, params["final_norm"], config.rms_eps, config.dtype)
-    return jnp.dot(x, params["lm_head"])
-
-
-def _mlp(w: Params, x, dense: bool, config: KimiLinearConfig):
-    """The second half of a layer on x: [T, dim] float32. Returns (x_next,
-    ids [T, k] or None, the expert layer's counts or None)."""
-    m = _rms(x, w["pre_mlp_norm"], config.rms_eps, config.dtype)
-    if dense:
-        f = _swiglu(m[None], w["w_gate_up"], w["w_down"])[0].astype(jnp.float32)
-        return x + f, None, None
-    f, ids, counts = expert_layer(w, m, config)
-    return x + f, ids, counts
-
-
 # ---------------------------------------------------------------------------
 # The KDA mixer.
 # ---------------------------------------------------------------------------
@@ -319,12 +300,7 @@ def _kda_inputs(w: Params, n, tail, config: KimiLinearConfig):
     t = n.shape[0]
     h, hd = config.kda_heads, config.kda_head_dim
     pre = jnp.dot(n, w["w_qkv"])  # [T, 3 H K]
-    if tail.ndim == 3:  # a wave: one position a row, each with its own tail
-        rows = jnp.concatenate([tail.astype(pre.dtype), pre[:, None]], axis=1)
-        y = jnp.sum(rows.astype(jnp.float32) * w["conv_w"].astype(jnp.float32)[None], axis=1)
-        new_tail = rows[:, 1:]
-    else:
-        y, new_tail = kda.short_conv(pre, tail, w["conv_w"])
+    y, new_tail = kda.short_conv(pre, tail, w["conv_w"])  # a wave's tails: one a row
     y = jax.nn.silu(y).reshape(t, 3, h, hd)
     q = (_l2norm(y[:, 0]) * np.float32(hd ** -0.5)).astype(config.dtype)
     k = _l2norm(y[:, 1]).astype(config.dtype)
@@ -339,7 +315,7 @@ def _kda_inputs(w: Params, n, tail, config: KimiLinearConfig):
 
 def _kda_out(w: Params, x, o, gate, config: KimiLinearConfig):
     """o: [T, H, V] float32. Per-head norm, the sigmoid gate, Wo, residual."""
-    normed = _rms(o, w["o_norm"], config.rms_eps, jnp.float32)
+    normed = rms(o, w["o_norm"], config.rms_eps, jnp.float32)
     gated = (normed * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(config.dtype)
     return x + jnp.dot(gated.reshape(x.shape[0], -1), w["wo"]).astype(jnp.float32)
 
@@ -355,7 +331,7 @@ def _mla_inputs(w: Params, n, config: KimiLinearConfig):
     q = jnp.einsum("td,dhk->thk", n, w["wq"])
     kva = jnp.dot(n, w["w_kva"])
     r = config.kv_lora_rank
-    c = _rms(kva[:, :r], w["kv_norm"], config.rms_eps)
+    c = rms(kva[:, :r], w["kv_norm"], config.rms_eps)
     return q, jnp.concatenate([c, kva[:, r:]], axis=-1)
 
 
@@ -376,12 +352,7 @@ def _mla_out(w: Params, x, attn, config: KimiLinearConfig):
 
 @functools.partial(jax.jit, static_argnames=("config",), donate_argnames=("caches",))
 def resume_chunk(
-    params: Params,
-    tokens: jax.Array,  # [S_c] int32, S_c <= block_tokens
-    start_pos: jax.Array,  # [] int32
-    caches: Caches,
-    block_table: jax.Array,  # [max_blocks] int32
-    config: KimiLinearConfig,
+    params: Params, tokens, start_pos, caches: Caches, block_table, config: KimiLinearConfig
 ) -> Tuple[jax.Array, Caches]:
     """ONE request's chunk at contiguous positions INSIDE ONE BLOCK (the
     caller cuts at block boundaries): a hit's question, and every piece of a
@@ -390,20 +361,15 @@ def resume_chunk(
     ones after its last token in the chunk's own block; the MLA layer writes
     its latents there and attends the table's pages. Returns (logits [S_c,
     vocab], caches); ``caches`` is donated."""
-    s_c = tokens.shape[0]
     bt = config.block_tokens
-    if s_c > bt:
-        raise ValueError(f"a chunk of {s_c} tokens does not lie in one {bt}-token block")
-    block = block_table[start_pos // bt]
-    before = block_table[jnp.maximum(start_pos - 1, 0) // bt]
-    fresh = start_pos == 0
-    x = _embed(params, tokens)
+    block, before, fresh = chunk_index(tokens, start_pos, block_table, bt)
+    x = embed(params, tokens)
     new_caches: Caches = []
     chosen = []
     for layer, cache in enumerate(caches):
-        w = _layer_weights(params, layer)
-        n = _rms(x, w["in_norm"], config.rms_eps, config.dtype)
-        cache, routes = _split_routes(cache, layer, config)
+        w = layer_weights(params, layer)
+        n = rms(x, w["in_norm"], config.rms_eps, config.dtype)
+        cache, routes = split_routes(cache, layer, config)
         if config.kind_of(layer) == KDA:
             states, tails = cache
             state = jnp.where(fresh, 0.0, states[before])
@@ -428,53 +394,21 @@ def resume_chunk(
             )
             cache = (latent,)
             x = _mla_out(w, x, attn, config)
-        x, ids, _ = _mlp(w, x, layer < config.n_dense_layers, config)
+        x, ids, _ = mlp(w, x, layer < config.n_dense_layers, config)
         if ids is not None:
             chosen.append(ids)
         if routes is not None:
-            # The last ``route_tail`` tokens' sets, the chunk's own the newest.
-            old = jnp.where(fresh, -1, routes[before]).reshape(config.route_tail, -1)
-            mine = jnp.stack(chosen, axis=1).reshape(s_c, -1)
-            kept = jnp.concatenate([old, mine])[-config.route_tail :]
-            cache += (routes.at[block].set(kept.reshape(routes.shape[1:])),)
+            cache += (chunk_routes(routes, chosen, block, before, fresh, config),)
         new_caches.append(cache)
-    return _head(params, x, config), new_caches
+    return head(params, x, config), new_caches
 
 
-def _split_routes(cache, layer: int, config: KimiLinearConfig):
-    """(the layer's own tensors, the ``routes`` tensor or None)."""
-    if config.route_tail and layer == config.n_layers - 1:
-        return cache[:-1], cache[-1]
-    return cache, None
-
-
-def prefill_continue(params, tokens, start_pos, caches, block_table, config, max_blocks):
-    """The harness's resume step (``llama.prefill_continue``'s signature)."""
-    if block_table.shape[0] != max_blocks:
-        raise ValueError(
-            f"block_table has {block_table.shape[0]} entries, expected max_blocks={max_blocks}"
-        )
-    return resume_chunk(params, tokens, start_pos, caches, block_table, config)
-
-
-def prefill(params, tokens, caches, block_table, config: KimiLinearConfig):
-    """A miss: every token given, cut at block boundaries through the chunk
-    program a hit's resume runs, so that each block's slot holds the state at
-    its end. ``block_table`` covers the tokens (a last block may be part
-    full). Returns (last-token logits, caches); ``caches`` is donated."""
-    bt = config.block_tokens
-    tokens = jnp.asarray(tokens, jnp.int32)
-    table = jnp.asarray(block_table, jnp.int32)
-    logits = None
-    for start in range(0, tokens.shape[0], bt):
-        logits, caches = resume_chunk(
-            params, tokens[start : start + bt], jnp.int32(start), caches, table, config
-        )
-    return logits[-1], caches
+prefill_continue = resume_step(resume_chunk)
+prefill = prefill_by_blocks(resume_chunk)
 
 
 def _wave_kda(w: Params, x, states, tails, src, dst, fresh, config: KimiLinearConfig):
-    n = _rms(x, w["in_norm"], config.rms_eps, config.dtype)
+    n = rms(x, w["in_norm"], config.rms_eps, config.dtype)
     state = jnp.where(fresh[:, None, None, None], 0.0, states[src])
     tail = jnp.where(fresh[:, None, None], jnp.zeros((), tails.dtype), tails[src])
     tail = tail.reshape(x.shape[0], config.conv_taps - 1, -1)
@@ -486,7 +420,7 @@ def _wave_kda(w: Params, x, states, tails, src, dst, fresh, config: KimiLinearCo
 
 
 def _wave_mla(w: Params, x, latent, dst, slots, row_tables, seq_lens, config: KimiLinearConfig):
-    n = _rms(x, w["in_norm"], config.rms_eps, config.dtype)
+    n = rms(x, w["in_norm"], config.rms_eps, config.dtype)
     q, rows = _mla_inputs(w, n, config)
     # A row a slice, in place: a scatter by (block, slot) makes XLA re-lay the
     # whole cache out and back every wave. A wave's rows are few.
@@ -511,90 +445,48 @@ def _wave_mla(w: Params, x, latent, dst, slots, row_tables, seq_lens, config: Ki
     jax.jit, static_argnames=("config", "max_blocks"), donate_argnames=("caches",)
 )
 def verify_step_ragged(
-    params: Params,
-    tokens: jax.Array,  # [T] int32: one token a request (a state absorbs a token once)
-    positions: jax.Array,  # [T] int32
-    row_of: jax.Array,  # [T] int32 owning request per flat token
-    pages: jax.Array,  # the wave's flat page list: unused, each row walks its table
-    page_rows: jax.Array,
-    page_starts: jax.Array,
-    caches: Caches,
-    block_tables: jax.Array,  # [B, max_blocks]
-    config: KimiLinearConfig,
-    max_blocks: int,
+    params: Params, tokens, positions, row_of, pages, page_rows, page_starts, caches: Caches,
+    block_tables, config: KimiLinearConfig, max_blocks: int,
 ):
-    """THE wave body (``llama.verify_step_ragged``'s contract and argument
-    order). Each row names, by its position and its table, the block its
-    state comes from (position p - 1's) and the block it goes to (p's): a row
-    that crosses a block boundary carries its running state into the new
-    block's slot. Returns ``(logits [T, vocab], caches, aux)``: ``aux["rows"]``
-    [T, sites, k] the experts every row chose at every expert layer IN THIS
-    STEP (with ``route_tail``, followed by the sets the tokens before it chose
-    in theirs, as the cache kept them), and ``aux["counters"]``: ``moe_pairs``,
-    ``moe_distinct_experts``, ``moe_streamed_experts``
-    (``afmoe.verify_step_ragged``'s) and ``state_carries``, the real rows that
-    crossed into a new block. ``caches`` is donated."""
+    """THE wave body (``serving.py``: ``wave``'s contract and argument order).
+    Each row names, by its position and its table, the block its state comes
+    from (position p - 1's) and the block it goes to (p's): a row that crosses
+    a block boundary carries its running state into the new block's slot.
+    Returns ``(logits [T, vocab], caches, aux)``: ``serving.ExpertTally``'s
+    ``aux`` (with ``route_tail`` its ``rows`` are followed by the sets the
+    tokens before each row chose in theirs, as the cache kept them) and, among
+    its counters, ``state_carries``, the real rows that crossed into a new
+    block. ``caches`` is donated."""
     del pages, page_rows, page_starts
-    if block_tables.ndim != 2 or block_tables.shape[1] != max_blocks:
-        raise ValueError(f"block_tables must be [B, {max_blocks}], got {block_tables.shape}")
     bt = config.block_tokens
-    x = _embed(params, tokens)
-    row_tables = jnp.take(block_tables, row_of, axis=0)
-    at = lambda pos: jnp.take_along_axis(row_tables, (pos // bt)[:, None], axis=1)[:, 0]
-    dst = at(positions)
-    src = at(jnp.maximum(positions - 1, 0))
-    fresh = positions == 0
-    slots = positions % bt
+    x = embed(params, tokens)
+    row_tables, dst, slots = wave_index(positions, row_of, block_tables, max_blocks, bt)
+    src, fresh = wave_sources(positions, row_tables, bt)
     seq_lens = positions + 1
 
     kda_fn = jax.jit(_wave_kda, static_argnames=("config",))
     new_caches: Caches = []
-    chosen, counts = [], expert_counts()
-    before = None
+    tally = ExpertTally()
+    found = None
     for layer, cache in enumerate(caches):
-        w = _layer_weights(params, layer)
-        cache, routes = _split_routes(cache, layer, config)
+        w = layer_weights(params, layer)
+        cache, routes = split_routes(cache, layer, config)
         if config.kind_of(layer) == KDA:
             x, states, tails = kda_fn(w, x, *cache, src, dst, fresh, config=config)
             cache = (states, tails)
         else:
             x, latent = _wave_mla(w, x, *cache, dst, slots, row_tables, seq_lens, config)
             cache = (latent,)
-        x, ids, n = _mlp(w, x, layer < config.n_dense_layers, config)
-        if ids is not None:
-            chosen.append(ids)
-            counts = jax.tree.map(jnp.add, counts, n)
+        x, ids, n = mlp(w, x, layer < config.n_dense_layers, config)
+        tally.add(ids, n)
         if routes is not None:
-            # Each row's tail moves on by its own sets, as its state does.
-            t, tail = tokens.shape[0], config.route_tail
-            before = jnp.where(fresh[:, None, None], -1, routes[src]).reshape(t, tail, -1)
-            mine = jnp.stack(chosen, axis=1).reshape(t, 1, -1)
-            kept = jnp.concatenate([before[:, 1:], mine], axis=1)
-            cache += (routes.at[dst].set(kept.reshape(t, *routes.shape[1:])),)
+            routes, found = wave_routes(routes, tally.chosen, src, dst, fresh, config)
+            cache += (routes,)
         new_caches.append(cache)
-    logits = _head(params, x, config)
-    real = jnp.concatenate([
-        jnp.ones((1,), bool),
-        (positions[1:] != positions[:-1]) | (row_of[1:] != row_of[:-1]),
-    ])
-    rows = jnp.stack(chosen, axis=1)  # [T, sites, k]
-    if before is not None:
-        # ... and the sets of the tokens before each row, the nearest first.
-        k = config.experts_per_token
-        rows = jnp.concatenate([rows, before[:, ::-1].reshape(rows.shape[0], -1, k)], axis=1)
-    aux = {
-        "rows": rows,  # [T, sites x (1 + route_tail), k]
-        "counters": {
-            "moe_pairs": jnp.sum(real, dtype=jnp.int32)
-            * (len(chosen) * config.experts_per_token),
-            **counts,
-            "state_carries": jnp.sum(real & (slots == 0) & ~fresh, dtype=jnp.int32),
-        },
-    }
+    logits = head(params, x, config)
+    real = real_rows(positions, row_of)
+    aux = tally.aux(real, config.experts_per_token)
+    if found is not None:
+        aux["rows"] = rows_with_routes(aux["rows"], found, config.experts_per_token)
+    aux["counters"]["state_carries"] = jnp.sum(real & (slots == 0) & ~fresh, dtype=jnp.int32)
     return logits, new_caches, aux
-
-
-def choices(harness, rows) -> np.ndarray:
-    """``afmoe.choices``'s contract: the experts the timed wave chose while it
-    made the logits ``rows``, read off what the wave returned with them."""
-    return np.asarray(harness.wave.row_aux(rows))

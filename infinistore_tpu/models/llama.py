@@ -30,7 +30,8 @@ from ..tpu.paged_attention import (
     paged_decode_attention_rows,
     rectangle_as_ragged,
 )
-from .serving import ServingSteps
+from .layers import rope
+from .serving import ServingSteps, resume_step, wave_index
 
 Params = Dict[str, jax.Array]
 Caches = List[Tuple[jax.Array, jax.Array]]
@@ -116,18 +117,6 @@ def init_params(config: LlamaConfig, key: jax.Array) -> Params:
 def _rms_norm(x: jax.Array, w: jax.Array) -> jax.Array:
     var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
     return (x * jax.lax.rsqrt(var + 1e-6).astype(x.dtype)) * w
-
-
-def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: [..., seq, heads, head_dim], positions: [..., seq]."""
-    hd = x.shape[-1]
-    freqs = 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
-    angles = positions[..., :, None].astype(jnp.float32) * freqs  # [..., seq, hd/2]
-    cos = jnp.cos(angles)[..., :, None, :]
-    sin = jnp.sin(angles)[..., :, None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
-    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
-    return out.astype(x.dtype)
 
 
 def _attention(
@@ -235,7 +224,7 @@ def _q_proj(params: Params, layer: int, x, positions, config):
     pre = f"l{layer}."
     h = _rms_norm(x, params[pre + "attn_norm"])
     q = jnp.einsum("bsd,dhk->bshk", h, params[pre + "wq"])
-    return _rope(q, positions, config.rope_theta)
+    return rope(q, positions, config.rope_theta)
 
 
 def _kv_proj(params: Params, layer: int, x, positions, config):
@@ -243,7 +232,7 @@ def _kv_proj(params: Params, layer: int, x, positions, config):
     h = _rms_norm(x, params[pre + "attn_norm"])
     k = jnp.einsum("bsd,dhk->bshk", h, params[pre + "wk"])
     v = jnp.einsum("bsd,dhk->bshk", h, params[pre + "wv"])
-    k = _rope(k, positions, config.rope_theta)
+    k = rope(k, positions, config.rope_theta)
     return k, v
 
 
@@ -448,19 +437,11 @@ def verify_step_ragged(
         )
     if page_starts.shape != (t,):
         raise ValueError(f"page_starts must be [{t}], got {page_starts.shape}")
-    if block_tables.ndim != 2 or block_tables.shape[1] != max_blocks:
-        raise ValueError(
-            f"block_tables must be [B, {max_blocks}], got {block_tables.shape}"
-        )
-    bt = config.block_tokens
     x = jnp.take(params["embed"], tokens, axis=0)[None]  # [1, T, dim]
     pos2d = positions[None]  # [1, T]
-
-    row_tables = jnp.take(block_tables, row_of, axis=0)  # [T, max_blocks]
-    block_idx = jnp.take_along_axis(
-        row_tables, (positions // bt)[:, None], axis=1
-    )[:, 0]
-    slots = positions % bt
+    row_tables, block_idx, slots = wave_index(
+        positions, row_of, block_tables, max_blocks, config.block_tokens
+    )
     seq_lens = positions + 1
 
     # One jit for this trace alone: the layers share its traced and lowered
@@ -527,34 +508,16 @@ def resume_chunk(
     return logits[0], new_caches
 
 
-def prefill_continue(
-    params: Params,
-    tokens: jax.Array,  # [S_c] int32, the suffix chunk
-    start_pos: jax.Array,  # [] int32, absolute position of tokens[0]
-    caches: Caches,
-    block_table: jax.Array,  # [max_blocks] int32 (padded)
-    config: LlamaConfig,
-    max_blocks: int,
-) -> Tuple[jax.Array, Caches]:
-    """Chunked continuation prefill: compute a multi-token suffix against an
-    already-populated paged prefix in ONE call per layer (the engine's
-    chunked-prefill resume path — vLLM's treatment of a prefix-cache hit).
-    Token-by-token ``decode_step`` costs S_c launches per layer and GEMV
-    matmuls; this inserts the whole chunk's K/V and attends all chunk rows
-    in one kernel launch that reads each context page once for the whole
-    chunk (each row masked to its own prefix length), with chunk-wide GEMMs
-    for the projections and FFN. Semantically equal to the decode loop
-    (tested). Returns ([S_c, vocab] logits, caches).
-
-    A plain function over the jitted ``resume_chunk``: what selects that
-    program is this signature (one request, contiguous positions, a chunk),
-    and its compile key is the chunk's length and ``max_blocks``."""
-    if block_table.shape[0] != max_blocks:
-        raise ValueError(
-            f"block_table has {block_table.shape[0]} entries, expected "
-            f"max_blocks={max_blocks} (pad the table to the static bound)"
-        )
-    return resume_chunk(params, tokens, start_pos, caches, block_table, config)
+# Chunked continuation prefill (the harness's resume step, ``serving.py``): a
+# multi-token suffix against an already-populated paged prefix in ONE call per
+# layer, vLLM's treatment of a prefix-cache hit. Token-by-token ``decode_step``
+# costs S_c launches per layer and GEMV matmuls; ``resume_chunk`` inserts the
+# whole chunk's K/V and attends all its rows in one kernel launch that reads
+# each context page once, with chunk-wide GEMMs for the projections and FFN.
+# Semantically equal to the decode loop (tested). What selects that program is
+# this signature (one request, contiguous positions, a chunk); its compile key
+# is the chunk's length and ``max_blocks``.
+prefill_continue = resume_step(resume_chunk)
 
 
 def speculative_verify(

@@ -22,7 +22,8 @@ import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .llama import LlamaConfig, Params, _rms_norm, _rope
+from .layers import rope
+from .llama import LlamaConfig, Params, _rms_norm
 from .ring_attention import _ring_attention_local
 
 
@@ -43,8 +44,8 @@ def _local_forward(params, tokens, config: LlamaConfig, axis: str):
         q = jnp.einsum("bsd,dhk->bshk", h, params[pre + "wq"])
         k = jnp.einsum("bsd,dhk->bshk", h, params[pre + "wk"])
         v = jnp.einsum("bsd,dhk->bshk", h, params[pre + "wv"])
-        q = _rope(q, positions, config.rope_theta)
-        k = _rope(k, positions, config.rope_theta)
+        q = rope(q, positions, config.rope_theta)
+        k = rope(k, positions, config.rope_theta)
         kvs.append((k, v))
         attn = _ring_attention_local(
             q,

@@ -26,13 +26,14 @@ ones divided by ``factor``, a linear blend between, cosine AND sine times
 carries that factor once; a hit installs those bytes as they were saved, so
 nothing downstream of the cache knows which rotation made them.
 
-The expert layer is ``afmoe.expert_layer`` itself (``router =
+The expert layer is ``tpu/moe.py``'s ``expert_layer`` (``router =
 "softmax_topk"``: softmax over all the experts, top-k, renormalised, which IS
 the softmax over the k chosen logits; ``n_shared_experts = 0``), the cache's
 shape ``afmoe``'s (per-layer ``windows``: a hit installs a sliding layer's
 trailing ``window / block_tokens`` blocks). The three serving entries keep
 the names the trace readers match and donate ``caches``; the wave returns the
-ids every row chose at every layer and ``afmoe``'s two counters.
+ids every row chose at every layer and the expert layer's counters
+(``serving.ExpertTally``).
 """
 
 import functools
@@ -48,10 +49,10 @@ from ..tpu.chunk_attention import chunk_prefix_attention
 from ..tpu.flash_prefill import flash_prefill_attention
 from ..tpu.paged import PagedKVCacheSpec, scatter_blocks
 from ..tpu.paged_attention import paged_decode_attention_rows
-from .afmoe import (  # noqa: F401 - ``choices``: the file's ``program.choices``
-    FULL, SLIDING, _layer_weights, _rms, choices, expert_counts, expert_layer,
-)
-from .serving import ServingSteps
+from ..tpu.moe import expert_layer
+from .layers import FULL, SLIDING, layer_weights, rms
+from .layers import choices  # re-exported: this file's ``program.choices`` (benchmarks/configs/)
+from .serving import ExpertTally, ServingSteps, real_rows, resume_step, wave_index
 
 Params = Dict[str, jax.Array]
 Caches = List[Tuple[jax.Array, jax.Array]]
@@ -130,7 +131,7 @@ class MellumConfig:
             raise ValueError(f"layer_types holds {sorted(unknown)}")
         if not self.norm_topk_prob:
             raise ValueError(
-                "norm_topk_prob is false: the router this family shares (afmoe.route, "
+                "norm_topk_prob is false: the router this family shares (tpu/moe.py route, "
                 "'softmax_topk') renormalises the chosen probabilities"
             )
         rope = _SMALL_ROPE if self.rope_parameters is None else self.rope_parameters
@@ -173,11 +174,11 @@ class MellumConfig:
     def steps(self) -> ServingSteps:
         return ServingSteps(prefill, prefill_continue, verify_step_ragged)
 
-    # What ``afmoe.expert_layer`` asks of a configuration beside the fields.
+    # What ``moe.expert_layer`` asks of a configuration beside the fields.
     router = "softmax_topk"
     n_shared_experts = 0
     # What the wave step counts and returns with its logits (serving.py).
-    step_counters = ("moe_pairs", "moe_distinct_experts", "moe_streamed_experts")
+    step_counters = ExpertTally.counters
     # What the published config says of the family and this file takes as
     # given (a configuration's file holds them to its own keys).
     attention_bias = False
@@ -251,7 +252,7 @@ def _embed(params: Params, tokens: jax.Array) -> jax.Array:
 
 
 def _head(params: Params, x: jax.Array, config: MellumConfig) -> jax.Array:
-    x = _rms(x, params["final_norm"], config.rms_eps, config.dtype)
+    x = rms(x, params["final_norm"], config.rms_eps, config.dtype)
     return jnp.einsum("bsd,dv->bsv", x, params["lm_head"], preferred_element_type=jnp.float32)
 
 
@@ -262,10 +263,10 @@ def _attn_inputs(w: Params, x, positions, kind: str, config: MellumConfig):
     rotation and are rounded ONCE, to the served type: every rounding of a
     key is one every later token's attention carries."""
     f32 = jnp.float32
-    n = _rms(x, w["in_norm"], config.rms_eps, config.dtype)
+    n = rms(x, w["in_norm"], config.rms_eps, config.dtype)
     project = lambda name: jnp.einsum("bsd,dhk->bshk", n, w[name], preferred_element_type=f32)
-    q = _rms(project("wq"), w["q_norm"], config.rms_eps)
-    k = _rms(project("wk"), w["k_norm"], config.rms_eps)
+    q = rms(project("wq"), w["q_norm"], config.rms_eps)
+    k = rms(project("wk"), w["k_norm"], config.rms_eps)
     v = jnp.einsum("bsd,dhk->bshk", n, w["wv"])
     inv_freq, scale = config.rotation(kind)
     with jax.named_scope("yarn_rope" if kind == FULL else "plain_rope"):
@@ -281,7 +282,7 @@ def _close(w: Params, x, attn, config: MellumConfig):
     output projected and then the expert layer of the normed sum. Returns
     (x_next, ids [T, k], the expert layer's counts)."""
     x = x + jnp.einsum("bshk,hkd->bsd", attn, w["wo"], preferred_element_type=jnp.float32)
-    m = _rms(x, w["post_norm"], config.rms_eps, config.dtype)
+    m = rms(x, w["post_norm"], config.rms_eps, config.dtype)
     f, ids, counts = expert_layer(w, m[0], config)
     return x + f[None], ids, counts
 
@@ -307,7 +308,7 @@ def prefill(
     x = _embed(params, tokens)
     new_caches: Caches = []
     for layer, (k_cache, v_cache) in enumerate(caches):
-        w = _layer_weights(params, layer)
+        w = layer_weights(params, layer)
         kind = config.layer_types[layer]
         q, k, v = _attn_inputs(w, x, positions, kind, config)
         with jax.named_scope(kind):
@@ -348,77 +349,46 @@ def _wave_layer(
     jax.jit, static_argnames=("config", "max_blocks"), donate_argnames=("caches",)
 )
 def verify_step_ragged(
-    params: Params,
-    tokens: jax.Array,  # [T] int32, the wave's chunks concatenated
-    positions: jax.Array,  # [T] int32
-    row_of: jax.Array,  # [T] int32 owning request per flat token
-    pages: jax.Array,  # [P] the wave's flat page list (RaggedWaveMeta)
-    page_rows: jax.Array,  # [P + 1]
-    page_starts: jax.Array,  # [T]
-    caches: Caches,
-    block_tables: jax.Array,  # [B, max_blocks]
-    config: MellumConfig,
-    max_blocks: int,
-    window_pages=None,  # the same triple for the sliding layers
+    params: Params, tokens, positions, row_of, pages, page_rows, page_starts, caches: Caches,
+    block_tables, config: MellumConfig, max_blocks: int, window_pages=None,
 ):
-    """THE wave body (``afmoe.verify_step_ragged``'s contract, argument order
-    and ``aux``): ``(logits [T, vocab], caches, aux)`` with ``aux["rows"]``
-    [T, sites, k] the experts every row chose at every layer in this step and
-    ``aux["counters"]`` ``moe_pairs`` / ``moe_distinct_experts`` /
-    ``moe_streamed_experts``, folded on the device. ``caches`` is donated."""
-    if block_tables.ndim != 2 or block_tables.shape[1] != max_blocks:
-        raise ValueError(f"block_tables must be [B, {max_blocks}], got {block_tables.shape}")
+    """THE wave body (``serving.py``: ``wave``'s contract and argument order,
+    the sliding layers on the wave's second page list): ``(logits [T, vocab],
+    caches, aux)`` with ``aux`` ``serving.ExpertTally``'s, the experts every
+    row chose at every layer in this step and the ``moe_*`` counters, folded on
+    the device. ``caches`` is donated."""
     if window_pages is None and SLIDING in config.layer_types:
         raise ValueError("a model with sliding layers needs the wave's window_pages")
-    bt = config.block_tokens
     x = _embed(params, tokens)
     pos2d = positions[None]
-    row_tables = jnp.take(block_tables, row_of, axis=0)
-    block_idx = jnp.take_along_axis(row_tables, (positions // bt)[:, None], axis=1)[:, 0]
-    slots = positions % bt
+    row_tables, block_idx, slots = wave_index(
+        positions, row_of, block_tables, max_blocks, config.block_tokens
+    )
     seq_lens = positions + 1
 
     layer_fn = jax.jit(_wave_layer, static_argnames=("config", "kind"))
     new_caches: Caches = []
-    chosen, counts = [], expert_counts()
+    tally = ExpertTally()
     for layer, (k_cache, v_cache) in enumerate(caches):
         kind = config.layer_types[layer]
         meta = window_pages if kind == SLIDING else (pages, page_rows, page_starts)
         x, k_cache, v_cache, ids, n = layer_fn(
-            _layer_weights(params, layer), x, pos2d, k_cache, v_cache, block_idx,
+            layer_weights(params, layer), x, pos2d, k_cache, v_cache, block_idx,
             slots, row_tables, seq_lens, *meta, config=config, kind=kind,
         )
         new_caches.append((k_cache, v_cache))
-        chosen.append(ids)
-        counts = jax.tree.map(jnp.add, counts, n)
+        tally.add(ids, n)
     logits = _head(params, x, config)[0]
-    # A tail row that repeats its predecessor is the bucket's padding.
-    real = jnp.concatenate([
-        jnp.ones((1,), bool),
-        (positions[1:] != positions[:-1]) | (row_of[1:] != row_of[:-1]),
-    ])
-    aux = {
-        "rows": jnp.stack(chosen, axis=1),  # [T, sites, k]
-        "counters": {
-            "moe_pairs": jnp.sum(real, dtype=jnp.int32)
-            * (len(chosen) * config.experts_per_token),
-            **counts,
-        },
-    }
+    aux = tally.aux(real_rows(positions, row_of), config.experts_per_token)
     return logits, new_caches, aux
 
 
 @functools.partial(jax.jit, static_argnames=("config",), donate_argnames=("caches",))
 def resume_chunk(
-    params: Params,
-    tokens: jax.Array,  # [S_c] int32, the suffix chunk
-    start_pos: jax.Array,  # [] int32
-    caches: Caches,
-    block_table: jax.Array,  # [max_blocks] int32
-    config: MellumConfig,
+    params: Params, tokens, start_pos, caches: Caches, block_table, config: MellumConfig
 ) -> Tuple[jax.Array, Caches]:
     """A prefix hit's question: ONE request's chunk at contiguous positions
-    over the pages in the cache (``llama.resume_chunk``'s contract). A
+    over the pages in the cache (``serving.py``: ``resume``'s contract). A
     sliding layer reads no page behind its first row's window: those a hit
     left uninstalled. ``caches`` is donated."""
     s_c = tokens.shape[0]
@@ -430,7 +400,7 @@ def resume_chunk(
     slots = positions % bt
     new_caches: Caches = []
     for layer, (k_cache, v_cache) in enumerate(caches):
-        w = _layer_weights(params, layer)
+        w = layer_weights(params, layer)
         kind = config.layer_types[layer]
         q, k, v = _attn_inputs(w, x, pos2d, kind, config)
         k_cache = k_cache.at[block_idx, slots].set(k[0].astype(k_cache.dtype))
@@ -444,11 +414,4 @@ def resume_chunk(
     return _head(params, x, config)[0], new_caches
 
 
-def prefill_continue(params, tokens, start_pos, caches, block_table, config, max_blocks):
-    """``llama.prefill_continue``'s signature over this file's
-    ``resume_chunk``: the harness's resume step."""
-    if block_table.shape[0] != max_blocks:
-        raise ValueError(
-            f"block_table has {block_table.shape[0]} entries, expected max_blocks={max_blocks}"
-        )
-    return resume_chunk(params, tokens, start_pos, caches, block_table, config)
+prefill_continue = resume_step(resume_chunk)

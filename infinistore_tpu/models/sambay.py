@@ -92,9 +92,12 @@ from ..tpu.flash_prefill import flash_prefill_attention
 from ..tpu.paged import CacheTensor, PagedKVCacheSpec
 from ..tpu.paged_attention import paged_decode_attention_rows
 from ..tpu.selective_scan import selective_scan_chunk, selective_scan_step
-from .afmoe import _layer_weights
-from .granite_hybrid import _tail_folded, _tail_rows  # the convolution tail's fold, by ``conv_taps`` and ``conv_width``
-from .serving import ServingSteps
+from .layers import (
+    embed, folded_tail_shape, layer_weights, set_slots, slots_of, tail_folded, tail_rows,
+)
+from .serving import (
+    ServingSteps, chunk_index, prefill_by_blocks, real_rows, resume_step, wave_index, wave_sources,
+)
 
 Params = Dict[str, jax.Array]
 Caches = List[Tuple[jax.Array, ...]]
@@ -183,14 +186,8 @@ class SambaYConfig:
 
     @property
     def tail_shape(self) -> Tuple[int, int]:
-        """The convolution tail's ``[taps - 1, ssm_width]`` rows as the cache
-        keeps them (``granite_hybrid``'s fold: 128 lanes where they divide,
-        the rows rounded up to four)."""
-        total = (self.conv_taps - 1) * self.conv_width
-        if total % 128:
-            return (self.conv_taps - 1, self.conv_width)
-        rows = total // 128
-        return (rows + -rows % 4, 128)
+        """The convolution tail's rows as the cache keeps them."""
+        return folded_tail_shape(self.conv_taps, self.conv_width)
 
     def lambda_init(self, layer: int) -> float:
         return 0.8 - 0.6 * float(np.exp(-0.3 * layer))
@@ -340,11 +337,6 @@ def _proj(spec: str, x, w):
     return sum(out[i * rows : (i + 1) * rows] for i in range(len(pieces)))
 
 
-def _embed(params: Params, tokens: jax.Array) -> jax.Array:
-    # [T, dim] float32: the residual stream, carried unrounded within a step.
-    return jnp.take(params["embed"], tokens, axis=0).astype(jnp.float32)
-
-
 def _head(params: Params, x: jax.Array, config: SambaYConfig) -> jax.Array:
     """The tied head: the embedding's rows against the normed stream."""
     x = _ln(x, params["final_norm"], params["final_norm_b"], config)
@@ -416,12 +408,7 @@ def _scan_inputs(w: Params, n, tail, config: SambaYConfig):
     uz = _proj("td,dcf->tcf", n, w["w_in"])
     # Float32 rows into the convolution; the cache rounds the tail it keeps.
     pre, z = uz[:, 0], uz[:, 1]
-    if tail.ndim == 3:  # a wave: one position a row, each with its own tail
-        rows = jnp.concatenate([tail.astype(pre.dtype), pre[:, None]], axis=1)
-        y = jnp.sum(rows.astype(f32) * w["conv_w"].astype(f32)[None], axis=1)
-        new_tail = rows[:, 1:]
-    else:
-        y, new_tail = kda.short_conv(pre, tail, w["conv_w"])
+    y, new_tail = kda.short_conv(pre, tail, w["conv_w"])  # a wave's tails: one a row
     u = jax.nn.silu(y + w["conv_b"].astype(f32))
     rbc = _proj("tc,cr->tr", u, w["w_x"])
     r, st = config.dt_rank, config.ssm_state
@@ -486,13 +473,8 @@ def _tail_after(old, new, start_pos, config: SambaYConfig):
 
 @functools.partial(jax.jit, static_argnames=("config",), donate_argnames=("caches",))
 def resume_chunk(
-    params: Params,
-    tokens: jax.Array,  # [S_c] int32, S_c <= block_tokens
-    start_pos: jax.Array,  # [] int32, a multiple of the window
-    caches: Caches,
-    block_table: jax.Array,  # [max_blocks] int32
-    config: SambaYConfig,
-) -> Caches:
+    params: Params, tokens, start_pos, caches: Caches, block_table, config: SambaYConfig
+) -> Tuple[None, Caches]:
     """ONE request's chunk at contiguous positions INSIDE ONE BLOCK: a hit's
     question, and every piece of a miss's prefill. The self-decoder and layer
     ``full``'s K/V projection, nothing else (module docstring): a Mamba layer
@@ -501,19 +483,15 @@ def resume_chunk(
     the chunk's own block; a sliding layer attends the tail it finds there and
     the chunk's own keys, and leaves the tail after its last token; layer
     ``full`` writes the chunk's K and V into the block's page and attends
-    nothing. Returns the caches: a prompt step has no logits. ``caches`` is
-    donated."""
+    nothing. Returns ``(None, caches)``: a prompt step has no logits
+    (``serving.py``). ``caches`` is donated."""
     s_c = tokens.shape[0]
     bt, win = config.block_tokens, config.sliding_window
-    if s_c > bt:
-        raise ValueError(f"a chunk of {s_c} tokens does not lie in one {bt}-token block")
-    block = block_table[start_pos // bt]
-    before = block_table[jnp.maximum(start_pos - 1, 0) // bt]
-    fresh = start_pos == 0
-    x = _embed(params, tokens)
+    block, before, fresh = chunk_index(tokens, start_pos, block_table, bt)
+    x = embed(params, tokens)
     new_caches: Caches = []
     for layer, cache in enumerate(caches):
-        w = _layer_weights(params, layer)
+        w = layer_weights(params, layer)
         n = _ln(x, w["in_norm"], w["in_norm_b"], config)
         kind = config.kind(layer)
         if kind == MAMBA:
@@ -521,10 +499,10 @@ def resume_chunk(
                 states, tails = cache
                 state = jnp.where(fresh, 0.0, states[before])
                 tail = jnp.where(fresh, jnp.zeros((), tails.dtype), tails[before])
-                u, dt, b, c, z, tail = _scan_inputs(w, n, _tail_rows(tail, config), config)
+                u, dt, b, c, z, tail = _scan_inputs(w, n, tail_rows(tail, config), config)
                 y, state = selective_scan_chunk(u, dt, w["A_log"], b, c, w["D"], state)
                 x = _scan_out(w, x, y, z, config)
-                cache = (states.at[block].set(state), tails.at[block].set(_tail_folded(tail, tails, config)))
+                cache = (states.at[block].set(state), tails.at[block].set(tail_folded(tail, tails)))
         elif kind == SLIDING:
             with jax.named_scope("sambay_sliding_mixer"):
                 k_tails, v_tails = cache
@@ -555,42 +533,11 @@ def resume_chunk(
             break
         x = _mlp(w, x, config)
         new_caches.append(cache)
-    return new_caches
+    return None, new_caches
 
 
-def prefill_continue(params, tokens, start_pos, caches, block_table, config, max_blocks):
-    """The harness's resume step (``llama.prefill_continue``'s signature). No
-    logits: ``(None, caches)``."""
-    if block_table.shape[0] != max_blocks:
-        raise ValueError(
-            f"block_table has {block_table.shape[0]} entries, expected max_blocks={max_blocks}"
-        )
-    return None, resume_chunk(params, tokens, start_pos, caches, block_table, config)
-
-
-def prefill(params, tokens, caches, block_table, config: SambaYConfig):
-    """A miss: every token given, cut at block boundaries through the chunk
-    program a hit's resume runs, so that each block's slot holds the state and
-    the tails at its end. ``block_table`` covers the tokens (a last block may
-    be part full). Returns ``(None, caches)``; ``caches`` is donated."""
-    bt = config.block_tokens
-    tokens = jnp.asarray(tokens, jnp.int32)
-    table = jnp.asarray(block_table, jnp.int32)
-    for start in range(0, tokens.shape[0], bt):
-        caches = resume_chunk(params, tokens[start : start + bt], jnp.int32(start), caches, table, config)
-    return None, caches
-
-
-def _slots_of(cache, ids):
-    # A row a slice, read in place: a gather by row makes XLA:TPU copy every
-    # block's tensor first (``falcon_h1.py``; PERF.md, PR 43). A wave's rows are few.
-    return jnp.stack([jax.lax.dynamic_index_in_dim(cache, ids[t], 0, keepdims=False) for t in range(ids.shape[0])])
-
-
-def _set_slots(cache, ids, values):
-    for t in range(ids.shape[0]):
-        cache = jax.lax.dynamic_update_index_in_dim(cache, values[t].astype(cache.dtype), ids[t], 0)
-    return cache
+prefill_continue = resume_step(resume_chunk)
+prefill = prefill_by_blocks(resume_chunk)
 
 
 def _wave_mamba(w: Params, x, states, tails, src, dst, fresh, config: SambaYConfig):
@@ -600,12 +547,12 @@ def _wave_mamba(w: Params, x, states, tails, src, dst, fresh, config: SambaYConf
     last Mamba layer's is the cross-decoder's memory)."""
     n = _ln(x, w["in_norm"], w["in_norm_b"], config)
     with jax.named_scope("sambay_mamba_mixer"):
-        state = jnp.where(fresh[:, None, None], 0.0, _slots_of(states, src))
-        tail = jnp.where(fresh[:, None, None], jnp.zeros((), tails.dtype), _slots_of(tails, src))
-        u, dt, b, c, z, tail = _scan_inputs(w, n, _tail_rows(tail, config), config)
+        state = jnp.where(fresh[:, None, None], 0.0, slots_of(states, src))
+        tail = jnp.where(fresh[:, None, None], jnp.zeros((), tails.dtype), slots_of(tails, src))
+        u, dt, b, c, z, tail = _scan_inputs(w, n, tail_rows(tail, config), config)
         y, state = selective_scan_step(u, dt, w["A_log"], b, c, w["D"], state)
-        states = _set_slots(states, dst, state)
-        tails = _set_slots(tails, dst, _tail_folded(tail, tails, config))
+        states = set_slots(states, dst, state)
+        tails = set_slots(tails, dst, tail_folded(tail, tails))
         x = _scan_out(w, x, y, z, config)
     return _mlp(w, x, config), states, tails, y
 
@@ -620,9 +567,9 @@ def _wave_sliding(w: Params, x, k_tails, v_tails, src, dst, positions, lambda_in
     with jax.named_scope("sambay_sliding_mixer"):
         k, v = _pair_keys_values(w, n, config)
         at = (jnp.arange(t), positions % win)
-        k_tail = _slots_of(k_tails, src).at[at].set(k.reshape(t, -1))
-        v_tail = _slots_of(v_tails, src).at[at].set(v.reshape(t, -1))
-        k_tails, v_tails = _set_slots(k_tails, dst, k_tail), _set_slots(v_tails, dst, v_tail)
+        k_tail = slots_of(k_tails, src).at[at].set(k.reshape(t, -1))
+        v_tail = slots_of(v_tails, src).at[at].set(v.reshape(t, -1))
+        k_tails, v_tails = set_slots(k_tails, dst, k_tail), set_slots(v_tails, dst, v_tail)
         # Row r holds the newest position <= the row's own with p % window == r.
         held = positions[:, None] - (positions[:, None] - jnp.arange(win)[None]) % win
         shape = (t, win, config.kv_pairs, config.pair_dim)
@@ -660,19 +607,10 @@ def _wave_shared(w: Params, x, k_cache, v_cache, write, row_tables, seq_lens, pa
     jax.jit, static_argnames=("config", "max_blocks"), donate_argnames=("caches",)
 )
 def verify_step_ragged(
-    params: Params,
-    tokens: jax.Array,  # [T] int32: one token a request (a state absorbs a token once)
-    positions: jax.Array,  # [T] int32
-    row_of: jax.Array,  # [T] int32 owning request per flat token
-    pages: jax.Array,  # [P] int32 the wave's flat page list (RaggedWaveMeta)
-    page_rows: jax.Array,  # [P + 1]
-    page_starts: jax.Array,  # [T]
-    caches: Caches,
-    block_tables: jax.Array,  # [B, max_blocks]
-    config: SambaYConfig,
-    max_blocks: int,
+    params: Params, tokens, positions, row_of, pages, page_rows, page_starts, caches: Caches,
+    block_tables, config: SambaYConfig, max_blocks: int,
 ):
-    """THE wave body (``llama.verify_step_ragged``'s contract and argument
+    """THE wave body (``serving.py``: ``wave``'s contract and argument
     order): all the model's layers for every row. ONE table serves every
     kind: a row's flat page list is what layer ``full`` and the cross layers
     walk (the SAME pages, each with its own queries), and by its position the
@@ -682,15 +620,12 @@ def verify_step_ragged(
     ``(logits [T, vocab], caches, aux)``; ``aux["counters"]``:
     ``cross_decoder_rows`` and ``stack_rows``, the wave's real rows (each ran
     the whole stack). ``caches`` is donated."""
-    if block_tables.ndim != 2 or block_tables.shape[1] != max_blocks:
-        raise ValueError(f"block_tables must be [B, {max_blocks}], got {block_tables.shape}")
     bt = config.block_tokens
-    x = _embed(params, tokens)
-    row_tables = jnp.take(block_tables, row_of, axis=0)
-    at = lambda pos: jnp.take_along_axis(row_tables, (pos // bt)[:, None], axis=1)[:, 0]
-    dst = at(positions)
-    src = at(jnp.maximum(positions - 1, 0))
-    fresh = positions == 0
+    x = embed(params, tokens)
+    # The slot is layer ``full``'s alone and is traced where it is used: traced
+    # here it reorders the optimised program (tools/program_fingerprints.py).
+    row_tables, dst, _ = wave_index(positions, row_of, block_tables, max_blocks, bt)
+    src, fresh = wave_sources(positions, row_tables, bt)
     walk = (row_tables, positions + 1, pages, page_rows, page_starts)
 
     mamba_fn = jax.jit(_wave_mamba, static_argnames=("config",))
@@ -700,7 +635,7 @@ def verify_step_ragged(
     new_caches: Caches = []
     memory = shared = None
     for layer, kind in enumerate(config.layer_kinds):
-        w = _layer_weights(params, layer)
+        w = layer_weights(params, layer)
         l0 = jnp.float32(config.lambda_init(layer))
         if kind == MAMBA:
             x, *cache, memory = mamba_fn(w, x, *caches[layer], src, dst, fresh, config=config)
@@ -717,9 +652,5 @@ def verify_step_ragged(
             continue
         new_caches.append(tuple(cache))
     logits = _head(params, x, config)
-    real = jnp.concatenate([
-        jnp.ones((1,), bool),
-        (positions[1:] != positions[:-1]) | (row_of[1:] != row_of[:-1]),
-    ])
-    rows = jnp.sum(real, dtype=jnp.int32)
+    rows = jnp.sum(real_rows(positions, row_of), dtype=jnp.int32)
     return logits, new_caches, {"counters": {"cross_decoder_rows": rows, "stack_rows": rows}}

@@ -5,11 +5,14 @@
 the harness runs what it finds there:
 
 ``prefill(params, tokens, caches, block_table, config) -> (logits, caches)``
-    a miss: the whole prompt, its K/V written to the blocks of the table.
+    a miss: the whole prompt (``tokens`` ``[S]`` int32), its K/V written to the
+    blocks of the table (``[ceil(S / block_tokens)]`` int32).
 ``resume(params, tokens, start_pos, caches, block_table, config, max_blocks)
 -> (logits, caches)``
-    a prefix hit's question: one request's chunk at contiguous positions
-    against the pages already in the cache.
+    a prefix hit's question: one request's chunk (``tokens`` ``[S_c]`` int32)
+    at contiguous positions from ``start_pos`` (``[]`` int32) against the
+    pages already in the cache; ``block_table`` is ``[max_blocks]`` int32,
+    padded with any valid id.
 
     **A prompt step may hand back no logits.** The engine reads the logits of
     neither ``prefill`` nor ``resume`` (a first token comes from the first
@@ -26,7 +29,12 @@ the harness runs what it finds there:
 caches, block_tables, config, max_blocks[, window_pages=]) -> (logits,
 caches[, aux])``
     a decode wave's BODY: flat rows of many requests, each over its own
-    pages. Where the cache's spec names a sliding window
+    pages. ``tokens``, ``positions`` (absolute) and ``row_of`` (the owning
+    request, sorted) are ``[T]`` int32, the wave's chunks concatenated;
+    ``pages`` ``[P]``, ``page_rows`` ``[P + 1]`` and ``page_starts`` ``[T]``
+    the flat page list the attention walks (``tpu/paged_attention.py``
+    ``RaggedWaveMeta``); ``block_tables`` ``[B, max_blocks]``, its rows padded
+    and the rows past the real requests referenced by no flat token. Where the cache's spec names a sliding window
     (``PagedKVCacheSpec.window``) it takes a second ``(pages, page_rows,
     page_starts)`` triple, the wave's windowed page list, as
     ``window_pages``. A body may return a third value, ``aux``: ``{"rows":
@@ -63,6 +71,28 @@ layout) -> (logits, caches, ids, feed, aux)``
     row is handed :func:`no_feed`.
 
 Every step DONATES ``caches``: the caller uses the returned ones.
+
+**What the model files' steps share is written here, once.** A model file
+keeps its layers and the loop over them; around the loop:
+
+:func:`resume_step`
+    a file's ``prefill_continue`` (the ``resume`` of its ``ServingSteps``)
+    from its jitted ``resume_chunk``: the table is as long as the harness
+    said, and the chunk program runs.
+:func:`prefill_by_blocks`
+    a file's ``prefill`` where its chunk lies inside ONE block (a recurrent
+    state, or ``resume_in_block``): the prompt cut at block boundaries
+    through ``resume_chunk``, the very programs a hit's resume runs.
+:func:`chunk_index`
+    such a chunk's blocks: the one it lies in, the one before it, whether it
+    starts a prompt.
+:func:`wave_index`, :func:`wave_sources`, :func:`real_rows`
+    a wave's rows by their tables: each row's table, the block and slot its
+    token is written to; for a row with a state the block the state comes
+    from; the rows that are no bucket padding.
+:class:`ExpertTally`
+    what a routed model's wave hands back beside its logits: the ids every
+    row chose at every expert layer and the step's ``moe_*`` counters.
 """
 
 import functools
@@ -72,6 +102,8 @@ from typing import Callable, Dict, List, NamedTuple, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from ..tpu.moe import EXPERT_COUNTERS, expert_counts
 
 
 # The rows of a wave whose sampled ids the NEXT wave's program can read on the
@@ -107,6 +139,122 @@ class ServingSteps(NamedTuple):
     # token in the first wave: what it does for a cache with a recurrent
     # state, whose models' chunks lie in one block for the state's sake.
     resume_in_block: bool = False
+
+
+def resume_step(resume_chunk: Callable) -> Callable:
+    """A model file's ``prefill_continue`` over its jitted ``resume_chunk``:
+    ``resume``'s signature (module docstring), the table held to the static
+    ``max_blocks`` the harness pads every table to."""
+
+    def prefill_continue(params, tokens, start_pos, caches, block_table, config, max_blocks):
+        if block_table.shape[0] != max_blocks:
+            raise ValueError(
+                f"block_table has {block_table.shape[0]} entries, expected "
+                f"max_blocks={max_blocks} (pad the table to the static bound)"
+            )
+        return resume_chunk(params, tokens, start_pos, caches, block_table, config)
+
+    return prefill_continue
+
+
+def prefill_by_blocks(resume_chunk: Callable) -> Callable:
+    """A model file's ``prefill`` where a chunk lies inside one block: a miss,
+    every token given, cut at block boundaries through the chunk program a
+    hit's resume runs, so that each block's slot holds what stands at its end
+    (a state, a tail) and a full hit's first token equals the miss's to the
+    bit. ``block_table`` covers the tokens (a last block may be part full).
+    Returns (the last row's logits, or None where the chunk has none,
+    caches); ``caches`` is donated."""
+
+    def prefill(params, tokens, caches, block_table, config):
+        bt = config.block_tokens
+        tokens = jnp.asarray(tokens, jnp.int32)
+        table = jnp.asarray(block_table, jnp.int32)
+        logits = None
+        for start in range(0, tokens.shape[0], bt):
+            logits, caches = resume_chunk(
+                params, tokens[start : start + bt], jnp.int32(start), caches, table, config
+            )
+        return None if logits is None else logits[-1], caches
+
+    return prefill
+
+
+def chunk_index(tokens, start_pos, block_table, block_tokens: int):
+    """Of a chunk at positions ``start_pos ..`` that lies inside ONE block:
+    (the block it lies in, the block of position ``start_pos - 1``, whose end
+    state it starts from, whether it starts a prompt and there is none)."""
+    if tokens.shape[0] > block_tokens:
+        raise ValueError(
+            f"a chunk of {tokens.shape[0]} tokens does not lie in one {block_tokens}-token block"
+        )
+    block = block_table[start_pos // block_tokens]
+    before = block_table[jnp.maximum(start_pos - 1, 0) // block_tokens]
+    return block, before, start_pos == 0
+
+
+def _block_of(row_tables, blocks):
+    """[T]: entry ``blocks[t]`` of row t's table."""
+    return jnp.take_along_axis(row_tables, blocks[:, None], axis=1)[:, 0]
+
+
+def wave_index(positions, row_of, block_tables, max_blocks: int, block_tokens: int):
+    """Of a wave's T flat rows: (``row_tables`` [T, max_blocks] each row's own
+    table, ``dst`` [T] the block its token's K/V or state is written to,
+    ``slots`` [T] the slot within it). A row's length with its token is
+    ``positions + 1``, written where a file's layers take it: XLA's schedule of
+    this arithmetic follows how often it was traced
+    (``tools/program_fingerprints.py``; CHANGES.md, PR 60)."""
+    if block_tables.ndim != 2 or block_tables.shape[1] != max_blocks:
+        raise ValueError(f"block_tables must be [B, {max_blocks}], got {block_tables.shape}")
+    row_tables = jnp.take(block_tables, row_of, axis=0)
+    return row_tables, _block_of(row_tables, positions // block_tokens), positions % block_tokens
+
+
+def wave_sources(positions, row_tables, block_tokens: int):
+    """Of rows that carry a state: (``src`` [T] the block each row's state
+    comes from, position p - 1's, ``fresh`` [T] whether the row starts a
+    prompt and has none). ``src`` differs from :func:`wave_index`'s ``dst``
+    where the row crosses into a new block: the running state moves on and the
+    block behind keeps its end state."""
+    return _block_of(row_tables, jnp.maximum(positions - 1, 0) // block_tokens), positions == 0
+
+
+def real_rows(positions, row_of):
+    """[T] bool: a wave's rows that are no padding. The bucket's tail repeats
+    its last row, so a row that equals its predecessor is padding."""
+    real = jnp.concatenate([
+        jnp.ones((1,), bool),
+        (positions[1:] != positions[:-1]) | (row_of[1:] != row_of[:-1]),
+    ])
+    return real
+
+
+class ExpertTally:
+    """What a routed model's wave step gathers over its layers: ``add`` a
+    layer's chosen ids and its ``expert_counts`` (a dense layer's are None and
+    add nothing), then ``aux`` is the wave's third value. A model file adds its
+    own counters to what ``aux`` returns, and names ``counters`` first in its
+    configuration's ``step_counters``."""
+
+    counters = ("moe_pairs", *EXPERT_COUNTERS)
+
+    def __init__(self):
+        self.chosen, self.counts = [], expert_counts()
+
+    def add(self, ids, counts):
+        if ids is not None:
+            self.chosen.append(ids)
+            self.counts = jax.tree.map(jnp.add, self.counts, counts)
+
+    def aux(self, real, experts_per_token: int):
+        """``{"rows": [T, sites, k]`` the experts every row chose at every
+        expert layer IN THIS STEP, ``"counters"``: ``moe_pairs``, the (row,
+        expert) pairs of the wave's ``real`` rows over its expert layers, and
+        the layers' ``expert_counts`` summed``}``."""
+        rows = jnp.stack(self.chosen, axis=1)
+        pairs = jnp.sum(real, dtype=jnp.int32) * (len(self.chosen) * experts_per_token)
+        return {"rows": rows, "counters": {"moe_pairs": pairs, **self.counts}}
 
 
 class WaveLayout(NamedTuple):

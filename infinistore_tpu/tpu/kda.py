@@ -114,10 +114,23 @@ def short_conv(x, tail, weight):
     """The causal depth-wise convolution over the last ``taps`` positions.
     x: [S, C] the rows before it; tail: [taps - 1, C] the rows that came
     before x (zeros at a prompt's start); weight: [taps, C]. Returns
-    (y [S, C] float32, the new tail: the last ``taps - 1`` rows of the two)."""
+    (y [S, C] float32, the new tail: the last ``taps - 1`` rows of the two).
+    A ``tail`` of ``[T, taps - 1, C]`` is a wave's: one position a row, each a
+    request with a tail of its own (:func:`short_conv_rows`)."""
+    if tail.ndim == 3:
+        return short_conv_rows(x, tail, weight)
     taps = weight.shape[0]
     s = x.shape[0]
     rows = jnp.concatenate([tail.astype(x.dtype), x], axis=0)  # [taps - 1 + S, C]
     w = weight.astype(jnp.float32)
     y = sum(rows[i : i + s].astype(jnp.float32) * w[i] for i in range(taps))
     return y, rows[s:]
+
+
+def short_conv_rows(x, tails, weight):
+    """:func:`short_conv` of a wave: one position a row, each a request with a
+    tail of its own. x: [T, C]; tails: [T, taps - 1, C]; weight: [taps, C].
+    Returns (y [T, C] float32, the new tails [T, taps - 1, C])."""
+    rows = jnp.concatenate([tails.astype(x.dtype), x[:, None]], axis=1)
+    y = jnp.sum(rows.astype(jnp.float32) * weight.astype(jnp.float32)[None], axis=1)
+    return y, rows[:, 1:]

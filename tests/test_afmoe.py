@@ -35,6 +35,7 @@ from infinistore_tpu.models import init_params as llama_init
 from infinistore_tpu.models.afmoe import FULL, SLIDING, AfmoeConfig
 from infinistore_tpu.tpu import chunk_attention as ca
 from infinistore_tpu.tpu import flash_prefill as fp
+from infinistore_tpu.tpu import moe
 from infinistore_tpu.tpu import paged_attention as pa
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -216,7 +217,7 @@ def _layer(params, layer=1):
 def _family(name, params):
     """(the family's small configuration class and its keywords, one expert
     layer's weights, the published route scale): both families run the ONE
-    expert layer, ``afmoe.expert_layer``."""
+    expert layer, ``moe.expert_layer``."""
     if name == "afmoe":
         return AfmoeConfig, {"dtype": jnp.float32}, _layer(params), 2.826
     from infinistore_tpu.models import kimi_linear
@@ -240,11 +241,11 @@ def test_four_shares_of_two_experts_add_up_to_the_uncut_layer(params, rows, fami
     config_class, kw, w, route_scale = _family(family, params)
     cfg = config_class(**kw)
     m = jax.random.normal(jax.random.key(rows), (rows, cfg.dim), jnp.float32)
-    whole, ids, _ = afmoe.expert_layer(w, m, cfg)
+    whole, ids, _ = moe.expert_layer(w, m, cfg)
     total = jnp.zeros_like(whole)
     for first in range(0, 8, 2):
         share = dict(w, **{k: w[k][first : first + 2] for k in ("w_gate", "w_up", "w_down_moe")})
-        part, share_ids, _ = afmoe.expert_layer(
+        part, share_ids, _ = moe.expert_layer(
             share, m, config_class(experts_held=(first, 2), **kw)
         )
         np.testing.assert_array_equal(share_ids, ids)  # every share routes over all
@@ -292,13 +293,13 @@ def test_the_waves_slots_are_the_distinct_held_choices(share):
     w.update({k: w[k][first : first + count] for k in ("w_gate", "w_up", "w_down_moe")})
     rows, k = 4, 2
     m = jax.random.normal(jax.random.key(4), (rows, cfg.dim), jnp.float32)
-    ids, weights = afmoe.route(m, w["router"], w["router_bias"], cfg)
+    ids, weights = moe.route(m, w["router"], w["router_bias"], cfg)
     chosen = np.asarray(ids)
     assert not set(chosen.reshape(-1).tolist()) & set(barred)
     want = sorted({e - first for e in chosen.reshape(-1).tolist() if first <= e < first + count})
     assert (share == "none-chosen") == (not want) and (share != "a-sixteenth" or want == [0])
 
-    slots, n, combine, distinct = afmoe._wave_slots(ids, weights, cfg)
+    slots, n, combine, distinct = moe._wave_slots(ids, weights, cfg)
     assert slots.shape == (min(rows * k, count),) and combine.shape == (slots.shape[0], rows)
     assert n.shape == (1,) and int(n[0]) == len(want)
     assert np.asarray(slots)[: len(want)].tolist() == want
@@ -309,7 +310,7 @@ def test_the_waves_slots_are_the_distinct_held_choices(share):
         by_hand[s] = np.where(chosen == first + e, np.asarray(weights), 0.0).sum(-1)
     np.testing.assert_array_equal(combine, by_hand)  # zero past the real slots
 
-    got, got_ids, counts = afmoe.expert_layer(w, m, cfg)
+    got, got_ids, counts = moe.expert_layer(w, m, cfg)
     np.testing.assert_array_equal(got_ids, ids)
     assert {name: int(v) for name, v in counts.items()} == {
         "moe_distinct_experts": int(distinct), "moe_streamed_experts": len(want),
@@ -337,7 +338,7 @@ def test_a_step_past_the_real_slots_names_the_block_before_it(n, tiles):
     pipeline copies that many tiles, not ``S * tiles``."""
     ids = jnp.asarray([3, 0, 7, 2, 5], jnp.int32)
     walk = [
-        tuple(int(v) for v in afmoe._wave_block(s, j, ids, jnp.asarray([n], jnp.int32), tiles))
+        tuple(int(v) for v in moe._wave_block(s, j, ids, jnp.asarray([n], jnp.int32), tiles))
         for s in range(ids.shape[0]) for j in range(tiles)
     ]
     real = [(s, int(ids[s]), j) for s in range(n) for j in range(tiles)]
@@ -353,18 +354,18 @@ def test_the_wave_kernel_streams_the_distinct_experts(n, width):
     """``_moe_wave_pallas`` (interpret mode) against the gathered XLA form,
     with ``n`` of six slots real (the combine weights past them are zero, and
     whatever ids stand there are never read)."""
-    assert width // afmoe._wave_f_tile(width) == {256: 1, 1024: 2, 2048: 4}[width]
+    assert width // moe._wave_f_tile(width) == {256: 1, 1024: 2, 2048: 4}[width]
     rng = np.random.default_rng(354)
     f = lambda *s: jnp.asarray(rng.standard_normal(s) / 8, jnp.float32)
     e, d, t = 8, 128, 16
     x, wg, wu, wd = f(t, d), f(e, d, width), f(e, d, width), f(e, width, d)
     slots = jnp.asarray([1, 4, 6, 7, 7, 7], jnp.int32)
     combine = jnp.asarray(rng.random((6, t)), jnp.float32).at[n:].set(0.0)
-    got = afmoe._moe_wave_pallas(
+    got = moe._moe_wave_pallas(
         x, slots, jnp.asarray([n], jnp.int32),
         jnp.broadcast_to(combine[:, :, None], (6, t, 128)), wg, wu, wd, interpret=True,
     )
-    want = afmoe.moe_wave_xla(x, slots, combine, wg, wu, wd)
+    want = moe.moe_wave_xla(x, slots, combine, wg, wu, wd)
     np.testing.assert_allclose(got, want, atol=1e-4, rtol=1e-4)
     assert n or not np.asarray(got).any()
 
@@ -395,7 +396,7 @@ def test_the_grouped_products_tiles_are_a_function_of_its_widths(case):
     it); K whole; the N tile a whole divisor of its width (a multiple of 128
     lanes) that keeps the weight tile within 2,304 x 1,024 elements."""
     _, _pairs, _rows_a_group, dim, width, up, down = case
-    assert afmoe._gmm_tiling(dim, width) == up and afmoe._gmm_tiling(width, dim) == down
+    assert moe._gmm_tiling(dim, width) == up and moe._gmm_tiling(width, dim) == down
     for (tm, tk, tn), (k, n) in ((up, (dim, width)), (down, (width, dim))):
         assert tm == 128 and tk == k and n % tn == 0 and tn % 128 == 0
         assert tk * tn <= 2304 * 1024 and (tn == n or tk * tn * 2 > 2304 * 1024)
@@ -406,11 +407,11 @@ def test_the_tile_rule_keeps_a_small_width_whole_and_cuts_an_odd_one_at_1024():
     divides stays whole up to 9,216 and beyond it is cut in 1,024s, the last
     tile ragged where they do not divide it, as before the rule; 2,304 is
     cut in whole halves or thirds, never at 1,024."""
-    assert afmoe._gmm_tiling(64, 32) == (128, 64, 32)
-    assert afmoe._gmm_tiling(1100, 2304) == (128, 1100, 1152)
-    assert afmoe._gmm_tiling(9216, 2048) == (128, 9216, 256)
-    assert afmoe._gmm_tiling(18432, 1024) == afmoe._gmm_tiling(20000, 1024) == (128, 1024, 1024)
-    assert afmoe._lane_tile(2304, 1024) == 768 and afmoe._lane_tile(2304, 2303) == 1152
+    assert moe._gmm_tiling(64, 32) == (128, 64, 32)
+    assert moe._gmm_tiling(1100, 2304) == (128, 1100, 1152)
+    assert moe._gmm_tiling(9216, 2048) == (128, 9216, 256)
+    assert moe._gmm_tiling(18432, 1024) == moe._gmm_tiling(20000, 1024) == (128, 1024, 1024)
+    assert moe._lane_tile(2304, 1024) == 768 and moe._lane_tile(2304, 2303) == 1152
 
 
 @pytest.mark.parametrize(
@@ -430,7 +431,7 @@ def test_gmm_under_the_rules_tiles_against_ragged_dot(sizes, k, n):
 
     rng = np.random.default_rng(51)
     real = sum(sizes)
-    tiling = afmoe._gmm_tiling(k, n)
+    tiling = moe._gmm_tiling(k, n)
     m = real + -real % tiling[0] + tiling[0]  # a whole tile of nobody's rows
     assert tiling[1] == k and n % tiling[2] == 0
     f = lambda *s: jnp.asarray(rng.standard_normal(s) / 8, jnp.float32).astype(jnp.bfloat16)
@@ -452,8 +453,8 @@ def test_the_grouped_ffn_pads_its_pairs_to_the_row_tile(monkeypatch, tokens):
 
     w = _layer(afmoe.init_params(CFG, jax.random.key(7)))
     m = jax.random.normal(jax.random.key(tokens), (tokens, CFG.dim), jnp.float32)
-    ids, weights = afmoe.route(m, w["router"], w.get("router_bias"), CFG)
-    want = afmoe._grouped_ffn(m, ids, weights, w, CFG)
+    ids, weights = moe.route(m, w["router"], w.get("router_bias"), CFG)
+    want = moe._grouped_ffn(m, ids, weights, w, CFG)
     seen, gmm = [], megablox.gmm
 
     def interpreted(lhs, rhs, group_sizes, **kw):
@@ -462,7 +463,7 @@ def test_the_grouped_ffn_pads_its_pairs_to_the_row_tile(monkeypatch, tokens):
 
     monkeypatch.setattr(megablox, "gmm", interpreted)
     monkeypatch.setattr(paged, "_use_pallas", lambda: True)
-    got = afmoe._grouped_ffn(m, ids, weights, w, CFG)
+    got = moe._grouped_ffn(m, ids, weights, w, CFG)
     assert len(seen) == 3 and all(t[0] == 128 and rows % 128 == 0 for rows, t in seen), seen
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=0)
 
@@ -621,6 +622,53 @@ def test_engine_names_no_model_file():
     imports = re.findall(r"^\s*(?:from|import)\s+(\S+)", source, flags=re.M)
     # The contract itself (the roles, the packed wave entry) is no model file.
     assert [m for m in imports if "models" in m] == [".models.serving"], imports
+
+
+def _package_imports(module: str):
+    """What ``infinistore_tpu/models/<module>.py`` imports from this package,
+    as written: ``.serving``, ``..tpu.paged``, ``..tpu`` (of ``from ..tpu
+    import kda``), ..."""
+    import ast
+
+    with open(os.path.join(REPO, "infinistore_tpu", "models", f"{module}.py")) as f:
+        tree = ast.parse(f.read())
+    found = []
+    for node in ast.walk(tree):
+        if isinstance(node, ast.ImportFrom) and node.level:
+            found.append("." * node.level + (node.module or ""))
+        elif isinstance(node, (ast.Import, ast.ImportFrom)):
+            names = [node.module] if isinstance(node, ast.ImportFrom) else [a.name for a in node.names]
+            found += [n for n in names if n.split(".")[0] == "infinistore_tpu"]
+    return found
+
+
+# The eight model files that serve a cell. ``long_context``, ``pipeline``,
+# ``ring_attention`` and ``ulysses`` are not in the list: they serve nothing and
+# go with ROADMAP D13.
+MODEL_FILES = [
+    "llama", "afmoe", "kimi_linear", "falcon_h1", "granite_hybrid", "mellum", "glm_dsa", "sambay",
+]
+
+
+@pytest.mark.parametrize("model", MODEL_FILES)
+def test_a_model_file_imports_no_other_model_file(model):
+    """The arrows point one way: ``tpu/*`` <- ``models/layers.py`` <-
+    ``models/serving.py`` <- a model file <- nothing under ``models/``."""
+    imports = _package_imports(model)
+    assert imports, model
+    allowed = lambda m: m in (".serving", ".layers", "..tpu") or m.startswith("..tpu.")
+    assert [m for m in imports if not allowed(m)] == [], imports
+
+
+def test_the_shared_math_imports_no_models_module():
+    imports = _package_imports("layers")
+    assert imports and all(m == "..tpu" or m.startswith("..tpu.") for m in imports), imports
+
+
+def test_the_contract_imports_no_model_file():
+    imports = _package_imports("serving")
+    assert [m for m in imports if m.startswith(".") and not m.startswith("..tpu")] == [], imports
+    assert not any(m.lstrip(".").split(".")[-1] in MODEL_FILES for m in imports), imports
 
 
 @pytest.mark.parametrize("model", ["llama", "afmoe"])

@@ -34,10 +34,10 @@ import pytest
 import infinistore_tpu as its
 from infinistore_tpu.connector import KVConnector
 from infinistore_tpu.engine import ContinuousBatchingHarness, EngineKVAdapter
-from infinistore_tpu.models import afmoe
+from infinistore_tpu.models import layers
 from infinistore_tpu.models import glm_dsa as gd
 from infinistore_tpu.models.glm_dsa import GlmDsaConfig
-from infinistore_tpu.tpu import dsa, mla
+from infinistore_tpu.tpu import dsa, mla, moe
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "benchmarks"))
@@ -483,9 +483,9 @@ def test_the_expert_layers_shares_add_up_to_the_uncut_layer(shares):
     shared expert counted once (by the share that holds expert 0), add up to
     what the REFERENCE gives for the whole layer held by one."""
     whole = dataclasses.replace(CFG, experts_held=None)
-    w = afmoe._layer_weights(gd.init_params(whole, jax.random.key(570)), 1)
+    w = layers.layer_weights(gd.init_params(whole, jax.random.key(570)), 1)
     h = 3.0 * jax.random.normal(jax.random.key(571), (24, CFG.dim), jnp.float32)
-    m = gd._rms(h, w["pre_mlp_norm"], CFG.rms_eps)
+    m = layers.rms(h, w["pre_mlp_norm"], CFG.rms_eps)
     span = CFG.n_experts // shares
     total = jnp.zeros_like(h)
     for first in range(0, CFG.n_experts, span):
@@ -493,7 +493,7 @@ def test_the_expert_layers_shares_add_up_to_the_uncut_layer(shares):
         held = dict(w, **{
             name: w[name][first : first + span] for name in ("w_gate", "w_up", "w_down_moe")
         })
-        total = total + afmoe.expert_layer(held, m, part)[0]
+        total = total + moe.expert_layer(held, m, part)[0]
     with jax.default_matmul_precision("highest"):
         ref, _ = reference_kimi_linear._expert_half(
             {k: w[k] for k in reference_kimi_linear.EXPERT}, h, jnp.zeros((0, 2), jnp.int32),

@@ -47,10 +47,10 @@ import pytest
 import infinistore_tpu as its
 from infinistore_tpu.connector import KVConnector
 from infinistore_tpu.engine import ContinuousBatchingHarness, EngineKVAdapter
-from infinistore_tpu.models import afmoe
+from infinistore_tpu.models import afmoe, layers
 from infinistore_tpu.models import granite_hybrid as gh
 from infinistore_tpu.models.kimi_linear import KimiLinearConfig
-from infinistore_tpu.tpu import chunk_attention, layerwise, paged_attention, ssd
+from infinistore_tpu.tpu import chunk_attention, layerwise, moe, paged_attention, ssd
 from infinistore_tpu.tpu.staging import StagedTransfer
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -159,7 +159,7 @@ def test_the_ssd_walk_at_one_group_and_chunk_256_is_the_recurrence(cuts):
 def test_the_router_is_a_top_k_over_the_logits_and_a_softmax_over_the_chosen(rows, params):
     m = jax.random.normal(jax.random.key(rows), (rows, CFG.dim), jnp.float32)
     router = params["l0.router"]
-    ids, weights = afmoe.route(m, router, None, CFG)
+    ids, weights = moe.route(m, router, None, CFG)
     logits = np.asarray(m, np.float64) @ np.asarray(router, np.float64)
     order = np.argsort(-logits, axis=1)[:, :K]
     np.testing.assert_array_equal(np.sort(np.asarray(ids), axis=1), np.sort(order, axis=1))
@@ -193,7 +193,7 @@ def test_the_accepted_routers_program_is_the_parents(config):
         jnp.zeros((5, config.dim), jnp.bfloat16), jnp.zeros((config.dim, config.n_experts), jnp.bfloat16),
         jnp.zeros((config.n_experts,), jnp.float32),
     )
-    now = jax.make_jaxpr(lambda m, r, b: afmoe.route(m, r, b, config))(*args)
+    now = jax.make_jaxpr(lambda m, r, b: moe.route(m, r, b, config))(*args)
     assert config.router == "sigmoid" and str(now) == str(jax.make_jaxpr(parents)(*args))
 
 
@@ -204,15 +204,15 @@ def test_the_two_shares_add_up_to_the_uncut_references_layer(rows, params):
     expert riding with the share that holds expert 0: their sum is the uncut
     layer, as the reference writes it out."""
     h = jax.random.normal(jax.random.key(10 + rows), (rows, CFG.dim), jnp.float32)
-    w = afmoe._layer_weights(params, 1)
-    m = afmoe._rms(h, w["pre_mlp_norm"], CFG.rms_eps)
+    w = layers.layer_weights(params, 1)
+    m = layers.rms(h, w["pre_mlp_norm"], CFG.rms_eps)
     total = jnp.zeros_like(h)
     for first in (0, 4):
         share = gh.GraniteHybridConfig(
             dtype=jnp.float32, layer_types=KINDS, experts_held=(first, 4), **MULTIPLIERS
         )
         held = dict(w, **{name: w[name][first : first + 4] for name in ("w_gate", "w_up", "w_down_moe")})
-        out, ids, _ = afmoe.expert_layer(held, m, share)
+        out, ids, _ = moe.expert_layer(held, m, share)
         total = total + out
     none = jnp.full((rows, K), -1, jnp.int32)
     with jax.default_matmul_precision("highest"):
@@ -232,7 +232,7 @@ def test_the_waves_expert_kernel_takes_a_width_of_768_whole(f, n):
     width as one tile (the accepted widths keep theirs), and its result is the
     XLA twin's; so is it at two tiles, with ``n`` of its six slots real (the
     held experts the rows chose: four of them, and none)."""
-    assert [afmoe._wave_f_tile(f) for f in (32, 512, 768, 1024)] == [32, 512, 768, 512]
+    assert [moe._wave_f_tile(f) for f in (32, 512, 768, 1024)] == [32, 512, 768, 512]
     keys = jax.random.split(jax.random.key(7), 5)
     e, d, tp = 6, 128, 16
     x = jax.random.normal(keys[0], (tp, d), jnp.float32)
@@ -240,11 +240,11 @@ def test_the_waves_expert_kernel_takes_a_width_of_768_whole(f, n):
     wd = jax.random.normal(keys[3], (e, f, d), jnp.float32) / np.sqrt(f)
     slots = jnp.asarray([0, 2, 3, 5, 5, 5], jnp.int32)
     combine = jax.random.uniform(keys[4], (6, tp), jnp.float32).at[n:].set(0.0)
-    got = afmoe._moe_wave_pallas(
+    got = moe._moe_wave_pallas(
         x, slots, jnp.asarray([n], jnp.int32), jnp.broadcast_to(combine[:, :, None], (6, tp, 128)),
         wg, wu, wd, interpret=True,
     )
-    want = afmoe.moe_wave_xla(x, slots, combine, wg, wu, wd)
+    want = moe.moe_wave_xla(x, slots, combine, wg, wu, wd)
     np.testing.assert_allclose(got, want, atol=1e-4 * max(float(jnp.max(jnp.abs(want))), 1.0), rtol=0)
     assert n or not np.asarray(got).any()
 
@@ -383,7 +383,7 @@ def test_a_bf16_state_or_a_bf16_router_fails_the_comparison(conn, params, monkey
         monkeypatch.setattr(gh.GraniteHybridConfig, "layer_cache", in_bf16)
     else:
         monkeypatch.setattr(
-            afmoe, "_router_logits",
+            moe, "_router_logits",
             lambda m, router: jnp.dot(m.astype(jnp.bfloat16), router.astype(jnp.bfloat16)).astype(jnp.float32),
         )
         jax.clear_caches()

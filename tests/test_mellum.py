@@ -16,7 +16,7 @@ live), seeded float32 weights.
 - YaRN's table against a direct transcription of the configuration file's
   equations; ``softmax_topk`` against softmax-over-all, top-k, renormalise (and
   a router in bf16 against the same: outside the limit);
-- ``afmoe.expert_layer`` with no shared expert and no dense layer against a
+- ``moe.expert_layer`` with no shared expert and no dense layer against a
   loop over the experts, in both its shapes, and the wave kernel (interpret
   mode) at a width that is whole tiles and at one that is not;
 - the configuration's file: the published keys, ``reduced``, the cache's
@@ -41,9 +41,9 @@ import pytest
 import infinistore_tpu as its
 from infinistore_tpu.connector import KVConnector
 from infinistore_tpu.engine import ContinuousBatchingHarness, EngineKVAdapter
-from infinistore_tpu.models import afmoe, mellum
+from infinistore_tpu.models import layers, mellum
 from infinistore_tpu.models.mellum import FULL, SLIDING, MellumConfig
-from infinistore_tpu.tpu import layerwise
+from infinistore_tpu.tpu import layerwise, moe
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "benchmarks"))
@@ -341,7 +341,7 @@ def test_softmax_topk_is_softmax_over_all_topk_renormalised(experts, k):
     rng = np.random.default_rng(experts)
     m = jnp.asarray(rng.standard_normal((40, cfg.dim)), jnp.float32)
     router = jnp.asarray(rng.standard_normal((cfg.dim, experts)) / 8, jnp.float32)
-    ids, weights = afmoe.route(m, router, None, cfg)
+    ids, weights = moe.route(m, router, None, cfg)
     logits = np.asarray(m, np.float64) @ np.asarray(router, np.float64)
     want_ids, want = published_route(logits, k)
     np.testing.assert_array_equal(np.sort(np.asarray(ids), -1), np.sort(want_ids, -1))
@@ -372,10 +372,10 @@ def experts_by_loop(w, m, k):
 
 @pytest.mark.parametrize("rows", [3, 40], ids=["few-rows", "many-tokens"])
 def test_expert_layer_without_shared_or_dense_against_a_loop_over_experts(params, rows):
-    w = afmoe._layer_weights(params, 1)
+    w = layers.layer_weights(params, 1)
     assert "ws_gate_up" not in w and "router_bias" not in w and "w_gate_up" not in w
     m = jax.random.normal(jax.random.key(rows), (rows, CFG.dim), jnp.float32)
-    got, ids, counts = afmoe.expert_layer(w, m, CFG)
+    got, ids, counts = moe.expert_layer(w, m, CFG)
     want_ids, want = experts_by_loop(w, m, CFG.experts_per_token)
     np.testing.assert_array_equal(np.sort(np.asarray(ids), -1), np.sort(want_ids, -1))
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=0)
@@ -393,19 +393,19 @@ def test_the_wave_kernel_at_a_width_of_whole_tiles_and_at_one_that_is_not(width,
     640 = 5 x 128 is no multiple of the kernel's 512-wide tile and is taken
     whole, as the configuration's 896 = 7 x 128 is; 1,024 is two tiles. With
     ``n`` of the six slots real: four and two padded, and none."""
-    assert afmoe._wave_f_tile(width) == (640 if width == 640 else 512)
-    assert afmoe._wave_f_tile(REAL["moe_intermediate_size"]) == 896
+    assert moe._wave_f_tile(width) == (640 if width == 640 else 512)
+    assert moe._wave_f_tile(REAL["moe_intermediate_size"]) == 896
     rng = np.random.default_rng(width)
     f = lambda *s: jnp.asarray(rng.standard_normal(s) / 8, jnp.float32)
     e, d, t = 8, 128, 16
     x, wg, wu, wd = f(t, d), f(e, d, width), f(e, d, width), f(e, width, d)
     slots = jnp.asarray([0, 3, 5, 7, 7, 7], jnp.int32)
     combine = jnp.asarray(rng.random((6, t)), jnp.float32).at[n:].set(0.0)
-    got = afmoe._moe_wave_pallas(
+    got = moe._moe_wave_pallas(
         x, slots, jnp.asarray([n], jnp.int32),
         jnp.broadcast_to(combine[:, :, None], (6, t, 128)), wg, wu, wd, interpret=True,
     )
-    want = afmoe.moe_wave_xla(x, slots, combine, wg, wu, wd)
+    want = moe.moe_wave_xla(x, slots, combine, wg, wu, wd)
     np.testing.assert_allclose(got, want, atol=1e-4, rtol=1e-4)
 
 

@@ -943,7 +943,7 @@ def test_grouped_ffn_compiles_under_its_tile_rule(v5e, monkeypatch, case):
 
     from jax.experimental.pallas.ops.tpu import megablox
 
-    from infinistore_tpu.models import afmoe
+    from infinistore_tpu.tpu import moe
 
     name, _, tokens = case
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -970,7 +970,7 @@ def test_grouped_ffn_compiles_under_its_tile_rule(v5e, monkeypatch, case):
         "w_down_moe": s((held, cfg.moe_ffn_dim, cfg.dim), jnp.bfloat16),
     }
     exe = _compile(
-        jax.jit(afmoe._grouped_ffn, static_argnames=("config",)),
+        jax.jit(moe._grouped_ffn, static_argnames=("config",)),
         s((tokens, cfg.dim), jnp.bfloat16), s((tokens, k), jnp.int32),
         s((tokens, k), jnp.float32), w, config=cfg,
     )

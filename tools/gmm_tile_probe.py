@@ -1,6 +1,6 @@
 """What the grouped expert product costs by the tiles it is handed.
 
-``models/afmoe.py`` ``_grouped_ffn`` multiplies a chunk's (token, expert)
+``tpu/moe.py`` ``_grouped_ffn`` multiplies a chunk's (token, expert)
 pairs, sorted by expert, into the held experts' weights with the Pallas
 grouped matmul (megablox ``gmm``). Its grid visits every non-empty group once
 a row tile the group touches, each visit one whole ``tm x tk x tn`` pass a K
@@ -15,13 +15,13 @@ benchmark's traffic:
 - a hit's question (``--question`` tokens through ``resume_chunk``);
 - a miss's piece: one block where the cache keeps a state a block (the
   engine then computes a miss block by block), else the chunks
-  ``afmoe._chunks`` cuts ``--documents`` + question tokens into.
+  ``moe._chunks`` cuts ``--documents`` + question tokens into.
 
 The group sizes are a seeded uniform draw of the pairs over the ROUTER's
 width, the held experts' share kept (rows past their sum are nobody's), as
 ``_grouped_ffn`` hands them over. The triples: row tiles ``--row-tiles`` x
-the K and N tiles of the rule (``afmoe._gmm_tiling``), of the parent of PR 51
-(``min(width, 1024)``) and of ``afmoe._lane_tile`` under each of
+the K and N tiles of the rule (``moe._gmm_tiling``), of the parent of PR 51
+(``min(width, 1024)``) and of ``moe._lane_tile`` under each of
 ``--lane-tiles``, the distinct ones. For each one JSON line: the host's time
 a call (a queue of calls, one wait), the device time of the ``gmm`` op a call
 from the profiler's trace, that time's share of the matrix unit's peak (the
@@ -78,13 +78,13 @@ def routed_configs(names):
 
 def shapes(cfg, question: int, documents):
     """(what, tokens) of the grouped products the traffic makes ``cfg`` run."""
-    from infinistore_tpu.models import afmoe
+    from infinistore_tpu.tpu import moe
 
     out = [("hit_question", question)]
     if cfg.kv_spec(1).has_state:
         out.append(("miss_piece", cfg.block_tokens))
     else:
-        sizes = sorted({afmoe._chunks(d + question)[1] for d in documents})
+        sizes = sorted({moe._chunks(d + question)[1] for d in documents})
         out += [("miss_chunk", s) for s in sizes]
     return out
 
@@ -115,7 +115,7 @@ def main() -> int:
 
     import trace_reduce
     from ffn_rows_probe import device_ops
-    from infinistore_tpu.models import afmoe
+    from infinistore_tpu.tpu import moe
 
     device = jax.devices()[0]
     with open(os.path.join(REPO, "benchmarks", "peaks.json")) as f:
@@ -152,9 +152,9 @@ def main() -> int:
             for product, rhs in products.items():
                 _, k, n = rhs.shape
                 lhs = jax.random.normal(key, (m, k), jnp.float32).astype(jnp.bfloat16)
-                rule = afmoe._gmm_tiling(k, n)
+                rule = moe._gmm_tiling(k, n)
                 lanes = {(min(k, 1024), min(n, 1024)), rule[1:]} | {
-                    (afmoe._lane_tile(k, most), afmoe._lane_tile(n, most)) for most in lane_tiles
+                    (moe._lane_tile(k, most), moe._lane_tile(n, most)) for most in lane_tiles
                 }
                 triples = sorted({(tm, *kn) for tm in row_tiles for kn in lanes} | {rule})
                 for tiling in triples:

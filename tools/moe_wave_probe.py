@@ -1,6 +1,6 @@
 """What a wave's expert product costs by what its rows chose, the bare kernel.
 
-``models/afmoe.py`` ``_moe_wave`` streams a wave's chosen experts through one
+``tpu/moe.py`` ``_moe_wave`` streams a wave's chosen experts through one
 Pallas kernel (``_moe_wave_pallas``: a grid of static slots x the tiles of an
 expert's width, the slots' expert ids scalar-prefetched into the weight
 blocks' index maps). What it has to read is the DISTINCT experts the rows
@@ -20,7 +20,9 @@ share of the peak. Then a table in markdown.
 ``--against DIR`` runs the same probe on a second tree (a ``git archive`` of
 another commit unpacked in ``DIR``, ``.chipcheck/parent`` say) and puts the
 two side by side: one process a tree, one after the other, because a process
-that touched jax holds the chip; this process then imports no jax.
+that touched jax holds the chip; this process then imports no jax. The
+second tree must have the kernel where this one has it (``tpu/moe.py``: PR 60
+on); an older tree is timed by its own copy of this probe.
 
     python3 tools/moe_wave_probe.py --against .chipcheck/parent   # on the chip: ~3 min
     python3 tools/moe_wave_probe.py --experts 4 --draws 2 --calls 1 \\
@@ -50,9 +52,9 @@ def probe(args) -> list:
     from gmm_tile_probe import routed_configs  # puts REPO on the path: the tree goes before it
 
     sys.path.insert(0, os.path.abspath(args.tree))
-    from infinistore_tpu.models import afmoe
+    from infinistore_tpu.tpu import moe
 
-    assert os.path.abspath(afmoe.__file__).startswith(os.path.abspath(args.tree)), afmoe.__file__
+    assert os.path.abspath(moe.__file__).startswith(os.path.abspath(args.tree)), moe.__file__
     device = jax.devices()[0]
     with open(os.path.join(REPO, "benchmarks", "peaks.json")) as f:
         peaks = json.load(f).get(device.device_kind)
@@ -86,14 +88,14 @@ def probe(args) -> list:
             m = jax.random.normal(key, (rows, d), jnp.float32).astype(jnp.bfloat16)
 
             def fn(m, ids, weights, w, _cfg=cfg):
-                return afmoe._moe_wave(m, ids, weights, w, _cfg)[0]
+                return moe._moe_wave(m, ids, weights, w, _cfg)[0]
 
             # Named so that ``clean_name`` keeps the whole name.
             fn.__name__ = f"wave{len(results) + len(runs)}x"
             draws = [(jnp.asarray(i), jnp.asarray(c)) for i, c in zip(ids, weights)]
             compiled = jax.jit(fn).lower(m, *draws[0], w).compile()
             slots = jax.eval_shape(
-                lambda i, c, _cfg=cfg: afmoe._wave_slots(i, c, _cfg)[0], *draws[0]
+                lambda i, c, _cfg=cfg: moe._wave_slots(i, c, _cfg)[0], *draws[0]
             ).shape[0]
             held_distinct = [
                 len({e for e in draw.reshape(-1).tolist() if first <= e < first + held})
@@ -102,7 +104,7 @@ def probe(args) -> list:
             runs.append((
                 {
                     "tree": args.tree, "config": name, "held": held, "routed": cfg.n_experts,
-                    "dim": d, "ffn": f, "tiles": f // afmoe._wave_f_tile(f), "k": k, "rows": rows,
+                    "dim": d, "ffn": f, "tiles": f // moe._wave_f_tile(f), "k": k, "rows": rows,
                     "slots": slots, "held_distinct": float(np.mean(held_distinct)),
                     "distinct": float(np.mean([len(set(i.reshape(-1).tolist())) for i in ids])),
                     "expert_mb": expert_bytes / 1e6, "device": device.device_kind,
