@@ -144,10 +144,14 @@ class GlmDsaConfig:
         return ServingSteps(prefill, prefill_continue, verify_step_ragged, resume_in_block=True)
 
     # What the wave step counts and returns with its logits (serving.py): the
-    # expert layer's three, and over its real rows and layers the positions the
+    # expert layer's three, over its real rows and layers the positions the
     # selection kept of those it could have (float32: a window's sum passes
-    # 2^31).
-    step_counters = (*ExpertTally.counters, "dsa_keys_selected", "dsa_keys_in_context")
+    # 2^31), and over its layers the counting passes the selection's searches
+    # made over their keys and the row tiles searched (``dsa.select``).
+    step_counters = (
+        *ExpertTally.counters, "dsa_keys_selected", "dsa_keys_in_context",
+        "dsa_select_passes", "dsa_select_searches",
+    )
     router = "sigmoid"  # ``moe.route``'s kind
 
 
@@ -309,7 +313,7 @@ def resume_chunk(
         with jax.named_scope("dsa_index"):
             scores = dsa.index_scores_chunk(q_i, w_i, index, block_table, start_pos)
         with jax.named_scope("dsa_select"):
-            bias = dsa.select(scores, positions + 1, config.index_topk)
+            bias, _ = dsa.select(scores, positions + 1, config.index_topk)
         with jax.named_scope("mla_sparse_attention"):
             attn = mla.latent_chunk_attention(
                 q, latent, block_table, start_pos, w["w_kvb"], rank=config.kv_lora_rank,
@@ -328,7 +332,8 @@ prefill = prefill_by_blocks(resume_chunk)
 def _wave_mixer(w: Params, x, latent, index, dst, slots, row_tables, positions,
                 config: GlmDsaConfig):
     """One layer's mixer over a wave's rows. Returns (x_next, latent, index,
-    the selection's bias [max_blocks, T, block_tokens])."""
+    the selection's bias [max_blocks, T, block_tokens], its passes a row
+    tile [tiles])."""
     n = rms(x, w["in_norm"], config.rms_eps, config.dtype)
     q, rows, q_i, k_i, w_i = _mixer_inputs(w, n, positions, config)
     # A row a slice, in place: a scatter by (block, slot) makes XLA re-lay the
@@ -341,7 +346,7 @@ def _wave_mixer(w: Params, x, latent, index, dst, slots, row_tables, positions,
     with jax.named_scope("dsa_index"):
         scores = dsa.index_scores_rows(q_i, w_i, index, row_tables, seq_lens)
     with jax.named_scope("dsa_select"):
-        bias = dsa.select(scores, seq_lens, config.index_topk)
+        bias, passes = dsa.select(scores, seq_lens, config.index_topk)
     nope, r = config.qk_nope_head_dim, config.kv_lora_rank
     with jax.named_scope("mla_sparse_attention"):
         # Absorbed: the query through the keys' up-projection, the output
@@ -352,7 +357,7 @@ def _wave_mixer(w: Params, x, latent, index, dst, slots, row_tables, positions,
             q_lat, latent, bias, row_tables, seq_lens, rank=r, scale=_scale(config)
         )
         attn = mla.einsum_f32("thr,rhd->thd", mix.astype(config.dtype), w["w_kvb"][..., nope:])
-    return _mixer_out(w, x, attn, config), latent, index, bias
+    return _mixer_out(w, x, attn, config), latent, index, bias, passes
 
 
 def _packed_set(bias, k: int):
@@ -382,7 +387,9 @@ def verify_step_ragged(
     layer, by the positions its selection kept as bits (``_packed_set``: the
     reference follows both, ``benchmarks/reference_glm_dsa.py``) and, among its
     counters, over the real rows and the layers, ``dsa_keys_selected`` of
-    ``dsa_keys_in_context`` (float32). ``caches`` is donated."""
+    ``dsa_keys_in_context`` (float32), and over the layers
+    ``dsa_select_passes`` in ``dsa_select_searches`` row tiles (int32).
+    ``caches`` is donated."""
     del pages, page_rows, page_starts
     x = embed(params, tokens)
     row_tables, dst, slots = wave_index(
@@ -391,12 +398,13 @@ def verify_step_ragged(
     real = real_rows(positions, row_of)
     new_caches: Caches = []
     tally, sets = ExpertTally(), []
-    selected = jnp.zeros((), jnp.float32)
+    selected, passes, searches = jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32), 0
     for layer, (latent, index) in enumerate(caches):
         w = layer_weights(params, layer)
-        x, latent, index, bias = _wave_mixer(
+        x, latent, index, bias, made = _wave_mixer(
             w, x, latent, index, dst, slots, row_tables, positions, config
         )
+        passes, searches = passes + jnp.sum(made), searches + made.shape[0]
         kept = jnp.sum(bias == 0.0, axis=(0, 2), dtype=jnp.float32)
         selected = selected + jnp.sum(jnp.where(real, kept, 0.0))
         sets.append(_packed_set(bias, config.experts_per_token))
@@ -410,4 +418,6 @@ def verify_step_ragged(
     aux["counters"]["dsa_keys_in_context"] = (
         jnp.sum(jnp.where(real, positions + 1, 0), dtype=jnp.float32) * config.n_layers
     )
+    aux["counters"]["dsa_select_passes"] = passes
+    aux["counters"]["dsa_select_searches"] = jnp.int32(searches)
     return logits, new_caches, aux

@@ -18,12 +18,17 @@ scores  ``I[t, s] = sum_j w[t, j] relu(q_I[t, j] . k_I[s])`` in float32 over
 select  per row the ``min(len, k)`` positions ``s < len`` of largest score,
         a tie broken towards the lower position: the SET ``lax.top_k`` gives,
         found without a sort. A float32 maps to an integer that orders the
-        same way; the k-th largest of those is found a bit at a time (32
-        counts of the keys at or above a candidate), then the ties at that
-        value are cut by position the same way. ``dsa_select_pallas`` on the
-        chip, a row tile's keys resident in VMEM; ``select_xla`` (a sort, so a
-        second opinion) elsewhere. The result is a BIAS, not ids: 0 where the
-        position is selected, -1e30 elsewhere.
+        same way; the k-th largest of those is found a bit at a time (a
+        count of the keys at or above a candidate a bit) and the search
+        STOPS at the first threshold whose count is exactly ``min(len, k)``:
+        the keys at or above it are the set, about 24 counts of 32 over
+        distinct scores, none for a row that chooses all it has. Only where
+        every bit was tried and more than k keys still stand (a tie across
+        the k-th place) the ties at that value are cut by position the same
+        way. ``dsa_select_pallas`` on the chip, a row tile's keys resident in
+        VMEM, its second result the counting passes each tile made;
+        ``select_xla`` (a sort, so a second opinion) elsewhere. The result is
+        a BIAS, not ids: 0 where the position is selected, -1e30 elsewhere.
 attend  ``tpu/mla.py``'s two latent attentions with that bias added to the
         scores, so every page is still READ and a position outside the set
         weighs nothing: ``mla_sparse_decode_pallas`` (the absorbed form) and
@@ -52,7 +57,10 @@ from . import paged
 from .mla import _NEG, einsum_f32
 
 _INT_MIN = np.int32(-(2**31))
-_ROW_TILE = 8  # the selection's rows a grid step: a float32 tile's sublanes
+_ROW_TILE = 32  # the selection's rows a grid step at most (``_row_tile``)
+_PAGE_UNROLL = 2  # pages a step of a counting pass's loop
+_BITS_A_CHECK = 2  # bits the search tries between two looks at whether it is done
+_NO_BOUND = np.int32(2**31 - 1)  # a tie cut that cuts nothing
 CHUNK_ROW_TILE = 128  # the chunk scoring pass's rows a grid step
 _VMEM_LIMIT = 64 << 20
 
@@ -208,55 +216,101 @@ def _ordered(x):
     return bits ^ ((bits >> 31) & np.int32(0x7FFFFFFF))
 
 
-def _select_kernel(pages_ref, lens_ref, s_ref, o_ref, key_sc, *, k: int, bt: int):
+def _select_kernel(pages_ref, lens_ref, s_ref, o_ref, passes_ref, key_sc, *, k: int, bt: int):
     p = s_ref.shape[0]
     n = pages_ref[pl.program_id(0)]  # pages the tile's longest row spans
-    lens = lens_ref[...]  # [8, 1]
+    lens = lens_ref[...]  # [rows, 1]
     lane = jax.lax.broadcasted_iota(jnp.int32, s_ref.shape[1:], 1)
+    steps = (n + _PAGE_UNROLL - 1) // _PAGE_UNROLL
 
-    # A page's hits are added up lane tile by lane tile (plain vector adds) and
-    # the lanes summed ONCE a pass: with a cross-lane sum a page a piece's
-    # selection took 26.5 ms a layer on the chip, so 11.7 (PERF.md, PR 56).
-    lanes = 128 if bt % 128 == 0 else bt
+    # A page's hits are added into ``[rows, bt]`` (a register a lane tile, none
+    # waiting on another) and the lanes summed ONCE a pass: with a cross-lane
+    # sum a page a piece's selection took 26.5 ms a layer on the chip, with
+    # one register for a page's eight lane tiles 5.4 (PERF.md, PRs 56 and 61).
+    # What a pass compares a key with is a value a ROW: spread over the lanes
+    # (``wide``) before the page loop and not inside it, where the spreading
+    # was two thirds of a pass (PERF.md, PR 61's probe).
+    wide = lambda x: jnp.broadcast_to(x, s_ref.shape[1:])
 
     def count(hit):
-        """[8, 1] float32: per row, the keys of its first ``n`` pages that
+        """[rows, 1] int32: per row, the keys of its first ``n`` pages that
         ``hit(key, position)`` holds for."""
-        def page(c, acc):
-            held = jnp.where(hit(key_sc[c], c * bt + lane), 1.0, 0.0)
-            for tile in range(0, bt, lanes):
-                acc = acc + held[:, tile : tile + lanes]
+        def step(i, acc):
+            for c in range(_PAGE_UNROLL):
+                page = i * _PAGE_UNROLL + c
+                acc = acc + jnp.where(hit(key_sc[page], page * bt + lane), 1, 0)
             return acc
-        acc = jax.lax.fori_loop(0, n, page, jnp.zeros((lens.shape[0], lanes), jnp.float32))
+        acc = jax.lax.fori_loop(0, steps, step, jnp.zeros(s_ref.shape[1:], jnp.int32))
         return jnp.sum(acc, axis=1, keepdims=True)
 
+    valid = wide(lens)
+
     def write(c, _):
-        key_sc[c] = jnp.where(c * bt + lane < lens, _ordered(s_ref[c]), _INT_MIN)
+        key_sc[c] = jnp.where(c * bt + lane < valid, _ordered(s_ref[c]), _INT_MIN)
         return 0
 
     jax.lax.fori_loop(0, n, write, 0)
-    want = jnp.minimum(lens, k).astype(jnp.float32)
+
+    def pad(c, _):  # the page loop's last step reads whole: keys nothing counts
+        key_sc[c] = jnp.full(s_ref.shape[1:], _INT_MIN, jnp.int32)
+        return 0
+
+    jax.lax.fori_loop(n, steps * _PAGE_UNROLL, pad, 0)
+    want = jnp.minimum(lens, k)
     # The want-th largest key, in the order of unsigned integers (a key with
     # its sign bit flipped), the highest bit first: the largest threshold at
-    # or above which ``want`` keys still stand.
-    found = jnp.zeros(lens.shape, jnp.int32)
-    for bit in range(31, -1, -1):
-        cand = found | np.int32((1 << bit) - (1 << 32 if bit == 31 else 0))
-        found = jnp.where(count(lambda key, _: key >= (cand ^ _INT_MIN)) >= want, cand, found)
-    kth = found ^ _INT_MIN
-    # Of the keys AT the threshold, the first ``ties`` by position: the
-    # largest bound under which no more than ``ties`` of them lie.
-    ties = want - count(lambda key, _: key > kth)
-    bound = jnp.zeros(lens.shape, jnp.int32)
-    for bit in range((p * bt).bit_length() - 1, -1, -1):
-        cand = bound | np.int32(1 << bit)
-        under = count(lambda key, pos: (key == kth) & (pos < cand))
-        bound = jnp.where(under <= ties, cand, bound)
+    # or above which ``want`` keys still stand, and how many stand there. A
+    # row whose count IS ``want`` is done: the keys at or above its threshold
+    # are its set, no lower bit and no tie can change it. A row that may
+    # choose everything (``len <= k``) is done before the first pass. The
+    # search ends when every row of the tile is done; it looks (a vector
+    # reduced to a scalar: 0.2 us) once ``_BITS_A_CHECK`` bits.
+    def open_rows(at):
+        return jnp.max(jnp.where(at == want, 0, 1))
+
+    def try_bit(state):
+        bit, found, at, _ = state
+        for _ in range(_BITS_A_CHECK):
+            cand = found | jnp.left_shift(jnp.int32(1), bit)
+            least = wide(cand ^ _INT_MIN)
+            stand = count(lambda key, _: key >= least)
+            take = (stand >= want) & (at != want)
+            found, at = jnp.where(take, cand, found), jnp.where(take, stand, at)
+            bit = bit - 1
+        return bit, found, at, open_rows(at)
+
+    at = jnp.where(lens <= k, lens, _INT_MIN)  # unknown: no count is negative
+    bit, found, at, tied = jax.lax.while_loop(
+        lambda state: (state[0] >= 0) & (state[3] > 0), try_bit,
+        (jnp.int32(31), jnp.zeros(lens.shape, jnp.int32), at, open_rows(at)),
+    )
+    kth = wide(found ^ _INT_MIN)
+    position_bits = (p * bt).bit_length()
+
+    def cut_ties():
+        """Of the keys AT the threshold, the first ``ties`` by position: the
+        largest bound under which no more than ``ties`` of them lie. Only in
+        a tile with a row that tried every bit and still counts more than
+        ``want``; its done rows ride along."""
+        ties = want - count(lambda key, _: key > kth)
+
+        def try_position(i, bound):
+            cand = bound | jnp.left_shift(jnp.int32(1), position_bits - 1 - i)
+            under = wide(cand)
+            lie = count(lambda key, pos: (key == kth) & (pos < under))
+            return jnp.where(lie <= ties, cand, bound)
+
+        bound = jax.lax.fori_loop(0, position_bits, try_position, jnp.zeros(lens.shape, jnp.int32))
+        return jnp.where(at == want, _NO_BOUND, bound)
+
+    bound = jax.lax.cond(tied > 0, cut_ties, lambda: jnp.full(lens.shape, _NO_BOUND, jnp.int32))
+    bound = wide(bound)
+    passes_ref[pl.program_id(0), 0] = 31 - bit + jnp.where(tied > 0, 1 + position_bits, 0)
 
     def emit(c, _):
         key, pos = key_sc[c], c * bt + lane
         chosen = (key > kth) | ((key == kth) & (pos < bound))
-        o_ref[c] = jnp.where(chosen & (pos < lens), 0.0, _NEG)
+        o_ref[c] = jnp.where(chosen & (pos < valid), 0.0, _NEG)
         return 0
 
     jax.lax.fori_loop(0, n, emit, 0)
@@ -268,27 +322,39 @@ def _select_kernel(pages_ref, lens_ref, s_ref, o_ref, key_sc, *, k: int, bt: int
     jax.lax.fori_loop(n, p, blank, 0)
 
 
+def _row_tile(rows: int) -> int:
+    """The selection's rows a grid step: 32 (a search's passes are latency,
+    the same for 8 rows' registers as for 32's; PERF.md, PR 61), a wave's few
+    rows one tile of whole sublanes."""
+    return min(_ROW_TILE, -(-rows // 8) * 8)
+
+
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
 def dsa_select_pallas(scores, lens, *, k: int, interpret: bool = False):
-    """scores: [P, R, bt] float32, R whole tiles of 8 rows; lens: [R] int32,
-    the positions a row may choose among (its own included). Returns the
-    bias [P, R, bt] float32: 0 on the row's ``min(len, k)`` best positions
-    under ``len``, -1e30 elsewhere."""
+    """scores: [P, R, bt] float32, R whole tiles of ``_row_tile(R)`` rows;
+    lens: [R] int32, the positions a row may choose among (its own included).
+    Returns the bias [P, R, bt] float32 (0 on the row's ``min(len, k)`` best
+    positions under ``len``, -1e30 elsewhere) and, [tiles, 1] int32, the
+    counting passes each row tile's search made over its keys."""
     p, r, bt = scores.shape
-    tiles = r // _ROW_TILE
-    longest = jnp.max(lens.reshape(tiles, _ROW_TILE), axis=1)
+    rows = _row_tile(r)
+    tiles = r // rows
+    longest = jnp.max(lens.reshape(tiles, rows), axis=1)
     pages = jnp.minimum((longest + bt - 1) // bt, p).astype(jnp.int32)
-    block = pl.BlockSpec((p, _ROW_TILE, bt), lambda i, pages: (0, i, 0))
+    block = pl.BlockSpec((p, rows, bt), lambda i, pages: (0, i, 0))
     return pl.pallas_call(
         functools.partial(_select_kernel, k=k, bt=bt),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(tiles,),
-            in_specs=[pl.BlockSpec((_ROW_TILE, 1), lambda i, pages: (i, 0)), block],
-            out_specs=block,
-            scratch_shapes=[pltpu.VMEM((p, _ROW_TILE, bt), jnp.int32)],
+            in_specs=[pl.BlockSpec((rows, 1), lambda i, pages: (i, 0)), block],
+            out_specs=[block, pl.BlockSpec(memory_space=pltpu.SMEM)],  # whole: a word a step
+            scratch_shapes=[pltpu.VMEM((-(-p // _PAGE_UNROLL) * _PAGE_UNROLL, rows, bt), jnp.int32)],
         ),
-        out_shape=jax.ShapeDtypeStruct(scores.shape, jnp.float32),
+        out_shape=[
+            jax.ShapeDtypeStruct(scores.shape, jnp.float32),
+            jax.ShapeDtypeStruct((tiles, 1), jnp.int32),
+        ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",), vmem_limit_bytes=_VMEM_LIMIT,
         ),
@@ -317,15 +383,19 @@ def select_xla(scores, lens, *, k: int):
 
 
 def select(scores, lens, k: int):
-    """The selection as a bias, [P, R, bt] float32 (module docstring)."""
-    if not paged._use_pallas():
-        return select_xla(scores, lens, k=k)
+    """The selection as a bias, [P, R, bt] float32 (module docstring), and
+    the counting passes each row tile made, [tiles] int32 (the sort makes
+    none)."""
     r = scores.shape[1]
-    pad = -r % _ROW_TILE
+    tile = _row_tile(r)
+    pad = -r % tile
+    if not paged._use_pallas():
+        return select_xla(scores, lens, k=k), jnp.zeros(((r + pad) // tile,), jnp.int32)
     if pad:
         scores = jnp.pad(scores, ((0, 0), (0, pad), (0, 0)))
         lens = jnp.pad(lens, (0, pad))
-    return dsa_select_pallas(scores, lens, k=k)[:, :r]
+    bias, passes = dsa_select_pallas(scores, lens, k=k)
+    return bias[:, :r], passes[:, 0]
 
 
 # ---------------------------------------------------------------------------

@@ -96,26 +96,79 @@ def _sets(bias) -> list:
     return [set(np.nonzero(row == 0)[0].tolist()) for row in flat]
 
 
-@pytest.mark.parametrize("scores_kind", ["distinct", "tied"])
-@pytest.mark.parametrize("form", ["sort", "search"])
-def test_the_selection_is_top_k_as_a_set(form, scores_kind):
-    p, r, bt, k = 5, 16, 128, 100
+def _selection_case(kind: str, p=5, r=16, bt=128, k=100):
+    """Scores [P, R, bt] and lens [R] of one row tile, by what the k-th-value
+    search meets in them."""
     rng = np.random.default_rng(561)
     scores = rng.standard_normal((p, r, bt)).astype(np.float32)
-    if scores_kind == "tied":
-        scores = np.round(scores * 4) / 4  # a few dozen values: ties at every threshold
     lens = rng.integers(1, p * bt + 1, size=r).astype(np.int32)
     lens[:4] = [0, p * bt, k, k - 1]  # nothing to choose, everything, exactly k, under k
+    if kind == "tied":
+        scores = np.round(scores * 4) / 4  # a few dozen values: ties at every threshold
+    elif kind == "mixed":
+        # Rows that end exact after a few bits, one whose ties need the search
+        # by position, and (the first four) rows that choose all they have.
+        scores[:, 5] = np.round(scores[:, 5] * 4) / 4
+        lens[4:] = p * bt - np.arange(r - 4)
+    elif kind == "equal":
+        scores[:] = 0.5  # no threshold ever counts exactly k
+    elif kind == "duplicate":
+        # ONE value twice, at the k-th and the (k + 1)-th place of every row.
+        lens[4:] = rng.integers(k + 2, p * bt + 1, size=r - 4)
+        flat = scores.transpose(1, 0, 2).reshape(r, -1)
+        for row in range(4, r):
+            order = np.argsort(-flat[row, : lens[row]], kind="stable")
+            flat[row, order[k]] = flat[row, order[k - 1]]
+        scores = flat.reshape(r, p, bt).transpose(1, 0, 2).copy()
+    elif kind == "short":
+        lens = rng.integers(0, k + 1, size=r).astype(np.int32)  # every row chooses all it has
+    else:
+        assert kind == "distinct", kind
+    return scores, lens, k
+
+
+SELECTION_CASES = ["distinct", "tied", "mixed", "equal", "duplicate", "short"]
+
+
+@pytest.mark.parametrize("scores_kind", SELECTION_CASES)
+@pytest.mark.parametrize("form", ["sort", "search"])
+def test_the_selection_is_top_k_as_a_set(form, scores_kind):
+    scores, lens, k = _selection_case(scores_kind)
+    r = scores.shape[1]
     if form == "sort":
         bias = dsa.select_xla(jnp.asarray(scores), jnp.asarray(lens), k=k)
     else:
-        bias = dsa.dsa_select_pallas(jnp.asarray(scores), jnp.asarray(lens), k=k, interpret=True)
+        bias, _ = dsa.dsa_select_pallas(jnp.asarray(scores), jnp.asarray(lens), k=k, interpret=True)
+    assert bias.shape == scores.shape and bias.dtype == jnp.float32
     assert set(np.unique(np.asarray(bias)).tolist()) <= {0.0, float(np.float32(-1e30))}
     flat = scores.transpose(1, 0, 2).reshape(r, -1)
     for row, got in enumerate(_sets(bias)):
         n = int(lens[row])
         want = set(np.asarray(jax.lax.top_k(jnp.asarray(flat[row, :n]), min(n, k))[1]).tolist())
         assert got == want, (row, n, sorted(got ^ want)[:8])
+
+
+@pytest.mark.parametrize("rows", [8, 16, 64])
+@pytest.mark.parametrize("scores_kind", ["short", "distinct", "mixed", "equal"])
+def test_the_search_counts_the_passes_it_made(scores_kind, rows):
+    """The kernel's second result, a row tile: no pass where every row
+    chooses all it has, the value bits at most where some threshold counts
+    exactly k for every row, every value bit, the count above and every
+    position bit where a tie stands across the k-th place. A tile is 32
+    rows, or all of fewer."""
+    scores, lens, k = _selection_case(scores_kind, r=rows)
+    p, _, bt = scores.shape
+    bias, passes = dsa.dsa_select_pallas(jnp.asarray(scores), jnp.asarray(lens), k=k, interpret=True)
+    assert passes.shape == (-(-rows // 32), 1) and passes.dtype == jnp.int32
+    assert _sets(bias) == _sets(dsa.select_xla(jnp.asarray(scores), jnp.asarray(lens), k=k))
+    every = 32 + 1 + (p * bt).bit_length()
+    made = np.asarray(passes)[:, 0].tolist()
+    if scores_kind == "short":
+        assert made == [0] * len(made)
+    elif scores_kind == "distinct":
+        assert all(0 < n <= 32 for n in made), made
+    else:
+        assert made[0] == every and all(n == every or n <= 32 for n in made), made
 
 
 def _index_case(rows, seed, hi=2, di=16, bt=8, blocks=12, table=4):
@@ -411,22 +464,47 @@ def test_the_program_through_the_harness_against_the_reference(conn, params, pat
     assert (kept < could) == (selection == "drops-keys"), (kept, could)
 
 
-def test_the_waves_counters_are_the_selections_own(conn, params):
+@pytest.mark.parametrize("form", ["sort", "search"])
+def test_the_waves_counters_are_the_selections_own(conn, params, monkeypatch, form):
     """One request, GEN + 1 waves of one row at positions 28 .. 35: each keeps
-    16 of its position + 1 keys at each of the three layers."""
+    16 of its position + 1 keys at each of the three layers, in one search a
+    layer (the row's tile). The sort makes no counting pass; the search
+    (interpreted here) at least one a search, its context being longer than
+    ``index_topk``, and never more than it has bits to try."""
+    if form == "search":
+        def searched(scores, lens, k):
+            pad = -scores.shape[1] % 8
+            bias, passes = dsa.dsa_select_pallas(
+                jnp.pad(scores, ((0, 0), (0, pad), (0, 0))), jnp.pad(lens, (0, pad)), k=k, interpret=True
+            )
+            return bias[:, : scores.shape[1]], passes[:, 0]
+
+        monkeypatch.setattr(dsa, "select", searched)
+        jax.clear_caches()
     rng = np.random.default_rng(568)
     tokens = rng.integers(0, CFG.vocab, size=DOC + QUESTION).tolist()
 
     async def drive():
-        t = Tapped(conn, params, "glm-counters")
+        t = Tapped(conn, params, f"glm-counters-{form}")
         stats, calls = await t.ask(tokens)
         return t.h.metrics(), len(calls)
 
-    metrics, waves = asyncio.run(drive())
+    try:
+        metrics, waves = asyncio.run(drive())
+    finally:
+        if form == "search":
+            jax.clear_caches()  # no later test finds a program traced around the stand-in
     positions = range(len(tokens) - 1, len(tokens) - 1 + waves)
     assert metrics["dsa_keys_selected"] == CFG.n_layers * CFG.index_topk * waves
     assert metrics["dsa_keys_in_context"] == CFG.n_layers * sum(p + 1 for p in positions)
     assert metrics["moe_pairs"] == waves * CFG.sites * CFG.experts_per_token
+    searches = CFG.n_layers * 1 * waves  # layers x row tiles x waves
+    assert metrics["dsa_select_searches"] == searches
+    every = 32 + 1 + (MAX_REQ_BLOCKS * BT).bit_length()
+    if form == "sort":
+        assert metrics["dsa_select_passes"] == 0
+    else:
+        assert searches <= metrics["dsa_select_passes"] <= every * searches
 
 
 @pytest.mark.parametrize("phase", ["chunk", "wave"])
@@ -440,9 +518,9 @@ def test_the_program_selects_the_references_sets_where_no_near_tie_stands(params
     real_select = dsa.select
 
     def recording(scores, lens, k):
-        bias = real_select(scores, lens, k)
+        bias, passes = real_select(scores, lens, k)
         seen.append(_sets(bias))
-        return bias
+        return bias, passes
 
     monkeypatch.setattr(dsa, "select", recording)
     caches = CFG.kv_spec(NUM_BLOCKS).make_caches()

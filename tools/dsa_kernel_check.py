@@ -10,7 +10,13 @@ Tier-1 holds the kernels in interpret mode at toy shapes
 COMPILED kernels compute what their twins do at 33 pages of 1,024 tokens: the
 scoring pass of a wave and of a piece (relative rms error over the valid
 positions), the k-th-value search against the sort as SETS (``lax.top_k``'s,
-ties by position: equal or not, nothing between), the latent decode under a
+ties by position: equal or not, nothing between; then the selection ALONE at
+the three shapes the cell's trace names it by, a piece's 1,024 rows, a
+question's 128 and a wave's 8 at the end of 8 / 16 / 33 pages of scores of
+the configuration's form: device milliseconds a call, the counting passes a
+row tile made and the sets' equality, the probe ISSUE 61 asks for before any
+cell is run; in a ``git archive`` of a tree before PR 61 the same rows
+without the passes), the latent decode under a
 selection's bias, the chunk's latent attention (``mla_chunk_attention_pallas``
 against the page loop it replaced, a block's rows over 1 / 8 / 32 pages with a
 selection's bias and without, a 127-row question, and the same kernel at
@@ -20,6 +26,7 @@ model of three published-width layers through three whole pieces, a part piece
 and one wave.
 
     chiprun --chips 1 -- python3 tools/dsa_kernel_check.py
+    chiprun --chips 1 -- python3 tools/dsa_kernel_check.py --sections select   # ~1 min
 
 One line a check, then ``{"ok": ...}``; exit code 1 unless the kernels' sets
 are equal and their errors under ``--tol`` (default 0.02: bf16 products summed
@@ -34,15 +41,17 @@ import argparse
 import json
 import os
 import sys
+import tempfile
 import time
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, REPO)
+sys.path[:0] = [REPO, os.path.join(REPO, "benchmarks")]
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
+import trace_reduce  # noqa: E402
 from infinistore_tpu.models import glm_dsa  # noqa: E402
 from infinistore_tpu.tpu import dsa, mla, paged  # noqa: E402
 
@@ -77,7 +86,7 @@ def kernels(real: dict, key) -> dict:
     out["index_decode_rel"] = rel(flat(got), flat(want))
 
     pad = -t % 8
-    got = dsa.dsa_select_pallas(jnp.pad(want, ((0, 0), (0, pad), (0, 0))), jnp.pad(lens, (0, pad)), k=k)[:, :t]
+    got = _bias(dsa.dsa_select_pallas(jnp.pad(want, ((0, 0), (0, pad), (0, 0))), jnp.pad(lens, (0, pad)), k=k))[:, :t]
     bias = dsa.select_xla(want, lens, k=k)
     out["select_wave_sets_equal"] = bool(jnp.array_equal(got, bias))
 
@@ -95,8 +104,55 @@ def kernels(real: dict, key) -> dict:
     out["index_chunk_rel"] = rel(got, want)
     lens_c = 3 * bt + jnp.arange(bt, dtype=I32) + 1
     out["select_piece_sets_equal"] = bool(
-        jnp.array_equal(dsa.dsa_select_pallas(want, lens_c, k=k), dsa.select_xla(want, lens_c, k=k))
+        jnp.array_equal(_bias(dsa.dsa_select_pallas(want, lens_c, k=k)), dsa.select_xla(want, lens_c, k=k))
     )
+    return out
+
+
+def _bias(result):
+    """``dsa_select_pallas``'s bias: its first result since PR 61, its only
+    one before (the tool also runs inside a ``git archive`` of an older tree)."""
+    return result if isinstance(result, jax.Array) else result[0]
+
+
+def selection(real: dict, key, calls: int = 8) -> dict:
+    """The selection alone at the shapes the cell's trace names it by, ``[33,
+    1024 | 128 | 8, 1024]`` (a piece, a question, a wave of three rows and a
+    padded one), its rows at the END of a context of 8 / 16 / 33 pages, over
+    scores of the configuration's own form (the chunk's scoring kernel over
+    seeded index keys): device milliseconds a call from a trace, the counting
+    passes a row tile made, and the sets against the sort's."""
+    bt, blocks = real["serving"]["block_tokens"], real["serving"]["cache_blocks"]
+    hi, di, k, p = real["index_n_heads"], real["index_head_dim"], real["index_topk"], 33
+    ks = jax.random.split(key, 4)
+    index = jax.random.normal(ks[0], (blocks, di, bt), F32).astype(BF)
+    table = jax.random.permutation(ks[1], blocks)[:p].astype(I32)
+    q = jax.random.normal(ks[2], (hi, bt, di), F32).astype(BF)
+    w = jax.random.normal(ks[3], (bt, hi), F32) * 0.05
+    scores = dsa.dsa_index_chunk_pallas(q, w, index, table, jnp.asarray([p], I32))
+    out = {}
+    for pages in (8, 16, 33):
+        last = min(pages * bt, p * bt - 1)
+        cases = {
+            "piece": (scores, last - bt + 1 + jnp.arange(bt, dtype=I32)),
+            "question": (scores[:, :128], last - 127 + jnp.arange(128, dtype=I32)),
+            "wave": (scores[:, :8], jnp.asarray([last, last - 1500, last - 3000, 1, 0, 0, 0, 0], I32)),
+        }
+        results = {name: dsa.dsa_select_pallas(s, lens, k=k) for name, (s, lens) in cases.items()}
+        with tempfile.TemporaryDirectory() as tmp:
+            with jax.profiler.trace(tmp):
+                for s, lens in cases.values():
+                    for _ in range(calls):
+                        timed = dsa.dsa_select_pallas(s, lens, k=k)
+                    jax.block_until_ready(timed)
+            ops = trace_reduce.reduce(trace_reduce.load(trace_reduce.find_xplane(tmp)))["ops"]
+        for name, (s, lens) in cases.items():
+            op = ops.get(f"dsa_select_pallas_f32_{p}_{s.shape[1]}_{bt}")
+            row = {"ms": op and round(op[0] / op[1] * 1e3, 4)}
+            if not isinstance(results[name], jax.Array):
+                row["passes_a_tile"] = round(float(jnp.mean(results[name][1])), 2)
+            row["sets_equal"] = bool(jnp.array_equal(_bias(results[name]), dsa.select_xla(s, lens, k=k)))
+            out[f"select_{name}_{pages}_pages"] = row
     return out
 
 
@@ -129,7 +185,7 @@ def chunk(real: dict, kimi: dict, key) -> dict:
         bias = None
         if biased:
             lens = start + jnp.arange(s, dtype=I32) + 1
-            bias = dsa.select(jax.random.normal(ks[4], (p, s, bt), F32), lens, k)
+            bias = _bias(dsa.select(jax.random.normal(ks[4], (p, s, bt), F32), lens, k))
         kw = dict(rank=rank, nope=nope, scale=float((nope + rope) ** -0.5))
         twin = jax.jit(lambda q, l, t, at, w, b: mla.latent_chunk_attention_xla(q, l, t, at, w, bias=b, **kw))
         kern = lambda q, l, t, at, w, b: mla.mla_chunk_attention_pallas(q, l, t, at, w, bias=b, **kw)
@@ -183,6 +239,7 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", type=int, default=5)
     ap.add_argument("--tol", type=float, default=0.02)
     ap.add_argument("--chunk-tol", type=float, default=0.0015)
+    ap.add_argument("--sections", default="kernels,select,chunk,model", help="which of them to run")
     args = ap.parse_args(argv)
     if jax.default_backend() != "tpu":
         print("dsa_kernel_check: needs a TPU (the kernels have no compiled form elsewhere)", file=sys.stderr)
@@ -191,11 +248,15 @@ def main(argv=None) -> int:
         real = json.load(f)
     with open(os.path.join(REPO, "benchmarks", "configs", "kimi-linear-48b-a3b.json")) as f:
         kimi = json.load(f)
-    key = jax.random.key(args.seed)
-    held = kernels(real, key)
-    timed = chunk(real, kimi, jax.random.fold_in(key, 2))
-    held.update({name: value for name, value in timed.items() if name.endswith("_rel")})
-    for name, value in {**timed, **held, **model(real, jax.random.fold_in(key, 1))}.items():
+    key, sections = jax.random.key(args.seed), set(args.sections.split(","))
+    held = kernels(real, key) if "kernels" in sections else {}
+    timed = selection(real, jax.random.fold_in(key, 3)) if "select" in sections else {}
+    held.update({f"{name}_sets_equal": row["sets_equal"] for name, row in timed.items()})
+    if "chunk" in sections:
+        timed.update(chunk(real, kimi, jax.random.fold_in(key, 2)))
+        held.update({name: value for name, value in timed.items() if name.endswith("_rel")})
+    reported = model(real, jax.random.fold_in(key, 1)) if "model" in sections else {}
+    for name, value in {**timed, **held, **reported}.items():
         print(json.dumps({name: value}), flush=True)
     ok = all(
         value is True if name.endswith("_equal")
