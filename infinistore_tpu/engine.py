@@ -346,10 +346,12 @@ class _WaveOut:
     __slots__ = ("ids", "feed", "aux_rows", "host_ids")
 
     def __init__(self, ids, feed, aux_rows):
-        self.ids = ids  # [T] int32 on the device, its host copy under way
+        # [T] int32 on the device, its host copy under way; of a model that
+        # drafts [2, T]: the ids over the drafts, one array for one read.
+        self.ids = ids
         self.feed = feed  # [FEED_ROWS]: what the next wave's fed rows read, on the device
         self.aux_rows = aux_rows  # the model's per-row aux [T, ...], or None
-        self.host_ids = None  # np [T], once the first request has asked
+        self.host_ids = None  # np, ``ids`` on the host once the first request has asked
 
 
 class WaveRows:
@@ -520,8 +522,22 @@ class WaveDecoder:
     back with its token. ``waves_ahead`` counts the launched waves with a fed
     row (``wave_ahead_waves`` in ``metrics()``).
 
+    **A model that drafts** (``config.steps.drafts``, models/serving.py) has a
+    row of two: every entry is laid out as a SLOT of ``width`` = 2 flat rows,
+    ``[token, draft]`` at ``[p, p + 1]``, and an entry that brings one token
+    (a request's first round, its last, a bare call) has its row repeated
+    within its slot (the same bytes to the same place, as the tail's
+    padding). So a wave of n entries is one program whatever its entries
+    drafted, and the buckets a run of bare one-token calls pins are the
+    buckets the drafting requests land on. The wave's program hands back the
+    ids and the drafts as ONE array: ``token_ids`` and ``draft_ids`` read its
+    two rows, one blocking read a wave.
+    Such a model's requests declare no stream: a round's chunk holds a draft
+    the host reads first, so no row of theirs is launched ahead.
+
     ``bucket_sizes`` records the distinct (B, T, P) buckets — table rows,
-    flat token rows, flat attention pages — which ARE the jit cache
+    flat token rows, flat attention pages, the last two in SLOTS (of one row,
+    but for a model that drafts: of ``width``) — which ARE the jit cache
     entries; the harness test pins the count. ``pad_rows``/
     ``launched_rows`` feed the ``engine_wave_pad_fraction`` metric: the
     share of launched wave rows that were padding (the rectangle's was
@@ -539,6 +555,8 @@ class WaveDecoder:
         self._window = spec.window
         self._layers = spec.num_layers
         self._sliding = sum(w is not None for w in spec.windows or ())
+        # Flat rows an entry's slot takes (class docstring, "a model that drafts").
+        self.width = 2 if harness.config.steps.drafts else 1
         self._pending: List[tuple] = []
         self._flush_scheduled = False
         # Wave-row padding ledger (engine_wave_pad_fraction).
@@ -615,6 +633,11 @@ class WaveDecoder:
         whatever its class."""
         if not tokens or len(tokens) != len(positions):
             raise ValueError("need non-empty tokens with matching positions")
+        if self.width > 1 and len(tokens) > self.width:
+            raise ValueError(
+                f"a model that drafts takes a chunk of at most {self.width} rows (the token and "
+                f"its draft), got {len(tokens)}"
+            )
         fut = asyncio.get_running_loop().create_future()
         stream = self._streams.get(id(padded_table))
         if stream is not None:
@@ -674,12 +697,20 @@ class WaveDecoder:
         first request of a wave to ask blocks once, for the whole wave's ids;
         every other reads that copy. Nothing is dispatched: the rows know
         their wave. ``KeyError`` for anything this decoder did not hand out."""
+        return self._host_ids(rows, 0)
+
+    def _host_ids(self, rows: WaveRows, which: int) -> np.ndarray:
+        """Row ``which`` (0: the sampled ids, 1: the drafts) of the wave's one
+        host copy, cut to ``rows``."""
         rows = self._mine(rows)
         out = rows.out
+        if which and out.ids.ndim == 1:
+            raise KeyError("this model's wave drafts nothing")
         if out.host_ids is None:
             out.host_ids = np.asarray(out.ids)
             self.blocking_reads += 1
-        return out.host_ids[rows.off : rows.off + rows.n]
+        host = out.host_ids if out.host_ids.ndim == 1 else out.host_ids[which]
+        return host[rows.off : rows.off + rows.n]
 
     def row_aux(self, rows: WaveRows):
         """The per-row ``aux`` slice the wave returned with the logits
@@ -690,6 +721,16 @@ class WaveDecoder:
         if rows.out.aux_rows is None:
             raise KeyError("this model's wave returns no per-row aux")
         return rows.out.aux_rows[rows.off : rows.off + rows.n]
+
+    def draft_ids(self, rows: WaveRows) -> np.ndarray:
+        """The draft ids ``[len(rows)] int32`` of a model that drafts, for the
+        logits ``rows`` that ``step_chunk`` handed a request, on the host:
+        row j's is the token the model's drafting layer proposes AFTER the one
+        row j sampled. The same host copy ``token_ids`` reads: the wave's
+        program hands back the ids and the drafts as one array, so a wave is
+        ONE blocking read with or without a drafter. ``KeyError`` for anything
+        this decoder did not hand out, or of a model that drafts nothing."""
+        return self._host_ids(rows, 1)
 
     def step_counters(self) -> dict:
         """The model step's named counters, summed over every wave so far
@@ -713,15 +754,20 @@ class WaveDecoder:
         tokens: List[int] = []
         positions: List[int] = []
         row_of: List[int] = []
+        t_real = 0
         for r, (toks, pos, _tbl, _fut) in enumerate(batch):
-            tokens.extend(toks)
-            positions.extend(pos)
-            row_of.extend([r] * len(toks))
-        t_real = len(tokens)
-        t_bucket = 1 << (t_real - 1).bit_length()
-        tokens.extend([tokens[-1]] * (t_bucket - t_real))
-        positions.extend([positions[-1]] * (t_bucket - t_real))
-        row_of.extend([row_of[-1]] * (t_bucket - t_real))
+            # A slot's spare rows repeat the entry's last (width 1: none).
+            slot = self._slot(toks)
+            spare = slot - len(toks)
+            tokens.extend(toks + toks[-1:] * spare)
+            positions.extend(pos + pos[-1:] * spare)
+            row_of.extend([r] * slot)
+            t_real += len(toks)
+        t_bucket = 1 << (len(tokens) - 1).bit_length()
+        tail = t_bucket - len(tokens)
+        tokens.extend([tokens[-1]] * tail)
+        positions.extend([positions[-1]] * tail)
+        row_of.extend([row_of[-1]] * tail)
         b_bucket = 1 << (len(batch) - 1).bit_length()
         tables = [np.asarray(b[2], dtype=np.int32) for b in batch]
         tables.extend([tables[-1]] * (b_bucket - len(batch)))
@@ -741,7 +787,7 @@ class WaveDecoder:
                 row_tables, row_lens, bt, window=self._window,
                 pad_to=min(meta.num_pages, t_bucket * (self._window // bt + 1)),
             )
-        self.bucket_sizes.add((b_bucket, t_bucket, meta.num_pages))
+        self.bucket_sizes.add((b_bucket, t_bucket // self.width, meta.num_pages // self.width))
         self.pad_rows += t_bucket - t_real
         self.launched_rows += t_bucket
         self.wave_pages += meta.num_pages
@@ -753,6 +799,11 @@ class WaveDecoder:
             )
         self.wave_layer_pages += self._layers * real_pages
         return _Wave(tokens, positions, row_of, meta, tables, wmeta, t_real)
+
+    def _slot(self, toks: List[int]) -> int:
+        """Flat rows the entry ``toks`` takes in a wave: its own, or the slot
+        of a model that drafts."""
+        return len(toks) if self.width == 1 else self.width
 
     def launch(self, tokens, positions, row_of, meta, tables, wmeta=None, prev_ids=None):
         """ONE wave on the device (cache-mutating: caller holds the exclusive
@@ -846,7 +897,7 @@ class WaveDecoder:
         for entry in launched:
             toks, pos, table, fut = entry
             if fut is None or not fut.done():
-                rows = WaveRows(self, logits, out, off, len(toks))
+                rows = WaveRows(self, logits, out, off, len(toks))  # its own rows of its slot
                 stream = self._streams.get(id(table))
                 if stream is not None and (fut is None or (
                     ahead_ok and stream.left > 0 and len(toks) == 1 and off < FEED_ROWS
@@ -856,7 +907,7 @@ class WaveDecoder:
                         again.append(entry)
                 elif fut is not None:
                     fut.set_result(rows)
-            off += len(toks)
+            off += self._slot(toks)
         self._keep(aux)
         return again
 
@@ -1213,7 +1264,10 @@ class ContinuousBatchingHarness:
         each generation round verifies the drafted chunk in one wave row
         (verify_step_ragged), emitting every greedy-accepted token plus
         the model's continuation, so tokens/round can exceed 1 with output
-        identical to plain greedy decode."""
+        identical to plain greedy decode. A model that drafts ITSELF brings
+        its drafter with its configuration, as it brings its steps
+        (``config.steps.drafts``, models/serving.py): nothing is handed in
+        here, and a host drafter beside it is refused."""
         self.adapter = adapter
         self.params = params
         self.config = config
@@ -1221,6 +1275,7 @@ class ContinuousBatchingHarness:
         self.spec_rounds = 0  # generation waves a request participated in
         self.spec_drafted = 0  # draft tokens proposed
         self.spec_accepted = 0  # draft tokens accepted
+        self.spec_emitted = 0  # tokens those rounds emitted
         # The model's three steps (``config.steps``, models/serving.py) and
         # its cache's shape come with the configuration: no model file is
         # named here.
@@ -1229,12 +1284,22 @@ class ContinuousBatchingHarness:
         # where a recurrent state absorbs a token once, and where the model's
         # resume step takes no more than a block's part (``ServingSteps``).
         self.by_blocks = self.spec.has_state or config.steps.resume_in_block
-        if self.by_blocks and drafter is not None:
-            # The first wave lands the prompt's last token, one token a row;
-            # with a state, a verified chunk's rejected rows would stay absorbed.
+        # Whether the model drafts itself: each round's chunk is then
+        # ``[token, draft]``, the draft the wave before's (``_generate``).
+        self.drafts = config.steps.drafts
+        if self.spec.has_state and (drafter is not None or self.drafts):
+            # A rejected row's slot in a latent or K/V cache is overwritten by
+            # the next round's first row, whether or not the cache is served
+            # by blocks; a state would keep the rejected row absorbed.
             raise ValueError(
-                "a model served a block at a time (a recurrent state, or a resume step "
-                "inside one block) decodes one token a row: no drafter"
+                "a cache that holds a recurrent state absorbs every row it is handed, a "
+                "rejected draft's too: no drafter (a latent or K/V cache takes one, served "
+                "by blocks or not)"
+            )
+        if self.drafts and (drafter is not None or not config.steps.resume_in_block):
+            raise ValueError(
+                "a model that drafts itself (config.steps.drafts) takes no second drafter, and "
+                "its resume step lies inside one block (the piece's next token is its operand)"
             )
         self.caches = self.spec.make_caches()
         self.pool = BlockPool(num_blocks)
@@ -1326,17 +1391,37 @@ class ContinuousBatchingHarness:
         exclusive gate."""
         bt = self.config.block_tokens
         padded = self._padded_table(table)
-        for start in range(start_block * bt, len(token_ids), bt):
+        first = start_block * bt
+        if self.drafts:
+            # Handed the WHOLE prompt (``run_request``): its last token is
+            # landed by the first wave, and is here the last piece's operand.
+            token_ids, next_token = token_ids[:-1], token_ids[-1]
+        for start in range(first, len(token_ids), bt):
             piece = jnp.asarray(token_ids[start : start + bt], jnp.int32)
             # A piece that completes its block leaves a snapshot a save can write.
             whole = piece.shape[0] == bt
             snapshot = tracing.trace_op("state_snapshot") if whole else contextlib.nullcontext()
-            with snapshot as span:
+            kw = {}
+            if self.drafts:
+                # The drafting layer's slot at the piece's last position is a
+                # function of the token AFTER it: the next piece's first, or
+                # ``next_token`` (the prompt's last, which the first wave lands).
+                after = token_ids[start + bt] if start + bt < len(token_ids) else next_token
+                kw["next_token"] = jnp.int32(after)
+            # A hit's first piece carries the rewrite of the one slot of the
+            # drafting layer that the installed prefix could not know.
+            rewrite = (
+                tracing.trace_op("boundary_rewrite")
+                if self.drafts and start == first and first > 0 else contextlib.nullcontext()
+            )
+            with snapshot as span, rewrite as rspan:
                 if span is not None:
                     span.annotate(block=start // bt)
+                if rspan is not None:
+                    rspan.annotate(block=start_block - 1, slot=first - 1)
                 _, self.caches = self.config.steps.resume(
                     self.params, piece, jnp.int32(start), self.caches, padded,
-                    self.config, self.max_req_blocks,
+                    self.config, self.max_req_blocks, **kw,
                 )
             self.prompt_rows += piece.shape[0]
 
@@ -1375,8 +1460,9 @@ class ContinuousBatchingHarness:
         (``_compute_by_blocks``)."""
         bt = self.config.block_tokens
         self.resumes += 1
-        self.resume_tokens += len(token_ids) - start_block * bt
-        self.resume_pages += -(-len(token_ids) // bt)
+        landed = len(token_ids) - self.drafts  # a drafting model is handed the whole prompt
+        self.resume_tokens += landed - start_block * bt
+        self.resume_pages += -(-landed // bt)
         if self.by_blocks:
             return self._compute_by_blocks(token_ids, table, start_block)
         suffix = jnp.asarray(token_ids[start_block * bt :], jnp.int32)
@@ -1527,17 +1613,23 @@ class ContinuousBatchingHarness:
         # Without a drafter every round is one token at the next position, and
         # this loop says so: the decoder may then have round k + 1's row on the
         # device while round k's token is read here (``WaveDecoder.stream``).
-        # A drafter's next chunk is the host's to draft.
+        # A drafter's next chunk is the host's to draft, and a model that
+        # drafts itself hands the host the draft that chunk holds.
+        drafting = self.drafter is not None or self.drafts
         declared = (
-            self.wave.stream(padded, gen_tokens + closing)
-            if self.drafter is None else contextlib.nullcontext()
+            contextlib.nullcontext() if drafting
+            else self.wave.stream(padded, gen_tokens + closing)
         )
+        draft: Optional[int] = None  # the model's own, from the round before
+        accepted: List[int] = []  # drafts accepted, a round
         with tracing.trace_op("generate") as gspan, declared:
             while len(out) < gen_tokens:
                 chunk = [tok]
+                remaining = gen_tokens - len(out)
                 if self.drafter is not None:
-                    remaining = gen_tokens - len(out)
                     chunk += self.drafter.draft(history)[: remaining - 1]
+                elif draft is not None and remaining > 1:
+                    chunk.append(draft)
                 if gspan is not None:
                     gspan.stage("wave_enqueue")
                 rows = await self.wave.step_chunk(
@@ -1552,6 +1644,8 @@ class ContinuousBatchingHarness:
                 # host copy (``WaveDecoder.token_ids``).
                 with tracing.device_call("its.readback", gspan):
                     preds = self.wave.token_ids(rows)
+                    if self.drafts:
+                        drafted = self.wave.draft_ids(rows)
                 now = time.perf_counter()
                 if gspan is not None:
                     gspan.stage("token")
@@ -1565,8 +1659,15 @@ class ContinuousBatchingHarness:
                 self.spec_rounds += 1
                 self.spec_drafted += len(chunk) - 1
                 self.spec_accepted += n_acc - 1
+                self.spec_emitted += len(emitted)
+                accepted.append(n_acc - 1)
                 pos += n_acc
                 tok = emitted[-1]
+                if self.drafts:
+                    # The last accepted row's draft: what would follow ``tok``.
+                    draft = int(drafted[n_acc - 1])
+            if gspan is not None and drafting:
+                gspan.annotate(accepted=accepted)
             # Each round inserts its CHUNK's K/V; the final emitted token's
             # insert only happens as the next round's committed token. When
             # it completes a block (which the extended-chain save below
@@ -1648,6 +1749,12 @@ class ContinuousBatchingHarness:
             total_blocks = -(-(n_blocks * bt + gen_tokens) // bt)
             token_ids = landed = list(token_ids)[: n_blocks * bt]
             ok = n_blocks > 0
+        # What a hit may install of the prompt. A drafting layer's slot at
+        # position i is a function of token i + 1, so the LAST landed slot is
+        # a function of the prompt's last token, which no block's chain of
+        # hashes covers: where the landed prompt ends with a block, that
+        # block is computed (its resume rewrites the slot behind it too).
+        hit_limit = n_blocks - 1 if self.drafts and n_blocks * bt == len(landed) else n_blocks
         if not ok or total_blocks > self.max_req_blocks:
             raise ValueError(
                 f"prompt + generation must span 1..{self.max_req_blocks} "
@@ -1730,7 +1837,7 @@ class ContinuousBatchingHarness:
                 ):
                     fetch_kw["priority"] = PRIORITY_BACKGROUND
                 try:
-                    result = starter(token_ids, limit_blocks=n_blocks, **fetch_kw)
+                    result = starter(token_ids, limit_blocks=hit_limit, **fetch_kw)
                     prefetch = await result if starter_is_async else result
                 except StagingPoolExhausted as e:
                     # Admission backpressure: the staging arena is carrying a
@@ -1809,7 +1916,7 @@ class ContinuousBatchingHarness:
                     # One phase: the span holds the store fetch too.
                     with tracing.trace_op("install") as ispan:
                         self.caches, loaded_tokens = await self.adapter.load_kv(
-                            token_ids, self.caches, prompt_table
+                            token_ids, self.caches, prompt_table[:hit_limit]
                         )
                         # (after the store's read ops, which annotate the
                         # span they run under with one op's `blocks`)
@@ -1861,10 +1968,14 @@ class ContinuousBatchingHarness:
                             with tracing.use_span(cspan), tracing.device_call(
                                 "its.compute", cspan
                             ):
+                                # A model that drafts is handed the prompt's
+                                # last token too, as the operand of its
+                                # drafting layer's last landed slot.
+                                prompt = token_ids if self.drafts else landed
                                 if full:
-                                    self._prefill_full(landed, landed_table)
+                                    self._prefill_full(prompt, landed_table)
                                 else:
-                                    self._chunked_resume(landed, table, loaded_blocks)
+                                    self._chunked_resume(prompt, table, loaded_blocks)
 
                         await loop.run_in_executor(None, compute)
             prefix_ready_us = (time.perf_counter() - t0) * 1e6
@@ -2096,7 +2207,9 @@ class ContinuousBatchingHarness:
         ``p99_ttft_fg_us`` — time to first generated token, overall and
         FOREGROUND-class only); generation/speculation (``generated_tokens``,
         ``spec_tokens_per_step``, ``spec_acceptance_rate``,
-        ``spec_drafted_tokens``, ``spec_accepted_tokens``);
+        ``spec_drafted_tokens``, ``spec_accepted_tokens``, ``spec_rounds``,
+        ``spec_emitted_tokens``: running counts, a host drafter's or the
+        model's own);
         ``all_verified``; and, over a self-healing pool, ``store_health``.
         """
         total_blocks = sum(s.hit_blocks + s.computed_blocks for s in self.stats)
@@ -2255,6 +2368,8 @@ class ContinuousBatchingHarness:
             ),
             "spec_drafted_tokens": self.spec_drafted,
             "spec_accepted_tokens": self.spec_accepted,
+            "spec_rounds": self.spec_rounds,
+            "spec_emitted_tokens": self.spec_emitted,
             "all_verified": all(
                 s.verified for s in self.stats if s.verified is not None
             ),

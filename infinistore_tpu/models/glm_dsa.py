@@ -50,7 +50,7 @@ import numpy as np
 
 from ..tpu import dsa, mla
 from ..tpu.paged import CacheTensor, PagedKVCacheSpec
-from .layers import embed, head, layer_weights, mlp, rms
+from .layers import embed, head, layer_weights, mlp, rms, rotate_pairs
 from .layers import choices  # re-exported: this file's ``program.choices`` (benchmarks/configs/)
 from .serving import (
     ExpertTally, ServingSteps, chunk_index, prefill_by_blocks, real_rows, resume_step, wave_index,
@@ -212,38 +212,6 @@ def init_params(config: GlmDsaConfig, key: jax.Array) -> Params:
 # ---------------------------------------------------------------------------
 
 
-def _pair_swap(width: int, first: int, rope: int) -> np.ndarray:
-    """The signed permutation that takes each pair ``(a, b)`` of the ``rope``
-    values from ``first`` on to ``(-b, a)`` and everything else to nought."""
-    m = np.zeros((width, width), np.float32)
-    for i in range(first, first + rope, 2):
-        m[i + 1, i], m[i, i + 1] = -1.0, 1.0
-    return m
-
-
-def rotate(x, positions, first: int, config: GlmDsaConfig, dtype=None) -> jax.Array:
-    """x: [T, ..., width] float32, positions: [T]. The ``qk_rope_head_dim``
-    values from ``first`` on rotated as interleaved pairs, the rest as they
-    are: ``x cos + swap(x) sin`` with cosine one and sine nought outside the
-    rotated part, in float32, rounded once to ``dtype``. The pairs are swapped
-    by a product with a signed permutation, exact in any type: a slice at a
-    lane that is no multiple of 128 (192 of a head's 256) would be re-laid out
-    (``mellum.rotate``, PERF.md PR 50)."""
-    rope, width = config.qk_rope_head_dim, x.shape[-1]
-    inv_freq = (config.rope_theta ** (-np.arange(0, rope, 2) / rope)).astype(np.float32)
-    angles = positions[:, None].astype(jnp.float32) * jnp.asarray(inv_freq)
-    pad = ((0, 0), (first, width - first - rope))
-    cos = jnp.pad(jnp.repeat(jnp.cos(angles), 2, axis=-1), pad, constant_values=1.0)
-    sin = jnp.pad(jnp.repeat(jnp.sin(angles), 2, axis=-1), pad)
-    shape = (x.shape[0],) + (1,) * (x.ndim - 2) + (width,)
-    swapped = jnp.dot(
-        x, jnp.asarray(_pair_swap(width, first, rope), x.dtype),
-        precision=jax.lax.Precision.HIGHEST,
-    )
-    out = x.astype(jnp.float32) * cos.reshape(shape) + swapped.astype(jnp.float32) * sin.reshape(shape)
-    return out.astype(dtype or x.dtype)
-
-
 def _mixer_inputs(w: Params, n, positions, config: GlmDsaConfig):
     """n: [T, dim], the normed input. Returns q [T, H, nope + rope] (rotated),
     the latent cache's row [T, rank + rope] (the normed latent beside the
@@ -256,15 +224,15 @@ def _mixer_inputs(w: Params, n, positions, config: GlmDsaConfig):
     kva = mla.einsum_f32("td,dr->tr", n, w["w_kva"])
     c = rms(kva[:, :r], w["kv_norm"], config.rms_eps, dt)
     with jax.named_scope("mla_rope"):
-        q = rotate(q, positions, nope, config, dt)
-        k_r = rotate(kva[:, r:], positions, 0, config, dt)
+        q = rotate_pairs(q, positions, nope, config, dt)
+        k_r = rotate_pairs(kva[:, r:], positions, 0, config, dt)
     with jax.named_scope("dsa_index"):
-        q_i = rotate(mla.einsum_f32("tr,rhk->thk", c_q, w["wi_q"]), positions, 0, config, dt)
+        q_i = rotate_pairs(mla.einsum_f32("tr,rhk->thk", c_q, w["wi_q"]), positions, 0, config, dt)
         k = mla.einsum_f32("td,dk->tk", n, w["wi_k"])
         k = k - jnp.mean(k, axis=-1, keepdims=True)
         k = k * jax.lax.rsqrt(jnp.mean(k * k, axis=-1, keepdims=True) + INDEX_NORM_EPS)
         k = k * w["wi_k_norm"].astype(f32) + w["wi_k_bias"].astype(f32)
-        k_i = rotate(k, positions, 0, config, dt)
+        k_i = rotate_pairs(k, positions, 0, config, dt)
         w_i = mla.einsum_f32("td,dh->th", n, w["wi_w"]) * np.float32(
             (config.index_heads * config.index_head_dim) ** -0.5
         )

@@ -1,9 +1,9 @@
 """The mathematics two or more model files share: a leaf module.
 
 A model file (``llama``, ``afmoe``, ``kimi_linear``, ``falcon_h1``,
-``granite_hybrid``, ``mellum``, ``glm_dsa``, ``sambay``) imports this module,
+``granite_hybrid``, ``mellum``, ``glm_dsa``, ``sambay``, ``pangu_mtp``) imports this module,
 ``serving`` and ``..tpu``, and no other model file. What only ONE file uses
-stays in that file (``mellum.rotate``, ``glm_dsa.rotate``, ``llama._rms_norm``,
+stays in that file (``mellum.rotate``, ``llama._rms_norm``,
 every ``_attn_inputs`` / ``_qkv`` / ``_ssm_inputs``); what two use is here,
 written once. This module imports ``jax``, ``numpy`` and ``..tpu`` only.
 """
@@ -52,6 +52,45 @@ def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
+
+
+# ---------------------------------------------------------------------------
+# The rotation of a latent mixer's positional values (``glm_dsa`` and
+# ``pangu_mtp``: the DeepSeek-V3 lineage's interleaved pairs).
+# ---------------------------------------------------------------------------
+
+
+def _pair_swap(width: int, first: int, rope: int) -> np.ndarray:
+    """The signed permutation that takes each pair ``(a, b)`` of the ``rope``
+    values from ``first`` on to ``(-b, a)`` and everything else to nought."""
+    m = np.zeros((width, width), np.float32)
+    for i in range(first, first + rope, 2):
+        m[i + 1, i], m[i, i + 1] = -1.0, 1.0
+    return m
+
+
+def rotate_pairs(x, positions, first: int, config, dtype=None) -> jax.Array:
+    """x: [T, ..., width] float32, positions: [T]. The ``qk_rope_head_dim``
+    values from ``first`` on rotated as interleaved pairs, the rest as they
+    are: ``x cos + swap(x) sin`` with cosine one and sine nought outside the
+    rotated part, in float32, rounded once to ``dtype``. The pairs are swapped
+    by a product with a signed permutation, exact in any type: a slice at a
+    lane that is no multiple of 128 (192 of a head's 256) would be re-laid out
+    (``mellum.rotate``, PERF.md PR 50). Of ``config``: ``qk_rope_head_dim``
+    and ``rope_theta``."""
+    rope, width = config.qk_rope_head_dim, x.shape[-1]
+    inv_freq = (config.rope_theta ** (-np.arange(0, rope, 2) / rope)).astype(np.float32)
+    angles = positions[:, None].astype(jnp.float32) * jnp.asarray(inv_freq)
+    pad = ((0, 0), (first, width - first - rope))
+    cos = jnp.pad(jnp.repeat(jnp.cos(angles), 2, axis=-1), pad, constant_values=1.0)
+    sin = jnp.pad(jnp.repeat(jnp.sin(angles), 2, axis=-1), pad)
+    shape = (x.shape[0],) + (1,) * (x.ndim - 2) + (width,)
+    swapped = jnp.dot(
+        x, jnp.asarray(_pair_swap(width, first, rope), x.dtype),
+        precision=jax.lax.Precision.HIGHEST,
+    )
+    out = x.astype(jnp.float32) * cos.reshape(shape) + swapped.astype(jnp.float32) * sin.reshape(shape)
+    return out.astype(dtype or x.dtype)
 
 
 # ---------------------------------------------------------------------------

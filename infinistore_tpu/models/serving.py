@@ -1,4 +1,5 @@
-"""What the serving engine asks of a model file: three steps, by role.
+"""What the serving engine asks of a model file: three steps, by role, and
+whether the model DRAFTS.
 
 ``engine.py`` names no model file. A configuration object carries ``steps``
 (a :class:`ServingSteps`) beside ``block_tokens`` and ``kv_spec(blocks)``, and
@@ -40,6 +41,32 @@ caches[, aux])``
     ``window_pages``. A body may return a third value, ``aux``: ``{"rows":
     array [T, ...], "counters": {name: scalar}}``, both still on the device.
 
+**A model that DRAFTS** says so (``ServingSteps.drafts``: a role, no model's
+name), and its three steps do a little more:
+    its ``wave`` body samples each row's id itself (the wave program's own
+    ``argmax``), runs its drafting layer on the rows and those ids, writes
+    that layer's cache at the rows' positions, and returns under
+    ``aux["drafts"]`` ``[T]`` int32 what it PROPOSES for each row's token
+    after next (row t's is the token that would follow ``ids[t]``). Its
+    ``aux["rows"]`` carry them too, closed by ``[ids[t], drafts[t], 0, ...]``
+    (the committed and the drafted id, which a reference follows like a
+    router's set). The engine then sends each request's next chunk as
+    ``[token, draft]`` and accepts the draft where the wave's own argmax
+    confirms it (``engine.py`` ``_generate``). **Which caches take a
+    drafter**: a latent or K/V cache, served by blocks or not (a slot a
+    rejected row wrote is overwritten by the next round's first row); not one
+    that holds a recurrent STATE (``PagedKVCacheSpec.has_state``: a rejected
+    row would stay absorbed). A cache tensor of kind ``"state"`` that is a
+    block's checkpoint and no recurrence (``CacheTensor.recurrent`` false: the
+    hidden row at a block's last position, from which a hit rewrites the
+    drafting layer's one slot that depends on the token AFTER the block) is a
+    slot in this sense.
+
+    A drafting model's ``resume`` takes one more operand, ``next_token`` (``[]``
+    int32, by keyword): the token that follows the piece, the next piece's
+    first or the prompt's last, which the drafting layer's slot at the piece's
+    last position is a function of.
+
 The engine does not call ``wave`` itself. Every decode wave it launches is ONE
 program, :func:`verify_step_ragged` below, whose traffic with the host is one
 array each way:
@@ -58,7 +85,9 @@ layout) -> (logits, caches, ids, feed, aux)``
     (``WaveDecoder.token_ids`` reads the ids back once a wave,
     ``WaveDecoder.row_aux`` gives a request its slice); it adds
     ``aux["counters"]`` up by name into ``harness.metrics()`` and reads
-    neither.
+    neither. Of a model that drafts the ids are ``[2, T]``, the sampled ids
+    over the body's ``aux["drafts"]`` (``WaveDecoder.draft_ids`` reads the
+    second row of the same host copy).
 
     **A row may take its token from the device.** ``prev_ids`` is an earlier
     wave's ``feed``: its first :data:`FEED_ROWS` ids, padded to that many, so
@@ -139,41 +168,52 @@ class ServingSteps(NamedTuple):
     # token in the first wave: what it does for a cache with a recurrent
     # state, whose models' chunks lie in one block for the state's sake.
     resume_in_block: bool = False
+    # Whether the model DRAFTS: its wave body proposes each row's token after
+    # next (module docstring, "a model that drafts").
+    drafts: bool = False
 
 
 def resume_step(resume_chunk: Callable) -> Callable:
     """A model file's ``prefill_continue`` over its jitted ``resume_chunk``:
     ``resume``'s signature (module docstring), the table held to the static
-    ``max_blocks`` the harness pads every table to."""
+    ``max_blocks`` the harness pads every table to. What a model's chunk
+    takes beside (a drafting model's ``next_token``) passes through by keyword."""
 
-    def prefill_continue(params, tokens, start_pos, caches, block_table, config, max_blocks):
+    def prefill_continue(params, tokens, start_pos, caches, block_table, config, max_blocks,
+                         **operands):
         if block_table.shape[0] != max_blocks:
             raise ValueError(
                 f"block_table has {block_table.shape[0]} entries, expected "
                 f"max_blocks={max_blocks} (pad the table to the static bound)"
             )
-        return resume_chunk(params, tokens, start_pos, caches, block_table, config)
+        return resume_chunk(params, tokens, start_pos, caches, block_table, config, **operands)
 
     return prefill_continue
 
 
-def prefill_by_blocks(resume_chunk: Callable) -> Callable:
+def prefill_by_blocks(resume_chunk: Callable, next_token: bool = False) -> Callable:
     """A model file's ``prefill`` where a chunk lies inside one block: a miss,
     every token given, cut at block boundaries through the chunk program a
     hit's resume runs, so that each block's slot holds what stands at its end
     (a state, a tail) and a full hit's first token equals the miss's to the
     bit. ``block_table`` covers the tokens (a last block may be part full).
-    Returns (the last row's logits, or None where the chunk has none,
-    caches); ``caches`` is donated."""
+    With ``next_token`` (a drafting model's chunk) each piece is handed the
+    token that follows it, the last piece its own last token (the engine,
+    which lands a prompt's last token in a wave, hands in the tokens before it
+    and names the real one). Returns (the last row's logits, or None where the
+    chunk has none, caches); ``caches`` is donated."""
 
     def prefill(params, tokens, caches, block_table, config):
         bt = config.block_tokens
         tokens = jnp.asarray(tokens, jnp.int32)
         table = jnp.asarray(block_table, jnp.int32)
         logits = None
+        last = tokens.shape[0] - 1
         for start in range(0, tokens.shape[0], bt):
+            after = {"next_token": tokens[min(start + bt, last)]} if next_token else {}
             logits, caches = resume_chunk(
-                params, tokens[start : start + bt], jnp.int32(start), caches, table, config
+                params, tokens[start : start + bt], jnp.int32(start), caches, table, config,
+                **after,
             )
         return None if logits is None else logits[-1], caches
 
@@ -362,4 +402,12 @@ def verify_step_ragged(
     ids = jax.lax.argmax(logits, 1, jnp.int32)  # what jnp.argmax(logits, -1) computes
     short = max(FEED_ROWS - layout.rows, 0)
     feed = jax.lax.pad(ids[:FEED_ROWS], jnp.int32(0), [(0, short, 0)])
-    return logits, caches, ids, feed, aux[0] if aux else {}
+    aux = dict(aux[0]) if aux else {}
+    drafts = aux.pop("drafts", None)
+    if config.steps.drafts:
+        # The ids over the body's drafts, ONE array: a round's token and its
+        # draft reach the host in one read (each blocking read costs the host
+        # about as much as a dispatch).
+        row = lambda a: jax.lax.expand_dims(a, (0,))
+        ids = jax.lax.concatenate([row(ids), row(drafts)], 0)
+    return logits, caches, ids, feed, aux

@@ -43,6 +43,12 @@ class CacheTensor:
     # index keys; all three grow with the prefix) or "state" (a recurrent
     # layer's state and its convolution tail).
     kind: str = "kv"
+    # Of a "state": whether it ABSORBS its tokens, each once (a recurrence's
+    # state, a convolution's tail). False: a block's checkpoint that a token
+    # at the same position rewrites whole, as it does a K/V slot (the hidden
+    # row at a block's last position that a drafting layer's hit resumes
+    # from): counted as state, served as a slot.
+    recurrent: bool = True
 
     @functools.cached_property
     def nbytes(self) -> int:
@@ -141,7 +147,7 @@ class PagedKVCacheSpec:
         is the state at the block's end, so a token is absorbed ONCE (the
         engine lands a prompt's last token in the first wave alone)."""
         return self.layers is not None and any(
-            t.kind == "state" for tensors in self.layers for t in tensors
+            t.kind == "state" and t.recurrent for tensors in self.layers for t in tensors
         )
 
     @property

@@ -642,11 +642,12 @@ def _package_imports(module: str):
     return found
 
 
-# The eight model files that serve a cell. ``long_context``, ``pipeline``,
+# The nine model files that serve a cell. ``long_context``, ``pipeline``,
 # ``ring_attention`` and ``ulysses`` are not in the list: they serve nothing and
 # go with ROADMAP D13.
 MODEL_FILES = [
     "llama", "afmoe", "kimi_linear", "falcon_h1", "granite_hybrid", "mellum", "glm_dsa", "sambay",
+    "pangu_mtp",
 ]
 
 

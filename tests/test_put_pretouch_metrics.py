@@ -20,7 +20,7 @@ REUSE_CELLS = [
     "mistral7b-prefix-reuse", "deepseek7b-prefix-reuse", "trinity-mini-long-prefix-reuse",
     "kimi-linear-long-prefix-reuse", "falcon-h1-long-prefix-reuse",
     "granite-h-small-long-prefix-reuse", "mellum2-completion-prefix-reuse",
-    "glm5-long-prefix-reuse", "phi4-flash-long-prefix-reuse",
+    "glm5-long-prefix-reuse", "phi4-flash-long-prefix-reuse", "openpangu-mtp-long-prefix-reuse",
 ]
 METRICS = {
     "save_put_gbps": ("GB/s", "save_put_bytes", "save_put_busy_us"),

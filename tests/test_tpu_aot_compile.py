@@ -875,6 +875,110 @@ def test_glm_dsa_entries_compile_at_published_widths_and_update_both_cache_tenso
     assert want <= kernels, kernels
 
 
+# The ninth model file's serving entries (models/pangu_mtp.py) at the published
+# widths of its configuration's file: hidden 7,680, 128 heads over a query
+# latent of 1,536 and a latent cache of 512 + 64, 8 of 256 experts of width
+# 2,048, pages of 1,024 tokens, a table of 33 blocks; one dense and one expert
+# layer, the MTP layer and a small vocabulary. The wave is ONE program, the
+# main stack and the drafter behind it, in the buckets the decoder launches
+# for one request's chunk of two and for three's (8 rows, 4 tables).
+PANGU_ENTRIES = ["packed_wave", "packed_wave_one_entry", "miss-piece", "hit-question"]
+
+
+def test_the_boundary_row_passes_the_block_copy_kernels(v5e):
+    """A save's gather and an install's scatter take the MTP layer's boundary
+    rows as the cache keeps them, folded to 128 lanes (``[blocks, 60, 128]``
+    at a hidden size of 7,680): a block whose last two axes are whole. The
+    row unfolded, ``[blocks, 7680]``, is refused by Mosaic (a block of ONE
+    row of 160), which the cell's first chip run found (PERF.md, PR 62)."""
+    from infinistore_tpu.models import pangu_mtp
+
+    cfg = pangu_mtp.PanguMtpConfig(dim=7680, dtype=jnp.bfloat16)
+    assert cfg.boundary_shape == (60, 128)
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=v5e)
+    cache, ids = s((160, *cfg.boundary_shape), jnp.bfloat16), s((8,), jnp.int32)
+    _compile(paged._gather_blocks_pallas, cache, ids, interpret=False)
+    exe = _compile(
+        paged._scatter_blocks_pallas, cache, ids, s((8, *cfg.boundary_shape), jnp.bfloat16),
+        interpret=False,
+    )
+    assert exe.memory_analysis().alias_size_in_bytes == 160 * 7680 * 2
+    with pytest.raises(Exception, match="divisible by 8 and 128"):
+        _compile(paged._gather_blocks_pallas, s((160, 7680), jnp.bfloat16), ids, interpret=False)
+
+
+@pytest.mark.parametrize("entry", PANGU_ENTRIES)
+def test_pangu_mtp_entries_compile_at_published_widths_and_update_every_cache_tensor_in_place(v5e, monkeypatch, entry):
+    """Each entry compiles for the v5e with its Mosaic kernels (the latent
+    decode and the wave's expert product; a piece's latent attention and
+    grouped matmul), holds an ``input_output_alias`` for every cache tensor
+    (the main layers' latents, the MTP layer's and its boundary rows: the
+    aliased bytes the whole cache's), and moves no array of a latent cache's
+    shape through a ``copy``, ``copy-start`` or ``slice-start``. The wave runs
+    one latent decode a main layer and ONE more for the drafter, and hands
+    back the ids over the drafts as one array; a prompt piece runs no kernel
+    for the MTP layer at all (it writes slots)."""
+    from infinistore_tpu.models import pangu_mtp, serving
+
+    monkeypatch.setattr(paged, "_use_pallas", lambda: True)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(repo, "benchmarks", "configs", "openpangu-ultra-moe-718b.json")) as f:
+        real = json.load(f)
+    fields = {k: real[v] for k, v in real["program"]["fields"].items()}
+    fields.update(vocab=1031, n_layers=2)
+    cfg = pangu_mtp.PanguMtpConfig(
+        block_tokens=real["serving"]["block_tokens"], dtype=jnp.bfloat16, **fields
+    )
+    assert (cfg.dim, cfg.n_heads, cfg.q_lora_rank, cfg.latent_width, cfg.held) == (7680, 128, 1536, 576, (0, 8))
+    s = functools.partial(jax.ShapeDtypeStruct, sharding=v5e)
+    i32 = lambda *shape: s(shape, jnp.int32)
+    shapes = jax.eval_shape(lambda k: pangu_mtp.init_params(cfg, k), jax.random.key(0))
+    params = jax.tree.map(lambda a: s(a.shape, a.dtype), shapes)
+    blocks, table = real["serving"]["cache_blocks"], 33
+    spec = cfg.kv_spec(blocks)
+    caches = [
+        tuple(s((blocks, *t.block_shape), t.dtype) for t in spec.layer_tensors(layer))
+        for layer in range(spec.num_layers)
+    ]
+    tensors = cfg.n_layers + 2
+    wave = entry.startswith("packed_wave")
+    if wave:
+        layout = (
+            serving.WaveLayout(rows=8, tables=4, pages=256) if entry == "packed_wave"
+            else serving.WaveLayout(rows=2, tables=1, pages=64)
+        )
+        args = (params, i32(layout.size(table)), i32(serving.FEED_ROWS), caches)
+        jitted = serving.verify_step_ragged
+        static = {"config": cfg, "max_blocks": table, "layout": layout}
+    else:
+        rows = 1024 if entry == "miss-piece" else 127
+        args = (params, i32(rows), i32(), caches, i32(table))
+        jitted, static = pangu_mtp.resume_chunk, {"config": cfg, "next_token": i32()}
+    lowered = jitted.trace(*args, **static).lower(lowering_platforms=("tpu",))
+    kernels = set(re.findall(r'kernel_name = "(\w+)"', lowered.as_text()))
+    exe = lowered.compile()
+    text = exe.as_text()
+    header = text.split("\n", 1)[0]
+    assert len(re.findall(r"\(\d+, \{\}, (?:may|must)-alias\)", header)) == tensors, header
+    whole = blocks * ((cfg.n_layers + 1) * 1152 * 1024 + cfg.dim * 2)
+    assert exe.memory_analysis().alias_size_in_bytes == whole
+    moved = re.findall(
+        rf"^.* = [^=]*bf16\[{blocks},576,1024\][^=]* (?:copy|copy-start|slice-start)\(.*$",
+        text, flags=re.M,
+    )
+    moved = [m for m in moved if "S(1)}" not in re.split(r" (?:copy|copy-start|slice-start)\(", m)[0]]
+    assert not moved, moved[:3]
+    if wave:
+        assert kernels == {"_decode_kernel", "_moe_wave_kernel"}, kernels
+        assert len(re.findall(r"^\s*%?[\w.\-]*mla_decode_pallas[\w.\-]* = .*custom-call\(", text, flags=re.M)) == cfg.n_layers + 1
+        ids = jax.eval_shape(functools.partial(jitted, **static), *args)[2]
+        assert (ids.shape, ids.dtype) == ((2, layout.rows), jnp.int32)
+    else:
+        assert "_chunk_kernel" in kernels and kernels - {"_chunk_kernel"}, kernels  # + the grouped matmul's
+        assert text.count("mla_chunk_attention_pallas") >= cfg.n_layers
+        assert not _page_loops(text, cfg.n_heads, rows, cfg.v_head_dim)
+
+
 # The third model file's chunk program (models/kimi_linear.py) at the published
 # widths of its configuration's file, one KDA and one latent layer and a small
 # vocabulary: the same kernel at 32 heads of 128 / 64 / 128 and NO bias.

@@ -99,6 +99,7 @@ SURFACE = [
     ("infinistore_tpu.tpu.ici", None),
     ("infinistore_tpu.shaping", None),
     ("infinistore_tpu.models", None),
+    ("infinistore_tpu.models.serving", None),
     ("infinistore_tpu.models.pipeline", None),
     ("infinistore_tpu.models.ring_attention", None),
     ("infinistore_tpu.models.long_context", None),

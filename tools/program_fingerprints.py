@@ -15,7 +15,9 @@ launch,
   served a block at a time) and at a 128-token question, over a table of the
   cell's longest request;
 - ``serving.verify_step_ragged``, the packed wave, at a one-row, a three-row
-  (a bucket of four) and the widest layout the cell's clients make,
+  (a bucket of four) and the widest layout the cell's clients make (of a model
+  that drafts, ``config.steps.drafts``: entries of two rows each; its chunk
+  takes ``next_token``),
 
 one JSON line ``{"row": "<configuration>/<program>/<shape>", "lowered": sha}``:
 the SHA-256 of ``jitted.trace(...).lower(lowering_platforms=("tpu",))
@@ -130,6 +132,8 @@ def programs(tree: str, names):
         ]
         bt = cfg.block_tokens
         by_blocks = spec.has_state or cfg.steps.resume_in_block
+        drafts = getattr(cfg.steps, "drafts", False)  # a tree before PR 62 has no such role
+        width = 2 if drafts else 1
         seen = set()
         for cell in bench["workloads"]:
             if cell["config"] != name:
@@ -147,18 +151,19 @@ def programs(tree: str, names):
             for tokens in sorted({bt, QUESTION_TOKENS} if by_blocks else {QUESTION_TOKENS}):
                 found.append((
                     f"resume_chunk/s{tokens}.mb{mb}", module.resume_chunk,
-                    (params, i32(tokens), i32(), caches, i32(mb)), {"config": cfg},
+                    (params, i32(tokens), i32(), caches, i32(mb)),
+                    {"config": cfg, **({"next_token": i32()} if drafts else {})},
                 ))
             for rows in sorted({1, 4, pow2(plan.clients)}):
                 pages = pow2(rows * mb)
                 window = None
                 if spec.window is not None:
                     window = min(pages, rows * (spec.window // bt + 1))
-                layout = serving.WaveLayout(rows, rows, pages, window)
+                layout = serving.WaveLayout(width * rows, rows, width * pages, window)
+                static = {"config": cfg, "max_blocks": mb, "layout": layout}
                 found.append((
                     f"verify_step_ragged/T{rows}.P{pages}.mb{mb}", serving.verify_step_ragged,
-                    (params, i32(layout.size(mb)), i32(serving.FEED_ROWS), caches),
-                    {"config": cfg, "max_blocks": mb, "layout": layout},
+                    (params, i32(layout.size(mb)), i32(serving.FEED_ROWS), caches), static,
                 ))
             for shape, jitted, args, static in found:
                 row = f"{name}/{shape}"
