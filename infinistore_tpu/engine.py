@@ -400,24 +400,35 @@ class WaveRows:
 
 
 class _Ahead(NamedTuple):
-    """A stream's row in a launched wave that its request has not been handed."""
+    """A stream's slot in a launched wave that its request has not been handed."""
 
-    position: int
-    # Its logits rows [1, vocab], as ``step_chunk`` will resolve to them; they
-    # know the wave it rode (``out``) and its flat row there (``off``: what
+    position: int  # of the slot's first row
+    # Its logits rows, as ``step_chunk`` will resolve to them; they know the
+    # wave it rode (``out``) and its first flat row there (``off``: what
     # ``fed_token`` names).
     rows: WaveRows
+    # Of a model that drafts, the verdict on the round before that this slot was
+    # launched under (its draft accepted: the slot starts two positions on, not
+    # one); None where the slot is the call's own, its tokens the host's.
+    accepted: Optional[bool] = None
 
 
 class _Stream:
-    """A request's declared run of one-token rounds (``WaveDecoder.stream``)."""
+    """A request's declared run of rounds (``WaveDecoder.stream``)."""
 
-    __slots__ = ("table", "left", "ahead")
+    __slots__ = ("table", "last", "ahead", "guess", "rounds", "accepted")
 
-    def __init__(self, table, rounds: int):
+    def __init__(self, table, last: int):
         self.table = table  # the padded table its request hands in every round
-        self.left = rounds  # ``step_chunk`` calls its request has yet to make
+        self.last = last  # no slot launched ahead writes a position past it
         self.ahead: Optional[_Ahead] = None
+        # The verdict its slot in the wave being launched goes out under, as
+        # ``_Ahead.accepted`` will keep it.
+        self.guess: Optional[bool] = None
+        # Of a model that drafts: the rounds whose verdict the decoder has seen
+        # (a call that came back where a slot was launched for it, or where the
+        # other verdict puts it), and how many of them accepted their draft.
+        self.rounds = self.accepted = 0
 
 
 class _Wave(NamedTuple):
@@ -489,8 +500,8 @@ class WaveDecoder:
     **And one wave ahead.** The token a round samples is four bytes that are
     on the device when its wave ends, and all that the next wave wants of
     them is to read them as its ``tokens``. A request that will come back
-    says so (``stream``: so many one-token rounds, always with this table;
-    ``_generate`` does, unless a drafter writes its chunks), and the decoder
+    says so (``stream``: round after round with this table, up to a last
+    position; ``_generate`` does, unless a host drafter writes its chunks), and the decoder
     then launches the row of round k + 1 while the request still reads round
     k's token: the row's token slot names its row of the wave before it
     (``serving.fed_token``) and the program reads the id out of that wave's
@@ -508,11 +519,12 @@ class WaveDecoder:
     is taken again by the next flush (started at once) as if it had been
     launched ahead. Rows fed in one wave all read the wave launched LAST; a
     stream whose row rode an older one comes back through the host once.
-    Nothing is launched for a call that will not come (a stream's last round,
-    a stream that ended), so no row is wasted and no recurrent state advances
+    Nothing is launched for a call that will not come (a row past the stream's
+    declared last position, a stream that ended), so no row is wasted and no
+    recurrent state advances
     unasked. **When it does not engage**, by what the flush finds and by no
     option: a bare ``step`` / ``step_chunk`` (no stream: a test, a warm-up, a
-    drafter's chunk); a row past ``FEED_ROWS``; ``harness.arriving`` non-zero
+    host drafter's chunk); a row past ``FEED_ROWS``; ``harness.arriving`` non-zero
     (a request between its admission and its first wave will ask for the
     device, and a wave queued ahead of its need would stand in front of its
     install, prefill or snapshot by up to a step); the device gate not idle
@@ -532,8 +544,38 @@ class WaveDecoder:
     buckets the drafting requests land on. The wave's program hands back the
     ids and the drafts as ONE array: ``token_ids`` and ``draft_ids`` read its
     two rows, one blocking read a wave.
-    Such a model's requests declare no stream: a round's chunk holds a draft
-    the host reads first, so no row of theirs is launched ahead.
+
+    Its requests declare their streams too, and what is launched ahead is the
+    stream's next SLOT. Round k is ``[token, draft]`` at ``[p, p + 1]``, flat
+    rows ``r`` and ``r + 1`` of its wave, whose program returns ``ids`` and
+    ``drafts`` a row; the next slot has two forms, both at positions the host
+    knows before the wave has run: the draft REJECTED, ``[ids[r], drafts[r]]``
+    at ``[p + 1, p + 2]``; the draft ACCEPTED, ``[ids[r + 1], drafts[r + 1]]``
+    at ``[p + 2, p + 3]``. Which one holds the device knows when the wave
+    ends and the host only after its read, so the wave's ``feed`` carries the
+    drafts behind the ids (``serving.feed_rows``; ``fed_token(src, True)`` is
+    row ``src``'s draft) and the flush launches ONE of the two, by the
+    likelier verdict (``_guess``: drafts accepted over drafts made, the
+    stream's own count once it has ``OWN_RECORD_ROUNDS`` rounds, before that
+    the harness's ``spec_accepted`` over ``spec_drafted``; rejected while
+    nothing is known; a slot of one token has no draft and one form). A request
+    that comes back where the slot was launched is handed its rows as a
+    width-1 stream is. One that comes back where the OTHER verdict puts it has
+    its slot dropped (``ahead_dropped``, ``wave_ahead_dropped``) and rides this
+    flush's wave with its tokens from the host, as a stream that fell out of the
+    wave train does: one round through the host and nothing else. What the
+    dropped slot wrote is overwritten by the real slot's rows before anything
+    reads it, or lies past the request's committed end under the position
+    mask, as a rejected draft's row always did: guessed rejected, accepted in
+    fact, ``p + 1`` holds the token that was there (the accepted draft IS
+    ``ids[r]``) and ``p + 2`` is the real slot's first row; guessed accepted,
+    rejected in fact, the real slot rewrites ``p + 1`` and ``p + 2`` and
+    ``p + 3`` waits for a real row, which comes, since no slot is launched past
+    the stream's ``last``. That holds for the drafting layer's own slots and
+    boundary rows (each a function of its own row) and is why a cache with a
+    recurrent state takes no drafter. Its one-token calls (a first round, a
+    last one, the closing step) lie outside ``last`` or before any slot: the
+    host launches them, as it launched every call before.
 
     ``bucket_sizes`` records the distinct (B, T, P) buckets — table rows,
     flat token rows, flat attention pages, the last two in SLOTS (of one row,
@@ -609,8 +651,11 @@ class WaveDecoder:
         # its place, and the launched waves that had a fed row.
         self._streams: Dict[int, _Stream] = {}
         self._last: Optional[_WaveOut] = None
-        self._no_feed = no_feed()
+        self._no_feed = no_feed(harness.config.steps.drafts)
         self.waves_ahead = 0
+        # Slots launched ahead under a verdict that did not hold: their calls
+        # came back elsewhere and went through the host once.
+        self.ahead_dropped = 0
 
     async def step(self, token: int, position: int, padded_table) -> jax.Array:
         """Advance this request by one token; returns its logits row (read,
@@ -639,9 +684,6 @@ class WaveDecoder:
                 f"its draft), got {len(tokens)}"
             )
         fut = asyncio.get_running_loop().create_future()
-        stream = self._streams.get(id(padded_table))
-        if stream is not None:
-            stream.left -= 1
         # A queue entry: (tokens, positions, table, future).
         self._enqueue([(list(tokens), list(positions), padded_table, fut)])
         return await fut
@@ -655,22 +697,30 @@ class WaveDecoder:
             task.add_done_callback(self._flush_tasks.discard)
 
     @contextlib.contextmanager
-    def stream(self, padded_table, rounds: int):
+    def stream(self, padded_table, last: int):
         """A request's way of saying that it will come back: inside the block
-        it calls ``step_chunk`` ``rounds`` times, one token a call at
-        consecutive positions, every time with ``padded_table`` (the object:
-        the decoder knows the stream by it) and, from its second call on, with
-        the token its last call's row sampled. The decoder may then launch the
-        row of call k + 1 while the request still reads call k's token (class
-        docstring, "one wave ahead"); a call that does not fit what was
-        launched for it is an error. Leaving the block ends the stream: no row
-        of it is launched afterwards."""
-        stream = _Stream(padded_table, rounds)
+        it calls ``step_chunk`` round after round, every time with
+        ``padded_table`` (the object: the decoder knows the stream by it), each
+        call a slot that follows from the rows of the call before. A slot is
+        one token at the next position, the token that call's row sampled; of
+        a model that drafts it is ``[token, draft]`` as one of that call's rows
+        sampled and drafted them, one position on (its draft rejected) or two
+        (accepted). ``last`` is the last position such a slot will write: what
+        a drafting request brings as one token (its first round, its last, a
+        closing step) is the host's to launch. The decoder may then launch the
+        slot of call k + 1 while the request still reads call k's rows (class
+        docstring, "one wave ahead"). A call that fits neither the slot launched
+        for it nor, under a drafting model, the slot the other verdict gives is
+        an error. Leaving the block ends the stream: nothing of it is launched
+        afterwards."""
+        if last >= len(padded_table) * self.h.config.block_tokens:
+            raise ValueError(f"position {last} lies past a table of {len(padded_table)} blocks")
+        stream = _Stream(padded_table, last)
         self._streams[id(padded_table)] = stream
         try:
             yield
         finally:
-            stream.left, stream.ahead = 0, None
+            stream.last, stream.ahead = -1, None
             del self._streams[id(padded_table)]
 
     # -- what the wave program returned beside its logits ---------------------
@@ -836,22 +886,39 @@ class WaveDecoder:
         ids.copy_to_host_async()
         return logits, _WaveOut(ids, feed, aux.get("rows")), aux
 
+    # Rounds of its own a drafting stream's record needs before the guess of
+    # its next verdict follows it and not the harness's.
+    OWN_RECORD_ROUNDS = 8
+
+    def _guess(self, stream: _Stream) -> bool:
+        """Whether the draft a stream's next call brings will be accepted, by
+        the likelier verdict so far: the stream's own once it has a few rounds,
+        before that every request's (the harness's count of drafts accepted
+        over drafts made); rejected while nothing is known."""
+        if stream.rounds >= self.OWN_RECORD_ROUNDS:
+            return 2 * stream.accepted > stream.rounds
+        return 2 * self.h.spec_accepted > self.h.spec_drafted
+
     def _sort(self, batch: List[tuple]):
         """The taken ``batch`` by what each entry wants of this flush.
 
-        ``taken``: ``(future, rows)`` of the calls whose row was launched
+        ``taken``: ``(future, rows)`` of the calls whose slot was launched
         ahead of them; they resolve to those rows once this flush's wave is
         on the device. ``launched``: the entries that ride this flush's wave:
-        the calls that have no row yet, as they came, then, where
-        ``ahead_ok``, one ``fed`` entry (no future, its token a
-        ``fed_token``) for each taken call whose stream goes on and whose row
-        rode the wave launched last. ``ahead_ok``: the engage rule (class
-        docstring), asked once a flush, and only where a stream is in it."""
+        the calls that have no slot yet (a drafting stream's call whose slot
+        was launched under the other verdict among them: that slot is dropped),
+        as they came, then, where ``ahead_ok``, one ``fed`` entry (no future,
+        its tokens ``fed_token``s) for each taken call whose stream goes on and
+        whose slot rode the wave launched last; the stream keeps the verdict it
+        goes out under (``guess``: None where a slot is one row and there is
+        nothing to guess). ``ahead_ok``: the engage rule (class docstring),
+        asked once a flush, and only where a stream is in it."""
         ahead_ok = (
             any(id(table) in self._streams for _, _, table, _ in batch)
             and self.h.arriving == 0
             and self.h.gate.idle
         )
+        width = self.width
         taken, launched, fed = [], [], []
         for entry in batch:
             toks, pos, table, fut = entry
@@ -862,18 +929,42 @@ class WaveDecoder:
                 launched.append(entry)
                 continue
             ahead, stream.ahead = stream.ahead, None
-            if len(toks) != 1 or pos[0] != ahead.position:
+            fits, guessed = len(toks) == ahead.rows.n and pos[0] == ahead.position, ahead.accepted
+            if not fits and (
+                guessed is None or len(toks) > width
+                or pos[0] != ahead.position + (-1 if guessed else 1)
+            ):
                 fut.set_exception(RuntimeError(
                     f"a declared stream came back with {len(toks)} token(s) at position "
-                    f"{pos[0]}: its row was launched for one token at {ahead.position}"
+                    f"{pos[0]}: its slot was launched for {ahead.rows.n} at {ahead.position}"
                 ))
                 continue
+            if guessed is not None:
+                # The call says which verdict held: the one guessed, or the other.
+                stream.rounds += 1
+                stream.accepted += guessed == fits
+            if not fits:
+                # The other verdict: through the host once, over what that slot wrote.
+                self.ahead_dropped += 1
+                launched.append(entry)
+                continue
             taken.append((fut, ahead.rows))
-            if (
-                ahead_ok and stream.left > 0
-                and ahead.rows.out is self._last and ahead.rows.off < FEED_ROWS
-            ):
-                fed.append(([fed_token(ahead.rows.off)], [ahead.position + 1], table, None))
+            if not ahead_ok or ahead.rows.out is not self._last:
+                continue
+            # The stream's next slot, read from this one's rows on the device:
+            # ``width`` tokens from the position after this slot's first. Under
+            # a drafting model from its first row (what it sampled and drafted,
+            # one position on) or, this slot's draft accepted, from its second
+            # (two on): one of the two, by the likelier verdict; a slot of one
+            # token had no draft to accept.
+            src, at = ahead.rows.off, ahead.position + 1
+            stream.guess = None
+            if width > 1:
+                stream.guess = ahead.rows.n == width and self._guess(stream)
+                src, at = src + stream.guess, at + stream.guess
+            if at + width - 1 <= stream.last and src < FEED_ROWS:
+                slot = [fed_token(src, draft=bool(k)) for k in range(width)]  # the token, its draft
+                fed.append((slot, list(range(at, at + width)), table, None))
         return taken, launched + fed, len(fed), ahead_ok
 
     def _resolve(self, launched: List[tuple], fed: int, ahead_ok: bool,
@@ -882,30 +973,34 @@ class WaveDecoder:
         wave's ``logits`` (no device call: nothing is cut until a reader asks),
         and the wave's counters kept. A call whose stream ends here, or has none,
         resolves now (only real rows' futures resolve). A call whose stream
-        goes on keeps its row as the stream's ``ahead`` and is returned, to be
-        enqueued again: the flush that takes it launches the stream's next row
+        goes on keeps its rows as the stream's ``ahead`` and is returned, to be
+        enqueued again: the flush that takes it launches the stream's next slot
         and only then resolves it, so the request blocks in its read-back
-        with that row already behind its wave on the device. Each of the last
+        with that slot already behind its wave on the device. Each of the last
         ``fed`` entries, which no call waits for yet, becomes its stream's
-        ``ahead``."""
+        ``ahead``, under the verdict ``_sort`` launched it under."""
         self.waves += 1
         self.waves_ahead += fed > 0
         self.one_row_waves += wave.real_rows == 1
         self.max_wave = max(self.max_wave, len(launched))
         self._last = out
+        width = self.width
         off, again = 0, []
         for entry in launched:
             toks, pos, table, fut = entry
             if fut is None or not fut.done():
                 rows = WaveRows(self, logits, out, off, len(toks))  # its own rows of its slot
                 stream = self._streams.get(id(table))
-                if stream is not None and (fut is None or (
-                    ahead_ok and stream.left > 0 and len(toks) == 1 and off < FEED_ROWS
-                )):
+                if fut is None:
+                    if stream is not None:
+                        stream.ahead = _Ahead(pos[0], rows, stream.guess)
+                elif (
+                    stream is not None and ahead_ok and pos[0] + width <= stream.last
+                    and len(toks) <= width and off + width <= FEED_ROWS
+                ):
                     stream.ahead = _Ahead(pos[0], rows)
-                    if fut is not None:
-                        again.append(entry)
-                elif fut is not None:
+                    again.append(entry)
+                else:
                     fut.set_result(rows)
             off += self._slot(toks)
         self._keep(aux)
@@ -947,7 +1042,7 @@ class WaveDecoder:
                     wspan.annotate(
                         entries=len(launched), real_rows=wave.real_rows,
                         rows=len(wave.tokens), pages=wave.meta.num_pages,
-                        pad_pages=wave.meta.pad_pages, fed_rows=fed,
+                        pad_pages=wave.meta.pad_pages, fed_rows=fed * self.width,
                     )
 
                 # The flush task inherited the context of the request that
@@ -1610,16 +1705,21 @@ class ContinuousBatchingHarness:
         emit_s: List[float] = []
         first_token_t: Optional[float] = None
         closing = (len(token_ids) + gen_tokens) % self.config.block_tokens == 0
-        # Without a drafter every round is one token at the next position, and
-        # this loop says so: the decoder may then have round k + 1's row on the
-        # device while round k's token is read here (``WaveDecoder.stream``).
-        # A drafter's next chunk is the host's to draft, and a model that
-        # drafts itself hands the host the draft that chunk holds.
+        # What this loop can promise of its rounds, it declares: the decoder may
+        # then have round k + 1's slot on the device while round k's rows are
+        # read here (``WaveDecoder.stream``). Without a drafter every round is
+        # one token at the next position, the closing step too. A model that
+        # drafts brings ``[token, draft]`` while two tokens are still wanted,
+        # one or two positions on by a verdict the device knows first; its
+        # one-token rounds (the first, the last, the closing step) are
+        # launched from here. A host drafter's next chunk is the host's to write.
+        if self.drafter is not None:
+            declared = contextlib.nullcontext()
+        else:
+            declared = self.wave.stream(
+                padded, pos + gen_tokens - 1 + (closing and not self.drafts)
+            )
         drafting = self.drafter is not None or self.drafts
-        declared = (
-            contextlib.nullcontext() if drafting
-            else self.wave.stream(padded, gen_tokens + closing)
-        )
         draft: Optional[int] = None  # the model's own, from the round before
         accepted: List[int] = []  # drafts accepted, a round
         with tracing.trace_op("generate") as gspan, declared:
@@ -2179,7 +2279,9 @@ class ContinuousBatchingHarness:
         ONE real flat row (a lone request's steps: the waves whose dense FFN
         pads its row, models/llama.py ``_ffn``), ``wave_ahead_waves``, the
         launched waves with a row fed from the wave before them on the device
-        (``WaveDecoder``, "one wave ahead"), ``wave_pages`` /
+        (``WaveDecoder``, "one wave ahead"), ``wave_ahead_dropped``, a drafting
+        model's slots launched ahead under a verdict that did not hold (each
+        cost its request one round through the host), ``wave_pages`` /
         ``wave_pad_pages``, the flat attention
         pages launched and those of them that were the page bucket's
         padding; ``wave_layer_pages`` / ``wave_window_pages_skipped``, the
@@ -2320,6 +2422,9 @@ class ContinuousBatchingHarness:
             # took its token from the wave before it on the device: the waves
             # launched while their requests still read the last one's tokens.
             "wave_ahead_waves": self.wave.waves_ahead,
+            # Of a model that drafts: the slots launched ahead that no call
+            # took, their verdict guessed wrong.
+            "wave_ahead_dropped": self.wave.ahead_dropped,
             # Flat attention pages the waves launched, and how many were
             # the power-of-two bucket's padding: steps the ragged kernel
             # neither computes nor fetches (tpu/paged_attention.py).

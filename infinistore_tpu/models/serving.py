@@ -97,7 +97,11 @@ layout) -> (logits, caches, ids, feed, aux)``
     what lets the decoder launch a stream's next wave before the host has seen
     the token that wave starts from (``WaveDecoder``, "one wave ahead"). The
     model's wave body gets ``tokens`` as it always did. A wave with no such
-    row is handed :func:`no_feed`.
+    row is handed :func:`no_feed`. Of a model that drafts the feed is twice
+    as long (:func:`feed_rows`): the first :data:`FEED_ROWS` ids, then the
+    drafts of the same rows, so that a slot ``[token, draft]`` can be read
+    whole on the device: ``fed_token(src)`` is row ``src``'s id and
+    ``fed_token(src, draft=True)`` its draft.
 
 Every step DONATES ``caches``: the caller uses the returned ones.
 
@@ -142,16 +146,24 @@ from ..tpu.moe import EXPERT_COUNTERS, expert_counts
 FEED_ROWS = 64
 
 
-def fed_token(src: int) -> int:
-    """The token slot that reads row ``src`` of ``prev_ids``."""
+def fed_token(src: int, draft: bool = False) -> int:
+    """The token slot that reads what row ``src`` of the wave before sampled:
+    ``prev_ids[src]``, or with ``draft`` (a model that drafts) what it drafted,
+    which lies :data:`FEED_ROWS` further on."""
     if not 0 <= src < FEED_ROWS:
         raise ValueError(f"only rows 0..{FEED_ROWS - 1} of a wave can feed the next, got {src}")
-    return -(src + 1)
+    return -(src + 1 + FEED_ROWS * draft)
 
 
-def no_feed() -> jax.Array:
+def feed_rows(drafts: bool = False) -> int:
+    """The length of a wave's ``feed``: the ids of its first :data:`FEED_ROWS`
+    rows and, of a model that ``drafts``, their drafts behind them."""
+    return FEED_ROWS * (2 if drafts else 1)
+
+
+def no_feed(drafts: bool = False) -> jax.Array:
     """``prev_ids`` for a wave whose every token comes from the host."""
-    return jnp.zeros((FEED_ROWS,), jnp.int32)
+    return jnp.zeros((feed_rows(drafts),), jnp.int32)
 
 
 class ServingSteps(NamedTuple):
@@ -380,13 +392,14 @@ def verify_step_ragged(
     body alone is: a jit nested under this one cost every wave bucket a
     quarter of a second of set-up on the chip's host (PERF.md, PR 36).
     ``caches`` is donated, declared here as the body declares it."""
-    if prev_ids.shape != (FEED_ROWS,):
-        raise ValueError(f"prev_ids is a wave's feed, [{FEED_ROWS}], got {prev_ids.shape}")
+    fed = feed_rows(config.steps.drafts)
+    if prev_ids.shape != (fed,):
+        raise ValueError(f"prev_ids is a wave's feed, [{fed}], got {prev_ids.shape}")
     f = unpack_wave(packed, layout, max_blocks)
     # A slot is a token or ``fed_token(src)``. ``lax`` calls and an index, no
     # ``jnp`` function that is a jit of its own (the set-up rule above).
     slots = f["tokens"]
-    src = jax.lax.clamp(0, -slots - 1, FEED_ROWS - 1)
+    src = jax.lax.clamp(0, -slots - 1, fed - 1)
     tokens = jax.lax.select(slots < 0, prev_ids.at[src].get(mode="promise_in_bounds"), slots)
     kw = {}
     if layout.window_pages is not None:
@@ -400,11 +413,15 @@ def verify_step_ragged(
         max_blocks, **kw,
     )
     ids = jax.lax.argmax(logits, 1, jnp.int32)  # what jnp.argmax(logits, -1) computes
-    short = max(FEED_ROWS - layout.rows, 0)
-    feed = jax.lax.pad(ids[:FEED_ROWS], jnp.int32(0), [(0, short, 0)])
+    short = [(0, max(FEED_ROWS - layout.rows, 0), 0)]
+    feed = jax.lax.pad(ids[:FEED_ROWS], jnp.int32(0), short)
     aux = dict(aux[0]) if aux else {}
     drafts = aux.pop("drafts", None)
     if config.steps.drafts:
+        # The next wave may read a slot's draft where it reads its token.
+        feed = jax.lax.concatenate(
+            [feed, jax.lax.pad(drafts[:FEED_ROWS], jnp.int32(0), short)], 0
+        )
         # The ids over the body's drafts, ONE array: a round's token and its
         # draft reach the host in one read (each blocking read costs the host
         # about as much as a dispatch).

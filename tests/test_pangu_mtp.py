@@ -188,6 +188,10 @@ def test_the_program_through_the_harness_against_the_reference(conn, params, pat
         t = Tapped(conn, params, f"pangu-{path}-{question}")
         miss, miss_calls = await t.ask(first)
         assert (miss.loaded_blocks, miss.computed_blocks) == (0, prompt_blocks)
+        # The rounds between the first and the last rode slots launched ahead
+        # of their request (``WaveDecoder.stream``), none under a wrong verdict.
+        ahead = t.h.metrics()
+        assert (ahead["wave_ahead_waves"], ahead["wave_ahead_dropped"]) == (GEN - 2, 0), ahead
         if path == "miss":
             return first, miss, miss_calls
         tokens = first if path == "full-hit" else other
@@ -202,6 +206,7 @@ def test_the_program_through_the_harness_against_the_reference(conn, params, pat
             np.testing.assert_array_equal(calls[0][0], miss_calls[0][0])
             assert hit.generated == miss.generated
             assert [c[2] for c in calls] == [c[2] for c in miss_calls]
+        assert t.h.metrics()["wave_ahead_waves"] == 2 * (GEN - 2)
         return tokens, hit, calls
 
     tokens, stats, calls = asyncio.run(drive())
@@ -265,6 +270,10 @@ def test_the_output_with_the_drafter_is_the_output_without(conn, vocab, together
     want, base, _ = _serve(conn, params, plain, f"plain-{vocab}-{together}", prompts, gen, together)
     got, m, calls = _serve(conn, params, cfg, f"mtp-{vocab}-{together}", prompts, gen, together)
     assert got == want
+    # Both were launched ahead, a row of one and a slot of two; a slot dropped
+    # (a verdict guessed wrong) where drafts land, and only there.
+    assert base["wave_ahead_waves"] > 10 and m["wave_ahead_waves"] > 10, (base, m)
+    assert base["wave_ahead_dropped"] == 0 and (m["wave_ahead_dropped"] > 0) == (vocab < 512), m
     assert base["spec_drafted_tokens"] == 0 and base["spec_emitted_tokens"] == base["spec_rounds"] == 3 * gen
     assert m["spec_emitted_tokens"] == 3 * gen == m["spec_rounds"] + m["spec_accepted_tokens"]
     assert m["spec_drafted_tokens"] >= m["spec_rounds"] - 3 - m["spec_accepted_tokens"] - 3

@@ -947,7 +947,7 @@ def test_pangu_mtp_entries_compile_at_published_widths_and_update_every_cache_te
             serving.WaveLayout(rows=8, tables=4, pages=256) if entry == "packed_wave"
             else serving.WaveLayout(rows=2, tables=1, pages=64)
         )
-        args = (params, i32(layout.size(table)), i32(serving.FEED_ROWS), caches)
+        args = (params, i32(layout.size(table)), i32(serving.feed_rows(drafts=True)), caches)
         jitted = serving.verify_step_ragged
         static = {"config": cfg, "max_blocks": table, "layout": layout}
     else:

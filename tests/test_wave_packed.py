@@ -11,6 +11,7 @@ rows ``step_chunk`` hands back, and the count of transfers to 2 a wave.
 
 import asyncio
 import contextlib
+import dataclasses
 
 import jax
 import jax.numpy as jnp
@@ -28,7 +29,9 @@ from infinistore_tpu.engine import (
     WaveDecoder,
     WaveRows,
 )
-from infinistore_tpu.models import AfmoeConfig, LlamaConfig, afmoe, falcon_h1, llama, serving
+from infinistore_tpu.models import (
+    AfmoeConfig, LlamaConfig, afmoe, falcon_h1, llama, pangu_mtp, serving,
+)
 from infinistore_tpu.models.serving import WaveLayout, pack_wave, unpack_wave
 
 NUM_BLOCKS, MAX_REQ_BLOCKS = 64, 16
@@ -560,7 +563,7 @@ async def request_loop(wave, table, tok, pos, rounds, declare=True, seen=None):
     """``_generate``'s loop without a drafter: a round's token is on the host
     before the next round is asked for."""
     out = []
-    declared = wave.stream(table, rounds) if declare else contextlib.nullcontext()
+    declared = wave.stream(table, pos + rounds - 1) if declare else contextlib.nullcontext()
     with declared:
         for _ in range(rounds):
             rows = await asyncio.wait_for(wave.step_chunk([tok], [pos], table), 60)
@@ -742,7 +745,7 @@ def test_a_stream_that_never_comes_back_strands_nobody():
         async def leaves():
             (tok, pos), table = starts[1], tables[1]
             with pytest.raises(asyncio.CancelledError):
-                with wave.stream(table, 10):
+                with wave.stream(table, pos + 9):
                     for _ in range(2):
                         rows = await wave.step_chunk([tok], [pos], table)
                         tok, pos = int(wave.token_ids(rows)[0]), pos + 1
@@ -806,10 +809,10 @@ def test_a_stream_that_comes_back_elsewhere_is_an_error():
     async def run():
         wave = streaming_decoder(cfg, params, caches)
         (tok, pos), table = starts[0], tables[0]  # the stream is known by the table OBJECT
-        with wave.stream(table, 5):
+        with wave.stream(table, pos + 4):
             rows = await wave.step_chunk([tok], [pos], table)
             tok = int(wave.token_ids(rows)[0])
-            with pytest.raises(RuntimeError, match="launched for one token at"):
+            with pytest.raises(RuntimeError, match="launched for 1 at"):
                 await wave.step_chunk([tok], [pos + 2], table)
 
     asyncio.run(run())
@@ -823,3 +826,235 @@ def test_the_counter_is_in_the_harness_metrics(conn):
     m = asyncio.run(h.run(ps, concurrency=3, gen_tokens=6))
     assert 0 < m["wave_ahead_waves"] < m["decode_waves"]
     assert m["wave_host_transfers"] == 2 * m["decode_waves"]
+
+
+# ---------------------------------------------------------------------------
+# A model that drafts: its stream's next SLOT ``[token, draft]`` is launched
+# ahead under a guessed verdict, one position on (the draft rejected) or two.
+# ---------------------------------------------------------------------------
+
+# The multi-token-prediction model at its small size (tests/test_pangu_mtp.py).
+# With these seeded weights no draft lands at a vocabulary of 512, every draft
+# at 4, and about half of them at 2.
+DRAFTING = pangu_mtp.PanguMtpConfig(dtype=jnp.float32)
+LANDS = {"rejected": 512, "accepted": 4}
+
+
+def drafting_run(conn, name, vocab, together, *, ahead=True, guess=None, gen=25):
+    """Three requests through a harness of the drafting model, served one at a
+    time or together. ``ahead`` false: the host-fed decoder (no request declares
+    its stream, as before drafting streams were). ``guess``: the verdict every
+    slot is launched under (the harness's record says so from the start; None:
+    the record as the run itself writes it). Returns ``(harness, generated a
+    request, (prompt length, table) a request, the fed entries that broke a
+    stream's promise)``."""
+    cfg = dataclasses.replace(DRAFTING, vocab=vocab)
+    params = pangu_mtp.init_params(cfg, jax.random.key(622))
+    rng = np.random.default_rng(623)
+    bt = cfg.block_tokens
+    # 29 + 25, 18 + 25, 15 + 25: the third answer closes a block.
+    ps = [rng.integers(0, vocab, size=n).tolist() for n in (3 * bt + 5, 2 * bt + 2, bt + 7)]
+    h = harness(conn, cfg, params, name)
+    if guess is not None:
+        h.spec_drafted, h.spec_accepted = 10_000, 10_000 * guess
+    wave, live, broken, served = h.wave, {}, [], []
+    real_stream, real_assemble, real_generate = wave.stream, wave._assemble, h._generate
+
+    @contextlib.contextmanager
+    def stream(table, last):
+        live[id(table)] = last
+        try:
+            with real_stream(table, last) if ahead else contextlib.nullcontext():
+                yield
+        finally:
+            del live[id(table)]
+
+    def assemble(batch):
+        # A fed entry has no future: its stream is live and the slot ends by ``last``.
+        broken.extend(
+            (pos, live.get(id(table))) for _, pos, table, fut in batch
+            if fut is None and not pos[-1] <= live.get(id(table), -1)
+        )
+        return real_assemble(batch)
+
+    async def generate(token_ids, table, gen_tokens):
+        served.append((len(token_ids), np.array(table)))
+        return await real_generate(token_ids, table, gen_tokens)
+
+    wave.stream, wave._assemble, h._generate = stream, assemble, generate
+
+    async def drive():
+        if together:
+            return await asyncio.gather(*(h.run_request(p, gen_tokens=gen) for p in ps))
+        return [await h.run_request(p, gen_tokens=gen) for p in ps]
+
+    stats = asyncio.run(drive())
+    return h, [s.generated for s in stats], served, broken
+
+
+def committed(h, served, gen):
+    """What a request's committed positions hold, layer by layer: every latent
+    slot up to the last position its rounds wrote, and the boundary rows of its
+    complete blocks."""
+    bt = h.config.block_tokens
+    out = []
+    for n, table in served:
+        end = n + gen - 1 + ((n + gen) % bt == 0)  # a closing step lands the last token too
+        ids = jnp.asarray(table[: -(-end // bt)])
+        for layer in h.caches:
+            latent = np.asarray(layer[0][ids])  # [blocks, width, bt]
+            out.append(np.moveaxis(latent, 2, 1).reshape(-1, latent.shape[1])[:end])
+            out.extend(np.asarray(t[ids[: end // bt]]) for t in layer[1:])
+    return out
+
+
+@pytest.fixture()
+def guess_by_the_harness(monkeypatch):
+    """The guess follows the harness's record alone, which ``drafting_run`` fixes."""
+    monkeypatch.setattr(WaveDecoder, "OWN_RECORD_ROUNDS", 1 << 30)
+
+
+@pytest.mark.parametrize("together", [False, True], ids=["one-at-a-time", "three-live"])
+@pytest.mark.parametrize("fact", sorted(LANDS))
+@pytest.mark.parametrize("guess", sorted(LANDS))
+def test_a_drafting_streams_slot_launched_ahead_is_the_host_fed_slot(
+    conn, guess_by_the_harness, guess, fact, together
+):
+    """Every slot launched under ``guess`` while every draft is, in ``fact``,
+    rejected or accepted: the tokens are the host-fed decoder's and so is every
+    committed position of every layer's cache, the drafting layer's slots and
+    boundary rows among them; a wave is read at most once; no slot is launched
+    for a stream that ended or past its last position. A right guess drops
+    nothing and costs no wave; a wrong one raises nothing, is counted, and costs
+    its request one more wave, the round through the host."""
+    gen, name = 25, f"slot-{guess}-{fact}-{together}"
+    h, got, served, broken = drafting_run(
+        conn, name, LANDS[fact], together, guess=guess == "accepted", gen=gen
+    )
+    p, want, p_served, _ = drafting_run(conn, name + "-plain", LANDS[fact], together, ahead=False, gen=gen)
+    assert got == want
+    assert not broken, broken
+    mine, theirs = (sorted(s, key=lambda r: r[0]) for s in (served, p_served))  # by prompt
+    for i, (a, b) in enumerate(zip(committed(h, mine, gen), committed(p, theirs, gen))):
+        np.testing.assert_array_equal(a, b, err_msg=f"cache tensor {i}")
+    m, base = h.metrics(), p.metrics()
+    assert base["wave_ahead_waves"] == base["wave_ahead_dropped"] == 0
+    assert m["spec_rounds"] == base["spec_rounds"]
+    assert m["spec_accepted_tokens"] - 10_000 * (guess == "accepted") == base["spec_accepted_tokens"]
+    assert h.wave.blocking_reads <= h.wave.waves and not h.wave._streams
+    assert m["wave_ahead_waves"] > 10 - 5 * together
+    if guess == fact:
+        # (At 512 one draft of these seeds may land: one slot dropped.)
+        assert m["wave_ahead_dropped"] <= (fact == "rejected")
+    else:
+        assert m["wave_ahead_dropped"] > 10
+    if not together:
+        assert m["wave_ahead_dropped"] <= m["wave_ahead_waves"]
+        assert m["decode_waves"] == base["decode_waves"] + m["wave_ahead_dropped"]
+        assert h.wave.blocking_reads == p.wave.blocking_reads  # a dropped slot's wave is never read
+
+
+@pytest.mark.parametrize("vocab", [2, 4], ids=["half-land", "all-land"])
+def test_the_guess_follows_what_the_drafts_did(conn, vocab):
+    """Nothing fixed: the first slots go out as rejected (nothing is known), and
+    once the record says that drafts land the slots go out as accepted. Where
+    every draft lands the wrong guesses are the first few; where half do, about
+    half the slots are dropped, and the answer is the host-fed decoder's still."""
+    h, got, _, broken = drafting_run(conn, f"guess-{vocab}", vocab, together=False)
+    _, want, _, _ = drafting_run(conn, f"guess-{vocab}-plain", vocab, together=False, ahead=False)
+    m = h.metrics()
+    assert got == want and not broken
+    assert m["spec_accepted_tokens"] >= 20
+    if vocab == 4:
+        assert 1 <= m["wave_ahead_dropped"] <= 3
+    else:
+        assert 3 < m["wave_ahead_dropped"] < m["wave_ahead_waves"]
+
+
+def test_a_streams_own_record_outvotes_the_harness_after_a_few_rounds():
+    cfg, init = MODELS["llama"]
+    h = bare_harness(cfg, init(cfg), cfg.kv_spec(NUM_BLOCKS).make_caches())
+    h.spec_drafted = h.spec_accepted = 0
+    wave = WaveDecoder(h)
+    stream = engine_mod._Stream(np.zeros(MAX_REQ_BLOCKS, np.int32), 99)
+    assert wave._guess(stream) is False  # nothing known
+    h.spec_drafted, h.spec_accepted = 10, 6
+    assert wave._guess(stream) is True
+    stream.rounds, stream.accepted = wave.OWN_RECORD_ROUNDS - 1, 0
+    assert wave._guess(stream) is True  # too few rounds of its own
+    stream.rounds += 1
+    assert wave._guess(stream) is False
+    stream.accepted = stream.rounds // 2 + 1
+    assert wave._guess(stream) is True
+
+
+def drafting_decoder():
+    """A bare decoder of the drafting model over one landed prompt: ``(decoder,
+    the request's table, the position after its prompt)``."""
+    cfg = dataclasses.replace(DRAFTING, vocab=64)
+    params = pangu_mtp.init_params(cfg, jax.random.key(633))
+    prompt = np.random.default_rng(634).integers(0, cfg.vocab, size=2 * cfg.block_tokens + 3)
+    table = np.arange(1, 1 + MAX_REQ_BLOCKS, dtype=np.int32)
+    _, caches = cfg.steps.prefill(
+        params, jnp.asarray(prompt, jnp.int32), cfg.kv_spec(NUM_BLOCKS).make_caches(),
+        jnp.asarray(table[:3]), cfg,
+    )
+    h = bare_harness(cfg, params, caches)
+    h.arriving = h.spec_drafted = h.spec_accepted = 0
+    return WaveDecoder(h), table, len(prompt)
+
+
+def test_a_drafting_waves_feed_carries_its_drafts_and_a_slot_reads_either():
+    """The program of a model that drafts: its ``feed`` is the ids of its first
+    ``FEED_ROWS`` rows and then their drafts, a slot ``fed_token(src)`` reads
+    the one and ``fed_token(src, draft=True)`` the other, and the fed wave is in
+    every bit the wave whose tokens the host wrote out."""
+    rows = serving.FEED_ROWS
+    assert (serving.feed_rows(), serving.feed_rows(True)) == (rows, 2 * rows)
+    assert serving.no_feed(True).shape == (2 * rows,)
+    wave, table, at = drafting_decoder()
+    w = wave._assemble([([5, 9], [at, at + 1], table, None), ([7], [at], table + 20, None)])
+    _, first, _ = wave.launch(w.tokens, w.positions, w.row_of, w.meta, w.tables)
+    ids = np.asarray(first.ids)
+    assert ids.shape == (2, 4)  # two slots of two; sampled over drafted
+    feed = np.zeros(2 * rows, np.int32)
+    feed[:4], feed[rows : rows + 4] = ids
+    np.testing.assert_array_equal(np.asarray(first.feed), feed)
+    # The next wave: the first entry's next slot under "rejected", from row 0.
+    nxt = wave._assemble([([int(ids[0, 0]), int(ids[1, 0])], [at + 1, at + 2], table, None)])
+    kept = jax.tree.map(jnp.copy, wave.h.caches)
+    want = wave.launch(nxt.tokens, nxt.positions, nxt.row_of, nxt.meta, nxt.tables)
+    want_caches, wave.h.caches = wave.h.caches, kept
+    slots = [serving.fed_token(0), serving.fed_token(0, draft=True)]
+    got = wave.launch(slots, nxt.positions, nxt.row_of, nxt.meta, nxt.tables, None, first.feed)
+    same_bits(got[0], want[0], "logits")
+    same_bits((got[1].ids, got[1].feed), (want[1].ids, want[1].feed), "ids")
+    same_bits(wave.h.caches, want_caches, "caches")
+    with pytest.raises(ValueError):
+        wave.launch(slots, nxt.positions, nxt.row_of, nxt.meta, nxt.tables, None, serving.no_feed())
+
+
+def test_a_drafting_stream_fits_one_of_two_verdicts_or_is_an_error():
+    """A drafting stream's call may come back one position on or two (the slot
+    launched for the other verdict is dropped and nothing is raised); anywhere
+    else, or past the table it declared, is refused."""
+    wave, table, at = drafting_decoder()
+
+    async def run():
+        with pytest.raises(ValueError, match="past a table"):
+            with wave.stream(table, MAX_REQ_BLOCKS * wave.h.config.block_tokens):
+                pass
+        with wave.stream(table, at + 20):
+            rows = await wave.step_chunk([5], [at - 1], table)  # a first round: one token
+            tok, draft = int(wave.token_ids(rows)[0]), int(wave.draft_ids(rows)[0])
+            rows = await wave.step_chunk([tok, draft], [at, at + 1], table)  # launched ahead
+            # Handed only once the flush that took it had launched the slot after it.
+            assert (wave.waves, wave.waves_ahead, wave.ahead_dropped) == (3, 2, 0)
+            # As if the draft had been accepted: two on, where nothing was launched.
+            ids, drafts = wave.token_ids(rows), wave.draft_ids(rows)
+            await wave.step_chunk([int(ids[1]), int(drafts[1])], [at + 2, at + 3], table)
+            assert wave.ahead_dropped == 1
+            with pytest.raises(RuntimeError, match="its slot was launched for 2 at"):
+                await wave.step_chunk([1, 2], [at + 9, at + 10], table)
+
+    asyncio.run(run())

@@ -134,6 +134,8 @@ def programs(tree: str, names):
         by_blocks = spec.has_state or cfg.steps.resume_in_block
         drafts = getattr(cfg.steps, "drafts", False)  # a tree before PR 62 has no such role
         width = 2 if drafts else 1
+        # A tree before PR 63 feeds the ids alone, whatever its steps draft.
+        feed = serving.feed_rows(drafts) if hasattr(serving, "feed_rows") else serving.FEED_ROWS
         seen = set()
         for cell in bench["workloads"]:
             if cell["config"] != name:
@@ -163,7 +165,7 @@ def programs(tree: str, names):
                 static = {"config": cfg, "max_blocks": mb, "layout": layout}
                 found.append((
                     f"verify_step_ragged/T{rows}.P{pages}.mb{mb}", serving.verify_step_ragged,
-                    (params, i32(layout.size(mb)), i32(serving.FEED_ROWS), caches), static,
+                    (params, i32(layout.size(mb)), i32(feed), caches), static,
                 ))
             for shape, jitted, args, static in found:
                 row = f"{name}/{shape}"

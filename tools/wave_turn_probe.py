@@ -125,7 +125,7 @@ def bare_decoder(config, params, num_blocks, max_req_blocks):
 async def streams(wave, tables, pos: int, rounds: int):
     async def one(table, pos=pos):
         tok = 1
-        with wave.stream(table, rounds):
+        with wave.stream(table, pos + rounds - 1):
             for _ in range(rounds):
                 rows = await wave.step_chunk([tok], [pos], table)
                 tok, pos = int(wave.token_ids(rows)[0]), pos + 1
